@@ -1,0 +1,514 @@
+#!/usr/bin/env python3
+"""The quickest proof that the system still starts on the chip.
+
+One process, which owns every local chip, drives the main path once through
+the entry points a user calls — ``ps.start_ps`` / ``KVServer`` /
+``KVWorker`` / ``ps.finalize`` with ``PS_VAN_TYPE=ici`` — at the full width
+of the configurations the repo supports (BASELINE.json configs 4 and 5 and
+the README's own snippet), and checks every result against a numpy
+reference:
+
+- ``resnet50``: all 25,557,032 f32 parameters of the ResNet-50 gradient
+  trace as its 36 buckets, host-origin ``[W, total]`` gradients,
+  ``push_pull`` + ``wait`` under the fused ``sgd_momentum`` server handle;
+- ``readme``: 40 keys x 256,000 f32 in one bucket, ``push_pull``, then a
+  separate ``push`` and ``pull``;
+- ``sparse``: a 2^20 x 64 embedding table, Zipf indices,
+  ``push_sparse`` / ``pull_sparse``;
+- ``message_path``: an unregistered key, which the collective path cannot
+  take, answered by the ``KVServer`` handler;
+- ``ring`` (two or more devices): the ResNet-50 buckets once more through
+  the fused Pallas ring kernel, per bucket and grouped, against XLA's
+  collectives.
+
+It has no CPU mode: without a TPU it exits non-zero before any work.  Every
+phase has a deadline; a phase that fails or hangs ends the run non-zero
+with its name.  Wall times are printed as set-up information only (the
+first step of a phase compiles, the later ones do not) — a measurement is
+the benchmark's business, not this script's.
+
+The last line of standard output is the verdict::
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+``tests/test_chip_smoke.py`` drives :func:`run_smoke` at a tiny size on the
+virtual CPU mesh, kernels interpreted.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import json
+import os
+import sys
+import threading
+import time
+import traceback
+from dataclasses import dataclass, field
+from typing import Callable, List, Optional, Sequence, Tuple
+
+import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+LR, MOMENTUM = 0.01, 0.9
+SERVER_HANDLE = f"sgd_momentum:{LR},{MOMENTUM}"
+RING_HANDLE = f"sgd:{LR}"  # the ring kernel serves stateless handles
+SEED = 20260926
+
+
+def _resnet50_buckets() -> Tuple[Tuple[str, int], ...]:
+    from pslite_tpu.models.resnet_trace import make_buckets
+
+    return tuple(make_buckets())
+
+
+@dataclass(frozen=True)
+class Sizes:
+    """What the smoke moves.  The defaults are the full width."""
+
+    dense_buckets: Sequence[Tuple[str, int]] = field(
+        default_factory=_resnet50_buckets
+    )
+    steps: int = 3
+    # How many of the dense buckets also go through the ring kernel (None:
+    # all).  The interpreter takes seconds per ring program, so the CPU
+    # test leaves the phase to tests/test_ring_collective.py.
+    ring_buckets: Optional[int] = None
+    readme_keys: int = 40
+    readme_val_len: int = 256_000
+    emb_rows: int = 1 << 20
+    emb_dim: int = 64
+    emb_batch: int = 4096
+
+
+class PhaseFailed(RuntimeError):
+    """A phase raised; ``__cause__`` is what it raised."""
+
+
+def check(ok, what) -> None:
+    """An ``assert`` that ``python -O`` cannot remove."""
+    if not ok:
+        raise AssertionError(what)
+
+
+def _expired(name: str, seconds: float) -> None:
+    """A hung device call cannot be interrupted from Python: say which
+    phase it was and leave."""
+    print(f"chip_smoke: FAILED phase={name}: no end after {seconds:.0f} s",
+          file=sys.stderr, flush=True)
+    sys.stdout.flush()
+    os._exit(3)
+
+
+@contextlib.contextmanager
+def deadline(name: str, seconds: float,
+             expired: Callable[[str, float], None] = _expired):
+    timer = threading.Timer(seconds, expired, args=(name, seconds))
+    timer.daemon = True
+    timer.start()
+    try:
+        yield
+    finally:
+        timer.cancel()
+
+
+def _momentum_reference(agg_steps, n: int) -> np.ndarray:
+    """The store after the ``sgd_momentum`` recurrence over the summed
+    gradients ``agg_steps`` (float32, like the kernel)."""
+    store = np.zeros(n, np.float32)
+    mom = np.zeros(n, np.float32)
+    for agg in agg_steps:
+        mom = np.float32(MOMENTUM) * mom + agg
+        store = store - np.float32(LR) * mom
+    return store
+
+
+class _Smoke:
+    def __init__(self, mesh, sizes: Sizes):
+        self.mesh = mesh
+        self.sizes = sizes
+        self.n_dev = int(mesh.devices.size)
+        self.on_tpu = next(iter(mesh.devices.flat)).platform == "tpu"
+        self.kv = None
+        self.server = None
+
+    def phases(self) -> List[Tuple[str, float, Callable[[], None]]]:
+        out = [
+            ("boot", 60, self.boot),
+            ("resnet50", 400, self.resnet50),
+            ("readme", 150, self.readme),
+            ("sparse", 200, self.sparse),
+            ("message_path", 30, self.message_path),
+        ]
+        if self.n_dev >= 2 and self.sizes.ring_buckets != 0:
+            out.append(("ring", 150, self.ring))
+        out.append(("shutdown", 30, self.shutdown))
+        return out
+
+    # -- boot / shutdown -----------------------------------------------------
+
+    def boot(self) -> None:
+        """Scheduler + one joint (server and worker) node in this process,
+        each through ``ps.start_ps``, over the in-process ICI van."""
+        import pslite_tpu as ps
+
+        env = ps.environment.Environment({
+            "PS_VAN_TYPE": "ici",
+            "PS_ICI_SERVER_HANDLE": SERVER_HANDLE,
+            "DMLC_NUM_WORKER": "1",
+            "DMLC_NUM_SERVER": "1",
+            "DMLC_PS_ROOT_URI": "chip_smoke",
+            "DMLC_PS_ROOT_PORT": "1",
+        })
+        errors: list = []
+
+        def start(role: str) -> None:
+            try:
+                ps.start_ps(role=role, env=env)
+            except BaseException as exc:  # re-raised on this thread below
+                errors.append(exc)
+
+        threads = [
+            threading.Thread(target=start, args=(role,), daemon=True,
+                             name=f"start-{role}")
+            for role in ("scheduler", "joint")
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        if errors:
+            raise errors[0]
+        self.server = ps.KVServer(0)
+        self.server.set_request_handle(ps.KVServerDefaultHandle())
+        self.kv = ps.KVWorker(0, 0)
+        eng = self.kv.engine
+        check(eng is not None, "the ici van built no collective engine")
+        got = [d.id for d in eng.mesh.devices.flat]
+        want = [d.id for d in self.mesh.devices.flat]
+        check(got == want, f"engine mesh {got} is not the given mesh {want}")
+        check(eng.impl == "xla", f"engine impl {eng.impl!r}")
+        print(f"  engine: {self.n_dev} device(s), worker sum W = "
+              f"{eng.num_workers}, kernels "
+              f"{'interpreted' if eng._interpret else 'compiled (Mosaic)'}")
+        check(eng._interpret == (not self.on_tpu),
+              "kernels interpreted on a TPU mesh, or compiled off one")
+
+    def shutdown(self) -> None:
+        import pslite_tpu as ps
+
+        ps.finalize()
+        self.server.stop()
+
+    # -- dense: ResNet-50 trace ---------------------------------------------
+
+    def resnet50(self) -> None:
+        kv, eng = self.kv, self.kv.engine
+        W = eng.num_workers
+        buckets = list(self.sizes.dense_buckets)
+        rng = np.random.default_rng(SEED)
+        keys, grads, outs = [], [], []
+        for i, (name, n) in enumerate(buckets):
+            # One distinct key per bucket: the worker routes a request to
+            # its bucket by (count, first key, last key).
+            k = np.array([1000 + i], dtype=np.uint64)
+            kv.register_dense(name, k, n)
+            keys.append(k)
+            grads.append(rng.standard_normal((W, n), dtype=np.float32))
+            outs.append(np.zeros(n, np.float32))
+        if self.on_tpu:
+            self._assert_fused_kernel_in_program(buckets[0][0])
+
+        walls = []
+        for step in range(self.sizes.steps):
+            t0 = time.perf_counter()
+            scale = np.float32(step + 1)
+            stamps = [
+                kv.push_pull(k, g * scale, out)
+                for k, g, out in zip(keys, grads, outs)
+            ]
+            for ts in stamps:
+                kv.wait(ts)
+            walls.append(time.perf_counter() - t0)
+
+        for (name, n), g, out in zip(buckets, grads, outs):
+            agg = g.sum(axis=0, dtype=np.float32)
+            want = _momentum_reference(
+                [agg * np.float32(s + 1) for s in range(self.sizes.steps)], n
+            )
+            check(out.shape == (n,) and np.isfinite(out).all(),
+                  f"{name}: not finite")
+            np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-6,
+                                       err_msg=name)
+        if self.n_dev > 1:
+            for name, _ in buckets:
+                self._assert_sharded(name)
+        total = sum(n for _, n in buckets)
+        print(f"  {len(buckets)} buckets, {total:,} f32 parameters, "
+              f"{self.sizes.steps} steps agree with the momentum "
+              f"recurrence at W = {W}")
+        print("  set-up: first step (compiles) "
+              f"{walls[0]:.2f} s; later steps "
+              + ", ".join(f"{w:.2f} s" for w in walls[1:]))
+
+    def _assert_fused_kernel_in_program(self, name: str) -> None:
+        """The fused optimizer must be a Mosaic kernel in the program the
+        chip runs, not an interpreted stand-in."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        eng = self.kv.engine
+        bucket = eng.bucket(name)
+        store = eng.store_spec(name)
+        grads = jax.ShapeDtypeStruct(
+            (eng.num_workers, bucket.padded_len), store.dtype,
+            sharding=NamedSharding(eng.mesh, P(eng.axis, None)),
+        )
+        prog = eng._program("push_pull_st", bucket.padded_len,
+                            bucket.dtype, SERVER_HANDLE)
+        text = prog.lower(store, store, grads).as_text()
+        check("tpu_custom_call" in text,
+              "push_pull_st lowered for a TPU mesh holds no tpu_custom_call: "
+              "the fused optimizer kernel is not in the program")
+
+    def _assert_sharded(self, name: str) -> None:
+        """Every device holds 1/N of the store and of each optimizer
+        slot."""
+        eng = self.kv.engine
+        want_devices = {d.id for d in self.mesh.devices.flat}
+        kind, slots = eng.opt_state(name)
+        check(kind == "sgd_momentum", f"{name}: optimizer state {kind!r}")
+        for what, arr in (("store", eng.store_array(name)),
+                          *((f"slot {i}", s) for i, s in enumerate(slots))):
+            shards = arr.addressable_shards
+            per_dev = arr.shape[0] // self.n_dev
+            check({s.device.id for s in shards} == want_devices,
+                  f"{name} {what}: not on every device")
+            check(all(s.data.shape == (per_dev,) for s in shards),
+                  f"{name} {what}: shards {[s.data.shape for s in shards]}, "
+                  f"want 1/{self.n_dev} = {per_dev} each")
+
+    # -- dense: the README's snippet ----------------------------------------
+
+    def readme(self) -> None:
+        kv = self.kv
+        W = kv.engine.num_workers
+        sz = self.sizes
+        keys = np.arange(sz.readme_keys, dtype=np.uint64)
+        total = sz.readme_keys * sz.readme_val_len
+        kv.register_dense("grads", keys, val_len=sz.readme_val_len)
+        grads = np.ones(total, np.float32)  # every worker's gradient
+        outs = np.zeros_like(grads)
+        agg = np.full(1, W, np.float32)
+        walls = []
+        for step in range(sz.steps):
+            t0 = time.perf_counter()
+            kv.wait(kv.push_pull(keys, grads, outs))
+            walls.append(time.perf_counter() - t0)
+            want = _momentum_reference([agg] * (step + 1), 1)[0]
+            np.testing.assert_allclose(outs, want, rtol=1e-5,
+                                       err_msg=f"push_pull {step}")
+        kv.wait(kv.push(keys, grads))
+        pulled = np.zeros_like(grads)
+        kv.wait(kv.pull(keys, pulled))
+        want = _momentum_reference([agg] * (sz.steps + 1), 1)[0]
+        np.testing.assert_allclose(pulled, want, rtol=1e-5,
+                                   err_msg="push then pull")
+        if self.n_dev > 1:
+            self._assert_sharded("grads")
+        print(f"  {sz.readme_keys} keys x {sz.readme_val_len:,} f32 in one "
+              f"bucket: {sz.steps} push_pull, then push and pull, agree")
+        print("  set-up: first push_pull (compiles) "
+              f"{walls[0]:.2f} s; later "
+              + ", ".join(f"{w:.2f} s" for w in walls[1:]))
+
+    # -- sparse plane ---------------------------------------------------------
+
+    def sparse(self) -> None:
+        import pslite_tpu as ps
+        from pslite_tpu.models.embedding import skewed_indices
+
+        kv = self.kv
+        sz = self.sizes
+        se = ps.postoffice(ps.Role.WORKER).van.sparse_engine
+        W = se.num_shards
+        se.register_sparse("emb", sz.emb_rows, sz.emb_dim)
+        ref = np.zeros((sz.emb_rows, sz.emb_dim), np.float32)
+        rng = np.random.default_rng(SEED + 1)
+        walls = []
+        for rnd in range(2):
+            idx = skewed_indices(sz.emb_rows, W, sz.emb_batch,
+                                 seed=SEED + rnd)
+            grads = rng.standard_normal(
+                (W, sz.emb_batch, sz.emb_dim), dtype=np.float32
+            )
+            out = np.zeros_like(grads)
+            t0 = time.perf_counter()
+            kv.wait(kv.push_sparse("emb", idx, grads))
+            kv.wait(kv.pull_sparse("emb", idx, out=out))
+            walls.append(time.perf_counter() - t0)
+            np.add.at(ref, idx.reshape(-1),
+                      grads.reshape(-1, sz.emb_dim))
+            check(np.isfinite(out).all(), "pulled rows not finite")
+            np.testing.assert_allclose(out, ref[idx], rtol=1e-4, atol=1e-3,
+                                       err_msg=f"round {rnd}")
+            # The Zipf head: every worker pulled row 0, and every copy of
+            # it is the one aggregated row.
+            hot = idx == 0
+            check(hot.any(axis=1).all(), "a worker never drew the hot row")
+            hot_rows = out[hot]
+            check((hot_rows == hot_rows[0]).all(),
+                  "the hot row differs between workers")
+        if self.n_dev > 1:
+            t = se.table("emb")
+            shards = se.store_raw("emb").addressable_shards
+            check(len(shards) == self.n_dev and all(
+                s.data.shape == (t.phys_rows, t.pack * t.dim) for s in shards
+            ), f"table shards {[s.data.shape for s in shards]}")
+        print(f"  {sz.emb_rows:,} x {sz.emb_dim} table, batch "
+              f"{sz.emb_batch} per worker, {int(hot.sum())} pulls of the "
+              f"hot row: push_sparse / pull_sparse agree")
+        print(f"  set-up: first round (compiles) {walls[0]:.2f} s; "
+              f"second {walls[1]:.2f} s")
+
+    # -- message path ---------------------------------------------------------
+
+    def message_path(self) -> None:
+        kv = self.kv
+        keys = np.array([7777], dtype=np.uint64)
+        check(kv._engine_route(keys) is None, "key 7777 is registered")
+        vals = np.arange(32, dtype=np.float32)
+        kv.wait(kv.push(keys, vals))
+        out = np.zeros_like(vals)
+        kv.wait(kv.pull(keys, out))
+        np.testing.assert_array_equal(out, vals)
+        print("  unregistered key 7777 answered by the KVServer handler")
+
+    # -- ring kernel (two or more devices) ----------------------------------
+
+    def ring(self) -> None:
+        """The ResNet-50 buckets through the fused ring kernel, per bucket
+        and grouped, against XLA's reduce-scatter / update / all-gather.
+        The ring kernel serves stateless handles, so this phase owns two
+        engines on the same mesh under plain ``sgd``."""
+        from pslite_tpu.parallel.engine import CollectiveEngine
+
+        xla = CollectiveEngine(mesh=self.mesh, server_handle=RING_HANDLE,
+                               impl="xla")
+        ring = CollectiveEngine(mesh=self.mesh, server_handle=RING_HANDLE,
+                                impl="pallas")
+        W = ring.num_workers
+        buckets = list(self.sizes.dense_buckets)[: self.sizes.ring_buckets]
+        names = [name for name, _ in buckets]
+        rng = np.random.default_rng(SEED + 2)
+        grads = []
+        one = np.arange(1, dtype=np.uint64)
+        check(ring._effective_impl(np.float32, RING_HANDLE) == "pallas",
+              "the ring kernel does not serve this config")
+        for name, n in buckets:
+            xla.register_dense(name, one, n)
+            ring.register_dense(name, one, n)
+            grads.append(rng.standard_normal((W, n), dtype=np.float32))
+
+        def agree(got, want, what):
+            for name, a, b in zip(names, got, want):
+                a, b = np.asarray(a), np.asarray(b)
+                check(np.isfinite(a).all(), f"{what} {name}: not finite")
+                np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5,
+                                           err_msg=f"{what} {name}")
+
+        t0 = time.perf_counter()
+        per_ring = [ring.push_pull(n, g) for n, g in zip(names, grads)]
+        per_xla = [xla.push_pull(n, g) for n, g in zip(names, grads)]
+        agree(per_ring, per_xla, "per bucket")
+        print(f"  {len(names)} buckets, one ring program each, agree with "
+              f"XLA (set-up, compiles: {time.perf_counter() - t0:.2f} s)",
+              flush=True)
+        t0 = time.perf_counter()
+        agree(ring.push_pull_group(names, grads),
+              xla.push_pull_group(names, grads), "grouped")
+        print(f"  {len(names)} ring kernels in one grouped program agree "
+              f"with XLA (set-up, compiles: {time.perf_counter() - t0:.2f} "
+              f"s)")
+
+
+def run_smoke(mesh, sizes: Sizes,
+              expired: Callable[[str, float], None] = _expired) -> None:
+    """Drive every phase on ``mesh`` (every local device — what the ICI
+    van gives its engine).  Raises :class:`PhaseFailed` naming the first
+    phase that raised; a phase that outlives its deadline calls
+    ``expired(name, seconds)``."""
+    for name, seconds, phase in _Smoke(mesh, sizes).phases():
+        print(f"phase {name}:", flush=True)
+        t0 = time.perf_counter()
+        with deadline(name, seconds, expired):
+            try:
+                phase()
+            except Exception as exc:
+                raise PhaseFailed(name) from exc
+        print(f"phase {name}: ok ({time.perf_counter() - t0:.1f} s)",
+              flush=True)
+
+
+def _count_cache_events() -> dict:
+    import jax
+
+    counts = {"hits": 0, "misses": 0}
+
+    def listener(event: str, **_) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            counts["hits"] += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            counts["misses"] += 1
+
+    jax.monitoring.register_event_listener(listener)
+    return counts
+
+
+def main() -> int:
+    t_start = time.perf_counter()
+    with deadline("device", 120):
+        import jax
+
+        devices = jax.devices()
+    d0 = devices[0]
+    print(f"platform: {d0.platform}")
+    print(f"device_kind: {d0.device_kind}")
+    print(f"devices: {len(devices)}")
+    print(f"jax: {jax.__version__}", flush=True)
+    if d0.platform != "tpu":
+        print("chip_smoke: JAX found no TPU — this script has no CPU mode "
+              "(tests/test_chip_smoke.py is the CPU drive)",
+              file=sys.stderr)
+        return 2
+
+    from pslite_tpu.parallel.mesh import default_mesh
+    from pslite_tpu.utils.compile_cache import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+    cache = _count_cache_events()
+    try:
+        with deadline("run", 1150):
+            run_smoke(default_mesh(), Sizes())
+    except PhaseFailed as failed:
+        traceback.print_exception(failed.__cause__)
+        print(f"chip_smoke: FAILED phase={failed}", file=sys.stderr,
+              flush=True)
+        sys.stdout.flush()
+        # Not sys.exit: a thread still inside a failed device call must
+        # not hold the interpreter open.
+        os._exit(1)
+    print(f"set-up: compile cache {cache_dir}: {cache['hits']} hits, "
+          f"{cache['misses']} misses; whole run "
+          f"{time.perf_counter() - t_start:.1f} s")
+    print(json.dumps({
+        "ok": True,
+        "device": {"platform": d0.platform, "kind": d0.device_kind,
+                   "count": len(devices)},
+    }), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -4,6 +4,14 @@ Run a 2-worker cluster on one machine::
 
     python -m pslite_tpu.tracker.local -n 2 -s 2 -- python examples/kv_basics.py
     python -m pslite_tpu.tracker.local -n 2 -s 2 --van shm -- python examples/kv_basics.py
+
+Under an ICI van the worker also registers a dense bucket, which then rides
+the jitted collectives on the worker's devices while the scheduler and the
+server stay off JAX.  One worker process per host there: every worker
+process builds its mesh from all local devices, and a chip belongs to one
+process at a time::
+
+    python -m pslite_tpu.tracker.local -n 1 -s 1 --van ici_tcp -- python examples/kv_basics.py
 """
 
 import os
@@ -55,6 +63,20 @@ def main() -> None:
 
         # Wire-compressed push for bandwidth-limited links:
         kv.wait(kv.push(keys, grads, compress="int8"))
+
+        if kv.engine is not None:
+            # ICI van: a registered bucket is one fused reduce-scatter +
+            # server update + all-gather over this worker's devices.
+            dense = np.arange(8, dtype=np.uint64) + 10_000
+            kv.register_dense("dense", dense, val_len=1024)
+            ones = np.ones(8 * 1024, dtype=np.float32)
+            out = np.zeros_like(ones)
+            kv.wait(kv.push_pull(dense, ones, out))
+            devices = kv.engine.mesh.devices.flat
+            print(f"worker {po.my_rank()}: registered bucket over "
+                  f"{kv.engine.num_workers} {devices[0].platform} "
+                  f"device(s) pulled {out[0]}")
+            assert np.allclose(out, kv.engine.num_workers)
 
     ps.finalize()
     if server is not None:

@@ -46,8 +46,6 @@ def make_flat_ps_step(
     from jax.flatten_util import ravel_pytree
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ..parallel.mesh import shard_map_compat
-
     axes = tuple(mesh.axis_names)
     n_dev = int(np.prod([mesh.shape[a] for a in axes]))
 
@@ -71,10 +69,11 @@ def make_flat_ps_step(
         new_store = store_l - lr * (agg / n_dev)
         return new_store, lax.psum(loss, axes) / n_dev
 
-    fn = shard_map_compat(
-        _local, mesh,
+    fn = jax.shard_map(
+        _local, mesh=mesh,
         in_specs=(P(axes), *batch_specs),
         out_specs=(P(axes), P()),
+        check_vma=False,
     )
     step = jax.jit(fn, donate_argnums=(0,))
     return step, flat_store, batch_shardings, store_sharding, unravel

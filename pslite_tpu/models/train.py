@@ -124,7 +124,6 @@ def make_pp_train_step(cfg: ModelConfig, mesh, lr: float = 0.1,
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ..parallel.mesh import shard_map_compat as shard_map
     from ..parallel.pipeline import (
         pipeline_loss,
         stack_layers,
@@ -239,11 +238,12 @@ def make_pp_train_step(cfg: ModelConfig, mesh, lr: float = 0.1,
     layer_spec = P(pp_axis)
     repl_spec = P()
     tok_spec = P(dp_axis) if dp_axis is not None else P(None)
-    fn = shard_map(
+    fn = jax.shard_map(
         _local_step,
         mesh=mesh,
         in_specs=(layer_spec, repl_spec, tok_spec, tok_spec),
         out_specs=(layer_spec, repl_spec, repl_spec),
+        check_vma=False,
     )
     jitted = jax.jit(fn, donate_argnums=(0, 1))
 

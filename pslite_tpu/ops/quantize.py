@@ -8,7 +8,8 @@ Tiles are ``(32, 128)`` (the int8 minimum), so rows are padded to a
 multiple of 32.  Scales come back lane-replicated ``[rows, 128]``; send
 ``scales[:, 0]`` on the wire and re-broadcast on receive.
 
-Kernels fall back to the Pallas interpreter off-TPU for tests.
+``interpret`` is the caller's decision (compiled for a TPU, interpreted
+anywhere else); nothing here looks at the process default backend.
 """
 
 from __future__ import annotations
@@ -20,10 +21,6 @@ import jax.numpy as jnp
 
 QUANT_BLOCK = 128  # elements per scale (one lane row)
 _TILE_ROWS = 32    # int8 min sublane tile
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def np_quantize_int8(x):
@@ -67,8 +64,8 @@ def decode_int8_payload(q_sarray, scales_sarray, val_len: int):
     return np_dequantize_int8(q, scales, val_len // 4)
 
 
-@jax.jit
-def quantize_int8(x):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def quantize_int8(x, *, interpret: bool):
     """flat fp32 -> (int8 ``[rows, 128]``, fp32 scales ``[rows, 128]``).
 
     Keep the original length for :func:`dequantize_int8`.
@@ -103,12 +100,12 @@ def quantize_int8(x):
         grid=(grid,),
         in_specs=[spec],
         out_specs=(spec, spec),
-        interpret=_use_interpret(),
+        interpret=interpret,
     )(x2)
 
 
-@functools.partial(jax.jit, static_argnames=("n",))
-def dequantize_int8(q, scales, n: int):
+@functools.partial(jax.jit, static_argnames=("n", "interpret"))
+def dequantize_int8(q, scales, n: int, *, interpret: bool):
     """Inverse of :func:`quantize_int8`; ``n`` is the original length.
 
     ``scales`` may be lane-replicated ``[rows, 128]`` or compact
@@ -132,6 +129,6 @@ def dequantize_int8(q, scales, n: int):
         grid=(rows // _TILE_ROWS,),
         in_specs=[spec, spec],
         out_specs=spec,
-        interpret=_use_interpret(),
+        interpret=interpret,
     )(q, jnp.asarray(scales, jnp.float32))
     return x.reshape(-1)[:n]

@@ -33,8 +33,16 @@ neighbors otherwise have no back-pressure and a fast sub-ring could
 clobber an unread slot; the reference's AddressPool plays the same role
 for RDMA imm slots, van_common.h:72-122).
 
-Off-TPU the kernel runs under the Pallas TPU interpreter so the unit
-tests exercise the full semaphore/DMA protocol on the virtual CPU mesh.
+VMEM: the kernel keeps the whole per-device chunk resident six times
+over (store in, store out, send, two receive slots, grads staging), so the
+chunk it can serve is bounded by :data:`VMEM_BUDGET_BYTES`; a larger chunk
+is refused by name before Mosaic sees it.
+
+``interpret`` is the caller's decision: the engine passes the rule it
+derived from its mesh (TPU mesh → Mosaic, anything else → the Pallas TPU
+interpreter, which runs the full semaphore/DMA protocol on the virtual CPU
+mesh for the unit tests).  Nothing here looks at the process default
+backend.
 """
 
 from __future__ import annotations
@@ -49,14 +57,14 @@ _LANES = 128
 _SUBLANES = 8
 _TILE = _LANES * _SUBLANES  # minimum chunk granularity (fp32 elements)
 
-
-def _use_interpret() -> bool:
-    """Default interpret decision when the caller does not say: follow
-    the process default backend.  Callers who know the TARGET mesh (the
-    engine) pass ``interpret`` explicitly instead — an AOT compile-only
-    TPU mesh must get real Mosaic lowering even from a CPU-default
-    process, and the CPU interpreter must not be selected for it."""
-    return jax.default_backend() != "tpu"
+# What the kernel may ask Mosaic for.  A v5e TensorCore has 128 MiB of
+# VMEM; the rest is left to the compiler's own scratch and to the XLA ops
+# scheduled around the kernel.  Mosaic's default scoped limit is 16 MiB,
+# so the kernel states its need (``vmem_limit_bytes``) instead of relying
+# on it.  Chips with less VMEM refuse the request in Mosaic's own words.
+VMEM_BUDGET_BYTES = 96 << 20
+_VMEM_DEFAULT_LIMIT_BYTES = 16 << 20
+_VMEM_HEADROOM_BYTES = 2 << 20  # semaphores, DMA descriptors, spills
 
 
 def derive_collective_id(*key_parts) -> int:
@@ -386,10 +394,25 @@ def _kernel_body(n: int, axis_name: str, handle: Callable, ndir: int,
     return kernel
 
 
+def ring_vmem_bytes(chunk: int, dtype, bidir: bool = True,
+                    compress: bool = False) -> int:
+    """VMEM the kernel holds for a per-device chunk of ``chunk`` elements:
+    store in + store out + grads staging at the bucket dtype, and send +
+    two receive slots at the wire dtype (int8 plus one scale tile per
+    direction when compressed)."""
+    ndir = 2 if bidir else 1
+    itemsize = jnp.dtype(dtype).itemsize
+    if compress:
+        comm = chunk + ndir * 4 * _TILE  # int8 payload + scale rows
+    else:
+        comm = chunk * itemsize
+    return 3 * chunk * itemsize + 3 * comm
+
+
 def _ring_call(grads_chunks, store_chunk, handle: Callable,
                axis_name: str, num_devices: int, collective_id,
-               bidir: bool, with_ag: bool, compress: bool = False,
-               mesh_axes=None, interpret=None):
+               bidir: bool, with_ag: bool, interpret: bool,
+               compress: bool = False, mesh_axes=None):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -409,6 +432,18 @@ def _ring_call(grads_chunks, store_chunk, handle: Callable,
             f"chunk {chunk} not a multiple of {min_tile} "
             f"(bidir={bidir}, compress={compress}, "
             f"dtype={store_chunk.dtype})"
+        )
+    vmem_need = (
+        ring_vmem_bytes(chunk, store_chunk.dtype, bidir, compress)
+        + _VMEM_HEADROOM_BYTES
+    )
+    if vmem_need > VMEM_BUDGET_BYTES:
+        raise ValueError(
+            f"ring kernel: a per-device chunk of {chunk} "
+            f"{store_chunk.dtype} elements needs {vmem_need} bytes of "
+            f"VMEM (six chunk-sized buffers) but the kernel's budget is "
+            f"{VMEM_BUDGET_BYTES} bytes; split the bucket (the ResNet-50 "
+            f"trace uses 4 MiB buckets) or run it with impl='xla'"
         )
     if collective_id is None:
         collective_id = derive_collective_id(
@@ -452,12 +487,12 @@ def _ring_call(grads_chunks, store_chunk, handle: Callable,
         out_specs=tuple(out_specs),
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
-            has_side_effects=True, collective_id=collective_id
+            has_side_effects=True, collective_id=collective_id,
+            vmem_limit_bytes=max(_VMEM_DEFAULT_LIMIT_BYTES, vmem_need),
         ),
         interpret=(
             pltpu.InterpretParams(dma_execution_mode="eager")
-            if (_use_interpret() if interpret is None else interpret)
-            else False
+            if interpret else False
         ),
     )(g2, s2)
     if with_ag:
@@ -466,10 +501,9 @@ def _ring_call(grads_chunks, store_chunk, handle: Callable,
 
 
 def ring_push_pull(grads_chunks, store_chunk, handle: Callable,
-                   axis_name: str, num_devices: int,
+                   axis_name: str, num_devices: int, *, interpret: bool,
                    collective_id: int = None, bidir: bool = True,
-                   compress: bool = False, mesh_axes=None,
-                   interpret=None):
+                   compress: bool = False, mesh_axes=None):
     """Run the fused RS+update+AG ring inside a shard_map body.
 
     Args (per-device views inside shard_map):
@@ -486,19 +520,20 @@ def ring_push_pull(grads_chunks, store_chunk, handle: Callable,
       mesh_axes:    ordered (name, size) pairs of the FULL mesh when the
                     ring runs along one axis of a multi-axis torus (see
                     :func:`_kernel_body`); None for a 1-D mesh.
+      interpret:    run under the Pallas TPU interpreter (any mesh that
+                    is not a TPU mesh) instead of compiling with Mosaic.
     Returns (new_store_chunk [chunk], pulled [n*chunk]).
     """
     return _ring_call(grads_chunks, store_chunk, handle, axis_name,
                       num_devices, collective_id, bidir, with_ag=True,
-                      compress=compress, mesh_axes=mesh_axes,
-                      interpret=interpret)
+                      interpret=interpret, compress=compress,
+                      mesh_axes=mesh_axes)
 
 
 def ring_push(grads_chunks, store_chunk, handle: Callable,
-              axis_name: str, num_devices: int,
+              axis_name: str, num_devices: int, *, interpret: bool,
               collective_id: int = None, bidir: bool = True,
-              compress: bool = False, mesh_axes=None,
-              interpret=None):
+              compress: bool = False, mesh_axes=None):
     """Push-only ring: reduce-scatter + fused server update, no
     all-gather (the ``ZPush`` leg alone).  Same contract as
     :func:`ring_push_pull`; returns just the new store chunk.
@@ -508,5 +543,5 @@ def ring_push(grads_chunks, store_chunk, handle: Callable,
     """
     return _ring_call(grads_chunks, store_chunk, handle, axis_name,
                       num_devices, collective_id, bidir, with_ag=False,
-                      compress=compress, mesh_axes=mesh_axes,
-                      interpret=interpret)
+                      interpret=interpret, compress=compress,
+                      mesh_axes=mesh_axes)

@@ -48,31 +48,12 @@ def distributed_options(env=None) -> Dict[str, object]:
     }
 
 
-def is_initialized() -> bool:
-    """Version-compat probe: ``jax.distributed.is_initialized`` only
-    exists on newer jax; older releases (e.g. 0.4.37) expose nothing
-    public, so fall back to the global client the initialize call
-    assigns.  Without this shim every multi-host worker died with an
-    AttributeError before jax.distributed ever initialized."""
-    import jax
-
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    try:
-        from jax._src.distributed import global_state
-
-        return global_state.client is not None
-    except Exception:  # noqa: BLE001 - private API moved: assume down
-        return False
-
-
 def _initialize_or_unwind(opts) -> None:
     """jax.distributed.initialize with half-init cleanup: jax assigns its
     global client BEFORE connecting, so a connect failure (coordinator
-    unreachable — the tunnel-outage case) would leave
-    ``is_initialized() == True`` on a never-connected runtime and poison
-    every later acquire.  Unwind on failure so retries re-initialize."""
+    unreachable) would leave ``jax.distributed.is_initialized()`` true on
+    a never-connected runtime and poison every later acquire.  Unwind on
+    failure so retries re-initialize."""
     import jax
 
     try:
@@ -97,12 +78,14 @@ def acquire(env=None) -> bool:
     for single-process configs (nothing to release).
     """
     global _leases, _opts, _owned
+    import jax
+
     env = env or environment.get()
     if env.find_int("DMLC_NUM_WORKER", 1) <= 1:
         return False
 
     with _mu:
-        if not is_initialized():
+        if not jax.distributed.is_initialized():
             opts = distributed_options(env)
             _initialize_or_unwind(opts)
             # Recorded only after a successful initialize.
@@ -152,12 +135,14 @@ def init_distributed(env=None) -> Optional[Dict[str, object]]:
     wrapper own any shutdown themselves).  Prefer acquire()/release().
     Returns the options used when this call initialized, else None."""
     global _opts
+    import jax
+
     env = env or environment.get()
     if env.find_int("DMLC_NUM_WORKER", 1) <= 1:
         return None
 
     with _mu:
-        if is_initialized():
+        if jax.distributed.is_initialized():
             return None
         opts = distributed_options(env)
         _initialize_or_unwind(opts)

@@ -38,7 +38,7 @@ from typing import Callable, Dict, Optional, Union
 import numpy as np
 
 from ..utils import logging as log
-from .mesh import shard_map_compat as shard_map
+from .placement import staging_xp
 
 
 @dataclass
@@ -134,9 +134,10 @@ class CollectiveEngine:
         XLA ops) or ``"pallas"`` (the fused ring kernel of
         ``ops/ring_collective.py``: one kernel per device, the update
         applied in VMEM between the reduce-scatter and all-gather ring
-        phases).  Defaults to env ``PS_ICI_IMPL``.  Configs the kernel
-        cannot serve (1-device mesh, 2-D mesh, stateful handles,
-        non-f32/bf16 dtypes) fall back to XLA transparently.
+        phases).  Defaults to env ``PS_ICI_IMPL``.  A config the kernel
+        cannot serve (see :meth:`_ring_unserved`) runs the XLA
+        collectives and says so once; a bucket whose per-device chunk
+        exceeds the kernel's VMEM budget is an error.
 
         ``worker_axis``: optional second mesh axis carrying the worker
         fan-in, decoupling worker count from server-shard count (the
@@ -192,10 +193,14 @@ class CollectiveEngine:
         self._mesh_platform = next(
             iter(self.mesh.devices.flat)
         ).platform
-        # Ring kernels interpret (CPU Pallas interpreter) iff the MESH
-        # is not TPU — AOT topology meshes compile real Mosaic even from
-        # a CPU-default process (see ring_collective._use_interpret).
-        self._ring_interpret = self._mesh_platform != "tpu"
+        # THE interpret rule for every Pallas kernel this engine builds
+        # (ring collective and fused optimizer handles): a TPU mesh gets
+        # compiled Mosaic, always — including an AOT topology mesh built
+        # from a CPU-default process — and only a mesh that is not TPU
+        # runs the Pallas interpreter.  The process default backend is
+        # never consulted: a process whose TPU init failed must not
+        # interpret its way to a passing run.
+        self._interpret = self._mesh_platform != "tpu"
         self._local_shard_count = (
             local_shard_count(self.mesh) if self._multiprocess
             else self.num_shards
@@ -203,6 +208,8 @@ class CollectiveEngine:
         self.impl = impl or os.environ.get("PS_ICI_IMPL", "xla")
         log.check(self.impl in ("xla", "pallas"),
                   f"unknown engine impl {self.impl!r}")
+        # Reasons already given for running XLA under impl="pallas".
+        self._impl_said: set = set()
         # Per-step payload threshold for the flat replay slab layout
         # (see _flat_replay); tunable for tests / unusual chips.
         self.replay_flat_min_bytes = int(
@@ -288,9 +295,9 @@ class CollectiveEngine:
         elif self._is_multiprocess():
             store = self._place(np.zeros(padded, np.dtype(dtype)), sharding)
         else:
-            store = jax.device_put(
-                jnp.zeros(padded, dtype=dtype), sharding
-            )
+            # Each device zero-fills its own shard; the whole store never
+            # exists on one device.
+            store = jnp.zeros(padded, dtype=dtype, device=sharding)
         with self._mu:
             self._buckets[name] = bucket
             self._stores[name] = store
@@ -352,12 +359,14 @@ class CollectiveEngine:
         """
         from ..ops import fused_update
 
+        interp = self._interpret
         if handle.startswith("sgd_momentum"):
             lr, momentum = self._handle_params(handle, (0.01, 0.9))
 
             def fn(store_l, state_l, agg):
                 new_store, new_mom = fused_update.sgd_update(
-                    store_l, state_l[0], agg, lr=lr, momentum=momentum
+                    store_l, state_l[0], agg, lr=lr, momentum=momentum,
+                    interpret=interp,
                 )
                 return new_store, (new_mom,)
 
@@ -372,7 +381,7 @@ class CollectiveEngine:
                 step = step_l[0] + 1.0
                 new_store, new_m, new_v = fused_update.adam_update(
                     store_l, m_l, v_l, agg, step, lr=lr,
-                    beta1=b1, beta2=b2, eps=eps,
+                    beta1=b1, beta2=b2, eps=eps, interpret=interp,
                 )
                 return new_store, (new_m, new_v, step_l + 1.0)
 
@@ -382,7 +391,8 @@ class CollectiveEngine:
 
             def fn(store_l, state_l, agg):
                 new_store, new_acc = fused_update.adagrad_update(
-                    store_l, state_l[0], agg, lr=lr, eps=eps
+                    store_l, state_l[0], agg, lr=lr, eps=eps,
+                    interpret=interp,
                 )
                 return new_store, (new_acc,)
 
@@ -483,41 +493,46 @@ class CollectiveEngine:
             return pulled + lax.bitcast_convert_type(dep, pulled.dtype)
 
         if op == "push_pull":
-            fn = shard_map(
+            fn = jax.shard_map(
                 _push_pull,
                 mesh=mesh,
                 in_specs=(store_spec, grads_spec),
                 out_specs=(store_spec, repl_spec),
+                check_vma=False,
             )
             jitted = jax.jit(fn, donate_argnums=(0,))
         elif op == "push_pull_zc":
-            fn = shard_map(
+            fn = jax.shard_map(
                 _push_pull_zc,
                 mesh=mesh,
                 in_specs=(store_spec,
                           store_spec if flat_zc else grads_spec),
                 out_specs=store_spec,
+                check_vma=False,
             )
             jitted = jax.jit(fn, donate_argnums=(0,))
         elif op == "push":
-            fn = shard_map(
+            fn = jax.shard_map(
                 _push,
                 mesh=mesh,
                 in_specs=(store_spec, grads_spec),
                 out_specs=(store_spec, store_spec),
+                check_vma=False,
             )
             jitted = jax.jit(fn, donate_argnums=(0,))
         elif op == "pull":
-            fn = shard_map(
-                _pull, mesh=mesh, in_specs=(store_spec,), out_specs=repl_spec
+            fn = jax.shard_map(
+                _pull, mesh=mesh, in_specs=(store_spec,), out_specs=repl_spec,
+                check_vma=False,
             )
             jitted = jax.jit(fn)
         elif op == "pull_pinned":
-            fn = shard_map(
+            fn = jax.shard_map(
                 _pull_pinned,
                 mesh=mesh,
                 in_specs=(repl_spec, store_spec),
                 out_specs=repl_spec,
+                check_vma=False,
             )
             jitted = jax.jit(fn, donate_argnums=(0,))
         else:
@@ -526,46 +541,56 @@ class CollectiveEngine:
             self._programs[key] = jitted
         return jitted
 
-    def _effective_impl(self, dtype, resolved_handle) -> str:
-        """Resolve the configured impl against what the fused ring kernel
-        supports; everything else runs the XLA collective path.  Custom
-        callable handles are excluded: the kernel applies the handle
-        blockwise in VMEM (with tile-padding lanes flowing through it),
-        which is only guaranteed sound for the built-in elementwise
-        handles.
+    def _ring_unserved(self, dtype, resolved_handle) -> Optional[str]:
+        """Why the fused ring kernel cannot serve this config, or None
+        when it can.
 
         2-D (worker_axis) meshes run the MULTI-AXIS plane: the fused
         ring executes the worker reduction + update + re-replication as
         per-column sub-rings along the worker axis, and the pulled
         broadcast rides XLA's all_gather on the kv-axis links — both
         torus axes carry the one push_pull."""
-        if self.impl != "pallas":
-            return "xla"
+        if self._is_stateful(resolved_handle):
+            return ("a stateful handle runs its own fused optimizer "
+                    "kernel between XLA's reduce-scatter and all-gather")
         if self.worker_axis is None and isinstance(self.axis, tuple):
-            # A composite kv axis has no single ring dimension; the
-            # multi-axis plane needs worker_axis sub-rings.
-            return "xla"
+            return ("a composite kv axis has no single ring dimension "
+                    "(the multi-axis plane needs worker_axis sub-rings)")
         ring_n = (
             self.num_workers if self.worker_axis is not None
             else self.num_shards
         )
         if ring_n < 2:
-            return "xla"
+            return f"a ring needs 2 or more devices and has {ring_n}"
         if np.dtype(dtype).itemsize not in (2, 4):
-            return "xla"
+            return (f"dtype {np.dtype(dtype)} (the kernel tiles 2- and "
+                    f"4-byte dtypes)")
         if callable(resolved_handle):
-            return "xla"
-        if self._multiprocess:
+            # The kernel applies the handle blockwise in VMEM with
+            # tile-padding lanes flowing through it, which is only
+            # guaranteed sound for the built-in elementwise handles.
+            return "a callable handle (built-in elementwise handles only)"
+        if self._multiprocess and self._mesh_platform != "tpu":
             # Real multi-host TPU rings ride ICI fine, but the off-TPU
-            # interpreter cannot DMA to another process's devices.  The
-            # MESH's platform decides, not the process default backend:
-            # an AOT compile-only TPU mesh (jax.experimental.topologies)
-            # must select the kernel even when this process defaults to
-            # CPU, and a multi-process CPU mesh must not select it even
-            # under a TPU-default process.
-            if self._mesh_platform != "tpu":
-                return "xla"
-        return "pallas"
+            # interpreter cannot DMA to another process's devices.
+            return ("a multi-process mesh that is not TPU (the "
+                    "interpreter cannot DMA across processes)")
+        return None
+
+    def _effective_impl(self, dtype, resolved_handle) -> str:
+        """The configured impl resolved against what the fused ring
+        kernel serves.  Running XLA under ``impl="pallas"`` is said once
+        per reason, never swapped in silence."""
+        if self.impl != "pallas":
+            return "xla"
+        why = self._ring_unserved(dtype, resolved_handle)
+        if why is None:
+            return "pallas"
+        if why not in self._impl_said:
+            self._impl_said.add(why)
+            log.warning(f"impl='pallas': running the XLA collectives "
+                        f"instead of the ring kernel — {why}")
+        return "xla"
 
     def _ring_program(self, padded_len: int, dtype, handle_key) -> Callable:
         """Fused ring RS+update+AG push_pull (ops/ring_collective.py):
@@ -614,7 +639,7 @@ class CollectiveEngine:
         chunk0 = padded_len // n
         kchunk = ring_chunk_len(padded_len, n, dtype, compress=compress)
         cid = derive_collective_id(*key)
-        interp = self._ring_interpret
+        interp = self._interpret
 
         def _padded(store_l, grads_l):
             # grads_l: my FLAT row [padded] (see _prep_grads_ring — the
@@ -648,11 +673,12 @@ class CollectiveEngine:
             body, out_specs = body_pp, (P(axis), P(None))
         else:
             body, out_specs = body_push, (P(axis), P(axis))
-        fn = shard_map(
+        fn = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(P(axis), P(axis)),
             out_specs=out_specs,
+            check_vma=False,
         )
         jitted = jax.jit(fn, donate_argnums=(0,))
         with self._mu:
@@ -699,11 +725,12 @@ class CollectiveEngine:
             body, out_specs = body_pp, (P(axis), P(None))
         else:
             body, out_specs = body_push, (P(axis), P(axis))
-        fn = shard_map(
+        fn = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(P(axis), P(self.worker_axis, axis)),
             out_specs=out_specs,
+            check_vma=False,
         )
         jitted = jax.jit(fn, donate_argnums=(0,))
         with self._mu:
@@ -725,7 +752,7 @@ class CollectiveEngine:
         waxis = self.worker_axis
         A = self.num_workers
         B = self.num_shards
-        interp = self._ring_interpret
+        interp = self._interpret
         chunk_kv = padded_len // B  # my kv shard (replicated over dp)
         ksub = ring_chunk_len(chunk_kv, A, dtype, compress=compress)
         maxes = tuple(
@@ -795,11 +822,12 @@ class CollectiveEngine:
             body, tails = _push_pull_zc, ()
         else:
             body, tails = _push_pull, (repl_spec,)
-        fn = shard_map(
+        fn = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(store_spec, *([store_spec] * n_state), grads_spec),
             out_specs=(store_spec, *([store_spec] * n_state), *tails),
+            check_vma=False,
         )
         jitted = jax.jit(fn, donate_argnums=tuple(range(1 + n_state)))
         with self._mu:
@@ -941,7 +969,8 @@ class CollectiveEngine:
         broadcast a missing row dim to ``rows``, validate the row count,
         pad the value tail.  The one definition behind every host/device
         staging path (1-D/2-D x single/multi-process x single/replay);
-        ``xp`` is np (host staging) or jnp (device staging)."""
+        ``xp`` is np (host staging) or jnp (device staging) — see
+        :func:`placement.staging_xp`."""
         arr = xp.asarray(grads, dtype=np.dtype(bucket.dtype))
         want = 3 if steps else 2
         log.check(arr.ndim in (want - 1, want), "bad grads rank")
@@ -1055,7 +1084,6 @@ class CollectiveEngine:
           there is no per-process row ownership to map a local
           contribution onto."""
         import jax
-        import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         if self.worker_axis is not None:
@@ -1081,7 +1109,7 @@ class CollectiveEngine:
                 )
                 return self._place(np.ascontiguousarray(arr), sharding)
             arr = self._normalize_host_grads(
-                grads, self.num_workers, bucket, jnp
+                grads, self.num_workers, bucket, staging_xp(grads)
             )
             return jax.device_put(arr, sharding)
         if self._is_multiprocess():
@@ -1095,7 +1123,7 @@ class CollectiveEngine:
                 (self.num_shards, bucket.padded_len),
             )
         arr = self._normalize_host_grads(
-            grads, self.num_shards, bucket, jnp
+            grads, self.num_shards, bucket, staging_xp(grads)
         )
         return jax.device_put(arr, sharding)
 
@@ -1137,8 +1165,6 @@ class CollectiveEngine:
         the transport allows it, transparently copied elsewhere."""
         if self.num_shards != 1:
             return False
-        if self._is_stateful(resolved):
-            return True
         return self._effective_impl(dtype, resolved) == "xla"
 
     def flat_ring_eligible(self, dtype, handle: Optional[ServerHandle] = None
@@ -1150,9 +1176,8 @@ class CollectiveEngine:
         The ONE definition the op routing and benchmarks share."""
         resolved, _ = self._resolve_handle(handle)
         return (
-            not self._is_stateful(resolved)
+            self._effective_impl(dtype, resolved) == "pallas"
             and self.worker_axis is None
-            and self._effective_impl(dtype, resolved) == "pallas"
         )
 
     def flat_zc_eligible(self, handle: Optional[ServerHandle] = None
@@ -1370,7 +1395,7 @@ class CollectiveEngine:
             grads_spec = P(axis, None)
         repl_spec = P(None)
         n = self.num_shards
-        interp = self._ring_interpret
+        interp = self._interpret
 
         def _ring_one(i, padded_len, dtype, store_l, grads_l):
             from ..ops.ring_collective import (
@@ -1421,11 +1446,12 @@ class CollectiveEngine:
                 pulled.append(out)
             return (*new_stores, *pulled)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             _body,
             mesh=self.mesh,
             in_specs=tuple([store_spec] * k + [grads_spec] * k),
             out_specs=tuple([store_spec] * k + [repl_spec] * k),
+            check_vma=False,
         )
         jitted = jax.jit(fn, donate_argnums=tuple(range(k)))
         with self._mu:
@@ -1680,7 +1706,8 @@ class CollectiveEngine:
                 (arr.shape[0], self.num_shards, bucket.padded_len),
             )
         arr = self._normalize_host_grads(
-            grads_seq, self.num_workers, bucket, jnp, steps=True
+            grads_seq, self.num_workers, bucket,
+            staging_xp(grads_seq), steps=True,
         )
         return jax.device_put(arr, sharding)
 
@@ -1695,8 +1722,8 @@ class CollectiveEngine:
             self._server_handle if handle_key == "_default" else handle_key
         )
         return (
-            not stateful
-            and self._effective_impl(dtype, resolved) == "pallas"
+            self._effective_impl(dtype, resolved) == "pallas"
+            and not stateful
             and not self._ring_compress(dtype)
         )
 
@@ -1805,11 +1832,12 @@ class CollectiveEngine:
             tails = () if (keep == "last" and zero_copy) else (
                 (P(None, None),) if keep == "all" else (P(None),)
             )
-            fn = shard_map(
+            fn = jax.shard_map(
                 _body,
                 mesh=self.mesh,
                 in_specs=(store_spec, *([store_spec] * n_state), grads_spec),
                 out_specs=(store_spec, *([store_spec] * n_state), *tails),
+                check_vma=False,
             )
             jitted = jax.jit(fn, donate_argnums=tuple(range(1 + n_state)))
         else:
@@ -1909,11 +1937,12 @@ class CollectiveEngine:
                 out_specs = (store_spec, P(None, None))
             else:
                 out_specs = (store_spec, P(None))
-            fn = shard_map(
+            fn = jax.shard_map(
                 _body,
                 mesh=self.mesh,
                 in_specs=(store_spec, grads_in_spec),
                 out_specs=out_specs,
+                check_vma=False,
             )
             jitted = jax.jit(fn, donate_argnums=(0,))
         with self._mu:
@@ -1945,7 +1974,7 @@ class CollectiveEngine:
         waxis = self.worker_axis
         compress = self._ring_compress(dtype)
         cid = derive_collective_id(*key)
-        interp = self._ring_interpret
+        interp = self._interpret
         store_spec = P(axis)
 
         if waxis is not None:
@@ -2005,7 +2034,7 @@ class CollectiveEngine:
 
             grads_spec = P(None, axis, None)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             _body,
             mesh=self.mesh,
             in_specs=(store_spec, grads_spec),
@@ -2013,6 +2042,7 @@ class CollectiveEngine:
                 store_spec,
                 P(None, None) if keep == "all" else P(None),
             ),
+            check_vma=False,
         )
         jitted = jax.jit(fn, donate_argnums=(0,))
         with self._mu:
@@ -2328,7 +2358,7 @@ class CollectiveEngine:
                 self._mesh_platform = next(
                     iter(mesh.devices.flat)
                 ).platform
-                self._ring_interpret = self._mesh_platform != "tpu"
+                self._interpret = self._mesh_platform != "tpu"
                 self._local_shard_count = (
                     local_shard_count(mesh) if new_multiprocess
                     else new_num_shards

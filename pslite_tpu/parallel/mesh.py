@@ -27,28 +27,6 @@ def default_mesh(axis_name: str = "kv", num_devices: Optional[int] = None):
     return Mesh(np.asarray(devices), (axis_name,))
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions, with the replication check off
-    (collective outputs like tiled all_gather are replicated by
-    construction; the static checker cannot always infer that)."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(
-                fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=False,
-            )
-        except TypeError:  # older signature
-            return jax.shard_map(
-                fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs
-            )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def make_mesh(shape: Sequence[int], axis_names: Tuple[str, ...]):
     """N-D mesh with the given per-axis sizes (product must divide the
     available device count)."""

@@ -8,6 +8,8 @@ callback form, and per-process contributions through
 
 from __future__ import annotations
 
+import numpy as np
+
 
 def mesh_is_multiprocess(mesh) -> bool:
     return len({d.process_index for d in mesh.devices.flat}) > 1
@@ -19,6 +21,22 @@ def local_shard_count(mesh) -> int:
 
     me = jax.process_index()
     return sum(1 for d in mesh.devices.flat if d.process_index == me)
+
+
+def staging_xp(arr):
+    """numpy for a host-origin array, jax.numpy for a device array: the
+    module to normalize it with (cast, broadcast, pad) before placement.
+    A host array is normalized on the host and then placed shard by shard
+    (``device_put`` of a numpy array moves each shard straight to its
+    device); ``jnp.asarray`` would first land the whole array on device 0
+    and reshard it from there."""
+    import jax
+
+    if isinstance(arr, jax.Array):
+        import jax.numpy as jnp
+
+        return jnp
+    return np
 
 
 def place_host_array(mesh, host_arr, sharding, multiprocess=None):
@@ -42,8 +60,6 @@ def to_host_global(arr, multiprocess: bool):
     every participating process must call this on the same array in the
     same order (jax.experimental.multihost_utils.process_allgather
     assembles the non-addressable shards across hosts)."""
-    import numpy as np
-
     if not multiprocess:
         return np.asarray(arr)
     from jax.experimental import multihost_utils

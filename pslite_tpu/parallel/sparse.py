@@ -32,7 +32,7 @@ from typing import Callable, Dict, Optional
 import numpy as np
 
 from ..utils import logging as log
-from .mesh import shard_map_compat as shard_map
+from .placement import staging_xp
 
 
 @dataclass
@@ -337,10 +337,10 @@ class SparseEngine:
                 sharding,
             )
         else:
-            store = jax.device_put(
-                jnp.zeros((table.phys_rows * S, pack * dim), dtype=dtype),
-                sharding,
-            )
+            # Each device zero-fills its own rows; the whole table never
+            # exists on one device.
+            store = jnp.zeros((table.phys_rows * S, pack * dim),
+                              dtype=dtype, device=sharding)
         with self._mu:
             self._tables[name] = table
             self._stores[name] = store
@@ -404,11 +404,12 @@ class SparseEngine:
                               dim=dim)
 
         if op == "push":
-            fn = shard_map(
+            fn = jax.shard_map(
                 _push,
                 mesh=self.mesh,
                 in_specs=(P(axis, None), P(axis, None), P(axis, None, None)),
                 out_specs=(P(axis, None), P(axis, None)),
+                check_vma=False,
             )
             jitted = jax.jit(
                 fn, donate_argnums=(0,),
@@ -417,12 +418,13 @@ class SparseEngine:
         elif op == "push_row_adagrad":
             # lr/eps are traced scalar args (replicated): one compiled
             # program serves every learning-rate schedule step.
-            fn = shard_map(
+            fn = jax.shard_map(
                 _push_row_adagrad,
                 mesh=self.mesh,
                 in_specs=(P(axis, None), P(axis), P(axis, None),
                           P(axis, None, None), P(), P()),
                 out_specs=(P(axis, None), P(axis), P(axis, None)),
+                check_vma=False,
             )
             jitted = jax.jit(
                 fn, donate_argnums=(0, 1),
@@ -430,11 +432,12 @@ class SparseEngine:
                                _sh(P(axis, None))),
             )
         elif op == "pull":
-            fn = shard_map(
+            fn = jax.shard_map(
                 _pull,
                 mesh=self.mesh,
                 in_specs=(P(axis, None), P(axis, None)),
                 out_specs=P(axis, None),
+                check_vma=False,
             )
             jitted = jax.jit(fn)
         else:
@@ -485,12 +488,14 @@ class SparseEngine:
                 g_sharding, g, (self.num_shards,) + g.shape[1:]
             )
             return idx_sh, g_sh
-        idx = jnp.asarray(indices, dtype=jnp.int32)
+        # Host inputs are cast on the host and placed row by row, each
+        # worker's batch straight onto its device (see staging_xp).
+        idx = staging_xp(indices).asarray(indices, dtype=jnp.int32)
         log.check_eq(int(idx.shape[0]), self.num_shards, "bad worker dim")
         idx_sh = jax.device_put(idx, idx_sharding)
         if grads is None:
             return idx_sh, None
-        g = jnp.asarray(grads, dtype=table.dtype)
+        g = staging_xp(grads).asarray(grads, dtype=table.dtype)
         g_sh = jax.device_put(g, g_sharding)
         return idx_sh, g_sh
 
@@ -716,11 +721,12 @@ class SparseEngine:
                 ]
                 return (*new, new[0][:1, :1])
 
-            fn = shard_map(
+            fn = jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=tuple([store_spec] * k + [idx_spec] * k
                                + [g_spec] * k),
                 out_specs=tuple([store_spec] * k + [store_spec]),
+                check_vma=False,
             )
             jitted = jax.jit(
                 fn, donate_argnums=tuple(range(k)),
@@ -743,13 +749,14 @@ class SparseEngine:
                     new_a.append(a2)
                 return (*new_s, *new_a, new_s[0][:1, :1])
 
-            fn = shard_map(
+            fn = jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=tuple([store_spec] * k + [acc_spec] * k
                                + [idx_spec] * k + [g_spec] * k
                                + [P(), P()]),
                 out_specs=tuple([store_spec] * k + [acc_spec] * k
                                 + [store_spec]),
+                check_vma=False,
             )
             jitted = jax.jit(
                 fn, donate_argnums=tuple(range(2 * k)),
@@ -765,10 +772,11 @@ class SparseEngine:
                     for i, s in enumerate(stores)
                 )
 
-            fn = shard_map(
+            fn = jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=tuple([store_spec] * k + [idx_spec] * k),
                 out_specs=tuple([store_spec] * k),
+                check_vma=False,
             )
             jitted = jax.jit(fn)
         else:

@@ -83,7 +83,9 @@ class _IciDataPlane:
         if self.engine is None and self.po.is_worker:
             from ..parallel.engine import CollectiveEngine
             from ..parallel.sparse import SparseEngine
+            from ..utils.compile_cache import enable_compile_cache
 
+            enable_compile_cache()
             handle = self.env.find("PS_ICI_SERVER_HANDLE", "sum")
             # Share the van's profiler so ENABLE_PROFILING covers the
             # collective data plane (reference: van.cc:29-77,440-457).

@@ -16,8 +16,10 @@ from __future__ import annotations
 
 import struct
 import threading
+import time
 from typing import Dict, Optional, Tuple
 
+from ..base import SCHEDULER_ID
 from ..message import Message, Node
 from ..utils import logging as log
 from ..utils.queues import PriorityRecvQueue, ThreadsafeQueue
@@ -28,6 +30,7 @@ from .van import Van
 _registry_mu = threading.Lock()
 _registry: Dict[Tuple[str, str, int], "LoopbackVan"] = {}
 _port_counter = [20000]
+_SCHEDULER_BIND_WAIT_S = 10.0
 
 
 def reset_registry() -> None:
@@ -68,6 +71,15 @@ class LoopbackVan(Van):
     def connect_transport(self, node: Node) -> None:
         if node.id >= 0:
             self._peers[node.id] = (node.hostname, node.port)
+        if node.id == SCHEDULER_ID and not self.po.is_scheduler:
+            # A socket van's connect retries until the scheduler
+            # listens; here the scheduler is another thread of this
+            # process (``start_ps`` once per role), so give it the same
+            # grace to bind before the ADD_NODE that follows is sent.
+            key = (self._ns, node.hostname, node.port)
+            deadline = time.monotonic() + _SCHEDULER_BIND_WAIT_S
+            while key not in _registry and time.monotonic() < deadline:
+                time.sleep(0.005)
 
     def _resolve(self, recver: int) -> "LoopbackVan":
         if recver == self.my_node.id:

@@ -1,0 +1,97 @@
+"""chip_smoke.py off the chip: the same phases at a tiny size on the virtual
+CPU mesh with kernels interpreted, the script's refusal to run without a
+TPU, and where the compile cache goes."""
+
+import os
+import subprocess
+import sys
+import time
+
+import pytest
+
+jax = pytest.importorskip("jax")
+
+import chip_smoke
+from pslite_tpu.parallel.mesh import default_mesh
+from pslite_tpu.utils import compile_cache
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# The ring phase is left to tests/test_ring_collective.py: the interpreter
+# takes seconds per ring program on the 8-device mesh.
+TINY = chip_smoke.Sizes(
+    dense_buckets=(("t0", 1000), ("t1", 4100), ("t2", 1000)),
+    steps=2, ring_buckets=0,
+    readme_keys=4, readme_val_len=64,
+    emb_rows=4096, emb_dim=8, emb_batch=64,
+)
+
+
+def test_smoke_phases_at_tiny_size(capsys):
+    hung = []
+    chip_smoke.run_smoke(default_mesh(), TINY,
+                         expired=lambda name, s: hung.append(name))
+    assert not hung
+    out = capsys.readouterr().out
+    for phase in ("boot", "resnet50", "readme", "sparse", "message_path",
+                  "shutdown"):
+        assert f"phase {phase}: ok" in out
+    assert "W = 8, kernels interpreted" in out
+
+
+def test_failing_phase_is_named(monkeypatch):
+    def boom():
+        raise ValueError("no such bucket")
+
+    monkeypatch.setattr(
+        chip_smoke._Smoke, "phases",
+        lambda self: [("first", 5, lambda: None), ("second", 5, boom)],
+    )
+    with pytest.raises(chip_smoke.PhaseFailed, match="second") as err:
+        chip_smoke.run_smoke(default_mesh(), TINY)
+    assert isinstance(err.value.__cause__, ValueError)
+
+
+def test_deadline_names_the_phase_that_hangs():
+    hung = []
+    with chip_smoke.deadline("stuck", 0.05,
+                             expired=lambda name, s: hung.append((name, s))):
+        time.sleep(0.3)
+    assert hung == [("stuck", 0.05)]
+    with chip_smoke.deadline("quick", 5, expired=lambda *a: hung.append(a)):
+        pass
+    assert len(hung) == 1
+
+
+def test_script_refuses_to_run_without_a_tpu():
+    out = subprocess.run(
+        [sys.executable, "chip_smoke.py"], cwd=_REPO,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode != 0
+    assert "platform: cpu" in out.stdout
+    assert "phase" not in out.stdout  # before any work
+    assert '"ok"' not in out.stdout
+    assert "no TPU" in out.stderr
+
+
+def test_compile_cache_is_placed_from_outside_or_in_the_checkout(
+        monkeypatch):
+    before = jax.config.jax_compilation_cache_dir
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+        # Placed from outside: no directory is set in code.
+        assert jax.config.jax_compilation_cache_dir == before
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == os.path.join(_REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compile_cache.enable_compile_cache() == path  # fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          floor)

@@ -66,9 +66,6 @@ def test_ici_two_process_push_pull(van, extra):
                 q.kill()
             raise
         outputs.append(out.decode())
-    if any("MULTIPROC_UNSUPPORTED" in o for o in outputs):
-        pytest.skip("this jaxlib's CPU backend lacks multiprocess "
-                    "computations (environment limitation)")
     for p, out in zip(procs, outputs):
         assert p.returncode == 0, f"child failed:\n{out}"
     worker_outs = [o for o in outputs if "WORKER_OK 24.0" in o]
@@ -98,10 +95,7 @@ def test_init_distributed_idempotent(monkeypatch):
     monkeypatch.setattr(distributed, "_leases", 0)
     monkeypatch.setattr(distributed, "_opts", None)
     monkeypatch.setattr(distributed, "_owned", False)
-    # raising=False: jax<0.5 has no is_initialized — the distributed
-    # module's compat probe picks the patched attribute up either way.
-    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: True,
-                        raising=False)
+    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: True)
     monkeypatch.setattr(
         jax.distributed, "initialize",
         lambda **kw: calls.append(kw),
@@ -141,7 +135,7 @@ def test_acquire_release_owned_lifecycle(monkeypatch):
     monkeypatch.setattr(distributed, "_owned", False)
     state = {"init": 0, "shutdown": 0, "up": False}
     monkeypatch.setattr(jax.distributed, "is_initialized",
-                        lambda: state["up"], raising=False)
+                        lambda: state["up"])
 
     def fake_init(**kw):
         state["init"] += 1
@@ -178,6 +172,5 @@ def test_acquire_release_owned_lifecycle(monkeypatch):
     # Single-process configs never touch the distributed runtime.
     env1 = Environment({"DMLC_NUM_WORKER": "1"})
     monkeypatch.setattr(jax.distributed, "is_initialized",
-                        lambda: (_ for _ in ()).throw(AssertionError),
-                        raising=False)
+                        lambda: (_ for _ in ()).throw(AssertionError))
     assert distributed.init_distributed(env1) is None

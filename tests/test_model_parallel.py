@@ -15,7 +15,7 @@ from pslite_tpu.models.transformer import (
     forward,
     init_params,
 )
-from pslite_tpu.parallel.mesh import default_mesh, shard_map_compat
+from pslite_tpu.parallel.mesh import default_mesh
 from pslite_tpu.parallel.ring_attention import ring_attention
 
 
@@ -30,10 +30,11 @@ def _sharded_forward(params, tokens, cfg, mesh, axis="sp", moe=False):
         )
         return forward(p, tok_l, cfg, ctx=ctx)
 
-    fn = shard_map_compat(
-        local, mesh,
+    fn = jax.shard_map(
+        local, mesh=mesh,
         in_specs=(P(), P(None, axis)),
         out_specs=P(None, axis, None),
+        check_vma=False,
     )
     return jax.jit(fn)(params, tokens)
 

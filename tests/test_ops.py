@@ -24,7 +24,7 @@ def test_sgd_update_matches_reference():
 
     new_store, new_mom = sgd_update(
         jnp.asarray(store), jnp.asarray(mom), jnp.asarray(agg),
-        lr=0.1, momentum=0.9,
+        lr=0.1, momentum=0.9, interpret=True,
     )
     ref_mom = 0.9 * mom + agg
     ref_store = store - 0.1 * ref_mom
@@ -46,6 +46,7 @@ def test_adam_update_matches_reference():
     new_store, new_m, new_v = adam_update(
         jnp.asarray(store), jnp.asarray(m), jnp.asarray(v),
         jnp.asarray(agg), step=1, lr=lr, beta1=b1, beta2=b2, eps=eps,
+        interpret=True,
     )
     ref_m = (1 - b1) * agg
     ref_v = (1 - b2) * agg * agg
@@ -61,9 +62,9 @@ def test_quantize_roundtrip_error_bounded():
     rng = np.random.default_rng(2)
     n = 5000
     x = (rng.normal(size=n) * 10).astype(np.float32)
-    q, scales = quantize_int8(jnp.asarray(x))
+    q, scales = quantize_int8(jnp.asarray(x), interpret=True)
     assert q.dtype == jnp.int8
-    out = np.asarray(dequantize_int8(q, scales, n))
+    out = np.asarray(dequantize_int8(q, scales, n, interpret=True))
     # Error bounded by half a quantization step per 128-lane row.
     per_elem_scale = np.repeat(np.asarray(scales)[:, 0], 128)[:n]
     assert np.all(np.abs(out - x) <= per_elem_scale * 0.5 + 1e-6)
@@ -72,15 +73,16 @@ def test_quantize_roundtrip_error_bounded():
     assert wire * 3 <= x.nbytes + 4 * 128 * 32 * 4
     # Compact wire scales round-trip too.
     out2 = np.asarray(
-        dequantize_int8(q, np.asarray(scales)[:, 0].copy(), n)
+        dequantize_int8(q, np.asarray(scales)[:, 0].copy(), n,
+                        interpret=True)
     )
     np.testing.assert_allclose(out2, out)
 
 
 def test_quantize_zero_input():
     x = jnp.zeros(1024, jnp.float32)
-    q, s = quantize_int8(x)
-    out = dequantize_int8(q, s, 1024)
+    q, s = quantize_int8(x, interpret=True)
+    out = dequantize_int8(q, s, 1024, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), 0)
 
 
@@ -314,7 +316,7 @@ def test_adagrad_update_matches_reference():
 
     new_store, new_acc = adagrad_update(
         jnp.asarray(store), jnp.asarray(acc), jnp.asarray(agg),
-        lr=lr, eps=eps,
+        lr=lr, eps=eps, interpret=True,
     )
     ref_acc = acc + agg * agg
     ref_store = store - lr * agg / (np.sqrt(ref_acc) + eps)

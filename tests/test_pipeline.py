@@ -9,7 +9,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from pslite_tpu.parallel.mesh import shard_map_compat as shard_map
 from pslite_tpu.parallel.pipeline import (
     pipeline_apply,
     pipeline_loss,
@@ -67,11 +66,12 @@ def test_forward_parity():
         return jax.lax.psum(outs, "pp")
 
     f = jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P("pp"), P(None)),
             out_specs=P(None),
+            check_vma=False,
         )
     )
     outs = np.asarray(f(stacked, jnp.asarray(x)))
@@ -111,11 +111,12 @@ def test_gradient_parity():
         return loss, gw, jax.lax.psum(gh, "pp")
 
     f = jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P("pp"), P(None), P(None), P(None)),
             out_specs=(P(), P("pp"), P(None)),
+            check_vma=False,
         )
     )
     loss, gw, gh = f(stacked, jnp.asarray(head), jnp.asarray(x),
@@ -181,11 +182,12 @@ def test_dp_pp_composition():
         return loss, gw, gh
 
     f = jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P("pp"), P(None), P("dp"), P("dp")),
             out_specs=(P(), P("pp"), P(None)),
+            check_vma=False,
         )
     )
     loss, gw, gh = f(stacked, jnp.asarray(head), jnp.asarray(x),
@@ -225,8 +227,9 @@ def test_single_microbatch_and_full_mesh():
         return jax.lax.psum(outs, "pp")
 
     f = jax.jit(
-        shard_map(
-            body, mesh=mesh, in_specs=(P("pp"), P(None)), out_specs=P(None)
+        jax.shard_map(
+            body, mesh=mesh, in_specs=(P("pp"), P(None)), out_specs=P(None),
+            check_vma=False,
         )
     )
     outs = np.asarray(f(stacked, jnp.asarray(x)))

@@ -21,7 +21,6 @@ from pslite_tpu.ops.ring_collective import (
     ring_push_pull,
 )
 from pslite_tpu.parallel.engine import CollectiveEngine
-from pslite_tpu.parallel.mesh import shard_map_compat as shard_map
 
 
 def _mesh(n):
@@ -36,14 +35,16 @@ def _run_kernel(n, chunk, handle, dtype=np.float32, seed=0, bidir=True):
 
     def body(store_l, grads_l):
         g = grads_l[0].reshape(n, chunk)
-        return ring_push_pull(g, store_l, handle, "kv", n, bidir=bidir)
+        return ring_push_pull(g, store_l, handle, "kv", n, bidir=bidir,
+                              interpret=True)
 
     f = jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=_mesh(n),
             in_specs=(P("kv"), P("kv", None)),
             out_specs=(P("kv"), P(None)),
+            check_vma=False,
         )
     )
     new_store, pulled = f(jnp.asarray(store0), jnp.asarray(grads))
@@ -85,14 +86,16 @@ def test_ring_bf16():
 
     def body(store_l, grads_l):
         g = grads_l[0].reshape(n, chunk)
-        return ring_push_pull(g, store_l, lambda s, a: s + a, "kv", n)
+        return ring_push_pull(g, store_l, lambda s, a: s + a, "kv", n,
+                              interpret=True)
 
     f = jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=_mesh(n),
             in_specs=(P("kv"), P("kv", None)),
             out_specs=(P("kv"), P(None)),
+            check_vma=False,
         )
     )
     new_store, pulled = f(
@@ -122,14 +125,15 @@ def test_ring_push_only(n, bidir):
     def body(store_l, grads_l):
         g = grads_l[0].reshape(n, chunk)
         return ring_push(g, store_l, lambda s, a: s + a, "kv", n,
-                         bidir=bidir)
+                         bidir=bidir, interpret=True)
 
     f = jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=_mesh(n),
             in_specs=(P("kv"), P("kv", None)),
             out_specs=P("kv"),
+            check_vma=False,
         )
     )
     new_store = np.asarray(f(jnp.asarray(store0), jnp.asarray(grads)))
@@ -157,14 +161,16 @@ def test_ring_compressed(n, bidir):
     def body(store_l, grads_l):
         g = grads_l[0].reshape(n, chunk)
         return ring_push_pull(g, store_l, lambda s, a: s + a, "kv", n,
-                              bidir=bidir, compress=True)
+                              bidir=bidir, compress=True,
+                              interpret=True)
 
     f = jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=_mesh(n),
             in_specs=(P("kv"), P("kv", None)),
             out_specs=(P("kv"), P(None)),
+            check_vma=False,
         )
     )
     new_store, pulled = f(jnp.asarray(store0), jnp.asarray(grads))
@@ -271,13 +277,21 @@ class TestEnginePallasImpl:
         op = np.asarray(ep.push_pull("p", grads))
         np.testing.assert_allclose(op, ox, rtol=1e-5, atol=1e-5)
 
-    def test_fallbacks_still_work(self):
-        # 1-device mesh and callable handles fall back to XLA silently.
+    def test_unserved_configs_run_xla_and_say_so_once(self, monkeypatch):
+        # A 1-device mesh, a callable handle and a stateful handle run
+        # the XLA collectives under impl="pallas" — said once per reason,
+        # however many ops follow.
+        from pslite_tpu.parallel import engine as engine_mod
+
+        said = []
+        monkeypatch.setattr(engine_mod.log, "warning", said.append)
         ep = CollectiveEngine(mesh=_mesh(1), impl="pallas")
         keys = np.arange(2, dtype=np.uint64)
         ep.register_dense("f", keys, 8)
-        out = np.asarray(ep.push_pull("f", np.ones(16, np.float32)))
-        np.testing.assert_allclose(out, np.ones(16), rtol=1e-6)
+        for step in (1, 2):
+            out = np.asarray(ep.push_pull("f", np.ones(16, np.float32)))
+            np.testing.assert_allclose(out, step * np.ones(16), rtol=1e-6)
+        assert len(said) == 1 and "2 or more devices" in said[0]
 
         ep2 = CollectiveEngine(mesh=_mesh(2), impl="pallas")
         ep2.register_dense("g", keys, 1024)
@@ -285,6 +299,10 @@ class TestEnginePallasImpl:
         grads = np.ones((2, 2048), np.float32)
         out = np.asarray(ep2.push_pull("g", grads, handle=custom))
         np.testing.assert_allclose(out, 4.0 * np.ones(2048), rtol=1e-6)
+        ep2.push_pull("g", grads, handle="sgd_momentum:0.1,0.9")
+        ep2.push_pull("g", grads, handle="sgd_momentum:0.1,0.9")
+        assert len(said) == 3, said
+        assert "callable handle" in said[1] and "stateful" in said[2]
 
     def test_push_only_parity(self):
         n = 4
