@@ -1,0 +1,25 @@
+"""The tiny cells of the rehearsals: configuration and traffic files under
+``cells/`` that no entry of ``workloads`` names, as ``harness.Cell``s."""
+
+import json
+import os
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+KINDS = {"dense": ("tiny-dense.json", "tiny-buckets.json"),
+         "sparse": ("tiny-sparse.json", "tiny-zipf.json")}
+
+
+def _json(path):
+    with open(path) as fh:
+        return json.load(fh)
+
+
+def cell(kind: str, chips: int = 4):
+    import harness
+
+    bench = _json(os.path.join(ROOT, "BENCHMARK.json"))
+    config, traffic = (_json(os.path.join(HERE, "cells", n))
+                       for n in KINDS[kind])
+    return harness.Cell("tiny-" + kind, chips, config, traffic,
+                        bench["end_to_end"], bench["per_layer"])
