@@ -451,21 +451,6 @@ def run_smoke(mesh, sizes: Sizes,
               flush=True)
 
 
-def _count_cache_events() -> dict:
-    import jax
-
-    counts = {"hits": 0, "misses": 0}
-
-    def listener(event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            counts["hits"] += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            counts["misses"] += 1
-
-    jax.monitoring.register_event_listener(listener)
-    return counts
-
-
 def main() -> int:
     t_start = time.perf_counter()
     with deadline("device", 120):
@@ -484,10 +469,10 @@ def main() -> int:
         return 2
 
     from pslite_tpu.parallel.mesh import default_mesh
-    from pslite_tpu.utils.compile_cache import enable_compile_cache
+    from pslite_tpu.utils.compile_cache import (cache_counts,
+                                                enable_compile_cache)
 
     cache_dir = enable_compile_cache()
-    cache = _count_cache_events()
     try:
         with deadline("run", 1150):
             run_smoke(default_mesh(), Sizes())
@@ -499,8 +484,8 @@ def main() -> int:
         # Not sys.exit: a thread still inside a failed device call must
         # not hold the interpreter open.
         os._exit(1)
-    print(f"set-up: compile cache {cache_dir}: {cache['hits']} hits, "
-          f"{cache['misses']} misses; whole run "
+    print(f"set-up: compile cache {cache_dir}: {cache_counts[0]} hits, "
+          f"{cache_counts[1]} misses; whole run "
           f"{time.perf_counter() - t_start:.1f} s")
     print(json.dumps({
         "ok": True,

@@ -52,6 +52,10 @@ from ..range import Range, find_range
 from ..sarray import SArray
 from ..utils import logging as log
 from ..utils.bounded import BoundedKeySet
+from ..utils.profiling import (
+    COMPLETE_SPANS, COMPLETED, KV_OP, OP_SPAN, TraceAnnotation, stage_clock,
+    stamp, tracing,
+)
 from ..vans import native
 from . import snapshot as snapshot_mod
 from .apply_shards import ApplyShardPool
@@ -474,6 +478,8 @@ class KVWorker:
         self._c_codec_wire = self.po.metrics.counter("codec.wire_bytes")
         self._device_results: Dict[int, object] = {}
         self._engine_pool = None  # lazy completion executor (engine path)
+        self._stage_clock = stage_clock()
+        self._note = self._stage_clock.note  # one C call an op
         # Last completion per pinned bucket: the next pinned pull joins it
         # before donating the previous result (one-outstanding contract).
         self._pinned_pull_futs: Dict[str, Callable] = {}
@@ -888,25 +894,61 @@ class KVWorker:
 
     _MAX_DEVICE_RESULTS = 8
 
-    def _engine_dispatch(self, result, out=None, callback=None,
-                         keep_result: bool = False,
-                         fut_out: Optional[list] = None) -> int:
-        """Timestamp + async completion for a collective op.
+    def _engine_op(self, op, args, keys=None, cmd: int = 0, lens=None,
+                   out=None, callback=None, keep_result: bool = False,
+                   pull: bool = False) -> Optional[int]:
+        """One op of the collective path: route, the engine's op, then
+        timestamp + async completion.  None where ``keys`` are no
+        registered bucket: the op is the message path's.
+
+        The stages are stamped for the ``StageClock``
+        (``utils.profiling.STAGES``): ``route`` for a dense call (``keys``
+        given; a sparse call routes nothing, its table's name is the
+        first of ``args``), the engine's ``op(name, *args)``, which notes
+        ``select``, ``prep`` and ``launch`` itself, then ``dispatch``,
+        the rest of this method.  While a profiler session runs the op
+        lies in a ``ps.kv.op`` span.  Callers pass everything by
+        position, and the dispatch is not a method of its own: on the
+        chip's host a Python call costs this path 2-3 us, a keyword call
+        half a microsecond more (PERF.md, PR 24).
 
         Completion (device done -> host copy -> callback) runs on a
         dedicated thread so callbacks fire without wait(), matching the
         message path; wait(ts) joins the same future (idempotent hook).
 
-        ``result`` must be a NON-donated array: pushes hand back a tiny
-        completion token (the store itself is donated by the next push of
-        the same bucket, so blocking on it would crash back-to-back
-        pushes); pulls hand back the gathered output.
-        """
-        import concurrent.futures
+        The op's result must be a NON-donated array: pushes hand back a
+        tiny completion token (the store itself is donated by the next
+        push of the same bucket, so blocking on it would crash
+        back-to-back pushes); pulls hand back the gathered output.
 
+        ``pull`` marks a dense pull: its result is retained for
+        get_pulled() unless the bucket's pull buffer is pinned — a pinned
+        result is donated by the NEXT pull, so retaining it would hand
+        out deleted arrays; its completion is what that next pull joins.
+        """
+        span = TraceAnnotation(OP_SPAN) if tracing() else None
+        if span is not None:
+            span.__enter__()
+        t0 = stamp()
+        route_ns = -1
+        if keys is not None:
+            name = self._engine_route(
+                np.asarray(keys, dtype=np.uint64), cmd, lens)
+            if name is None:
+                return None
+            args = (name, *args)
+            route_ns = stamp() - t0
+        name = args[0]
+        result = op(*args)
+        t2 = stamp()  # launch | dispatch, but for the way back up
+        pinned = pull and self.engine.pinned_pull_buffer(name) is not None
+        if pull:
+            keep_result = not pinned
         ts = self._customer.new_request(SERVER_GROUP, num_responses=0)
         with self._mu:
             if self._engine_pool is None:
+                import concurrent.futures
+
                 self._engine_pool = concurrent.futures.ThreadPoolExecutor(
                     max_workers=1, thread_name_prefix="kv-engine-complete"
                 )
@@ -915,16 +957,49 @@ class KVWorker:
                 while len(self._device_results) > self._MAX_DEVICE_RESULTS:
                     self._device_results.pop(next(iter(self._device_results)))
         fut = self._engine_pool.submit(
-            self._engine_complete, result, out, callback
+            self._engine_complete, ts, name, result, out, callback
         )
-        if fut_out is not None:
-            fut_out.append(fut.result)
+        if pinned:
+            self._pinned_pull_futs[name] = fut.result
         self._customer.add_wait_hook(ts, fut.result)
+        t3 = stamp()
+        self._note((KV_OP, t3, route_ns, t3 - t2, -1))
+        if span is not None:
+            span.set_metadata(ts=ts, name=name)
+            span.__exit__(None, None, None)
         return ts
 
-    @staticmethod
-    def _engine_complete(result, out, callback):
+    def _engine_pull(self, name: str):
+        """``engine.pull`` under the registered-buffer contract
+        (kv_app.h:210-217 for the reference's pinned buffers): at most
+        one outstanding pull per pinned bucket — the next pull donates
+        the previous result's buffer, so dispatching it while the
+        completion thread still copies would use-after-donate."""
+        if self.engine.pinned_pull_buffer(name) is not None:
+            prev = self._pinned_pull_futs.get(name)
+            if prev is not None:
+                prev()
+        return self.engine.pull(name)
+
+    def _engine_complete(self, ts: int, name: str, result, out, callback):
+        """On the ``kv-engine-complete`` thread: stage ``complete.wait``
+        (blocked on the device), then ``complete.copy`` (host work), each
+        in a span that carries the op's ``ts`` while a profiler session
+        runs."""
+        traced = tracing()
+        t0 = stamp()
+        span = (TraceAnnotation(COMPLETE_SPANS[0], ts=ts, name=name)
+                if traced else None)
+        if span is not None:
+            span.__enter__()
         result.block_until_ready()
+        if span is not None:
+            span.__exit__(None, None, None)
+        t1 = stamp()
+        span = (TraceAnnotation(COMPLETE_SPANS[1], ts=ts, name=name)
+                if traced else None)
+        if span is not None:
+            span.__enter__()
         if out is not None:
             if getattr(result, "is_fully_addressable", True) or getattr(
                 result, "is_fully_replicated", False
@@ -947,6 +1022,12 @@ class KVWorker:
             )
         if callback is not None:
             callback()
+        if span is not None:
+            span.__exit__(None, None, None)
+        t2 = stamp()
+        self._note((COMPLETED, t2, t1 - t0, t2 - t1, -1))
+        if not ts & 1023:  # now and then, and off the issuing thread
+            self._stage_clock.fold()
 
     def get_pulled(self, ts: int):
         """Device-resident pull result for a recent engine-path timestamp
@@ -988,16 +1069,15 @@ class KVWorker:
         sharded table (aggregation server handle)."""
         eng = getattr(self.po.van, "sparse_engine", None)
         log.check(eng is not None, "push_sparse requires the ici van")
-        token = eng.push(name, indices, grads)
-        return self._engine_dispatch(token, callback=callback)
+        return self._engine_op(eng.push, (name, indices, grads), None, 0,
+                               None, None, callback)
 
     def pull_sparse(self, name: str, indices, out=None,
                     callback=None) -> int:
         eng = getattr(self.po.van, "sparse_engine", None)
         log.check(eng is not None, "pull_sparse requires the ici van")
-        result = eng.pull(name, indices)
-        return self._engine_dispatch(result, out=out, callback=callback,
-                                     keep_result=True)
+        return self._engine_op(eng.pull, (name, indices), None, 0, None,
+                               out, callback, True)
 
     # -- telemetry -----------------------------------------------------------
 
@@ -1200,11 +1280,12 @@ class KVWorker:
         supported via per-key blockwise scaling.  Ignored on the
         collective (ICI) path, which needs no wire compression.
         """
-        route = self._engine_route(np.asarray(keys, dtype=np.uint64), cmd,
-                                   lens)
-        if route is not None:
-            token = self.engine.push(route, vals)
-            return self._engine_dispatch(token, callback=callback)
+        engine = self.engine
+        if engine is not None:
+            ts = self._engine_op(engine.push, (vals,), keys, cmd, lens,
+                                 None, callback)
+            if ts is not None:
+                return ts
         kvs = _as_kvs(keys, vals, lens, priority)
         codec = self._resolve_codec(kvs.keys, codec, compress)
         if codec is not None:
@@ -1260,29 +1341,11 @@ class KVWorker:
         if codec is not None:
             log.check(vals.dtype == np.float32,
                       f"codec {codec!r} requires float32 values")
-        route = self._engine_route(keys, cmd, lens)
-        if route is not None:
-            pinned = self.engine.pinned_pull_buffer(route) is not None
-            if pinned:
-                # Registered-buffer contract (kv_app.h:210-217 for the
-                # reference's pinned buffers): at most one outstanding
-                # pull per pinned bucket — the next pull donates the
-                # previous result's buffer, so dispatching it while the
-                # completion thread still copies would use-after-donate.
-                prev = self._pinned_pull_futs.get(route)
-                if prev is not None:
-                    prev()
-            result = self.engine.pull(route)
-            # keep_result retains device results for get_pulled(); a
-            # pinned result is donated by the NEXT pull, so retaining it
-            # would hand out deleted arrays.
-            holder: list = []
-            ts = self._engine_dispatch(result, out=vals, callback=callback,
-                                       keep_result=not pinned,
-                                       fut_out=holder if pinned else None)
-            if pinned and holder:
-                self._pinned_pull_futs[route] = holder[0]
-            return ts
+        if self.engine is not None:
+            ts = self._engine_op(self._engine_pull, (), keys, cmd, lens,
+                                 vals, callback, False, True)
+            if ts is not None:
+                return ts
         if (self._hot_cache is not None and lens is None
                 and codec is None and cmd == 0
                 and isinstance(vals, np.ndarray)
@@ -1339,12 +1402,12 @@ class KVWorker:
         registered-buffer delivery, and the request's EXT_CODEC marker
         already describes the pushed payload, not a response wish.
         """
-        route = self._engine_route(np.asarray(keys, dtype=np.uint64), cmd,
-                                   lens)
-        if route is not None:
-            result = self.engine.push_pull(route, vals)
-            return self._engine_dispatch(result, out=outs, callback=callback,
-                                         keep_result=True)
+        engine = self.engine
+        if engine is not None:
+            ts = self._engine_op(engine.push_pull, (vals,), keys, cmd, lens,
+                                 outs, callback, True)
+            if ts is not None:
+                return ts
         kvs = _as_kvs(keys, vals, lens, priority)
         codec = self._resolve_codec(kvs.keys, codec, compress)
         if codec is not None:

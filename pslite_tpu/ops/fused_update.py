@@ -59,11 +59,14 @@ def _store(ref, value):
     ref[:, :] = value.astype(ref.dtype)
 
 
-def _elementwise_call(kernel, state, agg, interpret: bool, scalars=None):
+def _elementwise_call(name: str, kernel, state, agg, interpret: bool,
+                      scalars=None):
     """Run ``kernel`` over ``(*state, agg)`` tiled ``(block_rows, 128)``,
     every ``state`` vector updated in place; returns the new state vectors
     at their original length.  ``scalars`` (a small f32 vector) rides
-    scalar prefetch and arrives as the kernel's first ref."""
+    scalar prefetch and arrives as the kernel's first ref.  ``name`` is
+    the kernel's name in a device trace: the jitted wrapper's own, which
+    the operation carried before it was given one."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -88,6 +91,7 @@ def _elementwise_call(kernel, state, agg, interpret: bool, scalars=None):
             n_prefetch + i: i for i in range(len(state))
         },
         interpret=interpret,
+        name=name,
     )(*(() if scalars is None else (scalars,)), *tiles)
     return tuple(o.reshape(-1)[:n] for o in outs)
 
@@ -105,7 +109,8 @@ def sgd_update(store, mom, agg, *, interpret: bool, lr: float = 0.01,
         _store(out_mom_ref, m)
         _store(out_store_ref, _f32(store_ref) - lr * m)
 
-    return _elementwise_call(kernel, (store, mom), agg, interpret)
+    return _elementwise_call("sgd_update", kernel, (store, mom), agg,
+                             interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("lr", "eps", "interpret"))
@@ -127,7 +132,8 @@ def adagrad_update(store, acc, agg, *, interpret: bool, lr: float = 0.01,
         _store(out_store_ref,
                _f32(store_ref) - lr * g / (jnp.sqrt(a) + eps))
 
-    return _elementwise_call(kernel, (store, acc), agg, interpret)
+    return _elementwise_call("adagrad_update", kernel, (store, acc), agg,
+                             interpret)
 
 
 @functools.partial(
@@ -159,5 +165,5 @@ def adam_update(store, m, v, agg, step, *, interpret: bool,
                _f32(store_ref)
                - scalar_ref[0] * m_new / (jnp.sqrt(v_new) + eps))
 
-    return _elementwise_call(kernel, (store, m, v), agg, interpret,
-                             scalars=scalars)
+    return _elementwise_call("adam_update", kernel, (store, m, v), agg,
+                             interpret, scalars=scalars)

@@ -30,7 +30,6 @@ from __future__ import annotations
 import contextlib
 import os
 import threading
-import time
 from dataclasses import dataclass
 from functools import partial
 from typing import Callable, Dict, Optional, Union
@@ -38,6 +37,7 @@ from typing import Callable, Dict, Optional, Union
 import numpy as np
 
 from ..utils import logging as log
+from ..utils.profiling import ENGINE_OP, stage_clock, stamp
 from .placement import staging_xp
 
 
@@ -84,14 +84,38 @@ def _slice_ring_pulled(pulled, n: int, kchunk: int, chunk0: int):
 def _aggregate(grads_l, axis, worker_axis=None):
     """Worker-reduction of a local grads block — psum_scatter on the 1-D
     colocated layout (reduce+shard in one hop), psum over the worker axis
-    on a 2-D layout (the kv sharding is already in the data layout)."""
+    on a 2-D layout (the kv sharding is already in the data layout).
+
+    The three phases of a program carry ``jax.named_scope``s
+    (``ps.push.reduce`` here, ``ps.update``, ``ps.pull.gather``): metadata
+    on the operations, by which a device trace is read."""
+    import jax
     from jax import lax
 
-    if worker_axis is None:
-        return lax.psum_scatter(
-            grads_l[0], axis, scatter_dimension=0, tiled=True
-        )
-    return lax.psum(grads_l[0], worker_axis)
+    with jax.named_scope("ps.push.reduce"):
+        if worker_axis is None:
+            return lax.psum_scatter(
+                grads_l[0], axis, scatter_dimension=0, tiled=True
+            )
+        return lax.psum(grads_l[0], worker_axis)
+
+
+def _update(handle, *args):
+    """The server handle (stateless ``handle(store, agg)`` or stateful
+    ``sfn(store, state, agg)``) under its ``ps.update`` scope."""
+    import jax
+
+    with jax.named_scope("ps.update"):
+        return handle(*args)
+
+
+def _gather(store_l, axis):
+    """The pull: all-gather of the store shards, under ``ps.pull.gather``."""
+    import jax
+    from jax import lax
+
+    with jax.named_scope("ps.pull.gather"):
+        return lax.all_gather(store_l, axis, tiled=True)
 
 
 def _rs_update_ag(store_l, grads_l, handle, axis, worker_axis=None):
@@ -101,12 +125,9 @@ def _rs_update_ag(store_l, grads_l, handle, axis, worker_axis=None):
     update, pull — kv_app.h:430-452 fused into the collectives).
 
     See :func:`_aggregate` for the 1-D vs 2-D reduction shapes."""
-    from jax import lax
-
     agg = _aggregate(grads_l, axis, worker_axis)
-    new_store = handle(store_l, agg)
-    pulled = lax.all_gather(new_store, axis, tiled=True)
-    return new_store, pulled
+    new_store = _update(handle, store_l, agg)
+    return new_store, _gather(new_store, axis)
 
 
 class CollectiveEngine:
@@ -124,7 +145,6 @@ class CollectiveEngine:
         mesh=None,
         axis_name: str = "kv",
         server_handle: ServerHandle = "sum",
-        profiler=None,
         worker_axis: Optional[str] = None,
         impl: Optional[str] = None,
         wire_compress: Optional[str] = None,
@@ -246,10 +266,14 @@ class CollectiveEngine:
         # same donated buffer to two programs).  Per-bucket rather than
         # engine-wide so different buckets still dispatch concurrently.
         self._bucket_mu: Dict[str, threading.Lock] = {}
-        # Observability (reference: van.cc:29-77 event log + van.h:183-184
-        # byte counters): application-payload bytes moved through the
-        # collective data plane, surfaced next to Van.send_bytes/recv_bytes.
-        self.profiler = profiler
+        # Observability (reference: van.h:183-184 byte counters):
+        # application-payload bytes moved through the collective data
+        # plane, surfaced next to Van.send_bytes/recv_bytes; host time per
+        # stage of an op goes to the process's StageClock.
+        self._clock = stage_clock()
+        # An op notes (ENGINE_OP, t_end, select ns, prep ns, launch ns): one
+        # C call, whatever the op groups or replays (see StageClock).
+        self._note = self._clock.note
         self.push_bytes = 0
         self.pull_bytes = 0
         self._counter_mu = threading.Lock()
@@ -415,6 +439,14 @@ class CollectiveEngine:
         callers)."""
         return self._is_stateful(self._server_handle)
 
+    def _keep(self, key, prog) -> Callable:
+        """Cache a program a lookup missed; the misses are counted here,
+        off the hot path (the hits are the ops less the misses)."""
+        with self._mu:
+            self._programs[key] = prog
+        self._clock.program_built()
+        return prog
+
     def _program(self, op: str, padded_len: int, dtype, handle_key) -> Callable:
         """Jitted SPMD program for (op, shape, dtype, handle) — the
         executable-cache analog of the reference's per-(key,push,recver)
@@ -463,19 +495,19 @@ class CollectiveEngine:
             # second output its own buffer — a full read+write that was
             # 40% of the headline's device time (r03 verdict, weak #1).
             if flat_zc:
-                return handle(store_l, grads_l)
+                return _update(handle, store_l, grads_l)
             agg = _aggregate(grads_l, axis, waxis)
-            return handle(store_l, agg)
+            return _update(handle, store_l, agg)
 
         def _push(store_l, grads_l):
             agg = _aggregate(grads_l, axis, waxis)
-            new = handle(store_l, agg)
+            new = _update(handle, store_l, agg)
             # Tiny non-donated completion token: callers block on this
             # instead of the store (which the next push donates).
             return new, new[:1]
 
         def _pull(store_l):
-            return lax.all_gather(store_l, axis, tiled=True)
+            return _gather(store_l, axis)
 
         def _pull_pinned(prev_l, store_l):
             # prev_l is the previous pinned output, passed to donate its
@@ -486,7 +518,7 @@ class CollectiveEngine:
             # arithmetic (prev*0 would resurrect NaNs from stale lanes).
             import jax.numpy as jnp
 
-            pulled = lax.all_gather(store_l, axis, tiled=True)
+            pulled = _gather(store_l, axis)
             nbits = np.dtype(pulled.dtype).itemsize * 8
             idt = jnp.dtype(f"int{nbits}")
             dep = lax.bitcast_convert_type(prev_l, idt) & jnp.array(0, idt)
@@ -537,9 +569,7 @@ class CollectiveEngine:
             jitted = jax.jit(fn, donate_argnums=(0,))
         else:
             raise ValueError(op)
-        with self._mu:
-            self._programs[key] = jitted
-        return jitted
+        return self._keep(key, jitted)
 
     def _ring_unserved(self, dtype, resolved_handle) -> Optional[str]:
         """Why the fused ring kernel cannot serve this config, or None
@@ -681,9 +711,7 @@ class CollectiveEngine:
             check_vma=False,
         )
         jitted = jax.jit(fn, donate_argnums=(0,))
-        with self._mu:
-            self._programs[key] = jitted
-        return jitted
+        return self._keep(key, jitted)
 
     def _ring_program_op_2d(self, op: str, key, padded_len: int, dtype,
                             handle_key, compress: bool) -> Callable:
@@ -714,7 +742,7 @@ class CollectiveEngine:
 
         def body_pp(store_l, grads_l):
             new_store = _updated_shard(store_l, grads_l)
-            pulled = lax.all_gather(new_store, axis, tiled=True)
+            pulled = _gather(new_store, axis)
             return new_store, pulled
 
         def body_push(store_l, grads_l):
@@ -733,9 +761,7 @@ class CollectiveEngine:
             check_vma=False,
         )
         jitted = jax.jit(fn, donate_argnums=(0,))
-        with self._mu:
-            self._programs[key] = jitted
-        return jitted
+        return self._keep(key, jitted)
 
     def _ring_2d_shard_fn(self, handle, padded_len: int, dtype,
                           compress: bool, cid: int):
@@ -799,21 +825,20 @@ class CollectiveEngine:
         def _push(store_l, *rest):
             state_l, grads_l = rest[:-1], rest[-1]
             agg = _aggregate(grads_l, axis, waxis)
-            new_store, new_state = sfn(store_l, tuple(state_l), agg)
+            new_store, new_state = _update(sfn, store_l, tuple(state_l), agg)
             return (new_store, *new_state, new_store[:1])  # token last
 
         def _push_pull(store_l, *rest):
             state_l, grads_l = rest[:-1], rest[-1]
             agg = _aggregate(grads_l, axis, waxis)
-            new_store, new_state = sfn(store_l, tuple(state_l), agg)
-            pulled = lax.all_gather(new_store, axis, tiled=True)
-            return (new_store, *new_state, pulled)
+            new_store, new_state = _update(sfn, store_l, tuple(state_l), agg)
+            return (new_store, *new_state, _gather(new_store, axis))
 
         def _push_pull_zc(store_l, *rest):
             # In-place pull delivery: see _program's _push_pull_zc.
             state_l, grads_l = rest[:-1], rest[-1]
             agg = _aggregate(grads_l, axis, waxis)
-            new_store, new_state = sfn(store_l, tuple(state_l), agg)
+            new_store, new_state = _update(sfn, store_l, tuple(state_l), agg)
             return (new_store, *new_state)
 
         if op == "push_st":
@@ -830,9 +855,7 @@ class CollectiveEngine:
             check_vma=False,
         )
         jitted = jax.jit(fn, donate_argnums=tuple(range(1 + n_state)))
-        with self._mu:
-            self._programs[key] = jitted
-        return jitted
+        return self._keep(key, jitted)
 
     def _ensure_opt_state(self, name: str, handle: str, bucket) -> None:
         """Allocate (or validate) the bucket's optimizer state.  Call with
@@ -846,6 +869,7 @@ class CollectiveEngine:
                   f"switch to {kind!r}")
         from jax.sharding import NamedSharding, PartitionSpec as P
 
+        t0 = stamp()
         sharding = NamedSharding(self.mesh, P(self.axis))
         dt = np.dtype(bucket.dtype)
         if kind in ("sgd_momentum", "adagrad"):
@@ -858,6 +882,7 @@ class CollectiveEngine:
             )
         self._opt_states[name] = state
         self._opt_kinds[name] = kind
+        self._clock.state_created(stamp() - t0)
 
     def opt_state(self, name: str):
         """Snapshot of the bucket's optimizer state (checkpointing).
@@ -1127,27 +1152,17 @@ class CollectiveEngine:
         )
         return jax.device_put(arr, sharding)
 
-    def _observe(self, name: str, op: str, bucket: DenseBucket,
-                 t0: float) -> None:
-        """Account one data-plane op: byte counters always, the
-        (bucket, op, bytes, µs) event when profiling is on.
-
-        The µs field is DISPATCH latency (op entry to async enqueue), not
-        device execution time — collectives are dispatched asynchronously;
-        use ``utils.profiling.device_trace`` (XPlane) for transfer-level
-        timing, as documented in record_engine's consumer docs."""
+    def _observe(self, op: str, bucket: DenseBucket, pushes: int = 1,
+                 pulls: int = 1) -> None:
+        """Account one data-plane op in the byte counters.  (Its host
+        time is the StageClock's; device time is a ``jax.profiler``
+        trace's, see ``utils.profiling.device_trace``.)"""
         payload = bucket.total_len * np.dtype(bucket.dtype).itemsize
         with self._counter_mu:
             if op in ("push", "push_pull"):
-                self.push_bytes += payload
+                self.push_bytes += payload * pushes
             if op in ("pull", "push_pull"):
-                self.pull_bytes += payload
-        if self.profiler is not None and getattr(
-            self.profiler, "enabled", False
-        ):
-            dur_us = int((time.perf_counter() - t0) * 1e6)
-            nbytes = payload * (2 if op == "push_pull" else 1)
-            self.profiler.record_engine(name, op, nbytes, dur_us)
+                self.pull_bytes += payload * pulls
 
     def _resolve_handle(self, handle: Optional[ServerHandle]):
         resolved = self._server_handle if handle is None else handle
@@ -1205,24 +1220,38 @@ class CollectiveEngine:
         RegisterRecvBuffer pulls (the next pull overwrites the registered
         buffer in place).  Configs the in-place path cannot serve fall
         back to the copying path transparently."""
-        t0 = time.perf_counter()
+        t0 = stamp()  # stage borders: see _note
         bucket = self._buckets[name]
         resolved, handle_key = self._resolve_handle(handle)
         zc = zero_copy and self._zc_pull_eligible(bucket.dtype, resolved)
-        flat_zc = zc and self.flat_zc_eligible(handle)
-        ring_1d = self.flat_ring_eligible(bucket.dtype, handle)
-        if flat_zc:
-            g = self._prep_grads_flat(bucket, grads)
-        elif ring_1d:
-            g = self._prep_grads_ring(bucket, grads)
-        else:
-            g = self._prep_grads(bucket, grads)
-        if self._is_stateful(resolved):
+        # Resolved for every op: it says once why "pallas" runs XLA.
+        impl = self._effective_impl(bucket.dtype, resolved)
+        stateful = self._is_stateful(resolved)
+        if stateful:
+            prep = self._prep_grads
             prog = self._program(
                 "push_pull_st_zc" if zc else "push_pull_st",
                 bucket.padded_len, bucket.dtype, handle_key
             )
-            with self._bucket_mu[name]:
+        elif impl == "pallas":
+            prep = (self._prep_grads_ring if self.worker_axis is None
+                    else self._prep_grads)
+            prog = self._ring_program(
+                bucket.padded_len, bucket.dtype, handle_key
+            )
+        else:
+            prep = (self._prep_grads_flat
+                    if zc and self.flat_zc_eligible(handle)
+                    else self._prep_grads)
+            prog = self._program(
+                "push_pull_zc" if zc else "push_pull",
+                bucket.padded_len, bucket.dtype, handle_key
+            )
+        t1 = stamp()  # select | prep
+        g = prep(bucket, grads)
+        t2 = stamp()  # prep | launch
+        with self._bucket_mu[name]:
+            if stateful:
                 self._ensure_opt_state(name, resolved, bucket)
                 outs = prog(
                     self._stores[name], *self._opt_states[name], g
@@ -1231,52 +1260,31 @@ class CollectiveEngine:
                 self._stores[name] = outs[0]
                 self._opt_states[name] = tuple(outs[1:1 + n_state])
                 pulled = outs[0] if zc else outs[-1]
-            self._observe(name, "push_pull", bucket, t0)
-            return pulled if zc else pulled[: bucket.total_len]
-        if self._effective_impl(bucket.dtype, resolved) == "pallas":
-            prog = self._ring_program(
-                bucket.padded_len, bucket.dtype, handle_key
-            )
-        elif zc:
-            prog = self._program(
-                "push_pull_zc", bucket.padded_len, bucket.dtype, handle_key
-            )
-        else:
-            prog = self._program(
-                "push_pull", bucket.padded_len, bucket.dtype, handle_key
-            )
-        with self._bucket_mu[name]:
-            if zc:
-                new_store = prog(self._stores[name], g)
-                pulled = new_store
+            elif zc:
+                pulled = self._stores[name] = prog(self._stores[name], g)
             else:
-                new_store, pulled = prog(self._stores[name], g)
-            self._stores[name] = new_store
-        self._observe(name, "push_pull", bucket, t0)
-        return pulled if zc else pulled[: bucket.total_len]
+                self._stores[name], pulled = prog(self._stores[name], g)
+            if not zc:
+                pulled = pulled[: bucket.total_len]
+        self._observe("push_pull", bucket)
+        t3 = stamp()
+        self._note((ENGINE_OP, t3, t1 - t0, t2 - t1, t3 - t2))
+        return pulled
 
     def push(self, name: str, grads, handle: Optional[ServerHandle] = None):
-        t0 = time.perf_counter()
+        t0 = stamp()  # stage borders: see _note
         bucket = self._buckets[name]
         resolved, handle_key = self._resolve_handle(handle)
-        ring_1d = self.flat_ring_eligible(bucket.dtype, handle)
-        g = (self._prep_grads_ring(bucket, grads) if ring_1d
-             else self._prep_grads(bucket, grads))
-        if self._is_stateful(resolved):
+        impl = self._effective_impl(bucket.dtype, resolved)
+        stateful = self._is_stateful(resolved)
+        prep = self._prep_grads
+        if stateful:
             prog = self._program(
                 "push_st", bucket.padded_len, bucket.dtype, handle_key
             )
-            with self._bucket_mu[name]:
-                self._ensure_opt_state(name, resolved, bucket)
-                outs = prog(
-                    self._stores[name], *self._opt_states[name], g
-                )
-                self._stores[name] = outs[0]
-                self._opt_states[name] = tuple(outs[1:-1])
-                token = outs[-1]
-            self._observe(name, "push", bucket, t0)
-            return token
-        if self._effective_impl(bucket.dtype, resolved) == "pallas":
+        elif impl == "pallas":
+            if self.worker_axis is None:
+                prep = self._prep_grads_ring
             prog = self._ring_program_op(
                 "push", bucket.padded_len, bucket.dtype, handle_key
             )
@@ -1284,10 +1292,23 @@ class CollectiveEngine:
             prog = self._program(
                 "push", bucket.padded_len, bucket.dtype, handle_key
             )
+        t1 = stamp()  # select | prep
+        g = prep(bucket, grads)
+        t2 = stamp()  # prep | launch
         with self._bucket_mu[name]:
-            new_store, token = prog(self._stores[name], g)
-            self._stores[name] = new_store
-        self._observe(name, "push", bucket, t0)
+            if stateful:
+                self._ensure_opt_state(name, resolved, bucket)
+                outs = prog(
+                    self._stores[name], *self._opt_states[name], g
+                )
+                self._stores[name] = outs[0]
+                self._opt_states[name] = tuple(outs[1:-1])
+                token = outs[-1]
+            else:
+                self._stores[name], token = prog(self._stores[name], g)
+        self._observe("push", bucket)
+        t3 = stamp()
+        self._note((ENGINE_OP, t3, t1 - t0, t2 - t1, t3 - t2))
         # The token is a tiny non-donated output that becomes ready when
         # the push completes — block on it freely (the store itself is
         # donated by the next push, so it must not escape).
@@ -1317,23 +1338,26 @@ class CollectiveEngine:
         resolved, handle_key = self._resolve_handle(handle)
         log.check(not self._is_stateful(resolved),
                   "push_pull_group supports stateless handles only")
-        t0 = time.perf_counter()
+        t0 = stamp()  # stage borders: see _note
         buckets = [self._buckets[n] for n in names]
-        # MUST mirror _group_program's use_ring resolution: the grouped
-        # 1-D ring program takes each bucket's grads FLAT (same sublane
-        # -pad rationale as _prep_grads_ring).
+        # MUST mirror _group_program's use_ring resolution: the
+        # grouped 1-D ring program takes each bucket's grads FLAT
+        # (same sublane-pad rationale as _prep_grads_ring).
         group_flat = self.worker_axis is None and all(
             self._effective_impl(b.dtype, resolved) == "pallas"
             for b in buckets
         )
         prep = self._prep_grads_ring if group_flat else self._prep_grads
-        gs = [prep(b, g) for b, g in zip(buckets, grads_list)]
         prog = self._group_program(
-            tuple((b.padded_len, str(np.dtype(b.dtype))) for b in buckets),
+            tuple((b.padded_len, str(np.dtype(b.dtype)))
+                  for b in buckets),
             handle_key,
         )
-        # Lock every bucket in sorted order (deadlock-free against other
-        # group/single ops) for the whole load-run-store.
+        t1 = stamp()  # select | prep
+        gs = [prep(b, g) for b, g in zip(buckets, grads_list)]
+        t2 = stamp()  # prep | launch
+        # Lock every bucket in sorted order (deadlock-free against
+        # other group/single ops) for the whole load-run-store.
         ordered = sorted(set(names))
         for n in ordered:
             self._bucket_mu[n].acquire()
@@ -1342,18 +1366,15 @@ class CollectiveEngine:
             k = len(names)
             for i, n in enumerate(names):
                 self._stores[n] = outs[i]
-            pulled = outs[k:]
         finally:
             for n in reversed(ordered):
                 self._bucket_mu[n].release()
-        for i, (n, b) in enumerate(zip(names, buckets)):
-            # One dispatch happened: attribute its latency to the first
-            # bucket's event only (zero for the rest) so summed profiler
-            # durations aren't inflated k-fold; byte counters are per
-            # bucket as usual.
-            self._observe(n, "push_pull", b,
-                          t0 if i == 0 else time.perf_counter())
-        return [p[: b.total_len] for p, b in zip(pulled, buckets)]
+        pulled = [p[: b.total_len] for p, b in zip(outs[k:], buckets)]
+        for b in buckets:
+            self._observe("push_pull", b)
+        t3 = stamp()
+        self._note((ENGINE_OP, t3, t1 - t0, t2 - t1, t3 - t2))
+        return pulled
 
     def _group_program(self, shapes_key, handle_key) -> Callable:
         # The ring gate is _effective_impl per bucket dtype — the same
@@ -1412,7 +1433,7 @@ class CollectiveEngine:
                     handle, padded_len, dtype, compress, cid
                 )
                 new = shard_fn(store_l, grads_l)
-                pulled = lax.all_gather(new, axis, tiled=True)
+                pulled = _gather(new, axis)
                 return new, pulled
             chunk0 = padded_len // n
             kchunk = ring_chunk_len(padded_len, n, dtype,
@@ -1454,9 +1475,7 @@ class CollectiveEngine:
             check_vma=False,
         )
         jitted = jax.jit(fn, donate_argnums=tuple(range(k)))
-        with self._mu:
-            self._programs[key] = jitted
-        return jitted
+        return self._keep(key, jitted)
 
     # -- fused multi-step replay --------------------------------------------
 
@@ -1488,7 +1507,7 @@ class CollectiveEngine:
             store itself — invalidated by the bucket's next mutating op.
         """
         log.check(keep in ("all", "last"), f"bad keep {keep!r}")
-        t0 = time.perf_counter()
+        t0 = stamp()  # stage borders: see _note
         bucket = self._buckets[name]
         resolved, handle_key = self._resolve_handle(handle)
         stateful = self._is_stateful(resolved)
@@ -1498,13 +1517,15 @@ class CollectiveEngine:
         flat = self._flat_replay(
             bucket.padded_len, bucket.dtype, handle_key, stateful, steps
         )
+        prog = self._replay_program(
+            steps, bucket.padded_len, bucket.dtype, handle_key, keep,
+            stateful=stateful, zero_copy=zc,
+        )
+        t1 = stamp()  # select | prep
         g = self._prep_grads_seq(bucket, grads_seq, flat=flat)
-        if stateful:
-            prog = self._replay_program(
-                steps, bucket.padded_len, bucket.dtype, handle_key, keep,
-                stateful=True, zero_copy=zc,
-            )
-            with self._bucket_mu[name]:
+        t2 = stamp()  # prep | launch
+        with self._bucket_mu[name]:
+            if stateful:
                 self._ensure_opt_state(name, resolved, bucket)
                 outs = prog(
                     self._stores[name], *self._opt_states[name], g
@@ -1513,33 +1534,20 @@ class CollectiveEngine:
                 self._stores[name] = outs[0]
                 self._opt_states[name] = tuple(outs[1:1 + n_state])
                 pulled = outs[0] if zc else outs[-1]
-        else:
-            prog = self._replay_program(
-                steps, bucket.padded_len, bucket.dtype, handle_key, keep,
-                stateful=False, zero_copy=zc,
-            )
-            with self._bucket_mu[name]:
-                if zc:
-                    new_store = prog(self._stores[name], g)
-                    pulled = new_store
-                else:
-                    new_store, pulled = prog(self._stores[name], g)
-                self._stores[name] = new_store
-        payload = bucket.total_len * np.dtype(bucket.dtype).itemsize
-        with self._counter_mu:
-            self.push_bytes += payload * steps
-            self.pull_bytes += payload * (steps if keep == "all" else 1)
-        if self.profiler is not None and getattr(
-            self.profiler, "enabled", False
-        ):
-            dur_us = int((time.perf_counter() - t0) * 1e6)
-            nbytes = payload * (steps + (steps if keep == "all" else 1))
-            self.profiler.record_engine(name, "replay", nbytes, dur_us)
-        if zc:
-            return pulled  # aliases the store; padded == total on zc configs
-        if keep == "all":
-            return pulled[:, : bucket.total_len]
-        return pulled[: bucket.total_len]
+            elif zc:
+                pulled = self._stores[name] = prog(self._stores[name], g)
+            else:
+                self._stores[name], pulled = prog(self._stores[name], g)
+            # A zero-copy result aliases the store; padded == total on
+            # zc configs.
+            if not zc:
+                pulled = (pulled[:, : bucket.total_len] if keep == "all"
+                          else pulled[: bucket.total_len])
+        self._observe("push_pull", bucket, pushes=steps,
+                      pulls=steps if keep == "all" else 1)
+        t3 = stamp()
+        self._note((ENGINE_OP, t3, t1 - t0, t2 - t1, t3 - t2))
+        return pulled
 
     def push_pull_stream(self, name: str, grads_iter,
                          handle: Optional[ServerHandle] = None,
@@ -1813,11 +1821,9 @@ class CollectiveEngine:
                 def step(carry, g):
                     store_c, state_c = carry[0], carry[1:]
                     agg = _aggregate([g], axis, waxis)
-                    new_store, new_state = sfn(store_c, tuple(state_c), agg)
-                    out = (
-                        lax.all_gather(new_store, axis, tiled=True)
-                        if keep == "all" else 0.0
-                    )
+                    new_store, new_state = _update(
+                        sfn, store_c, tuple(state_c), agg)
+                    out = _gather(new_store, axis) if keep == "all" else 0.0
                     return (new_store, *new_state), out
 
                 carry, outs = lax.scan(
@@ -1826,7 +1832,7 @@ class CollectiveEngine:
                 if keep == "last":
                     if zero_copy:
                         return carry
-                    outs = lax.all_gather(carry[0], axis, tiled=True)
+                    outs = _gather(carry[0], axis)
                 return (*carry, outs)
 
             tails = () if (keep == "last" and zero_copy) else (
@@ -1847,14 +1853,14 @@ class CollectiveEngine:
 
             def _step_out(new_store):
                 if keep == "all":
-                    return lax.all_gather(new_store, axis, tiled=True)
+                    return _gather(new_store, axis)
                 return 0.0
 
             def _finish(new_store, outs):
                 if keep == "last":
                     if zero_copy:
                         return new_store
-                    outs = lax.all_gather(new_store, axis, tiled=True)
+                    outs = _gather(new_store, axis)
                 return new_store, outs
 
             if flat:
@@ -1876,8 +1882,8 @@ class CollectiveEngine:
 
                     def inner(carry, u_off):
                         g = lax.dynamic_slice(seq, (u_off,), (padded_len,))
-                        new_store = handle(
-                            carry, _aggregate([g], axis, waxis)
+                        new_store = _update(
+                            handle, carry, _aggregate([g], axis, waxis)
                         )
                         return new_store, _step_out(new_store)
 
@@ -1923,7 +1929,8 @@ class CollectiveEngine:
                 def _body(store_l, grads_l):
                     # grads_l: [T, 1, padded] (my worker row per step).
                     def step(carry, g):
-                        new_store = handle(carry, _aggregate([g], axis, waxis))
+                        new_store = _update(
+                            handle, carry, _aggregate([g], axis, waxis))
                         return new_store, _step_out(new_store)
 
                     new_store, outs = lax.scan(step, store_l, grads_l[:, 0])
@@ -1945,9 +1952,7 @@ class CollectiveEngine:
                 check_vma=False,
             )
             jitted = jax.jit(fn, donate_argnums=(0,))
-        with self._mu:
-            self._programs[key] = jitted
-        return jitted
+        return self._keep(key, jitted)
 
     def _replay_ring_program(self, key, padded_len: int, dtype,
                              handle_key, keep: str) -> Callable:
@@ -1985,15 +1990,12 @@ class CollectiveEngine:
             def _body(store_l, grads_l):
                 def step(carry, g):
                     new = shard_fn(carry, g)
-                    out = (
-                        lax.all_gather(new, axis, tiled=True)
-                        if keep == "all" else 0.0
-                    )
+                    out = _gather(new, axis) if keep == "all" else 0.0
                     return new, out
 
                 new_store, outs = lax.scan(step, store_l, grads_l)
                 if keep == "last":
-                    outs = lax.all_gather(new_store, axis, tiled=True)
+                    outs = _gather(new_store, axis)
                 return new_store, outs
 
             grads_spec = P(None, waxis, axis)
@@ -2029,7 +2031,7 @@ class CollectiveEngine:
                 s, outs = lax.scan(step, s, grads_l)
                 s_out = s[:chunk0] if kchunk != chunk0 else s
                 if keep == "last":
-                    outs = lax.all_gather(s_out, axis, tiled=True)
+                    outs = _gather(s_out, axis)
                 return s_out, outs
 
             grads_spec = P(None, axis, None)
@@ -2045,38 +2047,39 @@ class CollectiveEngine:
             check_vma=False,
         )
         jitted = jax.jit(fn, donate_argnums=(0,))
-        with self._mu:
-            self._programs[key] = jitted
-        return jitted
+        return self._keep(key, jitted)
 
     def pull(self, name: str):
-        t0 = time.perf_counter()
+        t0 = stamp()  # stage borders: see _note
         bucket = self._buckets[name]
-        if name in self._pinned_pulls:
-            prog = self._program(
-                "pull_pinned", bucket.padded_len, bucket.dtype,
-                "_pull_pinned",
-            )
-            with self._bucket_mu[name]:
-                # Re-fetch under the lock: a concurrent unregister may
-                # have popped the entry since the unlocked check above.
-                pinned = self._pinned_pulls.get(name)
-                if pinned is not None:
-                    pulled = prog(pinned, self._stores[name])
-                    self._pinned_pulls[name] = pulled
-                    self._observe(name, "pull", bucket, t0)
-                    # Padded length: the caller registered the buffer and
-                    # owns its layout — slicing here would materialize a
-                    # copy and break the address-identity contract.
-                    return pulled
-        prog = self._program("pull", bucket.padded_len, bucket.dtype, "_pull")
+        to_pinned = name in self._pinned_pulls
+        prog = self._program(
+            "pull_pinned" if to_pinned else "pull", bucket.padded_len,
+            bucket.dtype, "_pull_pinned" if to_pinned else "_pull",
+        )
+        t1 = stamp()  # select | launch: a pull prepares nothing
         # Bucket lock: a concurrent push donates the store buffer; reading
         # it unlocked could hand an already-donated array to the pull
         # program.  Dispatch is async, so this only serializes enqueue.
         with self._bucket_mu[name]:
-            pulled = prog(self._stores[name])
-        self._observe(name, "pull", bucket, t0)
-        return pulled[: bucket.total_len]
+            # Re-fetch under the lock: a concurrent unregister may have
+            # popped the entry since the unlocked check above.
+            pinned = self._pinned_pulls.get(name) if to_pinned else None
+            if pinned is not None:
+                # Padded length: the caller registered the buffer and
+                # owns its layout — slicing here would materialize a
+                # copy and break the address-identity contract.
+                pulled = prog(pinned, self._stores[name])
+                self._pinned_pulls[name] = pulled
+            else:
+                if to_pinned:
+                    prog = self._program("pull", bucket.padded_len,
+                                         bucket.dtype, "_pull")
+                pulled = prog(self._stores[name])[: bucket.total_len]
+        self._observe("pull", bucket)
+        t2 = stamp()
+        self._note((ENGINE_OP, t2, t1 - t0, 0, t2 - t1))
+        return pulled
 
     def register_pull_buffer(self, name: str):
         """Pin a persistent pull-output buffer for ``name`` — the

@@ -25,13 +25,13 @@ from __future__ import annotations
 
 import contextlib
 import threading
-import time
 from dataclasses import dataclass
 from typing import Callable, Dict, Optional
 
 import numpy as np
 
 from ..utils import logging as log
+from ..utils.profiling import ENGINE_OP, stage_clock, stamp
 from .placement import staging_xp
 
 
@@ -140,26 +140,34 @@ def _scatter_rows(axis, S, R, pack, dim, store_l, idx_l, grads_l):
     writes the whole table per push (768MB of traffic for a 4096-row
     update on the 1M-row workload); this touches only the updated rows.
     Unowned rows map out of bounds and mode="drop" discards them.
-    Shared by the single-table and group programs."""
+    Shared by the single-table and group programs.
+
+    The sparse bodies carry ``jax.named_scope``s, by which a device trace
+    is read: ``ps.sparse.route`` (indices and rows crossing the workers,
+    and who owns what), ``ps.sparse.push.scatter_add``,
+    ``ps.sparse.pull.gather``, and ``ps.update`` for the optimizer."""
+    import jax
     from jax import lax
     import jax.numpy as jnp
 
-    all_idx = lax.all_gather(idx_l[0], axis, tiled=True)  # [W*n]
-    all_g = lax.all_gather(grads_l[0], axis, tiled=True)  # [W*n, d]
-    my = lax.axis_index(axis)
-    owned = (all_idx % S) == my
-    local = all_idx // S
-    masked = jnp.where(owned[:, None], all_g, 0)
-    if pack == 1:
-        rows = jnp.where(owned, local, R)  # R = out of bounds -> drop
-        return store_l.at[rows].add(masked, mode="drop")
-    phys = jnp.where(owned, local // pack, R // pack)
-    slot = (local % pack).astype(jnp.int32)
-    onehot = (slot[:, None] == jnp.arange(pack, dtype=jnp.int32)[None])
-    packed = (
-        onehot[:, :, None] * masked[:, None, :]
-    ).reshape(all_idx.shape[0], pack * dim)
-    return store_l.at[phys].add(packed, mode="drop")
+    with jax.named_scope("ps.sparse.route"):
+        all_idx = lax.all_gather(idx_l[0], axis, tiled=True)  # [W*n]
+        all_g = lax.all_gather(grads_l[0], axis, tiled=True)  # [W*n, d]
+        my = lax.axis_index(axis)
+        owned = (all_idx % S) == my
+        local = all_idx // S
+        masked = jnp.where(owned[:, None], all_g, 0)
+    with jax.named_scope("ps.sparse.push.scatter_add"):
+        if pack == 1:
+            rows = jnp.where(owned, local, R)  # R = out of bounds -> drop
+            return store_l.at[rows].add(masked, mode="drop")
+        phys = jnp.where(owned, local // pack, R // pack)
+        slot = (local % pack).astype(jnp.int32)
+        onehot = (slot[:, None] == jnp.arange(pack, dtype=jnp.int32)[None])
+        packed = (
+            onehot[:, :, None] * masked[:, None, :]
+        ).reshape(all_idx.shape[0], pack * dim)
+        return store_l.at[phys].add(packed, mode="drop")
 
 
 def _adagrad_rows(store_l, acc_l, G, lr, eps):
@@ -187,59 +195,63 @@ def _adagrad_sparse(axis, S, R, pack, dim, store_l, acc_l, idx_l,
     1-D, and the store step scatter-adds through the packed layout —
     identical numerics to _adagrad_rows on the touched rows, untouched
     rows never read or written."""
+    import jax
     from jax import lax
     import jax.numpy as jnp
 
-    all_idx = lax.all_gather(idx_l[0], axis, tiled=True)   # [m]
-    all_g = lax.all_gather(grads_l[0], axis, tiled=True)   # [m, d]
-    my = lax.axis_index(axis)
-    owned = (all_idx % S) == my
-    local = jnp.where(owned, all_idx // S, R)  # R = sentinel (dropped)
-    m = all_idx.shape[0]
+    with jax.named_scope("ps.sparse.route"):
+        all_idx = lax.all_gather(idx_l[0], axis, tiled=True)   # [m]
+        all_g = lax.all_gather(grads_l[0], axis, tiled=True)   # [m, d]
+        my = lax.axis_index(axis)
+        owned = (all_idx % S) == my
+        local = jnp.where(owned, all_idx // S, R)  # R = sentinel (dropped)
+        m = all_idx.shape[0]
 
-    # Segment-sum duplicates: sort by local row, one segment per unique
-    # row (sentinel rows sort last into their own segments).
-    order = jnp.argsort(local)
-    sr = local[order]
-    sg = jnp.where(owned[order][:, None], all_g[order], 0)
-    first = jnp.concatenate(
-        [jnp.ones((1,), bool), sr[1:] != sr[:-1]]
-    )
-    seg = jnp.cumsum(first) - 1                            # [m]
-    G_seg = jnp.zeros((m, sg.shape[1]), sg.dtype).at[seg].add(sg)
-    # Row of each segment (slots beyond the unique count stay at the
-    # sentinel and scatter harmlessly via drop/zero-G).
-    row_seg = jnp.full((m,), R, jnp.int32).at[seg].set(
-        sr.astype(jnp.int32)
-    )
-    valid = row_seg < R
-
-    # Accumulator: gather the touched rows, apply, scatter back (1-D
-    # logical rows — independent of the store's lane packing).
-    acc_rows = acc_l[jnp.where(valid, row_seg, 0)]
-    g2 = jnp.mean(G_seg.astype(jnp.float32) ** 2, axis=1)
-    acc_new_rows = acc_rows + g2
-    new_acc = acc_l.at[jnp.where(valid, row_seg, R)].set(
-        acc_new_rows, mode="drop"
-    )
-    step = (lr * G_seg.astype(jnp.float32)
-            / (jnp.sqrt(acc_new_rows)[:, None] + eps))
-    step = jnp.where(valid[:, None], step, 0).astype(store_l.dtype)
-
-    # Store: scatter-subtract the step through the (packed) layout.
-    if pack == 1:
-        new_store = store_l.at[jnp.where(valid, row_seg, R)].add(
-            -step, mode="drop"
+    with jax.named_scope("ps.update"):
+        # Segment-sum duplicates: sort by local row, one segment per unique
+        # row (sentinel rows sort last into their own segments).
+        order = jnp.argsort(local)
+        sr = local[order]
+        sg = jnp.where(owned[order][:, None], all_g[order], 0)
+        first = jnp.concatenate(
+            [jnp.ones((1,), bool), sr[1:] != sr[:-1]]
         )
-    else:
-        phys = jnp.where(valid, row_seg // pack, R // pack)
-        slot = (row_seg % pack).astype(jnp.int32)
-        onehot = (slot[:, None]
-                  == jnp.arange(pack, dtype=jnp.int32)[None])
-        packed = (onehot[:, :, None] * (-step)[:, None, :]).reshape(
-            m, pack * dim
+        seg = jnp.cumsum(first) - 1                            # [m]
+        G_seg = jnp.zeros((m, sg.shape[1]), sg.dtype).at[seg].add(sg)
+        # Row of each segment (slots beyond the unique count stay at the
+        # sentinel and scatter harmlessly via drop/zero-G).
+        row_seg = jnp.full((m,), R, jnp.int32).at[seg].set(
+            sr.astype(jnp.int32)
         )
-        new_store = store_l.at[phys].add(packed, mode="drop")
+        valid = row_seg < R
+
+        # Accumulator: gather the touched rows, apply, scatter back (1-D
+        # logical rows — independent of the store's lane packing).
+        acc_rows = acc_l[jnp.where(valid, row_seg, 0)]
+        g2 = jnp.mean(G_seg.astype(jnp.float32) ** 2, axis=1)
+        acc_new_rows = acc_rows + g2
+        new_acc = acc_l.at[jnp.where(valid, row_seg, R)].set(
+            acc_new_rows, mode="drop"
+        )
+        step = (lr * G_seg.astype(jnp.float32)
+                / (jnp.sqrt(acc_new_rows)[:, None] + eps))
+        step = jnp.where(valid[:, None], step, 0).astype(store_l.dtype)
+
+    with jax.named_scope("ps.sparse.push.scatter_add"):
+        # Store: scatter-subtract the step through the (packed) layout.
+        if pack == 1:
+            new_store = store_l.at[jnp.where(valid, row_seg, R)].add(
+                -step, mode="drop"
+            )
+        else:
+            phys = jnp.where(valid, row_seg // pack, R // pack)
+            slot = (row_seg % pack).astype(jnp.int32)
+            onehot = (slot[:, None]
+                      == jnp.arange(pack, dtype=jnp.int32)[None])
+            packed = (onehot[:, :, None] * (-step)[:, None, :]).reshape(
+                m, pack * dim
+            )
+            new_store = store_l.at[phys].add(packed, mode="drop")
     return new_store, new_acc
 
 
@@ -249,34 +261,38 @@ def _pull_rows(axis, S, store_l, idx_l, pack: int = 1, dim: int = None):
     worker dimension.  Shared single/group; packed stores gather the
     128-lane physical row and select the logical slot (see
     SparseTable.pack)."""
+    import jax
     from jax import lax
     import jax.numpy as jnp
 
-    all_idx = lax.all_gather(idx_l[0], axis, tiled=True)  # [W*n]
-    my = lax.axis_index(axis)
-    owned = (all_idx % S) == my
-    local = all_idx // S
-    if pack == 1:
-        rows = store_l[jnp.where(owned, local, 0)]  # [W*n, d]
-        d = store_l.shape[1]
-    else:
-        d = dim
-        m = all_idx.shape[0]
-        phys = store_l[jnp.where(owned, local // pack, 0)]  # [W*n, 128]
-        slot = (local % pack).astype(jnp.int32)
-        rows = jnp.take_along_axis(
-            phys.reshape(m, pack, d), slot[:, None, None], axis=1
-        )[:, 0]
-    vals = jnp.where(owned[:, None], rows, 0)
-    vals = vals.reshape(S, -1, d)  # [W, n, d]
-    return lax.psum_scatter(vals, axis, scatter_dimension=0,
-                            tiled=True)[0]  # [n, d] for my indices
+    with jax.named_scope("ps.sparse.route"):
+        all_idx = lax.all_gather(idx_l[0], axis, tiled=True)  # [W*n]
+        my = lax.axis_index(axis)
+        owned = (all_idx % S) == my
+        local = all_idx // S
+    with jax.named_scope("ps.sparse.pull.gather"):
+        if pack == 1:
+            rows = store_l[jnp.where(owned, local, 0)]  # [W*n, d]
+            d = store_l.shape[1]
+        else:
+            d = dim
+            m = all_idx.shape[0]
+            phys = store_l[jnp.where(owned, local // pack, 0)]  # [W*n, 128]
+            slot = (local % pack).astype(jnp.int32)
+            rows = jnp.take_along_axis(
+                phys.reshape(m, pack, d), slot[:, None, None], axis=1
+            )[:, 0]
+        vals = jnp.where(owned[:, None], rows, 0)
+        vals = vals.reshape(S, -1, d)  # [W, n, d]
+    with jax.named_scope("ps.sparse.route"):
+        return lax.psum_scatter(vals, axis, scatter_dimension=0,
+                                tiled=True)[0]  # [n, d] for my indices
 
 
 class SparseEngine:
     """Sparse tables on the same mesh/axis as a CollectiveEngine."""
 
-    def __init__(self, mesh, axis_name: str = "kv", profiler=None):
+    def __init__(self, mesh, axis_name: str = "kv"):
         from .placement import local_shard_count, mesh_is_multiprocess
 
         self.mesh = mesh
@@ -287,8 +303,13 @@ class SparseEngine:
             local_shard_count(mesh) if self._multiprocess
             else self.num_shards
         )
-        # Observability mirroring CollectiveEngine (van.cc:29-77 analog).
-        self.profiler = profiler
+        # Observability mirroring CollectiveEngine: byte counters, and
+        # host time per stage of an op on the process's StageClock.
+        self._clock = stage_clock()
+        # An op notes (ENGINE_OP, t_end, select ns, prep ns, launch ns): one
+        # C call (see StageClock).  The sparse stages run prep, select,
+        # launch: the program is chosen under the table's lock.
+        self._note = self._clock.note
         self.push_bytes = 0
         self.pull_bytes = 0
         self._counter_mu = threading.Lock()
@@ -346,6 +367,14 @@ class SparseEngine:
             self._stores[name] = store
             self._table_mu.setdefault(name, threading.Lock())
         return table
+
+    def _keep(self, key, prog):
+        """Cache a program a lookup missed; the misses are counted here,
+        off the hot path (the hits are the ops less the misses)."""
+        with self._mu:
+            self._programs[key] = prog
+        self._clock.program_built()
+        return prog
 
     def _sparse_program(self, op: str, table: SparseTable, batch: int):
         key = (op, table.name, batch, table.pack)
@@ -442,9 +471,7 @@ class SparseEngine:
             jitted = jax.jit(fn)
         else:
             raise ValueError(op)
-        with self._mu:
-            self._programs[key] = jitted
-        return jitted
+        return self._keep(key, jitted)
 
     def _is_multiprocess(self) -> bool:
         return self._multiprocess
@@ -499,8 +526,7 @@ class SparseEngine:
         g_sh = jax.device_put(g, g_sharding)
         return idx_sh, g_sh
 
-    def _observe(self, name: str, op: str, table: SparseTable,
-                 batch: int, t0: float) -> None:
+    def _observe(self, op: str, table: SparseTable, batch: int) -> None:
         payload = (
             self.num_shards * batch * table.dim
             * np.dtype(table.dtype).itemsize
@@ -510,22 +536,18 @@ class SparseEngine:
                 self.push_bytes += payload
             else:
                 self.pull_bytes += payload
-        if self.profiler is not None and getattr(
-            self.profiler, "enabled", False
-        ):
-            dur_us = int((time.perf_counter() - t0) * 1e6)
-            self.profiler.record_engine(name, f"sparse_{op}", payload,
-                                        dur_us)
 
     def _ensure_acc(self, name: str, table: SparseTable) -> None:
         if name in self._acc:
             return
         from jax.sharding import NamedSharding, PartitionSpec as P
 
+        t0 = stamp()
         self._acc[name] = self._place(
             np.zeros(table.rows_per_shard * self.num_shards, np.float32),
             NamedSharding(self.mesh, P(self.axis)),
         )
+        self._clock.state_created(stamp() - t0)
 
     def ensure_acc(self, name: str) -> None:
         """Create the (zero) Adagrad accumulator for a registered table —
@@ -641,32 +663,35 @@ class SparseEngine:
         updates a per-row accumulator, and the row steps by
         ``-lr * G / (sqrt(acc) + eps)`` — the fused sparse analog of the
         dense engine's optimizer handles."""
-        t0 = time.perf_counter()
+        t0 = stamp()  # stage borders: see _note
         table = self._tables[name]
         idx, g = self._prep(table, indices, grads)
         batch = int(idx.shape[1])
-        if handle is None:
-            with self._table_mu[name]:
-                # Program selection reads table.pack, which the orbax
-                # compat shim can mutate — resolve it under the lock.
-                prog = self._sparse_program("push", table, batch)
-                new_store, token = prog(self._stores[name], idx, g)
-                self._stores[name] = new_store
-        else:
-            import jax.numpy as jnp
+        t1 = stamp()  # prep | select
+        # Program selection reads table.pack, which the orbax compat shim
+        # can mutate — resolve it under the lock (so the sparse stages run
+        # prep, select, launch, and select has the wait for the lock).
+        with self._table_mu[name]:
+            prog = self._sparse_program(
+                "push" if handle is None else "push_row_adagrad",
+                table, batch,
+            )
+            t2 = stamp()  # select | launch
+            if handle is None:
+                self._stores[name], token = prog(
+                    self._stores[name], idx, g)
+            else:
+                import jax.numpy as jnp
 
-            _, (lr, eps) = self._parse_handle(handle)
-            with self._table_mu[name]:
-                prog = self._sparse_program("push_row_adagrad", table,
-                                            batch)
+                _, (lr, eps) = self._parse_handle(handle)
                 self._ensure_acc(name, table)
-                new_store, new_acc, token = prog(
+                self._stores[name], self._acc[name], token = prog(
                     self._stores[name], self._acc[name], idx, g,
                     jnp.float32(lr), jnp.float32(eps),
                 )
-                self._stores[name] = new_store
-                self._acc[name] = new_acc
-        self._observe(name, "push", table, batch, t0)
+        self._observe("push", table, batch)
+        t3 = stamp()
+        self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
         # The token is a tiny non-donated output that becomes ready when
         # the push completes — block on it freely (the store itself is
         # donated by the next push, so it must not escape).
@@ -781,9 +806,7 @@ class SparseEngine:
             jitted = jax.jit(fn)
         else:
             raise ValueError(op)
-        with self._mu:
-            self._programs[key] = jitted
-        return jitted
+        return self._keep(key, jitted)
 
     def _lock_tables(self, names):
         ordered = sorted(set(names))
@@ -803,7 +826,7 @@ class SparseEngine:
                   "group length mismatch")
         log.check(len(set(names)) == len(names),
                   "duplicate table in group (stores are donated)")
-        t0 = time.perf_counter()
+        t0 = stamp()  # stage borders: see _note
         tables = [self._tables[n] for n in names]
         prepped = [
             self._prep(t, i, g)
@@ -812,21 +835,25 @@ class SparseEngine:
         idxs = [p[0] for p in prepped]
         gs = [p[1] for p in prepped]
         batches = tuple(int(i.shape[1]) for i in idxs)
+        t1 = stamp()  # prep | select
         ordered = self._lock_tables(names)
         try:
+            prog = self._sparse_group_program(
+                "push" if handle is None else "push_row_adagrad",
+                tables, batches,
+            )
+            t2 = stamp()  # select | launch
+            kk = len(names)
             if handle is None:
-                prog = self._sparse_group_program("push", tables, batches)
-                outs = prog(*[self._stores[n] for n in names], *idxs, *gs)
+                outs = prog(*[self._stores[n] for n in names],
+                            *idxs, *gs)
                 for i, n in enumerate(names):
                     self._stores[n] = outs[i]
-                token = outs[len(names)]
+                token = outs[kk]
             else:
                 import jax.numpy as jnp
 
                 _, (lr, eps) = self._parse_handle(handle)
-                prog = self._sparse_group_program(
-                    "push_row_adagrad", tables, batches
-                )
                 for n, t in zip(names, tables):
                     self._ensure_acc(n, t)
                 outs = prog(
@@ -834,55 +861,65 @@ class SparseEngine:
                     *[self._acc[n] for n in names],
                     *idxs, *gs, jnp.float32(lr), jnp.float32(eps),
                 )
-                kk = len(names)
                 for i, n in enumerate(names):
                     self._stores[n] = outs[i]
                     self._acc[n] = outs[kk + i]
                 token = outs[2 * kk]
         finally:
             self._unlock_tables(ordered)
-        for i, (n, t) in enumerate(zip(names, tables)):
-            # One dispatch: attribute latency to the first table only so
-            # summed profiler durations aren't inflated k-fold.
-            self._observe(n, "push", t, batches[i],
-                          t0 if i == 0 else time.perf_counter())
+        t3 = stamp()
+        # One op with one launch, whatever it groups.
+        self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
+        for t, batch in zip(tables, batches):
+            self._observe("push", t, batch)
         return token
 
     def pull_group(self, names, indices_list):
         """Pull SEVERAL tables in one dispatch; returns the list of
         [W, n_i, d_i] arrays in ``names`` order."""
         log.check(len(names) == len(indices_list), "group length mismatch")
-        t0 = time.perf_counter()
+        t0 = stamp()  # stage borders: see _note
         tables = [self._tables[n] for n in names]
-        idxs = [self._prep(t, i)[0] for t, i in zip(tables, indices_list)]
+        idxs = [self._prep(t, i)[0]
+                for t, i in zip(tables, indices_list)]
         batches = tuple(int(i.shape[1]) for i in idxs)
+        t1 = stamp()  # prep | select
         ordered = self._lock_tables(names)
         try:
             # Resolve table.pack under the locks (see push).
             prog = self._sparse_group_program("pull", tables, batches)
+            t2 = stamp()  # select | launch
             outs = prog(*[self._stores[n] for n in names], *idxs)
+            pulled = [
+                o.reshape(self.num_shards, -1, t.dim)
+                for o, t in zip(outs, tables)
+            ]
         finally:
             self._unlock_tables(ordered)
-        for i, (n, t) in enumerate(zip(names, tables)):
-            self._observe(n, "pull", t, batches[i],
-                          t0 if i == 0 else time.perf_counter())
-        return [
-            o.reshape(self.num_shards, -1, t.dim)
-            for o, t in zip(outs, tables)
-        ]
+        for t, batch in zip(tables, batches):
+            self._observe("pull", t, batch)
+        t3 = stamp()
+        self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
+        return pulled
 
     def pull(self, name: str, indices):
         """indices: [W, n] -> [W, n, d] rows, each worker shard receiving its
         own batch."""
-        t0 = time.perf_counter()
+        t0 = stamp()  # stage borders: see _note
         table = self._tables[name]
         idx, _ = self._prep(table, indices)
+        batch = int(idx.shape[1])
+        t1 = stamp()  # prep | select
         with self._table_mu[name]:
             # Resolve table.pack under the lock (see push).
-            prog = self._sparse_program("pull", table, int(idx.shape[1]))
+            prog = self._sparse_program("pull", table, batch)
+            t2 = stamp()  # select | launch
             out = prog(self._stores[name], idx)  # global [W*n, d]
-        self._observe(name, "pull", table, int(idx.shape[1]), t0)
-        return out.reshape(self.num_shards, -1, table.dim)
+            pulled = out.reshape(self.num_shards, -1, table.dim)
+        self._observe("pull", table, batch)
+        t3 = stamp()
+        self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
+        return pulled
 
     def store_array(self, name: str):
         """A consistent snapshot of the sharded table in the LOGICAL

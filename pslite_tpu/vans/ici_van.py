@@ -83,21 +83,25 @@ class _IciDataPlane:
         if self.engine is None and self.po.is_worker:
             from ..parallel.engine import CollectiveEngine
             from ..parallel.sparse import SparseEngine
-            from ..utils.compile_cache import enable_compile_cache
+            from ..utils import compile_cache
+            from ..utils.profiling import stage_clock
 
-            enable_compile_cache()
+            compile_cache.enable_compile_cache()
             handle = self.env.find("PS_ICI_SERVER_HANDLE", "sum")
-            # Share the van's profiler so ENABLE_PROFILING covers the
-            # collective data plane (reference: van.cc:29-77,440-457).
             self.engine = CollectiveEngine(
                 mesh=self._make_mesh(), server_handle=handle,
-                profiler=self.profiler,
                 impl=self.env.find("PS_ICI_IMPL", None),
             )
             self.sparse_engine = SparseEngine(
                 self.engine.mesh, self.engine.axis,
-                profiler=self.profiler,
             )
+            # The engine path's host stages and cache counters, beside
+            # the message plane's instruments (docs/observability.md).
+            metrics = self.po.metrics
+            stage_clock().export(metrics)
+            counts = compile_cache.cache_counts
+            metrics.gauge("compile_cache.hits", fn=lambda: counts[0])
+            metrics.gauge("compile_cache.misses", fn=lambda: counts[1])
 
     def reshard_engines(self, mesh, customer_id: int = 0) -> None:
         """Cluster-coordinated elastic recut — the roster-level trigger
