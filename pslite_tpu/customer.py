@@ -208,7 +208,10 @@ class Customer:
 
         Hooks must be idempotent (e.g. ``Future.result``): they run on
         *every* wait of the timestamp so concurrent waiters all observe
-        completion.  Entries are evicted FIFO beyond a bounded window."""
+        completion.  Entries are evicted FIFO beyond a bounded window of
+        ``_MAX_HOOK_ENTRIES`` timestamps, so a hook that holds something
+        large (a device array) may drop it after its first run, as long
+        as later runs still return only once the work is complete."""
         with self._mu:
             self._hooks.setdefault(timestamp, []).append(hook)
             while len(self._hooks) > self._MAX_HOOK_ENTRIES:

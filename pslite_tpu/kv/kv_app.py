@@ -28,6 +28,7 @@ import threading
 import time
 import zlib
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -548,6 +549,9 @@ class KVWorker:
         # and per-ts trace bookkeeping for the distributed spans.
         self._c_pushes = self.po.metrics.counter("kv.pushes")
         self._c_pulls = self.po.metrics.counter("kv.pulls")
+        # Engine-path ops completed on the kv-engine-complete thread (they
+        # carried ``out`` or ``callback``); the others complete in wait().
+        self._c_threaded = self.po.metrics.counter("kv.complete.threaded")
         self._h_push_lat = self.po.metrics.histogram("kv.push_latency_s")
         self._h_pull_lat = self.po.metrics.histogram("kv.pull_latency_s")
         self._c_timeouts = self.po.metrics.counter("kv.timeouts")
@@ -845,11 +849,17 @@ class KVWorker:
         log.check(self.engine is not None,
                   "register_dense requires the ici van")
         keys = np.ascontiguousarray(np.asarray(keys, dtype=np.uint64))
-        bucket = self.engine.register_dense(name, keys, val_len, dtype=dtype,
-                                            init=init)
-        self._dense_routes[
-            (len(keys), int(keys[0]), int(keys[-1]))
-        ] = name
+        engine = self.engine
+        old = engine._buckets.get(name)
+        if old is not None:
+            # A name registered again under other keys: its old signature
+            # must not route to it (one- and two-key sets are not
+            # compared, see _engine_route).
+            self._dense_routes.pop(
+                (len(old.keys), old.keys.item(0), old.keys.item(-1)), None)
+        bucket = engine.register_dense(name, keys, val_len, dtype=dtype,
+                                       init=init)
+        self._dense_routes[(len(keys), keys.item(0), keys.item(-1))] = name
         return bucket
 
     def reshard(self, mesh) -> None:
@@ -881,15 +891,19 @@ class KVWorker:
         """Bucket name iff these exact keys are registered and the request
         carries nothing the collective path cannot express (custom cmd,
         variable lens fall back to the message path)."""
-        if self.engine is None or len(keys) == 0:
+        engine = self.engine
+        n = len(keys)
+        if engine is None or n == 0:
             return None
         if cmd != 0 or lens is not None:
             return None
-        name = self._dense_routes.get((len(keys), int(keys[0]), int(keys[-1])))
+        name = self._dense_routes.get((n, keys.item(0), keys.item(-1)))
         if name is None:
             return None
-        if not np.array_equal(self.engine.bucket(name).keys, keys):
-            return None  # same signature, different key set
+        # Of one or two keys the signature (len, first, last) is the key
+        # set; a longer set can share it and differ in between.
+        if n > 2 and not np.array_equal(engine.bucket(name).keys, keys):
+            return None
         return name
 
     _MAX_DEVICE_RESULTS = 8
@@ -912,9 +926,16 @@ class KVWorker:
         chip's host a Python call costs this path 2-3 us, a keyword call
         half a microsecond more (PERF.md, PR 24).
 
-        Completion (device done -> host copy -> callback) runs on a
-        dedicated thread so callbacks fire without wait(), matching the
-        message path; wait(ts) joins the same future (idempotent hook).
+        Completion, by what the call itself carries.  With ``out`` or
+        ``callback`` (and for a pinned pull, whose completion the next
+        pull joins): device done -> host copy -> callback run on the
+        dedicated ``kv-engine-complete`` thread, so callbacks fire
+        without wait() and the D2H copy stays off the issuing thread,
+        matching the message path; wait(ts) joins that future, and the op
+        counts in ``kv.complete.threaded``.  With neither there is
+        nothing for a thread to do but block, so none is woken: the wait
+        hook (``_engine_ready``) blocks on the result itself, on the
+        waiting thread, and lets go of the array after its first run.
 
         The op's result must be a NON-donated array: pushes hand back a
         tiny completion token (the store itself is donated by the next
@@ -935,6 +956,8 @@ class KVWorker:
             name = self._engine_route(
                 np.asarray(keys, dtype=np.uint64), cmd, lens)
             if name is None:
+                if span is not None:
+                    span.__exit__(None, None, None)
                 return None
             args = (name, *args)
             route_ns = stamp() - t0
@@ -944,30 +967,46 @@ class KVWorker:
         pinned = pull and self.engine.pinned_pull_buffer(name) is not None
         if pull:
             keep_result = not pinned
-        ts = self._customer.new_request(SERVER_GROUP, num_responses=0)
-        with self._mu:
-            if self._engine_pool is None:
-                import concurrent.futures
-
-                self._engine_pool = concurrent.futures.ThreadPoolExecutor(
-                    max_workers=1, thread_name_prefix="kv-engine-complete"
-                )
-            if keep_result:
+        ts = self._customer.new_request(SERVER_GROUP, 0)
+        if keep_result:
+            with self._mu:
                 self._device_results[ts] = result
                 while len(self._device_results) > self._MAX_DEVICE_RESULTS:
                     self._device_results.pop(next(iter(self._device_results)))
-        fut = self._engine_pool.submit(
-            self._engine_complete, ts, name, result, out, callback
-        )
-        if pinned:
-            self._pinned_pull_futs[name] = fut.result
-        self._customer.add_wait_hook(ts, fut.result)
+        if out is None and callback is None and not pinned:
+            hook = partial(self._engine_ready, ts, name, [result],
+                           threading.Lock())
+        else:
+            with self._mu:
+                if self._engine_pool is None:
+                    import concurrent.futures
+
+                    self._engine_pool = concurrent.futures.ThreadPoolExecutor(
+                        1, "kv-engine-complete")
+            hook = self._engine_pool.submit(
+                self._engine_complete, ts, name, result, out, callback
+            ).result
+            self._c_threaded.inc()
+            if pinned:
+                self._pinned_pull_futs[name] = hook
+        self._customer.add_wait_hook(ts, hook)
         t3 = stamp()
         self._note((KV_OP, t3, route_ns, t3 - t2, -1))
         if span is not None:
             span.set_metadata(ts=ts, name=name)
             span.__exit__(None, None, None)
         return ts
+
+    def _engine_ready(self, ts: int, name: str, box: list, lock) -> None:
+        """Wait hook of an op with nothing to copy and no callback: the
+        first wait blocks on the result where it stands and lets go of
+        the array (the customer keeps its last 256 hooks, which must not
+        pin 256 pulled buffers); a wait meanwhile returns when the first
+        has, a later one at once."""
+        with lock:
+            if box:
+                self._engine_complete(ts, name, box[0], None, None)
+                box.clear()
 
     def _engine_pull(self, name: str):
         """``engine.pull`` under the registered-buffer contract
@@ -982,10 +1021,11 @@ class KVWorker:
         return self.engine.pull(name)
 
     def _engine_complete(self, ts: int, name: str, result, out, callback):
-        """On the ``kv-engine-complete`` thread: stage ``complete.wait``
-        (blocked on the device), then ``complete.copy`` (host work), each
-        in a span that carries the op's ``ts`` while a profiler session
-        runs."""
+        """On the ``kv-engine-complete`` thread, or on the waiting thread
+        for an op that has neither ``out`` nor ``callback``
+        (``_engine_ready``): stage ``complete.wait`` (blocked on the
+        device), then ``complete.copy`` (host work), each in a span that
+        carries the op's ``ts`` while a profiler session runs."""
         traced = tracing()
         t0 = stamp()
         span = (TraceAnnotation(COMPLETE_SPANS[0], ts=ts, name=name)
@@ -1026,7 +1066,7 @@ class KVWorker:
             span.__exit__(None, None, None)
         t2 = stamp()
         self._note((COMPLETED, t2, t1 - t0, t2 - t1, -1))
-        if not ts & 1023:  # now and then, and off the issuing thread
+        if not ts & 1023:  # now and then, and not while an op is issued
             self._stage_clock.fold()
 
     def get_pulled(self, ts: int):

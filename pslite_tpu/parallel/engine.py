@@ -30,7 +30,7 @@ from __future__ import annotations
 import contextlib
 import os
 import threading
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from functools import partial
 from typing import Callable, Dict, Optional, Union
 
@@ -56,9 +56,32 @@ class DenseBucket:
     dtype: object
     total_len: int  # len(keys) * val_len
     padded_len: int  # rounded up to a multiple of the mesh axis size
+    # Application bytes one push (or one pull) moves: the byte counters' unit.
+    nbytes: int = field(init=False)
+
+    def __post_init__(self):
+        self.nbytes = self.total_len * np.dtype(self.dtype).itemsize
 
 
 ServerHandle = Union[str, Callable]
+
+
+@dataclass(eq=False, slots=True)
+class _BoundOp:
+    """What every ``push_pull`` (or ``push``) of one bucket under one
+    handle works out the same way, worked out once by
+    ``CollectiveEngine._bind``.  Invariants only: never a store, a state
+    array or a gradient."""
+
+    op: str  # "push_pull" or "push"
+    bucket: DenseBucket
+    lock: threading.Lock  # the bucket's write lock
+    prog: Callable
+    prep: Callable  # one of _prep_grads / _prep_grads_flat / _prep_grads_ring
+    sharding: object  # what ``prep`` delivers (and passes through as is)
+    state_kind: Optional[str]  # the optimizer state's kind; None: stateless
+    zc: bool  # in-place pull delivery
+    cut: bool  # the pulled array carries padding to slice off
 
 
 def _pad_ring_chunks(g, s, kchunk: int, chunk0: int):
@@ -259,6 +282,9 @@ class CollectiveEngine:
         # same HBM buffer every time via donation of the previous output.
         self._pinned_pulls: Dict[str, object] = {}
         self._programs: Dict[tuple, Callable] = {}
+        # (name, handle, zero_copy; None: push) -> see _bind.  Dropped
+        # wherever _programs is, and for a name that is registered again.
+        self._bound: Dict[tuple, _BoundOp] = {}
         self._mu = threading.Lock()
         # Per-bucket write locks: the jitted programs donate the store
         # buffer, so the load-run-store sequence must be atomic per bucket
@@ -326,6 +352,8 @@ class CollectiveEngine:
             self._buckets[name] = bucket
             self._stores[name] = store
             self._bucket_mu.setdefault(name, threading.Lock())
+            for key in [k for k in self._bound if k[0] == name]:
+                del self._bound[key]
         return bucket
 
     def bucket(self, name: str) -> DenseBucket:
@@ -858,8 +886,8 @@ class CollectiveEngine:
         return self._keep(key, jitted)
 
     def _ensure_opt_state(self, name: str, handle: str, bucket) -> None:
-        """Allocate (or validate) the bucket's optimizer state.  Call with
-        the bucket lock held."""
+        """Allocate (or validate) the bucket's optimizer state for
+        ``handle`` (or its kind alone).  Call with the bucket lock held."""
         kind = handle.split(":", 1)[0]
         have = self._opt_kinds.get(name)
         if have == kind:
@@ -1015,16 +1043,28 @@ class CollectiveEngine:
             arr = xp.pad(arr, pads)
         return arr
 
-    def _prep_grads_flat(self, bucket: DenseBucket, grads):
+    def _grads_sharding(self, flat: bool):
+        """What a prep delivers: ``[W, padded]`` rows over the worker
+        axis (``_prep_grads``), or the FLAT forms' ``P(axis)``.  A bound
+        op hands its prep the one its record holds."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        if flat:
+            return NamedSharding(self.mesh, P(self.axis))
+        if self.worker_axis is not None:
+            return NamedSharding(self.mesh, P(self.worker_axis, self.axis))
+        return NamedSharding(self.mesh, P(self.axis, None))
+
+    def _prep_grads_flat(self, bucket: DenseBucket, grads, sharding=None):
         """``[padded]`` FLAT grads for the degenerate 1-worker zero-copy
         program (see ``_push_pull_zc``'s flat_zc note): host arrays
         flatten for free; device ``[1, padded]`` arrays pay one reshape
         per call (a bitcast for f32, a relayout copy for packed dtypes
         — pass flat device arrays on the hot path)."""
         import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
 
-        sharding = NamedSharding(self.mesh, P(self.axis))
+        if sharding is None:
+            sharding = self._grads_sharding(True)
         if isinstance(grads, jax.Array):
             # Same worker-dim discipline as _prep_grads: a (2, N/2)
             # array must fail loud, not silently flatten into one
@@ -1047,7 +1087,7 @@ class CollectiveEngine:
             np.ascontiguousarray(arr).reshape(-1), sharding
         )
 
-    def _prep_grads_ring(self, bucket: DenseBucket, grads):
+    def _prep_grads_ring(self, bucket: DenseBucket, grads, sharding=None):
         """``[W*padded]`` FLAT grads, sharded ``P(axis)``, for the
         single-bucket 1-D fused ring programs.
 
@@ -1063,9 +1103,9 @@ class CollectiveEngine:
         flat device arrays on the hot path, as with _prep_grads_flat).
         """
         import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
 
-        sharding = NamedSharding(self.mesh, P(self.axis))
+        if sharding is None:
+            sharding = self._grads_sharding(True)
         W = self.num_shards
         flat_len = W * bucket.padded_len
         if isinstance(grads, jax.Array):
@@ -1093,7 +1133,7 @@ class CollectiveEngine:
             np.ascontiguousarray(arr).reshape(-1), sharding
         )
 
-    def _prep_grads(self, bucket: DenseBucket, grads):
+    def _prep_grads(self, bucket: DenseBucket, grads, sharding=None):
         """Accept [W, total] (or [total] broadcast) host/device arrays and
         deliver a [W, padded] device array sharded over the worker axis.
 
@@ -1109,22 +1149,20 @@ class CollectiveEngine:
           there is no per-process row ownership to map a local
           contribution onto."""
         import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
 
-        if self.worker_axis is not None:
-            sharding = NamedSharding(
-                self.mesh, P(self.worker_axis, self.axis)
-            )
-        else:
-            sharding = NamedSharding(self.mesh, P(self.axis, None))
+        if sharding is None:
+            sharding = self._grads_sharding(False)
         if isinstance(grads, jax.Array) and grads.ndim == 2:
-            if grads.shape[1] == bucket.padded_len:
+            shape = grads.shape
+            if shape[1] == bucket.padded_len:
                 # Row count must match the worker fan-in exactly — a
                 # silent reshard would drop rows (the shard body reads
                 # one local row per device position).
-                log.check_eq(int(grads.shape[0]), self.num_workers,
-                             "bad worker dim")
-                if grads.sharding == sharding:
+                if shape[0] != self.num_workers:
+                    log.check_eq(int(shape[0]), self.num_workers,
+                                 "bad worker dim")
+                have = grads.sharding
+                if have is sharding or have == sharding:
                     return grads
                 return jax.device_put(grads, sharding)
         if self.worker_axis is not None:
@@ -1157,7 +1195,7 @@ class CollectiveEngine:
         """Account one data-plane op in the byte counters.  (Its host
         time is the StageClock's; device time is a ``jax.profiler``
         trace's, see ``utils.profiling.device_trace``.)"""
-        payload = bucket.total_len * np.dtype(bucket.dtype).itemsize
+        payload = bucket.nbytes
         with self._counter_mu:
             if op in ("push", "push_pull"):
                 self.push_bytes += payload * pushes
@@ -1206,8 +1244,57 @@ class CollectiveEngine:
                 and not self._is_stateful(resolved)
                 and self.worker_axis is None)
 
+    def _bind(self, name: str, handle: Optional[ServerHandle],
+              zero_copy: Optional[bool]) -> _BoundOp:
+        """Stage ``select`` of the first ``push_pull(name, ., handle,
+        zero_copy)`` (``zero_copy`` None: of the first ``push``), and of
+        the first after a ``reshard`` or a new registration of ``name``
+        dropped the record: the handle resolved, zero-copy and ring
+        eligibility, the program and the prep that goes with it."""
+        mesh, bucket = self.mesh, self._buckets[name]
+        resolved, handle_key = self._resolve_handle(handle)
+        push = zero_copy is None
+        zc = bool(zero_copy) and self._zc_pull_eligible(bucket.dtype,
+                                                        resolved)
+        # Resolved for every record: it says once why "pallas" runs XLA.
+        impl = self._effective_impl(bucket.dtype, resolved)
+        stateful = self._is_stateful(resolved)
+        prep = self._prep_grads
+        if stateful:
+            op = ("push_st" if push
+                  else "push_pull_st_zc" if zc else "push_pull_st")
+            prog = self._program(op, bucket.padded_len, bucket.dtype,
+                                 handle_key)
+        elif impl == "pallas":
+            if self.worker_axis is None:
+                prep = self._prep_grads_ring
+            prog = self._ring_program_op(
+                "push" if push else "push_pull",
+                bucket.padded_len, bucket.dtype, handle_key
+            )
+        else:
+            if zc and self.flat_zc_eligible(handle):
+                prep = self._prep_grads_flat
+            op = "push" if push else "push_pull_zc" if zc else "push_pull"
+            prog = self._program(op, bucket.padded_len, bucket.dtype,
+                                 handle_key)
+        bound = _BoundOp(
+            op="push" if push else "push_pull", bucket=bucket,
+            lock=self._bucket_mu[name], prog=prog, prep=prep,
+            sharding=self._grads_sharding(prep != self._prep_grads),
+            state_kind=resolved.split(":", 1)[0] if stateful else None,
+            zc=zc, cut=not (push or zc
+                            or bucket.padded_len == bucket.total_len),
+        )
+        with self._mu:
+            # A reshard or a new registration meanwhile: the next op binds.
+            if self.mesh is mesh and self._buckets.get(name) is bucket:
+                self._bound[(name, handle, zero_copy)] = bound
+        self._clock.op_bound()
+        return bound
+
     def push_pull(self, name: str, grads, handle: Optional[ServerHandle] = None,
-                  zero_copy: bool = False):
+                  zero_copy: Optional[bool] = False):
         """Fused push+aggregate+update+pull; returns the replicated pulled
         array (async).  The benchmark hot path (SURVEY §3.2).
 
@@ -1219,100 +1306,49 @@ class CollectiveEngine:
         torn data).  Same caller contract as the reference's
         RegisterRecvBuffer pulls (the next pull overwrites the registered
         buffer in place).  Configs the in-place path cannot serve fall
-        back to the copying path transparently."""
+        back to the copying path transparently.
+
+        Bound once, launched many times: what no two ops of ``(name,
+        handle, zero_copy)`` differ in is a :class:`_BoundOp` that the
+        first op builds (:meth:`_bind`) and the others look up; an op
+        then checks the gradient it was handed, takes the bucket's lock,
+        calls the program on the current store and state and rebinds
+        them.  ``reshard`` drops every record, registering ``name`` again
+        drops that bucket's.  (``zero_copy=None`` is :meth:`push`.)"""
         t0 = stamp()  # stage borders: see _note
-        bucket = self._buckets[name]
-        resolved, handle_key = self._resolve_handle(handle)
-        zc = zero_copy and self._zc_pull_eligible(bucket.dtype, resolved)
-        # Resolved for every op: it says once why "pallas" runs XLA.
-        impl = self._effective_impl(bucket.dtype, resolved)
-        stateful = self._is_stateful(resolved)
-        if stateful:
-            prep = self._prep_grads
-            prog = self._program(
-                "push_pull_st_zc" if zc else "push_pull_st",
-                bucket.padded_len, bucket.dtype, handle_key
-            )
-        elif impl == "pallas":
-            prep = (self._prep_grads_ring if self.worker_axis is None
-                    else self._prep_grads)
-            prog = self._ring_program(
-                bucket.padded_len, bucket.dtype, handle_key
-            )
-        else:
-            prep = (self._prep_grads_flat
-                    if zc and self.flat_zc_eligible(handle)
-                    else self._prep_grads)
-            prog = self._program(
-                "push_pull_zc" if zc else "push_pull",
-                bucket.padded_len, bucket.dtype, handle_key
-            )
+        b = (self._bound.get((name, handle, zero_copy))
+             or self._bind(name, handle, zero_copy))
         t1 = stamp()  # select | prep
-        g = prep(bucket, grads)
+        g = b.prep(b.bucket, grads, b.sharding)
         t2 = stamp()  # prep | launch
-        with self._bucket_mu[name]:
-            if stateful:
-                self._ensure_opt_state(name, resolved, bucket)
-                outs = prog(
+        with b.lock:
+            if b.state_kind is not None:
+                if self._opt_kinds.get(name) != b.state_kind:
+                    self._ensure_opt_state(name, b.state_kind, b.bucket)
+                outs = b.prog(
                     self._stores[name], *self._opt_states[name], g
                 )
                 n_state = len(self._opt_states[name])
                 self._stores[name] = outs[0]
                 self._opt_states[name] = tuple(outs[1:1 + n_state])
-                pulled = outs[0] if zc else outs[-1]
-            elif zc:
-                pulled = self._stores[name] = prog(self._stores[name], g)
+                pulled = outs[0] if b.zc else outs[-1]
+            elif b.zc:
+                pulled = self._stores[name] = b.prog(self._stores[name], g)
             else:
-                self._stores[name], pulled = prog(self._stores[name], g)
-            if not zc:
-                pulled = pulled[: bucket.total_len]
-        self._observe("push_pull", bucket)
+                self._stores[name], pulled = b.prog(self._stores[name], g)
+            if b.cut:
+                pulled = pulled[: b.bucket.total_len]
+        self._observe(b.op, b.bucket)
         t3 = stamp()
         self._note((ENGINE_OP, t3, t1 - t0, t2 - t1, t3 - t2))
         return pulled
 
     def push(self, name: str, grads, handle: Optional[ServerHandle] = None):
-        t0 = stamp()  # stage borders: see _note
-        bucket = self._buckets[name]
-        resolved, handle_key = self._resolve_handle(handle)
-        impl = self._effective_impl(bucket.dtype, resolved)
-        stateful = self._is_stateful(resolved)
-        prep = self._prep_grads
-        if stateful:
-            prog = self._program(
-                "push_st", bucket.padded_len, bucket.dtype, handle_key
-            )
-        elif impl == "pallas":
-            if self.worker_axis is None:
-                prep = self._prep_grads_ring
-            prog = self._ring_program_op(
-                "push", bucket.padded_len, bucket.dtype, handle_key
-            )
-        else:
-            prog = self._program(
-                "push", bucket.padded_len, bucket.dtype, handle_key
-            )
-        t1 = stamp()  # select | prep
-        g = prep(bucket, grads)
-        t2 = stamp()  # prep | launch
-        with self._bucket_mu[name]:
-            if stateful:
-                self._ensure_opt_state(name, resolved, bucket)
-                outs = prog(
-                    self._stores[name], *self._opt_states[name], g
-                )
-                self._stores[name] = outs[0]
-                self._opt_states[name] = tuple(outs[1:-1])
-                token = outs[-1]
-            else:
-                self._stores[name], token = prog(self._stores[name], g)
-        self._observe("push", bucket)
-        t3 = stamp()
-        self._note((ENGINE_OP, t3, t1 - t0, t2 - t1, t3 - t2))
-        # The token is a tiny non-donated output that becomes ready when
-        # the push completes — block on it freely (the store itself is
-        # donated by the next push, so it must not escape).
-        return token
+        """Push alone: a ``push_pull`` whose programs return, where that
+        one has the pulled array, a tiny non-donated token that becomes
+        ready when the push completes — block on it freely (the store
+        itself is donated by the next push, so it must not escape)."""
+        return self.push_pull(name, grads, handle, None)
 
     def coalescer(self, handle: Optional[ServerHandle] = None, **kw):
         """A :class:`~pslite_tpu.parallel.coalesce.CoalescingDispatcher`
@@ -2368,6 +2404,7 @@ class CollectiveEngine:
                 )
                 with self._mu:
                     self._programs.clear()
+                    self._bound.clear()
                 for n in names:
                     b = snap[n][0]
                     entry = staged[n]

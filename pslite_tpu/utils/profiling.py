@@ -125,9 +125,10 @@ class Profiler:
 # push_sparse / pull_sparse = _engine_op -> CollectiveEngine / SparseEngine ->
 # back in _engine_op -> _engine_complete) passes through these stages, the
 # first five on the issuing thread, the last two on the
-# ``kv-engine-complete`` thread (``complete.wait`` is blocked on the
-# device, not host work).  The one definition the counters, the spans and
-# the benchmark's readers share.
+# ``kv-engine-complete`` thread, or in the first ``wait`` of an op that
+# carries neither ``out`` nor ``callback`` (``complete.wait`` is blocked on
+# the device, not host work).  The one definition the counters, the spans
+# and the benchmark's readers share.
 STAGES = ("route", "select", "prep", "launch", "dispatch",
           "complete.wait", "complete.copy")
 # While a ``jax.profiler`` session runs (``tracing()``), ``KVWorker`` wraps
@@ -163,7 +164,7 @@ class StageClock:
     stage borders and one :attr:`note` per layer (a bound
     ``deque.append``) of ``(kind, t_end, ns, ns, ns)``.  The notes are
     folded into the totals, a few thousand at a time with numpy, by
-    whoever reads and by ``KVWorker``'s completion thread now and then
+    whoever reads and by whoever completes a ``KVWorker`` op now and then
     (a fold in Python, note by note, cost the issuing thread more through
     the GIL than the notes themselves).  Past ``PENDING`` unfolded notes
     the oldest are dropped: only a caller of the engines alone that never
@@ -192,7 +193,14 @@ class StageClock:
         self._marks: Dict[int, Tuple[int, ...]] = {}
         self._slot = -1  # the newest slot an op ended in
         self.programs_built = 0
+        self.ops_bound = 0
         self.state_create_ns = 0
+
+    def op_bound(self) -> None:
+        """A dense bucket's first ``push_pull`` or ``push`` under a handle
+        (or its first after a reshard or a new registration) built the
+        record its other ops look up (``CollectiveEngine._bind``)."""
+        self.ops_bound += 1
 
     def program_built(self) -> None:
         """A look into an engine's program cache missed (stage
@@ -283,6 +291,7 @@ class StageClock:
         registry.gauge(
             "engine.programs.hits",
             fn=lambda: self.totals()["select"][1] - self.programs_built)
+        registry.gauge("engine.bound.misses", fn=lambda: self.ops_bound)
         registry.gauge("engine.state_create.s",
                        fn=lambda: self.state_create_ns / 1e9)
 
@@ -292,6 +301,9 @@ class _NullStageClock:
     nothing is exported."""
 
     note = staticmethod(tuple.__len__)  # a C call that keeps nothing
+
+    def op_bound(self) -> None:
+        pass
 
     def program_built(self) -> None:
         pass
