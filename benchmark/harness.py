@@ -1,17 +1,19 @@
 """One run of one cell: set-up, the measured window, the comparison with
 the plain reference, and the result line.
 
-Nothing here names a cell, a configuration or a model: a cell is an entry of
-``workloads`` in ``BENCHMARK.json``, which names a configuration file (sizes),
-a traffic file (parameters of one of the drivers in ``drivers.py``) and the
-chips it needs; per-layer metrics are readers found by name under
-``layer_metrics/``.
+Nothing here names a cell, a configuration, a model or a driver: a cell is an
+entry of ``workloads`` in ``BENCHMARK.json``, which names a configuration file
+(sizes), a traffic file (which names its driver and gives its parameters) and
+the chips it needs.  Traffic files, drivers (``drivers/<name>.py``) and
+per-layer readers (``layer_metrics/<name>.py``) are all found by name, by the
+one lookup ``_find``, under the directories of ``paths``.
 """
 
 from __future__ import annotations
 
 import contextlib
 import importlib.util
+import inspect
 import json
 import os
 import shutil
@@ -63,7 +65,7 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
                        f"{sorted(cells)}")
     w = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
-    search = [os.path.join(root, p) for p in bench["paths"]]
+    search = search_dirs(root)
     traffic = _find(search, "traffic", w["traffic"], (".json",))
     return Cell(
         name=w["name"],
@@ -76,6 +78,13 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
     )
 
 
+def search_dirs(root: str = ROOT) -> List[str]:
+    """The directories of ``paths``: where traffic, drivers and readers
+    are looked for, and where a driver's own modules lie."""
+    bench = _read_json(os.path.join(root, "BENCHMARK.json"))
+    return [os.path.join(root, p) for p in bench["paths"]]
+
+
 def _find(search: List[str], kind: str, name: str, endings) -> str:
     for base in search:
         for ending in endings:
@@ -86,15 +95,75 @@ def _find(search: List[str], kind: str, name: str, endings) -> str:
         f"no {kind}/{name}{{{','.join(endings)}}} under {search}")
 
 
+_modules: Dict[str, object] = {}   # by path: what _load has executed
+
+
+def _load(search: List[str], kind: str, name: str):
+    """The module ``<kind>/<name>.py``, found as a traffic file is and
+    executed once in a process.  Every directory of ``search`` is importable
+    from it, so a file brings its own reference, generator or least-bytes
+    function as modules beside ``<kind>/``."""
+    path = _find(search, kind, name, (".py",))
+    module = _modules.get(path)
+    if module is None:
+        for base in search:
+            if base not in sys.path:
+                sys.path.append(base)
+        spec = importlib.util.spec_from_file_location(
+            kind + "_" + "".join(c if c.isalnum() else "_" for c in name),
+            path)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        _modules[path] = module
+    return module
+
+
 def load_reader(search: List[str], name: str) -> Callable:
     """``read(ctx)`` of ``layer_metrics/<name>.py``."""
-    path = _find(search, "layer_metrics", name, (".py",))
-    spec = importlib.util.spec_from_file_location(
-        "layer_metric_" + "".join(c if c.isalnum() else "_" for c in name),
-        path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return _load(search, "layer_metrics", name).read
+
+
+# What ``_drive`` asks of a driver (``README.md``, "A driver"): constructed
+# as ``Driver(cluster, config, traffic, seed)``; these it calls, these it
+# reads.
+DRIVER_CALLS = ("setup", "checked_steps", "step", "compare", "counters",
+                "expected_counters", "least_bytes")
+DRIVER_READS = ("payload_bytes_per_step", "steps_done", "tracing")
+
+
+def load_driver(search: List[str], name: str) -> type:
+    """The class ``Driver`` of ``drivers/<name>.py``.  One that lacks a
+    member of the protocol is refused by name, here, before anything boots.
+    A driver file that builds on another takes its class through this same
+    call: ``harness.load_driver(harness.search_dirs(), "<its name>")``."""
+    module = _load(search, "drivers", name)
+    cls = getattr(module, "Driver", None)
+    if not isinstance(cls, type):
+        raise TypeError(f"{module.__file__} gives no class named Driver")
+    lacks = [m for m in DRIVER_CALLS if not callable(getattr(cls, m, None))]
+    lacks += [m for m in DRIVER_READS if not hasattr(cls, m)]
+    try:
+        inspect.signature(cls).bind("cluster", "config", "traffic", 0)
+    except TypeError:
+        lacks.insert(0, "__init__(cluster, config, traffic, seed)")
+    if lacks:
+        raise TypeError(
+            f"driver {name!r} ({module.__file__}) lacks "
+            f"{', '.join(lacks)}: what the harness asks of a driver is in "
+            f"benchmark/README.md")
+    return cls
+
+
+def resolve(cell: Cell) -> type:
+    """Find by file what the cell's data names as code, in a second and
+    before anything boots (``run_cell`` starts with it): each per-layer
+    reader, and the traffic's driver, whose class it returns."""
+    for m in cell.per_layer:
+        _find(cell.search, "layer_metrics", m["name"], (".py",))
+    if "driver" not in cell.traffic:
+        raise KeyError(f"the traffic file of cell {cell.name!r} names no "
+                       f"driver")
+    return load_driver(cell.search, cell.traffic["driver"])
 
 
 # -- what a reader is given ----------------------------------------------------
@@ -279,6 +348,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     the lower-precision control's numbers, printed on earlier lines."""
     from boot import Cluster
 
+    driver_class = resolve(cell)
     watchdog = threading.Timer(limit_s, _expired, args=(limit_s,))
     watchdog.daemon = True
     watchdog.start()
@@ -291,23 +361,23 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         t_boot = time.perf_counter()
         cluster = Cluster(cell.config["server_handle"])
         try:
-            return _drive(cell, cluster, devices, peaks, compiles, cache_dir,
-                          seed, seconds, trace, t_start, t_boot, control)
+            return _drive(cell, driver_class, cluster, devices, peaks,
+                          compiles, cache_dir, seed, seconds, trace, t_start,
+                          t_boot, control)
         finally:
             cluster.shutdown()
     finally:
         watchdog.cancel()
 
 
-def _drive(cell, cluster, devices, peaks, compiles, cache_dir, seed, seconds,
-           trace, t_start, t_boot, control):
+def _drive(cell, driver_class, cluster, devices, peaks, compiles, cache_dir,
+           seed, seconds, trace, t_start, t_boot, control):
     import reference
-    from drivers import DRIVERS
     from least_bytes import least_seconds
 
     traffic = cell.traffic
     deadline_s = float(traffic.get("step_deadline_s", 30.0))
-    driver = DRIVERS[traffic["driver"]](cluster, cell.config, traffic, seed)
+    driver = driver_class(cluster, cell.config, traffic, seed)
     t0 = time.perf_counter()
     parts = driver.setup()
     t1 = time.perf_counter()

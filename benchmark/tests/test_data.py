@@ -194,9 +194,12 @@ def test_benchmark_json_names_units_and_files():
     assert 1 <= BENCHMARK["run_seconds"] <= 51
 
 
-def test_run_py_names_no_cell_and_no_model():
+def test_run_py_names_no_cell_no_model_and_no_driver():
+    drivers = [f[:-3] for f in os.listdir(os.path.join(BENCH, "drivers"))
+               if f.endswith(".py")]
+    assert len(drivers) >= 2
     for name in ("run.py", "harness.py", "sets.py", "readings.py"):
         with open(os.path.join(BENCH, name)) as fh:
             text = fh.read().lower()
-        for word in ("bert", "gpt2", "gpt-2", "dlrm", "criteo"):
+        for word in ["bert", "gpt2", "gpt-2", "dlrm", "criteo"] + drivers:
             assert word not in text, (name, word)

@@ -2,7 +2,10 @@
 harness's own functions on four virtual CPU devices (the four-chip path)
 and, in a child process, on one.  ``run.py`` itself refuses a process
 without a TPU and has no size option; the tiny configuration and traffic
-files under ``cells/`` are named by no entry of ``workloads``.
+files under ``cells/`` are named by no entry of ``workloads``.  The kind
+``row-adagrad`` is the room a later PR has: its driver exists only as a file
+under ``cells/drivers/`` (a subclass of the sparse driver, taken through the
+harness's loader) with a reference of its own beside it.
 
 Also the two tests "How correct is decided" asks for: the lower-precision
 control comes out as not correct, and a run whose timed path is broken
@@ -26,7 +29,7 @@ def _run(kind, seed=7, seconds=0.3, trace=False, **kw):
                             time.perf_counter(), require_tpu=False, **kw)
 
 
-@pytest.mark.parametrize("kind", ["dense", "sparse"])
+@pytest.mark.parametrize("kind", ["dense", "sparse", "row-adagrad"])
 def test_cell_end_to_end_on_four_devices(kind, capsys):
     ok, result = _run(kind, seed=2**31 + 5)   # more than 32 signed bits hold
     out = capsys.readouterr().out
@@ -55,7 +58,7 @@ def test_traced_run_reports_what_it_can_read(kind):
     assert "breakdown" not in result and "busy_s" not in result["device"]
 
 
-@pytest.mark.parametrize("kind", ["dense", "sparse"])
+@pytest.mark.parametrize("kind", ["dense", "sparse", "row-adagrad"])
 def test_same_seed_same_inputs_and_control_fails(kind, capsys):
     """The bf16 control is not correct in either number, by a wide margin
     over what the program reads (the limits of the tiny cells are 1e-3)."""
@@ -177,6 +180,7 @@ def _break_sparse_lost_push(monkeypatch):
     ("dense", _break_dense_drop_a_worker, "first3_err"),
     ("sparse", _break_sparse_altered_answer, "first3_err"),
     ("sparse", _break_sparse_lost_push, "final_err"),
+    ("row-adagrad", _break_sparse_lost_push, "final_err"),
 ])
 def test_a_broken_timed_path_is_not_correct(kind, breaker, number,
                                             monkeypatch, capsys):

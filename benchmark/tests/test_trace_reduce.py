@@ -113,6 +113,30 @@ def test_nothing_to_read():
     assert tr.reduce_trace(p) is None   # a CPU trace: no device plane
 
 
+def test_every_operation_is_kept_and_the_ten_largest_are_printed():
+    """``op_seconds`` holds every operation by name; ``device_ops``, what
+    the result line prints, is its ten largest in falling order, as it was
+    before the field existed."""
+    p = _trace(devices=2)
+    for dev in p.planes[:2]:
+        for k in range(12):      # twelve more operations, 1..12 ns each
+            dev.lines[1].events.append(
+                Ev(f"%fusion.{k} = f32[{k + 1}]{{0}} fusion()", 10_250,
+                   k + 1))
+    r = tr.reduce_trace(p)
+    assert len(r.op_seconds) == 13
+    assert r.op_seconds["%fusion.0 f32[1]"] == pytest.approx(1e-9)
+    assert r.op_seconds["%adam_update.1 f32[8192,128]"] == pytest.approx(
+        400e-9)
+    ranked = sorted(r.op_seconds.items(), key=lambda kv: -kv[1])
+    assert r.device_ops == [[k, v] for k, v in ranked[:10]]
+    assert [n for n, _ in r.device_ops][:3] == [
+        "%adam_update.1 f32[8192,128]", "%fusion.11 f32[12]",
+        "%fusion.10 f32[11]"]
+    assert "%fusion.1 f32[2]" not in dict(map(tuple, r.device_ops))
+    assert sum(r.op_seconds.values()) >= r.busy_s   # nested ones count here
+
+
 def test_union_and_short_name():
     assert tr.union([(0, 2), (1, 3), (5, 6), (6, 6)]) == [(0, 3), (5, 6)]
     assert tr.short_name("%copy.16 = f32[1048576]{0:T(1024)} copy(f32[8])") \

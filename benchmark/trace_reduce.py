@@ -74,6 +74,11 @@ class Reduction:
     device_ops: List[List[object]]       # [[name, seconds], ...] top 10
     idle_gaps: List[List[object]]        # [[what the host was doing, seconds], ...]
     devices: int
+    # Every device operation's seconds over the traced steps (mean over
+    # devices) by its short name: ``device_ops`` is the ten largest of
+    # these.  A reader takes one program's or kernel's time from here,
+    # whether or not it is among the ten.
+    op_seconds: Dict[str, float] = field(default_factory=dict)
 
 
 def _events(line) -> List[Tuple[str, float, float]]:
@@ -208,6 +213,7 @@ def reduce_trace(profile) -> Optional[Reduction]:
         ranked = sorted(d.items(), key=lambda kv: -kv[1])[:10]
         return [[k, v] for k, v in ranked]
 
+    op_seconds = {k: v / n_dev / 1e9 for k, v in names.items()}
     return Reduction(
         steps=n_steps,
         window_s=window_ns / 1e9,
@@ -216,9 +222,10 @@ def reduce_trace(profile) -> Optional[Reduction]:
         launches_per_step=launches,
         launches_repeat=bool(first.modules)
         and len(first.modules) % n_steps == 0,
-        device_ops=top({k: v / n_dev / 1e9 for k, v in names.items()}),
+        device_ops=top(op_seconds),
         idle_gaps=top({k: v / 1e9 for k, v in gaps.items()}),
         devices=n_dev,
+        op_seconds=op_seconds,
     )
 
 
