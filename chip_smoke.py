@@ -14,7 +14,8 @@ reference:
 - ``readme``: 40 keys x 256,000 f32 in one bucket, ``push_pull``, then a
   separate ``push`` and ``pull``;
 - ``sparse``: a 2^20 x 64 embedding table, Zipf indices,
-  ``push_sparse`` / ``pull_sparse``;
+  ``push_sparse`` / ``pull_sparse``, then one push under the stateful
+  server handle ``row_adagrad`` on a table of its own;
 - ``message_path``: an unregistered key, which the collective path cannot
   take, answered by the ``KVServer`` handler;
 - ``ring`` (two or more devices): the ResNet-50 buckets once more through
@@ -371,6 +372,29 @@ class _Smoke:
               f"hot row: push_sparse / pull_sparse agree")
         print(f"  set-up: first round (compiles) {walls[0]:.2f} s; "
               f"second {walls[1]:.2f} s")
+        # Once under the stateful server handle, on a table of its own:
+        # from the zero state one push of row-wise Adagrad leaves
+        # -lr * G / (sqrt(mean(G**2)) + eps) in every touched row.
+        lr, eps = 0.05, 1e-8
+        se.register_sparse("emb_opt", sz.emb_rows, sz.emb_dim)
+        t0 = time.perf_counter()
+        kv.wait(kv.push_sparse("emb_opt", idx, grads,
+                               f"row_adagrad:{lr},{eps}"))
+        kv.wait(kv.pull_sparse("emb_opt", idx, out=out))
+        wall = time.perf_counter() - t0
+        rows, inverse = np.unique(idx.reshape(-1), return_inverse=True)
+        G = np.zeros((len(rows), sz.emb_dim), np.float64)
+        np.add.at(G, inverse, grads.reshape(-1, sz.emb_dim))
+        want = -lr * G / (np.sqrt(np.mean(G ** 2, axis=1))[:, None] + eps)
+        np.testing.assert_allclose(
+            out.reshape(-1, sz.emb_dim), want[inverse], rtol=1e-4,
+            atol=1e-6, err_msg="row_adagrad through push_sparse")
+        acc = np.asarray(se.acc_global_device("emb_opt"))
+        check(np.count_nonzero(acc) == len(rows),
+              "accumulator rows touched != rows pushed")
+        print(f"  one push under row_adagrad:{lr},{eps} through "
+              f"push_sparse: {len(rows):,} distinct rows and their "
+              f"accumulators agree ({wall:.2f} s, compiles)")
 
     # -- message path ---------------------------------------------------------
 

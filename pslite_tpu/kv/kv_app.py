@@ -910,7 +910,8 @@ class KVWorker:
 
     def _engine_op(self, op, args, keys=None, cmd: int = 0, lens=None,
                    out=None, callback=None, keep_result: bool = False,
-                   pull: bool = False) -> Optional[int]:
+                   pull: bool = False,
+                   handle: Optional[str] = None) -> Optional[int]:
         """One op of the collective path: route, the engine's op, then
         timestamp + async completion.  None where ``keys`` are no
         registered bucket: the op is the message path's.
@@ -921,7 +922,9 @@ class KVWorker:
         first of ``args``), the engine's ``op(name, *args)``, which notes
         ``select``, ``prep`` and ``launch`` itself, then ``dispatch``,
         the rest of this method.  While a profiler session runs the op
-        lies in a ``ps.kv.op`` span.  Callers pass everything by
+        lies in a ``ps.kv.op`` span, whose metadata also names the kind
+        of the server ``handle`` a call brought (``push_sparse``; the
+        engine is given it among ``args``).  Callers pass everything by
         position, and the dispatch is not a method of its own: on the
         chip's host a Python call costs this path 2-3 us, a keyword call
         half a microsecond more (PERF.md, PR 24).
@@ -993,7 +996,11 @@ class KVWorker:
         t3 = stamp()
         self._note((KV_OP, t3, route_ns, t3 - t2, -1))
         if span is not None:
-            span.set_metadata(ts=ts, name=name)
+            if handle is None:
+                span.set_metadata(ts=ts, name=name)
+            else:
+                span.set_metadata(ts=ts, name=name,
+                                  handle=handle.partition(":")[0])
             span.__exit__(None, None, None)
         return ts
 
@@ -1104,13 +1111,20 @@ class KVWorker:
         return self.engine.push_pull_stream(name, grads_iter, depth=depth)
 
     def push_sparse(self, name: str, indices, grads,
-                    callback=None) -> int:
-        """Sparse push: [W, n] rows + [W, n, d] grads scatter-added into the
-        sharded table (aggregation server handle)."""
+                    handle: Optional[str] = None, callback=None) -> int:
+        """Sparse push: [W, n] rows + [W, n, d] grads into the sharded
+        table.  With no ``handle`` they are scatter-added (the
+        aggregation server handle); ``handle="row_adagrad:lr,eps"`` has
+        the server apply row-wise Adagrad to the rows the push touches,
+        its accumulator kept beside the table (``SparseEngine.push``).
+        The handle is the call's, as ``CollectiveEngine.push_pull(name,
+        grads, handle)`` has it: an unknown one fails at the first push,
+        by name."""
         eng = getattr(self.po.van, "sparse_engine", None)
         log.check(eng is not None, "push_sparse requires the ici van")
-        return self._engine_op(eng.push, (name, indices, grads), None, 0,
-                               None, None, callback)
+        return self._engine_op(eng.push, (name, indices, grads, handle),
+                               None, 0, None, None, callback, False, False,
+                               handle)
 
     def pull_sparse(self, name: str, indices, out=None,
                     callback=None) -> int:

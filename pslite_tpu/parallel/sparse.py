@@ -60,6 +60,18 @@ class SparseTable:
 
 
 
+@dataclass(eq=False, slots=True)
+class _BoundPush:
+    """What every ``push`` of one table under one handle at one batch size
+    works out the same way, worked out once by ``SparseEngine._bind_push``
+    (the sparse twin of the dense engine's ``_BoundOp``).  Invariants
+    only: never a store, an accumulator or a gradient."""
+
+    prog: Callable
+    kind: Optional[str]  # the handle's kind; None: the plain sum
+    params: tuple  # the handle's numbers as device scalars; () for the sum
+
+
 def _interleave_rows(glob, num_rows: int, rps: int, S: int, dtype):
     """Global-order rows -> the sharded store layout: global row r
     lives on shard r % S at local row r // S.  ``glob`` is [num_rows]
@@ -145,7 +157,9 @@ def _scatter_rows(axis, S, R, pack, dim, store_l, idx_l, grads_l):
     The sparse bodies carry ``jax.named_scope``s, by which a device trace
     is read: ``ps.sparse.route`` (indices and rows crossing the workers,
     and who owns what), ``ps.sparse.push.scatter_add``,
-    ``ps.sparse.pull.gather``, and ``ps.update`` for the optimizer."""
+    ``ps.sparse.pull.gather``, and under a stateful handle
+    ``ps.sparse.combine`` (sort and segment sum of duplicates) and
+    ``ps.update`` (accumulator and step)."""
     import jax
     from jax import lax
     import jax.numpy as jnp
@@ -207,7 +221,7 @@ def _adagrad_sparse(axis, S, R, pack, dim, store_l, acc_l, idx_l,
         local = jnp.where(owned, all_idx // S, R)  # R = sentinel (dropped)
         m = all_idx.shape[0]
 
-    with jax.named_scope("ps.update"):
+    with jax.named_scope("ps.sparse.combine"):
         # Segment-sum duplicates: sort by local row, one segment per unique
         # row (sentinel rows sort last into their own segments).
         order = jnp.argsort(local)
@@ -225,6 +239,7 @@ def _adagrad_sparse(axis, S, R, pack, dim, store_l, acc_l, idx_l,
         )
         valid = row_seg < R
 
+    with jax.named_scope("ps.update"):
         # Accumulator: gather the touched rows, apply, scatter back (1-D
         # logical rows — independent of the store's lane packing).
         acc_rows = acc_l[jnp.where(valid, row_seg, 0)]
@@ -319,6 +334,12 @@ class SparseEngine:
         # as the table), created lazily by push(handle="row_adagrad:...").
         self._acc: Dict[str, object] = {}
         self._programs: Dict[tuple, Callable] = {}
+        # (table, handle, batch) -> see _bind_push.  Dropped with the
+        # programs by a reshard, and a table's by a new registration of
+        # its name or a change of its packing.
+        self._bound: Dict[tuple, _BoundPush] = {}
+        # Pushes that ran under a stateful handle (see export).
+        self.stateful_pushes = 0
         self._mu = threading.Lock()
         # Per-table write locks: push donates the store buffer, so the
         # load-run-store sequence must be atomic per table (same contract
@@ -366,7 +387,22 @@ class SparseEngine:
             self._tables[name] = table
             self._stores[name] = store
             self._table_mu.setdefault(name, threading.Lock())
+            self._unbind(name)
         return table
+
+    def _unbind(self, name: str) -> None:
+        """Drop the table's push records (call with ``_mu`` held)."""
+        for key in [k for k in self._bound if k[0] == name]:
+            del self._bound[key]
+
+    def export(self, registry) -> None:
+        """Lazily sampled gauges in a node's ``Registry``, beside the
+        stage clock's (``docs/observability.md``, "Engine path")."""
+        registry.gauge("engine.sparse.push.stateful",
+                       fn=lambda: self.stateful_pushes)
+        registry.gauge(
+            "engine.sparse.acc.bytes",
+            fn=lambda: sum(int(a.nbytes) for a in list(self._acc.values())))
 
     def _keep(self, key, prog):
         """Cache a program a lookup missed; the misses are counted here,
@@ -640,11 +676,13 @@ class SparseEngine:
         )
         self._stores[name] = placed
         t.pack = 1
+        with self._mu:
+            self._unbind(name)
 
     @staticmethod
     def _parse_handle(handle: str) -> tuple:
         kind, _, rest = handle.partition(":")
-        log.check(kind == "row_adagrad", f"unknown sparse handle {kind!r}")
+        log.check(kind == "row_adagrad", f"unknown sparse handle {handle!r}")
         lr, eps = 0.01, 1e-8
         if rest:
             parts = rest.split(",")
@@ -652,6 +690,36 @@ class SparseEngine:
             if len(parts) > 1:
                 eps = float(parts[1])
         return kind, (lr, eps)
+
+    def _handle_scalars(self, handle: str) -> tuple:
+        """(kind, the handle's numbers as f32 device scalars)."""
+        import jax.numpy as jnp
+
+        kind, params = self._parse_handle(handle)
+        return kind, tuple(jnp.float32(p) for p in params)
+
+    def _bind_push(self, name: str, handle: Optional[str], batch: int
+                   ) -> _BoundPush:
+        """Stage ``select`` of the first ``push(name, ., ., handle)`` at
+        this batch size (and of the first after a reshard, a new
+        registration of ``name`` or a change of its packing dropped the
+        record): the handle parsed, its numbers placed as device scalars,
+        the program.  An unknown handle fails here, by name.  Call with
+        the table's lock held."""
+        table = self._tables[name]
+        if handle is None:
+            bound = _BoundPush(self._sparse_program("push", table, batch),
+                               None, ())
+        else:
+            kind, params = self._handle_scalars(handle)
+            bound = _BoundPush(
+                self._sparse_program("push_" + kind, table, batch),
+                kind, params)
+        with self._mu:
+            # A new registration meanwhile: the next push binds.
+            if self._tables.get(name) is table:
+                self._bound[(name, handle, batch)] = bound
+        return bound
 
     def push(self, name: str, indices, grads, handle: str = None):
         """indices: [W, n] int rows per worker; grads: [W, n, d].
@@ -662,33 +730,32 @@ class SparseEngine:
         DLRM-standard row-wise Adagrad: the per-row aggregate gradient
         updates a per-row accumulator, and the row steps by
         ``-lr * G / (sqrt(acc) + eps)`` — the fused sparse analog of the
-        dense engine's optimizer handles."""
+        dense engine's optimizer handles.
+
+        Bound once, launched many times: what no two pushes of ``(name,
+        handle, batch)`` differ in is a :class:`_BoundPush` that the first
+        builds (:meth:`_bind_push`) and the others look up."""
         t0 = stamp()  # stage borders: see _note
         table = self._tables[name]
         idx, g = self._prep(table, indices, grads)
         batch = int(idx.shape[1])
         t1 = stamp()  # prep | select
-        # Program selection reads table.pack, which the orbax compat shim
-        # can mutate — resolve it under the lock (so the sparse stages run
+        # The record depends on table.pack, which the orbax compat shim
+        # can mutate — look it up under the lock (so the sparse stages run
         # prep, select, launch, and select has the wait for the lock).
         with self._table_mu[name]:
-            prog = self._sparse_program(
-                "push" if handle is None else "push_row_adagrad",
-                table, batch,
-            )
+            b = (self._bound.get((name, handle, batch))
+                 or self._bind_push(name, handle, batch))
             t2 = stamp()  # select | launch
-            if handle is None:
-                self._stores[name], token = prog(
+            if b.kind is None:
+                self._stores[name], token = b.prog(
                     self._stores[name], idx, g)
             else:
-                import jax.numpy as jnp
-
-                _, (lr, eps) = self._parse_handle(handle)
-                self._ensure_acc(name, table)
-                self._stores[name], self._acc[name], token = prog(
-                    self._stores[name], self._acc[name], idx, g,
-                    jnp.float32(lr), jnp.float32(eps),
-                )
+                if name not in self._acc:
+                    self._ensure_acc(name, table)
+                self._stores[name], self._acc[name], token = b.prog(
+                    self._stores[name], self._acc[name], idx, g, *b.params)
+                self.stateful_pushes += 1
         self._observe("push", table, batch)
         t3 = stamp()
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
@@ -851,20 +918,19 @@ class SparseEngine:
                     self._stores[n] = outs[i]
                 token = outs[kk]
             else:
-                import jax.numpy as jnp
-
-                _, (lr, eps) = self._parse_handle(handle)
+                _, params = self._handle_scalars(handle)
                 for n, t in zip(names, tables):
                     self._ensure_acc(n, t)
                 outs = prog(
                     *[self._stores[n] for n in names],
                     *[self._acc[n] for n in names],
-                    *idxs, *gs, jnp.float32(lr), jnp.float32(eps),
+                    *idxs, *gs, *params,
                 )
                 for i, n in enumerate(names):
                     self._stores[n] = outs[i]
                     self._acc[n] = outs[kk + i]
                 token = outs[2 * kk]
+                self.stateful_pushes += 1
         finally:
             self._unlock_tables(ordered)
         t3 = stamp()
@@ -1201,6 +1267,7 @@ class SparseEngine:
                 )
                 with self._mu:
                     self._programs.clear()
+                    self._bound.clear()
                     for n in names:
                         table, store, acc = staged[n]
                         self._tables[n] = table
