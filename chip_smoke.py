@@ -15,7 +15,8 @@ reference:
   separate ``push`` and ``pull``;
 - ``sparse``: a 2^20 x 64 embedding table, Zipf indices,
   ``push_sparse`` / ``pull_sparse``, then one push under the stateful
-  server handle ``row_adagrad`` on a table of its own;
+  server handle ``row_adagrad`` on a 2^20 x 128 table of its own (rows of
+  128 lanes: on the chip written by the ``ops/row_add.py`` kernel);
 - ``message_path``: an unregistered key, which the collective path cannot
   take, answered by the ``KVServer`` handler;
 - ``ring`` (two or more devices): the ResNet-50 buckets once more through
@@ -81,6 +82,9 @@ class Sizes:
     emb_rows: int = 1 << 20
     emb_dim: int = 64
     emb_batch: int = 4096
+    # The table pushed under the stateful handle: rows of 128 lanes, which
+    # on the chip the push writes through ops/row_add.py.
+    emb_opt_dim: int = 128
 
 
 class PhaseFailed(RuntimeError):
@@ -376,25 +380,33 @@ class _Smoke:
         # from the zero state one push of row-wise Adagrad leaves
         # -lr * G / (sqrt(mean(G**2)) + eps) in every touched row.
         lr, eps = 0.05, 1e-8
-        se.register_sparse("emb_opt", sz.emb_rows, sz.emb_dim)
+        dim = sz.emb_opt_dim
+        se.register_sparse("emb_opt", sz.emb_rows, dim)
+        grads = rng.standard_normal((W, sz.emb_batch, dim), dtype=np.float32)
+        out = np.zeros_like(grads)
         t0 = time.perf_counter()
         kv.wait(kv.push_sparse("emb_opt", idx, grads,
                                f"row_adagrad:{lr},{eps}"))
         kv.wait(kv.pull_sparse("emb_opt", idx, out=out))
         wall = time.perf_counter() - t0
         rows, inverse = np.unique(idx.reshape(-1), return_inverse=True)
-        G = np.zeros((len(rows), sz.emb_dim), np.float64)
-        np.add.at(G, inverse, grads.reshape(-1, sz.emb_dim))
+        G = np.zeros((len(rows), dim), np.float64)
+        np.add.at(G, inverse, grads.reshape(-1, dim))
         want = -lr * G / (np.sqrt(np.mean(G ** 2, axis=1))[:, None] + eps)
         np.testing.assert_allclose(
-            out.reshape(-1, sz.emb_dim), want[inverse], rtol=1e-4,
+            out.reshape(-1, dim), want[inverse], rtol=1e-4,
             atol=1e-6, err_msg="row_adagrad through push_sparse")
         acc = np.asarray(se.acc_global_device("emb_opt"))
         check(np.count_nonzero(acc) == len(rows),
               "accumulator rows touched != rows pushed")
+        kernel = se.row_kernel_pushes == 1
+        check(kernel == (self.on_tpu and dim == 128),
+              f"table written by the row kernel: {kernel}")
         print(f"  one push under row_adagrad:{lr},{eps} through "
-              f"push_sparse: {len(rows):,} distinct rows and their "
-              f"accumulators agree ({wall:.2f} s, compiles)")
+              f"push_sparse, {sz.emb_rows:,} x {dim}, the table written by "
+              f"{'ops/row_add.py' if kernel else 'XLA scatter'}: "
+              f"{len(rows):,} distinct rows and their accumulators agree "
+              f"({wall:.2f} s, compiles)")
 
     # -- message path ---------------------------------------------------------
 

@@ -70,6 +70,7 @@ class _BoundPush:
     prog: Callable
     kind: Optional[str]  # the handle's kind; None: the plain sum
     params: tuple  # the handle's numbers as device scalars; () for the sum
+    row_kernel: bool  # the program's table write is ops/row_add.py
 
 
 def _interleave_rows(glob, num_rows: int, rps: int, S: int, dtype):
@@ -184,6 +185,47 @@ def _scatter_rows(axis, S, R, pack, dim, store_l, idx_l, grads_l):
         return store_l.at[phys].add(packed, mode="drop")
 
 
+# Where the stateful push's table write is ``ops/row_add.py``: the platform
+# a program is lowered for -> the kernel's ``interpret`` there.  A platform
+# not named keeps XLA's scatter.
+_ROW_ADD_INTERPRET = {"tpu": False}
+
+
+def _row_add_takes(width: int, dtype) -> bool:
+    """The rows ``ops/row_add.py`` moves: 128 lanes of f32, one table row
+    each (a wider row spans tiles, and Mosaic refuses the slice of one)."""
+    return width == 128 and np.dtype(dtype) == np.float32
+
+
+def _add_rows(store_l, row_seg, valid, delta, R):
+    """``store_l[row_seg[i]] += delta[i]`` where ``valid[i]``, for combined
+    rows: ascending, each once, the valid ones first.  No two updates
+    touch one row, so where the program is lowered for a platform of
+    ``_ROW_ADD_INTERPRET`` and the kernel takes the rows
+    (:func:`_row_add_takes`) the write visits the distinct rows only
+    (``ops/row_add.py``); anywhere else it is XLA's scatter, which pays
+    for every slot."""
+    from jax import lax
+    import jax.numpy as jnp
+
+    from ..ops.row_add import row_add
+
+    def scatter(store_l, row_seg, valid, delta):
+        return store_l.at[jnp.where(valid, row_seg, R)].add(
+            delta, mode="drop"
+        )
+
+    if not _row_add_takes(store_l.shape[1], store_l.dtype):
+        return scatter(store_l, row_seg, valid, delta)
+    kernels = {
+        platform: lambda s, r, v, d, interpret=interpret: row_add(
+            s, r, d, jnp.sum(v), interpret=interpret)
+        for platform, interpret in _ROW_ADD_INTERPRET.items()
+    }
+    return lax.platform_dependent(store_l, row_seg, valid, delta,
+                                  default=scatter, **kernels)
+
+
 def _adagrad_rows(store_l, acc_l, G, lr, eps):
     """Row-wise Adagrad on a DENSE aggregated gradient [R, d] (the
     DLRM-standard embedding update): acc += mean(G^2, rows); row -=
@@ -206,7 +248,8 @@ def _adagrad_sparse(axis, S, R, pack, dim, store_l, acc_l, idx_l,
     Here duplicates are combined by a SEGMENT SUM over the sorted
     gathered indices (O(batch) workspaces, exact same per-row G as the
     dense form), the accumulator rows are gathered/updated/scattered
-    1-D, and the store step scatter-adds through the packed layout —
+    1-D, and the store step is added by distinct row (_add_rows) or, for
+    a lane-packed table, scatter-adds through the packed layout —
     identical numerics to _adagrad_rows on the touched rows, untouched
     rows never read or written."""
     import jax
@@ -253,11 +296,9 @@ def _adagrad_sparse(axis, S, R, pack, dim, store_l, acc_l, idx_l,
         step = jnp.where(valid[:, None], step, 0).astype(store_l.dtype)
 
     with jax.named_scope("ps.sparse.push.scatter_add"):
-        # Store: scatter-subtract the step through the (packed) layout.
+        # Store: subtract the step, lane-packed through the packed layout.
         if pack == 1:
-            new_store = store_l.at[jnp.where(valid, row_seg, R)].add(
-                -step, mode="drop"
-            )
+            new_store = _add_rows(store_l, row_seg, valid, -step, R)
         else:
             phys = jnp.where(valid, row_seg // pack, R // pack)
             slot = (row_seg % pack).astype(jnp.int32)
@@ -338,8 +379,10 @@ class SparseEngine:
         # programs by a reshard, and a table's by a new registration of
         # its name or a change of its packing.
         self._bound: Dict[tuple, _BoundPush] = {}
-        # Pushes that ran under a stateful handle (see export).
+        # Pushes that ran under a stateful handle, and those of them whose
+        # program writes the table through ops/row_add.py (see export).
         self.stateful_pushes = 0
+        self.row_kernel_pushes = 0
         self._mu = threading.Lock()
         # Per-table write locks: push donates the store buffer, so the
         # load-run-store sequence must be atomic per table (same contract
@@ -400,6 +443,8 @@ class SparseEngine:
         stage clock's (``docs/observability.md``, "Engine path")."""
         registry.gauge("engine.sparse.push.stateful",
                        fn=lambda: self.stateful_pushes)
+        registry.gauge("engine.sparse.push.row_kernel",
+                       fn=lambda: self.row_kernel_pushes)
         registry.gauge(
             "engine.sparse.acc.bytes",
             fn=lambda: sum(int(a.nbytes) for a in list(self._acc.values())))
@@ -709,17 +754,25 @@ class SparseEngine:
         table = self._tables[name]
         if handle is None:
             bound = _BoundPush(self._sparse_program("push", table, batch),
-                               None, ())
+                               None, (), False)
         else:
             kind, params = self._handle_scalars(handle)
             bound = _BoundPush(
                 self._sparse_program("push_" + kind, table, batch),
-                kind, params)
+                kind, params, self._row_kernel(table))
         with self._mu:
             # A new registration meanwhile: the next push binds.
             if self._tables.get(name) is table:
                 self._bound[(name, handle, batch)] = bound
         return bound
+
+    def _row_kernel(self, table: SparseTable) -> bool:
+        """Whether this mesh's stateful push program of ``table`` writes
+        it through ``ops/row_add.py`` (the rule of :func:`_add_rows`; the
+        mesh's platform is what the program is lowered for)."""
+        platform = next(iter(self.mesh.devices.flat)).platform
+        return (platform in _ROW_ADD_INTERPRET and table.pack == 1
+                and _row_add_takes(table.dim, table.dtype))
 
     def push(self, name: str, indices, grads, handle: str = None):
         """indices: [W, n] int rows per worker; grads: [W, n, d].
@@ -756,6 +809,7 @@ class SparseEngine:
                 self._stores[name], self._acc[name], token = b.prog(
                     self._stores[name], self._acc[name], idx, g, *b.params)
                 self.stateful_pushes += 1
+                self.row_kernel_pushes += b.row_kernel
         self._observe("push", table, batch)
         t3 = stamp()
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
@@ -931,6 +985,9 @@ class SparseEngine:
                     self._acc[n] = outs[kk + i]
                 token = outs[2 * kk]
                 self.stateful_pushes += 1
+                # One push, whatever it groups: counted where the kernel
+                # writes any of its tables.
+                self.row_kernel_pushes += any(map(self._row_kernel, tables))
         finally:
             self._unlock_tables(ordered)
         t3 = stamp()

@@ -12,6 +12,8 @@ tools/aot_ring_compile.py is the full sweep whose committed report is
 docs/AOT_RING.json.
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -158,3 +160,123 @@ def test_fused_handle_is_a_mosaic_kernel_in_push_pull_st(
     row = _compile_stateful(eng, v5e8_mesh, handle, padded,
                             jnp.dtype(dtype))
     assert row["mosaic_custom_call"]
+
+
+# -- the sparse table's write by distinct row (ops/row_add.py) ------------------
+
+
+@pytest.fixture(scope="module")
+def v5e_chip(v5e8_mesh):
+    from jax.sharding import Mesh
+
+    return Mesh(np.array([v5e8_mesh.devices.flat[0]]), ("kv",))
+
+
+@pytest.mark.parametrize("m", [12, 1500, 4096, 131_072])
+def test_row_add_compiles_for_v5e_in_place(v5e_chip, m):
+    """The kernel lowers through Mosaic at a real table size, for a batch
+    smaller than a block of row ids, one that is no whole number of
+    blocks, ``chip_smoke.py``'s and the cell's, and its one result is the
+    donated table itself: nothing of the table's size is allocated or
+    copied beside it."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pslite_tpu.ops.row_add import row_add
+
+    rows, width = 20_000_000, 128
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(v5e_chip, P()))
+
+    compiled = jax.jit(
+        lambda s, r, d, n: row_add(s, r, d, n, interpret=False),
+        donate_argnums=(0,),
+    ).lower(sds((rows, width), jnp.float32), sds((m,), jnp.int32),
+            sds((m, width), jnp.float32), sds((), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == rows * width * 4
+    assert mem.temp_size_in_bytes < 4 << 20      # a padded batch, at most
+    whole = [l for l in compiled.as_text().splitlines()
+             if f"= f32[{rows},{width}]" in l and " parameter(" not in l]
+    assert len(whole) == 1 and "tpu_custom_call" in whole[0], whole
+    assert " %row_add" in whole[0]
+
+
+@pytest.mark.parametrize("kept", [False, True])
+def test_row_adagrad_push_at_full_size_writes_the_table_with_row_add(
+        v5e_chip, kept, tmp_path, monkeypatch):
+    """The program ``benchmark/tests/test_compile_fullsize_handle.py``
+    compiles (the cell ``dlrm-criteo-rowadagrad.zipf``'s push, called as
+    that test calls it, from this CPU-default process): lowered for the
+    v5e its table write is the ``row_add`` kernel under the scope
+    ``ps.sparse.push.scatter_add``, no scatter has the table for its
+    result, and both donations still hold.  That is compiled once, for
+    ``kept``: the program a later process builds from the kernel's trace as
+    the compile cache's directory keeps it, without tracing the kernel
+    (the same module, so the same compiled program, as the one traced in
+    place, of which only the lowering is looked at)."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pslite_tpu.ops import row_add as row_add_module
+    from pslite_tpu.parallel import sparse
+    from pslite_tpu.utils import compile_cache
+
+    assert jax.devices()[0].platform == "cpu"
+    traced = []
+    real = row_add_module._row_add
+    monkeypatch.setattr(
+        row_add_module, "_row_add",
+        lambda store, *rest: traced.append(store.shape) or real(store, *rest))
+    if kept:
+        monkeypatch.setattr(compile_cache, "_trace_dir",
+                            lambda: str(tmp_path))
+    rows, dim, lookups = 20_000_000, 128, 131_072
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(v5e_chip, spec))
+
+    def body(st, ac, ix, g, lr, eps):
+        new, acc_new = sparse._adagrad_sparse("kv", 1, rows, 1, dim, st, ac,
+                                              ix, g, lr, eps)
+        return new, acc_new, new[:1, :1]
+
+    def push():
+        return jax.jit(jax.shard_map(
+            body, mesh=v5e_chip,
+            in_specs=(P("kv", None), P("kv"), P("kv", None),
+                      P("kv", None, None), P(), P()),
+            out_specs=(P("kv", None), P("kv"), P("kv", None)),
+            check_vma=False), donate_argnums=(0, 1))
+
+    scalar = sds((), jnp.float32, P())
+    args = (sds((rows, dim), jnp.float32, P("kv", None)),
+            sds((rows,), jnp.float32, P("kv")),
+            sds((1, lookups), jnp.int32, P("kv", None)),
+            sds((1, lookups, dim), jnp.float32, P("kv", None, None)),
+            scalar, scalar)
+    lowered = push().lower(*args)
+    assert traced == [(rows, dim)]
+    if not kept:
+        text = lowered.as_text(debug_info=True)
+        assert "tpu_custom_call" in text and "row_add" in text
+        assert "ps.sparse.push.scatter_add" in text
+        return
+    compile_cache._traced.clear()               # a new process
+    lowered = push().lower(*args)
+    assert traced == [(rows, dim)]              # not traced again
+    assert len(os.listdir(tmp_path)) == 1
+    compiled = lowered.compile()
+    table = [l for l in compiled.as_text().splitlines()
+             if f"= f32[{rows},{dim}]" in l and " parameter(" not in l]
+    assert len(table) == 1, table
+    assert " %row_add" in table[0] and "tpu_custom_call" in table[0]
+    assert "ps.sparse.push.scatter_add" in table[0]
+    assert " scatter(" not in table[0] and " copy(" not in table[0]
+    mem = compiled.memory_analysis()
+    state = rows * dim * 4 + rows * 4
+    assert state <= mem.alias_size_in_bytes < state + (1 << 20)
+    assert mem.temp_size_in_bytes < 10**8

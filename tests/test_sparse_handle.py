@@ -109,7 +109,7 @@ def _row_error(got, want):
 TOL = 2e-5
 
 
-@pytest.mark.parametrize("dim", [8, 128])        # lane-packed and not
+@pytest.mark.parametrize("dim", [8, 128, 256])   # lane-packed and not
 @pytest.mark.parametrize("cluster", [1, 4], indirect=True)
 def test_three_pushes_then_a_pull_match_the_reference(cluster, dim):
     kv, eng = cluster
@@ -174,6 +174,56 @@ def test_a_push_with_no_handle_is_the_plain_sum_it_was(cluster):
                   g.astype(np.float64).reshape(-1, 128))
     got = np.asarray(eng.store_global_device("emb"))
     np.testing.assert_allclose(got, init + want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("cluster", [1, 4], indirect=True)
+def test_the_row_kernel_in_the_push_is_bit_equal_to_xlas_scatter(
+        cluster, monkeypatch):
+    """On the CPU a stateful push writes the table with XLA's scatter; the
+    test names the CPU among the kernel's platforms (interpreted) and the
+    same three pushes give the same bits, rows and accumulator.  The
+    counter follows the program: whole 128-lane f32 rows under a handle."""
+    from pslite_tpu.ops import row_add as row_add_module
+    from pslite_tpu.parallel import sparse
+
+    kv, eng = cluster
+    W = eng.num_shards
+    idx, init, grads = _traffic(W, 128)
+    twin = SparseEngine(eng.mesh, eng.axis)
+    twin.register_sparse("emb", ROWS, 128, init=init)
+    for g in grads:
+        twin.push("emb", idx, g, HANDLE)
+    assert (twin.stateful_pushes, twin.row_kernel_pushes) == (3, 0)
+
+    traced = []
+    real = row_add_module.row_add
+    monkeypatch.setattr(
+        row_add_module, "row_add",
+        lambda store, *a, **kw: traced.append(store.shape) or real(
+            store, *a, **kw))
+    monkeypatch.setitem(sparse._ROW_ADD_INTERPRET, "cpu", True)
+    eng.register_sparse("emb", ROWS, 128, init=init)
+    eng.register_sparse("packed", ROWS, 8, init=init[:, :8])
+    # A row wider than one tile keeps the scatter (Mosaic refuses the
+    # kernel's one-row slice of it: ops/row_add.py).
+    wide = eng.register_sparse("wide", ROWS, 256)
+    assert eng._row_kernel(eng.table("emb")) and not eng._row_kernel(wide)
+    assert not eng._row_kernel(eng.table("packed"))
+    for g in grads:
+        ts = kv.push_sparse("emb", idx, g, HANDLE)
+    kv.wait(ts)
+    rps = eng.table("emb").rows_per_shard
+    assert traced and set(traced) == {(rps, 128)}       # in the program
+    assert (eng.store_array("emb") == twin.store_array("emb")).all()
+    assert (np.asarray(eng._acc["emb"]) == np.asarray(twin._acc["emb"])).all()
+    assert _gauges(kv)["engine.sparse.push.row_kernel"] == 3
+    # Not for a lane-packed table, nor for a push with no handle.
+    kv.wait(kv.push_sparse("packed", idx, grads[0][..., :8], HANDLE))
+    kv.wait(kv.push_sparse("emb", idx, grads[0]))
+    assert set(traced) == {(rps, 128)}
+    after = _gauges(kv)
+    assert after["engine.sparse.push.stateful"] == 4
+    assert after["engine.sparse.push.row_kernel"] == 3
 
 
 def test_a_pair_is_bound_once_and_an_unknown_handle_fails_by_name(cluster):
