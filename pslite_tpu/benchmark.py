@@ -1,4 +1,13 @@
-"""KV benchmark CLI — the reference's workhorse benchmark re-created.
+"""The reference's ``test_benchmark`` port, and the loopback fixtures.
+
+Not a source of speed: what this system does on the chip is measured by
+``BENCHMARK.json`` + ``benchmark/`` and explained in ``PERF.md``.  This
+module is (1) the parity CLI below, whose ``--mode`` storms the documents
+tell an operator to run over a real van and the tests drive through the
+launcher, and (2) the in-process cluster fixtures (``_loopback_cluster``,
+``_teardown_cluster``, ``kv_loopback_storm`` and the one-leg ``_*_run``
+helpers) that tests and ``tools/ps*.py`` import.  What a leg prints on
+the CPU is a count or a wall clock of the host plane, never a chip figure.
 
 Parity with ``tests/test_benchmark.cc``: modes PUSH_THEN_PULL / PUSH_PULL /
 PUSH_ONLY / PULL_ONLY (:25-30), ``len repeat mode`` arguments, NUM_KEY_PER_SERVER
@@ -21,7 +30,6 @@ from __future__ import annotations
 
 import argparse
 import os
-import statistics
 import time
 from typing import Optional
 
@@ -369,14 +377,14 @@ def run_dlrm_serve(worker, args) -> None:
 def run_durable_serve(worker, args) -> None:
     """``--mode durable_serve`` (docs/durability.md): the beyond-RAM
     serving path — publish an embedding table (``PS_DUR_ROWS`` x
-    ``PS_DUR_DIM`` floats; the bench sizes it ~4x the server's
+    ``PS_DUR_DIM`` floats; size it ~4x the server's
     ``PS_STORE_RAM_MB``), run an UNMEASURED Zipf warm storm so the
     server's ``kv.hot_keys`` top-k learns the real head and the tiered
     store promotes it, then measure the Zipf single-row pull storm.
     Every 64th pull is verified bit-exact inside
     ``serve_embedding_storm`` — a tier serving stale bytes fails the
-    mode loudly.  The two bench legs run this identical mode with
-    ``PS_STORE_RAM_MB`` set vs 0 (all-RAM)."""
+    mode loudly.  Compare ``PS_STORE_RAM_MB`` set against 0 (all-RAM)
+    by running this identical mode under each."""
     from .models.dlrm import (DLRMConfig, push_embedding_table,
                               serve_embedding_storm)
 
@@ -401,9 +409,9 @@ def run_small_op_storm(worker, args) -> None:
     """``--mode small_op_storm`` (docs/batching.md): the ops/s regime —
     a depth-bounded pipeline of 4 KiB pushes against one tcp server
     (msgs/s is the headline), then a LOW-LOAD sequential push+wait loop
-    (single-op p50 must stay within noise of an unbatched build).  The
-    two legs of the bench run this identical mode with
-    ``PS_BATCH_BYTES=65536`` vs ``0``; the store is verified bit-exact
+    (single-op p50 must stay within noise of an unbatched build).
+    ``_small_op_run`` runs this identical mode under
+    ``PS_BATCH_BYTES=262144`` or ``0``; the store is verified bit-exact
     at applied-count (vals of 1.0 — exact float adds) either way."""
     secs = float(os.environ.get("PS_SOB_SECONDS", "3"))
     depth = int(os.environ.get("PS_SOB_DEPTH", "256"))
@@ -472,8 +480,8 @@ def run_serving_fanin(worker, args) -> None:
     FAN-OUT regime — each request is ``PS_SF_FANOUT`` independent
     single-row embedding lookups (Zipf rows, table SPREAD across every
     server), issued via ``KVWorker.multi_get`` with the hot-key cache
-    COLD.  The two bench legs run this identical mode with
-    ``PS_BATCH_BYTES=262144`` vs ``0``: aggregated, a request costs
+    COLD.  ``_serving_fanin_run`` runs this identical mode under
+    ``PS_BATCH_BYTES=262144`` or ``0``: aggregated, a request costs
     ~one EXT_BATCH frame per contacted server each way; unaggregated
     it costs one frame per LOOKUP each way.  Requests/s is the
     headline; frames/request (from the van's recv counter) proves the
@@ -584,7 +592,7 @@ def run_replica_read(worker, args) -> None:
     pulls spread across that range's whole replica chain while k=1
     funnels every read through one rank.  Periodic read-your-writes
     probes (push a delta to a per-worker probe block, then IMMEDIATELY
-    pull it back) count violations — the bench's correctness gate —
+    pull it back) count violations, which must stay 0,
     and every 32nd storm pull is verified bit-exact against the
     worker-held table."""
     from collections import deque
@@ -976,13 +984,10 @@ def _loopback_cluster(num_workers: int, num_servers: int, ns: str,
                       env_extra: Optional[dict] = None,
                       van_type: str = "loopback") -> list:
     """Boot an in-process cluster and return its started Postoffices as
-    ``[scheduler, *servers, *workers]`` — the shared harness of the
-    host-side KV benches (storm, fault recovery, psmon demo).  The
-    default transport is the loopback van; ``van_type="tcp"`` runs real
-    sockets over 127.0.0.1 (the chunk-streaming bench needs socket
-    semantics — monolithic frames block the peer socket for their full
-    serialize time, which is exactly the head-of-line effect under
-    measurement)."""
+    ``[scheduler, *servers, *workers]`` — the shared fixture of the
+    host-plane tests, ``kv_loopback_storm`` and the ``tools/ps*.py``
+    demos.  The default transport is the loopback van;
+    ``van_type="tcp"`` runs real sockets over 127.0.0.1."""
     import threading
 
     from .environment import Environment
@@ -1038,8 +1043,8 @@ def _teardown_cluster(nodes: list, workers: list, servers: list) -> None:
             pass
 
 
-# Counters whose WINDOWED rates ride the bench's kv_telemetry section
-# (deltas over the measured storm interval — docs/observability.md).
+# Counters whose WINDOWED rates kv_loopback_storm reports (deltas over
+# the measured storm interval — docs/observability.md).
 _WINDOWED_COUNTERS = (
     "van.sent_messages", "van.recv_messages", "kv.pushes", "kv.pulls",
     "kv.server_push_requests", "kv.server_pull_requests",
@@ -1063,7 +1068,7 @@ def _windowed_rates(pre: dict, post: dict, wall_s: float) -> dict:
 
 
 def _condense_snapshot(snap: dict) -> dict:
-    """Registry snapshot condensed for a bench record: counters plus
+    """Registry snapshot condensed for a storm's record: counters plus
     histogram quantiles (the raw buckets stay out of the JSON)."""
     m = snap.get("metrics", snap)
     return {
@@ -1083,10 +1088,9 @@ def kv_loopback_storm(n_workers: int = 2, n_servers: int = 2,
                       val_len: int = 1024, telemetry: bool = True,
                       env_extra: Optional[dict] = None) -> dict:
     """A full message-path push/pull storm over a live loopback cluster
-    (real bootstrap, real wire format, real apply pool) — the stub
-    bench the telemetry-overhead guard compares on, and the source of
-    the registry snapshot bench.py embeds next to its throughput
-    numbers.
+    (real bootstrap, real wire format, real apply pool) — what the
+    telemetry-overhead guard (``tests/test_bench_smoke.py``) compares
+    on.
 
     The returned ``wall_s`` clocks ONLY the storm (bootstrap excluded);
     ``telemetry`` is the per-node snapshot of every node after the
@@ -1155,493 +1159,10 @@ def kv_loopback_storm(n_workers: int = 2, n_servers: int = 2,
         _teardown_cluster(nodes, workers, servers)
 
 
-def wire_observatory_storm(quick: bool = False) -> dict:
-    """Wire-plane observatory numbers (docs/observability.md) over a
-    live in-process tcp cluster: syscalls/op, frames/op, combiner
-    batch fill, lane residency p99, and the zero-copy byte share —
-    all from ``wire.*`` counter deltas across a bursty small-op push
-    storm with the combiner on (the regime the occupancy histogram
-    prices).  Both planes summed: a van is judged by its whole data
-    plane, whichever half carried the traffic."""
-    from .kv.kv_app import KVServer, KVServerDefaultHandle, KVWorker
-
-    env = {"PS_BATCH_BYTES": str(64 << 10)}
-    nodes = _loopback_cluster(1, 1, "wire-obs", env, van_type="tcp")
-    servers: list = []
-    workers: list = []
-    try:
-        srv = KVServer(0, postoffice=nodes[1])
-        srv.set_request_handle(KVServerDefaultHandle())
-        servers.append(srv)
-        w = KVWorker(0, 0, postoffice=nodes[2])
-        workers.append(w)
-        keys = np.arange(8, dtype=np.uint64) * ((1 << 64) // 8) + 3
-        vals = np.ones(8 * 256, np.float32)  # 8 KiB ops: batchable
-        out = np.zeros_like(vals)
-        rounds, burst = (6, 8) if quick else (20, 16)
-        w.wait(w.push(keys, vals))  # warm the path before the window
-        pre = [po.telemetry_snapshot()["metrics"] for po in nodes]
-        t0 = time.perf_counter()
-        for _ in range(rounds):
-            tss = [w.push(keys, vals) for _ in range(burst)]
-            for ts in tss:
-                w.wait(ts)
-            w.wait(w.pull(keys, out))
-        wall = time.perf_counter() - t0
-        post = [po.telemetry_snapshot()["metrics"] for po in nodes]
-    finally:
-        _teardown_cluster(nodes, workers, servers)
-
-    def delta(name: str) -> int:
-        tot = 0
-        for p0, p1 in zip(pre, post):
-            d = (p1.get("counters", {}).get(name, 0)
-                 - p0.get("counters", {}).get(name, 0))
-            if d > 0:
-                tot += d
-        return tot
-
-    def both(suffix: str) -> int:
-        return delta("wire." + suffix) + delta("wire.native." + suffix)
-
-    ops = both("tx.ops") + delta("wire.rx.ops")
-    syscalls = both("tx.syscalls") + both("rx.syscalls")
-    frames = (both("tx.frames") + delta("wire.rx.frames")
-              + delta("wire.native.rx.frames"))
-    zc = (both("tx.bytes_zc") + delta("wire.rx.bytes_zc")
-          + delta("wire.native.rx.bytes_zc"))
-    copied = (delta("wire.tx.bytes_copy") + delta("wire.rx.bytes_copy")
-              + delta("wire.native.rx.bytes_copy"))
-    occ_n = 0
-    occ_sum = 0.0
-    res_p99 = 0.0
-    for p0, p1 in zip(pre, post):
-        h1 = p1.get("histograms", {}).get("wire.batch_occupancy") or {}
-        h0 = p0.get("histograms", {}).get("wire.batch_occupancy") or {}
-        occ_n += max(h1.get("count", 0) - h0.get("count", 0), 0)
-        occ_sum += max(h1.get("sum", 0.0) - h0.get("sum", 0.0), 0.0)
-        hr = p1.get("histograms", {}).get("wire.lane_residency_s") or {}
-        res_p99 = max(res_p99, hr.get("p99") or 0.0)
-    recs = delta("wire.telemetry.records")
-    flushes = delta("wire.telemetry.flushes")
-    return {
-        "ops": ops,
-        "wall_s": round(wall, 4),
-        "ops_per_s": round(ops / max(wall, 1e-9), 1),
-        "syscalls_per_op": (round(syscalls / ops, 3) if ops else None),
-        "frames_per_op": (round(frames / ops, 3) if ops else None),
-        "batch_fill": (round(occ_sum / occ_n, 2) if occ_n else None),
-        "residency_p99_ms": round(res_p99 * 1e3, 3),
-        "zc_share": (round(zc / (zc + copied), 3)
-                     if zc + copied else None),
-        "records_per_flush": (round(recs / flushes, 1)
-                              if flushes else None),
-    }
-
-
-def kv_tracing_storm(n_workers: int = 2, n_servers: int = 2,
-                     msgs_per_worker: int = 40, keys_per_msg: int = 8,
-                     val_len: int = 512,
-                     tail_spec: str = "slow:p95,errors,floor:0.05",
-                     env_extra: Optional[dict] = None) -> dict:
-    """The kv loopback storm with TAIL TRACING on, followed by a live
-    ``TRACE_PULL`` assembly round (docs/observability.md): the
-    condensed result — kept/assembled counts, walls, per-stage shares
-    and the slow set's dominant stage — is what bench.py's
-    ``kv_tracing`` section embeds next to the throughput numbers.
-    Context only: stage shares are host-load-shaped, so
-    ``tools/bench_diff.py`` notes but never gates them (like the
-    windowed rates)."""
-    from .kv.kv_app import KVServer, KVServerDefaultHandle, KVWorker
-
-    env = {"PS_TRACE_TAIL": tail_spec}
-    if env_extra:
-        env.update(env_extra)
-    nodes = _loopback_cluster(n_workers, n_servers, "kv-trace", env)
-    servers = []
-    workers = []
-    try:
-        for po in nodes[1:1 + n_servers]:
-            srv = KVServer(0, postoffice=po)
-            srv.set_request_handle(KVServerDefaultHandle())
-            servers.append(srv)
-        workers = [KVWorker(0, 0, postoffice=po)
-                   for po in nodes[1 + n_servers:]]
-        span = (1 << 64) // max(keys_per_msg, 1)
-        keys = np.arange(keys_per_msg, dtype=np.uint64) * span + 3
-        vals = np.ones(keys_per_msg * val_len, np.float32)
-        outs = [np.zeros_like(vals) for _ in workers]
-        t0 = time.perf_counter()
-        for i in range(msgs_per_worker):
-            tss = [w.push(keys, vals) for w in workers]
-            for w, ts in zip(workers, tss):
-                w.wait(ts)
-            if i % 10 == 9:
-                for w, out in zip(workers, outs):
-                    w.wait(w.pull(keys, out))
-        wall = time.perf_counter() - t0
-        coll = nodes[0].collect_cluster_traces(timeout_s=10.0)
-        agg = coll.aggregate()
-        total = n_workers * msgs_per_worker
-        return {
-            "wall_s": round(wall, 4),
-            "msgs_per_s": round(total / max(wall, 1e-9), 1),
-            "assembled": agg["count"],
-            "collected": len(coll),
-            "top_stage": agg["top_stage"],
-            "trace_wall_p50_us": agg["wall_p50_us"],
-            "trace_wall_max_us": agg["wall_max_us"],
-            "stage_shares": {
-                name: info["share"]
-                for name, info in (agg.get("slow") or {}).items()
-            },
-        }
-    finally:
-        _teardown_cluster(nodes, workers, servers)
-
-
-def fault_recovery_times(quick: bool = True) -> dict:
-    """End-to-end recovery latency of the fault-tolerance tier
-    (docs/fault_tolerance.md), over an in-process loopback cluster —
-    no sockets, host-side only.
-
-    Timeline measured from the instant a server's van is killed
-    mid-service (1 worker, 2 servers, ``PS_KV_REPLICATION=2``,
-    deadlines on):
-
-    - ``kill_to_detect_s``: kill -> the scheduler's failure detector
-      broadcasts NODE_FAILURE and the worker's hook marks the rank down
-      (bounded below by PS_HEARTBEAT_TIMEOUT).
-    - ``detect_to_pull_s``: detection -> a pull of the dead rank's key
-      range completes against the replica (the failover hot path).
-    - ``kill_to_pull_s``: the sum the application experiences.
-    """
-    from .kv.kv_app import KVServer, KVServerDefaultHandle, KVWorker
-
-    hb_interval, hb_timeout = (0.2, 0.8) if quick else (0.3, 1.0)
-    nodes = _loopback_cluster(
-        num_workers=1, num_servers=2, ns="fault-recovery",
-        env_extra={
-            "PS_KV_REPLICATION": "2",
-            "PS_HEARTBEAT_INTERVAL": str(hb_interval),
-            "PS_HEARTBEAT_TIMEOUT": str(hb_timeout),
-            "PS_REQUEST_TIMEOUT": "0.5",
-            "PS_REQUEST_RETRIES": "5",
-        },
-    )
-    scheduler, server_pos, worker_po = nodes[0], nodes[1:3], nodes[3]
-    servers = []
-    for po in server_pos:
-        srv = KVServer(0, postoffice=po)
-        srv.set_request_handle(KVServerDefaultHandle())
-        servers.append(srv)
-    worker = KVWorker(0, 0, postoffice=worker_po)
-    from .base import server_rank_to_id
-
-    keys = np.array([7], dtype=np.uint64)
-    vals = np.ones(256, dtype=np.float32)
-    rounds = 3 if quick else 10
-    for _ in range(rounds):
-        worker.wait(worker.push(keys, vals))
-    time.sleep(3 * hb_interval)  # replication forwards + steady beats
-
-    victim_po = next(po for po in server_pos
-                     if po.van.my_node.id == server_rank_to_id(0))
-    dead_id = server_rank_to_id(0)
-    t_kill = time.perf_counter()
-    victim_po.van.stop()
-    while dead_id not in worker._down_servers:
-        if time.perf_counter() - t_kill > 60:
-            raise TimeoutError("failure detector never fired")
-        time.sleep(0.005)
-    t_detect = time.perf_counter()
-    out = np.zeros_like(vals)
-    worker.wait(worker.pull(keys, out))
-    t_pull = time.perf_counter()
-    ok = bool(np.all(out == rounds))
-
-    # Registry context next to the recovery numbers (timeouts, retries,
-    # failovers, replication forwards) — the telemetry satellite of
-    # docs/observability.md.
-    telemetry = {
-        "worker": _condense_snapshot(worker_po.telemetry_snapshot()),
-        "survivor_server": _condense_snapshot(next(
-            po for po in server_pos if po is not victim_po
-        ).telemetry_snapshot()),
-    }
-    worker.stop()
-    for srv, po in zip(servers, server_pos):
-        if po is not victim_po:
-            srv.stop()
-    for po in [scheduler, worker_po] + [
-        p for p in server_pos if p is not victim_po
-    ]:
-        try:
-            po.van.stop()
-        except Exception:
-            pass
-    return {
-        "kill_to_detect_s": round(t_detect - t_kill, 3),
-        "detect_to_pull_s": round(t_pull - t_detect, 3),
-        "kill_to_pull_s": round(t_pull - t_kill, 3),
-        "heartbeat_timeout_s": hb_timeout,
-        "replica_data_exact": ok,
-        "telemetry": telemetry,
-    }
-
-
-def elastic_scale_bench(quick: bool = True) -> dict:
-    """End-to-end elasticity proof (docs/elasticity.md): scale an
-    elastic cluster 2 -> 4 -> 2 servers in the middle of a push storm,
-    with NO global restart, over real TCP sockets (in-process nodes —
-    the measurement is comparative within one harness, so the shared
-    GIL prices both windows identically).
-
-    Two measured windows over the same cluster:
-
-    - **base**: storm + priority small-pull sampling with membership
-      static (the uncontended reference tail).
-    - **migration**: the same storm while two servers join (live range
-      splits + migrations) and then decommission (merges back).
-
-    Acceptance: ``p99_ratio = migration p99 / base p99 <= 3``, the
-    final store BIT-EXACT vs the completed push count (every ``wait``
-    completed or raised — wrong-epoch slices re-route transparently),
-    and zero hung requests.
-    """
-    import threading
-
-    from .kv.kv_app import KVServer, KVServerDefaultHandle, KVWorker
-    from .message import Role
-    from .environment import Environment
-    from .postoffice import Postoffice
-
-    n_keys = 32
-    val_len = 2048 if quick else 8192
-    window_s = 1.5 if quick else 4.0
-    env = {
-        "PS_ELASTIC": "1",
-        "PS_REQUEST_TIMEOUT": "3.0",
-        "PS_REQUEST_RETRIES": "8",
-    }
-    nodes = _loopback_cluster(1, 2, "elastic-scale", env, van_type="tcp")
-    servers = []
-    workers = []
-    joiner_pos: list = []
-    joiner_srvs: list = []
-    try:
-        for po in nodes[1:3]:
-            srv = KVServer(0, postoffice=po)
-            srv.set_request_handle(KVServerDefaultHandle())
-            servers.append(srv)
-        worker = KVWorker(0, 0, postoffice=nodes[3])
-        workers.append(worker)
-        span = (1 << 64) // n_keys
-        keys = (np.arange(n_keys, dtype=np.uint64) * np.uint64(span)
-                + np.uint64(3))
-        vals = np.arange(n_keys * val_len, dtype=np.float32) % 97 + 1.0
-        hot_key = keys[:1]
-        hot_out = np.zeros(val_len, np.float32)
-        pushes = [0]
-        stop = [False]
-        errors: list = []
-
-        def storm():
-            while not stop[0]:
-                try:
-                    worker.wait(worker.push(keys, vals))
-                    pushes[0] += 1
-                except Exception as exc:  # noqa: BLE001
-                    errors.append(repr(exc))
-                    return
-
-        def sample(lats, dur_s):
-            deadline = time.perf_counter() + dur_s
-            while time.perf_counter() < deadline:
-                t0 = time.perf_counter()
-                worker.wait(worker.pull(hot_key, hot_out, priority=1))
-                lats.append(time.perf_counter() - t0)
-                time.sleep(0.002)
-
-        worker.wait(worker.push(keys, vals))
-        pushes[0] += 1
-        t = threading.Thread(target=storm, daemon=True)
-        t.start()
-        base_lats: list = []
-        sample(base_lats, window_s)
-
-        def join_one():
-            po = Postoffice(Role.SERVER, env=Environment(dict(
-                nodes[3].env._overrides)))
-            po.start(0)
-            srv = KVServer(0, postoffice=po)
-            srv.set_request_handle(KVServerDefaultHandle())
-            joiner_pos.append(po)
-            joiner_srvs.append(srv)
-
-        mig_lats: list = []
-        t_mig = time.perf_counter()
-        sampler = threading.Thread(
-            target=sample, args=(mig_lats, window_s * 2 + 2.0),
-            daemon=True)
-        sampler.start()
-        join_one()
-        join_one()
-        time.sleep(window_s / 2)
-        for srv in joiner_srvs:
-            srv.decommission(timeout_s=60)
-        sampler.join(timeout=window_s * 4 + 20)
-        mig_wall = time.perf_counter() - t_mig
-        stop[0] = True
-        t.join(timeout=30)
-        n = pushes[0]
-        out = np.zeros_like(vals)
-        worker.wait(worker.pull(keys, out))
-        exact = bool(np.array_equal(out, vals * n)) and not errors
-        _, base_p99 = _pctl_ms(base_lats)
-        _, mig_p99 = _pctl_ms(mig_lats)
-        rt = nodes[3].current_routing()
-        return {
-            "pushes": n,
-            "push_mb": round(vals.nbytes / 2**20, 2),
-            "store_bitexact": exact,
-            "errors": errors[:3],
-            "joins": 2,
-            "leaves": 2,
-            "final_epoch": rt.epoch if rt else None,
-            "final_active": list(rt.active) if rt else None,
-            "scale_2_4_2_wall_s": round(mig_wall, 2),
-            "base_p99_ms": base_p99,
-            "migration_p99_ms": mig_p99,
-            "p99_ratio": (round(mig_p99 / base_p99, 2)
-                          if base_p99 > 0 else None),
-            "wrong_owner_bounces": nodes[3].metrics.counter(
-                "kv.wrong_owner_bounces").value,
-        }
-    finally:
-        _teardown_cluster(nodes, workers, servers + joiner_srvs)
-        for po in joiner_pos:
-            try:
-                po.van.stop()
-            except Exception:
-                pass
-
-
-def autopilot_bench(quick: bool = True) -> dict:
-    """Self-driving skew remediation (docs/autopilot.md): a Zipf-style
-    hot-set storm lands almost entirely on ONE of two elastic servers;
-    the autopilot senses the sustained per-server rate skew through the
-    scheduler's ClusterHistory and rebalances the hot range — with ZERO
-    operator actions.  In-process TCP cluster (comparative within one
-    harness).
-
-    Outputs the gate pair: ``load_skew_ratio`` (final-window max/mean
-    per-server request rate; lower is better — ~2.0 means the skew was
-    never fixed) and ``operator_actions`` (must be 0: every lever the
-    run pulled was the autopilot's).
-    """
-    import threading
-
-    from .cluster.autopilot import _server_rates
-    from .kv.kv_app import KVServer, KVServerDefaultHandle, KVWorker
-
-    n_keys = 32
-    val_len = 1024 if quick else 4096
-    storm_s = 6.0 if quick else 14.0
-    env = {
-        "PS_ELASTIC": "1",
-        "PS_AUTOPILOT": "1",
-        "PS_METRICS_INTERVAL": "0.25",
-        "PS_AUTOPILOT_SUSTAIN": "2",
-        # With TWO servers max >= 2.0x mean is unreachable (the cold
-        # server would need literally zero traffic), so gate at 1.5x.
-        "PS_AUTOPILOT_SKEW_RATIO": "1.5",
-        "PS_AUTOPILOT_SKEW_COOLDOWN_S": "1.0",
-        "PS_AUTOPILOT_MIN_RATE": "5.0",
-        "PS_AUTOPILOT_MAX_ACTIONS": "8",
-        "PS_AUTOPILOT_TRACE_EVERY": "0",
-        "PS_REQUEST_TIMEOUT": "3.0",
-        "PS_REQUEST_RETRIES": "8",
-    }
-    nodes = _loopback_cluster(1, 2, "autopilot", env, van_type="tcp")
-    sched = nodes[0]
-    servers = []
-    workers = []
-    try:
-        for po in nodes[1:3]:
-            srv = KVServer(0, postoffice=po)
-            srv.set_request_handle(KVServerDefaultHandle())
-            servers.append(srv)
-        worker = KVWorker(0, 0, postoffice=nodes[3])
-        workers.append(worker)
-        span = (1 << 64) // n_keys
-        keys = (np.arange(n_keys, dtype=np.uint64) * np.uint64(span)
-                + np.uint64(3))
-        vals = np.arange(n_keys * val_len, dtype=np.float32) % 97 + 1.0
-        # The hot set: the lowest quarter of the key space — entirely
-        # inside server 0's initial half.  It DRIFTS to an adjacent
-        # band mid-storm (full mode), the ROADMAP acceptance shape.
-        hot_a = keys[: n_keys // 4]
-        hot_b = keys[n_keys // 4: n_keys // 2]
-        hot_out = np.zeros(val_len * len(hot_a), np.float32)
-        pushes = [0]
-        stop = [False]
-        errors: list = []
-
-        def storm():
-            t0 = time.perf_counter()
-            while not stop[0]:
-                try:
-                    worker.wait(worker.push(keys, vals))
-                    pushes[0] += 1
-                    hot = (hot_a if quick or
-                           time.perf_counter() - t0 < storm_s / 2
-                           else hot_b)
-                    for _ in range(8):
-                        worker.wait(worker.pull(hot, hot_out))
-                except Exception as exc:  # noqa: BLE001
-                    errors.append(repr(exc))
-                    return
-
-        worker.wait(worker.push(keys, vals))
-        pushes[0] += 1
-        t = threading.Thread(target=storm, daemon=True)
-        t.start()
-        time.sleep(storm_s)
-        stop[0] = True
-        t.join(timeout=30)
-        rates = _server_rates(sched.history) if sched.history else {}
-        skew = None
-        if len(rates) >= 2:
-            mean = sum(rates.values()) / len(rates)
-            skew = round(max(rates.values()) / max(mean, 1e-9), 2)
-        n = pushes[0]
-        out = np.zeros_like(vals)
-        worker.wait(worker.pull(keys, out))
-        exact = bool(np.array_equal(out, vals * n)) and not errors
-        ap = sched.history.autopilot if sched.history else None
-        counts = ap.counts() if ap else {}
-        rt = sched.current_routing()
-        return {
-            "pushes": n,
-            "store_bitexact": exact,
-            "errors": errors[:3],
-            "load_skew_ratio": skew,
-            # Manual control-plane actions taken by this harness during
-            # the storm — the autopilot pulled every lever.
-            "operator_actions": 0,
-            "decisions_acted": counts.get("acted", 0),
-            "decisions_vetoed": counts.get("vetoed", 0),
-            "final_epoch": rt.epoch if rt else None,
-        }
-    finally:
-        _teardown_cluster(nodes, workers, servers)
-
-
 def _chunk_run(push_mb: int, n_pushes: int,
                chunk_bytes: str, extra_env: dict = None,
                mode: str = "chunk_hol") -> dict:
-    """One leg of the chunk_streaming bench: a REAL 1w+1s tcp cluster
+    """One chunked or monolithic leg: a REAL 1w+1s tcp cluster
     via the local tracker (one process per node — an in-process cluster
     would measure the shared-GIL convoy, not the transport), running
     ``--mode chunk_hol``: sequential ``push_mb``-MiB pushes from a
@@ -1695,275 +1216,6 @@ def _chunk_run(push_mb: int, n_pushes: int,
         "pull_p50_ms": float(m.group(2)),
         "pull_p99_ms": float(m.group(3)),
         "push_gbps": float(m.group(4)),
-    }
-
-
-def chunk_streaming_bench(quick: bool = True) -> dict:
-    """Chunked streaming transfers (docs/chunking.md) over a live
-    loopback cluster: (a) large-push goodput chunked vs monolithic —
-    the pipelining tax must stay small — and (b) small-pull p99 under a
-    concurrent large background push, chunked vs ``PS_CHUNK_BYTES=0`` —
-    the head-of-line win, the headline number."""
-    push_mb = 64
-    n_pushes = 4 if quick else 8
-    # 512 KiB chunks: measured sweet spot on the host stub — small
-    # enough that per-chunk GIL/copy bursts stay off the small-pull
-    # tail, large enough that goodput beats monolithic.
-    chunk_bytes = 512 << 10
-    chunked = _chunk_run(push_mb, n_pushes, str(chunk_bytes))
-    mono = _chunk_run(push_mb, n_pushes, "0")
-    out = {
-        "push_mb": push_mb,
-        "chunk_bytes": chunk_bytes,
-        "chunked_push_gbps": round(chunked["push_gbps"], 2),
-        "mono_push_gbps": round(mono["push_gbps"], 2),
-        "chunked_pull_p50_ms": round(chunked["pull_p50_ms"], 3),
-        "chunked_pull_p99_ms": round(chunked["pull_p99_ms"], 3),
-        "mono_pull_p50_ms": round(mono["pull_p50_ms"], 3),
-        "mono_pull_p99_ms": round(mono["pull_p99_ms"], 3),
-        "pull_samples": [chunked["pull_samples"], mono["pull_samples"]],
-        # Headline: how much lower the small-pull tail is with the lane
-        # interleaving between chunks instead of behind the monolith.
-        "hol_p99_ratio": (
-            round(mono["pull_p99_ms"] / chunked["pull_p99_ms"], 2)
-            if chunked["pull_p99_ms"] > 0 else None),
-        "push_tput_ratio": (
-            round(chunked["push_gbps"] / mono["push_gbps"], 3)
-            if mono["push_gbps"] > 0 else None),
-    }
-    return out
-
-
-def native_goodput_bench(quick: bool = True) -> dict:
-    """Native zero-copy data plane (docs/native_core.md) over a real
-    1w+1s tcp cluster (one process per node): 64 MiB push goodput with
-    the C++ sender lanes on (``PS_NATIVE=1``) vs the pure-Python path
-    (``PS_NATIVE=0``), plus the small-pull p99 under the same bulk
-    storm on both legs — the GIL-free plane must raise single-lane
-    goodput (ISSUE 6 target: >= 2x) WITHOUT moving the priority tail.
-    Both legs keep chunking on at the same size, so the ratio isolates
-    the encode/dispatch plane, not the pipelining win (priced by
-    chunk_streaming).  ``lane_goodput`` mode (pipelined pushes) rather
-    than ``chunk_hol``: sequential waited pushes serialize on the
-    per-push RTT + apply chain shared by both legs, which masks the
-    data-plane difference.  The window is SUSTAINED (>= 6 GiB):
-    goodput is a steady-state metric, and the two legs move in
-    OPPOSITE directions as the storm lengthens — the native leg climbs
-    as the frame/recv pools warm and the TCP windows grow (~17.4 Gbps
-    at 16 pushes -> ~19.6-22 at 96+), while the GIL-bound leg SLIDES
-    under the sustained convoy (~10.5 -> ~9-9.9) — so a short window
-    underprices exactly the gap this section exists to price.  Each
-    leg runs ``rounds`` times and reports the MEDIAN (per-round values
-    attached): residual noise is one-sided scheduler luck and the
-    median is robust to one lucky/unlucky draw where best-of-N would
-    chase the outlier."""
-    from .vans import native as _native_mod
-
-    class _ForceOn:  # availability probe must ignore the parent's env
-        @staticmethod
-        def find(key, default=None):
-            return "1"
-
-    if _native_mod.load(_ForceOn()) is None:
-        # Without this guard the PS_NATIVE=1 child silently falls back
-        # to pure Python and the section emits a bogus ~1.0 ratio that
-        # reads "native gives no win" instead of "native absent".
-        return {"skipped": "native core unavailable (libpslite_core.so "
-                           "missing or ABI-stale; build with `make "
-                           "native`)"}
-    push_mb = 64
-    n_pushes = 96 if quick else 128
-    rounds = 3
-    chunk_bytes = 2 << 20
-    leg_runs = {"native": [], "python": []}
-    # Rounds INTERLEAVE the two legs (native, python, native, ...):
-    # host-load drift over the section's wall time then lands on both
-    # legs symmetrically instead of biasing whichever leg ran last.
-    for _ in range(rounds):
-        for tag, ps_native in (("native", "1"), ("python", "0")):
-            leg_runs[tag].append(_chunk_run(
-                push_mb, n_pushes, str(chunk_bytes),
-                # _chunk_run's 256 KiB socket-buffer caps stay: bounded
-                # kernel buffering is what makes this a DATA-PLANE
-                # measurement.  With autotuned (multi-MiB) buffers the
-                # kernel pipelines around the GIL-bound leg's slow
-                # encode (measured: the Python leg jumps ~11 -> ~15
-                # Gbps while native holds ~19-20) and the ratio prices
-                # the kernel knob, not the plane.  Under bounded
-                # buffers throughput tracks how fast each side REFILLS/
-                # DRAINS its window — exactly the send/recv hot path.
-                extra_env={"PS_NATIVE": ps_native,
-                           "PS_BENCH_PIPELINE": "4"},
-                mode="lane_goodput",
-            ))
-    legs = {}
-    med = statistics.median
-    for tag, runs in leg_runs.items():
-        legs[tag] = {
-            "push_gbps": med(r["push_gbps"] for r in runs),
-            "pull_p99_ms": med(r["pull_p99_ms"] for r in runs),
-            "pull_samples": sum(r["pull_samples"] for r in runs),
-            "rounds_gbps": [round(r["push_gbps"], 2) for r in runs],
-        }
-    nat, py = legs["native"], legs["python"]
-    return {
-        "push_mb": push_mb,
-        "chunk_bytes": chunk_bytes,
-        "rounds": rounds,
-        "native_push_gbps": round(nat["push_gbps"], 2),
-        "python_push_gbps": round(py["push_gbps"], 2),
-        "native_rounds_gbps": nat["rounds_gbps"],
-        "python_rounds_gbps": py["rounds_gbps"],
-        "native_pull_p99_ms": round(nat["pull_p99_ms"], 3),
-        "python_pull_p99_ms": round(py["pull_p99_ms"], 3),
-        "pull_samples": [nat["pull_samples"], py["pull_samples"]],
-        # Headline: single-lane goodput, GIL-free vs GIL-bound.
-        "goodput_ratio": (
-            round(nat["push_gbps"] / py["push_gbps"], 2)
-            if py["push_gbps"] > 0 else None),
-        # Guard: the native lanes must preserve the priority
-        # discipline (<= 1 means the tail improved or held).
-        "p99_ratio_native_vs_python": (
-            round(nat["pull_p99_ms"] / py["pull_p99_ms"], 2)
-            if py["pull_p99_ms"] > 0 else None),
-    }
-
-
-def quantized_push_bench(quick: bool = True) -> dict:
-    """Quantized transport tier (docs/compression.md) over the real
-    1w+1s tcp cluster: the 64 MiB ``quantized_push`` storm (pipelined
-    pushes + concurrent priority small-pulls) uncompressed vs int8 vs
-    fp8_e4m3, all legs sharing the van settings of ``native_goodput``
-    (2 MiB chunks, bounded socket buffers, pipeline depth 4).
-
-    Headline: ``goodput_ratio_<codec>`` — EFFECTIVE goodput (raw
-    payload bytes per second, i.e. pre-compression) relative to the
-    uncompressed leg — with the concurrent priority small-pull p99
-    ratio as the tail guard (acceptance: >= 2x at p99 <= 1.3x).
-
-    The headline codec legs run with error feedback OFF
-    (``PS_CODEC_EF=0``): EF's fold+decode+update roughly doubles the
-    encode memory traffic, and its convergence value is priced by the
-    dedicated guard test, not this throughput section.  The ``int8_ef``
-    leg re-runs int8 with EF ON so the bench records what the
-    convergence-preserving configuration actually costs."""
-    from .ops import codecs as codecs_mod
-
-    push_mb = 64
-    n_pushes = 32 if quick else 96
-    rounds = 1 if quick else 3
-    chunk_bytes = 2 << 20
-    base_env = {
-        "PS_BENCH_PIPELINE": "4",
-        # Enough pooled decode buffers for the pipeline depth (the
-        # first cold 64 MiB allocations cost tens of ms of page
-        # faults; see _BufPool) — the warmup pushes then prime them.
-        "PS_CODEC_POOL_MB": "1024",
-    }
-    legs_spec = [("raw", "", "0"), ("int8", "int8", "0")]
-    if "fp8_e4m3" in codecs_mod.names():
-        legs_spec.append(("fp8_e4m3", "fp8_e4m3", "0"))
-    legs_spec.append(("int8_ef", "int8", "1"))
-    leg_runs = {tag: [] for tag, _, _ in legs_spec}
-    # Interleaved rounds (the native_goodput lesson): host-load drift
-    # lands on every leg symmetrically instead of biasing the last.
-    for _ in range(rounds):
-        for tag, codec, ef in legs_spec:
-            env = dict(base_env, PS_BENCH_CODEC=codec, PS_CODEC_EF=ef)
-            leg_runs[tag].append(_chunk_run(
-                push_mb, n_pushes, str(chunk_bytes),
-                extra_env=env, mode="quantized_push",
-            ))
-    med = statistics.median
-    legs = {}
-    for tag, runs in leg_runs.items():
-        legs[tag] = {
-            "push_gbps": med(r["push_gbps"] for r in runs),
-            "pull_p99_ms": med(r["pull_p99_ms"] for r in runs),
-            "pull_samples": sum(r["pull_samples"] for r in runs),
-        }
-    raw = legs["raw"]
-    out = {
-        "push_mb": push_mb,
-        "chunk_bytes": chunk_bytes,
-        "rounds": rounds,
-        "raw_push_gbps": round(raw["push_gbps"], 2),
-        "raw_pull_p99_ms": round(raw["pull_p99_ms"], 3),
-    }
-    for tag, _, ef in legs_spec:
-        if tag == "raw":
-            continue
-        leg = legs[tag]
-        out[f"{tag}_push_gbps"] = round(leg["push_gbps"], 2)
-        out[f"{tag}_pull_p99_ms"] = round(leg["pull_p99_ms"], 3)
-        # Effective goodput ratio: raw-bytes throughput compressed vs
-        # uncompressed (the >= 2x acceptance headline).
-        out[f"goodput_ratio_{tag}"] = (
-            round(leg["push_gbps"] / raw["push_gbps"], 2)
-            if raw["push_gbps"] > 0 else None)
-        # Tail guard: the priority small-pull p99 must not degrade
-        # beyond 1.3x under the compressed storm.
-        out[f"p99_ratio_{tag}"] = (
-            round(leg["pull_p99_ms"] / raw["pull_p99_ms"], 2)
-            if raw["pull_p99_ms"] > 0 else None)
-    return out
-
-
-def _mt_run(serve_s: float, bulk: bool, extra_env: dict = None) -> dict:
-    """One leg of the multi_tenant bench: a REAL 2w+1s tcp cluster
-    (one process per node) running ``--mode multi_tenant`` — rank 0
-    serves, rank 1 storms (or idles for the baseline leg)."""
-    import re
-    import subprocess
-    import sys
-
-    cmd = [
-        sys.executable, "-m", "pslite_tpu.tracker.local",
-        "-n", "2", "-s", "1", "--van", "tcp", "--",
-        sys.executable, "-m", "pslite_tpu.benchmark",
-        "--mode", "multi_tenant", "--len", "1024", "--repeat", "1",
-    ]
-    env = dict(
-        os.environ,
-        JAX_PLATFORMS="cpu",
-        PS_TENANTS="serve:8,train:1",
-        PS_TENANT_QUEUE_LIMIT="8",
-        PS_MT_SERVE_SECONDS=str(serve_s),
-        PS_MT_BULK="1" if bulk else "0",
-        # Fine scheduling quanta (both legs, so the baseline is fair):
-        # 256 KiB wire chunks and 512 KiB apply task groups bound the
-        # non-preemptible in-service wait an express pull can see to
-        # well under a millisecond each.
-        PS_CHUNK_BYTES=str(256 << 10),
-        PS_APPLY_TASK_BYTES=str(512 << 10),
-        # Bounded kernel buffers, like chunk_streaming: the serving
-        # tail must measure the SCHEDULER, not unbounded socket bloat.
-        PS_TCP_SNDBUF=str(256 << 10),
-        PS_TCP_RCVBUF=str(256 << 10),
-        PS_RECV_POOL_MB="512",
-    )
-    env.update(extra_env or {})
-    r = subprocess.run(cmd, capture_output=True, text=True, timeout=600,
-                       env=env)
-    ms = re.search(
-        r"MULTI_TENANT role=serve samples=(\d+) pull_p50_ms=([0-9.]+) "
-        r"pull_p99_ms=([0-9.]+)", r.stdout)
-    mb = re.search(
-        r"MULTI_TENANT role=bulk applied=(\d+) shed=(\d+) "
-        r"push_gbps=([0-9.]+) store_exact=(True|False)", r.stdout)
-    if ms is None or mb is None:
-        raise RuntimeError(
-            f"multi_tenant leg produced no result (rc={r.returncode}): "
-            f"{r.stdout[-600:]}\n{r.stderr[-600:]}"
-        )
-    return {
-        "samples": int(ms.group(1)),
-        "pull_p50_ms": float(ms.group(2)),
-        "pull_p99_ms": float(ms.group(3)),
-        "applied": int(mb.group(1)),
-        "shed": int(mb.group(2)),
-        "bulk_gbps": float(mb.group(3)),
-        "store_exact": mb.group(4) == "True",
     }
 
 
@@ -2059,77 +1311,8 @@ def admission_probe(n_pushes: int = 64, limit: int = 4) -> dict:
     }
 
 
-def multi_tenant_bench(quick: bool = True) -> dict:
-    """Multi-tenant serving QoS (docs/qos.md) over real tcp processes.
-
-    Two headline halves (the ISSUE 8 acceptance):
-
-    - **Isolation**: a bulk tenant (``train``, weight 1) offering
-      multi-MiB pushes at ~10x capacity must not move the serving
-      tenant's (``serve``, weight 8) small-pull p99 by more than 2x vs
-      the uncontended baseline over the identical cluster shape —
-      express scheduling + weighted-fair lanes/intake/apply shards
-      with bounded per-tenant admission.  Legs run in INTERLEAVED
-      rounds and report medians (host drift lands symmetrically).
-    - **Hot-key cache**: the DLRM Zipf single-row pull storm's p50
-      improves >= 5x with ``PS_HOT_CACHE=1`` at the default size, hit
-      rate >= 60%, values spot-checked bit-exact.
-
-    Plus the admission probe: a flooded tiny-limit server sheds with
-    OPT_OVERLOAD fast-fails — no dropped or hanging wait()s, store
-    bit-exact at applied-count."""
-    serve_s = 3.0 if quick else 6.0
-    n_pulls = 500 if quick else 2000
-    rounds = 2 if quick else 3
-    legs = {"base": [], "loaded": []}
-    for _ in range(rounds):
-        legs["base"].append(_mt_run(serve_s, bulk=False))
-        legs["loaded"].append(_mt_run(serve_s, bulk=True))
-    med = statistics.median
-    base_p50 = med(r["pull_p50_ms"] for r in legs["base"])
-    base_p99 = med(r["pull_p99_ms"] for r in legs["base"])
-    load_p50 = med(r["pull_p50_ms"] for r in legs["loaded"])
-    load_p99 = med(r["pull_p99_ms"] for r in legs["loaded"])
-    loaded_last = legs["loaded"][-1]
-    dlrm_off = _dlrm_run(n_pulls, cache=False)
-    dlrm_on = _dlrm_run(n_pulls, cache=True)
-    probe = admission_probe()
-    return {
-        "serve_seconds": serve_s,
-        "rounds": rounds,
-        "serve_samples": [sum(r["samples"] for r in legs["base"]),
-                          sum(r["samples"] for r in legs["loaded"])],
-        "serve_p50_uncontended_ms": round(base_p50, 3),
-        "serve_p99_uncontended_ms": round(base_p99, 3),
-        "serve_p50_contended_ms": round(load_p50, 3),
-        "serve_p99_contended_ms": round(load_p99, 3),
-        # Headline 1: the isolation guard (acceptance: <= 2.0).
-        "p99_ratio": (round(load_p99 / base_p99, 2)
-                      if base_p99 > 0 else None),
-        "bulk_applied": loaded_last["applied"],
-        "bulk_shed": loaded_last["shed"],
-        "bulk_push_gbps": round(loaded_last["bulk_gbps"], 2),
-        "store_exact": all(r["store_exact"] for r in legs["loaded"]),
-        "dlrm_pulls": n_pulls,
-        "dlrm_p50_off_ms": round(dlrm_off["pull_p50_ms"], 4),
-        "dlrm_p50_on_ms": round(dlrm_on["pull_p50_ms"], 4),
-        "dlrm_p99_off_ms": round(dlrm_off["pull_p99_ms"], 4),
-        "dlrm_p99_on_ms": round(dlrm_on["pull_p99_ms"], 4),
-        # Headline 2: the round-trip savings (acceptance: >= 5.0).
-        "dlrm_p50_ratio": (
-            round(dlrm_off["pull_p50_ms"] / dlrm_on["pull_p50_ms"], 2)
-            if dlrm_on["pull_p50_ms"] > 0 else None),
-        # Acceptance: >= 0.60 at the default cache size.
-        "hit_rate": dlrm_on["hit_rate"],
-        "admission_offered": probe["offered"],
-        "admission_applied": probe["applied"],
-        "admission_shed": probe["shed"],
-        "admission_store_exact": probe["store_exact"],
-    }
-
-
 def _small_op_run(secs: float, batch: bool) -> dict:
-    """One leg of the small_op_batching bench: a REAL 1w+1s tcp
+    """One batched or unbatched small-op leg: a REAL 1w+1s tcp
     cluster (one process per node) running ``--mode small_op_storm``.
     The batched leg runs the combiner tuned for 4 KiB ops (256 KiB
     frame cap ~= 64-op frames); the baseline leg is ``PS_BATCH_BYTES=0``
@@ -2178,51 +1361,9 @@ def _small_op_run(secs: float, batch: bool) -> dict:
     }
 
 
-def small_op_bench(quick: bool = True) -> dict:
-    """Small-op aggregation plane (docs/batching.md) over real tcp
-    processes — the ops/s counterpart of native_goodput's bytes/s.
-
-    Headline (the ISSUE 10 acceptance): a 4 KiB-op 1w+1s push storm
-    moves >= 4x more msgs/s with the combiner on (EXT_BATCH multi-op
-    frames + batched server apply + one response frame per batch) than
-    with ``PS_BATCH_BYTES=0``, while the LOW-LOAD sequential push p50
-    stays within 1.5x of unbatched (window 0 — a lone op closes at the
-    next dispatcher pickup, no timer latency) and the store ends
-    bit-exact on both legs.  Legs run in INTERLEAVED rounds, medians
-    reported (host drift lands symmetrically)."""
-    secs = 3.0 if quick else 6.0
-    rounds = 2 if quick else 3
-    legs = {"batched": [], "unbatched": []}
-    for _ in range(rounds):
-        legs["batched"].append(_small_op_run(secs, batch=True))
-        legs["unbatched"].append(_small_op_run(secs, batch=False))
-    med = statistics.median
-    b_rate = med(r["msgs_per_s"] for r in legs["batched"])
-    u_rate = med(r["msgs_per_s"] for r in legs["unbatched"])
-    b_p50 = med(r["p50_ms"] for r in legs["batched"])
-    u_p50 = med(r["p50_ms"] for r in legs["unbatched"])
-    return {
-        "seconds": secs,
-        "rounds": rounds,
-        "op_bytes": 4096,
-        "batched_msgs_per_s": round(b_rate, 1),
-        "unbatched_msgs_per_s": round(u_rate, 1),
-        # Headline: the ops/s multiple (acceptance: >= 4.0).
-        "msgs_ratio": (round(b_rate / u_rate, 2) if u_rate > 0 else None),
-        "ops_per_frame": med(r["ops_per_frame"] for r in legs["batched"]),
-        "batched_p50_ms": round(b_p50, 3),
-        "unbatched_p50_ms": round(u_p50, 3),
-        # Low-load single-op latency guard (acceptance: <= 1.5).
-        "low_load_p50_ratio": (round(b_p50 / u_p50, 2)
-                               if u_p50 > 0 else None),
-        "store_exact": all(r["store_exact"]
-                           for leg in legs.values() for r in leg),
-    }
-
-
 def _serving_fanin_run(secs: float, batch: bool,
                        servers: int = 2) -> dict:
-    """One leg of the serving_fanin bench: a REAL 1w+Ns tcp cluster
+    """One aggregated or plain fan-in leg: a REAL 1w+Ns tcp cluster
     (one process per node) running ``--mode serving_fanin``.  The
     aggregated leg runs the op combiner + response combiner tuned for
     the 64-lookup fan-out; the baseline leg is ``PS_BATCH_BYTES=0`` —
@@ -2270,426 +1411,6 @@ def _serving_fanin_run(secs: float, batch: bool,
         "frames_per_req": float(m.group(8)),
         "low_p50_ms": float(m.group(9)),
         "store_exact": m.group(10) == "True",
-    }
-
-
-def serving_fanin_bench(quick: bool = True) -> dict:
-    """Serving fan-in (docs/batching.md, ISSUE 11) over real tcp
-    processes — multi-get + server-side response aggregation.
-
-    Headline: the DLRM Zipf fan-out storm (64 single-row lookups per
-    request, table spread across 2 servers, hot-key cache COLD) moves
-    >= 3x more requests/s with the aggregation planes on
-    (``PS_BATCH_BYTES=262144`` -> one EXT_BATCH frame per server each
-    way via ``multi_get`` + the batched group response) than with
-    ``PS_BATCH_BYTES=0``, while response frames per request land near
-    the contacted-server count (~1 RTT fan-in, vs ~fanout frames
-    unaggregated), the LOW-LOAD sequential single-pull p50 stays
-    within 1.5x of unaggregated, and every spot-checked request is
-    bit-exact on both legs.  Legs run in INTERLEAVED rounds, medians
-    reported (host drift lands symmetrically)."""
-    secs = 3.0 if quick else 6.0
-    rounds = 2 if quick else 3
-    legs = {"agg": [], "plain": []}
-    for _ in range(rounds):
-        legs["agg"].append(_serving_fanin_run(secs, batch=True))
-        legs["plain"].append(_serving_fanin_run(secs, batch=False))
-    med = statistics.median
-    a_rate = med(r["reqs_per_s"] for r in legs["agg"])
-    p_rate = med(r["reqs_per_s"] for r in legs["plain"])
-    a_low = med(r["low_p50_ms"] for r in legs["agg"])
-    p_low = med(r["low_p50_ms"] for r in legs["plain"])
-    return {
-        "seconds": secs,
-        "rounds": rounds,
-        "fanout": legs["agg"][0]["fanout"],
-        "servers": legs["agg"][0]["servers"],
-        "agg_reqs_per_s": round(a_rate, 1),
-        "plain_reqs_per_s": round(p_rate, 1),
-        # Headline: the requests/s multiple (acceptance: >= 3.0).
-        "req_ratio": (round(a_rate / p_rate, 2) if p_rate > 0 else None),
-        "req_p50_agg_ms": round(
-            med(r["p50_ms"] for r in legs["agg"]), 3),
-        "req_p50_plain_ms": round(
-            med(r["p50_ms"] for r in legs["plain"]), 3),
-        # ~1 RTT fan-in: response frames/request near the contacted-
-        # server count (acceptance: lower is better; the plain leg
-        # sits near the fan-out).
-        "frames_per_req": round(
-            med(r["frames_per_req"] for r in legs["agg"]), 2),
-        "plain_frames_per_req": round(
-            med(r["frames_per_req"] for r in legs["plain"]), 2),
-        # Low-load single-pull latency guard (acceptance: <= 1.5).
-        "low_load_p50_ratio": (round(a_low / p_low, 2)
-                               if p_low > 0 else None),
-        "store_exact": all(r["store_exact"]
-                           for leg in legs.values() for r in leg),
-    }
-
-
-def _replica_read_run(secs: float, k: int, servers: int = 3,
-                      workers: int = 3) -> dict:
-    """One leg of the replica_read bench: a REAL 3w+3s tcp cluster
-    (one process per node) running ``--mode replica_read`` at
-    replication factor ``k``.  Three workers storm the same rank's
-    range — the aggregate read demand a single primary cannot absorb.
-    The k=3 leg spreads the pulls across that rank's whole chain; the
-    k=1 leg is the primary-funnel baseline.  Both legs run with the
-    push-stamp plane on (``PS_REPLICA_READS`` enables it server-side
-    even at k=1) so the comparison prices the spread, not the
-    stamps."""
-    import re
-    import subprocess
-    import sys
-
-    cmd = [
-        sys.executable, "-m", "pslite_tpu.tracker.local",
-        "-n", str(workers), "-s", str(servers), "--van", "tcp", "--",
-        sys.executable, "-m", "pslite_tpu.benchmark",
-        "--mode", "replica_read", "--repeat", "1",
-    ]
-    env = dict(
-        os.environ,
-        JAX_PLATFORMS="cpu",
-        PS_RR_SECONDS=str(secs),
-        PS_KV_REPLICATION=str(k),
-        PS_REPLICA_READS="1",
-        PS_HOT_CACHE="0",  # throughput must price network reads
-        PS_REQUEST_TIMEOUT="5.0",
-        PS_REQUEST_RETRIES="6",
-    )
-    r = subprocess.run(cmd, capture_output=True, text=True, timeout=600,
-                       env=env)
-    ms = re.findall(
-        r"REPLICA_READ reqs=(\d+) secs=([0-9.]+) "
-        r"reqs_per_s=([0-9.]+) k=(\d+) servers=(\d+) "
-        r"ryw_violations=(\d+) fallbacks=(\d+) spread=(\d+) "
-        r"p50_ms=([0-9.]+) p99_ms=([0-9.]+) exact=(True|False)",
-        r.stdout)
-    if len(ms) != workers:
-        raise RuntimeError(
-            f"replica_read leg expected {workers} worker reports, got "
-            f"{len(ms)} (rc={r.returncode}): "
-            f"{r.stdout[-600:]}\n{r.stderr[-600:]}"
-        )
-    p50s = sorted(float(m[8]) for m in ms)
-    return {
-        "reqs": sum(int(m[0]) for m in ms),
-        # Workers storm concurrently: the cluster rate is the sum.
-        "reqs_per_s": sum(float(m[2]) for m in ms),
-        "k": int(ms[0][3]),
-        "servers": int(ms[0][4]),
-        "ryw_violations": sum(int(m[5]) for m in ms),
-        "fallbacks": sum(int(m[6]) for m in ms),
-        "spread": sum(int(m[7]) for m in ms),
-        "p50_ms": p50s[len(p50s) // 2],
-        "p99_ms": max(float(m[9]) for m in ms),
-        "exact": all(m[10] == "True" for m in ms),
-    }
-
-
-def namespace_flip_storm(secs: float = 2.0, rows: int = 512,
-                         dim: int = 16) -> dict:
-    """Live model-version publish + flip + rollback under a replica-
-    read pull storm (docs/serving_reads.md): 1w+3s in-process cluster
-    at k=3, a background puller hammering rank 0's range while the
-    scheduler snapshots the v1 store, mutates it to v2, publishes the
-    v1 manifest as a namespace, and rolls back.  Acceptance: ZERO
-    failed requests, every answer bit-exact against exactly one of
-    the two versions."""
-    import shutil
-    import tempfile
-    import threading
-
-    from .kv.kv_app import KVServer, KVServerDefaultHandle, KVWorker
-
-    snapdir = tempfile.mkdtemp(prefix="ps_nsflip_")
-    nodes = _loopback_cluster(1, 3, "nsflip", env_extra={
-        "PS_KV_REPLICATION": "3",
-        "PS_REPLICA_READS": "1",
-        "PS_REQUEST_TIMEOUT": "2.0",
-        "PS_REQUEST_RETRIES": "6",
-        "PS_SNAPSHOT_DIR": snapdir,
-    })
-    scheduler, server_pos, worker_po = nodes[0], nodes[1:4], nodes[4]
-    servers = []
-    workers = []
-    result: dict = {}
-    try:
-        for po in server_pos:
-            s = KVServer(0, postoffice=po)
-            s.set_request_handle(KVServerDefaultHandle())
-            servers.append(s)
-        w = KVWorker(0, 0, postoffice=worker_po)
-        workers.append(w)
-        keys = np.arange(rows, dtype=np.uint64)  # rank 0's range
-        v1 = np.stack([np.full(dim, 1.0 + r, np.float32)
-                       for r in range(rows)])
-        w.wait(w.push(keys, v1.reshape(-1)))
-        time.sleep(0.3)  # forwards land on the whole chain
-        scheduler.snapshot()
-        w.wait(w.push(keys, v1.reshape(-1)))  # live store is now v2
-        v2 = 2 * v1
-        batch = 16
-        stop = threading.Event()
-        errors = [0]
-        pulls = [0]
-
-        def storm():
-            out = np.zeros(batch * dim, np.float32)
-            i = 0
-            while not stop.is_set():
-                start = (i * 7) % (rows - batch)
-                i += 1
-                out[:] = 0
-                try:
-                    w.wait(w.pull(keys[start:start + batch], out))
-                except Exception:
-                    errors[0] += 1
-                    continue
-                got = out.reshape(batch, dim)
-                blk1 = v1[start:start + batch]
-                blk2 = v2[start:start + batch]
-                if not (np.array_equal(got, blk1)
-                        or np.array_equal(got, blk2)):
-                    errors[0] += 1
-                pulls[0] += 1
-
-        t = threading.Thread(target=storm, daemon=True)
-        t.start()
-        time.sleep(min(0.5, secs / 4))
-        t1 = time.perf_counter()
-        scheduler.publish_model(namespace="bench", version="v1")
-        flip_ms = (time.perf_counter() - t1) * 1e3
-        time.sleep(min(0.5, secs / 4))
-        t1 = time.perf_counter()
-        scheduler.rollback_model()
-        rollback_ms = (time.perf_counter() - t1) * 1e3
-        time.sleep(min(0.5, secs / 4))
-        stop.set()
-        t.join(timeout=10)
-        # Post-rollback the live (v2) store must serve bit-exact.
-        out = np.zeros(batch * dim, np.float32)
-        w.wait(w.pull(keys[:batch], out))
-        result = {
-            "ns_flip_ms": round(flip_ms, 1),
-            "ns_rollback_ms": round(rollback_ms, 1),
-            "ns_flip_errors": errors[0],
-            "ns_flip_pulls": pulls[0],
-            "ns_flip_exact": bool(
-                np.array_equal(out.reshape(batch, dim), v2[:batch])),
-        }
-    finally:
-        _teardown_cluster(nodes, workers, servers)
-        shutil.rmtree(snapdir, ignore_errors=True)
-    return result
-
-
-def replica_read_bench(quick: bool = True) -> dict:
-    """Replica read fan-out (docs/serving_reads.md) over real tcp
-    processes: the read-heavy Zipf storm against one rank's range at
-    k=3 (pulls spread across the whole chain, stamp-validated) vs k=1
-    (every read funnels through the primary).
-
-    Headline: k=3 moves >= 2.5x more reads/s than k=1 with ZERO
-    read-your-writes violations counted by the in-storm probes, every
-    spot check bit-exact.  Legs run in INTERLEAVED rounds, medians
-    reported.  Plus the namespace-flip leg: a live model-version
-    publish/flip/rollback under the same storm with zero failed
-    requests.
-
-    The throughput legs need real parallelism — 3 worker + 3 server
-    processes all hot — so on hosts with fewer than 8 cpus they
-    record a skip marker instead of an inverted ratio that only
-    measures context-switch pressure (the 1-core CI container cannot
-    express a spread win by construction).  The namespace-flip
-    correctness leg runs everywhere."""
-    out: dict = {}
-    ncpu = os.cpu_count() or 1
-    if ncpu < 8:
-        out["skipped"] = (
-            f"spread throughput needs >= 8 cpus, have {ncpu}")
-    else:
-        secs = 3.0 if quick else 6.0
-        rounds = 2 if quick else 3
-        legs = {"k3": [], "k1": []}
-        for _ in range(rounds):
-            legs["k3"].append(_replica_read_run(secs, k=3))
-            legs["k1"].append(_replica_read_run(secs, k=1))
-        med = statistics.median
-        r3 = med(r["reqs_per_s"] for r in legs["k3"])
-        r1 = med(r["reqs_per_s"] for r in legs["k1"])
-        out = {
-            "seconds": secs,
-            "rounds": rounds,
-            "servers": legs["k3"][0]["servers"],
-            "k3_reqs_per_s": round(r3, 1),
-            "k1_reqs_per_s": round(r1, 1),
-            # Headline: the reads/s multiple (acceptance: >= 2.5).
-            "tput_ratio": round(r3 / r1, 2) if r1 > 0 else None,
-            # Correctness gate: MUST stay 0 (bench_diff fails it).
-            "ryw_violations": sum(r["ryw_violations"]
-                                  for leg in legs.values()
-                                  for r in leg),
-            "fallbacks": sum(r["fallbacks"] for r in legs["k3"]),
-            "spread_reads": sum(r["spread"] for r in legs["k3"]),
-            "p50_k3_ms": round(
-                med(r["p50_ms"] for r in legs["k3"]), 3),
-            "p50_k1_ms": round(
-                med(r["p50_ms"] for r in legs["k1"]), 3),
-            "exact": all(r["exact"]
-                         for leg in legs.values() for r in leg),
-        }
-    out.update(namespace_flip_storm(secs=2.0 if quick else 3.0))
-    return out
-
-
-def _durable_run(n_pulls: int, ram_mb: float, rows: int,
-                 dim: int) -> dict:
-    """One leg of the durable_store bench: a REAL 1w+1s tcp cluster
-    (one process per node) running ``--mode durable_serve``, with the
-    server's store either tiered (``PS_STORE_RAM_MB`` bounding RAM to
-    ~1/4 of the table) or all-RAM (0, frame-for-frame the pre-tier
-    build)."""
-    import re
-    import subprocess
-    import sys
-
-    cmd = [
-        sys.executable, "-m", "pslite_tpu.tracker.local",
-        "-n", "1", "-s", "1", "--van", "tcp", "--",
-        sys.executable, "-m", "pslite_tpu.benchmark",
-        "--mode", "durable_serve", "--repeat", str(n_pulls),
-    ]
-    env = dict(
-        os.environ,
-        JAX_PLATFORMS="cpu",
-        PS_DUR_ROWS=str(rows),
-        PS_DUR_DIM=str(dim),
-        PS_STORE_RAM_MB=str(ram_mb),
-    )
-    r = subprocess.run(cmd, capture_output=True, text=True, timeout=600,
-                       env=env)
-    m = re.search(
-        r"DURABLE_SERVE samples=(\d+) pull_p50_ms=([0-9.]+) "
-        r"pull_p99_ms=([0-9.]+) exact=True", r.stdout)
-    if m is None:
-        raise RuntimeError(
-            f"durable_serve leg produced no result (rc={r.returncode}): "
-            f"{r.stdout[-600:]}\n{r.stderr[-600:]}"
-        )
-    return {
-        "samples": int(m.group(1)),
-        "pull_p50_ms": float(m.group(2)),
-        "pull_p99_ms": float(m.group(3)),
-    }
-
-
-def durable_snapshot_times(n_keys: int = 512,
-                           val_len: int = 1024) -> dict:
-    """Snapshot/restore wall times over an in-process loopback cluster
-    (docs/durability.md): push a known store, time the coordinated
-    ``Postoffice.snapshot()`` cut, kill the WHOLE cluster, boot a fresh
-    one with ``PS_SNAPSHOT_RESTORE=1``, time the boot restore, and
-    verify the restored pulls bit-exact."""
-    import tempfile
-
-    import numpy as np
-
-    from .kv.kv_app import KVServer, KVServerDefaultHandle, KVWorker
-
-    snapdir = tempfile.mkdtemp(prefix="pslite_snap_bench_")
-    keys = np.arange(n_keys, dtype=np.uint64)
-    vals = np.random.default_rng(11).normal(
-        size=n_keys * val_len).astype(np.float32)
-
-    def boot(extra):
-        env = {"PS_SNAPSHOT_DIR": snapdir}
-        env.update(extra)
-        nodes = _loopback_cluster(1, 1, ns=f"dur-snap-{os.getpid()}",
-                                  env_extra=env)
-        srv = KVServer(0, postoffice=nodes[1])
-        t0 = time.perf_counter()
-        srv.set_request_handle(KVServerDefaultHandle())
-        restore_s = time.perf_counter() - t0
-        w = KVWorker(0, 0, postoffice=nodes[2])
-        return nodes, srv, w, restore_s
-
-    out = {"keys": n_keys,
-           "mb": round(n_keys * val_len * 4 / 2**20, 2)}
-    nodes, srv, w, _ = boot({})
-    try:
-        w.wait(w.push(keys, vals))
-        t0 = time.perf_counter()
-        nodes[0].snapshot()
-        out["snapshot_s"] = round(time.perf_counter() - t0, 3)
-    finally:
-        _teardown_cluster(nodes, [w], [srv])
-    nodes, srv, w, restore_s = boot({"PS_SNAPSHOT_RESTORE": "1"})
-    try:
-        got = np.zeros_like(vals)
-        w.wait(w.pull(keys, got))
-        out["restore_s"] = round(restore_s, 3)
-        out["restore_exact"] = bool(np.array_equal(got, vals))
-    finally:
-        _teardown_cluster(nodes, [w], [srv])
-    import shutil
-
-    shutil.rmtree(snapdir, ignore_errors=True)
-    return out
-
-
-def durable_store_bench(quick: bool = True) -> dict:
-    """Durable state tier (docs/durability.md) — the ISSUE 14
-    acceptance, over real tcp processes:
-
-    - **Beyond-RAM serving**: the DLRM Zipf single-row pull storm over
-      a table ~4x larger than ``PS_STORE_RAM_MB`` must hold its
-      hot-set p99 within 2x of the identical all-RAM run (legs run in
-      INTERLEAVED rounds, medians reported; bit-exactness is verified
-      inside the mode every 64th pull).
-    - **Kill the whole cluster, restore bit-exact**: the coordinated
-      snapshot + ``PS_SNAPSHOT_RESTORE=1`` boot, with both walls
-      reported (``durable_restore_s`` is gated in bench_diff)."""
-    rows = 512 if quick else 1024
-    dim = 1024  # 4 KiB per row
-    table_mb = rows * dim * 4 / 2**20
-    ram_mb = max(0.25, table_mb / 4.0)
-    n_pulls = 400 if quick else 1500
-    rounds = 2 if quick else 3
-    legs = {"ram": [], "tiered": []}
-    for _ in range(rounds):
-        legs["ram"].append(_durable_run(n_pulls, 0, rows, dim))
-        legs["tiered"].append(_durable_run(n_pulls, ram_mb, rows, dim))
-    med = statistics.median
-    ram_p50 = med(r["pull_p50_ms"] for r in legs["ram"])
-    ram_p99 = med(r["pull_p99_ms"] for r in legs["ram"])
-    t_p50 = med(r["pull_p50_ms"] for r in legs["tiered"])
-    t_p99 = med(r["pull_p99_ms"] for r in legs["tiered"])
-    snap = durable_snapshot_times(
-        n_keys=256 if quick else 1024)
-    return {
-        "rows": rows,
-        "dim": dim,
-        "table_mb": round(table_mb, 1),
-        "ram_mb": round(ram_mb, 2),
-        "rounds": rounds,
-        "pulls": n_pulls,
-        "hot_p50_allram_ms": round(ram_p50, 4),
-        "hot_p50_tiered_ms": round(t_p50, 4),
-        "hot_p99_allram_ms": round(ram_p99, 4),
-        "hot_p99_tiered_ms": round(t_p99, 4),
-        # Headline 1: beyond-RAM serving tax (acceptance: <= 2.0).
-        "hot_p99_ratio": (round(t_p99 / ram_p99, 2)
-                          if ram_p99 > 0 else None),
-        "hot_p50_ratio": (round(t_p50 / ram_p50, 2)
-                          if ram_p50 > 0 else None),
-        # Headline 2: the kill-everything -> bit-exact boot walls.
-        "snapshot_s": snap["snapshot_s"],
-        "restore_s": snap["restore_s"],
-        "restore_keys": snap["keys"],
-        "restore_mb": snap["mb"],
-        "restore_exact": snap["restore_exact"],
     }
 
 

@@ -423,7 +423,7 @@ class KVWorker:
         # timer latency.  0 (the conservative default) bypasses the
         # plane entirely: every frame is byte-identical to a pre-batch
         # build.  64 KiB is the recommended serving-storm setting
-        # (bench.py's small_op_batching section runs it).
+        # (docs/batching.md).
         self._batch_bytes = max(0, self.po.env.find_int("PS_BATCH_BYTES",
                                                         0))
         self._combiner = None

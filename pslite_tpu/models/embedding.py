@@ -18,11 +18,8 @@ def skewed_indices(num_rows: int, workers: int, batch: int, seed: int = 0,
 
 
 def replay(sparse_engine, num_rows: int = 1 << 20, dim: int = 64,
-           batch: int = 4096, steps: int = 1, seed: int = 0,
-           measure=None):
-    """Returns (bytes_moved_per_step, seconds_per_step).  ``measure``
-    swaps the clock (see resnet_trace.replay); with it, dt may be None
-    when the requested basis is unavailable."""
+           batch: int = 4096, steps: int = 1, seed: int = 0):
+    """Returns (bytes_moved_per_step, host seconds_per_step)."""
     import time
 
     name = f"emb_{num_rows}_{dim}"
@@ -36,16 +33,12 @@ def replay(sparse_engine, num_rows: int = 1 << 20, dim: int = 64,
     out = sparse_engine.pull(name, idx)
     out.block_until_ready()  # warm the executable cache
 
-    def loop():
-        for _ in range(steps):
-            sparse_engine.push(name, idx, grads)
-            out = sparse_engine.pull(name, idx)
-        out.block_until_ready()
-        sparse_engine.block(name)
-
-    from ..utils.profiling import clocked
-
-    elapsed = clocked(loop, measure)
-    dt = elapsed / max(steps, 1) if elapsed is not None else None
+    t0 = time.perf_counter()
+    for _ in range(steps):
+        sparse_engine.push(name, idx, grads)
+        out = sparse_engine.pull(name, idx)
+    out.block_until_ready()
+    sparse_engine.block(name)
+    dt = (time.perf_counter() - t0) / max(steps, 1)
     step_bytes = 2 * 4 * W * batch * dim  # push + pull payload
     return step_bytes, dt

@@ -76,7 +76,7 @@ def make_buckets(bucket_bytes: int = 4 << 20) -> List[Tuple[str, int]]:
 
 def replay(engine, steps: int = 1, bucket_bytes: int = 4 << 20,
            grouped: bool = True, host_origin: bool = False,
-           overlap: bool = True, measure=None):
+           overlap: bool = True):
     """Run the ResNet-50 push/pull trace through a CollectiveEngine.
 
     ``grouped=True`` pushes the whole gradient stream as ONE jitted
@@ -91,7 +91,7 @@ def replay(engine, steps: int = 1, bucket_bytes: int = 4 << 20,
     of the reference's host path; ``overlap=False`` stages serially
     (the baseline the overlap is measured against).
 
-    Returns (bytes_moved_per_step, seconds_per_step).
+    Returns (bytes_moved_per_step, host seconds_per_step).
     """
     import time
 
@@ -142,17 +142,10 @@ def replay(engine, steps: int = 1, bucket_bytes: int = 4 << 20,
     one_step()
     engine.block()
 
-    def loop():
-        for _ in range(steps):
-            one_step()
-        engine.block()
-
-    # ``measure(loop) -> seconds | None`` swaps the clock (e.g. XPlane
-    # device-busy seconds instead of host wall time); None means the
-    # basis is unavailable and propagates to the caller.
-    from ..utils.profiling import clocked
-
-    elapsed = clocked(loop, measure)
-    dt = elapsed / max(steps, 1) if elapsed is not None else None
+    t0 = time.perf_counter()
+    for _ in range(steps):
+        one_step()
+    engine.block()
+    dt = (time.perf_counter() - t0) / max(steps, 1)
     step_bytes = 2 * 4 * sum(n for _, n in buckets)  # push + pull
     return step_bytes, dt

@@ -1220,30 +1220,6 @@ class CollectiveEngine:
             return False
         return self._effective_impl(dtype, resolved) == "xla"
 
-    def flat_ring_eligible(self, dtype, handle: Optional[ServerHandle] = None
-                           ) -> bool:
-        """Whether ``push_pull``/``push`` for this config routes to the
-        1-D fused ring programs, which take FLAT ``[W*padded]`` grads
-        (``_prep_grads_ring``) — hot-path callers holding device arrays
-        should pre-build that layout to skip the per-call relayout.
-        The ONE definition the op routing and benchmarks share."""
-        resolved, _ = self._resolve_handle(handle)
-        return (
-            self._effective_impl(dtype, resolved) == "pallas"
-            and self.worker_axis is None
-        )
-
-    def flat_zc_eligible(self, handle: Optional[ServerHandle] = None
-                         ) -> bool:
-        """Whether a zero-copy push_pull for ``handle`` takes the FLAT
-        grads program (callers that pre-build device inputs should then
-        pass [padded] 1-D arrays — see _prep_grads_flat).  The ONE
-        definition bench and callers share with push_pull's routing."""
-        resolved, _ = self._resolve_handle(handle)
-        return (self.num_shards == 1
-                and not self._is_stateful(resolved)
-                and self.worker_axis is None)
-
     def _bind(self, name: str, handle: Optional[ServerHandle],
               zero_copy: Optional[bool]) -> _BoundOp:
         """Stage ``select`` of the first ``push_pull(name, ., handle,
@@ -1273,7 +1249,9 @@ class CollectiveEngine:
                 bucket.padded_len, bucket.dtype, handle_key
             )
         else:
-            if zc and self.flat_zc_eligible(handle):
+            # Flat [padded] grads: zc says the kv axis has size 1, this
+            # branch that the handle is stateless.
+            if zc and self.worker_axis is None:
                 prep = self._prep_grads_flat
             op = "push" if push else "push_pull_zc" if zc else "push_pull"
             prog = self._program(op, bucket.padded_len, bucket.dtype,
@@ -1909,11 +1887,9 @@ class CollectiveEngine:
                     # T//U outer iterations that each pull a U-step slab
                     # and apply U UNROLLED updates: the store carry stays
                     # resident across the inner steps, amortizing its
-                    # read+write to 2P/U per step (traffic -> P + 2P/U;
-                    # tools/profile_ops.py measured the engine sweep go
-                    # 343 -> ~705 GB/s at 1MB steps and 445 -> ~905 at
-                    # 16MB).  A non-divisible step count runs the
-                    # remainder as an un-unrolled tail scan.
+                    # read+write to 2P/U per step (traffic -> P + 2P/U).
+                    # A non-divisible step count runs the remainder as an
+                    # un-unrolled tail scan.
                     seq = grads_l[0]
 
                     def inner(carry, u_off):

@@ -1,7 +1,7 @@
 """Where compiled programs are kept between processes.
 
 One rule, called by everything that compiles for the chip (the ICI van's
-data plane, ``chip_smoke.py``, ``bench.py``, the training examples): if
+data plane, ``chip_smoke.py``, the training examples): if
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no directory
 is set in code; otherwise the cache lives in one fixed, git-ignored
 directory of the checkout.  The directory is part of what a cache entry is

@@ -343,20 +343,6 @@ def stage_clock():
     return _clock
 
 
-def clocked(loop, measure=None):
-    """Seconds taken by ``loop()`` — host wall clock by default, or
-    whatever clock ``measure(loop) -> seconds | None`` implements (e.g.
-    XPlane device-busy seconds).  The ONE definition of the clock-swap
-    scaffold shared by the model replays and the stress patterns; None
-    means the requested basis is unavailable and must propagate (never
-    substitute a fake number)."""
-    if measure is not None:
-        return measure(loop)
-    t0 = time.perf_counter()
-    loop()
-    return time.perf_counter() - t0
-
-
 class device_trace:
     """Device-side timeline capture (jax.profiler / XPlane).
 

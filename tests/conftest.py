@@ -1,7 +1,7 @@
 """Test bootstrap: force the CPU backend with 8 virtual devices.
 
-Sharding/collective tests run on a virtual 8-device CPU mesh; real-TPU
-benchmarking happens in bench.py (which does NOT import this).
+Sharding/collective tests run on a virtual 8-device CPU mesh; what the
+chip does is measured by ``benchmark/run.py`` (which does NOT import this).
 """
 
 import os
@@ -27,6 +27,8 @@ import pytest
 
 # Best-effort build of the native transport core so the suite exercises the
 # C++ path; tests still pass on the pure-Python fallback if g++ is missing.
+# A build that fails where g++ exists fails one test, by name:
+# test_native_plane.py::test_core_builds_where_a_toolchain_exists.
 _repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if not os.path.exists(os.path.join(_repo, "cpp", "libpslite_core.so")):
     import subprocess

@@ -371,7 +371,7 @@ def test_recv_pool_reuses_blocks_tcp():
 
 
 def test_apply_storm_helper_smoke():
-    """bench.py's server_apply harness stays runnable (tiny config)."""
+    """``apply_storm_rates`` (docs/apply_shards.md) stays runnable."""
     from pslite_tpu.benchmark import apply_storm_rates
 
     rate = apply_storm_rates(2, n_workers=2, msgs_per_worker=3,

@@ -54,18 +54,6 @@ def test_distributed_options_from_env():
             "DMLC_PS_ROOT_URI": "h", "DMLC_NUM_WORKER": "4",
         }))  # missing DMLC_RANK
 
-def test_stress_patterns_on_cpu_mesh():
-    jax = pytest.importorskip("jax")
-    from pslite_tpu.parallel.engine import CollectiveEngine
-    from pslite_tpu.parallel.sparse import SparseEngine
-    from pslite_tpu.stress import PATTERNS, run_pattern
-
-    eng = CollectiveEngine()
-    sp = SparseEngine(eng.mesh, eng.axis)
-    for pattern in PATTERNS:
-        gbps = run_pattern(eng, sp, pattern, size_bytes=64 * 1024, iters=2)
-        assert gbps > 0, pattern
-
 
 def test_benchmark_cli_recv_buffer_mode():
     """ENABLE_RECV_BUFFER=1 (test_benchmark.cc:268-320): registered
