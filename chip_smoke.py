@@ -15,8 +15,9 @@ reference:
   separate ``push`` and ``pull``;
 - ``sparse``: a 2^20 x 64 embedding table, Zipf indices,
   ``push_sparse`` / ``pull_sparse``, then one push under the stateful
-  server handle ``row_adagrad`` on a 2^20 x 128 table of its own (rows of
-  128 lanes: on the chip written by the ``ops/row_add.py`` kernel);
+  server handle ``row_adagrad`` on a 2^20 x 128 table of its own and one
+  plain sum into it (rows of 128 lanes: on the chip both are written by
+  the ``ops/row_add.py`` kernel);
 - ``message_path``: an unregistered key, which the collective path cannot
   take, answered by the ``KVServer`` handler;
 - ``ring`` (two or more devices): the ResNet-50 buckets once more through
@@ -82,8 +83,8 @@ class Sizes:
     emb_rows: int = 1 << 20
     emb_dim: int = 64
     emb_batch: int = 4096
-    # The table pushed under the stateful handle: rows of 128 lanes, which
-    # on the chip the push writes through ops/row_add.py.
+    # The table pushed under the stateful handle, then summed into: rows of
+    # 128 lanes, which on the chip a push writes through ops/row_add.py.
     emb_opt_dim: int = 128
 
 
@@ -407,6 +408,17 @@ class _Smoke:
               f"{'ops/row_add.py' if kernel else 'XLA scatter'}: "
               f"{len(rows):,} distinct rows and their accumulators agree "
               f"({wall:.2f} s, compiles)")
+        # And the plain sum into the same table: where the kernel takes the
+        # rows, a push with no handle is written by distinct row too.
+        kv.wait(kv.push_sparse("emb_opt", idx, grads))
+        kv.wait(kv.pull_sparse("emb_opt", idx, out=out))
+        np.testing.assert_allclose(
+            out.reshape(-1, dim), (want + G)[inverse], rtol=1e-4,
+            atol=1e-5, err_msg="the sum after row_adagrad")
+        check((se.row_kernel_pushes == 2) == kernel,
+              f"row kernel pushes {se.row_kernel_pushes} after the sum")
+        print(f"  one push with no handle into the same table, written by "
+              f"{'ops/row_add.py' if kernel else 'XLA scatter'}: agrees")
 
     # -- message path ---------------------------------------------------------
 

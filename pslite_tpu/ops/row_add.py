@@ -1,9 +1,11 @@
 """Row read-add-write kernel (Pallas, TPU): the sparse table's write by
 distinct row.
 
-Under a stateful sparse handle (``parallel/sparse.py`` ``_adagrad_sparse``)
-the rows a push touches arrive combined: ascending, each once, the valid
-ones first.  XLA's scatter is told none of that and pays a serial
+The rows a sparse push touches arrive combined (``parallel/sparse.py``
+``_combine_rows``): ascending, each once, the valid ones first, under a
+stateful handle (``_adagrad_sparse``: the rows' steps) and under the plain
+sum (``_scatter_rows``: the rows' summed gradients) alike.  XLA's scatter
+is told none of that and pays a serial
 read-modify-write for every slot of the batch, dropped sentinel slots
 included.  ``row_add`` visits only the first ``n`` slots and moves whole
 512 B rows: a block of row ids reaches the scalar core, one DMA a row brings

@@ -24,6 +24,7 @@ BASELINE.md config 5) then load-balance across shards by construction.
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 from dataclasses import dataclass
 from typing import Callable, Dict, Optional
@@ -148,22 +149,31 @@ def _store_out_format(store, mesh, axis):
 
 
 def _scatter_rows(axis, S, R, pack, dim, store_l, idx_l, grads_l):
-    """Sum-handle push: scatter-add the owned rows DIRECTLY into the
-    donated (possibly packed) store.  A dense-aggregate form reads +
-    writes the whole table per push (768MB of traffic for a 4096-row
-    update on the 1M-row workload); this touches only the updated rows.
-    Unowned rows map out of bounds and mode="drop" discards them.
-    Shared by the single-table and group programs.
+    """Sum-handle push: add the owned rows DIRECTLY into the donated
+    (possibly packed) store.  A dense-aggregate form reads + writes the
+    whole table per push (768MB of traffic for a 4096-row update on the
+    1M-row workload); this touches only the updated rows.  Shared by the
+    single-table and group programs.
+
+    Where ``ops/row_add.py`` takes the table's rows (:func:`_on_row_add`:
+    an unpacked table of 128 f32 lanes, a program lowered for a TPU) the
+    duplicates are combined first (:func:`_combine_rows`) and the kernel
+    writes each distinct row once; anywhere else it is XLA's scatter-add,
+    which pays for every slot of the batch but needs no combine.  Unowned
+    rows map out of bounds and mode="drop" discards them.
 
     The sparse bodies carry ``jax.named_scope``s, by which a device trace
     is read: ``ps.sparse.route`` (indices and rows crossing the workers,
     and who owns what), ``ps.sparse.push.scatter_add``,
-    ``ps.sparse.pull.gather``, and under a stateful handle
-    ``ps.sparse.combine`` (sort and segment sum of duplicates) and
-    ``ps.update`` (accumulator and step)."""
+    ``ps.sparse.pull.gather``, ``ps.sparse.combine`` (sort and segment sum
+    of duplicates: under a stateful handle, and before ``row_add`` in the
+    sum) and under a stateful handle ``ps.update`` (accumulator and
+    step)."""
     import jax
     from jax import lax
     import jax.numpy as jnp
+
+    from ..ops.row_add import row_add
 
     with jax.named_scope("ps.sparse.route"):
         all_idx = lax.all_gather(idx_l[0], axis, tiled=True)  # [W*n]
@@ -171,22 +181,38 @@ def _scatter_rows(axis, S, R, pack, dim, store_l, idx_l, grads_l):
         my = lax.axis_index(axis)
         owned = (all_idx % S) == my
         local = all_idx // S
-        masked = jnp.where(owned[:, None], all_g, 0)
-    with jax.named_scope("ps.sparse.push.scatter_add"):
-        if pack == 1:
-            rows = jnp.where(owned, local, R)  # R = out of bounds -> drop
-            return store_l.at[rows].add(masked, mode="drop")
-        phys = jnp.where(owned, local // pack, R // pack)
-        slot = (local % pack).astype(jnp.int32)
-        onehot = (slot[:, None] == jnp.arange(pack, dtype=jnp.int32)[None])
-        packed = (
-            onehot[:, :, None] * masked[:, None, :]
-        ).reshape(all_idx.shape[0], pack * dim)
-        return store_l.at[phys].add(packed, mode="drop")
+
+    def scatter(store_l, owned, local, all_g):
+        with jax.named_scope("ps.sparse.push.scatter_add"):
+            masked = jnp.where(owned[:, None], all_g, 0)
+            if pack == 1:
+                rows = jnp.where(owned, local, R)  # R: out of bounds, drop
+                return store_l.at[rows].add(masked, mode="drop")
+            phys = jnp.where(owned, local // pack, R // pack)
+            slot = (local % pack).astype(jnp.int32)
+            onehot = (slot[:, None]
+                      == jnp.arange(pack, dtype=jnp.int32)[None])
+            packed = (
+                onehot[:, :, None] * masked[:, None, :]
+            ).reshape(all_idx.shape[0], pack * dim)
+            return store_l.at[phys].add(packed, mode="drop")
+
+    def by_distinct_row(store_l, owned, local, all_g, interpret):
+        with jax.named_scope("ps.sparse.combine"):
+            G_seg, row_seg, valid = _combine_rows(
+                jnp.where(owned, local, R), all_g, R)
+        with jax.named_scope("ps.sparse.push.scatter_add"):
+            return row_add(store_l, row_seg, G_seg, jnp.sum(valid),
+                           interpret=interpret)
+
+    if pack != 1:
+        return scatter(store_l, owned, local, all_g)
+    return _on_row_add(scatter, by_distinct_row, store_l, owned, local,
+                       all_g)
 
 
-# Where the stateful push's table write is ``ops/row_add.py``: the platform
-# a program is lowered for -> the kernel's ``interpret`` there.  A platform
+# Where a sparse push's table write is ``ops/row_add.py``: the platform a
+# program is lowered for -> the kernel's ``interpret`` there.  A platform
 # not named keeps XLA's scatter.
 _ROW_ADD_INTERPRET = {"tpu": False}
 
@@ -197,15 +223,31 @@ def _row_add_takes(width: int, dtype) -> bool:
     return width == 128 and np.dtype(dtype) == np.float32
 
 
+def _on_row_add(scatter, kernel, store_l, *operands):
+    """``kernel(store_l, *operands, interpret)`` where the program is
+    lowered for a platform of ``_ROW_ADD_INTERPRET`` and ``row_add`` takes
+    the table's rows (:func:`_row_add_takes`), ``scatter(store_l,
+    *operands)`` anywhere else: chosen at lowering, so one traced program
+    serves whatever it is compiled for, and no caller says which.  The
+    one rule of both pushes' table write (and of
+    ``SparseEngine._row_kernel``, which counts it)."""
+    from jax import lax
+
+    if not _row_add_takes(store_l.shape[1], store_l.dtype):
+        return scatter(store_l, *operands)
+    kernels = {platform: functools.partial(kernel, interpret=interpret)
+               for platform, interpret in _ROW_ADD_INTERPRET.items()}
+    return lax.platform_dependent(store_l, *operands, default=scatter,
+                                  **kernels)
+
+
 def _add_rows(store_l, row_seg, valid, delta, R):
     """``store_l[row_seg[i]] += delta[i]`` where ``valid[i]``, for combined
-    rows: ascending, each once, the valid ones first.  No two updates
-    touch one row, so where the program is lowered for a platform of
-    ``_ROW_ADD_INTERPRET`` and the kernel takes the rows
-    (:func:`_row_add_takes`) the write visits the distinct rows only
+    rows (:func:`_combine_rows`): ascending, each once, the valid ones
+    first.  No two updates touch one row, so where the kernel serves
+    (:func:`_on_row_add`) the write visits the distinct rows only
     (``ops/row_add.py``); anywhere else it is XLA's scatter, which pays
     for every slot."""
-    from jax import lax
     import jax.numpy as jnp
 
     from ..ops.row_add import row_add
@@ -215,15 +257,50 @@ def _add_rows(store_l, row_seg, valid, delta, R):
             delta, mode="drop"
         )
 
-    if not _row_add_takes(store_l.shape[1], store_l.dtype):
-        return scatter(store_l, row_seg, valid, delta)
-    kernels = {
-        platform: lambda s, r, v, d, interpret=interpret: row_add(
-            s, r, d, jnp.sum(v), interpret=interpret)
-        for platform, interpret in _ROW_ADD_INTERPRET.items()
-    }
-    return lax.platform_dependent(store_l, row_seg, valid, delta,
-                                  default=scatter, **kernels)
+    def kernel(store_l, row_seg, valid, delta, interpret):
+        return row_add(store_l, row_seg, delta, jnp.sum(valid),
+                       interpret=interpret)
+
+    return _on_row_add(scatter, kernel, store_l, row_seg, valid, delta)
+
+
+def _combine_rows(local, all_g, R):
+    """Combine the duplicates of a gathered batch: ``local`` is ``s32[m]``,
+    a slot's row on this shard or the sentinel ``R`` where another shard
+    owns it, ``all_g`` the slots' gradient rows ``[m, d]``.  Returns
+    ``G_seg [m, d]``, ``row_seg s32[m]``, ``valid pred[m]``: the distinct
+    owned rows ascending, each once with the sum of its slots' gradients,
+    the valid ones first.  Past them ``row_seg`` is ``R`` and ``G_seg`` is
+    not for use (its first such row is the unowned slots' sum: masking
+    them out was a pass over the batch, 0.2 ms of a v5e's step, and every
+    caller drops what is not ``valid``).
+
+    One sort carries what the combine needs of the batch: its keys come
+    back sorted beside the permutation (``jnp.argsort`` is this sort with
+    the keys thrown away, and ``local[order]`` a 1-D gather to get them
+    back: 9.7 ns a slot on a v5e), and a slot is owned exactly where its
+    sorted key is under ``R``, so ownership is not gathered either.
+    Stable, so equal rows keep the batch's order and every f32 sum is that
+    order's.  Shared by both pushes: the stateful handle's
+    (:func:`_adagrad_sparse`) and, where ``row_add`` writes the table, the
+    plain sum's (:func:`_scatter_rows`)."""
+    from jax import lax
+    import jax.numpy as jnp
+
+    m = local.shape[0]
+    sr, order = lax.sort((local.astype(jnp.int32), lax.iota(jnp.int32, m)),
+                         num_keys=1, is_stable=True)
+    sg = all_g[order]
+    # One segment a distinct row; the sentinels sort last, into one.
+    first = jnp.concatenate([jnp.ones((1,), bool), sr[1:] != sr[:-1]])
+    seg = jnp.cumsum(first) - 1                                # [m]
+    G_seg = jnp.zeros((m, sg.shape[1]), sg.dtype).at[seg].add(sg)
+    # Row of each segment: every segment's first id, the others at the
+    # sentinel, sorted once more, which is "distinct rows ascending,
+    # sentinels last" (0.09 ms on a v5e at m = 131,072, where
+    # ``full(R).at[seg].set(sr)`` took 0.61).
+    row_seg = lax.sort(jnp.where(first, sr, R), is_stable=False)
+    return G_seg, row_seg, row_seg < R
 
 
 def _adagrad_rows(store_l, acc_l, G, lr, eps):
@@ -265,22 +342,7 @@ def _adagrad_sparse(axis, S, R, pack, dim, store_l, acc_l, idx_l,
         m = all_idx.shape[0]
 
     with jax.named_scope("ps.sparse.combine"):
-        # Segment-sum duplicates: sort by local row, one segment per unique
-        # row (sentinel rows sort last into their own segments).
-        order = jnp.argsort(local)
-        sr = local[order]
-        sg = jnp.where(owned[order][:, None], all_g[order], 0)
-        first = jnp.concatenate(
-            [jnp.ones((1,), bool), sr[1:] != sr[:-1]]
-        )
-        seg = jnp.cumsum(first) - 1                            # [m]
-        G_seg = jnp.zeros((m, sg.shape[1]), sg.dtype).at[seg].add(sg)
-        # Row of each segment (slots beyond the unique count stay at the
-        # sentinel and scatter harmlessly via drop/zero-G).
-        row_seg = jnp.full((m,), R, jnp.int32).at[seg].set(
-            sr.astype(jnp.int32)
-        )
-        valid = row_seg < R
+        G_seg, row_seg, valid = _combine_rows(local, all_g, R)
 
     with jax.named_scope("ps.update"):
         # Accumulator: gather the touched rows, apply, scatter back (1-D
@@ -379,8 +441,9 @@ class SparseEngine:
         # programs by a reshard, and a table's by a new registration of
         # its name or a change of its packing.
         self._bound: Dict[tuple, _BoundPush] = {}
-        # Pushes that ran under a stateful handle, and those of them whose
-        # program writes the table through ops/row_add.py (see export).
+        # Pushes that ran under a stateful handle, and pushes, under a
+        # handle or not, whose program writes the table through
+        # ops/row_add.py (see export).
         self.stateful_pushes = 0
         self.row_kernel_pushes = 0
         self._mu = threading.Lock()
@@ -489,8 +552,8 @@ class SparseEngine:
             return NamedSharding(self.mesh, spec)
 
         def _push(store_l, idx_l, grads_l):
-            # Scatter-add directly into the donated (packed) store —
-            # see _scatter_rows for the traffic/layout rationale.
+            # Add directly into the donated (packed) store: see
+            # _scatter_rows for the traffic / layout rationale.
             new = _scatter_rows(axis, S, R, pack, dim, store_l, idx_l,
                                 grads_l)
             # Tiny non-donated completion token: callers block on this
@@ -752,14 +815,12 @@ class SparseEngine:
         the program.  An unknown handle fails here, by name.  Call with
         the table's lock held."""
         table = self._tables[name]
-        if handle is None:
-            bound = _BoundPush(self._sparse_program("push", table, batch),
-                               None, (), False)
-        else:
-            kind, params = self._handle_scalars(handle)
-            bound = _BoundPush(
-                self._sparse_program("push_" + kind, table, batch),
-                kind, params, self._row_kernel(table))
+        kind, params = (None, ()) if handle is None \
+            else self._handle_scalars(handle)
+        bound = _BoundPush(
+            self._sparse_program("push" if kind is None else "push_" + kind,
+                                 table, batch),
+            kind, params, self._row_kernel(table))
         with self._mu:
             # A new registration meanwhile: the next push binds.
             if self._tables.get(name) is table:
@@ -767,9 +828,10 @@ class SparseEngine:
         return bound
 
     def _row_kernel(self, table: SparseTable) -> bool:
-        """Whether this mesh's stateful push program of ``table`` writes
-        it through ``ops/row_add.py`` (the rule of :func:`_add_rows`; the
-        mesh's platform is what the program is lowered for)."""
+        """Whether this mesh's push programs of ``table``, the sum's and a
+        stateful handle's, write it through ``ops/row_add.py`` (the rule of
+        :func:`_on_row_add`; the mesh's platform is what the program is
+        lowered for)."""
         platform = next(iter(self.mesh.devices.flat)).platform
         return (platform in _ROW_ADD_INTERPRET and table.pack == 1
                 and _row_add_takes(table.dim, table.dtype))
@@ -809,7 +871,7 @@ class SparseEngine:
                 self._stores[name], self._acc[name], token = b.prog(
                     self._stores[name], self._acc[name], idx, g, *b.params)
                 self.stateful_pushes += 1
-                self.row_kernel_pushes += b.row_kernel
+            self.row_kernel_pushes += b.row_kernel
         self._observe("push", table, batch)
         t3 = stamp()
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
@@ -985,9 +1047,9 @@ class SparseEngine:
                     self._acc[n] = outs[kk + i]
                 token = outs[2 * kk]
                 self.stateful_pushes += 1
-                # One push, whatever it groups: counted where the kernel
-                # writes any of its tables.
-                self.row_kernel_pushes += any(map(self._row_kernel, tables))
+            # One push, whatever it groups: counted where the kernel
+            # writes any of its tables.
+            self.row_kernel_pushes += any(map(self._row_kernel, tables))
         finally:
             self._unlock_tables(ordered)
         t3 = stamp()

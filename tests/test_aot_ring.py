@@ -280,3 +280,47 @@ def test_row_adagrad_push_at_full_size_writes_the_table_with_row_add(
     state = rows * dim * 4 + rows * 4
     assert state <= mem.alias_size_in_bytes < state + (1 << 20)
     assert mem.temp_size_in_bytes < 10**8
+
+
+def test_sum_push_at_full_size_combines_and_writes_the_table_with_row_add(
+        v5e_chip):
+    """The cell ``dlrm-criteo-emb.zipf``'s push (``_scatter_rows``, called
+    as ``benchmark/tests/test_compile_fullsize.py`` calls it) lowered for
+    the v5e: the table's one result is the ``row_add`` kernel's, in place
+    over the donated table, no scatter has the table for its result, the
+    combine's sorts stand before it, and the workspaces are of the batch's
+    size."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pslite_tpu.parallel import sparse
+
+    rows, dim, lookups = 20_000_000, 128, 131_072
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(v5e_chip, spec))
+
+    compiled = jax.jit(jax.shard_map(
+        lambda st, ix, g: sparse._scatter_rows("kv", 1, rows, 1, dim, st,
+                                               ix, g),
+        mesh=v5e_chip,
+        in_specs=(P("kv", None), P("kv", None), P("kv", None, None)),
+        out_specs=P("kv", None), check_vma=False), donate_argnums=(0,),
+    ).lower(sds((rows, dim), jnp.float32, P("kv", None)),
+            sds((1, lookups), jnp.int32, P("kv", None)),
+            sds((1, lookups, dim), jnp.float32, P("kv", None, None))
+            ).compile()
+    text = compiled.as_text()
+    table = [l for l in text.splitlines()
+             if f"= f32[{rows},{dim}]" in l and " parameter(" not in l]
+    assert len(table) == 1, table
+    assert " %row_add" in table[0] and "tpu_custom_call" in table[0]
+    assert "ps.sparse.push.scatter_add" in table[0]
+    assert " scatter(" not in table[0] and " copy(" not in table[0]
+    assert "ps.sparse.combine/sort" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == rows * dim * 4
+    # The gathered gradients in sorted order and their segment sums (64 MiB
+    # each at this batch), and the ids.
+    assert 2 * lookups * dim * 4 <= mem.temp_size_in_bytes < 3 * lookups * dim * 4

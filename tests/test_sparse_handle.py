@@ -2,7 +2,9 @@
 ``KVWorker.push_sparse(name, indices, grads, handle)`` -> ``_engine_op`` ->
 ``SparseEngine.push``: row-wise Adagrad against a plain float64 reference,
 the plain sum left as it was, the record a ``(table, handle, batch)`` is
-bound to, and the counters and the span that say a push ran under a handle.
+bound to, the counters and the span that say a push ran under a handle, the
+combine both pushes share (``_combine_rows``) and the sum written by distinct
+row where ``ops/row_add.py`` takes the table's rows.
 """
 
 import glob
@@ -176,6 +178,24 @@ def test_a_push_with_no_handle_is_the_plain_sum_it_was(cluster):
     np.testing.assert_allclose(got, init + want, rtol=1e-5, atol=1e-5)
 
 
+def _kernel_on_cpu(monkeypatch):
+    """Name the CPU among the platforms whose programs write a table with
+    ``ops/row_add.py`` (interpreted there), from here to the test's end;
+    returns the list that takes the shape of every table the kernel is
+    traced with."""
+    from pslite_tpu.ops import row_add as row_add_module
+    from pslite_tpu.parallel import sparse
+
+    traced = []
+    real = row_add_module.row_add
+    monkeypatch.setattr(
+        row_add_module, "row_add",
+        lambda store, *a, **kw: traced.append(store.shape) or real(
+            store, *a, **kw))
+    monkeypatch.setitem(sparse._ROW_ADD_INTERPRET, "cpu", True)
+    return traced
+
+
 @pytest.mark.parametrize("cluster", [1, 4], indirect=True)
 def test_the_row_kernel_in_the_push_is_bit_equal_to_xlas_scatter(
         cluster, monkeypatch):
@@ -183,9 +203,6 @@ def test_the_row_kernel_in_the_push_is_bit_equal_to_xlas_scatter(
     test names the CPU among the kernel's platforms (interpreted) and the
     same three pushes give the same bits, rows and accumulator.  The
     counter follows the program: whole 128-lane f32 rows under a handle."""
-    from pslite_tpu.ops import row_add as row_add_module
-    from pslite_tpu.parallel import sparse
-
     kv, eng = cluster
     W = eng.num_shards
     idx, init, grads = _traffic(W, 128)
@@ -195,13 +212,7 @@ def test_the_row_kernel_in_the_push_is_bit_equal_to_xlas_scatter(
         twin.push("emb", idx, g, HANDLE)
     assert (twin.stateful_pushes, twin.row_kernel_pushes) == (3, 0)
 
-    traced = []
-    real = row_add_module.row_add
-    monkeypatch.setattr(
-        row_add_module, "row_add",
-        lambda store, *a, **kw: traced.append(store.shape) or real(
-            store, *a, **kw))
-    monkeypatch.setitem(sparse._ROW_ADD_INTERPRET, "cpu", True)
+    traced = _kernel_on_cpu(monkeypatch)
     eng.register_sparse("emb", ROWS, 128, init=init)
     eng.register_sparse("packed", ROWS, 8, init=init[:, :8])
     # A row wider than one tile keeps the scatter (Mosaic refuses the
@@ -217,13 +228,234 @@ def test_the_row_kernel_in_the_push_is_bit_equal_to_xlas_scatter(
     assert (eng.store_array("emb") == twin.store_array("emb")).all()
     assert (np.asarray(eng._acc["emb"]) == np.asarray(twin._acc["emb"])).all()
     assert _gauges(kv)["engine.sparse.push.row_kernel"] == 3
-    # Not for a lane-packed table, nor for a push with no handle.
+    # Not for a lane-packed table; a push with no handle whose program
+    # writes through the kernel is counted like one under a handle.
     kv.wait(kv.push_sparse("packed", idx, grads[0][..., :8], HANDLE))
+    assert _gauges(kv)["engine.sparse.push.row_kernel"] == 3
     kv.wait(kv.push_sparse("emb", idx, grads[0]))
     assert set(traced) == {(rps, 128)}
     after = _gauges(kv)
     assert after["engine.sparse.push.stateful"] == 4
-    assert after["engine.sparse.push.row_kernel"] == 3
+    assert after["engine.sparse.push.row_kernel"] == 4
+
+
+def _sum_reference(init, idx, grads):
+    want = np.asarray(init, np.float64).copy()
+    for g in grads:
+        np.add.at(want, idx.reshape(-1),
+                  g.astype(np.float64).reshape(-1, want.shape[1]))
+    return want
+
+
+@pytest.mark.parametrize("cluster", [1, 4], indirect=True)
+def test_the_sum_push_by_distinct_row_matches_the_reference(
+        cluster, monkeypatch):
+    """With the CPU named among the kernel's platforms a push with no
+    handle combines its duplicates and writes each distinct row once
+    (``row_add``, interpreted): the float64 sum at the scatter's own
+    tolerance, a hot row read alike by every worker, and the counter."""
+    kv, eng = cluster
+    W = eng.num_shards
+    idx, init, grads = _traffic(W, 128)
+    traced = _kernel_on_cpu(monkeypatch)
+    table = eng.register_sparse("emb", ROWS, 128, init=init)
+    for g in grads:
+        ts = kv.push_sparse("emb", idx, g)
+    kv.wait(ts)
+    assert set(traced) == {(table.rows_per_shard, 128)}  # in the program
+    assert "emb" not in eng._acc
+    got = np.asarray(eng.store_global_device("emb"))
+    np.testing.assert_allclose(got, _sum_reference(init, idx, grads),
+                               rtol=1e-5, atol=1e-5)
+    # Rows no push touched are bit-unchanged.
+    quiet = np.setdiff1d(np.arange(ROWS), np.unique(idx))
+    assert len(quiet) >= 10 and (got[quiet] == init[quiet]).all()
+    # Every copy of the hottest row in the next pull is the same bits.
+    out = np.zeros((W, idx.shape[1], 128), np.float32)
+    kv.wait(kv.pull_sparse("emb", idx, out=out))
+    assert (out[:, 0] == out[0, 0]).all() and (out[0, 0] == got[0]).all()
+    assert (out[:, 1] == out[:, 2]).all()       # the duplicate in a worker
+    gauges = _gauges(kv)
+    assert gauges["engine.sparse.push.stateful"] == 0
+    assert gauges["engine.sparse.push.row_kernel"] == 3
+    assert eng._bound[("emb", None, idx.shape[1])].row_kernel
+
+
+@pytest.mark.parametrize("cluster", [1, 4], indirect=True)
+def test_packed_and_wide_tables_keep_the_scatter_and_a_group_follows_its_tables(
+        cluster, monkeypatch):
+    kv, eng = cluster
+    W = eng.num_shards
+    idx, init, grads = _traffic(W, 256)
+    traced = _kernel_on_cpu(monkeypatch)
+    eng.register_sparse("packed", ROWS, 8, init=init[:, :8])
+    eng.register_sparse("wide", ROWS, 256, init=init)
+    for g in grads:
+        kv.wait(kv.push_sparse("packed", idx, g[..., :8]))
+        kv.wait(kv.push_sparse("wide", idx, g))
+    assert not traced and eng.row_kernel_pushes == 0
+    for name, width in (("packed", 8), ("wide", 256)):
+        np.testing.assert_allclose(
+            np.asarray(eng.store_global_device(name)),
+            _sum_reference(init[:, :width], idx,
+                           [g[..., :width] for g in grads]),
+            rtol=1e-5, atol=1e-5)
+    # A group is one push: the kernel writes the table that it takes, the
+    # scatter the one it does not, and the push is counted once.
+    a = eng.register_sparse("a", ROWS, 128, init=init[:, :128])
+    eng.register_sparse("b", ROWS, 8, init=init[:, :8])
+    for g in grads:
+        token = eng.push_group(["a", "b"], [idx, idx],
+                               [g[..., :128], g[..., :8]])
+    token.block_until_ready()
+    assert set(traced) == {(a.rows_per_shard, 128)}
+    assert (eng.stateful_pushes, eng.row_kernel_pushes) == (0, 3)
+    for name, width in (("a", 128), ("b", 8)):
+        np.testing.assert_allclose(
+            np.asarray(eng.store_global_device(name)),
+            _sum_reference(init[:, :width], idx,
+                           [g[..., :width] for g in grads]),
+            rtol=1e-5, atol=1e-5)
+    eng.push_group(["b"], [idx], [grads[0][..., :8]]).block_until_ready()
+    assert eng.row_kernel_pushes == 3
+
+
+# -- the combine both pushes share ----------------------------------------------
+
+
+def _zipf(rng, rows, n):
+    p = 1.0 / np.arange(1, rows + 1) ** 0.99
+    return rng.choice(rows, size=n, p=p / p.sum()).astype(np.int32)
+
+
+def _combine_as_before(owned, local, all_g, R):
+    """The combine as ``_adagrad_sparse`` spelled it before the sort
+    carried its keys: ``argsort``, then ids, ownership and gradients
+    gathered by its order."""
+    import jax.numpy as jnp
+
+    m = local.shape[0]
+    order = jnp.argsort(local)
+    sr = local[order]
+    sg = jnp.where(owned[order][:, None], all_g[order], 0)
+    first = jnp.concatenate([jnp.ones((1,), bool), sr[1:] != sr[:-1]])
+    seg = jnp.cumsum(first) - 1
+    G_seg = jnp.zeros((m, sg.shape[1]), sg.dtype).at[seg].add(sg)
+    return G_seg, jnp.full((m,), R, jnp.int32).at[seg].set(
+        sr.astype(jnp.int32))
+
+
+@pytest.mark.parametrize("case, S, n", [
+    ("all distinct", 1, 64),
+    ("all one row", 1, 64),
+    ("zipf duplicates", 1, 512),
+    ("unowned slots on four shards", 4, 512),
+    ("a batch that is no multiple of 8", 1, 45),
+])
+def test_combine_rows_is_numpys_unique_and_add_at(case, S, n):
+    """``_combine_rows`` on every shard of an ``S``-shard mesh, against
+    ``np.unique`` / ``np.add.at`` of the slots the shard owns: distinct
+    rows ascending and first, each with the sum of its slots' gradients,
+    the sentinel past them."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    from pslite_tpu.parallel.sparse import _combine_rows
+
+    rng = np.random.default_rng(len(case))
+    rows, dim = 4 * 97, 16
+    R = -(-rows // S)
+    idx = {"all distinct": lambda: rng.permutation(rows)[:n],
+           "all one row": lambda: np.full(n, 7)}.get(
+               case, lambda: _zipf(rng, rows, n))().astype(np.int32)
+    g = rng.normal(size=(n, dim)).astype(np.float32)
+
+    def body(idx, g):
+        owned = (idx % S) == lax.axis_index("kv")
+        local = jnp.where(owned, idx // S, R)
+        return tuple(x[None] for x in (
+            *_combine_rows(local, g, R), *_combine_as_before(owned, local,
+                                                             g, R)))
+
+    out = jax.jit(jax.shard_map(
+        body, mesh=_mesh(S), in_specs=(P(), P()),
+        out_specs=(P("kv"),) * 5, check_vma=False))(idx, g)
+    G_seg, row_seg, valid, G_before, row_before = map(np.asarray, out)
+    assert row_seg.dtype == np.int32 and valid.dtype == bool
+    # Bit for bit what the argsort and the three gathers gave, in every
+    # valid row (past them the gradients are not for use).
+    assert (row_seg == row_before).all()
+    assert (G_seg[valid] == G_before[valid]).all()
+    distinct = 0
+    for shard in range(S):
+        mine = (idx % S) == shard
+        want_rows = np.unique(idx[mine] // S)
+        k = len(want_rows)
+        distinct += k
+        assert (row_seg[shard, :k] == want_rows).all()
+        assert (row_seg[shard, k:] == R).all()
+        assert valid[shard, :k].all() and not valid[shard, k:].any()
+        want = np.zeros((R, dim), np.float64)
+        np.add.at(want, idx[mine] // S, g[mine].astype(np.float64))
+        np.testing.assert_allclose(G_seg[shard, :k], want[want_rows],
+                                   rtol=1e-5, atol=1e-5)
+    assert distinct == len(np.unique(idx))
+    if case == "all one row":
+        # Stable: equal rows keep the batch's order, so the one sum is
+        # the batch's own left-to-right f32 sum, bit for bit.
+        acc = np.zeros(dim, np.float32)
+        for row in g:
+            acc = acc + row
+        assert (G_seg[0, 0] == acc).all()
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+def test_the_stateful_push_gathers_nothing_out_of_the_batchs_ids():
+    """The sort brings the sorted ids back beside the permutation, and a
+    slot is owned where its sorted id is a row: no 1-D gather whose
+    operand is the batch's ``s32[m]`` ids or ``pred[m]`` ownership is left
+    in ``_adagrad_sparse`` (on a v5e each was ~1 ms of a 12.5 ms step)."""
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from pslite_tpu.parallel.sparse import _adagrad_sparse
+
+    m, R, dim = 96, 61, 128
+
+    def body(st, ac, ix, g, lr, eps):
+        return _adagrad_sparse("kv", 1, R, 1, dim, st, ac, ix, g, lr, eps)
+
+    jaxpr = jax.make_jaxpr(jax.shard_map(
+        body, mesh=_mesh(1),
+        in_specs=(P("kv", None), P("kv"), P("kv", None),
+                  P("kv", None, None), P(), P()),
+        out_specs=(P("kv", None), P("kv")), check_vma=False))(
+        jnp.zeros((R, dim)), jnp.zeros((R,)), jnp.zeros((1, m), jnp.int32),
+        jnp.zeros((1, m, dim)), jnp.float32(LR), jnp.float32(EPS))
+    eqns = list(_eqns(jaxpr.jaxpr))
+    # Two sorts: the batch's ids with their positions, the segments' ids.
+    sorts = [e for e in eqns if e.primitive.name == "sort"]
+    assert sorted(len(e.invars) for e in sorts) == [1, 2]
+    assert not [e.primitive.name for e in eqns
+                if e.primitive.name.startswith("scatter")
+                and e.invars[0].aval.shape == (m,)]     # no 1-D scatter of ids
+    gathers = [e.invars[0].aval for e in eqns if e.primitive.name == "gather"]
+    # The gradients by the sort's order, and the accumulator's touched rows.
+    assert sorted(a.shape for a in gathers) == [(R,), (m, dim)]
+    assert not [a for a in gathers
+                if a.shape == (m,) and a.dtype in (jnp.int32, jnp.bool_)]
 
 
 def test_a_pair_is_bound_once_and_an_unknown_handle_fails_by_name(cluster):
