@@ -15,9 +15,10 @@ reference:
   separate ``push`` and ``pull``;
 - ``sparse``: a 2^20 x 64 embedding table, Zipf indices,
   ``push_sparse`` / ``pull_sparse``, then one push under the stateful
-  server handle ``row_adagrad`` on a 2^20 x 128 table of its own and one
-  plain sum into it (rows of 128 lanes: on the chip both are written by
-  the ``ops/row_add.py`` kernel);
+  server handle ``row_adagrad`` and one plain sum on tables of their own,
+  2^20 x 128 and 2^20 x 64 (physical rows of 128 f32 lanes, the 64-wide
+  lane-packed two to one: on the chip all are written by the
+  ``ops/row_add.py`` kernel);
 - ``message_path``: an unregistered key, which the collective path cannot
   take, answered by the ``KVServer`` handler;
 - ``ring`` (two or more devices): the ResNet-50 buckets once more through
@@ -377,18 +378,34 @@ class _Smoke:
               f"hot row: push_sparse / pull_sparse agree")
         print(f"  set-up: first round (compiles) {walls[0]:.2f} s; "
               f"second {walls[1]:.2f} s")
-        # Once under the stateful server handle, on a table of its own:
-        # from the zero state one push of row-wise Adagrad leaves
-        # -lr * G / (sqrt(mean(G**2)) + eps) in every touched row.
+        # A 64-wide table is lane-packed two rows to a 128-lane physical
+        # row: on the chip both rounds were written by the kernel, by
+        # physical row.
+        t = se.table("emb")
+        check((se.row_kernel_pushes, se.packed_pushes)
+              == (2 * (self.on_tpu and t.pack * t.dim == 128),
+                  2 * (t.pack != 1)),
+              f"row kernel / packed pushes {se.row_kernel_pushes} / "
+              f"{se.packed_pushes} after two rounds")
+        # Once under the stateful server handle and once more under the
+        # sum, on tables of their own: 128 lanes, and 64 (lane-packed).
+        for name, dim in (("emb_opt", sz.emb_opt_dim), ("emb_opt64", 64)):
+            self._sparse_under_handle(se, name, dim, idx, rng)
+
+    def _sparse_under_handle(self, se, name, dim, idx, rng) -> None:
+        """From the zero state one push of row-wise Adagrad leaves
+        -lr * G / (sqrt(mean(G**2)) + eps) in every touched row; a plain
+        sum into the same table then adds G."""
+        kv, sz = self.kv, self.sizes
+        W = se.num_shards
         lr, eps = 0.05, 1e-8
-        dim = sz.emb_opt_dim
-        se.register_sparse("emb_opt", sz.emb_rows, dim)
+        table = se.register_sparse(name, sz.emb_rows, dim)
+        before = se.row_kernel_pushes, se.packed_pushes
         grads = rng.standard_normal((W, sz.emb_batch, dim), dtype=np.float32)
         out = np.zeros_like(grads)
         t0 = time.perf_counter()
-        kv.wait(kv.push_sparse("emb_opt", idx, grads,
-                               f"row_adagrad:{lr},{eps}"))
-        kv.wait(kv.pull_sparse("emb_opt", idx, out=out))
+        kv.wait(kv.push_sparse(name, idx, grads, f"row_adagrad:{lr},{eps}"))
+        kv.wait(kv.pull_sparse(name, idx, out=out))
         wall = time.perf_counter() - t0
         rows, inverse = np.unique(idx.reshape(-1), return_inverse=True)
         G = np.zeros((len(rows), dim), np.float64)
@@ -396,27 +413,31 @@ class _Smoke:
         want = -lr * G / (np.sqrt(np.mean(G ** 2, axis=1))[:, None] + eps)
         np.testing.assert_allclose(
             out.reshape(-1, dim), want[inverse], rtol=1e-4,
-            atol=1e-6, err_msg="row_adagrad through push_sparse")
-        acc = np.asarray(se.acc_global_device("emb_opt"))
+            atol=1e-6, err_msg=f"row_adagrad through push_sparse, {name}")
+        acc = np.asarray(se.acc_global_device(name))
         check(np.count_nonzero(acc) == len(rows),
               "accumulator rows touched != rows pushed")
-        kernel = se.row_kernel_pushes == 1
-        check(kernel == (self.on_tpu and dim == 128),
+        # The kernel takes physical rows of 128 f32 lanes, packed or not.
+        kernel = se.row_kernel_pushes == before[0] + 1
+        check(kernel == (self.on_tpu and table.pack * dim == 128),
               f"table written by the row kernel: {kernel}")
         print(f"  one push under row_adagrad:{lr},{eps} through "
-              f"push_sparse, {sz.emb_rows:,} x {dim}, the table written by "
+              f"push_sparse, {sz.emb_rows:,} x {dim} (pack {table.pack}), "
+              f"the table written by "
               f"{'ops/row_add.py' if kernel else 'XLA scatter'}: "
               f"{len(rows):,} distinct rows and their accumulators agree "
               f"({wall:.2f} s, compiles)")
         # And the plain sum into the same table: where the kernel takes the
         # rows, a push with no handle is written by distinct row too.
-        kv.wait(kv.push_sparse("emb_opt", idx, grads))
-        kv.wait(kv.pull_sparse("emb_opt", idx, out=out))
+        kv.wait(kv.push_sparse(name, idx, grads))
+        kv.wait(kv.pull_sparse(name, idx, out=out))
         np.testing.assert_allclose(
             out.reshape(-1, dim), (want + G)[inverse], rtol=1e-4,
-            atol=1e-5, err_msg="the sum after row_adagrad")
-        check((se.row_kernel_pushes == 2) == kernel,
+            atol=1e-5, err_msg=f"the sum after row_adagrad, {name}")
+        check((se.row_kernel_pushes == before[0] + 2) == kernel,
               f"row kernel pushes {se.row_kernel_pushes} after the sum")
+        check(se.packed_pushes == before[1] + 2 * (table.pack != 1),
+              f"packed pushes {se.packed_pushes} after the sum")
         print(f"  one push with no handle into the same table, written by "
               f"{'ops/row_add.py' if kernel else 'XLA scatter'}: agrees")
 

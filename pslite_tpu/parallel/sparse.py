@@ -72,6 +72,7 @@ class _BoundPush:
     kind: Optional[str]  # the handle's kind; None: the plain sum
     params: tuple  # the handle's numbers as device scalars; () for the sum
     row_kernel: bool  # the program's table write is ops/row_add.py
+    packed: bool  # the table is lane-packed (pack > 1)
 
 
 def _interleave_rows(glob, num_rows: int, rps: int, S: int, dtype):
@@ -128,6 +129,16 @@ def _unpack_host(phys, rps: int, S: int, pack: int, dim: int):
     ).reshape(S * rps, dim)
 
 
+def _lies_as(arr, sharding, dtype, ndim: int) -> bool:
+    """Whether ``arr`` is a device array of ``dtype`` laid out as
+    ``sharding`` (the same one, not merely an equivalent: the program's
+    cache is keyed by it): what ``jax.device_put(arr, sharding)`` returns."""
+    import jax
+
+    return (isinstance(arr, jax.Array) and arr.ndim == ndim
+            and arr.dtype == dtype and arr.sharding == sharding)
+
+
 def _store_out_format(store, mesh, axis):
     """Output Format pinning a program's donated store output to the
     LIVE store's committed layout (left alone, XLA commits the scatter
@@ -156,18 +167,22 @@ def _scatter_rows(axis, S, R, pack, dim, store_l, idx_l, grads_l):
     single-table and group programs.
 
     Where ``ops/row_add.py`` takes the table's rows (:func:`_on_row_add`:
-    an unpacked table of 128 f32 lanes, a program lowered for a TPU) the
-    duplicates are combined first (:func:`_combine_rows`) and the kernel
-    writes each distinct row once; anywhere else it is XLA's scatter-add,
-    which pays for every slot of the batch but needs no combine.  Unowned
-    rows map out of bounds and mode="drop" discards them.
+    a physical row of 128 f32 lanes, lane-packed or not, a program lowered
+    for a TPU) the duplicates are combined first (:func:`_combine_rows`; on
+    a lane-packed table :func:`_combine_phys_rows`, which also merges the
+    row-mates of a physical row) and the kernel writes each distinct
+    physical row once; anywhere else it is XLA's scatter-add, which pays
+    for every slot of the batch but needs no combine.  Unowned rows map out
+    of bounds and mode="drop" discards them.
 
     The sparse bodies carry ``jax.named_scope``s, by which a device trace
     is read: ``ps.sparse.route`` (indices and rows crossing the workers,
     and who owns what), ``ps.sparse.push.scatter_add``,
     ``ps.sparse.pull.gather``, ``ps.sparse.combine`` (sort and segment sum
     of duplicates: under a stateful handle, and before ``row_add`` in the
-    sum) and under a stateful handle ``ps.update`` (accumulator and
+    sum), ``ps.sparse.pack.place`` (a lane-packed table's rows placed in
+    their slot's lanes, and merged by physical row where ``row_add``
+    follows) and under a stateful handle ``ps.update`` (accumulator and
     step)."""
     import jax
     from jax import lax
@@ -185,28 +200,19 @@ def _scatter_rows(axis, S, R, pack, dim, store_l, idx_l, grads_l):
     def scatter(store_l, owned, local, all_g):
         with jax.named_scope("ps.sparse.push.scatter_add"):
             masked = jnp.where(owned[:, None], all_g, 0)
-            if pack == 1:
-                rows = jnp.where(owned, local, R)  # R: out of bounds, drop
-                return store_l.at[rows].add(masked, mode="drop")
-            phys = jnp.where(owned, local // pack, R // pack)
-            slot = (local % pack).astype(jnp.int32)
-            onehot = (slot[:, None]
-                      == jnp.arange(pack, dtype=jnp.int32)[None])
-            packed = (
-                onehot[:, :, None] * masked[:, None, :]
-            ).reshape(all_idx.shape[0], pack * dim)
-            return store_l.at[phys].add(packed, mode="drop")
+            # R: out of bounds (as R // pack is in the packed table), drop
+            masked, rows = _place_rows(masked, jnp.where(owned, local, R),
+                                       pack)
+            return store_l.at[rows].add(masked, mode="drop")
 
     def by_distinct_row(store_l, owned, local, all_g, interpret):
         with jax.named_scope("ps.sparse.combine"):
-            G_seg, row_seg, valid = _combine_rows(
-                jnp.where(owned, local, R), all_g, R)
+            G_seg, row_seg, valid = _combine_phys_rows(
+                jnp.where(owned, local, R), all_g, R, pack)
         with jax.named_scope("ps.sparse.push.scatter_add"):
             return row_add(store_l, row_seg, G_seg, jnp.sum(valid),
                            interpret=interpret)
 
-    if pack != 1:
-        return scatter(store_l, owned, local, all_g)
     return _on_row_add(scatter, by_distinct_row, store_l, owned, local,
                        all_g)
 
@@ -218,8 +224,10 @@ _ROW_ADD_INTERPRET = {"tpu": False}
 
 
 def _row_add_takes(width: int, dtype) -> bool:
-    """The rows ``ops/row_add.py`` moves: 128 lanes of f32, one table row
-    each (a wider row spans tiles, and Mosaic refuses the slice of one)."""
+    """The rows ``ops/row_add.py`` moves: PHYSICAL rows of 128 f32 lanes,
+    ``width = pack * dim``: one unpacked row, or the ``pack`` logical rows
+    a lane-packed table keeps in one (a wider row spans tiles, and Mosaic
+    refuses the slice of one)."""
     return width == 128 and np.dtype(dtype) == np.float32
 
 
@@ -241,27 +249,69 @@ def _on_row_add(scatter, kernel, store_l, *operands):
                                   **kernels)
 
 
-def _add_rows(store_l, row_seg, valid, delta, R):
+def _add_rows(store_l, row_seg, valid, delta, R, pack):
     """``store_l[row_seg[i]] += delta[i]`` where ``valid[i]``, for combined
-    rows (:func:`_combine_rows`): ascending, each once, the valid ones
-    first.  No two updates touch one row, so where the kernel serves
-    (:func:`_on_row_add`) the write visits the distinct rows only
-    (``ops/row_add.py``); anywhere else it is XLA's scatter, which pays
-    for every slot."""
+    LOGICAL rows (:func:`_combine_rows`): ascending, each once, the valid
+    ones first, ``delta`` zero past them.  Where the kernel serves
+    (:func:`_on_row_add`) the write visits the distinct physical rows only
+    (``ops/row_add.py``; on a lane-packed table the rows are placed and
+    their row-mates merged first, :func:`_combine_phys_rows`); anywhere
+    else it is XLA's scatter, which pays for every slot."""
     import jax.numpy as jnp
 
     from ..ops.row_add import row_add
 
     def scatter(store_l, row_seg, valid, delta):
-        return store_l.at[jnp.where(valid, row_seg, R)].add(
-            delta, mode="drop"
-        )
+        delta, rows = _place_rows(delta, jnp.where(valid, row_seg, R), pack)
+        return store_l.at[rows].add(delta, mode="drop")
 
     def kernel(store_l, row_seg, valid, delta, interpret):
+        if pack != 1:
+            delta, row_seg, valid = _combine_phys_rows(
+                jnp.where(valid, row_seg, R), delta, R, pack)
         return row_add(store_l, row_seg, delta, jnp.sum(valid),
                        interpret=interpret)
 
     return _on_row_add(scatter, kernel, store_l, row_seg, valid, delta)
+
+
+def _place_rows(g, rows, pack):
+    """A lane-packed table's updates in the table's own layout: ``g[i]``
+    (``[m, dim]``, for logical row ``rows[i]``) in the ``dim`` lanes of its
+    slot ``rows[i] % pack`` of a ``pack * dim``-lane row, zero in its
+    row-mates' lanes.  Returns them with the physical rows ``rows // pack``
+    (a sentinel ``R``, a multiple of ``pack``, becomes ``R // pack``: the
+    physical table's); an unpacked table's (``pack == 1``) as they are.  A
+    select, not a product with a one-hot: a row-mate is added zero whatever
+    ``g`` holds (``inf * 0`` is NaN)."""
+    from jax import lax
+    import jax.numpy as jnp
+
+    if pack == 1:
+        return g, rows
+    dim = g.shape[1]
+    lane_slot = lax.iota(jnp.int32, pack * dim) // dim
+    mine = (rows % pack).astype(jnp.int32)[:, None] == lane_slot[None, :]
+    return jnp.where(mine, jnp.tile(g, (1, pack)), 0), rows // pack
+
+
+def _combine_phys_rows(local, g, R, pack):
+    """:func:`_combine_rows` by PHYSICAL row, for the write by distinct row
+    (``ops/row_add.py`` moves whole physical rows, and two logical rows of
+    one physical row written by two DMAs would race): ``local`` are
+    logical rows or the sentinel ``R``, ``g`` their ``[m, dim]`` updates.
+    Each is placed in its slot's lanes (:func:`_place_rows`) and the
+    combine runs over ``local // pack``, so duplicates and row-mates end
+    in one entry a distinct physical row; every lane's sum is its own
+    slot's gradients in the batch's order and zeros.  Unpacked
+    (``pack == 1``) it is :func:`_combine_rows` itself."""
+    import jax
+
+    if pack == 1:
+        return _combine_rows(local, g, R)
+    with jax.named_scope("ps.sparse.pack.place"):
+        placed, phys = _place_rows(g, local, pack)
+        return _combine_rows(phys, placed, R // pack)
 
 
 def _combine_rows(local, all_g, R):
@@ -325,10 +375,12 @@ def _adagrad_sparse(axis, S, R, pack, dim, store_l, acc_l, idx_l,
     Here duplicates are combined by a SEGMENT SUM over the sorted
     gathered indices (O(batch) workspaces, exact same per-row G as the
     dense form), the accumulator rows are gathered/updated/scattered
-    1-D, and the store step is added by distinct row (_add_rows) or, for
-    a lane-packed table, scatter-adds through the packed layout —
-    identical numerics to _adagrad_rows on the touched rows, untouched
-    rows never read or written."""
+    1-D by logical row whatever the store's lane packing, and the store
+    step is added by distinct row (_add_rows; a lane-packed table's
+    placed in its slot's lanes and merged by physical row) — identical
+    numerics to _adagrad_rows on the touched rows, untouched rows never
+    read, and written only zeros where they share a touched physical
+    row."""
     import jax
     from jax import lax
     import jax.numpy as jnp
@@ -339,7 +391,6 @@ def _adagrad_sparse(axis, S, R, pack, dim, store_l, acc_l, idx_l,
         my = lax.axis_index(axis)
         owned = (all_idx % S) == my
         local = jnp.where(owned, all_idx // S, R)  # R = sentinel (dropped)
-        m = all_idx.shape[0]
 
     with jax.named_scope("ps.sparse.combine"):
         G_seg, row_seg, valid = _combine_rows(local, all_g, R)
@@ -358,18 +409,8 @@ def _adagrad_sparse(axis, S, R, pack, dim, store_l, acc_l, idx_l,
         step = jnp.where(valid[:, None], step, 0).astype(store_l.dtype)
 
     with jax.named_scope("ps.sparse.push.scatter_add"):
-        # Store: subtract the step, lane-packed through the packed layout.
-        if pack == 1:
-            new_store = _add_rows(store_l, row_seg, valid, -step, R)
-        else:
-            phys = jnp.where(valid, row_seg // pack, R // pack)
-            slot = (row_seg % pack).astype(jnp.int32)
-            onehot = (slot[:, None]
-                      == jnp.arange(pack, dtype=jnp.int32)[None])
-            packed = (onehot[:, :, None] * (-step)[:, None, :]).reshape(
-                m, pack * dim
-            )
-            new_store = store_l.at[phys].add(packed, mode="drop")
+        # Store: subtract the step (a lane-packed table's by physical row).
+        new_store = _add_rows(store_l, row_seg, valid, -step, R, pack)
     return new_store, new_acc
 
 
@@ -446,6 +487,7 @@ class SparseEngine:
         # ops/row_add.py (see export).
         self.stateful_pushes = 0
         self.row_kernel_pushes = 0
+        self.packed_pushes = 0  # pushes into a lane-packed table
         self._mu = threading.Lock()
         # Per-table write locks: push donates the store buffer, so the
         # load-run-store sequence must be atomic per table (same contract
@@ -508,6 +550,8 @@ class SparseEngine:
                        fn=lambda: self.stateful_pushes)
         registry.gauge("engine.sparse.push.row_kernel",
                        fn=lambda: self.row_kernel_pushes)
+        registry.gauge("engine.sparse.push.packed",
+                       fn=lambda: self.packed_pushes)
         registry.gauge(
             "engine.sparse.acc.bytes",
             fn=lambda: sum(int(a.nbytes) for a in list(self._acc.values())))
@@ -660,12 +704,19 @@ class SparseEngine:
             )
             return idx_sh, g_sh
         # Host inputs are cast on the host and placed row by row, each
-        # worker's batch straight onto its device (see staging_xp).
-        idx = staging_xp(indices).asarray(indices, dtype=jnp.int32)
+        # worker's batch straight onto its device (see staging_xp).  A
+        # device array that already lies as the program takes it (a
+        # trainer's own batch) is passed on as it is: the cast and the
+        # placement would both hand it back, ~0.13 ms of the host later.
+        placed = _lies_as(indices, idx_sharding, jnp.int32, 2)
+        idx = (indices if placed
+               else staging_xp(indices).asarray(indices, dtype=jnp.int32))
         log.check_eq(int(idx.shape[0]), self.num_shards, "bad worker dim")
-        idx_sh = jax.device_put(idx, idx_sharding)
+        idx_sh = idx if placed else jax.device_put(idx, idx_sharding)
         if grads is None:
             return idx_sh, None
+        if _lies_as(grads, g_sharding, table.dtype, 3):
+            return idx_sh, grads
         g = staging_xp(grads).asarray(grads, dtype=table.dtype)
         g_sh = jax.device_put(g, g_sharding)
         return idx_sh, g_sh
@@ -820,7 +871,7 @@ class SparseEngine:
         bound = _BoundPush(
             self._sparse_program("push" if kind is None else "push_" + kind,
                                  table, batch),
-            kind, params, self._row_kernel(table))
+            kind, params, self._row_kernel(table), table.pack != 1)
         with self._mu:
             # A new registration meanwhile: the next push binds.
             if self._tables.get(name) is table:
@@ -833,8 +884,8 @@ class SparseEngine:
         :func:`_on_row_add`; the mesh's platform is what the program is
         lowered for)."""
         platform = next(iter(self.mesh.devices.flat)).platform
-        return (platform in _ROW_ADD_INTERPRET and table.pack == 1
-                and _row_add_takes(table.dim, table.dtype))
+        return (platform in _ROW_ADD_INTERPRET
+                and _row_add_takes(table.pack * table.dim, table.dtype))
 
     def push(self, name: str, indices, grads, handle: str = None):
         """indices: [W, n] int rows per worker; grads: [W, n, d].
@@ -872,6 +923,7 @@ class SparseEngine:
                     self._stores[name], self._acc[name], idx, g, *b.params)
                 self.stateful_pushes += 1
             self.row_kernel_pushes += b.row_kernel
+            self.packed_pushes += b.packed
         self._observe("push", table, batch)
         t3 = stamp()
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
@@ -1050,6 +1102,7 @@ class SparseEngine:
             # One push, whatever it groups: counted where the kernel
             # writes any of its tables.
             self.row_kernel_pushes += any(map(self._row_kernel, tables))
+            self.packed_pushes += any(t.pack != 1 for t in tables)
         finally:
             self._unlock_tables(ordered)
         t3 = stamp()

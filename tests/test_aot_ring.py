@@ -324,3 +324,62 @@ def test_sum_push_at_full_size_combines_and_writes_the_table_with_row_add(
     # The gathered gradients in sorted order and their segment sums (64 MiB
     # each at this batch), and the ids.
     assert 2 * lookups * dim * 4 <= mem.temp_size_in_bytes < 3 * lookups * dim * 4
+
+
+@pytest.mark.parametrize("handle", ["sum", "row_adagrad"])
+def test_a_lane_packed_tables_pushes_at_full_size_write_it_with_row_add(
+        v5e_chip, handle):
+    """Both pushes of the configuration ``dlrm-terabyte-emb64`` (54,000,000
+    rows of 64 f32 lanes kept two to a 128-lane physical row, 53,248
+    lookups) lowered for the v5e: the physical table's one result is the
+    ``row_add`` kernel's, in place over the donated table, the rows are
+    placed and merged by physical row under ``ps.sparse.pack.place``
+    before it, and the whole fits the chip.  The cell runs the sum; the
+    push under the handle is measured by no cell and held here."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pslite_tpu.parallel import sparse
+
+    rows, dim, lookups = 54_000_000, 64, 53_248
+    pack = 128 // dim
+    phys = rows // pack
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(v5e_chip, spec))
+
+    store = sds((phys, 128), jnp.float32, P("kv", None))
+    idx = sds((1, lookups), jnp.int32, P("kv", None))
+    grads = sds((1, lookups, dim), jnp.float32, P("kv", None, None))
+    if handle == "sum":
+        body = lambda st, ix, g: sparse._scatter_rows(
+            "kv", 1, rows, pack, dim, st, ix, g)
+        specs = (P("kv", None), P("kv", None), P("kv", None, None))
+        out_specs, donate, args = P("kv", None), (0,), (store, idx, grads)
+        state = phys * 128 * 4
+    else:
+        body = lambda st, ac, ix, g, lr, eps: sparse._adagrad_sparse(
+            "kv", 1, rows, pack, dim, st, ac, ix, g, lr, eps)
+        specs = (P("kv", None), P("kv"), P("kv", None), P("kv", None, None),
+                 P(), P())
+        out_specs, donate = (P("kv", None), P("kv")), (0, 1)
+        scalar = sds((), jnp.float32, P())
+        args = (store, sds((rows,), jnp.float32, P("kv")), idx, grads,
+                scalar, scalar)
+        state = phys * 128 * 4 + rows * 4
+    compiled = jax.jit(jax.shard_map(
+        body, mesh=v5e_chip, in_specs=specs, out_specs=out_specs,
+        check_vma=False), donate_argnums=donate).lower(*args).compile()
+    text = compiled.as_text()
+    table = [l for l in text.splitlines()
+             if f"= f32[{phys},128]" in l and " parameter(" not in l]
+    assert len(table) == 1, table
+    assert " %row_add" in table[0] and "tpu_custom_call" in table[0]
+    assert "ps.sparse.push.scatter_add" in table[0]
+    assert "ps.sparse.pack.place/sort" in text
+    assert ("ps.sparse.combine/sort" in text) == (handle == "row_adagrad")
+    mem = compiled.memory_analysis()
+    assert state <= mem.alias_size_in_bytes < state + (1 << 20)
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 16e9

@@ -71,3 +71,33 @@ def test_the_stateful_sparse_cell_names_its_handle_and_driver(harness):
     assert issubclass(driver, base) and driver is not base
     assert {"combine_ms", "table_write_ms"} <= {
         m["name"] for m in cell.per_layer}
+
+
+def test_the_lane_packed_cell_names_its_table_traffic_and_driver(harness):
+    """``dlrm-terabyte-emb64.zipf``: the upstream DLRM repository's Criteo
+    Terabyte run whole, a 64-wide table (two rows to a 128-lane physical
+    row) under the plain sum, the sparse driver as it stands, and the two
+    per-layer metrics that only this cell reports."""
+    cell = harness.load_cell("dlrm-terabyte-emb64.zipf")
+    config, traffic = cell.config, cell.traffic
+    assert config["kind"] == "sparse" and config["server_handle"] == "sum"
+    assert (config["rows"], config["dim"]) == (54_000_000, 64)
+    assert config["reduced"] == [] and config["dtype"] == "float32"
+    sizes = config["sizes"]
+    assert sizes["mini_batch"] * sizes["categorical_features"] \
+        == traffic["lookups_per_worker"] == 53_248
+    assert sizes["embedding_width"] == config["dim"]
+    assert traffic["name"] == "zipf-rows-2048x26"
+    assert traffic["driver"] == "sparse_pull_push"
+    entry = next(c for c in BENCHMARK["configs"]
+                 if c["name"] == "dlrm-terabyte-emb64")
+    assert entry["source"] == config["source"] and entry["reduced"] == []
+    # The whole table on one chip: over the floor of a quarter of it.
+    assert 0.25 * 16e9 < config["rows"] * config["dim"] * 4 < 16e9
+    names = {m["name"] for m in cell.per_layer}
+    assert {"packed_write_ms", "packed_combine_ms", "roofline_share",
+            "busy_ms", "launches_per_step"} <= names
+    assert not {"combine_ms", "table_write_ms", "route_ms"} & names
+    # The sum cell's guarantees word for word, and one more of its own.
+    emb = harness.load_cell("dlrm-criteo-emb.zipf").config
+    assert config["guarantees"].startswith(emb["guarantees"])

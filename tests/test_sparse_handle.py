@@ -151,6 +151,45 @@ def test_three_pushes_then_a_pull_match_the_reference(cluster, dim):
     assert _row_error(rounded.table, ref.table) > 100 * TOL
 
 
+@pytest.mark.parametrize("dim", [64, 128])
+@pytest.mark.parametrize("cluster", [1, 4], indirect=True)
+def test_inputs_that_lie_as_the_program_takes_them_are_passed_on(cluster, dim):
+    """``_prep`` hands a device array of the worker axis' sharding and the
+    table's dtype to the program as it is (no cast, no placement), takes
+    every other input the way it did, and the table ends the same."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    kv, eng = cluster
+    W = eng.num_shards
+    idx, init, grads = _traffic(W, dim)
+    table = eng.register_sparse("emb", ROWS, dim, init=init)
+    eng.register_sparse("host", ROWS, dim, init=init)
+    idx_d = jax.device_put(idx, NamedSharding(eng.mesh, P(eng.axis, None)))
+    g_d = jax.device_put(
+        grads[0], NamedSharding(eng.mesh, P(eng.axis, None, None)))
+    got_idx, got_g = eng._prep(table, idx_d, g_d)
+    assert got_idx is idx_d and got_g is g_d
+    # Another dtype, another layout or the host: cast and placed as before.
+    for other in (idx.astype(np.int64), jnp.asarray(idx), idx):
+        placed, _ = eng._prep(table, other)
+        assert placed is not other and placed.dtype == jnp.int32
+        assert placed.sharding.is_equivalent_to(idx_d.sharding, 2)
+        assert (np.asarray(placed) == idx).all()
+    kv.wait(kv.push_sparse("emb", idx_d, g_d))
+    kv.wait(kv.push_sparse("host", idx, grads[0]))
+    assert (np.asarray(eng.store_global_device("emb"))
+            == np.asarray(eng.store_global_device("host"))).all()
+    ts = kv.pull_sparse("emb", idx_d)
+    pulled = np.asarray(kv.get_pulled(ts))
+    kv.wait(ts)
+    out = np.zeros((W, idx.shape[1], dim), np.float32)
+    kv.wait(kv.pull_sparse("host", idx, out=out))
+    assert (pulled == out).all()
+    with pytest.raises(log.CheckError, match="bad worker dim"):
+        eng._prep(table, jnp.zeros((W + 1, 4), jnp.int32))
+
+
 @pytest.mark.parametrize("cluster", [1, 4], indirect=True)
 def test_a_push_with_no_handle_is_the_plain_sum_it_was(cluster):
     """The same ``_programs`` key, and bit for bit what
@@ -214,12 +253,16 @@ def test_the_row_kernel_in_the_push_is_bit_equal_to_xlas_scatter(
 
     traced = _kernel_on_cpu(monkeypatch)
     eng.register_sparse("emb", ROWS, 128, init=init)
-    eng.register_sparse("packed", ROWS, 8, init=init[:, :8])
-    # A row wider than one tile keeps the scatter (Mosaic refuses the
-    # kernel's one-row slice of it: ops/row_add.py).
+    packed = eng.register_sparse("packed", ROWS, 8, init=init[:, :8])
+    # The rule speaks of the PHYSICAL row: a lane-packed table keeps 16
+    # rows of 8 lanes in one of 128 and takes the kernel; a row wider than
+    # one tile keeps the scatter (Mosaic refuses the kernel's one-row slice
+    # of it: ops/row_add.py), and so does a table that is not f32.
     wide = eng.register_sparse("wide", ROWS, 256)
-    assert eng._row_kernel(eng.table("emb")) and not eng._row_kernel(wide)
-    assert not eng._row_kernel(eng.table("packed"))
+    half = eng.register_sparse("half", ROWS, 64, dtype=jax.numpy.bfloat16)
+    assert eng._row_kernel(eng.table("emb")) and eng._row_kernel(packed)
+    assert not eng._row_kernel(wide) and not eng._row_kernel(half)
+    assert (packed.pack, half.pack, wide.pack) == (16, 2, 1)
     for g in grads:
         ts = kv.push_sparse("emb", idx, g, HANDLE)
     kv.wait(ts)
@@ -228,15 +271,19 @@ def test_the_row_kernel_in_the_push_is_bit_equal_to_xlas_scatter(
     assert (eng.store_array("emb") == twin.store_array("emb")).all()
     assert (np.asarray(eng._acc["emb"]) == np.asarray(twin._acc["emb"])).all()
     assert _gauges(kv)["engine.sparse.push.row_kernel"] == 3
-    # Not for a lane-packed table; a push with no handle whose program
-    # writes through the kernel is counted like one under a handle.
+    assert _gauges(kv)["engine.sparse.push.packed"] == 0
+    # A lane-packed table's push is written by the kernel too, by physical
+    # row; a push with no handle whose program writes through the kernel is
+    # counted like one under a handle.
     kv.wait(kv.push_sparse("packed", idx, grads[0][..., :8], HANDLE))
-    assert _gauges(kv)["engine.sparse.push.row_kernel"] == 3
+    assert set(traced) == {(rps, 128), (packed.phys_rows, 128)}
+    assert _gauges(kv)["engine.sparse.push.row_kernel"] == 4
+    assert _gauges(kv)["engine.sparse.push.packed"] == 1
     kv.wait(kv.push_sparse("emb", idx, grads[0]))
-    assert set(traced) == {(rps, 128)}
     after = _gauges(kv)
     assert after["engine.sparse.push.stateful"] == 4
-    assert after["engine.sparse.push.row_kernel"] == 4
+    assert after["engine.sparse.push.row_kernel"] == 5
+    assert after["engine.sparse.push.packed"] == 1
 
 
 def _sum_reference(init, idx, grads):
@@ -282,42 +329,143 @@ def test_the_sum_push_by_distinct_row_matches_the_reference(
 
 
 @pytest.mark.parametrize("cluster", [1, 4], indirect=True)
-def test_packed_and_wide_tables_keep_the_scatter_and_a_group_follows_its_tables(
+def test_packed_tables_take_the_kernel_wide_ones_the_scatter_and_a_group_follows_its_tables(
         cluster, monkeypatch):
     kv, eng = cluster
     W = eng.num_shards
     idx, init, grads = _traffic(W, 256)
     traced = _kernel_on_cpu(monkeypatch)
-    eng.register_sparse("packed", ROWS, 8, init=init[:, :8])
+    packed = eng.register_sparse("packed", ROWS, 8, init=init[:, :8])
     eng.register_sparse("wide", ROWS, 256, init=init)
     for g in grads:
-        kv.wait(kv.push_sparse("packed", idx, g[..., :8]))
         kv.wait(kv.push_sparse("wide", idx, g))
     assert not traced and eng.row_kernel_pushes == 0
+    for g in grads:
+        kv.wait(kv.push_sparse("packed", idx, g[..., :8]))
+    assert set(traced) == {(packed.phys_rows, 128)}
+    assert (eng.row_kernel_pushes, eng.packed_pushes) == (3, 3)
     for name, width in (("packed", 8), ("wide", 256)):
         np.testing.assert_allclose(
             np.asarray(eng.store_global_device(name)),
             _sum_reference(init[:, :width], idx,
                            [g[..., :width] for g in grads]),
             rtol=1e-5, atol=1e-5)
-    # A group is one push: the kernel writes the table that it takes, the
+    # A group is one push: the kernel writes the tables that it takes, the
     # scatter the one it does not, and the push is counted once.
+    del traced[:]
     a = eng.register_sparse("a", ROWS, 128, init=init[:, :128])
-    eng.register_sparse("b", ROWS, 8, init=init[:, :8])
+    b = eng.register_sparse("b", ROWS, 64, init=init[:, :64])
+    eng.register_sparse("c", ROWS, 256, init=init)
     for g in grads:
-        token = eng.push_group(["a", "b"], [idx, idx],
-                               [g[..., :128], g[..., :8]])
+        token = eng.push_group(["a", "b", "c"], [idx, idx, idx],
+                               [g[..., :128], g[..., :64], g])
     token.block_until_ready()
-    assert set(traced) == {(a.rows_per_shard, 128)}
-    assert (eng.stateful_pushes, eng.row_kernel_pushes) == (0, 3)
-    for name, width in (("a", 128), ("b", 8)):
+    assert set(traced) == {(a.rows_per_shard, 128), (b.phys_rows, 128)}
+    assert (eng.stateful_pushes, eng.row_kernel_pushes,
+            eng.packed_pushes) == (0, 6, 6)
+    for name, width in (("a", 128), ("b", 64), ("c", 256)):
         np.testing.assert_allclose(
             np.asarray(eng.store_global_device(name)),
             _sum_reference(init[:, :width], idx,
                            [g[..., :width] for g in grads]),
             rtol=1e-5, atol=1e-5)
-    eng.push_group(["b"], [idx], [grads[0][..., :8]]).block_until_ready()
-    assert eng.row_kernel_pushes == 3
+    eng.push_group(["c"], [idx], [grads[0]]).block_until_ready()
+    assert (eng.row_kernel_pushes, eng.packed_pushes) == (6, 6)
+
+
+# -- lane-packed tables by physical row ------------------------------------------
+
+
+def _mates(table, S, rows):
+    """The logical rows that share a physical row with one of ``rows``
+    (``rows`` among them): global row r lives on shard r % S at local row
+    r // S, ``pack`` local rows to a physical one."""
+    everyone = np.arange(table.num_rows)
+    where = lambda r: (r % S) * table.phys_rows + (r // S) // table.pack
+    return everyone[np.isin(where(everyone), where(np.asarray(rows)))]
+
+
+def _packed_traffic(W, dim):
+    """``_traffic`` and two rows more in every worker's batch: row ``W``,
+    which on ``W`` shards is local row 1 of shard 0 and so the hottest
+    row's mate in any lane-packed table, and row 58, whose mates 50.. no
+    push touches."""
+    idx, init, grads = _traffic(W, dim)
+    idx[:, 4], idx[:, 5] = W, 58
+    return idx, init, grads
+
+
+@pytest.mark.parametrize("grouped", [False, True],
+                         ids=["single", "grouped"])
+@pytest.mark.parametrize("handle", [None, HANDLE], ids=["sum", "row_adagrad"])
+@pytest.mark.parametrize("dim", [8, 64, 128])
+@pytest.mark.parametrize("cluster", [1, 4], indirect=True)
+def test_a_push_by_physical_row_matches_the_reference_and_the_scatter(
+        cluster, dim, handle, grouped, monkeypatch):
+    """With the CPU named among the kernel's platforms both pushes of a
+    lane-packed table (``dim`` 8: 16 rows to a physical row, 64: two) go
+    through placement, the combine by physical row and ``row_add``
+    (interpreted), as an unpacked table's (128) through the combine alone:
+    the float64 reference (every gradient once, duplicates, row-mates of
+    one physical row in one push, unowned slots dropped on four shards),
+    an untouched row-mate bit-unchanged, and XLA's scatter to f32
+    rounding; under the handle, bit for bit."""
+    kv, eng = cluster
+    W = eng.num_shards
+    idx, init, grads = _packed_traffic(W, dim)
+    other = 64 if dim == 128 else 128       # a group mixes packed and not
+    init_o = init[:, :1].repeat(other, axis=1)
+    grads_o = [g[..., :1].repeat(other, axis=2) for g in grads]
+    names = ["emb", "other"] if grouped else ["emb"]
+    data = {"emb": (dim, init, grads), "other": (other, init_o, grads_o)}
+
+    def run(engine):
+        for n in names:
+            engine.register_sparse(n, ROWS, data[n][0], init=data[n][1])
+        for step in range(len(grads)):
+            if grouped:
+                token = engine.push_group(
+                    names, [idx] * 2, [data[n][2][step] for n in names],
+                    handle=handle)
+            else:
+                token = engine.push("emb", idx, grads[step], handle)
+        token.block_until_ready()
+
+    twin = SparseEngine(eng.mesh, eng.axis)
+    run(twin)                               # XLA's scatter: the CPU's own
+    assert twin.row_kernel_pushes == 0
+    traced = _kernel_on_cpu(monkeypatch)
+    run(eng)
+    tables = [eng.table(n) for n in names]
+    assert set(traced) == {(t.phys_rows, 128) for t in tables}
+    packed = any(t.pack != 1 for t in tables)
+    assert (eng.row_kernel_pushes, eng.packed_pushes, eng.stateful_pushes) \
+        == (3, 3 * packed, 3 * (handle is not None))
+    touched = np.unique(idx)
+    for n, table in zip(names, tables):
+        width, init_n, grads_n = data[n]
+        got = np.asarray(eng.store_global_device(n))
+        scattered = np.asarray(twin.store_global_device(n))
+        if handle is None:
+            want = _sum_reference(init_n, idx, grads_n)
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+            np.testing.assert_allclose(got, scattered, rtol=1e-5, atol=1e-5)
+        else:
+            ref = RowAdagrad(init_n)
+            for g in grads_n:
+                ref.push(idx, g)
+            assert _row_error(got, ref.table) < TOL
+            # One logical row a lane, and zeros: the merge adds nothing.
+            assert (got == scattered).all()
+            assert (np.asarray(eng._acc[n]) == np.asarray(twin._acc[n])).all()
+        # Rows no push touched keep their bits, those among them that
+        # share a physical row with a touched one too.
+        quiet = np.setdiff1d(np.arange(ROWS), touched)
+        assert (got[quiet] == init_n[quiet]).all()
+        if table.pack != 1:
+            quiet_mates = np.intersect1d(quiet, _mates(table, W, touched))
+            assert len(quiet_mates) >= 1 and W in _mates(table, W, [0])
+        assert np.isfinite(got).all()
 
 
 # -- the combine both pushes share ----------------------------------------------
