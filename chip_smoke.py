@@ -17,8 +17,8 @@ reference:
   ``push_sparse`` / ``pull_sparse``, then one push under the stateful
   server handle ``row_adagrad`` and one plain sum on tables of their own,
   2^20 x 128 and 2^20 x 64 (physical rows of 128 f32 lanes, the 64-wide
-  lane-packed two to one: on the chip all are written by the
-  ``ops/row_add.py`` kernel);
+  lane-packed two to one: on the chip all are summed by distinct row with
+  the ``ops/segment_sum.py`` kernel and written by ``ops/row_add.py``'s);
 - ``message_path``: an unregistered key, which the collective path cannot
   take, answered by the ``KVServer`` handler;
 - ``ring`` (two or more devices): the ResNet-50 buckets once more through
@@ -379,13 +379,14 @@ class _Smoke:
         print(f"  set-up: first round (compiles) {walls[0]:.2f} s; "
               f"second {walls[1]:.2f} s")
         # A 64-wide table is lane-packed two rows to a 128-lane physical
-        # row: on the chip both rounds were written by the kernel, by
-        # physical row.
+        # row: on the chip both rounds were summed by physical row with
+        # ops/segment_sum.py and written by ops/row_add.py.
         t = se.table("emb")
-        check((se.row_kernel_pushes, se.packed_pushes)
-              == (2 * (self.on_tpu and t.pack * t.dim == 128),
-                  2 * (t.pack != 1)),
-              f"row kernel / packed pushes {se.row_kernel_pushes} / "
+        kernels = 2 * (self.on_tpu and t.pack * t.dim == 128)
+        check((se.row_kernel_pushes, se.segsum_kernel_pushes,
+               se.packed_pushes) == (kernels, kernels, 2 * (t.pack != 1)),
+              f"row kernel / segment sum kernel / packed pushes "
+              f"{se.row_kernel_pushes} / {se.segsum_kernel_pushes} / "
               f"{se.packed_pushes} after two rounds")
         # Once under the stateful server handle and once more under the
         # sum, on tables of their own: 128 lanes, and 64 (lane-packed).
@@ -400,7 +401,8 @@ class _Smoke:
         W = se.num_shards
         lr, eps = 0.05, 1e-8
         table = se.register_sparse(name, sz.emb_rows, dim)
-        before = se.row_kernel_pushes, se.packed_pushes
+        before = (se.row_kernel_pushes, se.packed_pushes,
+                  se.segsum_kernel_pushes)
         grads = rng.standard_normal((W, sz.emb_batch, dim), dtype=np.float32)
         out = np.zeros_like(grads)
         t0 = time.perf_counter()
@@ -417,12 +419,18 @@ class _Smoke:
         acc = np.asarray(se.acc_global_device(name))
         check(np.count_nonzero(acc) == len(rows),
               "accumulator rows touched != rows pushed")
-        # The kernel takes physical rows of 128 f32 lanes, packed or not.
+        # The kernels take physical rows of 128 f32 lanes, packed or not:
+        # where one writes the table, the other summed the duplicates (a
+        # lane-packed table's where they are merged by physical row).
         kernel = se.row_kernel_pushes == before[0] + 1
         check(kernel == (self.on_tpu and table.pack * dim == 128),
               f"table written by the row kernel: {kernel}")
+        check(se.segsum_kernel_pushes == before[2] + kernel,
+              f"segment sum kernel pushes {se.segsum_kernel_pushes}")
         print(f"  one push under row_adagrad:{lr},{eps} through "
               f"push_sparse, {sz.emb_rows:,} x {dim} (pack {table.pack}), "
+              f"duplicates summed by "
+              f"{'ops/segment_sum.py' if kernel else 'XLA scatter-add'}, "
               f"the table written by "
               f"{'ops/row_add.py' if kernel else 'XLA scatter'}: "
               f"{len(rows):,} distinct rows and their accumulators agree "
@@ -436,6 +444,9 @@ class _Smoke:
             atol=1e-5, err_msg=f"the sum after row_adagrad, {name}")
         check((se.row_kernel_pushes == before[0] + 2) == kernel,
               f"row kernel pushes {se.row_kernel_pushes} after the sum")
+        check(se.segsum_kernel_pushes == before[2] + 2 * kernel,
+              f"segment sum kernel pushes {se.segsum_kernel_pushes} after "
+              f"the sum")
         check(se.packed_pushes == before[1] + 2 * (table.pack != 1),
               f"packed pushes {se.packed_pushes} after the sum")
         print(f"  one push with no handle into the same table, written by "

@@ -72,6 +72,7 @@ class _BoundPush:
     kind: Optional[str]  # the handle's kind; None: the plain sum
     params: tuple  # the handle's numbers as device scalars; () for the sum
     row_kernel: bool  # the program's table write is ops/row_add.py
+    segsum_kernel: bool  # it sums its segments with ops/segment_sum.py
     packed: bool  # the table is lane-packed (pack > 1)
 
 
@@ -223,30 +224,69 @@ def _scatter_rows(axis, S, R, pack, dim, store_l, idx_l, grads_l):
 _ROW_ADD_INTERPRET = {"tpu": False}
 
 
+# Where the combine's segment sum is ``ops/segment_sum.py``: a table of its
+# own, so that a test can put either kernel alone into a CPU program
+# (``row_add`` gives XLA's scatter's bits only while the sums before it are
+# added in XLA's order).
+_SEGMENT_SUM_INTERPRET = {"tpu": False}
+
+
 def _row_add_takes(width: int, dtype) -> bool:
     """The rows ``ops/row_add.py`` moves: PHYSICAL rows of 128 f32 lanes,
     ``width = pack * dim``: one unpacked row, or the ``pack`` logical rows
     a lane-packed table keeps in one (a wider row spans tiles, and Mosaic
-    refuses the slice of one)."""
+    refuses the slice of one).  ``ops/segment_sum.py`` sums the same rows
+    and no others (:func:`_segment_sums`)."""
     return width == 128 and np.dtype(dtype) == np.float32
+
+
+def _where_lowered(interprets, xla, kernel, *operands):
+    """``kernel(*operands, interpret)`` where the program is lowered for a
+    platform of ``interprets`` (platform -> the kernel's ``interpret``
+    there), ``xla(*operands)`` anywhere else: chosen at lowering, so one
+    traced program serves whatever it is compiled for, and no caller says
+    which."""
+    from jax import lax
+
+    kernels = {platform: functools.partial(kernel, interpret=interpret)
+               for platform, interpret in interprets.items()}
+    return lax.platform_dependent(*operands, default=xla, **kernels)
 
 
 def _on_row_add(scatter, kernel, store_l, *operands):
     """``kernel(store_l, *operands, interpret)`` where the program is
     lowered for a platform of ``_ROW_ADD_INTERPRET`` and ``row_add`` takes
     the table's rows (:func:`_row_add_takes`), ``scatter(store_l,
-    *operands)`` anywhere else: chosen at lowering, so one traced program
-    serves whatever it is compiled for, and no caller says which.  The
-    one rule of both pushes' table write (and of
-    ``SparseEngine._row_kernel``, which counts it)."""
-    from jax import lax
-
+    *operands)`` anywhere else (:func:`_where_lowered`).  The one rule of
+    both pushes' table write (and of ``SparseEngine._row_kernel``, which
+    counts it)."""
     if not _row_add_takes(store_l.shape[1], store_l.dtype):
         return scatter(store_l, *operands)
-    kernels = {platform: functools.partial(kernel, interpret=interpret)
-               for platform, interpret in _ROW_ADD_INTERPRET.items()}
-    return lax.platform_dependent(store_l, *operands, default=scatter,
-                                  **kernels)
+    return _where_lowered(_ROW_ADD_INTERPRET, scatter, kernel, store_l,
+                          *operands)
+
+
+def _segment_sums(seg, sg):
+    """``G[k] = sum(sg[j] for j where seg[j] == k)`` as ``[m, d]``, for the
+    sorted batch of :func:`_combine_rows`: ``ops/segment_sum.py`` where the
+    program is lowered for a platform of ``_SEGMENT_SUM_INTERPRET`` and the
+    rows are 128 f32 lanes (both pushes of an unpacked 128-wide table, and
+    whatever is combined by physical row on a lane-packed one), XLA's
+    scatter-add into a workspace anywhere else, which pays for every slot
+    (:func:`_where_lowered`; ``SparseEngine._segsum_kernel`` counts it).
+    The kernel leaves rows past the last segment's block unwritten: no
+    caller reads a row that is not ``valid``."""
+    import jax.numpy as jnp
+
+    from ..ops.segment_sum import segment_sum
+
+    def scatter(seg, sg):
+        return jnp.zeros(sg.shape, sg.dtype).at[seg].add(sg)
+
+    if not _row_add_takes(sg.shape[1], sg.dtype):
+        return scatter(seg, sg)
+    return _where_lowered(_SEGMENT_SUM_INTERPRET, scatter, segment_sum, seg,
+                          sg)
 
 
 def _add_rows(store_l, row_seg, valid, delta, R, pack):
@@ -344,7 +384,7 @@ def _combine_rows(local, all_g, R):
     # One segment a distinct row; the sentinels sort last, into one.
     first = jnp.concatenate([jnp.ones((1,), bool), sr[1:] != sr[:-1]])
     seg = jnp.cumsum(first) - 1                                # [m]
-    G_seg = jnp.zeros((m, sg.shape[1]), sg.dtype).at[seg].add(sg)
+    G_seg = _segment_sums(seg, sg)
     # Row of each segment: every segment's first id, the others at the
     # sentinel, sorted once more, which is "distinct rows ascending,
     # sentinels last" (0.09 ms on a v5e at m = 131,072, where
@@ -484,9 +524,11 @@ class SparseEngine:
         self._bound: Dict[tuple, _BoundPush] = {}
         # Pushes that ran under a stateful handle, and pushes, under a
         # handle or not, whose program writes the table through
-        # ops/row_add.py (see export).
+        # ops/row_add.py, or sums its duplicates with ops/segment_sum.py
+        # (see export).
         self.stateful_pushes = 0
         self.row_kernel_pushes = 0
+        self.segsum_kernel_pushes = 0
         self.packed_pushes = 0  # pushes into a lane-packed table
         self._mu = threading.Lock()
         # Per-table write locks: push donates the store buffer, so the
@@ -550,6 +592,8 @@ class SparseEngine:
                        fn=lambda: self.stateful_pushes)
         registry.gauge("engine.sparse.push.row_kernel",
                        fn=lambda: self.row_kernel_pushes)
+        registry.gauge("engine.sparse.push.segsum_kernel",
+                       fn=lambda: self.segsum_kernel_pushes)
         registry.gauge("engine.sparse.push.packed",
                        fn=lambda: self.packed_pushes)
         registry.gauge(
@@ -871,7 +915,8 @@ class SparseEngine:
         bound = _BoundPush(
             self._sparse_program("push" if kind is None else "push_" + kind,
                                  table, batch),
-            kind, params, self._row_kernel(table), table.pack != 1)
+            kind, params, self._row_kernel(table),
+            self._segsum_kernel(table, kind is not None), table.pack != 1)
         with self._mu:
             # A new registration meanwhile: the next push binds.
             if self._tables.get(name) is table:
@@ -881,11 +926,24 @@ class SparseEngine:
     def _row_kernel(self, table: SparseTable) -> bool:
         """Whether this mesh's push programs of ``table``, the sum's and a
         stateful handle's, write it through ``ops/row_add.py`` (the rule of
-        :func:`_on_row_add`; the mesh's platform is what the program is
-        lowered for)."""
-        platform = next(iter(self.mesh.devices.flat)).platform
-        return (platform in _ROW_ADD_INTERPRET
+        :func:`_on_row_add`)."""
+        return (self._platform() in _ROW_ADD_INTERPRET
                 and _row_add_takes(table.pack * table.dim, table.dtype))
+
+    def _platform(self) -> str:
+        """What this mesh's programs are lowered for."""
+        return next(iter(self.mesh.devices.flat)).platform
+
+    def _segsum_kernel(self, table: SparseTable, stateful: bool) -> bool:
+        """Whether this mesh's push program of ``table`` sums a combine's
+        segments with ``ops/segment_sum.py`` (the rule of
+        :func:`_segment_sums`).  Where ``row_add`` follows, either push
+        combines by physical row, which is the width both kernels take; a
+        stateful handle also combines by logical row whatever follows."""
+        return (self._platform() in _SEGMENT_SUM_INTERPRET
+                and (self._row_kernel(table)
+                     or (stateful
+                         and _row_add_takes(table.dim, table.dtype))))
 
     def push(self, name: str, indices, grads, handle: str = None):
         """indices: [W, n] int rows per worker; grads: [W, n, d].
@@ -923,6 +981,7 @@ class SparseEngine:
                     self._stores[name], self._acc[name], idx, g, *b.params)
                 self.stateful_pushes += 1
             self.row_kernel_pushes += b.row_kernel
+            self.segsum_kernel_pushes += b.segsum_kernel
             self.packed_pushes += b.packed
         self._observe("push", table, batch)
         t3 = stamp()
@@ -1099,9 +1158,11 @@ class SparseEngine:
                     self._acc[n] = outs[kk + i]
                 token = outs[2 * kk]
                 self.stateful_pushes += 1
-            # One push, whatever it groups: counted where the kernel
-            # writes any of its tables.
+            # One push, whatever it groups: counted where a kernel
+            # serves any of its tables.
             self.row_kernel_pushes += any(map(self._row_kernel, tables))
+            self.segsum_kernel_pushes += any(
+                self._segsum_kernel(t, handle is not None) for t in tables)
             self.packed_pushes += any(t.pack != 1 for t in tables)
         finally:
             self._unlock_tables(ordered)

@@ -172,6 +172,20 @@ def v5e_chip(v5e8_mesh):
     return Mesh(np.array([v5e8_mesh.devices.flat[0]]), ("kv",))
 
 
+def _segment_sum_is_the_kernel(text, m, scope):
+    """The compiled push sums its segments with ``ops/segment_sum.py``: one
+    Mosaic call named ``%segment_sum`` whose result is the batch's
+    ``f32[m,128]``, under ``scope``, and no scatter of that shape is left
+    (XLA's scatter-add into the workspace was one)."""
+    sums = [l for l in text.splitlines()
+            if l.replace("ROOT ", "").lstrip().startswith("%segment_sum")]
+    assert len(sums) == 1, sums
+    assert f"= f32[{m},128]" in sums[0] and "tpu_custom_call" in sums[0]
+    assert scope in sums[0] and "segment_sum/pallas_call" in sums[0]
+    assert not [l for l in text.splitlines()
+                if " scatter(" in l and f"= f32[{m},128]" in l]
+
+
 @pytest.mark.parametrize("m", [12, 1500, 4096, 131_072])
 def test_row_add_compiles_for_v5e_in_place(v5e_chip, m):
     """The kernel lowers through Mosaic at a real table size, for a batch
@@ -204,6 +218,33 @@ def test_row_add_compiles_for_v5e_in_place(v5e_chip, m):
     assert " %row_add" in whole[0]
 
 
+@pytest.mark.parametrize("m", [12, 1500, 53_248, 131_072])
+def test_segment_sum_compiles_for_v5e(v5e_chip, m):
+    """``ops/segment_sum.py`` lowers through Mosaic for a batch below one
+    block of slots, one that is no whole number of blocks (padded, and cut
+    back), and the two cells' own, whose one result of the batch's size is
+    the kernel's: no copy of it beside it."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pslite_tpu.ops.segment_sum import _BLOCK, segment_sum
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(v5e_chip, P()))
+
+    compiled = jax.jit(
+        lambda seg, sg: segment_sum(seg, sg, interpret=False),
+    ).lower(sds((m,), jnp.int32), sds((m, 128), jnp.float32)).compile()
+    text = compiled.as_text()
+    padded = -(-m // _BLOCK) * _BLOCK
+    _segment_sum_is_the_kernel(text, padded, "segment_sum")
+    if padded == m:
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+        assert not [l for l in text.splitlines() if " copy(" in l
+                    and f"= f32[{m},128]" in l]
+
+
 @pytest.mark.parametrize("kept", [False, True])
 def test_row_adagrad_push_at_full_size_writes_the_table_with_row_add(
         v5e_chip, kept, tmp_path, monkeypatch):
@@ -212,24 +253,31 @@ def test_row_adagrad_push_at_full_size_writes_the_table_with_row_add(
     that test calls it, from this CPU-default process): lowered for the
     v5e its table write is the ``row_add`` kernel under the scope
     ``ps.sparse.push.scatter_add``, no scatter has the table for its
-    result, and both donations still hold.  That is compiled once, for
-    ``kept``: the program a later process builds from the kernel's trace as
-    the compile cache's directory keeps it, without tracing the kernel
-    (the same module, so the same compiled program, as the one traced in
-    place, of which only the lowering is looked at)."""
+    result, the segments are summed by the ``segment_sum`` kernel under
+    ``ps.sparse.combine``, and both donations still hold.  That is compiled
+    once, for ``kept``: the program a later process builds from the two
+    kernels' traces as the compile cache's directory keeps them, without
+    tracing either (the same module, so the same compiled program, as the
+    one traced in place, of which only the lowering is looked at)."""
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from pslite_tpu.ops import row_add as row_add_module
+    from pslite_tpu.ops import segment_sum as segment_sum_module
     from pslite_tpu.parallel import sparse
     from pslite_tpu.utils import compile_cache
 
     assert jax.devices()[0].platform == "cpu"
-    traced = []
+    traced, summed = [], []
     real = row_add_module._row_add
     monkeypatch.setattr(
         row_add_module, "_row_add",
         lambda store, *rest: traced.append(store.shape) or real(store, *rest))
+    real_sum = segment_sum_module._segment_sum
+    monkeypatch.setattr(
+        segment_sum_module, "_segment_sum",
+        lambda seg, sg, *rest: summed.append(sg.shape) or real_sum(
+            seg, sg, *rest))
     if kept:
         monkeypatch.setattr(compile_cache, "_trace_dir",
                             lambda: str(tmp_path))
@@ -259,16 +307,18 @@ def test_row_adagrad_push_at_full_size_writes_the_table_with_row_add(
             sds((1, lookups, dim), jnp.float32, P("kv", None, None)),
             scalar, scalar)
     lowered = push().lower(*args)
-    assert traced == [(rows, dim)]
+    assert traced == [(rows, dim)] and summed == [(lookups, dim)]
     if not kept:
         text = lowered.as_text(debug_info=True)
         assert "tpu_custom_call" in text and "row_add" in text
+        assert "segment_sum" in text
         assert "ps.sparse.push.scatter_add" in text
         return
     compile_cache._traced.clear()               # a new process
     lowered = push().lower(*args)
-    assert traced == [(rows, dim)]              # not traced again
-    assert len(os.listdir(tmp_path)) == 1
+    assert traced == [(rows, dim)]              # neither is traced again
+    assert summed == [(lookups, dim)]
+    assert len(os.listdir(tmp_path)) == 2       # a file a kernel
     compiled = lowered.compile()
     table = [l for l in compiled.as_text().splitlines()
              if f"= f32[{rows},{dim}]" in l and " parameter(" not in l]
@@ -276,10 +326,16 @@ def test_row_adagrad_push_at_full_size_writes_the_table_with_row_add(
     assert " %row_add" in table[0] and "tpu_custom_call" in table[0]
     assert "ps.sparse.push.scatter_add" in table[0]
     assert " scatter(" not in table[0] and " copy(" not in table[0]
+    _segment_sum_is_the_kernel(compiled.as_text(), lookups,
+                               "ps.sparse.combine")
     mem = compiled.memory_analysis()
     state = rows * dim * 4 + rows * 4
     assert state <= mem.alias_size_in_bytes < state + (1 << 20)
-    assert mem.temp_size_in_bytes < 10**8
+    # One workspace of the batch's size in HBM (64 MiB: the gathered
+    # gradients in sorted order; their sums stay on the chip's own memory)
+    # and the ids.
+    batch = lookups * dim * 4
+    assert batch <= mem.temp_size_in_bytes < batch + (4 << 20)
 
 
 def test_sum_push_at_full_size_combines_and_writes_the_table_with_row_add(
@@ -319,11 +375,14 @@ def test_sum_push_at_full_size_combines_and_writes_the_table_with_row_add(
     assert "ps.sparse.push.scatter_add" in table[0]
     assert " scatter(" not in table[0] and " copy(" not in table[0]
     assert "ps.sparse.combine/sort" in text
+    _segment_sum_is_the_kernel(text, lookups, "ps.sparse.combine")
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == rows * dim * 4
     # The gathered gradients in sorted order and their segment sums (64 MiB
-    # each at this batch), and the ids.
-    assert 2 * lookups * dim * 4 <= mem.temp_size_in_bytes < 3 * lookups * dim * 4
+    # each at this batch: the kernel's result is the workspace the scatter
+    # had), and the ids.
+    batch = lookups * dim * 4
+    assert 2 * batch <= mem.temp_size_in_bytes < 2 * batch + (1 << 20)
 
 
 @pytest.mark.parametrize("handle", ["sum", "row_adagrad"])
@@ -379,7 +438,22 @@ def test_a_lane_packed_tables_pushes_at_full_size_write_it_with_row_add(
     assert "ps.sparse.push.scatter_add" in table[0]
     assert "ps.sparse.pack.place/sort" in text
     assert ("ps.sparse.combine/sort" in text) == (handle == "row_adagrad")
+    # The combine by physical row (128 lanes) is the kernel's, in the sum
+    # under the combine's scope; under the handle it is the merge before
+    # ``row_add``, and the first combine, by logical row of 64 lanes, keeps
+    # XLA's scatter-add.
+    _segment_sum_is_the_kernel(
+        text, lookups,
+        "ps.sparse.combine/ps.sparse.pack.place" if handle == "sum"
+        else "ps.sparse.push.scatter_add/cond/branch_0_fun/"
+             "ps.sparse.pack.place")
+    assert bool([l for l in text.splitlines() if " scatter(" in l
+                 and f"= f32[{lookups},{dim}]" in l]) == (
+                     handle == "row_adagrad")
     mem = compiled.memory_analysis()
     assert state <= mem.alias_size_in_bytes < state + (1 << 20)
+    # The batch's workspaces (27 MB each) fit the chip's own memory: none
+    # of the batch's size is left in HBM.
+    assert mem.temp_size_in_bytes < 1 << 20
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 16e9

@@ -272,6 +272,9 @@ def test_the_row_kernel_in_the_push_is_bit_equal_to_xlas_scatter(
     assert (np.asarray(eng._acc["emb"]) == np.asarray(twin._acc["emb"])).all()
     assert _gauges(kv)["engine.sparse.push.row_kernel"] == 3
     assert _gauges(kv)["engine.sparse.push.packed"] == 0
+    # The CPU is named for ``row_add`` alone: the duplicates were summed by
+    # XLA's scatter-add, in its order, which is why the bits are equal.
+    assert _gauges(kv)["engine.sparse.push.segsum_kernel"] == 0
     # A lane-packed table's push is written by the kernel too, by physical
     # row; a push with no handle whose program writes through the kernel is
     # counted like one under a handle.
@@ -284,6 +287,90 @@ def test_the_row_kernel_in_the_push_is_bit_equal_to_xlas_scatter(
     assert after["engine.sparse.push.stateful"] == 4
     assert after["engine.sparse.push.row_kernel"] == 5
     assert after["engine.sparse.push.packed"] == 1
+    assert after["engine.sparse.push.segsum_kernel"] == 0
+
+
+def _both_kernels_on_cpu(monkeypatch):
+    """:func:`_kernel_on_cpu`, and the CPU named among the platforms whose
+    programs sum a combine's segments with ``ops/segment_sum.py`` too: the
+    lowering rule of a TPU, interpreted.  Returns the two lists of shapes,
+    ``row_add``'s tables and ``segment_sum``'s batches."""
+    from pslite_tpu.ops import segment_sum as segment_sum_module
+    from pslite_tpu.parallel import sparse
+
+    summed = []
+    real = segment_sum_module.segment_sum
+    monkeypatch.setattr(
+        segment_sum_module, "segment_sum",
+        lambda seg, sg, **kw: summed.append(sg.shape) or real(seg, sg, **kw))
+    monkeypatch.setitem(sparse._SEGMENT_SUM_INTERPRET, "cpu", True)
+    return _kernel_on_cpu(monkeypatch), summed
+
+
+@pytest.mark.parametrize("handle", [None, HANDLE], ids=["sum", "row_adagrad"])
+@pytest.mark.parametrize("dim", [64, 128, 256])
+@pytest.mark.parametrize("cluster", [1, 4], indirect=True)
+def test_the_segment_sum_kernel_in_the_push_is_counted_and_matches(
+        cluster, dim, handle, monkeypatch):
+    """On the CPU mesh a push sums its duplicates with XLA's scatter-add and
+    ``engine.sparse.push.segsum_kernel`` stays 0; under a TPU's lowering
+    rule (the CPU named for both kernels, interpreted) the same three pushes
+    sum them with ``ops/segment_sum.py`` wherever the combined rows are 128
+    f32 lanes (an unpacked 128-wide table's; a lane-packed table's by
+    physical row, under ``row_adagrad`` its merge and not its first combine
+    by logical row), the counter equals the pushes, and the table is the
+    reference's and, to f32 rounding, the scatter path's.  A 256-wide table
+    keeps XLA's in both."""
+    kv, eng = cluster
+    W = eng.num_shards
+    idx, init, grads = _packed_traffic(W, dim)
+
+    def run(engine):
+        engine.register_sparse("emb", ROWS, dim, init=init)
+        for g in grads:
+            token = engine.push("emb", idx, g, handle)
+        token.block_until_ready()
+
+    twin = SparseEngine(eng.mesh, eng.axis)
+    run(twin)                               # the CPU's own: XLA's
+    assert (twin.segsum_kernel_pushes, twin.row_kernel_pushes) == (0, 0)
+    assert not twin._bound[("emb", handle, idx.shape[1])].segsum_kernel
+    written, summed = _both_kernels_on_cpu(monkeypatch)
+    eng.register_sparse("emb", ROWS, dim, init=init)
+    for g in grads:
+        ts = kv.push_sparse("emb", idx, g, handle)
+    kv.wait(ts)
+    table = eng.table("emb")
+    m = W * idx.shape[1]
+    served = dim != 256
+    assert set(summed) == ({(m, 128)} if served else set())
+    assert set(written) == ({(table.phys_rows, 128)} if served else set())
+    gauges = _gauges(kv)
+    assert gauges["engine.sparse.push.segsum_kernel"] == 3 * served
+    assert gauges["engine.sparse.push.row_kernel"] == 3 * served
+    assert eng._bound[("emb", handle, idx.shape[1])].segsum_kernel == served
+    got = np.asarray(eng.store_global_device("emb"))
+    scattered = np.asarray(twin.store_global_device("emb"))
+    if handle is None:
+        np.testing.assert_allclose(got, _sum_reference(init, idx, grads),
+                                   rtol=1e-5, atol=1e-5)
+    else:
+        ref = RowAdagrad(init)
+        for g in grads:
+            ref.push(idx, g)
+        assert _row_error(got, ref.table) < TOL
+        np.testing.assert_allclose(np.asarray(eng._acc["emb"]),
+                                   np.asarray(twin._acc["emb"]), rtol=1e-5)
+    np.testing.assert_allclose(got, scattered, rtol=1e-5, atol=1e-5)
+    quiet = np.setdiff1d(np.arange(ROWS), np.unique(idx))
+    assert (got[quiet] == init[quiet]).all() and np.isfinite(got).all()
+    # The segment sum named alone: the sum combines only where ``row_add``
+    # follows, a handle's combine by logical row takes 128-wide rows only.
+    from pslite_tpu.parallel import sparse
+
+    monkeypatch.delitem(sparse._ROW_ADD_INTERPRET, "cpu")
+    assert eng._segsum_kernel(table, True) == (dim == 128)
+    assert not eng._segsum_kernel(table, False)
 
 
 def _sum_reference(init, idx, grads):
