@@ -58,6 +58,8 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 LR, MOMENTUM = 0.01, 0.9
 SERVER_HANDLE = f"sgd_momentum:{LR},{MOMENTUM}"
 RING_HANDLE = f"sgd:{LR}"  # the ring kernel serves stateless handles
+LAMB = dict(lr=0.01, b1=0.9, b2=0.999, eps=1e-6, wd=0.01)
+LAMB_HANDLE = "lamb:" + ",".join(str(v) for v in LAMB.values())
 SEED = 20260926
 
 
@@ -145,6 +147,7 @@ class _Smoke:
             ("boot", 60, self.boot),
             ("resnet50", 400, self.resnet50),
             ("readme", 150, self.readme),
+            ("lamb", 150, self.lamb),
             ("sparse", 200, self.sparse),
             ("message_path", 30, self.message_path),
         ]
@@ -329,6 +332,64 @@ class _Smoke:
         print("  set-up: first push_pull (compiles) "
               f"{walls[0]:.2f} s; later "
               + ", ".join(f"{w:.2f} s" for w in walls[1:]))
+
+    # -- dense: keys of their own lengths under LAMB -------------------------
+
+    def lamb(self) -> None:
+        """Two steps of ``lamb`` on a bucket registered with ``lens``: keys
+        of 3 and 2 values, one on no lane border, one that crosses every
+        shard's border (so that on more than one chip the norms' ``psum``
+        crosses chips), one left out of decay and adaptation; against the
+        recurrence in float64."""
+        import jax.numpy as jnp
+
+        from pslite_tpu.ops.fused_update import LAMB_TILE
+        from pslite_tpu.parallel.engine import KEY_NO_ADAPT, KEY_NO_DECAY
+
+        kv, eng = self.kv, self.kv.engine
+        W = eng.num_workers
+        lens = np.array([3, 30522, LAMB_TILE * self.n_dev + 77, 1000, 2])
+        flags = np.array([0, 0, 0, KEY_NO_DECAY | KEY_NO_ADAPT, 0])
+        keys = np.arange(5000, 5000 + len(lens), dtype=np.uint64)
+        starts = np.concatenate([[0], np.cumsum(lens)])
+        total = int(lens.sum())
+        rng = np.random.default_rng(SEED)
+        init = (0.02 * rng.standard_normal(total)).astype(np.float32)
+        bucket = kv.register_dense("lamb_tree", keys, lens=lens, flags=flags,
+                                   init=init)
+        check(kv._engine_route(keys, 0, lens) == "lamb_tree",
+              "a call with the registered lens is the engine's")
+        p = init.astype(np.float64)
+        m, v = np.zeros(total), np.zeros(total)
+        lr, b1, b2, eps, wd = LAMB.values()
+        before = eng.lamb_updates
+        for t in (1, 2):
+            g = rng.standard_normal((W, total)).astype(np.float32)
+            # Host-origin, then device-origin: at the keys' own length.
+            sent = g if t == 1 else jnp.asarray(g)
+            pulled = np.asarray(
+                eng.push_pull("lamb_tree", sent, LAMB_HANDLE))
+            gs = g.astype(np.float64).sum(axis=0)
+            m = b1 * m + (1 - b1) * gs
+            v = b2 * v + (1 - b2) * gs * gs
+            ratios = []
+            for k in range(len(lens)):
+                sl = slice(starts[k], starts[k + 1])
+                decay = 0.0 if flags[k] & KEY_NO_DECAY else wd
+                u = ((m[sl] / (1 - b1 ** t))
+                     / (np.sqrt(v[sl] / (1 - b2 ** t)) + eps) + decay * p[sl])
+                pn, un = np.linalg.norm(p[sl]), np.linalg.norm(u)
+                r = (pn / un if not flags[k] & KEY_NO_ADAPT
+                     and pn > 0 and un > 0 else 1.0)
+                ratios.append(r)
+                p[sl] -= lr * r * u
+            np.testing.assert_allclose(pulled, p, atol=2e-6,
+                                       err_msg=f"lamb step {t}")
+        check(eng.lamb_updates - before == 2, "both ops ran under LAMB")
+        print(f"  {len(lens)} keys of {', '.join(f'{n:,}' for n in lens)} "
+              f"values ({bucket.padded_len:,} padded) over {self.n_dev} "
+              f"device(s): 2 steps under {LAMB_HANDLE} agree; trust ratios "
+              + ", ".join(f"{r:.3f}" for r in ratios))
 
     # -- sparse plane ---------------------------------------------------------
 

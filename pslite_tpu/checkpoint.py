@@ -16,6 +16,7 @@ from typing import Dict, Optional
 
 import numpy as np
 
+from .parallel.engine import STEP_SLOT_KINDS
 from .utils import logging as log
 
 
@@ -64,7 +65,7 @@ def save_engine_orbax(engine, path: str, sparse_engine=None) -> None:
             kind, states = opt
             slots = []
             for i, s in enumerate(states):
-                if kind == "adam" and i == 2:
+                if kind in STEP_SLOT_KINDS and i == 2:
                     # Per-shard step counter -> one entry (identical on
                     # every shard by construction).
                     slots.append(s.reshape(-1)[:1])
@@ -131,7 +132,7 @@ def _restore_orbax_v2(engine, path: str, sparse_engine, saved_md) -> None:
         opt_kinds[name] = kind
         tslots = []
         for i, m in enumerate(slots):
-            repl = kind == "adam" and i == 2  # the step scalar
+            repl = kind in STEP_SLOT_KINDS and i == 2  # the step scalar
             tslots.append(_sds(
                 tuple(m.shape),
                 getattr(m, "dtype", np.float32),
@@ -297,7 +298,7 @@ def save_engine(engine, path: str, sparse_engine=None) -> None:
             meta["opt"][name] = {"kind": kind, "n": len(states)}
             for i, s in enumerate(states):
                 host = np.asarray(s)
-                if kind == "adam" and i == 2:
+                if kind in STEP_SLOT_KINDS and i == 2:
                     # Per-shard step counter -> one scalar (identical on
                     # every shard by construction).
                     host = host.reshape(-1)[:1]

@@ -840,12 +840,19 @@ class KVWorker:
 
     # -- ICI collective fast path -------------------------------------------
 
-    def register_dense(self, name: str, keys, val_len: int, dtype=None,
-                       init=None):
+    def register_dense(self, name: str, keys, val_len: Optional[int] = None,
+                       dtype=None, init=None, lens=None, flags=None):
         """Register a dense bucket on the collective engine; subsequent
         push/pull on exactly these keys ride jitted ICI collectives.  The
         analog of the reference's first-touch rendezvous + registration
-        (rdma_van.h:520-548)."""
+        (rdma_van.h:520-548).
+
+        ``val_len`` values a key, or ``lens``: each key's own length, as
+        ``KVPairs.lens`` gives it on the message path.  A call on these
+        keys that carries no ``lens``, or the registered ones, is then the
+        engine's; ``flags`` (a word a key, ``parallel.engine.KEY_NO_DECAY``
+        / ``KEY_NO_ADAPT``) is read by a server handle that treats keys
+        apart (``lamb:...``)."""
         log.check(self.engine is not None,
                   "register_dense requires the ici van")
         keys = np.ascontiguousarray(np.asarray(keys, dtype=np.uint64))
@@ -858,8 +865,14 @@ class KVWorker:
             self._dense_routes.pop(
                 (len(old.keys), old.keys.item(0), old.keys.item(-1)), None)
         bucket = engine.register_dense(name, keys, val_len, dtype=dtype,
-                                       init=init)
+                                       init=init, lens=lens, flags=flags)
         self._dense_routes[(len(keys), keys.item(0), keys.item(-1))] = name
+        # From the buckets registered now: registering a small bucket in a
+        # large one's place lifts it again.
+        self._results_heavy = any(
+            engine.bucket(n).nbytes * self._MAX_DEVICE_RESULTS
+            > self._DEVICE_RESULTS_BYTES
+            for n in self._dense_routes.values())
         return bucket
 
     def reshard(self, mesh) -> None:
@@ -889,13 +902,13 @@ class KVWorker:
     def _engine_route(self, keys: np.ndarray, cmd: int = 0,
                       lens=None) -> Optional[str]:
         """Bucket name iff these exact keys are registered and the request
-        carries nothing the collective path cannot express (custom cmd,
-        variable lens fall back to the message path)."""
+        carries nothing the collective path cannot express (a custom cmd,
+        or ``lens`` on a bucket registered with one ``val_len``, fall back
+        to the message path).  ``lens`` on a bucket registered with its
+        keys' own lengths must be those lengths."""
         engine = self.engine
         n = len(keys)
-        if engine is None or n == 0:
-            return None
-        if cmd != 0 or lens is not None:
+        if engine is None or n == 0 or cmd != 0:
             return None
         name = self._dense_routes.get((n, keys.item(0), keys.item(-1)))
         if name is None:
@@ -904,9 +917,24 @@ class KVWorker:
         # set; a longer set can share it and differ in between.
         if n > 2 and not np.array_equal(engine.bucket(name).keys, keys):
             return None
+        if lens is not None:
+            have = engine.bucket(name).lens
+            if have is None:
+                return None
+            log.check(np.array_equal(have, np.asarray(lens).reshape(-1)),
+                      f"bucket {name!r} was registered with other lens than "
+                      f"this call carries: a registered key keeps its "
+                      f"length (register the bucket again to change it)")
         return name
 
+    # Device results kept for get_pulled(): the last 8.  While a dense
+    # bucket is registered of which 8 pulled copies would pass
+    # _DEVICE_RESULTS_BYTES (a whole tree in one bucket), of those 8 the
+    # newest that together stay within it (_trim_results): small results
+    # keep their window beside a tree, which keeps its last one.
     _MAX_DEVICE_RESULTS = 8
+    _DEVICE_RESULTS_BYTES = 2 << 30
+    _results_heavy = False
 
     def _engine_op(self, op, args, keys=None, cmd: int = 0, lens=None,
                    out=None, callback=None, keep_result: bool = False,
@@ -923,8 +951,9 @@ class KVWorker:
         ``select``, ``prep`` and ``launch`` itself, then ``dispatch``,
         the rest of this method.  While a profiler session runs the op
         lies in a ``ps.kv.op`` span, whose metadata also names the kind
-        of the server ``handle`` a call brought (``push_sparse``; the
-        engine is given it among ``args``).  Callers pass everything by
+        of the server handle: the one a call brought (``push_sparse``; the
+        engine is given it among ``args``), for a dense op the engine's
+        own (``adam``, ``lamb``).  Callers pass everything by
         position, and the dispatch is not a method of its own: on the
         chip's host a Python call costs this path 2-3 us, a keyword call
         half a microsecond more (PERF.md, PR 24).
@@ -976,6 +1005,8 @@ class KVWorker:
                 self._device_results[ts] = result
                 while len(self._device_results) > self._MAX_DEVICE_RESULTS:
                     self._device_results.pop(next(iter(self._device_results)))
+                if self._results_heavy:
+                    self._trim_results()
         if out is None and callback is None and not pinned:
             hook = partial(self._engine_ready, ts, name, [result],
                            threading.Lock())
@@ -996,13 +1027,30 @@ class KVWorker:
         t3 = stamp()
         self._note((KV_OP, t3, route_ns, t3 - t2, -1))
         if span is not None:
-            if handle is None:
-                span.set_metadata(ts=ts, name=name)
-            else:
+            if handle is None and keys is not None:
+                # A dense op runs under the engine's own handle.
+                handle = self.engine._server_handle
+            if isinstance(handle, str):
                 span.set_metadata(ts=ts, name=name,
                                   handle=handle.partition(":")[0])
+            else:
+                span.set_metadata(ts=ts, name=name)
             span.__exit__(None, None, None)
         return ts
+
+    def _trim_results(self) -> None:
+        """Let go of the oldest kept device results until the rest hold
+        at most ``_DEVICE_RESULTS_BYTES``; the newest stays whatever its
+        size.  Call with ``_mu`` held."""
+        kept = self._device_results
+        room = self._DEVICE_RESULTS_BYTES
+        stamps = list(kept)
+        for i in range(len(stamps) - 1, -1, -1):
+            room -= getattr(kept[stamps[i]], "nbytes", 0)
+            if room < 0 and i < len(stamps) - 1:
+                for old in stamps[:i + 1]:
+                    del kept[old]
+                return
 
     def _engine_ready(self, ts: int, name: str, box: list, lock) -> None:
         """Wait hook of an op with nothing to copy and no callback: the

@@ -5,7 +5,9 @@ The server-side update is the aggregation hot loop of the reference
 the update is HBM-bandwidth-bound; these kernels apply the whole optimizer
 step (SGD+momentum / Adagrad / Adam) in **one** tiled pass over the shard
 with in-place aliasing — guaranteeing the single-pass fusion rather than
-hoping XLA finds it.
+hoping XLA finds it.  LAMB alone takes two (``lamb_moments``,
+``lamb_apply``): its step of a tensor is scaled by the norms of the whole
+tensor, so nothing may be written before every element has been read.
 
 Layout: flat vectors are zero-padded and reshaped to ``(rows, 128)`` with
 ``rows`` a multiple of the dtype's sublane tile (8 for 4-byte, 16 for
@@ -26,9 +28,13 @@ Pallas interpreter); nothing here looks at the process default backend.
 from __future__ import annotations
 
 import functools
+import math
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 _LANES = 128
 _SUBLANES = 8
@@ -167,3 +173,212 @@ def adam_update(store, m, v, agg, step, *, interpret: bool,
 
     return _elementwise_call("adam_update", kernel, (store, m, v), agg,
                              interpret, scalars=scalars)
+
+
+# -- LAMB: two passes with the keys' norms between them ----------------------
+
+# Elements a grid step of the two LAMB kernels.  A bucket registered with
+# per-key lengths is kept in whole tiles a shard (``parallel/engine.py``
+# ``_padded_len``), so store and moments reshape to ``(rows, 128)`` in
+# place.  The gradient does not: see :func:`lamb_moments`.
+LAMB_TILE = _MAX_BLOCK_ROWS * _LANES
+
+
+def lamb_blocks(starts, padded_len: int, shards: int):
+    """``int32[shards, 2 * tiles]``: for each tile of each shard of a
+    bucket ``padded_len`` long, the first key that reaches into it and one
+    past the last (``starts`` as :func:`_block_keys` has them)."""
+    offs = np.arange(padded_len // LAMB_TILE, dtype=np.int64) * LAMB_TILE
+    return np.stack(
+        [np.searchsorted(starts[1:], offs, "right"),
+         np.searchsorted(starts[:-1], offs + LAMB_TILE, "left")],
+        axis=1).astype(np.int32).reshape(shards, -1)
+
+
+def _bias_corrections(step, beta1: float, beta2: float):
+    """``[1/(1-b1^t), 1/(1-b2^t)]``.  ``1 - b**t`` is ``-expm1(t*log(b))``:
+    ``b ** t`` in f32 loses the digits of ``1 - 0.999**t`` at small t."""
+    t = jnp.asarray(step, jnp.float32)
+    return jnp.stack([
+        -1.0 / jnp.expm1(t * math.log(beta1)),
+        -1.0 / jnp.expm1(t * math.log(beta2)),
+    ]).astype(jnp.float32)
+
+
+def _lamb_direction(scal_ref, m, v, eps: float):
+    """``mh / (sqrt(vh) + eps)``: the part of LAMB's ``u`` that knows no
+    key.  Both kernels compute it from the same m and v with the same
+    operations, so the ``u`` whose norm was taken is the ``u`` applied."""
+    return (m * scal_ref[0]) / (jnp.sqrt(v * scal_ref[1]) + eps)
+
+
+def _tile_iota():
+    """Each element's place in its tile, ``int32[rows, 128]``."""
+    shape = (_MAX_BLOCK_ROWS, _LANES)
+    return (lax.broadcasted_iota(jnp.int32, shape, 0) * _LANES
+            + lax.broadcasted_iota(jnp.int32, shape, 1))
+
+
+def _block_keys(starts_ref, blocks_ref, base_ref, body, init):
+    """Fold ``body(k, mask, carry)`` over the keys that own an element of
+    this grid step's tile; ``mask`` picks key k's elements in the tile.
+    ``starts`` are the keys' first elements in the whole bucket (one more
+    entry closes the last key), ``base`` this shard's first element there,
+    ``blocks[2*i]`` / ``[2*i+1]`` the first key of tile i and one past its
+    last.  A key's border lies on no tile's, so a tile is asked about
+    every key that reaches into it; padding belongs to none."""
+    from jax.experimental import pallas as pl
+
+    i = pl.program_id(0)
+    off = base_ref[0] + i * LAMB_TILE
+    idx = _tile_iota()
+
+    def step(k, carry):
+        lo = starts_ref[k] - off
+        hi = starts_ref[k + 1] - off
+        return body(k, (idx >= lo) & (idx < hi), carry)
+
+    return lax.fori_loop(blocks_ref[2 * i], blocks_ref[2 * i + 1], step,
+                         init)
+
+
+def _lamb_call(name: str, kernel, prefetch, tiles, n_out: int, sums: int,
+               interpret: bool, row=None):
+    """One LAMB pass over ``tiles`` (flat, whole tiles long): the first
+    ``n_out`` of them updated in place; with ``sums`` also an
+    ``f32[sums]`` vector the kernel adds to in SMEM from tile to tile.
+    ``row`` is one more input, ``[1, n]`` with ``n`` anywhere in the last
+    tile: the kernel is handed ``(1, LAMB_TILE)`` of it a grid step, and
+    behind its end whatever lies there."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n = tiles[0].shape[0]
+    assert n % LAMB_TILE == 0, (n, LAMB_TILE)
+    tiles = [t.reshape(-1, _LANES) for t in tiles]
+    spec = pl.BlockSpec((_MAX_BLOCK_ROWS, _LANES), lambda i, *_: (i, 0))
+    in_specs = [spec] * len(tiles)
+    if row is not None:
+        assert row.ndim == 2 and n - LAMB_TILE < row.shape[1] <= n, (
+            row.shape, n)
+        tiles.append(row)
+        in_specs.append(pl.BlockSpec((1, LAMB_TILE), lambda i, *_: (0, i)))
+    out_shape = [jax.ShapeDtypeStruct(t.shape, t.dtype)
+                 for t in tiles[:n_out]]
+    out_specs = [spec] * n_out
+    if sums:
+        out_shape.append(jax.ShapeDtypeStruct((sums,), jnp.float32))
+        out_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+    outs = pl.pallas_call(
+        kernel,
+        out_shape=tuple(out_shape),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(n // LAMB_TILE,),
+            in_specs=in_specs,
+            out_specs=tuple(out_specs),
+        ),
+        input_output_aliases={len(prefetch) + i: i for i in range(n_out)},
+        interpret=interpret,
+        name=name,
+    )(*prefetch, *tiles)
+    return tuple(o.reshape(-1) if o.ndim == 2 else o for o in outs)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("beta1", "beta2", "eps", "interpret"))
+def lamb_moments(store, m, v, agg, step, starts, decay, blocks, base, *,
+                 interpret: bool, beta1: float = 0.9, beta2: float = 0.999,
+                 eps: float = 1e-6):
+    """LAMB's first pass over one shard: the moments, in place, and what
+    the trust ratios are made of.
+
+    ``agg`` is the summed gradient as a row, ``[1, n]``, and may end
+    anywhere in the shard's last tile: a job's gradient has the length of
+    its keys, not of the kernel's tiles, and a pad in front of the kernel
+    is a copy of the whole gradient (5.2 ms of a 26 ms step at 336 M
+    values, PERF.md, PR 33).  The kernel takes ``(1, LAMB_TILE)`` of the
+    row a grid step, folds it to ``(rows, 128)`` in VMEM and reads zeros
+    behind the row's end.
+
+    ``m = b1*m + (1-b1)*g; v = b2*v + (1-b2)*g*g`` (computed as
+    ``m + (1-b1)*(g-m)``, which settles at g whatever f32 makes of the
+    betas).  With ``u = mh/(sqrt(vh)+eps) + decay[k]*p`` for key k's
+    elements it returns ``(new_m, new_v, sums)``: ``sums[2k]`` the sum of
+    ``p*p`` and ``sums[2k+1]`` of ``u*u`` over the elements of key k that
+    lie on this shard (see :func:`_block_keys` for ``starts``, ``blocks``,
+    ``base``).
+    ``u`` is not kept: ``lamb_apply`` makes it again from m and v, the
+    same bytes as writing and reading it and one vector less to hold.
+    """
+    scal = _bias_corrections(step, beta1, beta2)
+    n_sums = 2 * decay.shape[0]
+    ragged = agg.shape[1] % LAMB_TILE != 0
+
+    def kernel(scal_ref, base_ref, starts_ref, decay_ref, blocks_ref,
+               m_ref, v_ref, p_ref, g_ref, out_m_ref, out_v_ref, sums_ref):
+        from jax.experimental import pallas as pl
+
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            def zero(j, c):
+                sums_ref[j] = 0.0
+                return c
+
+            lax.fori_loop(0, n_sums, zero, 0)
+
+        g = g_ref[...].astype(jnp.float32).reshape(_MAX_BLOCK_ROWS, _LANES)
+        if ragged:
+            left = agg.shape[1] - pl.program_id(0) * LAMB_TILE
+            g = jnp.where(_tile_iota() < left, g, 0.0)
+        p = _f32(p_ref)
+        # b*x + (1-b)*y as x + (1-b)*(y - x): f32 holds 0.999 to 1.3e-8,
+        # which in the first form is 1.3e-5 of the 0.001 that v settles by.
+        m_old, v_old = _f32(m_ref), _f32(v_ref)
+        m_new = m_old + (1 - beta1) * (g - m_old)
+        v_new = v_old + (1 - beta2) * (g * g - v_old)
+        _store(out_m_ref, m_new)
+        _store(out_v_ref, v_new)
+        d = _lamb_direction(scal_ref, m_new, v_new, eps)
+        pp = p * p
+
+        def add(k, mask, c):
+            u = d + decay_ref[k] * p
+            sums_ref[2 * k] += jnp.sum(jnp.where(mask, pp, 0.0))
+            sums_ref[2 * k + 1] += jnp.sum(jnp.where(mask, u * u, 0.0))
+            return c
+
+        _block_keys(starts_ref, blocks_ref, base_ref, add, 0)
+
+    return _lamb_call("lamb_moments", kernel,
+                      (scal, base, starts, decay, blocks),
+                      (m, v, store), 2, n_sums, interpret, row=agg)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("beta1", "beta2", "eps", "interpret"))
+def lamb_apply(store, m, v, step, starts, decay, scale, blocks, base, *,
+               interpret: bool, beta1: float = 0.9, beta2: float = 0.999,
+               eps: float = 1e-6):
+    """LAMB's second pass: ``p -= scale[k] * u`` for key k's elements,
+    ``scale[k] = lr * r_k`` and ``u`` as in :func:`lamb_moments` from the
+    new m and v; the store in place.  Padding keeps its value."""
+    scal = _bias_corrections(step, beta1, beta2)
+
+    def kernel(scal_ref, base_ref, starts_ref, decay_ref, scale_ref,
+               blocks_ref, p_ref, m_ref, v_ref, out_p_ref):
+        p = _f32(p_ref)
+        d = _lamb_direction(scal_ref, _f32(m_ref), _f32(v_ref), eps)
+
+        def pick(k, mask, upd):
+            return jnp.where(mask, scale_ref[k] * (d + decay_ref[k] * p),
+                             upd)
+
+        upd = _block_keys(starts_ref, blocks_ref, base_ref, pick,
+                          jnp.zeros_like(p))
+        _store(out_p_ref, p - upd)
+
+    (new_store,) = _lamb_call(
+        "lamb_apply", kernel, (scal, base, starts, decay, scale, blocks),
+        (store, m, v), 1, 0, interpret)
+    return new_store

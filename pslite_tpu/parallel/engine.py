@@ -47,20 +47,70 @@ class DenseBucket:
 
     Mirrors the reference benchmark's layout of ``NUM_KEY_PER_SERVER`` keys
     of ``len`` bytes each (test_benchmark.cc:407-414): ``keys[i]`` owns
-    ``val_len`` consecutive values in the flat bucket vector.
+    ``val_len`` consecutive values in the flat bucket vector.  A bucket
+    registered with ``lens`` (the reference's ``KVPairs.lens``) keeps each
+    key's own length instead, ``keys[i]`` owning ``lens[i]`` values from
+    ``starts[i]``, and a flag word a key (``KEY_NO_DECAY``,
+    ``KEY_NO_ADAPT``) for a handle that treats keys apart (``lamb``).
     """
 
     name: str
     keys: np.ndarray
-    val_len: int
+    val_len: int  # 0 where the keys have lengths of their own
     dtype: object
-    total_len: int  # len(keys) * val_len
-    padded_len: int  # rounded up to a multiple of the mesh axis size
+    total_len: int  # len(keys) * val_len, or sum(lens)
+    padded_len: int  # see _padded_len
+    lens: Optional[np.ndarray] = None  # int64 a key
+    flags: Optional[np.ndarray] = None  # int32 a key, with ``lens``
     # Application bytes one push (or one pull) moves: the byte counters' unit.
     nbytes: int = field(init=False)
+    # Where each key begins in the flat vector (one more entry closes the
+    # last), and what tells these segments from any other bucket's.
+    starts: Optional[np.ndarray] = field(init=False, default=None)
+    segments_key: Optional[bytes] = field(init=False, default=None)
 
     def __post_init__(self):
         self.nbytes = self.total_len * np.dtype(self.dtype).itemsize
+        if self.lens is not None:
+            self.starts = np.concatenate(
+                [[0], np.cumsum(self.lens)]).astype(np.int32)
+            self.segments_key = (self.starts.tobytes()
+                                 + self.flags.tobytes())
+
+
+# Flags of a key in a bucket registered with ``lens``: LAMB leaves the key
+# out of the weight decay / gives it trust ratio 1.
+KEY_NO_DECAY = 1
+KEY_NO_ADAPT = 2
+
+# Optimizer state kinds whose last slot is the per-shard step counter.
+STEP_SLOT_KINDS = ("adam", "lamb")
+
+
+def _lamb_ratios(sq, adapt):
+    """LAMB's trust ratio a key from the keys' summed squares ``[K, 2]``
+    (of p, of u): ``|p|/|u|``, and 1 for a key that is not adapted or has
+    a zero norm."""
+    import jax.numpy as jnp
+
+    p_norm, u_norm = jnp.sqrt(sq[:, 0]), jnp.sqrt(sq[:, 1])
+    return jnp.where(adapt & (p_norm > 0) & (u_norm > 0), p_norm / u_norm,
+                     1.0)
+
+
+def _padded_len(total: int, shards: int, segmented: bool) -> int:
+    """A bucket's length on the devices: ``total`` rounded up to a whole
+    number of elements a shard, and for a bucket with ``lens`` to whole
+    tiles of the LAMB kernels a shard, so that a shard is tiled where it
+    lies and no pass copies it to pad it.  The one place that knows it
+    (registration and ``reshard`` ask here); no caller needs the answer:
+    see :meth:`CollectiveEngine._stateful_program`."""
+    unit = shards
+    if segmented:
+        from ..ops.fused_update import LAMB_TILE
+
+        unit = shards * LAMB_TILE
+    return -(-total // unit) * unit
 
 
 ServerHandle = Union[str, Callable]
@@ -77,11 +127,15 @@ class _BoundOp:
     bucket: DenseBucket
     lock: threading.Lock  # the bucket's write lock
     prog: Callable
-    prep: Callable  # one of _prep_grads / _prep_grads_flat / _prep_grads_ring
+    # One of _prep_grads / _prep_grads_whole / _prep_grads_flat /
+    # _prep_grads_ring.
+    prep: Callable
     sharding: object  # what ``prep`` delivers (and passes through as is)
     state_kind: Optional[str]  # the optimizer state's kind; None: stateless
     zc: bool  # in-place pull delivery
-    cut: bool  # the pulled array carries padding to slice off
+    # The pulled array carries padding to slice off (a program shared by
+    # every bucket of a length; a bucket's own cuts inside: _bind).
+    cut: bool
 
 
 def _pad_ring_chunks(g, s, kchunk: int, chunk0: int):
@@ -121,6 +175,48 @@ def _aggregate(grads_l, axis, worker_axis=None):
                 grads_l[0], axis, scatter_dimension=0, tiled=True
             )
         return lax.psum(grads_l[0], worker_axis)
+
+
+def _aggregate_whole(rows_l, shard_len: int, shards: int, axis,
+                     worker_axis=None):
+    """The worker reduction in a bucket's own program
+    (:meth:`CollectiveEngine._stateful_program`): ``rows_l`` is the local
+    ``[1, total]`` block of the gradient as the job has it.  Returns this
+    shard's part as a row: ``[1, shard_len]`` of the gradient padded with
+    zeros where the bucket lies over several shards, and on one shard the
+    row as it came, ``[1, total]``, since nothing has to be cut and a pad
+    is a copy of the whole gradient."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    with jax.named_scope("ps.push.reduce"):
+        if shards > 1:
+            rows_l = jnp.pad(
+                rows_l,
+                ((0, 0), (0, shards * shard_len - rows_l.shape[1])))
+        if worker_axis is None:
+            return lax.psum_scatter(rows_l, axis, scatter_dimension=1,
+                                    tiled=True)
+        if shards > 1:
+            # Every kv position of a worker holds the worker's whole row.
+            rows_l = lax.dynamic_slice_in_dim(
+                rows_l, lax.axis_index(axis) * shard_len, shard_len, axis=1)
+        return lax.psum(rows_l, worker_axis)
+
+
+def _zero_filled(sfn):
+    """An element-wise stateful handle in a bucket's own program: it takes
+    the shard whole, so the row of :func:`_aggregate_whole` is filled with
+    zeros to the shard's length (a zero gradient leaves the padding's p, m
+    and v as they are)."""
+    import jax.numpy as jnp
+
+    def fn(store_l, state_l, row):
+        pad = store_l.shape[0] - row.shape[1]
+        return sfn(store_l, state_l, jnp.pad(row, ((0, 0), (0, pad)))[0])
+
+    return fn
 
 
 def _update(handle, *args):
@@ -303,6 +399,8 @@ class CollectiveEngine:
         self.push_bytes = 0
         self.pull_bytes = 0
         self._counter_mu = threading.Lock()
+        # Ops whose program applied LAMB (``engine.update.lamb``).
+        self.lamb_updates = 0
 
     # -- registration --------------------------------------------------------
 
@@ -310,15 +408,21 @@ class CollectiveEngine:
         self,
         name: str,
         keys,
-        val_len: int,
+        val_len: Optional[int] = None,
         dtype=None,
         init: Optional[np.ndarray] = None,
+        lens=None,
+        flags=None,
     ) -> DenseBucket:
         """Register a dense bucket and allocate its sharded store.
 
         This is the moment the reference performs rendezvous + memory
         registration (rdma_van.h:520-548); here it allocates the sharded
         HBM store and (lazily) compiles the bucket's programs.
+
+        ``val_len`` values a key, or ``lens``: a length for each key (the
+        reference's ``KVPairs.lens``), and with them ``flags``, a word a
+        key of ``KEY_NO_DECAY`` / ``KEY_NO_ADAPT`` (default 0).
         """
         import jax
         import jax.numpy as jnp
@@ -327,8 +431,34 @@ class CollectiveEngine:
         if dtype is None:
             dtype = jnp.float32
         keys = np.ascontiguousarray(np.asarray(keys, dtype=np.uint64))
-        total = len(keys) * val_len
-        padded = -(-total // self.num_shards) * self.num_shards
+        log.check((val_len is None) != (lens is None),
+                  f"bucket {name!r}: give val_len (one length for every "
+                  f"key) or lens (a length a key), not both or neither")
+        if lens is None:
+            log.check(flags is None,
+                      f"bucket {name!r}: per-key flags need per-key lens")
+            total = len(keys) * val_len
+        else:
+            lens = np.ascontiguousarray(np.asarray(lens, dtype=np.int64))
+            log.check(lens.shape == keys.shape and (lens >= 0).all(),
+                      f"bucket {name!r}: lens must give one length >= 0 "
+                      f"for each of the {len(keys)} keys, got shape "
+                      f"{lens.shape}")
+            flags = np.ascontiguousarray(np.asarray(
+                np.zeros(len(keys)) if flags is None else flags,
+                dtype=np.int32))
+            log.check(flags.shape == keys.shape,
+                      f"bucket {name!r}: flags must give one word for each "
+                      f"of the {len(keys)} keys, got shape {flags.shape}")
+            val_len, total = 0, int(lens.sum())
+            log.check(total < 2 ** 31,
+                      f"bucket {name!r}: {total:,} values; the keys' "
+                      f"borders are kept as int32")
+        if init is not None:
+            log.check_eq(int(np.size(init)), total,
+                         f"bucket {name!r}: init must hold one value for "
+                         f"each of the keys' values")
+        padded = _padded_len(total, self.num_shards, lens is not None)
         bucket = DenseBucket(
             name=name,
             keys=keys,
@@ -336,6 +466,8 @@ class CollectiveEngine:
             dtype=dtype,
             total_len=total,
             padded_len=padded,
+            lens=lens,
+            flags=flags,
         )
         sharding = NamedSharding(self.mesh, P(self.axis))
         if init is not None:
@@ -401,17 +533,23 @@ class CollectiveEngine:
                 vals[i] = float(tok)
         return vals
 
-    def _stateful_handle(self, handle: str):
+    def _stateful_handle(self, handle: str,
+                         bucket: Optional[DenseBucket] = None):
         """(n_state, fn) for the fused-kernel server handles.
 
         ``fn(store_l, state_l, agg) -> (new_store_l, new_state_l)`` runs
         per shard inside shard_map, applying the whole optimizer step as
         one Pallas pass over the shard (the aggregation hot loop of
         kv_app.h:430-452 fused with the reduce-scatter's output).
+        ``lamb`` is told the ``bucket`` whose keys' borders it needs.
         """
         from ..ops import fused_update
 
         interp = self._interpret
+        if self._needs_segments(handle):
+            log.check(bucket is not None and bucket.lens is not None,
+                      self._segments_refusal(handle, bucket))
+            return 3, self._lamb_fn(handle, bucket)
         if handle.startswith("sgd_momentum"):
             lr, momentum = self._handle_params(handle, (0.01, 0.9))
 
@@ -451,13 +589,85 @@ class CollectiveEngine:
             return 1, fn
         raise ValueError(f"not a stateful handle: {handle!r}")
 
+    def _lamb_fn(self, handle: str, bucket: DenseBucket) -> Callable:
+        """``lamb:lr,b1,b2,eps,wd`` on one shard of ``bucket`` (You et
+        al. 2020, with Adam's bias correction), for key k::
+
+            m = b1*m + (1-b1)*g;  v = b2*v + (1-b2)*g*g
+            u = mh/(sqrt(vh)+eps) + wd_k*p     (wd_k 0 for KEY_NO_DECAY)
+            r_k = |p|/|u|, or 1 for KEY_NO_ADAPT or where a norm is 0
+            p = p - lr*r_k*u
+
+        Two passes over the shard with a reduction between them: no
+        element of a key may be written before every element's ``u`` is
+        known, on every shard.  The norms are over the key's own elements
+        wherever they lie (shard and tile borders are not the keys') and
+        never over the padding.  ``agg`` is a row, as
+        :func:`_aggregate_whole` leaves it."""
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        from ..ops import fused_update
+
+        lr, b1, b2, eps, wd = self._handle_params(
+            handle, (1e-3, 0.9, 0.999, 1e-6, 0.01))
+        interp, axis, S = self._interpret, self.axis, self.num_shards
+        starts = bucket.starts
+        decay = np.where(bucket.flags & KEY_NO_DECAY, 0.0, wd).astype(
+            np.float32)
+        adapt = (bucket.flags & KEY_NO_ADAPT) == 0
+        n_keys = len(bucket.keys)
+        blocks = fused_update.lamb_blocks(starts, bucket.padded_len, S)
+        kw = dict(beta1=b1, beta2=b2, eps=eps, interpret=interp)
+
+        def fn(store_l, state_l, agg):
+            m_l, v_l, step_l = state_l
+            step = step_l[0] + 1.0
+            shard = lax.axis_index(axis)
+            base = (shard * store_l.shape[0]).astype(jnp.int32).reshape(1)
+            mine = lax.dynamic_index_in_dim(jnp.asarray(blocks), shard,
+                                            keepdims=False)
+            with jax.named_scope("ps.update.lamb.moments"):
+                new_m, new_v, sums = fused_update.lamb_moments(
+                    store_l, m_l, v_l, agg, step, starts, decay, mine,
+                    base, **kw)
+            with jax.named_scope("ps.update.lamb.norms"):
+                sq = lax.psum(sums.reshape(n_keys, 2), axis)
+                scale = (lr * _lamb_ratios(sq, adapt)).astype(jnp.float32)
+            with jax.named_scope("ps.update.lamb.apply"):
+                new_store = fused_update.lamb_apply(
+                    store_l, new_m, new_v, step, starts, decay, scale,
+                    mine, base, **kw)
+            return new_store, (new_m, new_v, step_l + 1.0)
+
+        return fn
+
     @staticmethod
     def _is_stateful(handle) -> bool:
         return isinstance(handle, str) and (
             handle.startswith("sgd_momentum")
             or handle.startswith("adam")
             or handle.startswith("adagrad")
+            or handle.startswith("lamb")
         )
+
+    @staticmethod
+    def _needs_segments(handle) -> bool:
+        """Whether the handle's update of an element depends on which key
+        the element belongs to."""
+        return isinstance(handle, str) and handle.startswith("lamb")
+
+    @staticmethod
+    def _segments_refusal(handle, bucket: Optional[DenseBucket]) -> str:
+        where = ("a call that carries no bucket (replay and the streams "
+                 "compile one program for any bucket of a length)"
+                 if bucket is None else
+                 f"bucket {bucket.name!r}, registered with one val_len for "
+                 f"all its keys")
+        return (f"handle {handle!r} takes a norm over each key and needs "
+                f"the keys' own lengths, which {where} does not have: "
+                f"register the bucket with lens= and use push_pull or push")
 
     @property
     def handle_is_stateful(self) -> bool:
@@ -475,11 +685,16 @@ class CollectiveEngine:
         self._clock.program_built()
         return prog
 
-    def _program(self, op: str, padded_len: int, dtype, handle_key) -> Callable:
+    def _program(self, op: str, padded_len: int, dtype, handle_key,
+                 bucket: Optional[DenseBucket] = None) -> Callable:
         """Jitted SPMD program for (op, shape, dtype, handle) — the
         executable-cache analog of the reference's per-(key,push,recver)
-        rendezvous cache."""
+        rendezvous cache.  Under a handle that treats keys apart
+        (``bucket`` given: see :meth:`_bind`) also for the keys' segments
+        and flags, which such a program holds."""
         key = (op, padded_len, str(dtype), handle_key)
+        if bucket is not None:
+            key += (bucket.segments_key,)
         with self._mu:
             prog = self._programs.get(key)
         if prog is not None:
@@ -492,7 +707,7 @@ class CollectiveEngine:
         axis = self.axis
         mesh = self.mesh
         if op in ("push_st", "push_pull_st", "push_pull_st_zc"):
-            return self._stateful_program(op, key, handle_key)
+            return self._stateful_program(op, key, handle_key, bucket)
         if op in ("pull", "pull_pinned"):
             handle = None  # pull is read-only; no server update to fuse
         else:
@@ -832,41 +1047,69 @@ class CollectiveEngine:
 
         return _updated_shard
 
-    def _stateful_program(self, op: str, key, handle_key: str) -> Callable:
+    def _stateful_program(self, op: str, key, handle_key: str,
+                          bucket: Optional[DenseBucket] = None) -> Callable:
         """Program for the fused-kernel handles: the Pallas optimizer pass
         runs between the reduce-scatter and the all-gather, with store AND
         optimizer state donated (one HBM pass per step, no double
         buffering).  On a 2-D mesh the worker reduction is the psum over
         ``worker_axis`` and state lives sharded over kv / replicated over
-        dp, exactly like the store."""
+        dp, exactly like the store.
+
+        With ``bucket`` the program is that bucket's own (:meth:`_bind`:
+        one that keeps its keys' lengths).  It takes the gradient as the
+        job has it, ``[W, total_len]`` (a worker's row whole on each of
+        the worker's devices: :meth:`_prep_grads_whole`), and returns the
+        pulled values at ``total_len``: what lies behind the last key is
+        the program's business and no caller's.  Before or after the
+        program, a pad or a cut is a launch and a copy of its own, of a
+        whole tree where the bucket is one."""
         import jax
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        n_state, sfn = self._stateful_handle(handle_key)
+        n_state, sfn = self._stateful_handle(handle_key, bucket)
         axis = self.axis
         waxis = self.worker_axis
         store_spec = P(axis)
-        grads_spec = P(axis, None) if waxis is None else P(waxis, axis)
         repl_spec = P(None)
+        if bucket is None:
+            grads_spec = P(axis, None) if waxis is None else P(waxis, axis)
+
+            def aggregate(grads_l):
+                return _aggregate(grads_l, axis, waxis)
+
+            def cut(pulled):
+                return pulled
+        else:
+            grads_spec = self._grads_sharding(False, True).spec
+            shards = self.num_shards
+            shard_len, total = bucket.padded_len // shards, bucket.total_len
+            if not self._needs_segments(handle_key):
+                sfn = _zero_filled(sfn)
+
+            def aggregate(grads_l):
+                return _aggregate_whole(grads_l, shard_len, shards, axis,
+                                        waxis)
+
+            def cut(pulled):
+                return pulled[:total]
+
+        def _updated(store_l, rest):
+            state_l, grads_l = rest[:-1], rest[-1]
+            return _update(sfn, store_l, tuple(state_l), aggregate(grads_l))
 
         def _push(store_l, *rest):
-            state_l, grads_l = rest[:-1], rest[-1]
-            agg = _aggregate(grads_l, axis, waxis)
-            new_store, new_state = _update(sfn, store_l, tuple(state_l), agg)
+            new_store, new_state = _updated(store_l, rest)
             return (new_store, *new_state, new_store[:1])  # token last
 
         def _push_pull(store_l, *rest):
-            state_l, grads_l = rest[:-1], rest[-1]
-            agg = _aggregate(grads_l, axis, waxis)
-            new_store, new_state = _update(sfn, store_l, tuple(state_l), agg)
-            return (new_store, *new_state, _gather(new_store, axis))
+            new_store, new_state = _updated(store_l, rest)
+            return (new_store, *new_state, cut(_gather(new_store, axis)))
 
         def _push_pull_zc(store_l, *rest):
             # In-place pull delivery: see _program's _push_pull_zc.
-            state_l, grads_l = rest[:-1], rest[-1]
-            agg = _aggregate(grads_l, axis, waxis)
-            new_store, new_state = _update(sfn, store_l, tuple(state_l), agg)
+            new_store, new_state = _updated(store_l, rest)
             return (new_store, *new_state)
 
         if op == "push_st":
@@ -902,7 +1145,7 @@ class CollectiveEngine:
         dt = np.dtype(bucket.dtype)
         if kind in ("sgd_momentum", "adagrad"):
             state = (self._place(np.zeros(bucket.padded_len, dt), sharding),)
-        else:  # adam
+        else:  # adam, lamb: m, v, the step
             state = (
                 self._place(np.zeros(bucket.padded_len, dt), sharding),
                 self._place(np.zeros(bucket.padded_len, dt), sharding),
@@ -943,7 +1186,8 @@ class CollectiveEngine:
         norm = []
         placed_device = {}
         for i, v in enumerate(values):
-            if isinstance(v, jax.Array) and not (kind == "adam" and i == 2):
+            if isinstance(v, jax.Array) and not (
+                    kind in STEP_SLOT_KINDS and i == 2):
                 # Fleet-portable DEVICE restore (orbax v2): logical
                 # vectors pad+reshard on device, no host fetch.
                 import jax.numpy as jnp
@@ -973,7 +1217,7 @@ class CollectiveEngine:
                 norm.append(None)
                 continue
             arr = np.ascontiguousarray(np.asarray(v))
-            if kind == "adam" and i == 2:
+            if kind in STEP_SLOT_KINDS and i == 2:
                 step = float(arr.reshape(-1)[0]) if arr.size else 0.0
                 arr = np.full(self.num_shards, step, np.float32)
             else:
@@ -1017,13 +1261,15 @@ class CollectiveEngine:
 
     def _normalize_host_grads(self, grads, rows, bucket, xp,
                               steps: bool = False,
-                              row_msg: str = "bad worker dim"):
+                              row_msg: str = "bad worker dim",
+                              width: Optional[int] = None):
         """Coerce a grads array to ``[(T,)? rows, padded]``: dtype cast,
         broadcast a missing row dim to ``rows``, validate the row count,
-        pad the value tail.  The one definition behind every host/device
-        staging path (1-D/2-D x single/multi-process x single/replay);
-        ``xp`` is np (host staging) or jnp (device staging) — see
-        :func:`placement.staging_xp`."""
+        pad the value tail (to ``width``, where a program takes another
+        than the bucket's ``padded_len``).  The one definition behind
+        every host/device staging path (1-D/2-D x single/multi-process x
+        single/replay); ``xp`` is np (host staging) or jnp (device
+        staging) — see :func:`placement.staging_xp`."""
         arr = xp.asarray(grads, dtype=np.dtype(bucket.dtype))
         want = 3 if steps else 2
         log.check(arr.ndim in (want - 1, want), "bad grads rank")
@@ -1035,24 +1281,29 @@ class CollectiveEngine:
             else:
                 arr = xp.broadcast_to(arr, (rows, arr.shape[0]))
         log.check_eq(int(arr.shape[-2]), rows, row_msg)
-        if arr.shape[-1] != bucket.padded_len:
+        if width is None:
+            width = bucket.padded_len
+        if arr.shape[-1] != width:
             log.check_eq(int(arr.shape[-1]), bucket.total_len,
                          "bad grad len")
-            pad = bucket.padded_len - bucket.total_len
+            pad = width - bucket.total_len
             pads = [(0, 0)] * (arr.ndim - 1) + [(0, pad)]
             arr = xp.pad(arr, pads)
         return arr
 
-    def _grads_sharding(self, flat: bool):
+    def _grads_sharding(self, flat: bool, whole: bool = False):
         """What a prep delivers: ``[W, padded]`` rows over the worker
-        axis (``_prep_grads``), or the FLAT forms' ``P(axis)``.  A bound
-        op hands its prep the one its record holds."""
+        axis (``_prep_grads``), the FLAT forms' ``P(axis)``, or ``whole``
+        rows ``[W, total]`` (``_prep_grads_whole``: no row is cut over
+        the kv axis, which ``total`` need not divide by).  A bound op
+        hands its prep the one its record holds."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         if flat:
             return NamedSharding(self.mesh, P(self.axis))
         if self.worker_axis is not None:
-            return NamedSharding(self.mesh, P(self.worker_axis, self.axis))
+            return NamedSharding(self.mesh, P(
+                self.worker_axis, None if whole else self.axis))
         return NamedSharding(self.mesh, P(self.axis, None))
 
     def _prep_grads_flat(self, bucket: DenseBucket, grads, sharding=None):
@@ -1133,9 +1384,21 @@ class CollectiveEngine:
             np.ascontiguousarray(arr).reshape(-1), sharding
         )
 
-    def _prep_grads(self, bucket: DenseBucket, grads, sharding=None):
+    def _prep_grads_whole(self, bucket: DenseBucket, grads, sharding=None):
+        """``[W, total]`` for a program that is the bucket's own
+        (:meth:`_stateful_program`): the gradient as the job has it.  A
+        device array of that shape under ``sharding`` passes as it is,
+        and nothing is padded here or anywhere before the program."""
+        if sharding is None:
+            sharding = self._grads_sharding(False, True)
+        return self._prep_grads(bucket, grads, sharding, bucket.total_len)
+
+    def _prep_grads(self, bucket: DenseBucket, grads, sharding=None,
+                    width: Optional[int] = None):
         """Accept [W, total] (or [total] broadcast) host/device arrays and
-        deliver a [W, padded] device array sharded over the worker axis.
+        deliver a [W, padded] device array sharded over the worker axis
+        (``width``: another length than ``padded``, see
+        :meth:`_prep_grads_whole`).
 
         Multi-process host-array contracts differ by layout:
         - 1-D mesh: the host array is this PROCESS's contribution —
@@ -1152,9 +1415,11 @@ class CollectiveEngine:
 
         if sharding is None:
             sharding = self._grads_sharding(False)
+        if width is None:
+            width = bucket.padded_len
         if isinstance(grads, jax.Array) and grads.ndim == 2:
             shape = grads.shape
-            if shape[1] == bucket.padded_len:
+            if shape[1] == width:
                 # Row count must match the worker fan-in exactly — a
                 # silent reshard would drop rows (the shard body reads
                 # one local row per device position).
@@ -1168,11 +1433,12 @@ class CollectiveEngine:
         if self.worker_axis is not None:
             if self._is_multiprocess():
                 arr = self._normalize_host_grads(
-                    grads, self.num_workers, bucket, np
+                    grads, self.num_workers, bucket, np, width=width
                 )
                 return self._place(np.ascontiguousarray(arr), sharding)
             arr = self._normalize_host_grads(
-                grads, self.num_workers, bucket, staging_xp(grads)
+                grads, self.num_workers, bucket, staging_xp(grads),
+                width=width
             )
             return jax.device_put(arr, sharding)
         if self._is_multiprocess():
@@ -1180,13 +1446,14 @@ class CollectiveEngine:
                 grads, self._local_shards(), bucket, np,
                 row_msg="bad local worker dim (rows = this process's "
                         "devices on a multi-process mesh)",
+                width=width,
             )
             return jax.make_array_from_process_local_data(
                 sharding, np.ascontiguousarray(arr),
-                (self.num_shards, bucket.padded_len),
+                (self.num_shards, width),
             )
         arr = self._normalize_host_grads(
-            grads, self.num_shards, bucket, staging_xp(grads)
+            grads, self.num_shards, bucket, staging_xp(grads), width=width
         )
         return jax.device_put(arr, sharding)
 
@@ -1230,17 +1497,31 @@ class CollectiveEngine:
         mesh, bucket = self.mesh, self._buckets[name]
         resolved, handle_key = self._resolve_handle(handle)
         push = zero_copy is None
-        zc = bool(zero_copy) and self._zc_pull_eligible(bucket.dtype,
-                                                        resolved)
+        # (A bucket with lens is kept in whole tiles on one shard too: its
+        # store is not its pulled value.)
+        zc = (bool(zero_copy)
+              and self._zc_pull_eligible(bucket.dtype, resolved)
+              and bucket.padded_len == bucket.total_len)
         # Resolved for every record: it says once why "pallas" runs XLA.
         impl = self._effective_impl(bucket.dtype, resolved)
         stateful = self._is_stateful(resolved)
+        # A stateful program of a bucket that keeps its keys' lengths is
+        # the bucket's own: it takes the gradient and gives the pulled
+        # values at total_len (_stateful_program), and a handle that treats
+        # keys apart finds their borders in it (one that needs them on a
+        # bucket without is refused in _stateful_handle).
+        own = stateful and (bucket.lens is not None
+                            or self._needs_segments(resolved))
         prep = self._prep_grads
         if stateful:
             op = ("push_st" if push
                   else "push_pull_st_zc" if zc else "push_pull_st")
             prog = self._program(op, bucket.padded_len, bucket.dtype,
-                                 handle_key)
+                                 handle_key, bucket if own else None)
+            if own:
+                prep = self._prep_grads_whole
+            if self._needs_segments(resolved):
+                prog = self._counted_lamb(prog)
         elif impl == "pallas":
             if self.worker_axis is None:
                 prep = self._prep_grads_ring
@@ -1259,9 +1540,10 @@ class CollectiveEngine:
         bound = _BoundOp(
             op="push" if push else "push_pull", bucket=bucket,
             lock=self._bucket_mu[name], prog=prog, prep=prep,
-            sharding=self._grads_sharding(prep != self._prep_grads),
+            sharding=self._grads_sharding(
+                prep not in (self._prep_grads, self._prep_grads_whole), own),
             state_kind=resolved.split(":", 1)[0] if stateful else None,
-            zc=zc, cut=not (push or zc
+            zc=zc, cut=not (push or zc or own
                             or bucket.padded_len == bucket.total_len),
         )
         with self._mu:
@@ -1270,6 +1552,25 @@ class CollectiveEngine:
                 self._bound[(name, handle, zero_copy)] = bound
         self._clock.op_bound()
         return bound
+
+    def _counted_lamb(self, prog: Callable) -> Callable:
+        """``prog`` behind the count of ``engine.update.lamb``: what a
+        record of :meth:`_bind` knows is counted by the record's own
+        program, and no other op pays for it."""
+        def counted(*args):
+            self.lamb_updates += 1
+            return prog(*args)
+
+        return counted
+
+    def export(self, registry) -> None:
+        """Lazily sampled gauges in a node's ``Registry``, beside the
+        stage clock's (``docs/observability.md``, "Engine path")."""
+        registry.gauge("engine.update.lamb", fn=lambda: self.lamb_updates)
+        registry.gauge(
+            "engine.dense.segments",
+            fn=lambda: sum(len(b.keys) for b in list(self._buckets.values())
+                           if b.lens is not None))
 
     def push_pull(self, name: str, grads, handle: Optional[ServerHandle] = None,
                   zero_copy: Optional[bool] = False):
@@ -2327,9 +2628,8 @@ class CollectiveEngine:
             staged = {}
             for n in names:
                 b, store, opt = snap[n]
-                padded = (
-                    -(-b.total_len // new_num_shards) * new_num_shards
-                )
+                padded = _padded_len(b.total_len, new_num_shards,
+                                     b.lens is not None)
                 entry = {
                     "padded": padded,
                     "store": _repad(store, b.total_len, padded, b.dtype),
@@ -2348,7 +2648,7 @@ class CollectiveEngine:
                         state = (
                             _repad(arrs[0], b.total_len, padded, b.dtype),
                         )
-                    else:  # adam: m, v, per-shard step counter
+                    else:  # adam, lamb: m, v, per-shard step counter
                         step = float(arrs[2][0]) if len(arrs[2]) else 0.0
                         state = (
                             _repad(arrs[0], b.total_len, padded, b.dtype),

@@ -99,6 +99,7 @@ class _IciDataPlane:
             # the message plane's instruments (docs/observability.md).
             metrics = self.po.metrics
             stage_clock().export(metrics)
+            self.engine.export(metrics)
             self.sparse_engine.export(metrics)
             counts = compile_cache.cache_counts
             metrics.gauge("compile_cache.hits", fn=lambda: counts[0])

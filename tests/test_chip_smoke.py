@@ -33,7 +33,7 @@ def test_smoke_phases_at_tiny_size(capsys):
                          expired=lambda name, s: hung.append(name))
     assert not hung
     out = capsys.readouterr().out
-    for phase in ("boot", "resnet50", "readme", "sparse", "message_path",
+    for phase in ("boot", "resnet50", "readme", "lamb", "sparse", "message_path",
                   "shutdown"):
         assert f"phase {phase}: ok" in out
     assert "W = 8, kernels interpreted" in out
