@@ -457,17 +457,21 @@ class _Smoke:
     def _sparse_under_handle(self, se, name, dim, idx, rng) -> None:
         """From the zero state one push of row-wise Adagrad leaves
         -lr * G / (sqrt(mean(G**2)) + eps) in every touched row; a plain
-        sum into the same table then adds G."""
+        sum into the same table then adds G; a second push under the handle
+        meets the accumulators the first left (on the chip
+        ops/acc_update.py reads, steps and writes them; on several chips
+        each its own shard's)."""
         kv, sz = self.kv, self.sizes
         W = se.num_shards
         lr, eps = 0.05, 1e-8
         table = se.register_sparse(name, sz.emb_rows, dim)
+        handle = f"row_adagrad:{lr},{eps}"
         before = (se.row_kernel_pushes, se.packed_pushes,
-                  se.segsum_kernel_pushes)
+                  se.segsum_kernel_pushes, se.acc_kernel_pushes)
         grads = rng.standard_normal((W, sz.emb_batch, dim), dtype=np.float32)
         out = np.zeros_like(grads)
         t0 = time.perf_counter()
-        kv.wait(kv.push_sparse(name, idx, grads, f"row_adagrad:{lr},{eps}"))
+        kv.wait(kv.push_sparse(name, idx, grads, handle))
         kv.wait(kv.pull_sparse(name, idx, out=out))
         wall = time.perf_counter() - t0
         rows, inverse = np.unique(idx.reshape(-1), return_inverse=True)
@@ -488,7 +492,11 @@ class _Smoke:
               f"table written by the row kernel: {kernel}")
         check(se.segsum_kernel_pushes == before[2] + kernel,
               f"segment sum kernel pushes {se.segsum_kernel_pushes}")
-        print(f"  one push under row_adagrad:{lr},{eps} through "
+        # The accumulator is by logical row whatever the table's packing:
+        # at these sizes every push lowered for a TPU takes its kernel.
+        check(se.acc_kernel_pushes == before[3] + self.on_tpu,
+              f"accumulator kernel pushes {se.acc_kernel_pushes}")
+        print(f"  one push under {handle} through "
               f"push_sparse, {sz.emb_rows:,} x {dim} (pack {table.pack}), "
               f"duplicates summed by "
               f"{'ops/segment_sum.py' if kernel else 'XLA scatter-add'}, "
@@ -512,6 +520,62 @@ class _Smoke:
               f"packed pushes {se.packed_pushes} after the sum")
         print(f"  one push with no handle into the same table, written by "
               f"{'ops/row_add.py' if kernel else 'XLA scatter'}: agrees")
+        # A second step of the recurrence, in float64: the accumulators the
+        # first push left are read, stepped and written back.
+        grads = rng.standard_normal((W, sz.emb_batch, dim), dtype=np.float32)
+        kv.wait(kv.push_sparse(name, idx, grads, handle))
+        kv.wait(kv.pull_sparse(name, idx, out=out))
+        G2 = np.zeros_like(G)
+        np.add.at(G2, inverse, grads.reshape(-1, dim))
+        acc_want = np.mean(G ** 2, axis=1) + np.mean(G2 ** 2, axis=1)
+        want = want + G - lr * G2 / (np.sqrt(acc_want)[:, None] + eps)
+        np.testing.assert_allclose(
+            out.reshape(-1, dim), want[inverse], rtol=1e-4, atol=1e-5,
+            err_msg=f"a second push under row_adagrad, {name}")
+        acc = np.asarray(se.acc_global_device(name))
+        np.testing.assert_allclose(
+            acc[rows], acc_want, rtol=1e-5,
+            err_msg=f"accumulators after two pushes, {name}")
+        check(np.count_nonzero(acc) == len(rows),
+              "accumulator rows touched != rows pushed, second push")
+        check(se.acc_kernel_pushes == before[3] + 2 * self.on_tpu,
+              f"accumulator kernel pushes {se.acc_kernel_pushes} after the "
+              f"second push")
+        print(f"  a second push under {handle}: rows and accumulators "
+              f"follow the float64 recurrence, the accumulator updated by "
+              f"{'ops/acc_update.py' if self.on_tpu else 'XLA gather + scatter'}")
+        # A third, every slot a distinct row of the first shard's lowest
+        # rows (row r is shard r % W's row r // W): no slot is dropped, so
+        # the kernel's last chunk of ids is live, and it ends in the
+        # accumulator's first tile with every other tile still to pass.
+        low = (W * np.arange(W * sz.emb_batch, dtype=idx.dtype)).reshape(
+            W, sz.emb_batch)
+        kv.wait(kv.pull_sparse(name, low, out=out))
+        grads = rng.standard_normal((W, sz.emb_batch, dim), dtype=np.float32)
+        G3 = grads.reshape(-1, dim).astype(np.float64)
+        acc_want = acc.astype(np.float64)
+        acc_want[low.reshape(-1)] += np.mean(G3 ** 2, axis=1)
+        want = out.reshape(-1, dim) - lr * G3 / (
+            np.sqrt(acc_want[low.reshape(-1)])[:, None] + eps)
+        kv.wait(kv.push_sparse(name, low, grads, handle))
+        kv.wait(kv.pull_sparse(name, low, out=out))
+        np.testing.assert_allclose(
+            out.reshape(-1, dim), want, rtol=1e-4, atol=1e-5,
+            err_msg=f"a push of distinct low rows under row_adagrad, {name}")
+        after = np.asarray(se.acc_global_device(name))
+        np.testing.assert_allclose(
+            after[low.reshape(-1)], acc_want[low.reshape(-1)], rtol=1e-5,
+            err_msg=f"accumulators of the distinct low rows, {name}")
+        quiet = np.ones(len(acc), bool)
+        quiet[low.reshape(-1)] = False
+        check((after[quiet] == acc[quiet]).all(),
+              "an accumulator no row of the third push names has changed")
+        check(se.acc_kernel_pushes == before[3] + 3 * self.on_tpu,
+              f"accumulator kernel pushes {se.acc_kernel_pushes} after the "
+              f"third push")
+        print(f"  a third, {low.size:,} distinct rows of the first shard's "
+              f"lowest: rows and accumulators follow, every other "
+              f"accumulator is as it was")
 
     # -- message path ---------------------------------------------------------
 

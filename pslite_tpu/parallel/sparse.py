@@ -73,6 +73,7 @@ class _BoundPush:
     params: tuple  # the handle's numbers as device scalars; () for the sum
     row_kernel: bool  # the program's table write is ops/row_add.py
     segsum_kernel: bool  # it sums its segments with ops/segment_sum.py
+    acc_kernel: bool  # it updates the accumulator with ops/acc_update.py
     packed: bool  # the table is lane-packed (pack > 1)
 
 
@@ -231,6 +232,10 @@ _ROW_ADD_INTERPRET = {"tpu": False}
 _SEGMENT_SUM_INTERPRET = {"tpu": False}
 
 
+# Where a stateful push's accumulator is updated by ``ops/acc_update.py``.
+_ACC_UPDATE_INTERPRET = {"tpu": False}
+
+
 def _row_add_takes(width: int, dtype) -> bool:
     """The rows ``ops/row_add.py`` moves: PHYSICAL rows of 128 f32 lanes,
     ``width = pack * dim``: one unpacked row, or the ``pack`` logical rows
@@ -238,6 +243,35 @@ def _row_add_takes(width: int, dtype) -> bool:
     refuses the slice of one).  ``ops/segment_sum.py`` sums the same rows
     and no others (:func:`_segment_sums`)."""
     return width == 128 and np.dtype(dtype) == np.float32
+
+
+# What ``_acc_update_takes`` weighs, in ns on a v5e, both read with every slot
+# a distinct row spread over the table, the pass's dearest batch (every step
+# of the walk then holds ids) and XLA's cheapest (my chip runs, PR 34,
+# ``PERF.md`` section 6: 20,000,000 accumulators under 8,192 to 131,072
+# slots, 1,048,576 under 1,024 to 8,192): a grid step of
+# ``ops/acc_update.py``'s pass (1.08-1.13 us at six batch sizes of the large
+# table; 0.65 where it holds no id), and a slot of XLA's 1-D gather, add and
+# scatter (19.6 ns from 32,768 slots to 131,072; 20.5 under Zipf duplicates).
+# What either costs whatever the batch is left out (XLA's pair ~0.15 ms at
+# 20,000,000 rows, a kernel's start ~0.04): the kernel is taken from ~43,000
+# slots there and is the faster from ~34,000 (between 16,384 and 32,768
+# under Zipf duplicates), XLA's kept at a loss of at most a tenth in between;
+# at 1,048,576 rows taken from ~2,200 slots and the faster from ~1,700.
+_ACC_STEP_NS = 1100
+_ACC_SLOT_NS = 20
+
+
+def _acc_update_takes(R: int, m: int) -> bool:
+    """Whether ``ops/acc_update.py`` updates an accumulator of ``R`` rows
+    under a batch of ``m`` slots: the accumulator is whole 128-lane rows,
+    and the pass, which costs by ``R`` (and by ``m`` only in chunks), is
+    reckoned cheaper than XLA's pair, which costs by the slot.  A small
+    batch into a large table keeps XLA's."""
+    from ..ops.acc_update import steps
+
+    return (R % 128 == 0
+            and steps(R, m) * _ACC_STEP_NS < m * _ACC_SLOT_NS)
 
 
 def _where_lowered(interprets, xla, kernel, *operands):
@@ -287,6 +321,37 @@ def _segment_sums(seg, sg):
         return scatter(seg, sg)
     return _where_lowered(_SEGMENT_SUM_INTERPRET, scatter, segment_sum, seg,
                           sg)
+
+
+def _update_acc(acc_l, row_seg, valid, g2):
+    """``acc_l[row_seg[i]] += g2[i]`` where ``valid[i]``, for the combined
+    logical rows of :func:`_combine_rows`; returns the new accumulator and
+    ``f32[m]``, the new accumulators of the valid rows in ``row_seg``'s
+    order (what it holds past them is not for use).  ``ops/acc_update.py``
+    where the program is lowered for a platform of ``_ACC_UPDATE_INTERPRET``
+    and the pass pays (:func:`_acc_update_takes`), XLA's 1-D gather and
+    scatter anywhere else (:func:`_where_lowered`;
+    ``SparseEngine._acc_kernel`` counts it).  The accumulator is by logical
+    row whatever the table's width, dtype or packing."""
+    import jax.numpy as jnp
+
+    from ..ops.acc_update import acc_update
+
+    R = acc_l.shape[0]
+
+    def xla(acc_l, row_seg, valid, g2):
+        new_rows = acc_l[jnp.where(valid, row_seg, 0)] + g2
+        return acc_l.at[jnp.where(valid, row_seg, R)].set(
+            new_rows, mode="drop"), new_rows
+
+    def kernel(acc_l, row_seg, valid, g2, interpret):
+        return acc_update(acc_l, row_seg, g2, jnp.sum(valid),
+                          interpret=interpret)
+
+    if not _acc_update_takes(R, row_seg.shape[0]):
+        return xla(acc_l, row_seg, valid, g2)
+    return _where_lowered(_ACC_UPDATE_INTERPRET, xla, kernel, acc_l, row_seg,
+                          valid, g2)
 
 
 def _add_rows(store_l, row_seg, valid, delta, R, pack):
@@ -414,10 +479,10 @@ def _adagrad_sparse(axis, S, R, pack, dim, store_l, acc_l, idx_l,
     for a 4096-row batch) and cannot serve the lane-packed layout.
     Here duplicates are combined by a SEGMENT SUM over the sorted
     gathered indices (O(batch) workspaces, exact same per-row G as the
-    dense form), the accumulator rows are gathered/updated/scattered
-    1-D by logical row whatever the store's lane packing, and the store
-    step is added by distinct row (_add_rows; a lane-packed table's
-    placed in its slot's lanes and merged by physical row) — identical
+    dense form), the accumulator rows are read, stepped and written back
+    1-D by logical row whatever the store's lane packing (_update_acc),
+    and the store step is added by distinct row (_add_rows; a lane-packed
+    table's placed in its slot's lanes and merged by physical row) — identical
     numerics to _adagrad_rows on the touched rows, untouched rows never
     read, and written only zeros where they share a touched physical
     row."""
@@ -436,14 +501,10 @@ def _adagrad_sparse(axis, S, R, pack, dim, store_l, acc_l, idx_l,
         G_seg, row_seg, valid = _combine_rows(local, all_g, R)
 
     with jax.named_scope("ps.update"):
-        # Accumulator: gather the touched rows, apply, scatter back (1-D
-        # logical rows — independent of the store's lane packing).
-        acc_rows = acc_l[jnp.where(valid, row_seg, 0)]
+        # Accumulator: the touched rows read, stepped and written back
+        # (1-D logical rows — independent of the store's lane packing).
         g2 = jnp.mean(G_seg.astype(jnp.float32) ** 2, axis=1)
-        acc_new_rows = acc_rows + g2
-        new_acc = acc_l.at[jnp.where(valid, row_seg, R)].set(
-            acc_new_rows, mode="drop"
-        )
+        new_acc, acc_new_rows = _update_acc(acc_l, row_seg, valid, g2)
         step = (lr * G_seg.astype(jnp.float32)
                 / (jnp.sqrt(acc_new_rows)[:, None] + eps))
         step = jnp.where(valid[:, None], step, 0).astype(store_l.dtype)
@@ -524,11 +585,12 @@ class SparseEngine:
         self._bound: Dict[tuple, _BoundPush] = {}
         # Pushes that ran under a stateful handle, and pushes, under a
         # handle or not, whose program writes the table through
-        # ops/row_add.py, or sums its duplicates with ops/segment_sum.py
-        # (see export).
+        # ops/row_add.py, sums its duplicates with ops/segment_sum.py, or
+        # updates the accumulator with ops/acc_update.py (see export).
         self.stateful_pushes = 0
         self.row_kernel_pushes = 0
         self.segsum_kernel_pushes = 0
+        self.acc_kernel_pushes = 0
         self.packed_pushes = 0  # pushes into a lane-packed table
         self._mu = threading.Lock()
         # Per-table write locks: push donates the store buffer, so the
@@ -594,6 +656,8 @@ class SparseEngine:
                        fn=lambda: self.row_kernel_pushes)
         registry.gauge("engine.sparse.push.segsum_kernel",
                        fn=lambda: self.segsum_kernel_pushes)
+        registry.gauge("engine.sparse.push.acc_kernel",
+                       fn=lambda: self.acc_kernel_pushes)
         registry.gauge("engine.sparse.push.packed",
                        fn=lambda: self.packed_pushes)
         registry.gauge(
@@ -916,7 +980,9 @@ class SparseEngine:
             self._sparse_program("push" if kind is None else "push_" + kind,
                                  table, batch),
             kind, params, self._row_kernel(table),
-            self._segsum_kernel(table, kind is not None), table.pack != 1)
+            self._segsum_kernel(table, kind is not None),
+            kind is not None and self._acc_kernel(table, batch),
+            table.pack != 1)
         with self._mu:
             # A new registration meanwhile: the next push binds.
             if self._tables.get(name) is table:
@@ -944,6 +1010,14 @@ class SparseEngine:
                 and (self._row_kernel(table)
                      or (stateful
                          and _row_add_takes(table.dim, table.dtype))))
+
+    def _acc_kernel(self, table: SparseTable, batch: int) -> bool:
+        """Whether this mesh's stateful push program of ``table`` at
+        ``batch`` lookups a worker updates the accumulator with
+        ``ops/acc_update.py`` (the rule of :func:`_update_acc`)."""
+        return (self._platform() in _ACC_UPDATE_INTERPRET
+                and _acc_update_takes(table.rows_per_shard,
+                                      self.num_shards * batch))
 
     def push(self, name: str, indices, grads, handle: str = None):
         """indices: [W, n] int rows per worker; grads: [W, n, d].
@@ -982,6 +1056,7 @@ class SparseEngine:
                 self.stateful_pushes += 1
             self.row_kernel_pushes += b.row_kernel
             self.segsum_kernel_pushes += b.segsum_kernel
+            self.acc_kernel_pushes += b.acc_kernel
             self.packed_pushes += b.packed
         self._observe("push", table, batch)
         t3 = stamp()
@@ -1163,6 +1238,8 @@ class SparseEngine:
             self.row_kernel_pushes += any(map(self._row_kernel, tables))
             self.segsum_kernel_pushes += any(
                 self._segsum_kernel(t, handle is not None) for t in tables)
+            self.acc_kernel_pushes += handle is not None and any(
+                map(self._acc_kernel, tables, batches))
             self.packed_pushes += any(t.pack != 1 for t in tables)
         finally:
             self._unlock_tables(ordered)

@@ -186,6 +186,28 @@ def _segment_sum_is_the_kernel(text, m, scope):
                 if " scatter(" in l and f"= f32[{m},128]" in l]
 
 
+def _acc_update_is_the_kernel(text, rows, m):
+    """The compiled push updates the accumulator with ``ops/acc_update.py``:
+    one Mosaic call named ``%acc_update`` under ``ps.update`` whose first
+    result is the accumulator as 128-lane rows, a bitcast of the donated
+    ``f32[rows]`` either way, so no operation but parameter and bitcast has
+    the accumulator for its result: no scatter, no copy; and no gather
+    leaves the batch's ``f32[m]``."""
+    lines = [l.replace("ROOT ", "").strip() for l in text.splitlines()]
+    calls = [l for l in lines if l.startswith("%acc_update")]
+    assert len(calls) == 1, calls
+    assert f"(f32[{rows // 128},128]" in calls[0]
+    assert "tpu_custom_call" in calls[0] and "ps.update" in calls[0]
+    assert "acc_update/pallas_call" in calls[0]
+    whole = [l for l in lines if f"= f32[{rows}]" in l
+             or f"= f32[{rows // 128},128]" in l]
+    assert whole and all(
+        " parameter(" in l or " bitcast(" in l or " get-tuple-element(" in l
+        for l in whole), whole
+    assert not [l for l in lines if f"= f32[{m}]" in l and "/gather" in l]
+    assert not [l for l in lines if " scatter(" in l and f"f32[{rows}]" in l]
+
+
 @pytest.mark.parametrize("m", [12, 1500, 4096, 131_072])
 def test_row_add_compiles_for_v5e_in_place(v5e_chip, m):
     """The kernel lowers through Mosaic at a real table size, for a batch
@@ -245,6 +267,46 @@ def test_segment_sum_compiles_for_v5e(v5e_chip, m):
                     and f"= f32[{m},128]" in l]
 
 
+@pytest.mark.parametrize("m", [12, 1500, 16_384, 131_072])
+def test_acc_update_compiles_for_v5e_in_place(v5e_chip, m):
+    """``ops/acc_update.py`` lowers through Mosaic over the cell's
+    accumulator (156,250 rows of 128: a ragged last tile, and no multiple
+    of the 1,024 a 1-D array is tiled by) for a batch below one chunk of
+    ids, one that is no whole number of chunks, ``chip_smoke.py``'s on four
+    chips and the cell's; the accumulator is donated and updated in place,
+    seen as 128-lane rows through bitcasts, with nothing of its size
+    allocated or copied beside it."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pslite_tpu.ops.acc_update import acc_update
+
+    rows = 20_000_000
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(v5e_chip, P()))
+
+    compiled = jax.jit(
+        lambda a, r, g, n: acc_update(a, r, g, n, interpret=False),
+        donate_argnums=(0,),
+    ).lower(sds((rows,), jnp.float32), sds((m,), jnp.int32),
+            sds((m,), jnp.float32), sds((), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert rows * 4 <= mem.alias_size_in_bytes < rows * 4 + (1 << 16)
+    assert mem.temp_size_in_bytes < 1 << 20
+    text = compiled.as_text()
+    calls = [l for l in text.splitlines()
+             if l.replace("ROOT ", "").lstrip().startswith("%acc_update")]
+    assert len(calls) == 1 and "tpu_custom_call" in calls[0], calls
+    assert f"(f32[{rows // 128},128]" in calls[0]
+    whole = [l for l in text.splitlines()
+             if f"= f32[{rows}]" in l or f"= f32[{rows // 128},128]" in l]
+    assert whole and all(
+        " parameter(" in l or " bitcast(" in l or " get-tuple-element(" in l
+        for l in whole), whole
+
+
 @pytest.mark.parametrize("kept", [False, True])
 def test_row_adagrad_push_at_full_size_writes_the_table_with_row_add(
         v5e_chip, kept, tmp_path, monkeypatch):
@@ -254,21 +316,30 @@ def test_row_adagrad_push_at_full_size_writes_the_table_with_row_add(
     v5e its table write is the ``row_add`` kernel under the scope
     ``ps.sparse.push.scatter_add``, no scatter has the table for its
     result, the segments are summed by the ``segment_sum`` kernel under
-    ``ps.sparse.combine``, and both donations still hold.  That is compiled
-    once, for ``kept``: the program a later process builds from the two
-    kernels' traces as the compile cache's directory keeps them, without
-    tracing either (the same module, so the same compiled program, as the
-    one traced in place, of which only the lowering is looked at)."""
+    ``ps.sparse.combine``, the accumulator is updated by the ``acc_update``
+    kernel under ``ps.update`` (no gather of the batch's ``f32[131072]``,
+    no scatter into ``f32[20000000]``, no copy of the accumulator: its view
+    as 128-lane rows is a bitcast), and both donations still hold.  That is
+    compiled once, for ``kept``: the program a later process builds from
+    the three kernels' traces as the compile cache's directory keeps them,
+    without tracing any (the same module, so the same compiled program, as
+    the one traced in place, of which only the lowering is looked at)."""
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from pslite_tpu.ops import acc_update as acc_update_module
     from pslite_tpu.ops import row_add as row_add_module
     from pslite_tpu.ops import segment_sum as segment_sum_module
     from pslite_tpu.parallel import sparse
     from pslite_tpu.utils import compile_cache
 
     assert jax.devices()[0].platform == "cpu"
-    traced, summed = [], []
+    traced, summed, stepped = [], [], []
+    real_acc = acc_update_module._acc_update
+    monkeypatch.setattr(
+        acc_update_module, "_acc_update",
+        lambda acc, rows_, *rest: stepped.append(
+            (acc.shape, rows_.shape)) or real_acc(acc, rows_, *rest))
     real = row_add_module._row_add
     monkeypatch.setattr(
         row_add_module, "_row_add",
@@ -308,18 +379,21 @@ def test_row_adagrad_push_at_full_size_writes_the_table_with_row_add(
             scalar, scalar)
     lowered = push().lower(*args)
     assert traced == [(rows, dim)] and summed == [(lookups, dim)]
+    assert stepped == [((rows,), (lookups,))]
     if not kept:
         text = lowered.as_text(debug_info=True)
         assert "tpu_custom_call" in text and "row_add" in text
-        assert "segment_sum" in text
+        assert "segment_sum" in text and "acc_update" in text
         assert "ps.sparse.push.scatter_add" in text
         return
     compile_cache._traced.clear()               # a new process
     lowered = push().lower(*args)
-    assert traced == [(rows, dim)]              # neither is traced again
+    assert traced == [(rows, dim)]              # none is traced again
     assert summed == [(lookups, dim)]
-    assert len(os.listdir(tmp_path)) == 2       # a file a kernel
+    assert stepped == [((rows,), (lookups,))]
+    assert len(os.listdir(tmp_path)) == 3       # a file a kernel
     compiled = lowered.compile()
+    _acc_update_is_the_kernel(compiled.as_text(), rows, lookups)
     table = [l for l in compiled.as_text().splitlines()
              if f"= f32[{rows},{dim}]" in l and " parameter(" not in l]
     assert len(table) == 1, table
@@ -331,11 +405,12 @@ def test_row_adagrad_push_at_full_size_writes_the_table_with_row_add(
     mem = compiled.memory_analysis()
     state = rows * dim * 4 + rows * 4
     assert state <= mem.alias_size_in_bytes < state + (1 << 20)
-    # One workspace of the batch's size in HBM (64 MiB: the gathered
-    # gradients in sorted order; their sums stay on the chip's own memory)
-    # and the ids.
+    # Two workspaces of the batch's size in HBM (64 MiB each: the gathered
+    # gradients in sorted order, and their sums, which XLA moves off the
+    # chip's own memory before the accumulator's kernel: it keeps nothing
+    # there across a custom call) and the ids.
     batch = lookups * dim * 4
-    assert batch <= mem.temp_size_in_bytes < batch + (4 << 20)
+    assert 2 * batch <= mem.temp_size_in_bytes < 2 * batch + (4 << 20)
 
 
 def test_sum_push_at_full_size_combines_and_writes_the_table_with_row_add(

@@ -373,6 +373,140 @@ def test_the_segment_sum_kernel_in_the_push_is_counted_and_matches(
     assert not eng._segsum_kernel(table, False)
 
 
+def _acc_kernel_on_cpu(monkeypatch):
+    """Name the CPU among the platforms whose stateful pushes update the
+    accumulator with ``ops/acc_update.py`` (interpreted there); returns the
+    list that takes ``(accumulators, slots)`` of every trace of the kernel."""
+    from pslite_tpu.ops import acc_update as acc_update_module
+    from pslite_tpu.parallel import sparse
+
+    traced = []
+    real = acc_update_module.acc_update
+    monkeypatch.setattr(
+        acc_update_module, "acc_update",
+        lambda acc, rows, *a, **kw: traced.append(
+            (acc.shape[0], rows.shape[0])) or real(acc, rows, *a, **kw))
+    monkeypatch.setitem(sparse._ACC_UPDATE_INTERPRET, "cpu", True)
+    return traced
+
+
+@pytest.mark.parametrize("grouped", [False, True],
+                         ids=["single", "grouped"])
+@pytest.mark.parametrize("dim", [64, 128, 256],
+                         ids=["lane-packed", "unpacked", "256-wide"])
+@pytest.mark.parametrize("cluster", [1, 4], indirect=True)
+def test_the_accumulator_kernel_in_the_push_is_counted_and_bit_equal(
+        cluster, dim, grouped, monkeypatch):
+    """On the CPU mesh a stateful push updates the accumulator with XLA's
+    1-D gather and scatter and ``engine.sparse.push.acc_kernel`` stays 0;
+    with the CPU named among ``acc_update``'s platforms (interpreted; alone,
+    so that sums and table writes keep XLA's order) the same three pushes
+    with duplicates leave the same bits in table and accumulator, whatever the
+    table's width or packing (the accumulator is by logical row), and the
+    counter equals the pushes, a group counting as one.  The plain sum, and
+    a batch too small for a pass over the accumulator to pay, are not
+    counted and keep XLA's."""
+    from pslite_tpu.parallel import sparse
+
+    kv, eng = cluster
+    W = eng.num_shards
+    rows, batch = 128 * W, 64           # 128 accumulators a shard: one row
+    rng = np.random.default_rng(dim + W)
+    idx = rng.integers(0, rows, size=(W, batch)).astype(np.int32)
+    idx[:, 0], idx[:, 1] = 0, rows - 1  # a shard's first and last, by all
+    idx[:, 2] = idx[:, 3]               # a duplicate within a worker
+    init = rng.normal(size=(rows, dim)).astype(np.float32)
+    grads = [rng.normal(size=(W, batch, dim)).astype(np.float32)
+             for _ in range(3)]
+    names = ["emb", "other"] if grouped else ["emb"]
+
+    def run(engine):
+        for name in names:
+            engine.register_sparse(name, rows, dim, init=init)
+        for g in grads:
+            if grouped:
+                token = engine.push_group(names, [idx] * 2, [g, 2 * g],
+                                          handle=HANDLE)
+            else:
+                token = engine.push("emb", idx, g, HANDLE)
+        token.block_until_ready()
+
+    twin = SparseEngine(eng.mesh, eng.axis)
+    run(twin)                               # the CPU's own: XLA's
+    assert (twin.stateful_pushes, twin.acc_kernel_pushes) == (3, 0)
+    traced = _acc_kernel_on_cpu(monkeypatch)
+    run(eng)
+    assert set(traced) == {(128, W * batch)}            # in the program
+    assert (eng.stateful_pushes, eng.acc_kernel_pushes) == (3, 3)
+    assert _gauges(kv)["engine.sparse.push.acc_kernel"] == 3
+    if not grouped:
+        assert eng._bound[("emb", HANDLE, batch)].acc_kernel
+        assert not twin._bound[("emb", HANDLE, batch)].acc_kernel
+    for name in names:
+        assert (eng.store_array(name) == twin.store_array(name)).all()
+        acc = np.asarray(eng._acc[name])
+        assert (acc == np.asarray(twin._acc[name])).all()
+        assert acc.shape == (rows,) and (acc > 0).sum() == len(np.unique(idx))
+    # The plain sum has no accumulator; a batch of 12 slots a worker keeps
+    # XLA's (the pass costs by the accumulator, the gather by the slot).
+    del traced[:]
+    eng.push("emb", idx, grads[0]).block_until_ready()
+    eng.push("emb", idx[:, :12], grads[0][:, :12], HANDLE).block_until_ready()
+    assert not traced and eng.acc_kernel_pushes == 3
+    assert eng.stateful_pushes == 4
+    assert not eng._acc_kernel(eng.table("emb"), 12)
+    assert not sparse._acc_update_takes(128, 12 * W)
+    # The rule on shapes alone: the cell's, a small batch into its table,
+    # and an accumulator that is no whole 128-lane rows.
+    assert sparse._acc_update_takes(20_000_000, 131_072)
+    assert not sparse._acc_update_takes(20_000_000, 4_096)
+    assert not sparse._acc_update_takes(20_000_001, 131_072)
+
+
+@pytest.mark.parametrize("cluster", [1, 4], indirect=True)
+def test_the_accumulator_kernel_under_distinct_rows_of_a_tables_first_tile(
+        cluster, monkeypatch):
+    """Every slot of the batch a distinct row and all of them on the first
+    shard, in the first of its accumulator's two tiles: the kernel's last
+    chunk of ids is live and ends below the last tile, so the walk passes
+    the tile that follows under it.  Table and accumulator are XLA's bit
+    for bit over two pushes, the second onto accumulators that are not
+    zero, at the kernel's own tile and chunk and by ``_acc_update_takes``'s
+    own reckoning."""
+    from pslite_tpu.ops import acc_update as acc_update_module
+
+    kv, eng = cluster
+    W = eng.num_shards
+    per_shard = 2 * acc_update_module._TILE_ROWS * 128
+    rows, batch, dim = per_shard * W, acc_update_module._CHUNK, 128
+    # Row ``r`` is shard ``r % W``'s row ``r // W``.
+    idx = (W * np.arange(W * batch, dtype=np.int32)).reshape(W, batch)
+    rng = np.random.default_rng(W)
+    init = np.zeros((rows, dim), np.float32)
+    init[idx.reshape(-1)] = rng.normal(size=(W * batch, dim))
+    grads = [rng.normal(size=(W, batch, dim)).astype(np.float32)
+             for _ in range(2)]
+
+    def run(engine):
+        engine.register_sparse("emb", rows, dim, init=init)
+        for g in grads:
+            token = engine.push("emb", idx, g, HANDLE)
+        token.block_until_ready()
+
+    twin = SparseEngine(eng.mesh, eng.axis)
+    run(twin)
+    traced = _acc_kernel_on_cpu(monkeypatch)
+    run(eng)
+    assert set(traced) == {(per_shard, W * batch)}
+    assert (eng.acc_kernel_pushes, twin.acc_kernel_pushes) == (2, 0)
+    assert (eng.store_array("emb") == twin.store_array("emb")).all()
+    acc = np.asarray(eng._acc["emb"])
+    assert (acc == np.asarray(twin._acc["emb"])).all()
+    by_row = np.asarray(eng.acc_global_device("emb"))
+    assert (by_row[idx.reshape(-1)] > 0).all()
+    assert np.count_nonzero(by_row) == W * batch
+
+
 def _sum_reference(init, idx, grads):
     want = np.asarray(init, np.float64).copy()
     for g in grads:
