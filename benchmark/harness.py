@@ -179,6 +179,12 @@ class LayerContext:
     reduction: object              # trace_reduce.Reduction of the traced steps, or None
     least: Dict[str, float]        # least_bytes of one step, per device
     peaks: Dict[str, float]        # this device_kind's row of peaks.json
+    config: dict = field(default_factory=dict)    # the cell's configuration file
+    traffic: dict = field(default_factory=dict)   # the cell's traffic file
+    # The traced section's ``jax.profiler.ProfileData`` (planes -> lines ->
+    # events with their stats), for what ``reduction`` does not keep; None
+    # where nothing was traced.  The files it was read from are gone.
+    profile: object = None
 
 
 # -- device ----------------------------------------------------------------------
@@ -295,7 +301,8 @@ def run_window(driver, seconds: float, deadline_s: float,
 
 def traced_section(driver, traffic: dict, deadline_s: float):
     """A short steady section under the profiler, in a run of its own
-    part: returns (window, reduction or None)."""
+    part: returns (window, reduction or None, the profile it was reduced
+    from or None)."""
     import jax
 
     import trace_reduce
@@ -324,7 +331,22 @@ def traced_section(driver, traffic: dict, deadline_s: float):
                      if profile is not None else None)
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
-    return w, reduction
+    return w, reduction, profile
+
+
+def _clock_line(r) -> str:
+    """How the device's timeline was laid on the host's, for the ``trace:``
+    line of every traced run."""
+    if r.clock != "spans":
+        return (f"clock {r.clock} (the first operation drawn to the first "
+                f"issue: {r.clock_note}), offset {r.clock_offset_ns:.0f} ns")
+    idle_ns = (r.window_s - r.busy_s) * 1e9 / r.steps
+    wide = (", WIDE: over a fifth of the idle time a step"
+            if r.clock_bracket_ns > idle_ns / 5 else "")
+    return (f"clock by the program's spans (a launch told by "
+            f"{r.clock_note}), offset {r.clock_offset_ns:.0f} ns, bracket "
+            f"{r.clock_bracket_ns:.0f} ns of {idle_ns:.0f} ns idle a "
+            f"step{wide}")
 
 
 # -- one run ---------------------------------------------------------------------
@@ -405,7 +427,8 @@ def _drive(cell, driver_class, cluster, devices, peaks, compiles, cache_dir,
     attempted, failed = window.attempted, window.failed
     reduction = None
     if trace:
-        traced, reduction = traced_section(driver, traffic, deadline_s)
+        traced, reduction, profile = traced_section(driver, traffic,
+                                                    deadline_s)
         attempted += traced.attempted
         failed += traced.failed
 
@@ -463,7 +486,8 @@ def _drive(cell, driver_class, cluster, devices, peaks, compiles, cache_dir,
     else:
         ctx = LayerContext(
             spans=window.spans, compiles_in_window=compiles_in_window,
-            reduction=reduction, least=driver.least_bytes(), peaks=peaks)
+            reduction=reduction, least=driver.least_bytes(), peaks=peaks,
+            config=cell.config, traffic=traffic, profile=profile)
         least = least_seconds(ctx.least, peaks)
         print(f"least time of a step on this chip: "
               f"{least['seconds'] * 1e3:.3f} ms, bound by {least['bound']}",
@@ -471,7 +495,8 @@ def _drive(cell, driver_class, cluster, devices, peaks, compiles, cache_dir,
         if reduction is not None:
             print(f"trace: {reduction.steps} steps on {reduction.devices} "
                   f"device(s), launches repeat exactly: "
-                  f"{reduction.launches_repeat}", flush=True)
+                  f"{reduction.launches_repeat}; {_clock_line(reduction)}",
+                  flush=True)
         for m in cell.per_layer:
             value = load_reader(cell.search, m["name"])(ctx)
             if value is not None:

@@ -1,7 +1,7 @@
 """Which device operations of a step under ``lamb`` the readers
 ``layer_metrics/lamb_update_ms.py``, ``lamb_update_roofline.py`` and
-``lamb_norm_ms.py`` count, worked out from the sizes of the one cell that
-reports them (``bert-large-lamb.tree``: its configuration file).
+``lamb_norm_ms.py`` count, worked out from the sizes of the cell that is
+read (``ctx.config``: the cell's own configuration file).
 
 A reader is given ``Reduction.op_seconds`` (``sparse_handle_ops.py`` says
 what a short name is and how kind and shape are taken from it):
@@ -18,16 +18,11 @@ what a short name is and how kind and shape are taken from it):
 
 from __future__ import annotations
 
-import json
-import os
 from typing import Dict, Tuple
 
 from buckets import expand_tensors
 from lamb_bytes import lamb_update, over_vmem
 from sparse_handle_ops import ms_a_step
-
-HERE = os.path.dirname(os.path.abspath(__file__))
-CONFIG = os.path.join(HERE, "configs", "bert-large-lamb.json")
 
 KERNELS = ("lamb_moments", "lamb_apply")
 
@@ -36,10 +31,8 @@ def norm_shapes(n_keys: int) -> Tuple[str, ...]:
     return (f"f32[{n_keys}]", f"f32[{n_keys},2]", f"f32[{2 * n_keys}]")
 
 
-def cell_sizes() -> Dict[str, float]:
+def cell_sizes(config: dict) -> Dict[str, float]:
     """Keys, chips and the update's least bytes on one device."""
-    with open(CONFIG) as fh:
-        config = json.load(fh)
     sizes = [n for _, n in expand_tensors(config["tensors"])]
     W = int(config["chips"])
     return {"keys": len(sizes), "chips": W,

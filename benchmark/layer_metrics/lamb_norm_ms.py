@@ -9,6 +9,8 @@ from sparse_handle_ops import ms_a_step
 
 
 def read(ctx):
-    shapes = norm_shapes(cell_sizes()["keys"])
+    if ctx.reduction is None:
+        return None
+    shapes = norm_shapes(cell_sizes(ctx.config)["keys"])
     return ms_a_step(ctx, lambda kind, shape: kind not in KERNELS
                      and shape in shapes)

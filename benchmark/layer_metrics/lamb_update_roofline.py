@@ -10,5 +10,6 @@ def read(ctx):
     ms = update_ms(ctx)
     if not ms:
         return None
-    least_s = cell_sizes()["update_bytes"] / (ctx.peaks["hbm_gb_s"] * 1e9)
+    least_s = (cell_sizes(ctx.config)["update_bytes"]
+               / (ctx.peaks["hbm_gb_s"] * 1e9))
     return 100.0 * least_s * 1e3 / ms

@@ -1,7 +1,7 @@
-"""Which device operations of a step under a stateful sparse handle the
-readers ``layer_metrics/combine_ms.py`` and ``layer_metrics/table_write_ms.py``
-count, worked out from the sizes of the one cell that reports them
-(``dlrm-criteo-rowadagrad.zipf``: its configuration and traffic files).
+"""Which device operations of a sparse step the readers
+``layer_metrics/combine_ms.py`` and ``layer_metrics/table_write_ms.py``
+count, worked out from the sizes of the cell that is read (``ctx.config``
+and ``ctx.traffic``: the cell's own configuration and traffic files).
 
 A reader is given ``Reduction.op_seconds``: every device operation's seconds
 over the traced steps by its short name, ``%<instruction> <first result's
@@ -19,14 +19,17 @@ that of one device's shard, so it follows from the sizes:
 
 from __future__ import annotations
 
-import json
-import os
 import re
 from typing import Callable, Dict, Optional, Tuple
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-CONFIG = os.path.join(HERE, "configs", "dlrm-criteo-rowadagrad.json")
-TRAFFIC = os.path.join(HERE, "traffic", "zipf-rows-handle.json")
+# The sparse path's kernels by the names they give their custom calls:
+# ``pslite_tpu/ops/segment_sum.py`` (the combine's segment sum),
+# ``pslite_tpu/ops/row_add.py`` (the table written by distinct row) and
+# ``pslite_tpu/ops/acc_update.py`` (row-wise Adagrad's accumulator).
+KERNELS = SEGMENT_SUM, ROW_ADD, ACC_UPDATE = ("segment_sum", "row_add",
+                                              "acc_update")
+# On the TPU a gather or a scatter shows as ``%fusion.<n>``.
+MOVERS = ("fusion", "scatter", "scatter-add", "gather")
 
 _SHORT = re.compile(r"^%([A-Za-z_\-]+(?:\.[A-Za-z_\-]+)*)[.\d]* (\w+\[[\d,]*\])$")
 
@@ -42,14 +45,6 @@ def shapes(config: dict, traffic: dict) -> Dict[str, str]:
             "accumulator": f"f32[{rps}]",
             "batch_rows": f"f32[{m},{dim}]",
             "batch_ids": f"s32[{m}]", "batch_flags": f"pred[{m}]"}
-
-
-def cell_shapes() -> Dict[str, str]:
-    with open(CONFIG) as fh:
-        config = json.load(fh)
-    with open(TRAFFIC) as fh:
-        traffic = json.load(fh)
-    return shapes(config, traffic)
 
 
 def kind_and_shape(short_name: str) -> Optional[Tuple[str, str]]:
@@ -72,3 +67,16 @@ def ms_a_step(ctx, pick: Callable[[str, str], bool]) -> Optional[float]:
             seconds += s
             found = True
     return seconds * 1e3 / reduction.steps if found else None
+
+
+def combine_ms(ctx, shapes_of: Callable[[dict, dict], Dict[str, str]],
+               batch: Tuple[str, ...]) -> Optional[float]:
+    """What ``combine_ms`` and ``packed_combine_ms`` share: every sort, the
+    segment sum's kernel, and every mover whose result is one of the
+    workspaces ``batch`` of ``shapes_of(ctx.config, ctx.traffic)``."""
+    if ctx.reduction is None:
+        return None
+    s = shapes_of(ctx.config, ctx.traffic)
+    workspaces = tuple(s[name] for name in batch)
+    return ms_a_step(ctx, lambda kind, shape: kind in ("sort", SEGMENT_SUM)
+                     or (kind in MOVERS and shape in workspaces))

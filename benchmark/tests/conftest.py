@@ -35,3 +35,61 @@ def _loopback_isolation(request):
 
     loopback_van.reset_registry()
     os.environ.pop("PS_LOOPBACK_NS", None)
+
+
+@pytest.fixture(scope="session")
+def appended_root(tmp_path_factory):
+    """A root whose ``BENCHMARK.json`` is the committed one with what an
+    addition PR brings appended: a directory of ``paths`` with a
+    configuration, a traffic mix and a reader in it, and an entry of
+    ``configs``, ``workloads`` and ``per_layer`` each at the end of its
+    list.  The committed files are named by absolute path, so nothing is
+    copied but the two data files the new cell runs on."""
+    import json
+
+    root = tmp_path_factory.mktemp("appended")
+    extra = root / "extra"
+    for kind in ("configs", "traffic", "layer_metrics"):
+        (extra / kind).mkdir(parents=True)
+    cells = os.path.join(HERE, "cells")
+    for src, dst, name in (
+            ("tiny-sparse.json", extra / "configs" / "appended-table.json",
+             "appended-table"),
+            ("tiny-zipf.json", extra / "traffic" / "appended-zipf.json",
+             "appended-zipf")):
+        with open(os.path.join(cells, src)) as fh:
+            data = json.load(fh)
+        dst.write_text(json.dumps(dict(
+            data, name=name, reduced=[], chips=1,
+            source="benchmark/tests/conftest.py")))
+    (extra / "layer_metrics" / "appended_steps.py").write_text(
+        '"""Steps in the profiler-off window: a count any run can read."""\n'
+        "def read(ctx):\n    return float(len(ctx.spans)) or None\n")
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        bench = json.load(fh)
+    bench["paths"] = [BENCH, str(extra)]
+    for config in bench["configs"]:
+        config["file"] = os.path.join(ROOT, config["file"])
+    bench["configs"].append({
+        "name": "appended-table", "source": "benchmark/tests/conftest.py",
+        "file": str(extra / "configs" / "appended-table.json"),
+        "reduced": [], "why": "what an addition PR appends"})
+    bench["workloads"].append({
+        "name": "appended-table.zipf", "config": "appended-table",
+        "traffic": "appended-zipf", "chips": 1,
+        "why": "what an addition PR appends"})
+    bench["per_layer"].append({
+        "name": "appended_steps", "unit": "count", "better": "higher",
+        "source": "program_counter", "layer": "dense and sparse engines",
+        "moves": "goodput"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return str(root)
+
+
+@pytest.fixture(params=["committed", "appended"])
+def bench_root(request):
+    """The repository's root, and the root with an entry of each kind
+    appended: what a test asserts by name holds under both."""
+    if request.param == "committed":
+        return ROOT
+    return request.getfixturevalue("appended_root")

@@ -95,5 +95,4 @@ def test_row_adagrad_push_compiles_in_place_over_table_and_accumulator(mesh):
              and " parameter(" not in l]
     assert whole and not [l for l in whole if " copy(" in l], whole
     assert any("ps.sparse.push.scatter_add" in l for l in whole)
-    assert any("ps.update" in l for l in whole)
     assert "ps.sparse.combine" in text

@@ -1,7 +1,10 @@
-"""``combine_ms`` and ``table_write_ms`` on a synthetic trace of the shape
-the chip's has (``test_trace_reduce.py``): which operations each counts, by
-kind and result shape worked out from the cell's own sizes, and that they
-read nothing where there is no device plane."""
+"""``combine_ms`` and ``table_write_ms`` on synthetic traces of the shape
+the chip's has (``test_trace_reduce.py``), one of a program that keeps XLA's
+scatters and one of the program as the chip runs it since PRs 28-34 (the
+three kernels): which operations each counts, by kind, by kernel name and by
+result shape worked out from the sizes of the cell that is read
+(``ctx.config`` / ``ctx.traffic``), and that they read nothing where there
+is no device plane."""
 
 import json
 import os
@@ -15,14 +18,40 @@ from conftest import BENCH, ROOT
 from test_trace_reduce import Ev, Line, Plane, Profile
 
 CELL = "dlrm-criteo-rowadagrad.zipf"
+SUM_CELL = "dlrm-criteo-emb.zipf"
 T = "{1,0:T(8,128)}"
 
+# The push as the chip runs it (PERF.md section 5): the combine with the
+# segment sum's kernel, the accumulator's kernel (a tuple: the accumulator
+# seen as whole 128-lane rows first), the table's.  ``ACC`` only under the
+# handle.
+PULL = [("%fusion = f32[131072,128]" + T + " fusion(f32[20000000,128] %p)",
+         1300)]
+COMBINE = [
+    ("%sort.16 = (s32[131072]{0:T(1024)}, s32[131072]{0}) sort(%a, %b)", 94),
+    ("%fusion.2 = f32[131072,128]" + T + " fusion(%g, %order)", 240),
+    ("%segment_sum.1 = (f32[131072,128]" + T + ", s32[1]{0}) custom-call(%s)",
+     385),
+    ("%sort.19 = s32[131072]{0:T(1024)} sort(%first_rows)", 42),
+    ("%copy.1 = f32[1,131072,128]{2,1,0:T(8,128)} copy(%g)", 204),
+]
+ACC = [
+    ("%copy-done.2 = f32[131072,128]" + T + " copy-done(%cs)", 102),
+    ("%acc_update.1 = (f32[156250,128]" + T + ", f32[512,1,256]{2,1,0})"
+     " custom-call(%ids, %g2, %acc)", 878),
+    ("%select_negate_fusion = f32[131072,128]" + T + " fusion(%x)", 207),
+    ("%bitcast.3 = f32[20000000]{0:T(1024)} bitcast(%acc_update.1)", 0.5),
+]
+ROW_ADD = [("%row_add.1 = f32[20000000,128]" + T
+            + " custom-call(%n, %rows, %G, %st)", 2006)]
 
-def _profile(extra=(), steps=2):
+
+def _profile(extra=(), steps=2, per_step=None):
     """Two steps of 10 us; a step runs the pull program (its gather) and
-    the push program under the handle: four sorts, the permutation and the
-    segment sum, the elementwise step, and the two in-place scatters."""
-    per_step = [
+    the push program under the handle, by default as XLA alone compiles
+    it: four sorts, the permutation and the segment sum, the elementwise
+    step, and the two in-place scatters."""
+    per_step = list(per_step) + list(extra) if per_step is not None else [
         # the pull program
         ("%fusion = f32[131072,128]" + T + " fusion(f32[20000000,128] %p)",
          1300),
@@ -59,10 +88,12 @@ def _profile(extra=(), steps=2):
                     Plane("/host:CPU", [host])])
 
 
-def _ctx(reduction):
+def _ctx(reduction, cell=CELL):
+    cell = harness.load_cell(cell)
     return harness.LayerContext(spans=[], compiles_in_window=0,
                                 reduction=reduction,
-                                least={"hbm": 1.0, "ici": 0.0}, peaks={})
+                                least={"hbm": 1.0, "ici": 0.0}, peaks={},
+                                config=cell.config, traffic=cell.traffic)
 
 
 @pytest.fixture
@@ -72,11 +103,17 @@ def readers():
             harness.load_reader(search, "table_write_ms"))
 
 
-def test_shapes_follow_from_the_cells_sizes():
-    assert ops.cell_shapes() == {
+@pytest.mark.parametrize("cell", [CELL, SUM_CELL])
+def test_shapes_follow_from_the_cells_sizes(cell):
+    ctx = _ctx(None, cell)
+    assert ops.shapes(ctx.config, ctx.traffic) == {
         "table": "f32[20000000,128]", "accumulator": "f32[20000000]",
         "batch_rows": "f32[131072,128]", "batch_ids": "s32[131072]",
         "batch_flags": "pred[131072]"}
+    assert not hasattr(ops, "CONFIG") and not hasattr(ops, "cell_shapes")
+
+
+def test_kinds_shapes_and_the_kernels_names():
     # Four chips and a lane-packed width: a shard of each, rounded up.
     assert ops.shapes({"chips": 4, "rows": 4001, "dim": 32},
                       {"lookups_per_worker": 256}) == {
@@ -90,6 +127,12 @@ def test_shapes_follow_from_the_cells_sizes():
     assert ops.kind_and_shape("%select_negate_fusion f32[8,4]")[0] \
         == "select_negate_fusion"
     assert ops.kind_and_shape("jit__push_row_adagrad(2)") is None
+    # A kernel's custom call carries its name: that is its kind.
+    assert ops.KERNELS == ("segment_sum", "row_add", "acc_update")
+    for kernel, shape in zip(ops.KERNELS, ("f32[131072,128]",
+                                           "f32[20000000,128]",
+                                           "f32[156250,128]")):
+        assert ops.kind_and_shape(f"%{kernel}.1 {shape}") == (kernel, shape)
 
 
 def test_what_each_reader_counts(readers):
@@ -105,12 +148,44 @@ def test_what_each_reader_counts(readers):
     assert table_write_ms(ctx) == pytest.approx((700 + 2500) * 1e-6)
 
 
-def test_a_copy_of_a_donated_operand_shows_in_table_write_ms(readers):
+def test_the_three_kernels_are_counted_once_each(readers):
+    """The row-adagrad push as the chip runs it: the segment sum's kernel
+    is the combine's, the accumulator's and the table's are the write's,
+    each by its name and once (the accumulator's bitcast back to
+    ``f32[20000000]`` is of the accumulator's shape and costs nothing);
+    what XLA moved off VMEM before the accumulator's kernel and the step
+    are neither's."""
+    combine_ms, table_write_ms = readers
+    ctx = _ctx(tr.reduce_trace(_profile(
+        per_step=PULL + COMBINE + ACC + ROW_ADD)))
+    assert combine_ms(ctx) == pytest.approx(
+        (1300 + 94 + 240 + 385 + 42) * 1e-6)
+    assert table_write_ms(ctx) == pytest.approx((878 + 0.5 + 2006) * 1e-6)
+    busy = ctx.reduction.busy_ms_per_step
+    assert combine_ms(ctx) + table_write_ms(ctx) < busy
+
+
+def test_the_sum_cell_reads_both_with_no_accumulator(readers):
+    """``dlrm-criteo-emb.zipf`` runs the same combine and ``row_add``
+    with no accumulator: its shapes come from its own files."""
+    combine_ms, table_write_ms = readers
+    ctx = _ctx(tr.reduce_trace(_profile(per_step=PULL + COMBINE + ROW_ADD)),
+               SUM_CELL)
+    assert ctx.config["server_handle"] == "sum"
+    assert combine_ms(ctx) == pytest.approx(
+        (1300 + 94 + 240 + 385 + 42) * 1e-6)
+    assert table_write_ms(ctx) == pytest.approx(2006e-6)
+
+
+@pytest.mark.parametrize("per_step, wrote", [
+    (None, 700 + 2500), (PULL + COMBINE + ACC + ROW_ADD, 878 + 0.5 + 2006)])
+def test_a_copy_of_a_donated_operand_shows_in_table_write_ms(readers,
+                                                             per_step, wrote):
     _, table_write_ms = readers
     copy = ("%copy.9 = f32[20000000,128]" + T + " copy(f32[20000000,128] %st)",
             25_000)
-    ctx = _ctx(tr.reduce_trace(_profile(extra=[copy])))
-    assert table_write_ms(ctx) == pytest.approx((700 + 2500 + 25_000) * 1e-6)
+    ctx = _ctx(tr.reduce_trace(_profile(extra=[copy], per_step=per_step)))
+    assert table_write_ms(ctx) == pytest.approx((wrote + 25_000) * 1e-6)
 
 
 def test_nothing_is_read_without_a_device_plane(readers):
@@ -133,18 +208,24 @@ def test_nothing_is_read_without_a_device_plane(readers):
         assert read(ctx) is None
 
 
-def test_both_metrics_list_the_one_cell():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+def test_both_metrics_list_the_two_criteo_cells(bench_root):
+    with open(os.path.join(bench_root, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
     entries = {m["name"]: m for m in bench["per_layer"]}
+    cells = {CELL, SUM_CELL}
     for name in ("combine_ms", "table_write_ms"):
         m = entries[name]
-        assert m["workloads"] == [CELL] and m["source"] == "device_trace"
+        assert cells <= set(m["workloads"])
+        assert m["source"] == "device_trace"
         assert m["layer"] == "xla programs and kernels"
         assert m["moves"] == "step_p50" and m["unit"] == "ms"
-    cell = harness.load_cell(CELL)
-    assert {"combine_ms", "table_write_ms"} <= {
-        m["name"] for m in cell.per_layer}
-    for other in (w["name"] for w in bench["workloads"] if w["name"] != CELL):
-        assert not {"combine_ms", "table_write_ms"} & {
-            m["name"] for m in harness.load_cell(other).per_layer}
+    for cell in cells:
+        assert {"combine_ms", "table_write_ms"} <= {
+            m["name"] for m in harness.load_cell(
+                cell, root=bench_root).per_layer}
+    # Each reports them where it is listed, wherever else that is.
+    for w in bench["workloads"]:
+        names = {m["name"] for m in harness.load_cell(
+            w["name"], root=bench_root).per_layer}
+        for name in ("combine_ms", "table_write_ms"):
+            assert (name in names) == (w["name"] in entries[name]["workloads"])

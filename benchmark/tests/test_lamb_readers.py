@@ -56,10 +56,12 @@ def _profile(ops, steps=2):
 
 
 def _ctx(reduction):
+    cell = harness.load_cell(CELL)
     return harness.LayerContext(spans=[], compiles_in_window=0,
                                 reduction=reduction,
                                 least={"hbm": 1.0, "ici": 0.0},
-                                peaks={"hbm_gb_s": 819})
+                                peaks={"hbm_gb_s": 819},
+                                config=cell.config, traffic=cell.traffic)
 
 
 @pytest.fixture
@@ -88,7 +90,8 @@ def test_the_readers_find_nothing_to_read(readers):
 
 
 def test_the_cells_sizes_come_from_its_configuration():
-    sizes = lamb_ops.cell_sizes()
+    assert not hasattr(lamb_ops, "CONFIG")
+    sizes = lamb_ops.cell_sizes(harness.load_cell(CELL).config)
     assert sizes["keys"] == 398 and sizes["chips"] == 1
     assert lamb_ops.norm_shapes(398) == ("f32[398]", "f32[398,2]",
                                          "f32[796]")
@@ -117,10 +120,10 @@ def test_least_bytes_against_hand_sums():
     assert lamb_bytes.lamb_update(n, 1, 31254528) < 40 * n
 
 
-def test_the_loader_takes_the_new_entries():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+def test_the_loader_takes_the_new_entries(bench_root):
+    with open(os.path.join(bench_root, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
-    cell = harness.load_cell(CELL)
+    cell = harness.load_cell(CELL, root=bench_root)
     assert cell.chips == 1 and cell.traffic["driver"] == "dense_tree_push_pull"
     assert cell.config["server_handle"].startswith("lamb:")
     names = [m["name"] for m in cell.per_layer]
@@ -131,14 +134,16 @@ def test_the_loader_takes_the_new_entries():
     for name in ("combine_ms", "table_write_ms", "packed_write_ms"):
         assert name not in names
     assert harness.resolve(cell).__name__ == "Driver"
-    # The three metrics are this cell's alone, at the end of their list.
-    last = bench["per_layer"][-3:]
-    assert [m["name"] for m in last] == ["lamb_update_ms",
-                                         "lamb_update_roofline",
-                                         "lamb_norm_ms"]
-    assert all(m["workloads"] == [CELL] for m in last)
-    assert bench["workloads"][-1]["name"] == CELL
-    assert bench["configs"][-1]["name"] == "bert-large-lamb"
+    # The three metrics are this cell's alone, wherever they stand in
+    # their list; the cell and its configuration are there by name.
+    entries = {m["name"]: m for m in bench["per_layer"]}
+    for name in ("lamb_update_ms", "lamb_update_roofline", "lamb_norm_ms"):
+        assert entries[name]["workloads"] == [CELL]
+    assert [w["config"] for w in bench["workloads"] if w["name"] == CELL] \
+        == ["bert-large-lamb"]
+    assert [os.path.relpath(os.path.join(bench_root, c["file"]), ROOT)
+            for c in bench["configs"] if c["name"] == "bert-large-lamb"] \
+        == ["benchmark/configs/bert-large-lamb.json"]
     assert all(len(w["why"]) <= 200 for w in bench["workloads"])
     assert all(len(c["source"]) <= 200 for c in bench["configs"])
     assert os.path.exists(os.path.join(BENCH, "lamb_reference.py"))

@@ -35,10 +35,11 @@ def _driver_file(tmp_path, name, body):
 
 @pytest.mark.parametrize("workload",
                          [w["name"] for w in BENCHMARK["workloads"]])
-def test_every_workload_resolves_by_file(workload):
+def test_every_workload_resolves_by_file(workload, bench_root, no_boot):
     """No boot, no device: the configuration, the traffic, the driver's
-    file and class, and every reader the cell reports."""
-    cell = harness.load_cell(workload)
+    file and class, and every reader the cell reports; the same with an
+    entry of each kind appended to ``BENCHMARK.json``."""
+    cell = harness.load_cell(workload, root=bench_root)
     assert cell.config["server_handle"] and cell.config["limits"]
     driver = harness.resolve(cell)
     assert driver is harness.load_driver(cell.search,
@@ -50,6 +51,58 @@ def test_every_workload_resolves_by_file(workload):
     assert cell.per_layer
     for metric in cell.per_layer:
         assert callable(harness.load_reader(cell.search, metric["name"]))
+
+
+def test_an_appended_configuration_cell_and_metric_are_taken(appended_root,
+                                                            no_boot):
+    """What an addition PR brings (``conftest.py`` ``appended_root``: a
+    configuration, a cell and a per-layer metric, each the last of its
+    list, their files in a directory of ``paths`` of their own) loads by
+    name, and every cell that was there keeps every metric it had and
+    gains the one that lists no cell."""
+    with open(os.path.join(appended_root, "BENCHMARK.json")) as fh:
+        appended = json.load(fh)
+    for group in ("configs", "workloads", "per_layer"):
+        assert len(appended[group]) == len(BENCHMARK[group]) + 1
+    cell = harness.load_cell("appended-table.zipf", root=appended_root)
+    assert cell.config["name"] == "appended-table" and cell.chips == 1
+    assert cell.traffic["name"] == "appended-zipf"
+    assert harness.resolve(cell) is harness.load_driver(cell.search,
+                                                        "sparse_pull_push")
+    assert len(cell.search) == 2 and cell.search[0] == BENCH
+    names = [m["name"] for m in cell.per_layer]
+    assert "appended_steps" in names and "lamb_norm_ms" not in names
+    ctx = harness.LayerContext(spans=[(0.0, 0.1, 0.2)] * 3,
+                               compiles_in_window=0, reduction=None,
+                               least={}, peaks={})
+    assert harness.load_reader(cell.search, "appended_steps")(ctx) == 3.0
+    for w in BENCHMARK["workloads"]:
+        before = [m["name"] for m in harness.load_cell(w["name"]).per_layer]
+        after = [m["name"] for m in harness.load_cell(
+            w["name"], root=appended_root).per_layer]
+        assert after == before + ["appended_steps"]
+    with pytest.raises(KeyError, match="appended-table.zipf"):
+        harness.load_cell("appended-table.zipf")      # not in the committed
+
+
+def test_an_appended_counter_reports_in_the_cpu_rehearsal(appended_root):
+    """A tiny cell under the appended file, traced on the CPU: the metric a
+    later PR appended is read beside those that were there, and the
+    rehearsals' check of a run's metrics (``tiny.check_metrics``) takes
+    it, since it is named in the file."""
+    from tiny import check_metrics
+
+    ok, result = harness.run_cell(_cell("sparse", root=appended_root), 5, 0.3,
+                                  True, time.perf_counter(),
+                                  require_tpu=False)
+    assert ok
+    got = check_metrics(result, "per_layer",
+                        {"issue_ms", "wait_ms", "compiles_in_window",
+                         "appended_steps"}, root=appended_root)
+    assert "issue_exposed_ms" not in got     # no device plane, no clock
+    assert result["metrics"]["appended_steps"]["value"] >= 1
+    with pytest.raises(AssertionError):      # the committed file lacks it
+        check_metrics(result, "per_layer", {"issue_ms"})
 
 
 def test_a_traffic_file_naming_no_driver_file(tmp_path, no_boot):

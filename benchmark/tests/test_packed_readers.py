@@ -34,7 +34,8 @@ SCATTER_PUSH = [
     ("%fusion.3 = " + TABLE + T + " fusion(%st, %rows, %placed)", 3900),
 ]
 # A push by distinct physical row: placement, sort, permutation, segment
-# sum, the sort of the segments' rows, the kernel.
+# sum (XLA's scatter-add: before PR 32), the sort of the segments' rows,
+# the kernel.
 KERNEL_PUSH = [
     ("%copy.5 = f32[53248,128]" + T + " copy(%tiled)", 30),
     ("%compare_select_fusion = f32[53248,128]" + T + " fusion(%c, %s)", 45),
@@ -67,9 +68,11 @@ def _profile(push, steps=2):
 
 
 def _ctx(reduction):
+    cell = harness.load_cell(CELL)
     return harness.LayerContext(spans=[], compiles_in_window=0,
                                 reduction=reduction,
-                                least={"hbm": 1.0, "ici": 0.0}, peaks={})
+                                least={"hbm": 1.0, "ici": 0.0}, peaks={},
+                                config=cell.config, traffic=cell.traffic)
 
 
 @pytest.fixture
@@ -80,7 +83,9 @@ def readers():
 
 
 def test_shapes_follow_from_the_cells_sizes():
-    assert ops.cell_shapes() == {
+    ctx = _ctx(None)
+    assert not hasattr(ops, "CONFIG") and not hasattr(ops, "cell_shapes")
+    assert ops.shapes(ctx.config, ctx.traffic) == {
         "table": TABLE, "accumulator": "f32[54000000]",
         "batch_rows": "f32[53248,64]", "batch_phys_rows": "f32[53248,128]",
         "batch_ids": "s32[53248]", "batch_flags": "pred[53248]"}
@@ -102,6 +107,22 @@ def test_what_each_reader_counts_on_either_side(readers):
     assert packed_write_ms(ctx) == pytest.approx(930e-6)
     assert packed_combine_ms(ctx) == pytest.approx(
         (500 + 60 + 41 + 7 + 100 + 42 + 680 + 20) * 1e-6)
+
+
+def test_the_segment_sums_kernel_is_the_combines(readers):
+    """Since PR 32 the segment sum is a kernel of its own name: counted by
+    that name, once, as XLA's scatter-add was by its shape; ``row_add`` is
+    the write's by its name as by its shape, once."""
+    packed_write_ms, packed_combine_ms = readers
+    push = [(("%segment_sum.1 = (f32[53248,128]" + T + ", s32[1]{0})"
+              " custom-call(%sorted)", 154) if name.startswith("%fusion.5")
+             else (name, ns)) for name, ns in KERNEL_PUSH]
+    ctx = _ctx(tr.reduce_trace(_profile(push)))
+    assert packed_write_ms(ctx) == pytest.approx(930e-6)
+    assert packed_combine_ms(ctx) == pytest.approx(
+        (500 + 60 + 41 + 7 + 100 + 42 + 154 + 20) * 1e-6)
+    assert packed_write_ms(ctx) + packed_combine_ms(ctx) \
+        < ctx.reduction.busy_ms_per_step
 
 
 def test_a_copy_of_the_donated_table_shows_in_packed_write_ms(readers):
@@ -130,8 +151,8 @@ def test_nothing_is_read_without_a_device_plane_or_in_another_cell(readers):
         assert read(ctx) is None
 
 
-def test_both_metrics_list_the_one_cell():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+def test_both_metrics_list_the_one_cell(bench_root):
+    with open(os.path.join(bench_root, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
     entries = {m["name"]: m for m in bench["per_layer"]}
     for name in ("packed_write_ms", "packed_combine_ms"):
@@ -139,10 +160,11 @@ def test_both_metrics_list_the_one_cell():
         assert m["workloads"] == [CELL] and m["source"] == "device_trace"
         assert m["layer"] == "xla programs and kernels"
         assert m["moves"] == "step_p50" and m["unit"] == "ms"
-    cell = harness.load_cell(CELL)
+    cell = harness.load_cell(CELL, root=bench_root)
     names = {m["name"] for m in cell.per_layer}
     assert {"packed_write_ms", "packed_combine_ms"} <= names
     assert not {"combine_ms", "table_write_ms", "route_ms"} & names
     for other in (w["name"] for w in bench["workloads"] if w["name"] != CELL):
         assert not {"packed_write_ms", "packed_combine_ms"} & {
-            m["name"] for m in harness.load_cell(other).per_layer}
+            m["name"] for m in harness.load_cell(
+                other, root=bench_root).per_layer}

@@ -22,7 +22,7 @@ import pytest
 
 import harness
 from conftest import BENCH, HERE, ROOT
-from tiny import cell as _cell
+from tiny import cell as _cell, check_metrics
 
 def _run(kind, seed=7, seconds=0.3, trace=False, **kw):
     return harness.run_cell(_cell(kind), seed, seconds, trace,
@@ -36,8 +36,8 @@ def test_cell_end_to_end_on_four_devices(kind, capsys):
     assert ok and result["correct"] and result["failed"] == 0
     assert set(result) == {"correct", "attempted", "failed", "metrics",
                            "device"}
-    assert set(result["metrics"]) == {"goodput", "step_p50", "step_p95",
-                                      "setup_s"}
+    check_metrics(result, "end_to_end", {"goodput", "step_p50", "step_p95",
+                                         "setup_s"})
     assert all(m["value"] > 0 for m in result["metrics"].values())
     assert result["device"]["count"] == 4
     assert result["attempted"] >= 1
@@ -52,8 +52,8 @@ def test_traced_run_reports_what_it_can_read(kind):
     their metrics are left out; the host spans and the counter are read."""
     ok, result = _run(kind, trace=True)
     assert ok
-    assert set(result["metrics"]) == {"issue_ms", "wait_ms",
-                                      "compiles_in_window"}
+    check_metrics(result, "per_layer", {"issue_ms", "wait_ms",
+                                        "compiles_in_window"})
     assert result["metrics"]["compiles_in_window"]["value"] == 0
     assert "breakdown" not in result and "busy_s" not in result["device"]
 

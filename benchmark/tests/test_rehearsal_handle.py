@@ -17,6 +17,7 @@ import pytest
 
 import harness
 from conftest import BENCH, HERE, ROOT
+from tiny import check_metrics
 from tiny_handle import cell as _cell
 
 
@@ -42,8 +43,8 @@ def test_cell_end_to_end_on_four_devices(capsys):
     ok, result = _run("handle", seed=2**31 + 9)
     out = capsys.readouterr().out
     assert ok and result["correct"] and result["failed"] == 0
-    assert set(result["metrics"]) == {"goodput", "step_p50", "step_p95",
-                                      "setup_s"}
+    check_metrics(result, "end_to_end", {"goodput", "step_p50", "step_p95",
+                                         "setup_s"})
     assert result["device"]["count"] == 4 and result["attempted"] >= 1
     assert "0 compilations in the window" in out
     assert "compare first3_err" in out and "compare final_err" in out
@@ -60,8 +61,8 @@ def test_traced_run_on_a_cpu_reads_no_device_metric():
     device plane."""
     ok, result = _run("handle", trace=True)
     assert ok
-    assert set(result["metrics"]) == {"issue_ms", "wait_ms",
-                                      "compiles_in_window"}
+    check_metrics(result, "per_layer", {"issue_ms", "wait_ms",
+                                        "compiles_in_window"})
 
 
 def test_the_bf16_control_fails_both_numbers(capsys):
