@@ -153,6 +153,54 @@ _STAGES_OF = ((0, 4, None),   # KV_OP: route, dispatch, -1
 
 StageWindow = Tuple[Dict[str, Tuple[int, int]], int, float]
 
+# -- the occupancy account ---------------------------------------------------
+
+# What the HOST knows of the device, folded from the same notes: the process
+# is *starved* from the moment every op it launched has been waited for (the
+# count of launched-and-not-completed ops falls to 0 at ``t_end -
+# complete.copy`` of a ``COMPLETED`` note) until the next launch stage begins
+# (``t_end - launch`` of an ``ENGINE_OP`` note): ``starved.prelaunch``, all
+# of it the host's own Python with nothing on the device; and, counted
+# apart, through that launch stage to its end: ``starved.launch``, an upper
+# bound, since the device starts somewhere inside it.  The prelaunch part is
+# split by what the issuing thread was in: the stages of the op that ends
+# the spell (``prep``, ``select``, ``route``), the ``complete.copy`` of the
+# op that began it, and ``outside`` (the caller's own time, and the
+# microsecond between two layers' stamps).  It cannot hold the runtime's
+# wake-up: the spell begins when ``block_until_ready`` has returned.  Where
+# a caller issues many ops before it waits once, something is outstanding
+# all the while by this account, whatever the device does: there
+# ``ready_at_wait`` speaks, the ``COMPLETED`` notes whose ``complete.wait``
+# was under ``READY_NS`` (the result was there when first waited for).
+STARVED_PARTS = ("complete.copy", "route", "select", "prep", "outside")
+OCCUPANCY = ("starved.prelaunch", "starved.launch",
+             *("starved." + part for part in STARVED_PARTS),
+             "spells", "ready_at_wait", "resets")
+_OCC = 2 * len(STAGES)  # where the account begins in the totals' vector
+# A wait that found its result ready: 100 us lies 15 x above
+# ``block_until_ready`` on a finished array (6 us an op in the BERT cell,
+# ``wait_ms`` 2.83 over 467 ops) and 2.6 x under the fastest wake-up of a
+# wait that did block (0.26 ms; PERF.md, PR 36).
+READY_NS = 100_000
+# An op never waited for (an engine called with no ``KVWorker``; a caller
+# that drops its timestamps) has no ``COMPLETED`` note: it stops counting
+# once its launch ended this long before the first event of a fold.  4.3 s:
+# no op of this system runs for seconds (the longest step of the
+# benchmark's cells is 0.6 s), and the count is held as a stack, a
+# completion taking off the newest launch, so that a surplus stays the
+# oldest entry and ages out instead of looking fresh for ever.
+FORGET_NS = 1 << 32
+_READY, _RESETS = (_OCC + OCCUPANCY.index(name)
+                   for name in ("ready_at_wait", "resets"))
+# A spell's row: OCCUPANCY up to and with ``spells``.
+_PRELAUNCH, _LAUNCH = 0, 1
+_COPY, _ROUTE, _SELECT, _PREP, _OUTSIDE = (
+    OCCUPANCY.index("starved." + part) for part in STARVED_PARTS)
+_SPELLS = OCCUPANCY.index("spells")
+
+OccupancyWindow = Tuple[Dict[str, int], int, float]
+_NO_TIMES = np.empty(0, dtype=np.int64)
+
 
 class StageClock:
     """Host nanoseconds and calls per stage of the engine path, for the
@@ -177,6 +225,13 @@ class StageClock:
     per-layer readers) asks :meth:`window`.  Slots begin at whole
     multiples of their width; a stage is put down to the slot its op
     ended in.
+
+    From the same notes :meth:`fold` keeps the occupancy account
+    (``OCCUPANCY``, above): its totals ride in the same vector behind the
+    stages', so the slot marks carry them and :meth:`occupancy` answers
+    for a window as :meth:`window` does for the stages.  It is the
+    process's, as the clock is: several workers of one process share one
+    device set, and "nothing outstanding" means none of them has.
     """
 
     SLOT_SHIFT = 30
@@ -188,13 +243,21 @@ class StageClock:
         self.note = self._pending.append
         self.backlog = self._pending.__len__  # notes not yet folded
         self._fold_mu = threading.Lock()
-        self._totals = [0] * (2 * len(STAGES))  # ns, calls of each stage
+        # ns, calls of each stage; then the occupancy account
+        self._totals = [0] * (_OCC + len(OCCUPANCY))
         # slot -> the totals at its start
         self._marks: Dict[int, Tuple[int, ...]] = {}
         self._slot = -1  # the newest slot an op ended in
         self.programs_built = 0
         self.ops_bound = 0
         self.state_create_ns = 0
+        # The account's state from fold to fold: the launch ends of the
+        # ops counted outstanding (a stack, oldest first), the start of
+        # the open spell and what is left of the ``complete.copy`` that
+        # began it (-1: none is open), and the newest moment accounted.
+        self._open = _NO_TIMES
+        self._since, self._since_copy = -1, 0
+        self._horizon = 0
 
     def op_bound(self) -> None:
         """A dense bucket's first ``push_pull`` or ``push`` under a handle
@@ -224,6 +287,9 @@ class StageClock:
                 itertools.chain.from_iterable(take() for _ in range(n)),
                 dtype=np.int64, count=5 * n).reshape(n, 5)
             slots = rec[:, 1] >> self.SLOT_SHIFT
+            # A deque at its bound has dropped its oldest notes.
+            closing, spells = self._account(rec, dropped=n >= self.PENDING)
+            ended_in = slots[closing]
             for slot in np.unique(slots):  # ascending: a few at most
                 if slot != self._slot:
                     self._roll(int(slot))
@@ -235,6 +301,130 @@ class StageClock:
                             had = ns[:, col] >= 0
                             tot[2 * stage] += int(ns[had, col].sum())
                             tot[2 * stage + 1] += int(had.sum())
+                waits = of_slot[of_slot[:, 0] == COMPLETED, 2]
+                tot[_READY] += int((waits < READY_NS).sum())
+                # A spell is put down to the slot of the op that ended it.
+                if len(ended_in):
+                    ended = spells[ended_in == slot].sum(axis=0).tolist()
+                    for i, total in enumerate(ended):
+                        tot[_OCC + i] += total
+
+    def _account(self, rec, dropped: bool):
+        """The occupancy account over one fold's notes ``rec``: the rows
+        of the ``ENGINE_OP`` notes whose launch ended a starved spell, and
+        for each the spell's ``[prelaunch, launch, *STARVED_PARTS, 1]``.
+
+        Notes come from several threads and not in time order, and an
+        event lies before its note (a launch begins ``launch`` ns before
+        its op's ``t_end``), so the events are sorted here, and the
+        account's clock never runs backwards: an event older than what
+        the folds before have accounted (a ``COMPLETED`` note of the
+        ``kv-engine-complete`` thread whose copy outlasted the next op's
+        launch) takes effect at that horizon, or, while a spell is open,
+        at its start.  ``dropped`` (notes lost past ``PENDING``): the
+        account starts anew, with nothing outstanding and no spell open,
+        and counts that in ``resets``; it does not drift."""
+        if dropped:
+            self._open = _NO_TIMES
+            self._since = -1
+            self._totals[_RESETS] += 1
+        eng = np.flatnonzero(rec[:, 0] == ENGINE_OP)
+        done = np.flatnonzero(rec[:, 0] == COMPLETED)
+        if not len(eng) and not len(done):
+            return eng, np.zeros((0, _SPELLS + 1), dtype=np.int64)
+        # When a launch stage began, when a result was known.
+        t = np.concatenate((rec[eng, 1] - rec[eng, 4],
+                            rec[done, 1] - rec[done, 3]))
+        # An op never waited for is forgotten by the first fold whose
+        # events begin FORGET_NS after its launch ended.  Where it was
+        # the last one counted the account is as at the start: nobody
+        # knows when the device fell idle, so the next launch ends no
+        # spell.
+        alive = self._open >= t.min() - FORGET_NS
+        if not alive.all():
+            self._open = self._open[alive]
+        c0, since = len(self._open), self._since
+        t = np.maximum(t, since if not c0 and since >= 0 else self._horizon)
+        # When a launch stage ended; a completion's own moment again.
+        ends = t.copy()
+        ends[:len(eng)] = np.maximum(rec[eng, 1], t[:len(eng)])
+        step = np.ones(len(t), dtype=np.int64)
+        step[len(eng):] = -1
+        # By time, at one moment the launch first (one key: half the
+        # time of a lexsort).
+        order = np.argsort(2 * t - (step > 0), kind="stable")
+        t, step, ends = t[order], step[order], ends[order]
+        count = c0 + np.cumsum(step)
+        if count.min() < 0:
+            # A completion with nothing counted outstanding (its launch
+            # forgotten, or dropped) counts for nothing.
+            count -= np.minimum.accumulate(np.minimum(count, 0))
+        before = np.concatenate(((c0,), count[:-1]))
+        closes = np.flatnonzero((step > 0) & (before == 0))
+        opens = np.flatnonzero((step < 0) & (count == 0) & (before == 1))
+        starts = t[opens]  # and what its copy still had to run from there
+        copies = rec[done[order[opens] - len(eng)], 1] - starts
+        if not c0:
+            if since >= 0:  # the spell that was open ends in this fold
+                starts = np.concatenate(((since,), starts))
+                copies = np.concatenate(((self._since_copy,), copies))
+            else:  # the process's first launch ends no spell
+                closes = closes[1:]
+        closing = eng[order[closes]]  # rows of rec
+        k = len(closing)
+        spells = np.zeros((k, _SPELLS + 1), dtype=np.int64)
+        if k:
+            spells[:, _PRELAUNCH] = left = t[closes] - starts[:k]
+            spells[:, _LAUNCH] = ends[closes] - t[closes]
+            route, sparse = self._route_of(rec, closing)
+            select, prep = rec[closing, 2], rec[closing, 3]
+            # Back from the launch: the stage next to it (a sparse op
+            # runs prep, select; a dense one select, prep), the other,
+            # the route, what the copy still covered; the rest is none
+            # of the program's.
+            rows = np.arange(k)
+            for col, ns in ((np.where(sparse, _SELECT, _PREP),
+                             np.where(sparse, select, prep)),
+                            (np.where(sparse, _PREP, _SELECT),
+                             np.where(sparse, prep, select)),
+                            (_ROUTE, route), (_COPY, copies[:k])):
+                took = np.minimum(np.maximum(ns, 0), left)
+                spells[rows, col] = took
+                left = left - took
+            spells[:, _OUTSIDE] = left
+            spells[:, _SPELLS] = 1
+        # What stays counted: a launch stays while the count has not come
+        # back under its level (a completion takes off the newest).
+        if count[-1]:
+            low = np.minimum.accumulate(count[::-1])[::-1]
+            stays = (step > 0) & (low == count)
+            self._open = np.concatenate(
+                (self._open[:min(c0, int(count.min()))], ends[stays]))
+        else:
+            self._open = _NO_TIMES
+        self._horizon = max(self._horizon, int(t[-1]))
+        if len(starts) > k:
+            self._since, self._since_copy = int(starts[k]), int(copies[k])
+        elif len(self._open):
+            self._since = -1
+        return closing, spells
+
+    @staticmethod
+    def _route_of(rec, closing):
+        """For the ``ENGINE_OP`` notes at rows ``closing``: the ``route``
+        ns of the op's ``KV_OP`` note and whether it is a sparse op's
+        (no route).  The issuing thread makes the two notes 15 us apart,
+        so the op's ``KV_OP`` is the note next after its ``ENGINE_OP``,
+        with a dispatch that began (``t_end - dispatch``) when the engine
+        had ended; an engine called with no ``KVWorker`` has none, nor has
+        an op between whose two notes another thread's note or a fold
+        came: no route then (it falls to ``outside``), and the dense
+        order of stages."""
+        after = rec[np.minimum(closing + 1, len(rec) - 1)]
+        own = (after[:, 0] == KV_OP) & (closing + 1 < len(rec)) & (
+            after[:, 1] - after[:, 3] >= rec[closing, 1])
+        return (np.where(own, np.maximum(after[:, 2], 0), 0),
+                own & (after[:, 2] < 0))
 
     def _roll(self, slot: int) -> None:
         if slot < self._slot:
@@ -263,20 +453,51 @@ class StageClock:
             return tuple(self._totals)  # no op has ended since it started
         return mark
 
-    def window(self, t_lo: float, t_hi: float) -> StageWindow:
-        """``({stage: (ns, calls)}, slots, seconds)`` over the whole slots
-        inside ``[t_lo, t_hi]``, seconds on the ``time.perf_counter``
-        clock; 0 slots where none lies inside or a mark is gone."""
+    def _between(self, t_lo: float, t_hi: float):
+        """The totals' growth over the whole slots inside ``[t_lo,
+        t_hi]``, the slots and their seconds; 0 slots where none lies
+        inside or a mark is gone."""
         self.fold()
         width = 1 << self.SLOT_SHIFT
         first = (int(t_lo * 1e9) + width - 1) >> self.SLOT_SHIFT
         last = int(t_hi * 1e9) >> self.SLOT_SHIFT  # the slot t_hi is in
         lo, hi = self._mark(first), self._mark(last)
         if last <= first or lo is None or hi is None:
+            return (), 0, 0.0
+        return ([b - a for a, b in zip(lo, hi)], last - first,
+                (last - first) * width / 1e9)
+
+    def window(self, t_lo: float, t_hi: float) -> StageWindow:
+        """``({stage: (ns, calls)}, slots, seconds)`` over the whole slots
+        inside ``[t_lo, t_hi]``, seconds on the ``time.perf_counter``
+        clock; 0 slots where none lies inside or a mark is gone."""
+        grown, slots, seconds = self._between(t_lo, t_hi)
+        if not slots:
             return {}, 0, 0.0
-        return ({name: (hi[2 * i] - lo[2 * i], hi[2 * i + 1] - lo[2 * i + 1])
-                 for i, name in enumerate(STAGES)},
-                last - first, (last - first) * width / 1e9)
+        return ({name: (grown[2 * i], grown[2 * i + 1])
+                 for i, name in enumerate(STAGES)}, slots, seconds)
+
+    def occupancy_totals(self) -> Dict[str, int]:
+        """The occupancy account since the process started: nanoseconds
+        for the ``starved.*`` keys (the ``STARVED_PARTS`` add up to
+        ``starved.prelaunch`` exactly), counts for ``spells``,
+        ``ready_at_wait`` and ``resets``, and ``completed``, the
+        ``COMPLETED`` notes that ``ready_at_wait`` is a share of."""
+        self.fold()
+        return self._occupancy(self._totals)
+
+    @staticmethod
+    def _occupancy(vector) -> Dict[str, int]:
+        account = dict(zip(OCCUPANCY, vector[_OCC:]))
+        account["completed"] = vector[2 * STAGES.index("complete.wait") + 1]
+        return account
+
+    def occupancy(self, t_lo: float, t_hi: float) -> OccupancyWindow:
+        """:meth:`occupancy_totals` over the whole slots inside ``[t_lo,
+        t_hi]``, as :meth:`window` answers for the stages: ``(account,
+        slots, seconds)``."""
+        grown, slots, seconds = self._between(t_lo, t_hi)
+        return (self._occupancy(grown) if slots else {}), slots, seconds
 
     def export(self, registry) -> None:
         """Lazily sampled gauges in a node's ``Registry``, so
@@ -286,11 +507,13 @@ class StageClock:
                            fn=lambda name=name: self.totals()[name][0])
             registry.gauge(f"engine.stage.{name}.calls",
                            fn=lambda name=name: self.totals()[name][1])
+        for name in OCCUPANCY:
+            unit = ".ns" if name.startswith("starved.") else ""
+            registry.gauge(f"engine.occupancy.{name}{unit}",
+                           fn=lambda name=name:
+                           self.occupancy_totals()[name])
         registry.gauge("engine.programs.misses",
                        fn=lambda: self.programs_built)
-        registry.gauge(
-            "engine.programs.hits",
-            fn=lambda: self.totals()["select"][1] - self.programs_built)
         registry.gauge("engine.bound.misses", fn=lambda: self.ops_bound)
         registry.gauge("engine.state_create.s",
                        fn=lambda: self.state_create_ns / 1e9)
@@ -318,6 +541,12 @@ class _NullStageClock:
         return {}
 
     def window(self, t_lo: float, t_hi: float) -> StageWindow:
+        return {}, 0, 0.0
+
+    def occupancy_totals(self) -> Dict[str, int]:
+        return {}
+
+    def occupancy(self, t_lo: float, t_hi: float) -> OccupancyWindow:
         return {}, 0, 0.0
 
     def export(self, registry) -> None:

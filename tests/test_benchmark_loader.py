@@ -1,7 +1,9 @@
 """Every cell of ``BENCHMARK.json`` resolves by file, with nothing booted:
 the configuration, the traffic file, the driver's file and class, and every
 per-layer reader the cell reports (ISSUE 26's loader test, which
-``benchmark/tests/test_loader.py`` holds outside tier-1)."""
+``benchmark/tests/test_loader.py`` holds outside tier-1), and what an
+addition PR appends is taken by name (ISSUE 36's, whose first real case
+are the three metrics of the occupancy account that PR 37 appended)."""
 
 import json
 import os
@@ -101,3 +103,90 @@ def test_the_lane_packed_cell_names_its_table_traffic_and_driver(harness):
     # The sum cell's guarantees word for word, and one more of its own.
     emb = harness.load_cell("dlrm-criteo-emb.zipf").config
     assert config["guarantees"].startswith(emb["guarantees"])
+
+
+# What an addition PR may do: append entries, each at the end of its list,
+# and add files under ``paths``.  The occupancy account's three metrics
+# (PR 37) came that way.
+OCCUPANCY_METRICS = ("starved_prelaunch_ms", "starved_ms",
+                     "ready_at_wait_share")
+
+
+@pytest.mark.parametrize("name", OCCUPANCY_METRICS)
+def test_an_appended_metric_that_lists_no_cell_is_every_cells(name, harness):
+    entry, = [m for m in BENCHMARK["per_layer"] if m["name"] == name]
+    assert "workloads" not in entry
+    assert entry["layer"] == "app api and engine host side"
+    assert entry["moves"] == "step_p50" and entry["better"] == "lower"
+    assert entry["source"] in ("program_span", "program_counter")
+    for w in BENCHMARK["workloads"]:
+        cell = harness.load_cell(w["name"])
+        assert name in [m["name"] for m in cell.per_layer]
+    # A reader is given spans and finds the program's clock itself: with
+    # no spans it reads nothing and does not raise.
+    read = harness.load_reader(harness.search_dirs(), name)
+    ctx = harness.LayerContext(spans=[], compiles_in_window=0,
+                               reduction=None, least={}, peaks={})
+    assert read(ctx) is None
+
+
+def test_an_appended_configuration_cell_and_metric_are_taken(harness,
+                                                            tmp_path):
+    """The appended-entry case of ``benchmark/tests/test_loader.py`` (its
+    ``conftest.py`` ``appended_root``), inside tier-1: a root whose
+    ``BENCHMARK.json`` is the committed one with a configuration, a cell
+    and a per-layer metric appended, their files in a directory of
+    ``paths`` of their own.  They load by name, and every cell that was
+    there keeps every metric it had, in its order, and gains the one that
+    lists no cell."""
+    cells = os.path.join(BENCH, "tests", "cells")
+    extra = tmp_path / "extra"
+    for kind in ("configs", "traffic", "layer_metrics"):
+        (extra / kind).mkdir(parents=True)
+    for src, kind, name in (("tiny-sparse.json", "configs", "appended-table"),
+                            ("tiny-zipf.json", "traffic", "appended-zipf")):
+        with open(os.path.join(cells, src)) as fh:
+            data = json.load(fh)
+        (extra / kind / (name + ".json")).write_text(json.dumps(dict(
+            data, name=name, reduced=[], chips=1, source=__file__)))
+    (extra / "layer_metrics" / "appended_steps.py").write_text(
+        "def read(ctx):\n    return float(len(ctx.spans)) or None\n")
+    bench = json.loads(json.dumps(BENCHMARK))
+    bench["paths"] = [BENCH, str(extra)]
+    for config in bench["configs"]:
+        config["file"] = os.path.join(ROOT, config["file"])
+    bench["configs"].append({
+        "name": "appended-table", "source": __file__, "reduced": [],
+        "file": str(extra / "configs" / "appended-table.json"),
+        "why": "what an addition PR appends"})
+    bench["workloads"].append({
+        "name": "appended-table.zipf", "config": "appended-table",
+        "traffic": "appended-zipf", "chips": 1,
+        "why": "what an addition PR appends"})
+    bench["per_layer"].append({
+        "name": "appended_steps", "unit": "count", "better": "higher",
+        "source": "program_counter", "layer": "dense and sparse engines",
+        "moves": "goodput"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    root = str(tmp_path)
+
+    cell = harness.load_cell("appended-table.zipf", root=root)
+    assert cell.config["name"] == "appended-table" and cell.chips == 1
+    assert cell.traffic["name"] == "appended-zipf"
+    assert harness.resolve(cell) is harness.load_driver(cell.search,
+                                                        "sparse_pull_push")
+    assert cell.search == [BENCH, str(extra)]
+    names = [m["name"] for m in cell.per_layer]
+    assert "appended_steps" in names and "lamb_norm_ms" not in names
+    assert set(OCCUPANCY_METRICS) <= set(names)
+    ctx = harness.LayerContext(spans=[(0.0, 0.1, 0.2)] * 3,
+                               compiles_in_window=0, reduction=None,
+                               least={}, peaks={})
+    assert harness.load_reader(cell.search, "appended_steps")(ctx) == 3.0
+    for w in BENCHMARK["workloads"]:
+        before = [m["name"] for m in harness.load_cell(w["name"]).per_layer]
+        after = [m["name"] for m in harness.load_cell(
+            w["name"], root=root).per_layer]
+        assert after == before + ["appended_steps"]
+    with pytest.raises(KeyError, match="appended-table.zipf"):
+        harness.load_cell("appended-table.zipf")      # not in the committed
