@@ -362,7 +362,7 @@ class _Smoke:
         p = init.astype(np.float64)
         m, v = np.zeros(total), np.zeros(total)
         lr, b1, b2, eps, wd = LAMB.values()
-        before = eng.lamb_updates
+        before, pulls_before = eng.lamb_updates, eng.kernel_pulls
         for t in (1, 2):
             g = rng.standard_normal((W, total)).astype(np.float32)
             # Host-origin, then device-origin: at the keys' own length.
@@ -386,6 +386,14 @@ class _Smoke:
             np.testing.assert_allclose(pulled, p, atol=2e-6,
                                        err_msg=f"lamb step {t}")
         check(eng.lamb_updates - before == 2, "both ops ran under LAMB")
+        # One shard holds the bucket whole: lamb_apply wrote the pulled
+        # values itself; over several they are the gathered shards, cut.
+        np.testing.assert_array_equal(
+            pulled, np.asarray(eng.store_array("lamb_tree"))[:total],
+            err_msg="the pulled values are the store's")
+        check(eng.kernel_pulls - pulls_before
+              == (2 if eng.num_shards == 1 else 0),
+              "the kernel's pulled values on one shard and there alone")
         print(f"  {len(lens)} keys of {', '.join(f'{n:,}' for n in lens)} "
               f"values ({bucket.padded_len:,} padded) over {self.n_dev} "
               f"device(s): 2 steps under {LAMB_HANDLE} agree; trust ratios "

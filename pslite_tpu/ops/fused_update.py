@@ -13,7 +13,9 @@ Layout: flat vectors are zero-padded and reshaped to ``(rows, 128)`` with
 ``rows`` a multiple of the dtype's sublane tile (8 for 4-byte, 16 for
 2-byte dtypes), and the kernels use 2-D ``(block_rows, 128)`` BlockSpecs —
 rank-1 blocks and sub-tile blocks pass the interpreter but fail Mosaic
-lowering on real TPU hardware.
+lowering on real TPU hardware.  (The one rank-1 block here, the vector
+``lamb_apply`` leaves beside the store, is whole 1,024-element tiles of a
+vector the chip lays out in such: :func:`lamb_apply_pulls`.)
 
 Arithmetic runs in float32 whatever the bucket dtype and the result is
 rounded once on the store: the v5e vector and transcendental units have no
@@ -243,13 +245,19 @@ def _block_keys(starts_ref, blocks_ref, base_ref, body, init):
 
 
 def _lamb_call(name: str, kernel, prefetch, tiles, n_out: int, sums: int,
-               interpret: bool, row=None):
+               interpret: bool, row=None, pulled_len: int = 0):
     """One LAMB pass over ``tiles`` (flat, whole tiles long): the first
     ``n_out`` of them updated in place; with ``sums`` also an
     ``f32[sums]`` vector the kernel adds to in SMEM from tile to tile.
     ``row`` is one more input, ``[1, n]`` with ``n`` anywhere in the last
     tile: the kernel is handed ``(1, LAMB_TILE)`` of it a grid step, and
-    behind its end whatever lies there."""
+    behind its end whatever lies there.  ``pulled_len`` is the length of one
+    more result, the last and aliased to nothing, that may end anywhere in
+    the last tile too: a vector ``[pulled_len]`` of which the kernel writes
+    ``(LAMB_TILE,)`` a grid step, and what it writes behind the vector's
+    end goes nowhere.  (A vector and not a row ``[1, pulled_len]``: the chip
+    lays a row out in tiles of 128 and a vector in tiles of 1,024, so a
+    row reshaped to the vector a program returns is a copy of it.)"""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -269,6 +277,10 @@ def _lamb_call(name: str, kernel, prefetch, tiles, n_out: int, sums: int,
     if sums:
         out_shape.append(jax.ShapeDtypeStruct((sums,), jnp.float32))
         out_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+    if pulled_len:
+        assert n - LAMB_TILE < pulled_len <= n, (pulled_len, n)
+        out_shape.append(jax.ShapeDtypeStruct((pulled_len,), tiles[0].dtype))
+        out_specs.append(pl.BlockSpec((LAMB_TILE,), lambda i, *_: (i,)))
     outs = pl.pallas_call(
         kernel,
         out_shape=tuple(out_shape),
@@ -282,7 +294,8 @@ def _lamb_call(name: str, kernel, prefetch, tiles, n_out: int, sums: int,
         interpret=interpret,
         name=name,
     )(*prefetch, *tiles)
-    return tuple(o.reshape(-1) if o.ndim == 2 else o for o in outs)
+    return tuple(o.reshape(-1) if i < n_out else o
+                 for i, o in enumerate(outs))
 
 
 @functools.partial(jax.jit,
@@ -355,18 +368,37 @@ def lamb_moments(store, m, v, agg, step, starts, decay, blocks, base, *,
                       (m, v, store), 2, n_sums, interpret, row=agg)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("beta1", "beta2", "eps", "interpret"))
+def lamb_apply_pulls(total_len: int) -> bool:
+    """Whether :func:`lamb_apply` can leave the new parameters as a vector
+    of ``total_len`` besides: the kernel writes it in blocks of whole
+    1,024-element tiles, which is how the chip lays out a vector of more
+    than 512 elements; a shorter one lies in a single tile of its own
+    length, and Mosaic refuses the kernel (a cut of so few is nothing)."""
+    return total_len > _SUBLANES * _LANES // 2
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("beta1", "beta2", "eps", "interpret", "pulled_len"))
 def lamb_apply(store, m, v, step, starts, decay, scale, blocks, base, *,
                interpret: bool, beta1: float = 0.9, beta2: float = 0.999,
-               eps: float = 1e-6):
+               eps: float = 1e-6, pulled_len: int = 0):
     """LAMB's second pass: ``p -= scale[k] * u`` for key k's elements,
     ``scale[k] = lr * r_k`` and ``u`` as in :func:`lamb_moments` from the
-    new m and v; the store in place.  Padding keeps its value."""
+    new m and v; the store in place.  Padding keeps its value.
+
+    Returns ``(new_store, pulled)``.  With ``pulled_len`` (the bucket's
+    ``total_len``, anywhere in the shard's last tile;
+    :func:`lamb_apply_pulls`) ``pulled`` is the new parameters once more,
+    ``[pulled_len]`` in a buffer of its own: the pulled values of a bucket
+    that one shard holds whole, written from VMEM where every new ``p``
+    already is (4 B an element in place of the 8 B of a cut after the
+    kernel: the mirror of how :func:`lamb_moments` reads the gradient).
+    Without it ``pulled`` is None and the kernel has the one result."""
     scal = _bias_corrections(step, beta1, beta2)
 
     def kernel(scal_ref, base_ref, starts_ref, decay_ref, scale_ref,
-               blocks_ref, p_ref, m_ref, v_ref, out_p_ref):
+               blocks_ref, p_ref, m_ref, v_ref, out_p_ref, *pulled_ref):
         p = _f32(p_ref)
         d = _lamb_direction(scal_ref, _f32(m_ref), _f32(v_ref), eps)
 
@@ -376,9 +408,12 @@ def lamb_apply(store, m, v, step, starts, decay, scale, blocks, base, *,
 
         upd = _block_keys(starts_ref, blocks_ref, base_ref, pick,
                           jnp.zeros_like(p))
-        _store(out_p_ref, p - upd)
+        new_p = (p - upd).astype(out_p_ref.dtype)
+        out_p_ref[:, :] = new_p
+        for ref in pulled_ref:
+            ref[...] = new_p.reshape(LAMB_TILE)
 
-    (new_store,) = _lamb_call(
+    new_store, *pulled = _lamb_call(
         "lamb_apply", kernel, (scal, base, starts, decay, scale, blocks),
-        (store, m, v), 1, 0, interpret)
-    return new_store
+        (store, m, v), 1, 0, interpret, pulled_len=pulled_len)
+    return new_store, (pulled[0] if pulled else None)

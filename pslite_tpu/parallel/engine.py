@@ -401,6 +401,9 @@ class CollectiveEngine:
         self._counter_mu = threading.Lock()
         # Ops whose program applied LAMB (``engine.update.lamb``).
         self.lamb_updates = 0
+        # ... and whose pulled values its second kernel wrote
+        # (``engine.pull.from_kernel``).
+        self.kernel_pulls = 0
 
     # -- registration --------------------------------------------------------
 
@@ -603,7 +606,15 @@ class CollectiveEngine:
         known, on every shard.  The norms are over the key's own elements
         wherever they lie (shard and tile borders are not the keys') and
         never over the padding.  ``agg`` is a row, as
-        :func:`_aggregate_whole` leaves it."""
+        :func:`_aggregate_whole` leaves it.
+
+        ``fn`` takes one argument more, ``pulled_len``: with it
+        (``total_len``, where this shard holds the whole bucket:
+        :meth:`_kernel_pulls`) the second pass writes the new parameters
+        twice, in place and as a vector ``[pulled_len]`` of its own, and
+        ``fn`` returns that as a third value: the pulled values, which a
+        cut of the store after the kernel would read and write once
+        more."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -621,7 +632,7 @@ class CollectiveEngine:
         blocks = fused_update.lamb_blocks(starts, bucket.padded_len, S)
         kw = dict(beta1=b1, beta2=b2, eps=eps, interpret=interp)
 
-        def fn(store_l, state_l, agg):
+        def fn(store_l, state_l, agg, pulled_len=0):
             m_l, v_l, step_l = state_l
             step = step_l[0] + 1.0
             shard = lax.axis_index(axis)
@@ -636,10 +647,13 @@ class CollectiveEngine:
                 sq = lax.psum(sums.reshape(n_keys, 2), axis)
                 scale = (lr * _lamb_ratios(sq, adapt)).astype(jnp.float32)
             with jax.named_scope("ps.update.lamb.apply"):
-                new_store = fused_update.lamb_apply(
+                new_store, pulled = fused_update.lamb_apply(
                     store_l, new_m, new_v, step, starts, decay, scale,
-                    mine, base, **kw)
-            return new_store, (new_m, new_v, step_l + 1.0)
+                    mine, base, pulled_len=pulled_len, **kw)
+            new_state = (new_m, new_v, step_l + 1.0)
+            if pulled is None:
+                return new_store, new_state
+            return new_store, new_state, pulled
 
         return fn
 
@@ -1063,12 +1077,18 @@ class CollectiveEngine:
         pulled values at ``total_len``: what lies behind the last key is
         the program's business and no caller's.  Before or after the
         program, a pad or a cut is a launch and a copy of its own, of a
-        whole tree where the bucket is one."""
+        whole tree where the bucket is one.  Inside it the cut is that
+        copy too where one shard holds the bucket (the all-gather is the
+        identity): there a handle whose kernel can leave the pulled values
+        itself is asked to (:meth:`_kernel_pulls`), and what its function
+        hands back third is what the program returns."""
         import jax
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
         n_state, sfn = self._stateful_handle(handle_key, bucket)
+        if self._kernel_pulls(op, handle_key, bucket):
+            sfn = partial(sfn, pulled_len=bucket.total_len)
         axis = self.axis
         waxis = self.worker_axis
         store_spec = P(axis)
@@ -1104,8 +1124,10 @@ class CollectiveEngine:
             return (new_store, *new_state, new_store[:1])  # token last
 
         def _push_pull(store_l, *rest):
-            new_store, new_state = _updated(store_l, rest)
-            return (new_store, *new_state, cut(_gather(new_store, axis)))
+            new_store, new_state, *pulled = _updated(store_l, rest)
+            if not pulled:
+                pulled = [cut(_gather(new_store, axis))]
+            return (new_store, *new_state, *pulled)
 
         def _push_pull_zc(store_l, *rest):
             # In-place pull delivery: see _program's _push_pull_zc.
@@ -1521,7 +1543,8 @@ class CollectiveEngine:
             if own:
                 prep = self._prep_grads_whole
             if self._needs_segments(resolved):
-                prog = self._counted_lamb(prog)
+                prog = self._counted_lamb(
+                    prog, self._kernel_pulls(op, resolved, bucket))
         elif impl == "pallas":
             if self.worker_axis is None:
                 prep = self._prep_grads_ring
@@ -1553,12 +1576,30 @@ class CollectiveEngine:
         self._clock.op_bound()
         return bound
 
-    def _counted_lamb(self, prog: Callable) -> Callable:
-        """``prog`` behind the count of ``engine.update.lamb``: what a
-        record of :meth:`_bind` knows is counted by the record's own
-        program, and no other op pays for it."""
+    def _kernel_pulls(self, op: str, handle,
+                      bucket: Optional[DenseBucket]) -> bool:
+        """Whether the program of ``bucket`` under ``op`` and ``handle``
+        takes its pulled values from the update kernel: it returns them
+        (not the store in their place, and not nothing), the handle is
+        ``lamb`` (whose second pass can leave them,
+        ``fused_update.lamb_apply``), and one shard holds the whole
+        bucket.  Over several shards they are the all-gather of the
+        shards, cut at ``total_len``."""
+        from ..ops.fused_update import lamb_apply_pulls
+
+        return (op == "push_pull_st" and self.num_shards == 1
+                and bucket is not None and self._needs_segments(handle)
+                and lamb_apply_pulls(bucket.total_len))
+
+    def _counted_lamb(self, prog: Callable, kernel_pulls: bool) -> Callable:
+        """``prog`` behind the counts of ``engine.update.lamb`` and, where
+        the program takes its pulled values from ``lamb_apply``,
+        ``engine.pull.from_kernel``: what a record of :meth:`_bind` knows
+        is counted by the record's own program, and no other op pays for
+        it."""
         def counted(*args):
             self.lamb_updates += 1
+            self.kernel_pulls += kernel_pulls
             return prog(*args)
 
         return counted
@@ -1567,6 +1608,8 @@ class CollectiveEngine:
         """Lazily sampled gauges in a node's ``Registry``, beside the
         stage clock's (``docs/observability.md``, "Engine path")."""
         registry.gauge("engine.update.lamb", fn=lambda: self.lamb_updates)
+        registry.gauge("engine.pull.from_kernel",
+                       fn=lambda: self.kernel_pulls)
         registry.gauge(
             "engine.dense.segments",
             fn=lambda: sum(len(b.keys) for b in list(self._buckets.values())

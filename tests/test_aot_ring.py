@@ -532,3 +532,158 @@ def test_a_lane_packed_tables_pushes_at_full_size_write_it_with_row_add(
     assert mem.temp_size_in_bytes < 1 << 20
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 16e9
+
+
+# -- LAMB's pulled values from its second kernel (ops/fused_update.py) ----------
+
+
+def _lamb_program(devices, lens, op, dtype="float32", flags=None):
+    """(compiled, lowered text, total, padded) of the program of a bucket
+    with ``lens`` under ``lamb`` over ``devices`` (described, not
+    attached: the record alone, since registering would allocate)."""
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from pslite_tpu.parallel.engine import (CollectiveEngine, DenseBucket,
+                                            _padded_len)
+
+    handle = "lamb:1e-4,0.9,0.999,1e-6,0.01"
+    chips = len(devices)
+    mesh = Mesh(np.array(devices), ("kv",))
+    eng = CollectiveEngine(mesh=mesh, server_handle=handle)
+    assert not eng._interpret
+    lens = np.asarray(lens, dtype=np.int64)
+    total = int(lens.sum())
+    padded = _padded_len(total, chips, True)
+    dtype = jnp.dtype(dtype)
+    bucket = DenseBucket(
+        name="tree", keys=np.arange(len(lens), dtype=np.uint64), val_len=0,
+        dtype=dtype, total_len=total, padded_len=padded, lens=lens,
+        flags=(np.zeros(len(lens), np.int32) if flags is None else flags))
+    shard = NamedSharding(mesh, P("kv"))
+    vec = jax.ShapeDtypeStruct((padded,), dtype, sharding=shard)
+    slot = jax.ShapeDtypeStruct((chips,), jnp.float32, sharding=shard)
+    grads = jax.ShapeDtypeStruct(
+        (chips, total), dtype, sharding=NamedSharding(mesh, P("kv", None)))
+    lowered = eng._program(op, padded, dtype, handle, bucket).lower(
+        vec, vec, vec, slot, grads)
+    return lowered.compile(), lowered.as_text(), total, padded
+
+
+def _makers(text, shape):
+    """The opcodes of the operations of a compiled text whose first result
+    is ``shape``, parameters apart."""
+    import re
+
+    found = re.findall(
+        rf"^\s*(?:ROOT )?%[\w.\-]+ = \(?{re.escape(shape)}[{{,)\s]\S* "
+        rf"([\w\-]+)\(", text, flags=re.M)
+    return [opcode for opcode in found if opcode != "parameter"]
+
+
+def _bert_large_lens():
+    """The 398 tensors of ``bert-large-lamb`` and their flags, as the
+    cell's driver registers them."""
+    import fnmatch
+    import json
+    import sys
+
+    from pslite_tpu.parallel.engine import KEY_NO_ADAPT, KEY_NO_DECAY
+
+    bench = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark")
+    sys.path.insert(0, bench)
+    import buckets
+
+    with open(os.path.join(bench, "configs", "bert-large-lamb.json")) as fh:
+        config = json.load(fh)
+    tensors = buckets.expand_tensors(config["tensors"])
+    flags = np.array([
+        (KEY_NO_DECAY | KEY_NO_ADAPT)
+        if any(fnmatch.fnmatchcase(name, p)
+               for p in config["no_decay_no_adapt"]) else 0
+        for name, _ in tensors], dtype=np.int32)
+    return np.array([n for _, n in tensors], dtype=np.int64), flags
+
+
+def test_lamb_apply_writes_the_pulled_tree_at_full_size_on_one_chip(
+        v5e8_mesh):
+    """The program of ``bert-large-lamb.tree`` (what
+    ``benchmark/tests/test_compile_fullsize_lamb_pulled.py`` compiles): one
+    shard holds the bucket, and the second kernel's second result, a vector
+    of the tree's own length that ends in the middle of the last tile, is
+    the program's pulled result as it stands; nothing copies the tree."""
+    lens, flags = _bert_large_lens()
+    compiled, lowered, total, padded = _lamb_program(
+        v5e8_mesh.devices.flat[:1], lens, "push_pull_st", flags=flags)
+    assert (total, padded) == (336226108, 5131 * 65536)
+    assert lowered.count("tpu_custom_call") == 2
+    text = compiled.as_text()
+    rows = padded // 128
+    apply = [l for l in text.splitlines()
+             if l.lstrip().startswith("%lamb_apply")]
+    assert len(apply) == 1, apply
+    assert f"= (f32[{rows},128]" in apply[0]      # the store first, in place
+    assert f", f32[{total}]" in apply[0].split(" custom-call(")[0]
+    assert "ps.update.lamb.apply" in apply[0]
+    made = _makers(text, f"f32[{total}]")
+    assert made and set(made) <= {"get-tuple-element", "bitcast"}, made
+    assert "all-gather" not in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 3 * 4 * padded
+    assert mem.temp_size_in_bytes < 10**7
+    # Held: p, m, v, the gradient, and the pulled tree once.
+    args = 3 * 4 * padded + 4 * total + 4
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert args + 4 * total <= held < args + 4 * total + 10**7
+
+
+@pytest.mark.parametrize("op", ["push_pull_st", "push_st"])
+def test_lamb_elsewhere_at_full_size_has_the_one_result_kernel(
+        v5e8_mesh, op):
+    """Over four shards the pulled tree is the all-gather of the shards,
+    cut at the tree's length, and a push alone returns none: in both
+    ``lamb_apply`` has the store for its one result."""
+    lens, flags = _bert_large_lens()
+    chips = 4 if op == "push_pull_st" else 1
+    compiled, lowered, total, padded = _lamb_program(
+        v5e8_mesh.devices.flat[:chips], lens, op, flags=flags)
+    assert lowered.count("tpu_custom_call") == 2
+    text = compiled.as_text()
+    assert f"%lamb_apply.1 = f32[{padded // chips // 128},128]" in text
+    made = _makers(text, f"f32[{total}]")
+    if chips == 4:
+        assert "all-gather" in text
+        assert set(made) & {"slice", "fusion", "copy"}, made
+    else:
+        assert not made
+
+
+@pytest.mark.parametrize("lens, dtype", [
+    ([2 * 65536 - 1000, 1000], "float32"),     # ends on a tile's border
+    ([300, 700], "float32"),                   # shorter than a tile
+    ([300, 513 - 300], "float32"),             # the shortest it takes
+    ([30522, 100000], "bfloat16"),
+])
+def test_lamb_apply_leaves_the_pulled_vector(v5e8_mesh, lens, dtype):
+    compiled, _, total, padded = _lamb_program(
+        v5e8_mesh.devices.flat[:1], lens, "push_pull_st", dtype)
+    short = {"float32": "f32", "bfloat16": "bf16"}[dtype]
+    made = _makers(compiled.as_text(), f"{short}[{total}]")
+    assert made and set(made) <= {"get-tuple-element", "bitcast"}, made
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 16
+
+
+def test_a_vector_the_chip_lays_in_one_tile_is_cut_from_the_store(
+        v5e8_mesh):
+    """Up to 512 values XLA lays a vector out in a single tile of its own
+    length, which Mosaic refuses as a result of whole 1,024-element
+    blocks (``fused_update.lamb_apply_pulls``): the program keeps the
+    cut, and compiles."""
+    compiled, _, total, padded = _lamb_program(
+        v5e8_mesh.devices.flat[:1], [300, 212], "push_pull_st")
+    text = compiled.as_text()
+    assert f"%lamb_apply.1 = f32[{padded // 128},128]" in text
+    assert _makers(text, f"f32[{total}]")

@@ -263,6 +263,8 @@ def test_an_elementwise_handle_on_a_bucket_with_lens(shards, handle):
     assert not bound.cut and bound.prep == own._prep_grads_whole
     own.push("t", g)                      # the push alone, the same way
     assert not np.asarray(own._stores["t"])[TOTAL:].any()
+    # These handles' kernels leave no pulled values: the cut, as before.
+    assert (own.lamb_updates, own.kernel_pulls) == (0, 0)
 
 
 def test_worker_axis_and_kv_axis_apart():
@@ -307,6 +309,150 @@ def test_state_moves_to_another_number_of_shards():
     pulled = np.asarray(other.push_pull("t", g))
     ref.step(_split(g))
     assert _err(pulled, ref) < 2e-6
+
+
+# -- the pulled values as the second kernel leaves them ------------------------
+
+
+@pytest.mark.parametrize("total", [
+    2 * LAMB_TILE + 26428,      # ends in the middle of the last tile
+    2 * LAMB_TILE,              # ends on a tile's border
+    1000,                       # a bucket shorter than one tile
+])
+def test_lamb_apply_leaves_the_new_parameters_twice(total):
+    """With ``pulled_len`` the kernel's second result is its first cut at
+    that length, bit for bit, in a buffer of its own; the first is what
+    the kernel without it stores, and the padding keeps its value."""
+    import jax.numpy as jnp
+
+    from pslite_tpu.ops import fused_update
+
+    padded = -(-total // LAMB_TILE) * LAMB_TILE
+    lens = np.array([total - 300, 300])
+    starts = np.concatenate([[0], np.cumsum(lens)]).astype(np.int32)
+    rng = np.random.default_rng(total)
+    store = rng.normal(size=padded).astype(np.float32)   # padding not zero
+    m = rng.normal(size=padded).astype(np.float32)
+    v = rng.random(size=padded).astype(np.float32)
+    args = (jnp.asarray(store), jnp.asarray(m), jnp.asarray(v),
+            jnp.float32(3.0), jnp.asarray(starts),
+            jnp.full(2, 0.01, jnp.float32), jnp.full(2, 0.1, jnp.float32),
+            jnp.asarray(fused_update.lamb_blocks(starts, padded, 1)[0]),
+            jnp.zeros(1, jnp.int32))
+    new_store, pulled = fused_update.lamb_apply(*args, interpret=True,
+                                                pulled_len=total)
+    alone, none = fused_update.lamb_apply(*args, interpret=True)
+    assert none is None and pulled.shape == (total,)
+    assert pulled.unsafe_buffer_pointer() != new_store.unsafe_buffer_pointer()
+    new_store = np.asarray(new_store)
+    np.testing.assert_array_equal(new_store, np.asarray(alone))
+    np.testing.assert_array_equal(np.asarray(pulled), new_store[:total])
+    np.testing.assert_array_equal(new_store[total:], store[total:])
+    assert np.abs(new_store[:total] - store[:total]).min() > 0
+
+
+@pytest.mark.parametrize("mesh_shape, from_kernel", [
+    ((1,), True), ((4,), False), ((2, 1), True)])
+def test_where_one_shard_holds_the_bucket_the_kernel_writes_the_pulled_values(
+        mesh_shape, from_kernel):
+    """``push_pull`` returns the store's values up to ``total_len`` bit for
+    bit on either path; on one shard (under a worker axis too) they are
+    the kernel's own second result, counted by ``engine.pull.from_kernel``;
+    over four they are the gathered shards, and the count stays."""
+    from pslite_tpu.telemetry.metrics import Registry
+
+    devices = np.array(jax.devices()[:int(np.prod(mesh_shape))])
+    if len(mesh_shape) == 1:
+        eng = CollectiveEngine(mesh=Mesh(devices, ("kv",)),
+                               server_handle=HANDLE)
+    else:
+        eng = CollectiveEngine(
+            mesh=Mesh(devices.reshape(mesh_shape), ("dp", "kv")),
+            server_handle=HANDLE, worker_axis="dp")
+    registry = Registry()
+    eng.export(registry)
+    rng = np.random.default_rng(len(devices))
+    init = _init(rng)
+    eng.register_dense("t", KEYS, lens=LENS, flags=FLAGS, init=init)
+    ref = _reference(init)
+    for step in range(3):
+        g = rng.normal(size=(eng.num_workers, TOTAL)).astype(np.float32)
+        pulled = eng.push_pull("t", g)
+        ref.step(_split(g))
+        assert pulled.shape == (TOTAL,)
+        np.testing.assert_array_equal(
+            np.asarray(pulled), np.asarray(eng.store_array("t"))[:TOTAL])
+        assert _err(pulled, ref) < 2e-6, step
+    eng.push("t", g)            # a push alone pulls nothing
+    eng.pull("t")               # nor does a pull run the kernel
+    gauges = registry.snapshot()["gauges"]
+    assert gauges["engine.update.lamb"] == 4
+    assert gauges["engine.pull.from_kernel"] == (3 if from_kernel else 0)
+    assert not np.asarray(eng.store_array("t"))[TOTAL:].any()
+
+
+def test_one_shard_and_four_pull_the_same_values():
+    """The kernel's vector on one shard against the gathered shards on
+    four, the same store and gradients: within the file's tolerance (the
+    norms are summed in another order over four shards)."""
+    rng = np.random.default_rng(12)
+    init = _init(rng)
+    one = CollectiveEngine(mesh=_mesh(1), server_handle=HANDLE)
+    four = CollectiveEngine(mesh=_mesh(4), server_handle=HANDLE)
+    for eng in (one, four):
+        eng.register_dense("t", KEYS, lens=LENS, flags=FLAGS, init=init)
+    for step in range(3):
+        g = rng.normal(size=(1, TOTAL)).astype(np.float32)
+        spread = np.concatenate([g, np.zeros((3, TOTAL), np.float32)])
+        a = np.asarray(one.push_pull("t", g))
+        b = np.asarray(four.push_pull("t", spread))
+        assert np.max(np.abs(a - b)) < 2e-6, step
+    assert (one.kernel_pulls, four.kernel_pulls) == (3, 0)
+
+
+def test_a_push_alone_lowers_the_one_result_kernel():
+    """Whether the vector is made is a static argument of the one kernel:
+    the program of ``push`` has ``lamb_apply`` with the store for its one
+    result, that of ``push_pull`` on one shard with two."""
+    import re
+
+    eng = CollectiveEngine(mesh=_mesh(1), server_handle=HANDLE)
+    bucket = eng.register_dense("t", KEYS, lens=LENS, flags=FLAGS)
+    g = np.zeros((1, TOTAL), np.float32)
+    eng.push_pull("t", g)
+    _, state = eng.opt_state("t")
+    args = (eng.store_array("t"), *state,
+            jax.device_put(g, NamedSharding(eng.mesh, P(eng.axis, None))))
+
+    def results(op):
+        text = eng._program(op, bucket.padded_len, bucket.dtype, HANDLE,
+                            bucket).lower(*args).as_text()
+        (sig,) = re.findall(
+            r"func\.func private @lamb_apply\(.*?\) -> \(?(.*?)\)? \{", text)
+        return re.findall(r"tensor<[^>]*>", sig)
+
+    assert results("push_st") == [f"tensor<{bucket.padded_len}xf32>"]
+    assert results("push_pull_st") == [f"tensor<{bucket.padded_len}xf32>",
+                                       f"tensor<{TOTAL}xf32>"]
+
+
+def test_a_bucket_the_kernel_cannot_leave_a_vector_of_keeps_the_cut():
+    """Up to 512 values the chip lays a vector out in one tile of its own
+    length, which the kernel's blocks of whole tiles are not
+    (``fused_update.lamb_apply_pulls``): the program cuts the store, as it
+    does over several shards, and the count stays."""
+    lens, keys = np.array([300, 212]), KEYS[:2]
+    eng = CollectiveEngine(mesh=_mesh(1), server_handle=HANDLE)
+    rng = np.random.default_rng(13)
+    init = (0.02 * rng.normal(size=512)).astype(np.float32)
+    eng.register_dense("t", keys, lens=lens, init=init)
+    ref = LambReference([init[:300], init[300:]], np.zeros(2, np.int32),
+                        **parse_lamb_handle(HANDLE))
+    g = rng.normal(size=(1, 512)).astype(np.float32)
+    pulled = np.asarray(eng.push_pull("t", g))
+    ref.step([g[:, :300], g[:, 300:]])
+    assert pulled.shape == (512,) and _err(pulled, ref) < 2e-6
+    assert (eng.lamb_updates, eng.kernel_pulls) == (1, 0)
 
 
 # -- what is refused, each with a sentence -------------------------------------
