@@ -122,6 +122,42 @@ def deadline(name: str, seconds: float,
         timer.cancel()
 
 
+class _LambRecurrence:
+    """LAMB with bias correction in float64, key by key (``starts``,
+    ``flags`` as the engine has them): what the ``lamb`` and ``mixed``
+    phases hold the store to."""
+
+    def __init__(self, init, starts, flags):
+        from pslite_tpu.parallel.engine import KEY_NO_ADAPT, KEY_NO_DECAY
+
+        self.p = np.asarray(init, np.float64).copy()
+        self.m, self.v = np.zeros_like(self.p), np.zeros_like(self.p)
+        self.starts, self.t = starts, 0
+        self.no_decay = [bool(f & KEY_NO_DECAY) for f in flags]
+        self.no_adapt = [bool(f & KEY_NO_ADAPT) for f in flags]
+
+    def step(self, g) -> list:
+        """Apply ``g`` (``[W, total]``, summed over W); the keys' trust
+        ratios."""
+        lr, b1, b2, eps, wd = LAMB.values()
+        self.t += 1
+        t, p = self.t, self.p
+        gs = np.asarray(g, np.float64).sum(axis=0)
+        m = self.m = b1 * self.m + (1 - b1) * gs
+        v = self.v = b2 * self.v + (1 - b2) * gs * gs
+        ratios = []
+        for k in range(len(self.starts) - 1):
+            sl = slice(self.starts[k], self.starts[k + 1])
+            decay = 0.0 if self.no_decay[k] else wd
+            u = ((m[sl] / (1 - b1 ** t))
+                 / (np.sqrt(v[sl] / (1 - b2 ** t)) + eps) + decay * p[sl])
+            pn, un = np.linalg.norm(p[sl]), np.linalg.norm(u)
+            r = pn / un if not self.no_adapt[k] and pn > 0 and un > 0 else 1.0
+            ratios.append(r)
+            p[sl] -= lr * r * u
+        return ratios
+
+
 def _momentum_reference(agg_steps, n: int) -> np.ndarray:
     """The store after the ``sgd_momentum`` recurrence over the summed
     gradients ``agg_steps`` (float32, like the kernel)."""
@@ -148,6 +184,7 @@ class _Smoke:
             ("resnet50", 400, self.resnet50),
             ("readme", 150, self.readme),
             ("lamb", 150, self.lamb),
+            ("mixed", 150, self.mixed),
             ("sparse", 200, self.sparse),
             ("message_path", 30, self.message_path),
         ]
@@ -359,9 +396,7 @@ class _Smoke:
                                    init=init)
         check(kv._engine_route(keys, 0, lens) == "lamb_tree",
               "a call with the registered lens is the engine's")
-        p = init.astype(np.float64)
-        m, v = np.zeros(total), np.zeros(total)
-        lr, b1, b2, eps, wd = LAMB.values()
+        ref = _LambRecurrence(init, starts, flags)
         before, pulls_before = eng.lamb_updates, eng.kernel_pulls
         for t in (1, 2):
             g = rng.standard_normal((W, total)).astype(np.float32)
@@ -369,21 +404,8 @@ class _Smoke:
             sent = g if t == 1 else jnp.asarray(g)
             pulled = np.asarray(
                 eng.push_pull("lamb_tree", sent, LAMB_HANDLE))
-            gs = g.astype(np.float64).sum(axis=0)
-            m = b1 * m + (1 - b1) * gs
-            v = b2 * v + (1 - b2) * gs * gs
-            ratios = []
-            for k in range(len(lens)):
-                sl = slice(starts[k], starts[k + 1])
-                decay = 0.0 if flags[k] & KEY_NO_DECAY else wd
-                u = ((m[sl] / (1 - b1 ** t))
-                     / (np.sqrt(v[sl] / (1 - b2 ** t)) + eps) + decay * p[sl])
-                pn, un = np.linalg.norm(p[sl]), np.linalg.norm(u)
-                r = (pn / un if not flags[k] & KEY_NO_ADAPT
-                     and pn > 0 and un > 0 else 1.0)
-                ratios.append(r)
-                p[sl] -= lr * r * u
-            np.testing.assert_allclose(pulled, p, atol=2e-6,
+            ratios = ref.step(g)
+            np.testing.assert_allclose(pulled, ref.p, atol=2e-6,
                                        err_msg=f"lamb step {t}")
         check(eng.lamb_updates - before == 2, "both ops ran under LAMB")
         # One shard holds the bucket whole: lamb_apply wrote the pulled
@@ -397,6 +419,82 @@ class _Smoke:
         print(f"  {len(lens)} keys of {', '.join(f'{n:,}' for n in lens)} "
               f"values ({bucket.padded_len:,} padded) over {self.n_dev} "
               f"device(s): 2 steps under {LAMB_HANDLE} agree; trust ratios "
+              + ", ".join(f"{r:.3f}" for r in ratios))
+
+    def mixed(self) -> None:
+        """Two steps of ``lamb`` on a bucket whose job's dtype (bfloat16)
+        is narrower than its store's (float32), the keys the ``lamb``
+        phase's: the f32 store against the float64 recurrence fed the bf16
+        gradients widened, the pulled bf16 values bit-equal to the store's
+        rounding (from ``lamb_apply`` itself on one chip, from the rounded
+        shards gathered on several), m and v f32, ``pull`` alone, and the
+        refusals: a gradient of another dtype, a stateless handle."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from pslite_tpu.ops.fused_update import LAMB_TILE
+        from pslite_tpu.parallel.engine import KEY_NO_ADAPT, KEY_NO_DECAY
+        from pslite_tpu.utils import logging as log
+
+        eng = self.kv.engine
+        W = eng.num_workers
+        bf16 = np.dtype(jnp.bfloat16)
+        lens = np.array([3, 30522, LAMB_TILE * self.n_dev + 77, 1000, 2])
+        flags = np.array([0, 0, 0, KEY_NO_DECAY | KEY_NO_ADAPT, 0])
+        keys = np.arange(6000, 6000 + len(lens), dtype=np.uint64)
+        starts = np.concatenate([[0], np.cumsum(lens)])
+        total = int(lens.sum())
+        rng = np.random.default_rng(SEED + 7)
+        init = (0.02 * rng.standard_normal(total)).astype(np.float32)
+        bucket = self.kv.register_dense(
+            "mixed_tree", keys, lens=lens, flags=flags, init=init,
+            dtype=jnp.float32, job_dtype=jnp.bfloat16)
+        check(bucket.mixed and bucket.nbytes == 2 * total,
+              "the bucket counts the job's bytes")
+        ref = _LambRecurrence(init, starts, flags)
+        narrow, pulls = eng.narrow_ops, eng.kernel_pulls
+
+        def held(pulled, what):
+            check(pulled.dtype == bf16 and pulled.shape == (total,), what)
+            store = np.asarray(eng.store_array("mixed_tree"))[:total]
+            np.testing.assert_array_equal(
+                np.asarray(pulled).view(np.uint16),
+                store.astype(bf16).view(np.uint16),
+                err_msg=f"{what}: pulled is not the store rounded")
+            return store
+
+        for t in (1, 2):
+            g = rng.standard_normal((W, total)).astype(bf16)
+            # Host-origin rows, then the same rows on the device.
+            sent = g if t == 1 else jax.device_put(
+                g, NamedSharding(eng.mesh, P(eng.axis)))
+            pulled = eng.push_pull("mixed_tree", sent, LAMB_HANDLE)
+            ratios = ref.step(g.astype(np.float32))
+            store = held(pulled, f"mixed step {t}")
+            np.testing.assert_allclose(store, ref.p, atol=2e-6,
+                                       err_msg=f"mixed step {t}")
+        held(eng.pull("mixed_tree"), "pull alone")
+        kind, (m, v, slot) = eng.opt_state("mixed_tree")
+        check(m.dtype == v.dtype == jnp.float32, "m and v stay f32")
+        check(eng.narrow_ops - narrow == 3, "three ops counted as narrow")
+        check(eng.kernel_pulls - pulls == (2 if eng.num_shards == 1 else 0),
+              "the kernel's own bf16 pulled values on one shard")
+        for what, call in (
+                ("an f32 device gradient", lambda: eng.push_pull(
+                    "mixed_tree", jnp.zeros((W, total), jnp.float32),
+                    LAMB_HANDLE)),
+                ("a stateless handle", lambda: eng.push_pull(
+                    "mixed_tree", g, RING_HANDLE))):
+            try:
+                call()
+            except log.CheckError as exc:
+                check("'mixed_tree'" in str(exc), f"{what}: no bucket named")
+            else:
+                check(False, f"{what} was not refused")
+        print(f"  {len(lens)} keys ({total:,} values) pushed and pulled in "
+              f"bfloat16 over an f32 store on {self.n_dev} device(s): 2 "
+              f"steps agree, pulled == rounded store bit for bit, ratios "
               + ", ".join(f"{r:.3f}" for r in ratios))
 
     # -- sparse plane ---------------------------------------------------------

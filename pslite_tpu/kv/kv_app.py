@@ -599,7 +599,11 @@ class KVWorker:
         (``'int8'``, ``'fp8_e4m3'``, ``'bf16'``) unless the call
         overrides with ``codec=`` (``codec='raw'`` forces uncompressed).
         ``codec=None`` unregisters.  Message-path only — the collective
-        (ICI) plane needs no wire compression and ignores it."""
+        (ICI) plane needs no wire compression and ignores it.  What
+        ``codec='bf16'`` is to this path, a dense bucket's ``job_dtype``
+        (:meth:`register_dense`) is to the engine's: the same contract by
+        two routes, values rounded to 16 bits on the way and an f32 store
+        that no rounding reaches."""
         keys = np.ascontiguousarray(np.asarray(keys, dtype=np.uint64))
         log.check(len(keys) > 0, "register_bucket: empty key set")
         if codec is not None:
@@ -841,7 +845,8 @@ class KVWorker:
     # -- ICI collective fast path -------------------------------------------
 
     def register_dense(self, name: str, keys, val_len: Optional[int] = None,
-                       dtype=None, init=None, lens=None, flags=None):
+                       dtype=None, init=None, lens=None, flags=None,
+                       job_dtype=None):
         """Register a dense bucket on the collective engine; subsequent
         push/pull on exactly these keys ride jitted ICI collectives.  The
         analog of the reference's first-touch rendezvous + registration
@@ -852,7 +857,20 @@ class KVWorker:
         keys that carries no ``lens``, or the registered ones, is then the
         engine's; ``flags`` (a word a key, ``parallel.engine.KEY_NO_DECAY``
         / ``KEY_NO_ADAPT``) is read by a server handle that treats keys
-        apart (``lamb:...``)."""
+        apart (``lamb:...``).
+
+        ``job_dtype`` (default ``dtype``): what this job pushes and what
+        ``push_pull`` / ``pull`` hand back, where it is narrower than the
+        store: ``dtype=float32, job_dtype=bfloat16`` keeps f32 master
+        parameters and moments on the server under a bf16 model.  A
+        gradient is widened exactly and summed over W in f32; a pulled
+        value is the stored one rounded to nearest-even.  The gradient is
+        rows ``[W, total]`` as for any bucket, of ``job_dtype`` or
+        refused (a device array of another shape too: nothing is laid out
+        anew on the way); so is a call that would leave the engine for the
+        message path (a custom ``cmd``).  An ``out`` of another dtype is
+        given the values converted, as for any bucket.  See
+        ``CollectiveEngine.register_dense``."""
         log.check(self.engine is not None,
                   "register_dense requires the ici van")
         keys = np.ascontiguousarray(np.asarray(keys, dtype=np.uint64))
@@ -865,8 +883,11 @@ class KVWorker:
             self._dense_routes.pop(
                 (len(old.keys), old.keys.item(0), old.keys.item(-1)), None)
         bucket = engine.register_dense(name, keys, val_len, dtype=dtype,
-                                       init=init, lens=lens, flags=flags)
+                                       init=init, lens=lens, flags=flags,
+                                       job_dtype=job_dtype)
         self._dense_routes[(len(keys), keys.item(0), keys.item(-1))] = name
+        self._routes_mixed = any(
+            engine.bucket(n).mixed for n in self._dense_routes.values())
         # From the buckets registered now: registering a small bucket in a
         # large one's place lifts it again.
         self._results_heavy = any(
@@ -908,7 +929,11 @@ class KVWorker:
         keys' own lengths must be those lengths."""
         engine = self.engine
         n = len(keys)
-        if engine is None or n == 0 or cmd != 0:
+        if engine is None or n == 0:
+            return None
+        if cmd != 0:
+            if self._routes_mixed:
+                self._refuse_mixed_off_engine(keys, f"a custom cmd ({cmd})")
             return None
         name = self._dense_routes.get((n, keys.item(0), keys.item(-1)))
         if name is None:
@@ -926,6 +951,22 @@ class KVWorker:
                       f"this call carries: a registered key keeps its "
                       f"length (register the bucket again to change it)")
         return name
+
+    _routes_mixed = False  # a registered dense bucket has a job dtype
+
+    def _refuse_mixed_off_engine(self, keys: np.ndarray, why: str) -> None:
+        """A call on the keys of a mixed bucket that the engine path cannot
+        take would go to the message path, whose servers know nothing of
+        the bucket's f32 store: refused by name."""
+        name = self._dense_routes.get(
+            (len(keys), keys.item(0), keys.item(-1)))
+        if name is None:
+            return
+        bucket = self.engine.bucket(name)
+        if bucket.mixed and np.array_equal(bucket.keys, keys):
+            self.engine._refuse_mixed(
+                bucket, f"the message path (this call carries {why})",
+                "call push_pull, push or pull with cmd 0")
 
     # Device results kept for get_pulled(): the last 8.  While a dense
     # bucket is registered of which 8 pulled copies would pass
@@ -1030,11 +1071,15 @@ class KVWorker:
             if handle is None and keys is not None:
                 # A dense op runs under the engine's own handle.
                 handle = self.engine._server_handle
+            meta = {"ts": ts, "name": name}
             if isinstance(handle, str):
-                span.set_metadata(ts=ts, name=name,
-                                  handle=handle.partition(":")[0])
-            else:
-                span.set_metadata(ts=ts, name=name)
+                meta["handle"] = handle.partition(":")[0]
+            bucket = (self.engine._buckets.get(name)
+                      if keys is not None else None)
+            if bucket is not None and bucket.mixed:
+                # Pushed and pulled in another dtype than it is kept.
+                meta["job"] = str(bucket.job_dtype)
+            span.set_metadata(**meta)
             span.__exit__(None, None, None)
         return ts
 

@@ -43,9 +43,12 @@ _SUBLANES = 8
 _MAX_BLOCK_ROWS = 512  # (512, 128) fp32 block = 256 KiB per operand
 
 
-def _tile_geometry(n: int, dtype):
-    """(padded_len, block_rows, grid) for a flat length n of ``dtype``."""
-    sublanes = _SUBLANES * max(1, 4 // jnp.dtype(dtype).itemsize)
+def _tile_geometry(n: int, *dtypes):
+    """(padded_len, block_rows, grid) for a flat length n of ``dtypes``:
+    the narrowest of them sets the sublane tile (a gradient may be
+    narrower than the state it updates)."""
+    sublanes = _SUBLANES * max(
+        max(1, 4 // jnp.dtype(dt).itemsize) for dt in dtypes)
     rows0 = -(-n // _LANES)
     block_rows = min(_MAX_BLOCK_ROWS, -(-rows0 // sublanes) * sublanes)
     rows = -(-rows0 // block_rows) * block_rows
@@ -79,7 +82,7 @@ def _elementwise_call(name: str, kernel, state, agg, interpret: bool,
     from jax.experimental.pallas import tpu as pltpu
 
     n = state[0].shape[0]
-    padded, block_rows, grid = _tile_geometry(n, state[0].dtype)
+    padded, block_rows, grid = _tile_geometry(n, state[0].dtype, agg.dtype)
     tiles = [_to_tiles(x, padded) for x in (*state, agg)]
     n_prefetch = 0 if scalars is None else 1
     # Index maps receive the prefetched scalar ref as a trailing argument.
@@ -245,7 +248,8 @@ def _block_keys(starts_ref, blocks_ref, base_ref, body, init):
 
 
 def _lamb_call(name: str, kernel, prefetch, tiles, n_out: int, sums: int,
-               interpret: bool, row=None, pulled_len: int = 0):
+               interpret: bool, row=None, pulled_len: int = 0,
+               pulled_dtype=None):
     """One LAMB pass over ``tiles`` (flat, whole tiles long): the first
     ``n_out`` of them updated in place; with ``sums`` also an
     ``f32[sums]`` vector the kernel adds to in SMEM from tile to tile.
@@ -253,7 +257,8 @@ def _lamb_call(name: str, kernel, prefetch, tiles, n_out: int, sums: int,
     tile: the kernel is handed ``(1, LAMB_TILE)`` of it a grid step, and
     behind its end whatever lies there.  ``pulled_len`` is the length of one
     more result, the last and aliased to nothing, that may end anywhere in
-    the last tile too: a vector ``[pulled_len]`` of which the kernel writes
+    the last tile too: a vector ``[pulled_len]`` (of ``pulled_dtype``; the
+    first tile's where none is given) of which the kernel writes
     ``(LAMB_TILE,)`` a grid step, and what it writes behind the vector's
     end goes nowhere.  (A vector and not a row ``[1, pulled_len]``: the chip
     lays a row out in tiles of 128 and a vector in tiles of 1,024, so a
@@ -279,7 +284,8 @@ def _lamb_call(name: str, kernel, prefetch, tiles, n_out: int, sums: int,
         out_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     if pulled_len:
         assert n - LAMB_TILE < pulled_len <= n, (pulled_len, n)
-        out_shape.append(jax.ShapeDtypeStruct((pulled_len,), tiles[0].dtype))
+        out_shape.append(jax.ShapeDtypeStruct(
+            (pulled_len,), pulled_dtype or tiles[0].dtype))
         out_specs.append(pl.BlockSpec((LAMB_TILE,), lambda i, *_: (i,)))
     outs = pl.pallas_call(
         kernel,
@@ -312,7 +318,12 @@ def lamb_moments(store, m, v, agg, step, starts, decay, blocks, base, *,
     is a copy of the whole gradient (5.2 ms of a 26 ms step at 336 M
     values, PERF.md, PR 33).  The kernel takes ``(1, LAMB_TILE)`` of the
     row a grid step, folds it to ``(rows, 128)`` in VMEM and reads zeros
-    behind the row's end.
+    behind the row's end.  The row may be narrower than the state (a bf16
+    job's gradient over an f32 store): it is widened here in VMEM and by
+    no pass before the kernel.  (The chip lays a single row of a 2-byte
+    type out in tiles of two rows, ``T(2,128)(2,1)``, half of each
+    padding, so the bf16 row is held and read at the f32 row's bytes;
+    PERF.md, PR 41, has what a packed vector read instead.)
 
     ``m = b1*m + (1-b1)*g; v = b2*v + (1-b2)*g*g`` (computed as
     ``m + (1-b1)*(g-m)``, which settles at g whatever f32 makes of the
@@ -340,6 +351,8 @@ def lamb_moments(store, m, v, agg, step, starts, decay, blocks, base, *,
 
             lax.fori_loop(0, n_sums, zero, 0)
 
+        # Widened, then folded: the fold is of f32 tiles whatever the
+        # row's dtype.
         g = g_ref[...].astype(jnp.float32).reshape(_MAX_BLOCK_ROWS, _LANES)
         if ragged:
             left = agg.shape[1] - pl.program_id(0) * LAMB_TILE
@@ -372,17 +385,19 @@ def lamb_apply_pulls(total_len: int) -> bool:
     """Whether :func:`lamb_apply` can leave the new parameters as a vector
     of ``total_len`` besides: the kernel writes it in blocks of whole
     1,024-element tiles, which is how the chip lays out a vector of more
-    than 512 elements; a shorter one lies in a single tile of its own
-    length, and Mosaic refuses the kernel (a cut of so few is nothing)."""
+    than 512 elements, of 4 bytes or of 2; a shorter one lies in a single
+    tile of its own length, and Mosaic refuses the kernel (a cut of so few
+    is nothing)."""
     return total_len > _SUBLANES * _LANES // 2
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("beta1", "beta2", "eps", "interpret", "pulled_len"))
+    static_argnames=("beta1", "beta2", "eps", "interpret", "pulled_len",
+                     "pulled_dtype"))
 def lamb_apply(store, m, v, step, starts, decay, scale, blocks, base, *,
                interpret: bool, beta1: float = 0.9, beta2: float = 0.999,
-               eps: float = 1e-6, pulled_len: int = 0):
+               eps: float = 1e-6, pulled_len: int = 0, pulled_dtype=None):
     """LAMB's second pass: ``p -= scale[k] * u`` for key k's elements,
     ``scale[k] = lr * r_k`` and ``u`` as in :func:`lamb_moments` from the
     new m and v; the store in place.  Padding keeps its value.
@@ -393,8 +408,12 @@ def lamb_apply(store, m, v, step, starts, decay, scale, blocks, base, *,
     ``[pulled_len]`` in a buffer of its own: the pulled values of a bucket
     that one shard holds whole, written from VMEM where every new ``p``
     already is (4 B an element in place of the 8 B of a cut after the
-    kernel: the mirror of how :func:`lamb_moments` reads the gradient).
-    Without it ``pulled`` is None and the kernel has the one result."""
+    kernel: the mirror of how :func:`lamb_moments` reads the gradient),
+    and of ``pulled_dtype`` where the job's parameters are narrower than
+    the store: each the stored value rounded to nearest-even, 2 B an
+    element where a narrowing pass after the kernel reads 4 and writes 2.
+    Without ``pulled_len`` ``pulled`` is None and the kernel has the one
+    result."""
     scal = _bias_corrections(step, beta1, beta2)
 
     def kernel(scal_ref, base_ref, starts_ref, decay_ref, scale_ref,
@@ -411,9 +430,10 @@ def lamb_apply(store, m, v, step, starts, decay, scale, blocks, base, *,
         new_p = (p - upd).astype(out_p_ref.dtype)
         out_p_ref[:, :] = new_p
         for ref in pulled_ref:
-            ref[...] = new_p.reshape(LAMB_TILE)
+            ref[...] = new_p.astype(ref.dtype).reshape(LAMB_TILE)
 
     new_store, *pulled = _lamb_call(
         "lamb_apply", kernel, (scal, base, starts, decay, scale, blocks),
-        (store, m, v), 1, 0, interpret, pulled_len=pulled_len)
+        (store, m, v), 1, 0, interpret, pulled_len=pulled_len,
+        pulled_dtype=pulled_dtype)
     return new_store, (pulled[0] if pulled else None)

@@ -52,6 +52,12 @@ class DenseBucket:
     key's own length instead, ``keys[i]`` owning ``lens[i]`` values from
     ``starts[i]``, and a flag word a key (``KEY_NO_DECAY``,
     ``KEY_NO_ADAPT``) for a handle that treats keys apart (``lamb``).
+
+    ``dtype`` is the store's, the optimizer state's and every norm's;
+    ``job_dtype`` what the job pushes and is handed back (one field: a
+    job's gradients and parameters are of one dtype), the store's unless
+    registered narrower (``mixed``: bf16 gradients in, f32 master
+    parameters and moments, bf16 parameters out).
     """
 
     name: str
@@ -62,15 +68,22 @@ class DenseBucket:
     padded_len: int  # see _padded_len
     lens: Optional[np.ndarray] = None  # int64 a key
     flags: Optional[np.ndarray] = None  # int32 a key, with ``lens``
-    # Application bytes one push (or one pull) moves: the byte counters' unit.
+    job_dtype: object = None  # np.dtype; None: the store's
+    # Application bytes one push (or one pull) moves, in the job's dtype:
+    # the byte counters' unit.
     nbytes: int = field(init=False)
+    # The job's dtype is not the store's.
+    mixed: bool = field(init=False, default=False)
     # Where each key begins in the flat vector (one more entry closes the
     # last), and what tells these segments from any other bucket's.
     starts: Optional[np.ndarray] = field(init=False, default=None)
     segments_key: Optional[bytes] = field(init=False, default=None)
 
     def __post_init__(self):
-        self.nbytes = self.total_len * np.dtype(self.dtype).itemsize
+        self.job_dtype = np.dtype(
+            self.dtype if self.job_dtype is None else self.job_dtype)
+        self.mixed = self.job_dtype != np.dtype(self.dtype)
+        self.nbytes = self.total_len * self.job_dtype.itemsize
         if self.lens is not None:
             self.starts = np.concatenate(
                 [[0], np.cumsum(self.lens)]).astype(np.int32)
@@ -217,6 +230,35 @@ def _zero_filled(sfn):
         return sfn(store_l, state_l, jnp.pad(row, ((0, 0), (0, pad)))[0])
 
     return fn
+
+
+def _widened(rows_l, dtype, width: int):
+    """The gradient of a mixed bucket, widened outside a kernel: ``rows_l``
+    is one worker's whole gradient, the row ``[1, total]`` of the job's
+    dtype, widened exactly to the store's ``dtype`` and filled with zeros
+    to ``width`` (what :func:`_aggregate_whole` would pad it to: one pass,
+    and the trace tells whose) before it is summed: the sum over W is
+    taken in the store's precision, never in the job's.  (Where there is
+    nothing to sum or to cut, one worker on one shard, the row goes to the
+    handle as it came and its kernel widens it in VMEM.)  Under
+    ``ps.push.widen``."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("ps.push.widen"):
+        return jnp.pad(rows_l.astype(dtype),
+                       ((0, 0), (0, width - rows_l.shape[1])))
+
+
+def _narrowed(store_l, dtype):
+    """A shard of the store rounded to the job's ``dtype`` (to nearest,
+    ties to even: ``astype``), before it is gathered: what a mixed bucket
+    pulls where no kernel wrote the pulled values itself.  Under
+    ``ps.pull.narrow``."""
+    import jax
+
+    with jax.named_scope("ps.pull.narrow"):
+        return store_l.astype(dtype)
 
 
 def _update(handle, *args):
@@ -404,6 +446,9 @@ class CollectiveEngine:
         # ... and whose pulled values its second kernel wrote
         # (``engine.pull.from_kernel``).
         self.kernel_pulls = 0
+        # Ops on a bucket whose job dtype is narrower than its store's
+        # (``engine.dense.narrow``).
+        self.narrow_ops = 0
 
     # -- registration --------------------------------------------------------
 
@@ -416,6 +461,7 @@ class CollectiveEngine:
         init: Optional[np.ndarray] = None,
         lens=None,
         flags=None,
+        job_dtype=None,
     ) -> DenseBucket:
         """Register a dense bucket and allocate its sharded store.
 
@@ -426,6 +472,17 @@ class CollectiveEngine:
         ``val_len`` values a key, or ``lens``: a length for each key (the
         reference's ``KVPairs.lens``), and with them ``flags``, a word a
         key of ``KEY_NO_DECAY`` / ``KEY_NO_ADAPT`` (default 0).
+
+        ``job_dtype`` (default ``dtype``): what the job pushes and what
+        ``push_pull`` and ``pull`` hand back, where that is narrower than
+        the store (``dtype=float32, job_dtype=bfloat16``: mixed-precision
+        training with the master copy on the server).  The store, the
+        optimizer state, the sum over W and every norm stay ``dtype``; a
+        gradient is widened exactly; a pulled value is the stored one
+        rounded to nearest-even, ``store[:total].astype(job_dtype)`` bit
+        for bit.  Served by ``push_pull``, ``push`` and ``pull`` on the
+        bucket's own program: a bucket with ``lens`` under a stateful
+        handle (:meth:`_mixed_refusal` names what else asks for it).
         """
         import jax
         import jax.numpy as jnp
@@ -457,6 +514,20 @@ class CollectiveEngine:
             log.check(total < 2 ** 31,
                       f"bucket {name!r}: {total:,} values; the keys' "
                       f"borders are kept as int32")
+        if job_dtype is not None and np.dtype(job_dtype) != np.dtype(dtype):
+            job, own = np.dtype(job_dtype), np.dtype(dtype)
+            log.check(
+                jnp.issubdtype(job, jnp.floating)
+                and jnp.issubdtype(own, jnp.floating)
+                and job.itemsize < own.itemsize,
+                f"bucket {name!r}: job_dtype {job} over a {own} store: the "
+                f"job's dtype is the store's or a narrower float (gradients "
+                f"are widened exactly, parameters rounded on the way out)")
+            log.check(lens is not None, self._mixed_refusal(
+                name, job, own,
+                "a bucket registered with one val_len for all its keys "
+                "(its programs are shared by every bucket of a length)",
+                "register it with lens="))
         if init is not None:
             log.check_eq(int(np.size(init)), total,
                          f"bucket {name!r}: init must hold one value for "
@@ -471,6 +542,7 @@ class CollectiveEngine:
             padded_len=padded,
             lens=lens,
             flags=flags,
+            job_dtype=job_dtype,
         )
         sharding = NamedSharding(self.mesh, P(self.axis))
         if init is not None:
@@ -606,7 +678,9 @@ class CollectiveEngine:
         known, on every shard.  The norms are over the key's own elements
         wherever they lie (shard and tile borders are not the keys') and
         never over the padding.  ``agg`` is a row, as
-        :func:`_aggregate_whole` leaves it.
+        :func:`_aggregate_whole` leaves it, of the store's dtype or, where
+        a mixed bucket's gradient passes (:func:`_widened`), of the
+        job's: the first kernel widens what it reads.
 
         ``fn`` takes one argument more, ``pulled_len``: with it
         (``total_len``, where this shard holds the whole bucket:
@@ -614,7 +688,8 @@ class CollectiveEngine:
         twice, in place and as a vector ``[pulled_len]`` of its own, and
         ``fn`` returns that as a third value: the pulled values, which a
         cut of the store after the kernel would read and write once
-        more."""
+        more.  They are of the bucket's job dtype: on a mixed bucket the
+        kernel rounds each value it has just stored."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -631,6 +706,7 @@ class CollectiveEngine:
         n_keys = len(bucket.keys)
         blocks = fused_update.lamb_blocks(starts, bucket.padded_len, S)
         kw = dict(beta1=b1, beta2=b2, eps=eps, interpret=interp)
+        pulled_dtype = bucket.job_dtype if bucket.mixed else None
 
         def fn(store_l, state_l, agg, pulled_len=0):
             m_l, v_l, step_l = state_l
@@ -649,7 +725,8 @@ class CollectiveEngine:
             with jax.named_scope("ps.update.lamb.apply"):
                 new_store, pulled = fused_update.lamb_apply(
                     store_l, new_m, new_v, step, starts, decay, scale,
-                    mine, base, pulled_len=pulled_len, **kw)
+                    mine, base, pulled_len=pulled_len,
+                    pulled_dtype=pulled_dtype, **kw)
             new_state = (new_m, new_v, step_l + 1.0)
             if pulled is None:
                 return new_store, new_state
@@ -683,6 +760,21 @@ class CollectiveEngine:
                 f"the keys' own lengths, which {where} does not have: "
                 f"register the bucket with lens= and use push_pull or push")
 
+    @staticmethod
+    def _mixed_refusal(name: str, job, own, what: str, instead: str) -> str:
+        """Why ``what`` does not serve a bucket whose job dtype is narrower
+        than its store's, and what does."""
+        return (f"bucket {name!r} is pushed and pulled in {np.dtype(job)} "
+                f"over a {np.dtype(own)} store, which only the bucket's own "
+                f"program serves (lens= and a stateful handle, by push_pull, "
+                f"push or pull); {what} would have to cast on the way: "
+                f"{instead}")
+
+    def _refuse_mixed(self, bucket: DenseBucket, what: str,
+                      instead: str = "use push_pull, push or pull") -> None:
+        log.check(not bucket.mixed, self._mixed_refusal(
+            bucket.name, bucket.job_dtype, bucket.dtype, what, instead))
+
     @property
     def handle_is_stateful(self) -> bool:
         """Whether the engine's default server handle carries optimizer
@@ -705,10 +797,11 @@ class CollectiveEngine:
         executable-cache analog of the reference's per-(key,push,recver)
         rendezvous cache.  Under a handle that treats keys apart
         (``bucket`` given: see :meth:`_bind`) also for the keys' segments
-        and flags, which such a program holds."""
+        and flags, which such a program holds, and for the job's dtype
+        (``pull`` of a mixed bucket is the bucket's own too)."""
         key = (op, padded_len, str(dtype), handle_key)
         if bucket is not None:
-            key += (bucket.segments_key,)
+            key += (bucket.segments_key, str(bucket.job_dtype))
         with self._mu:
             prog = self._programs.get(key)
         if prog is not None:
@@ -764,6 +857,9 @@ class CollectiveEngine:
             return new, new[:1]
 
         def _pull(store_l):
+            if bucket is not None:  # mixed: rounded, gathered, cut
+                return _gather(_narrowed(store_l, bucket.job_dtype),
+                               axis)[:bucket.total_len]
             return _gather(store_l, axis)
 
         def _pull_pinned(prev_l, store_l):
@@ -1081,7 +1177,15 @@ class CollectiveEngine:
         copy too where one shard holds the bucket (the all-gather is the
         identity): there a handle whose kernel can leave the pulled values
         itself is asked to (:meth:`_kernel_pulls`), and what its function
-        hands back third is what the program returns."""
+        hands back third is what the program returns.
+
+        A mixed bucket (job dtype narrower than the store's) hands its
+        rows over in the job's dtype: they go to the handle as they came
+        (one worker, one shard: its kernel widens them) or are widened
+        for the sum over W (:func:`_widened`), and it pulls its store
+        rounded (:func:`_narrowed`, before the gather: half the bytes
+        cross the chips) where no kernel wrote the pulled values in the
+        job's dtype itself."""
         import jax
         from jax import lax
         from jax.sharding import PartitionSpec as P
@@ -1102,18 +1206,31 @@ class CollectiveEngine:
             def cut(pulled):
                 return pulled
         else:
+            mixed = bucket.mixed
             grads_spec = self._grads_sharding(False, True).spec
             shards = self.num_shards
             shard_len, total = bucket.padded_len // shards, bucket.total_len
+            passes = shards == 1 and self.num_workers == 1
             if not self._needs_segments(handle_key):
                 sfn = _zero_filled(sfn)
 
             def aggregate(grads_l):
+                if mixed:
+                    if passes:
+                        return grads_l
+                    grads_l = _widened(
+                        grads_l, bucket.dtype,
+                        shards * shard_len if shards > 1 else total)
                 return _aggregate_whole(grads_l, shard_len, shards, axis,
                                         waxis)
 
             def cut(pulled):
                 return pulled[:total]
+
+        def narrow(store_l):
+            if bucket is not None and bucket.mixed:
+                return _narrowed(store_l, bucket.job_dtype)
+            return store_l
 
         def _updated(store_l, rest):
             state_l, grads_l = rest[:-1], rest[-1]
@@ -1126,7 +1243,7 @@ class CollectiveEngine:
         def _push_pull(store_l, *rest):
             new_store, new_state, *pulled = _updated(store_l, rest)
             if not pulled:
-                pulled = [cut(_gather(new_store, axis))]
+                pulled = [cut(_gather(narrow(new_store), axis))]
             return (new_store, *new_state, *pulled)
 
         def _push_pull_zc(store_l, *rest):
@@ -1285,14 +1402,22 @@ class CollectiveEngine:
                               steps: bool = False,
                               row_msg: str = "bad worker dim",
                               width: Optional[int] = None):
-        """Coerce a grads array to ``[(T,)? rows, padded]``: dtype cast,
-        broadcast a missing row dim to ``rows``, validate the row count,
-        pad the value tail (to ``width``, where a program takes another
+        """Coerce a grads array to ``[(T,)? rows, padded]``: dtype cast
+        (to the job's dtype, the store's but on a mixed bucket, where
+        another dtype is refused), broadcast a missing row dim to
+        ``rows``, validate the row count, pad the value tail (to ``width``, where a program takes another
         than the bucket's ``padded_len``).  The one definition behind
         every host/device staging path (1-D/2-D x single/multi-process x
         single/replay); ``xp`` is np (host staging) or jnp (device
         staging) — see :func:`placement.staging_xp`."""
-        arr = xp.asarray(grads, dtype=np.dtype(bucket.dtype))
+        if bucket.mixed:
+            # Never cast: a narrowing here would round the job's gradient
+            # behind its back.
+            arr = xp.asarray(grads)
+            if arr.dtype != bucket.job_dtype:
+                self._refuse_grad_dtype(bucket, arr.dtype)
+        else:
+            arr = xp.asarray(grads, dtype=bucket.job_dtype)
         want = 3 if steps else 2
         log.check(arr.ndim in (want - 1, want), "bad grads rank")
         if arr.ndim == want - 1:
@@ -1415,6 +1540,16 @@ class CollectiveEngine:
             sharding = self._grads_sharding(False, True)
         return self._prep_grads(bucket, grads, sharding, bucket.total_len)
 
+    def _refuse_grad_dtype(self, bucket: DenseBucket, got) -> None:
+        over = (f" over its {np.dtype(bucket.dtype)} store"
+                if bucket.mixed else "")
+        raise log.CheckError(
+            f"bucket {bucket.name!r} takes gradients of "
+            f"{bucket.job_dtype}{over} and was handed {np.dtype(got)}: "
+            f"cast in the job, where the rounding is the job's to choose "
+            f"(a device gradient is never cast here, and a program traced "
+            f"for another dtype is not the bucket's)")
+
     def _prep_grads(self, bucket: DenseBucket, grads, sharding=None,
                     width: Optional[int] = None):
         """Accept [W, total] (or [total] broadcast) host/device arrays and
@@ -1442,6 +1577,9 @@ class CollectiveEngine:
         if isinstance(grads, jax.Array) and grads.ndim == 2:
             shape = grads.shape
             if shape[1] == width:
+                # Never cast, never traced anew for another dtype.
+                if grads.dtype != bucket.job_dtype:
+                    self._refuse_grad_dtype(bucket, grads.dtype)
                 # Row count must match the worker fan-in exactly — a
                 # silent reshard would drop rows (the shard body reads
                 # one local row per device position).
@@ -1452,6 +1590,15 @@ class CollectiveEngine:
                 if have is sharding or have == sharding:
                     return grads
                 return jax.device_put(grads, sharding)
+        if bucket.mixed and isinstance(grads, jax.Array):
+            if grads.dtype != bucket.job_dtype:
+                self._refuse_grad_dtype(bucket, grads.dtype)
+            raise log.CheckError(
+                f"bucket {bucket.name!r} takes a device gradient as rows "
+                f"[{self.num_workers}, {width}] of {bucket.job_dtype} and "
+                f"was handed {tuple(grads.shape)}: no other form is laid "
+                f"out anew here (a pass over the tree on every call, "
+                f"outside the bucket's program): hand the rows over")
         if self.worker_axis is not None:
             if self._is_multiprocess():
                 arr = self._normalize_host_grads(
@@ -1523,10 +1670,16 @@ class CollectiveEngine:
         # store is not its pulled value.)
         zc = (bool(zero_copy)
               and self._zc_pull_eligible(bucket.dtype, resolved)
-              and bucket.padded_len == bucket.total_len)
+              and bucket.padded_len == bucket.total_len
+              and not bucket.mixed)  # the store is not the job's dtype
         # Resolved for every record: it says once why "pallas" runs XLA.
         impl = self._effective_impl(bucket.dtype, resolved)
         stateful = self._is_stateful(resolved)
+        if not stateful:
+            self._refuse_mixed(
+                bucket, f"the stateless handle {resolved!r} (on the "
+                f"programs shared by length, or the ring's)",
+                "use a stateful handle (lamb, adam, adagrad, sgd_momentum)")
         # A stateful program of a bucket that keeps its keys' lengths is
         # the bucket's own: it takes the gradient and gives the pulled
         # values at total_len (_stateful_program), and a handle that treats
@@ -1542,9 +1695,10 @@ class CollectiveEngine:
                                  handle_key, bucket if own else None)
             if own:
                 prep = self._prep_grads_whole
-            if self._needs_segments(resolved):
-                prog = self._counted_lamb(
-                    prog, self._kernel_pulls(op, resolved, bucket))
+            if self._needs_segments(resolved) or bucket.mixed:
+                prog = self._counted(
+                    prog, self._needs_segments(resolved),
+                    self._kernel_pulls(op, resolved, bucket), bucket.mixed)
         elif impl == "pallas":
             if self.worker_axis is None:
                 prep = self._prep_grads_ring
@@ -1591,15 +1745,18 @@ class CollectiveEngine:
                 and bucket is not None and self._needs_segments(handle)
                 and lamb_apply_pulls(bucket.total_len))
 
-    def _counted_lamb(self, prog: Callable, kernel_pulls: bool) -> Callable:
-        """``prog`` behind the counts of ``engine.update.lamb`` and, where
-        the program takes its pulled values from ``lamb_apply``,
-        ``engine.pull.from_kernel``: what a record of :meth:`_bind` knows
+    def _counted(self, prog: Callable, lamb: bool, kernel_pulls: bool,
+                 narrow: bool) -> Callable:
+        """``prog`` behind the counts of ``engine.update.lamb``, where
+        the program takes its pulled values from ``lamb_apply`` of
+        ``engine.pull.from_kernel``, and on a mixed bucket of
+        ``engine.dense.narrow``: what a record of :meth:`_bind` knows
         is counted by the record's own program, and no other op pays for
         it."""
         def counted(*args):
-            self.lamb_updates += 1
+            self.lamb_updates += lamb
             self.kernel_pulls += kernel_pulls
+            self.narrow_ops += narrow
             return prog(*args)
 
         return counted
@@ -1610,6 +1767,7 @@ class CollectiveEngine:
         registry.gauge("engine.update.lamb", fn=lambda: self.lamb_updates)
         registry.gauge("engine.pull.from_kernel",
                        fn=lambda: self.kernel_pulls)
+        registry.gauge("engine.dense.narrow", fn=lambda: self.narrow_ops)
         registry.gauge(
             "engine.dense.segments",
             fn=lambda: sum(len(b.keys) for b in list(self._buckets.values())
@@ -1698,6 +1856,9 @@ class CollectiveEngine:
                   "push_pull_group supports stateless handles only")
         t0 = stamp()  # stage borders: see _note
         buckets = [self._buckets[n] for n in names]
+        for b in buckets:
+            self._refuse_mixed(b, "a group of buckets (stateless handles, "
+                               "one program for the group)")
         # MUST mirror _group_program's use_ring resolution: the
         # grouped 1-D ring program takes each bucket's grads FLAT
         # (same sublane-pad rationale as _prep_grads_ring).
@@ -1867,6 +2028,8 @@ class CollectiveEngine:
         log.check(keep in ("all", "last"), f"bad keep {keep!r}")
         t0 = stamp()  # stage borders: see _note
         bucket = self._buckets[name]
+        self._refuse_mixed(bucket, "replay (one program for any bucket of "
+                           "a length)")
         resolved, handle_key = self._resolve_handle(handle)
         stateful = self._is_stateful(resolved)
         zc = (zero_copy and keep == "last"
@@ -1961,6 +2124,9 @@ class CollectiveEngine:
         def _stager():
             try:
                 for name, g in pairs_iter:
+                    self._refuse_mixed(
+                        self._buckets[name], "a stream (it stages [W, "
+                        "padded] rows of the store's dtype ahead)")
                     staged = self._prep_grads(self._buckets[name], g)
                     if not _put(("ok", name, staged)):
                         return
@@ -2409,9 +2575,12 @@ class CollectiveEngine:
         t0 = stamp()  # stage borders: see _note
         bucket = self._buckets[name]
         to_pinned = name in self._pinned_pulls
+        # A mixed bucket's pull is its own program: the store rounded to
+        # the job's dtype, gathered and cut at total_len inside.
+        own = bucket if bucket.mixed else None
         prog = self._program(
             "pull_pinned" if to_pinned else "pull", bucket.padded_len,
-            bucket.dtype, "_pull_pinned" if to_pinned else "_pull",
+            bucket.dtype, "_pull_pinned" if to_pinned else "_pull", own,
         )
         t1 = stamp()  # select | launch: a pull prepares nothing
         # Bucket lock: a concurrent push donates the store buffer; reading
@@ -2431,7 +2600,11 @@ class CollectiveEngine:
                 if to_pinned:
                     prog = self._program("pull", bucket.padded_len,
                                          bucket.dtype, "_pull")
-                pulled = prog(self._stores[name])[: bucket.total_len]
+                pulled = prog(self._stores[name])
+                if own is None:
+                    pulled = pulled[: bucket.total_len]
+                else:
+                    self.narrow_ops += 1
         self._observe("pull", bucket)
         t2 = stamp()
         self._note((ENGINE_OP, t2, t1 - t0, 0, t2 - t1))
@@ -2453,6 +2626,9 @@ class CollectiveEngine:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         bucket = self._buckets[name]
+        self._refuse_mixed(bucket, "a pinned pull buffer (a padded-length "
+                           "buffer of the store's dtype, donated from pull "
+                           "to pull)", "pull into a fresh array")
         # _place handles multi-process meshes (device_put cannot target
         # non-addressable devices).
         buf = self._place(

@@ -537,10 +537,12 @@ def test_a_lane_packed_tables_pushes_at_full_size_write_it_with_row_add(
 # -- LAMB's pulled values from its second kernel (ops/fused_update.py) ----------
 
 
-def _lamb_program(devices, lens, op, dtype="float32", flags=None):
+def _lamb_program(devices, lens, op, dtype="float32", flags=None,
+                  job_dtype=None):
     """(compiled, lowered text, total, padded) of the program of a bucket
     with ``lens`` under ``lamb`` over ``devices`` (described, not
-    attached: the record alone, since registering would allocate)."""
+    attached: the record alone, since registering would allocate).  With
+    ``job_dtype`` the bucket is mixed and its gradient the job's rows."""
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -559,12 +561,14 @@ def _lamb_program(devices, lens, op, dtype="float32", flags=None):
     bucket = DenseBucket(
         name="tree", keys=np.arange(len(lens), dtype=np.uint64), val_len=0,
         dtype=dtype, total_len=total, padded_len=padded, lens=lens,
-        flags=(np.zeros(len(lens), np.int32) if flags is None else flags))
+        flags=(np.zeros(len(lens), np.int32) if flags is None else flags),
+        job_dtype=job_dtype)
     shard = NamedSharding(mesh, P("kv"))
     vec = jax.ShapeDtypeStruct((padded,), dtype, sharding=shard)
     slot = jax.ShapeDtypeStruct((chips,), jnp.float32, sharding=shard)
     grads = jax.ShapeDtypeStruct(
-        (chips, total), dtype, sharding=NamedSharding(mesh, P("kv", None)))
+        (chips, total), bucket.job_dtype,
+        sharding=NamedSharding(mesh, P("kv", None)))
     lowered = eng._program(op, padded, dtype, handle, bucket).lower(
         vec, vec, vec, slot, grads)
     return lowered.compile(), lowered.as_text(), total, padded
@@ -674,6 +678,118 @@ def test_lamb_apply_leaves_the_pulled_vector(v5e8_mesh, lens, dtype):
     made = _makers(compiled.as_text(), f"{short}[{total}]")
     assert made and set(made) <= {"get-tuple-element", "bitcast"}, made
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 16
+
+
+@pytest.mark.parametrize("lens", [
+    [30522, 100000],            # a ragged last block, in and out
+    [2 * 65536 - 1000, 1000],   # ends on a tile's border
+    [300, 213],                 # the shortest vector the kernels take
+])
+def test_the_lamb_kernels_take_the_jobs_bf16_over_an_f32_store(v5e8_mesh,
+                                                               lens):
+    """A mixed bucket on one chip: ``lamb_moments`` reads the job's bf16
+    row as the program was handed it, ``lamb_apply`` leaves the pulled
+    bf16 vector second; nothing widens, narrows or copies outside them."""
+    import re
+
+    compiled, lowered, total, padded = _lamb_program(
+        v5e8_mesh.devices.flat[:1], lens, "push_pull_st",
+        job_dtype="bfloat16")
+    assert lowered.count("tpu_custom_call") == 2
+    text = compiled.as_text()
+    rows = padded // 128
+    # The row as the chip holds it (tiles of two rows, one of them
+    # padding: the f32 row's bytes), the pulled vector packed.
+    assert f"bf16[1,{total}]{{1,0:T(2,128)(2,1)}}" in text
+    assert f"bf16[{total}]{{0:T(1024)(128)(2,1)}}" in text
+    assert re.search(
+        rf"%lamb_apply\.1 = \(f32\[{rows},128\]\S*, bf16\[{total}\]", text)
+    made = _makers(text, f"bf16[{total}]")
+    assert made and set(made) <= {"get-tuple-element", "bitcast"}, made
+    # (XLA prefetches a gradient of a few hundred KB into VMEM: a
+    # copy-start / copy-done of its own, no pass over HBM.)
+    assert set(_makers(text, f"bf16[1,{total}]")) <= {"copy-start",
+                                                       "copy-done"}
+    # (Where the keys end on a tile's border the store is as long.)
+    assert set(_makers(text, f"f32[{total}]")) <= {"bitcast"}
+    assert not _makers(text, f"f32[1,{total}]")
+    assert "convert" not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 16
+    assert mem.argument_size_in_bytes < 4 * 4 * padded + 4096
+
+
+@pytest.mark.parametrize("op, chips", [("push_st", 1), ("push_pull_st", 4)])
+def test_a_mixed_bucket_elsewhere_has_the_one_result_kernel(v5e8_mesh, op,
+                                                            chips):
+    """A push alone returns nothing to round; over four shards XLA widens
+    the gradient before the f32 sum (``ps.push.widen``) and rounds the
+    shards before the gather (``ps.pull.narrow``), which carries bf16."""
+    import re
+
+    compiled, lowered, total, padded = _lamb_program(
+        v5e8_mesh.devices.flat[:chips], [30522, 100000, 65536 * 4], op,
+        job_dtype="bfloat16")
+    assert lowered.count("tpu_custom_call") == 2
+    text = compiled.as_text()
+    assert f"%lamb_apply.1 = f32[{padded // chips // 128},128]" in text
+    if chips == 1:
+        assert not _makers(text, f"bf16[{total}]")
+        assert "convert" not in text
+    else:
+        assert re.search(rf"= bf16\[{padded}\]\S* all-gather\(", text)
+        assert "ps.push.widen" in text and "ps.pull.narrow" in text
+        assert re.search(
+            r"= f32\[\d+(,\d+)?\]\S* (all-reduce|reduce-scatter)\(", text)
+
+
+def test_a_mixed_bucket_of_up_to_512_values_leaves_the_rounding_to_xla(
+        v5e8_mesh):
+    """A vector of 512 values lies in one tile of its own length
+    (``T(512)(128)(2,1)``), which Mosaic refuses as rank-1 blocks of the
+    second kernel: the first reads the bf16 row all the same, and the
+    pulled values are the store's cut, rounded, after the second."""
+    compiled, _, total, padded = _lamb_program(
+        v5e8_mesh.devices.flat[:1], [300, 212], "push_pull_st",
+        job_dtype="bfloat16")
+    text = compiled.as_text()
+    assert f"%lamb_apply.1 = f32[{padded // 128},128]" in text
+    assert set(_makers(text, f"bf16[{total}]")) & {"slice", "fusion",
+                                                    "convert"}
+    assert f"bf16[1,{total}]{{1,0:T(2,128)(2,1)" in text
+    assert not _makers(text, f"f32[1,{total}]")
+
+
+def test_the_elementwise_kernels_take_a_gradient_narrower_than_the_state(
+        v5e8_mesh):
+    """``adam`` on a mixed bucket with ``lens``: ``_elementwise_call``
+    tiles for the narrower operand and widens in VMEM."""
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from pslite_tpu.parallel.engine import (CollectiveEngine, DenseBucket,
+                                            _padded_len)
+
+    handle = "adam:1e-4,0.9,0.999,1e-8"
+    mesh = Mesh(np.array(v5e8_mesh.devices.flat[:1]), ("kv",))
+    eng = CollectiveEngine(mesh=mesh, server_handle=handle)
+    lens = np.array([30522, 100000], dtype=np.int64)
+    total, padded = int(lens.sum()), _padded_len(int(lens.sum()), 1, True)
+    bucket = DenseBucket(
+        name="tree", keys=np.arange(2, dtype=np.uint64), val_len=0,
+        dtype=jnp.float32, total_len=total, padded_len=padded, lens=lens,
+        flags=np.zeros(2, np.int32), job_dtype=jnp.bfloat16)
+    shard = NamedSharding(mesh, P("kv"))
+    vec = jax.ShapeDtypeStruct((padded,), jnp.float32, sharding=shard)
+    slot = jax.ShapeDtypeStruct((1,), jnp.float32, sharding=shard)
+    grads = jax.ShapeDtypeStruct(
+        (1, total), jnp.bfloat16, sharding=NamedSharding(mesh, P("kv", None)))
+    compiled = eng._program("push_pull_st", padded, jnp.float32, handle,
+                            bucket).lower(vec, vec, vec, slot,
+                                          grads).compile()
+    text = compiled.as_text()
+    assert "%adam_update.1 = (f32[" in text
+    assert _makers(text, f"bf16[{total}]")      # the pulled tree, rounded
 
 
 def test_a_vector_the_chip_lays_in_one_tile_is_cut_from_the_store(

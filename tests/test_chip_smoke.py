@@ -33,8 +33,8 @@ def test_smoke_phases_at_tiny_size(capsys):
                          expired=lambda name, s: hung.append(name))
     assert not hung
     out = capsys.readouterr().out
-    for phase in ("boot", "resnet50", "readme", "lamb", "sparse", "message_path",
-                  "shutdown"):
+    for phase in ("boot", "resnet50", "readme", "lamb", "mixed", "sparse",
+                  "message_path", "shutdown"):
         assert f"phase {phase}: ok" in out
     assert "W = 8, kernels interpreted" in out
 
