@@ -377,49 +377,97 @@ class _Smoke:
         of 3 and 2 values, one on no lane border, one that crosses every
         shard's border (so that on more than one chip the norms' ``psum``
         crosses chips), one left out of decay and adaptation; against the
-        recurrence in float64."""
-        import jax.numpy as jnp
-
-        from pslite_tpu.ops.fused_update import LAMB_TILE
+        recurrence in float64: the pulled values, and m and v.  On a TPU
+        one key more, between those: too long for VMEM to hold
+        (``fused_update.LAMB_HELD_TILES``), 130 tiles over however many
+        shards; on one chip it takes two passes, and with it the two short
+        keys in its last tile, while the keys before it and one more
+        behind take one.  Then, on one chip, a short key and a long one
+        that share tile 0, so that every tile is walked and no key held:
+        the two passes, and m and v whole after them."""
+        from pslite_tpu.ops.fused_update import LAMB_HELD_TILES, LAMB_TILE
         from pslite_tpu.parallel.engine import KEY_NO_ADAPT, KEY_NO_DECAY
+
+        eng = self.kv.engine
+        one_shard = eng.num_shards == 1
+        lens = [3, 30522, LAMB_TILE * self.n_dev + 77, 1000, 2]
+        flags = [0, 0, 0, KEY_NO_DECAY | KEY_NO_ADAPT, 0]
+        # (The interpreter would take minutes over the long key.)
+        long = (LAMB_HELD_TILES + 1) * LAMB_TILE + 5 if self.on_tpu else 0
+        two_pass = 0
+        if long:
+            lens.insert(3, long)
+            flags.insert(3, 0)
+            if one_shard:
+                lens.append(LAMB_TILE + 5)
+                flags.append(0)
+                two_pass = long + 1000 + 2
+        total = sum(lens)
+        ratios = self._lamb_steps("lamb_tree", 5000, lens, flags, 2)
+        check(eng.lamb_one_pass == (total - two_pass if one_shard else 0),
+              f"one pass over {eng.lamb_one_pass:,} of {total:,} values")
+        print(f"  {len(lens)} keys of {', '.join(f'{n:,}' for n in lens)} "
+              f"values ({eng.bucket('lamb_tree').padded_len:,} padded) over "
+              f"{self.n_dev} device(s): 2 steps under {LAMB_HANDLE} agree, "
+              f"{eng.lamb_one_pass:,} values in one pass; trust ratios "
+              + ", ".join(f"{r:.3f}" for r in ratios))
+        if long and one_shard:
+            self._lamb_steps("lamb_pair", 5100, [300, long], [0, 0], 1)
+            check(eng.lamb_one_pass == 0, "a key in walked tiles alone is "
+                  f"held: one pass over {eng.lamb_one_pass:,} values")
+            print(f"  2 keys of 300, {long:,} values in one tile 0: the two "
+                  f"passes, m and v whole")
+
+    def _lamb_steps(self, name: str, key0: int, lens, flags,
+                    steps: int) -> list:
+        """Register ``name`` with ``lens`` and ``flags``, take ``steps``
+        steps of ``push_pull`` under LAMB and hold the pulled values, the
+        store, m and v to the float64 recurrence; the last step's trust
+        ratios."""
+        import jax.numpy as jnp
 
         kv, eng = self.kv, self.kv.engine
         W = eng.num_workers
-        lens = np.array([3, 30522, LAMB_TILE * self.n_dev + 77, 1000, 2])
-        flags = np.array([0, 0, 0, KEY_NO_DECAY | KEY_NO_ADAPT, 0])
-        keys = np.arange(5000, 5000 + len(lens), dtype=np.uint64)
+        lens, flags = np.array(lens), np.array(flags)
+        keys = np.arange(key0, key0 + len(lens), dtype=np.uint64)
         starts = np.concatenate([[0], np.cumsum(lens)])
         total = int(lens.sum())
         rng = np.random.default_rng(SEED)
         init = (0.02 * rng.standard_normal(total)).astype(np.float32)
-        bucket = kv.register_dense("lamb_tree", keys, lens=lens, flags=flags,
-                                   init=init)
-        check(kv._engine_route(keys, 0, lens) == "lamb_tree",
+        kv.register_dense(name, keys, lens=lens, flags=flags, init=init)
+        check(kv._engine_route(keys, 0, lens) == name,
               "a call with the registered lens is the engine's")
         ref = _LambRecurrence(init, starts, flags)
         before, pulls_before = eng.lamb_updates, eng.kernel_pulls
-        for t in (1, 2):
+        for t in range(1, steps + 1):
             g = rng.standard_normal((W, total)).astype(np.float32)
+            # Where W gradients cancel to within eps, u = gs / (|gs| + eps)
+            # turns on the last bits of the sum, and the f32 sum of the
+            # chips parts from the recurrence's float64 sum by more than
+            # the tolerance: 4 of 8.7 M did.  No sum lies there.
+            g[0, np.abs(g.sum(axis=0, dtype=np.float64)) < 1e-3] += 0.5
             # Host-origin, then device-origin: at the keys' own length.
             sent = g if t == 1 else jnp.asarray(g)
-            pulled = np.asarray(
-                eng.push_pull("lamb_tree", sent, LAMB_HANDLE))
+            pulled = np.asarray(eng.push_pull(name, sent, LAMB_HANDLE))
             ratios = ref.step(g)
             np.testing.assert_allclose(pulled, ref.p, atol=2e-6,
-                                       err_msg=f"lamb step {t}")
-        check(eng.lamb_updates - before == 2, "both ops ran under LAMB")
-        # One shard holds the bucket whole: lamb_apply wrote the pulled
+                                       err_msg=f"{name} step {t}")
+        check(eng.lamb_updates - before == steps, "every op ran under LAMB")
+        # One shard holds the bucket whole: the kernel wrote the pulled
         # values itself; over several they are the gathered shards, cut.
         np.testing.assert_array_equal(
-            pulled, np.asarray(eng.store_array("lamb_tree"))[:total],
+            pulled, np.asarray(eng.store_array(name))[:total],
             err_msg="the pulled values are the store's")
         check(eng.kernel_pulls - pulls_before
-              == (2 if eng.num_shards == 1 else 0),
+              == (steps if eng.num_shards == 1 else 0),
               "the kernel's pulled values on one shard and there alone")
-        print(f"  {len(lens)} keys of {', '.join(f'{n:,}' for n in lens)} "
-              f"values ({bucket.padded_len:,} padded) over {self.n_dev} "
-              f"device(s): 2 steps under {LAMB_HANDLE} agree; trust ratios "
-              + ", ".join(f"{r:.3f}" for r in ratios))
+        _, (m, v, _) = eng.opt_state(name)
+        for what, got, want in (("m", m, ref.m), ("v", v, ref.v)):
+            got = np.asarray(got)
+            np.testing.assert_allclose(got[:total], want, rtol=1e-5,
+                                       atol=1e-6, err_msg=f"{name}: {what}")
+            check(not got[total:].any(), f"{name}: {what} of the padding")
+        return ratios
 
     def mixed(self) -> None:
         """Two steps of ``lamb`` on a bucket whose job's dtype (bfloat16)

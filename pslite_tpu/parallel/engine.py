@@ -78,6 +78,9 @@ class DenseBucket:
     # last), and what tells these segments from any other bucket's.
     starts: Optional[np.ndarray] = field(init=False, default=None)
     segments_key: Optional[bytes] = field(init=False, default=None)
+    # ((padded_len, shards), fused_update.LambPlan) once ``lamb`` has bound
+    # the bucket: ``CollectiveEngine._lamb_plan``.
+    lamb_plan: Optional[tuple] = field(init=False, default=None)
 
     def __post_init__(self):
         self.job_dtype = np.dtype(
@@ -443,9 +446,12 @@ class CollectiveEngine:
         self._counter_mu = threading.Lock()
         # Ops whose program applied LAMB (``engine.update.lamb``).
         self.lamb_updates = 0
-        # ... and whose pulled values its second kernel wrote
+        # ... whose pulled values ``lamb_apply`` wrote
         # (``engine.pull.from_kernel``).
         self.kernel_pulls = 0
+        # The elements the last of them updated in one pass
+        # (``engine.update.lamb.one_pass``).
+        self.lamb_one_pass = 0
         # Ops on a bucket whose job dtype is narrower than its store's
         # (``engine.dense.narrow``).
         self.narrow_ops = 0
@@ -673,18 +679,26 @@ class CollectiveEngine:
             r_k = |p|/|u|, or 1 for KEY_NO_ADAPT or where a norm is 0
             p = p - lr*r_k*u
 
-        Two passes over the shard with a reduction between them: no
-        element of a key may be written before every element's ``u`` is
-        known, on every shard.  The norms are over the key's own elements
-        wherever they lie (shard and tile borders are not the keys') and
-        never over the padding.  ``agg`` is a row, as
-        :func:`_aggregate_whole` leaves it, of the store's dtype or, where
-        a mixed bucket's gradient passes (:func:`_widened`), of the
-        job's: the first kernel widens what it reads.
+        No element of a key may be written before every element's ``u``
+        is known, on every shard.  Over several shards that is two passes
+        with a reduction between them (``lamb_moments``, a ``psum`` of the
+        keys' sums, ``lamb_apply``).  Where one shard holds the bucket a
+        norm needs no ``psum``, and every key that VMEM can hold between
+        its first element and its last (``fused_update.lamb_plan``; what
+        decides is the keys' lengths, known here) is updated in one pass
+        by ``lamb_one_pass``, which takes ``lamb_apply``'s place and name
+        in the program, reads the gradient besides and makes that key's
+        ratio itself; ``lamb_moments`` then walks the tiles of the larger
+        keys alone, and is left out where there are none.  The norms are over the key's own elements wherever they lie
+        (shard and tile borders are not the keys') and never over the
+        padding.  ``agg`` is a row, as :func:`_aggregate_whole` leaves it,
+        of the store's dtype or, where a mixed bucket's gradient passes
+        (:func:`_widened`), of the job's: the kernel that reads it widens
+        it.
 
         ``fn`` takes one argument more, ``pulled_len``: with it
         (``total_len``, where this shard holds the whole bucket:
-        :meth:`_kernel_pulls`) the second pass writes the new parameters
+        :meth:`_kernel_pulls`) that kernel writes the new parameters
         twice, in place and as a vector ``[pulled_len]`` of its own, and
         ``fn`` returns that as a third value: the pulled values, which a
         cut of the store after the kernel would read and write once
@@ -698,35 +712,48 @@ class CollectiveEngine:
 
         lr, b1, b2, eps, wd = self._handle_params(
             handle, (1e-3, 0.9, 0.999, 1e-6, 0.01))
-        interp, axis, S = self._interpret, self.axis, self.num_shards
+        interp, axis = self._interpret, self.axis
         starts = bucket.starts
         decay = np.where(bucket.flags & KEY_NO_DECAY, 0.0, wd).astype(
             np.float32)
         adapt = (bucket.flags & KEY_NO_ADAPT) == 0
         n_keys = len(bucket.keys)
-        blocks = fused_update.lamb_blocks(starts, bucket.padded_len, S)
+        plan = self._lamb_plan(bucket)
         kw = dict(beta1=b1, beta2=b2, eps=eps, interpret=interp)
         pulled_dtype = bucket.job_dtype if bucket.mixed else None
 
         def fn(store_l, state_l, agg, pulled_len=0):
-            m_l, v_l, step_l = state_l
+            new_m, new_v, step_l = state_l
             step = step_l[0] + 1.0
             shard = lax.axis_index(axis)
             base = (shard * store_l.shape[0]).astype(jnp.int32).reshape(1)
-            mine = lax.dynamic_index_in_dim(jnp.asarray(blocks), shard,
+            mine = lax.dynamic_index_in_dim(jnp.asarray(plan.blocks), shard,
                                             keepdims=False)
-            with jax.named_scope("ps.update.lamb.moments"):
-                new_m, new_v, sums = fused_update.lamb_moments(
-                    store_l, m_l, v_l, agg, step, starts, decay, mine,
-                    base, **kw)
-            with jax.named_scope("ps.update.lamb.norms"):
-                sq = lax.psum(sums.reshape(n_keys, 2), axis)
-                scale = (lr * _lamb_ratios(sq, adapt)).astype(jnp.float32)
+            keys = (step, starts, decay)
+            scale = jnp.zeros(n_keys, jnp.float32)
+            if plan.tiles.size:
+                with jax.named_scope("ps.update.lamb.moments"):
+                    new_m, new_v, sums = fused_update.lamb_moments(
+                        store_l, new_m, new_v, agg, *keys, mine, base,
+                        plan.tiles, **kw)
+                with jax.named_scope("ps.update.lamb.norms"):
+                    sq = lax.psum(sums.reshape(n_keys, 2), axis)
+                    scale = (lr * _lamb_ratios(sq, adapt)).astype(
+                        jnp.float32)
+            kw_pulled = dict(kw, pulled_len=pulled_len,
+                             pulled_dtype=pulled_dtype)
             with jax.named_scope("ps.update.lamb.apply"):
-                new_store, pulled = fused_update.lamb_apply(
-                    store_l, new_m, new_v, step, starts, decay, scale,
-                    mine, base, pulled_len=pulled_len,
-                    pulled_dtype=pulled_dtype, **kw)
+                if plan.one_pass_len:
+                    new_store, new_m, new_v, pulled = (
+                        fused_update.lamb_one_pass(
+                            store_l, new_m, new_v, agg, *keys, scale, mine,
+                            base, plan.held.astype(np.int32),
+                            adapt.astype(np.int32), plan.walked,
+                            plan.stepped, lr=lr, lag=plan.lag, **kw_pulled))
+                else:
+                    new_store, pulled = fused_update.lamb_apply(
+                        store_l, new_m, new_v, *keys, scale, mine, base,
+                        **kw_pulled)
             new_state = (new_m, new_v, step_l + 1.0)
             if pulled is None:
                 return new_store, new_state
@@ -1698,7 +1725,9 @@ class CollectiveEngine:
             if self._needs_segments(resolved) or bucket.mixed:
                 prog = self._counted(
                     prog, self._needs_segments(resolved),
-                    self._kernel_pulls(op, resolved, bucket), bucket.mixed)
+                    self._kernel_pulls(op, resolved, bucket), bucket.mixed,
+                    self._lamb_plan(bucket).one_pass_len
+                    if self._needs_segments(resolved) else 0)
         elif impl == "pallas":
             if self.worker_axis is None:
                 prep = self._prep_grads_ring
@@ -1745,16 +1774,32 @@ class CollectiveEngine:
                 and bucket is not None and self._needs_segments(handle)
                 and lamb_apply_pulls(bucket.total_len))
 
+    def _lamb_plan(self, bucket: DenseBucket):
+        """``fused_update.lamb_plan`` of ``bucket`` as it lies on this
+        mesh, made once for a layout (``reshard`` gives the bucket another
+        ``padded_len`` or the mesh other shards): what ``_lamb_fn``
+        builds the program from and ``engine.update.lamb.one_pass``
+        reads."""
+        from ..ops.fused_update import lamb_plan
+
+        layout = (bucket.padded_len, self.num_shards)
+        if bucket.lamb_plan is None or bucket.lamb_plan[0] != layout:
+            bucket.lamb_plan = (layout, lamb_plan(bucket.starts, *layout))
+        return bucket.lamb_plan[1]
+
     def _counted(self, prog: Callable, lamb: bool, kernel_pulls: bool,
-                 narrow: bool) -> Callable:
-        """``prog`` behind the counts of ``engine.update.lamb``, where
-        the program takes its pulled values from ``lamb_apply`` of
-        ``engine.pull.from_kernel``, and on a mixed bucket of
-        ``engine.dense.narrow``: what a record of :meth:`_bind` knows
-        is counted by the record's own program, and no other op pays for
-        it."""
+                 narrow: bool, one_pass: int) -> Callable:
+        """``prog`` behind the counts of ``engine.update.lamb`` (with
+        ``engine.update.lamb.one_pass``, the elements that the last such
+        program updated in one pass), where the program takes its pulled
+        values from ``lamb_apply`` of ``engine.pull.from_kernel``, and on
+        a mixed bucket of ``engine.dense.narrow``: what a record of
+        :meth:`_bind` knows is counted by the record's own program, and
+        no other op pays for it."""
         def counted(*args):
-            self.lamb_updates += lamb
+            if lamb:
+                self.lamb_updates += 1
+                self.lamb_one_pass = one_pass
             self.kernel_pulls += kernel_pulls
             self.narrow_ops += narrow
             return prog(*args)
@@ -1765,6 +1810,8 @@ class CollectiveEngine:
         """Lazily sampled gauges in a node's ``Registry``, beside the
         stage clock's (``docs/observability.md``, "Engine path")."""
         registry.gauge("engine.update.lamb", fn=lambda: self.lamb_updates)
+        registry.gauge("engine.update.lamb.one_pass",
+                       fn=lambda: self.lamb_one_pass)
         registry.gauge("engine.pull.from_kernel",
                        fn=lambda: self.kernel_pulls)
         registry.gauge("engine.dense.narrow", fn=lambda: self.narrow_ops)

@@ -534,7 +534,7 @@ def test_a_lane_packed_tables_pushes_at_full_size_write_it_with_row_add(
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 16e9
 
 
-# -- LAMB's pulled values from its second kernel (ops/fused_update.py) ----------
+# -- LAMB's one pass and its pulled values (ops/fused_update.py) ----------------
 
 
 def _lamb_program(devices, lens, op, dtype="float32", flags=None,
@@ -585,6 +585,21 @@ def _makers(text, shape):
     return [opcode for opcode in found if opcode != "parameter"]
 
 
+def _results(text, kernel):
+    """The result shapes (``f32[2627072,128]``, layouts dropped) of the
+    custom call ``%<kernel>.1`` in a compiled text; none where the program
+    has no such kernel."""
+    import re
+
+    line = [l for l in text.splitlines()
+            if l.lstrip().startswith(f"%{kernel}.1 = ")]
+    assert len(line) <= 1, line
+    if not line:
+        return []
+    return re.findall(r"\w+\[[\d,]*\]",
+                      line[0].split(" = ", 1)[1].split(" custom-call(")[0])
+
+
 def _bert_large_lens():
     """The 398 tensors of ``bert-large-lamb`` and their flags, as the
     cell's driver registers them."""
@@ -614,10 +629,13 @@ def _bert_large_lens():
 def test_lamb_apply_writes_the_pulled_tree_at_full_size_on_one_chip(
         v5e8_mesh):
     """The program of ``bert-large-lamb.tree`` (what
-    ``benchmark/tests/test_compile_fullsize_lamb_pulled.py`` compiles): one
-    shard holds the bucket, and the second kernel's second result, a vector
-    of the tree's own length that ends in the middle of the last tile, is
-    the program's pulled result as it stands; nothing copies the tree."""
+    ``benchmark/tests/test_compile_fullsize_lamb_one_pass.py`` compiles):
+    one shard holds the bucket, so every key but ``emb.word`` is held in
+    VMEM and updated in one pass by ``lamb_apply``, which has the store, m
+    and v for results and last a vector of the tree's own length that ends
+    in the middle of the last tile: the program's pulled result as it
+    stands; nothing copies the tree.  ``lamb_moments`` walks ``emb.word``'s
+    477 tiles."""
     lens, flags = _bert_large_lens()
     compiled, lowered, total, padded = _lamb_program(
         v5e8_mesh.devices.flat[:1], lens, "push_pull_st", flags=flags)
@@ -625,12 +643,11 @@ def test_lamb_apply_writes_the_pulled_tree_at_full_size_on_one_chip(
     assert lowered.count("tpu_custom_call") == 2
     text = compiled.as_text()
     rows = padded // 128
-    apply = [l for l in text.splitlines()
-             if l.lstrip().startswith("%lamb_apply")]
-    assert len(apply) == 1, apply
-    assert f"= (f32[{rows},128]" in apply[0]      # the store first, in place
-    assert f", f32[{total}]" in apply[0].split(" custom-call(")[0]
-    assert "ps.update.lamb.apply" in apply[0]
+    state = f"f32[{rows},128]"
+    assert _results(text, "lamb_moments") == [state, state, "f32[796]"]
+    assert "s32[477]" in text                     # the tiles it walks
+    assert _results(text, "lamb_apply") == [state] * 3 + [f"f32[{total}]"]
+    assert "ps.update.lamb.apply" in text
     made = _makers(text, f"f32[{total}]")
     assert made and set(made) <= {"get-tuple-element", "bitcast"}, made
     assert "all-gather" not in text
@@ -648,15 +665,17 @@ def test_lamb_apply_writes_the_pulled_tree_at_full_size_on_one_chip(
 def test_lamb_elsewhere_at_full_size_has_the_one_result_kernel(
         v5e8_mesh, op):
     """Over four shards the pulled tree is the all-gather of the shards,
-    cut at the tree's length, and a push alone returns none: in both
-    ``lamb_apply`` has the store for its one result."""
+    cut at the tree's length, and ``lamb_apply`` is the second of two
+    passes with the store for its one result; a push alone on one chip
+    returns no vector: the store, m and v."""
     lens, flags = _bert_large_lens()
     chips = 4 if op == "push_pull_st" else 1
     compiled, lowered, total, padded = _lamb_program(
         v5e8_mesh.devices.flat[:chips], lens, op, flags=flags)
     assert lowered.count("tpu_custom_call") == 2
     text = compiled.as_text()
-    assert f"%lamb_apply.1 = f32[{padded // chips // 128},128]" in text
+    assert _results(text, "lamb_apply") == [
+        f"f32[{padded // chips // 128},128]"] * (1 if chips == 4 else 3)
     made = _makers(text, f"f32[{total}]")
     if chips == 4:
         assert "all-gather" in text
@@ -665,19 +684,35 @@ def test_lamb_elsewhere_at_full_size_has_the_one_result_kernel(
         assert not made
 
 
-@pytest.mark.parametrize("lens, dtype", [
-    ([2 * 65536 - 1000, 1000], "float32"),     # ends on a tile's border
-    ([300, 700], "float32"),                   # shorter than a tile
-    ([300, 513 - 300], "float32"),             # the shortest it takes
-    ([30522, 100000], "bfloat16"),
+@pytest.mark.parametrize("lens, dtype, one_pass", [
+    ([2 * 65536 - 1000, 1000], "float32", True),   # ends on a tile's border
+    ([300, 700], "float32", True),                 # shorter than a tile
+    ([300, 513 - 300], "float32", True),           # the shortest it takes
+    ([30522, 100000], "bfloat16", True),
+    # a key VMEM cannot hold between two it can: both kernels, 130 tiles
+    # walked by the first
+    ([70000, 129 * 65536 + 5, 70000], "float32", True),
+    ([70000, 129 * 65536 + 5, 70000], "bfloat16", True),
+    # ... between two that lie in its first tile and its last: every tile
+    # is walked, no key held, the second of two passes
+    ([300, 129 * 65536 + 5, 1000], "float32", False),
+    ([300, 129 * 65536 + 5, 1000], "bfloat16", False),
 ])
-def test_lamb_apply_leaves_the_pulled_vector(v5e8_mesh, lens, dtype):
+def test_lamb_apply_leaves_the_pulled_vector(v5e8_mesh, lens, dtype,
+                                             one_pass):
     compiled, _, total, padded = _lamb_program(
         v5e8_mesh.devices.flat[:1], lens, "push_pull_st", dtype)
     short = {"float32": "f32", "bfloat16": "bf16"}[dtype]
-    made = _makers(compiled.as_text(), f"{short}[{total}]")
+    text = compiled.as_text()
+    made = _makers(text, f"{short}[{total}]")
     assert made and set(made) <= {"get-tuple-element", "bitcast"}, made
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 16
+    state = f"{short}[{padded // 128},128]"
+    assert _results(text, "lamb_apply") == [state] * (3 if one_pass else 1) \
+        + [f"{short}[{total}]"]
+    assert _results(text, "lamb_moments") == (
+        [state, state, f"f32[{2 * len(lens)}]"] if max(lens) > 128 * 65536
+        else [])
 
 
 @pytest.mark.parametrize("lens", [
@@ -687,23 +722,22 @@ def test_lamb_apply_leaves_the_pulled_vector(v5e8_mesh, lens, dtype):
 ])
 def test_the_lamb_kernels_take_the_jobs_bf16_over_an_f32_store(v5e8_mesh,
                                                                lens):
-    """A mixed bucket on one chip: ``lamb_moments`` reads the job's bf16
-    row as the program was handed it, ``lamb_apply`` leaves the pulled
-    bf16 vector second; nothing widens, narrows or copies outside them."""
-    import re
-
+    """A mixed bucket on one chip, every key held: ``lamb_apply``, the
+    program's one kernel, reads the job's bf16 row as the program was
+    handed it and leaves the pulled bf16 vector last; nothing widens,
+    narrows or copies outside it."""
     compiled, lowered, total, padded = _lamb_program(
         v5e8_mesh.devices.flat[:1], lens, "push_pull_st",
         job_dtype="bfloat16")
-    assert lowered.count("tpu_custom_call") == 2
+    assert lowered.count("tpu_custom_call") == 1
     text = compiled.as_text()
     rows = padded // 128
     # The row as the chip holds it (tiles of two rows, one of them
     # padding: the f32 row's bytes), the pulled vector packed.
     assert f"bf16[1,{total}]{{1,0:T(2,128)(2,1)}}" in text
     assert f"bf16[{total}]{{0:T(1024)(128)(2,1)}}" in text
-    assert re.search(
-        rf"%lamb_apply\.1 = \(f32\[{rows},128\]\S*, bf16\[{total}\]", text)
+    assert _results(text, "lamb_apply") == [f"f32[{rows},128]"] * 3 + [
+        f"bf16[{total}]"]
     made = _makers(text, f"bf16[{total}]")
     assert made and set(made) <= {"get-tuple-element", "bitcast"}, made
     # (XLA prefetches a gradient of a few hundred KB into VMEM: a
@@ -722,7 +756,8 @@ def test_the_lamb_kernels_take_the_jobs_bf16_over_an_f32_store(v5e8_mesh,
 @pytest.mark.parametrize("op, chips", [("push_st", 1), ("push_pull_st", 4)])
 def test_a_mixed_bucket_elsewhere_has_the_one_result_kernel(v5e8_mesh, op,
                                                             chips):
-    """A push alone returns nothing to round; over four shards XLA widens
+    """A push alone returns nothing to round (on one chip the one pass's
+    store, m and v); over four shards XLA widens
     the gradient before the f32 sum (``ps.push.widen``) and rounds the
     shards before the gather (``ps.pull.narrow``), which carries bf16."""
     import re
@@ -730,9 +765,10 @@ def test_a_mixed_bucket_elsewhere_has_the_one_result_kernel(v5e8_mesh, op,
     compiled, lowered, total, padded = _lamb_program(
         v5e8_mesh.devices.flat[:chips], [30522, 100000, 65536 * 4], op,
         job_dtype="bfloat16")
-    assert lowered.count("tpu_custom_call") == 2
+    assert lowered.count("tpu_custom_call") == (1 if chips == 1 else 2)
     text = compiled.as_text()
-    assert f"%lamb_apply.1 = f32[{padded // chips // 128},128]" in text
+    assert _results(text, "lamb_apply") == [
+        f"f32[{padded // chips // 128},128]"] * (3 if chips == 1 else 1)
     if chips == 1:
         assert not _makers(text, f"bf16[{total}]")
         assert "convert" not in text
@@ -746,14 +782,14 @@ def test_a_mixed_bucket_elsewhere_has_the_one_result_kernel(v5e8_mesh, op,
 def test_a_mixed_bucket_of_up_to_512_values_leaves_the_rounding_to_xla(
         v5e8_mesh):
     """A vector of 512 values lies in one tile of its own length
-    (``T(512)(128)(2,1)``), which Mosaic refuses as rank-1 blocks of the
-    second kernel: the first reads the bf16 row all the same, and the
-    pulled values are the store's cut, rounded, after the second."""
+    (``T(512)(128)(2,1)``), which Mosaic refuses as rank-1 blocks of
+    ``lamb_apply``: it reads the bf16 row all the same, and the pulled
+    values are the store's cut, rounded, after it."""
     compiled, _, total, padded = _lamb_program(
         v5e8_mesh.devices.flat[:1], [300, 212], "push_pull_st",
         job_dtype="bfloat16")
     text = compiled.as_text()
-    assert f"%lamb_apply.1 = f32[{padded // 128},128]" in text
+    assert _results(text, "lamb_apply") == [f"f32[{padded // 128},128]"] * 3
     assert set(_makers(text, f"bf16[{total}]")) & {"slice", "fusion",
                                                     "convert"}
     assert f"bf16[1,{total}]{{1,0:T(2,128)(2,1)" in text
@@ -801,5 +837,5 @@ def test_a_vector_the_chip_lays_in_one_tile_is_cut_from_the_store(
     compiled, _, total, padded = _lamb_program(
         v5e8_mesh.devices.flat[:1], [300, 212], "push_pull_st")
     text = compiled.as_text()
-    assert f"%lamb_apply.1 = f32[{padded // 128},128]" in text
+    assert _results(text, "lamb_apply") == [f"f32[{padded // 128},128]"] * 3
     assert _makers(text, f"f32[{total}]")
