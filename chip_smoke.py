@@ -158,6 +158,31 @@ class _LambRecurrence:
         return ratios
 
 
+def _bf16(x: np.ndarray) -> np.ndarray:
+    """Round to the nearest bfloat16 (ties to even), as float64."""
+    bits = np.asarray(x, np.float32).view(np.uint32)
+    bits = (bits + np.uint32(0x7FFF) + ((bits >> np.uint32(16)) & np.uint32(1))
+            ) & np.uint32(0xFFFF0000)
+    return bits.view(np.float32).astype(np.float64)
+
+
+def _muon_matrix_step(p, mom, g, lr, mu, wd):
+    """One Muon step of one matrix in float64 outside the products, whose
+    operands are rounded to bfloat16 (``ops/muon.py`` has the recurrence):
+    returns the new parameters and momentum."""
+    a, b, c = 3.4445, -4.7750, 2.0315
+    mom = mu * mom + g
+    x = _bf16(g + mu * mom)
+    tall = x.shape[0] > x.shape[1]
+    x = x.T if tall else x
+    x = _bf16(x / (float(np.float32(np.sqrt(np.sum(x * x)))) + 1e-7))
+    for _ in range(5):
+        xx = _bf16(x @ x.T)
+        x = _bf16(a * x + _bf16(b * xx + c * (xx @ xx)) @ x)
+    o = x.T if tall else x
+    return p * (1 - lr * wd) - lr * 0.2 * np.sqrt(max(p.shape)) * o, mom
+
+
 def _momentum_reference(agg_steps, n: int) -> np.ndarray:
     """The store after the ``sgd_momentum`` recurrence over the summed
     gradients ``agg_steps`` (float32, like the kernel)."""
@@ -185,6 +210,7 @@ class _Smoke:
             ("readme", 150, self.readme),
             ("lamb", 150, self.lamb),
             ("mixed", 150, self.mixed),
+            ("muon", 150, self.muon),
             ("sparse", 200, self.sparse),
             ("message_path", 30, self.message_path),
         ]
@@ -546,6 +572,101 @@ class _Smoke:
               + ", ".join(f"{r:.3f}" for r in ratios))
 
     # -- sparse plane ---------------------------------------------------------
+
+    def muon(self) -> None:
+        """Two steps of ``muon`` on a bucket registered with ``lens`` and
+        ``shapes``: wide, tall and square matrices, two of one shape (one
+        batched product), a 64-row router and an AdamW key on no lane
+        border between them, against the recurrence in float64 with the
+        products' operands rounded to bfloat16: what the chip does with
+        bf16 products, which tier-1's interpreter cannot see.  Two
+        bfloat16 computations of one recurrence differ by roundings that
+        flip, so a matrix is held to 0.3 of one step's root mean square in
+        any element and 0.05 in the root mean square (the chip reads 0.146
+        and 0.018, a Newton-Schulz step left out 0.8); the AdamW key and
+        the momentum to f32 rounding.  On more than one chip a matrix would
+        lie across chips: there the handle must refuse by name."""
+        import jax.numpy as jnp
+
+        from pslite_tpu.parallel.engine import KEY_ELEMENTWISE
+        from pslite_tpu.utils import logging as log
+
+        kv, eng = self.kv, self.kv.engine
+        W = eng.num_workers
+        lr, mu, wd, b1, b2, eps = 1e-3, 0.95, 0.1, 0.9, 0.95, 1e-8
+        handle = f"muon:{lr},{mu},{wd},{b1},{b2},{eps}"
+        shapes = np.array([(192, 512), (1, 333), (512, 192), (256, 256),
+                           (192, 512), (64, 2048)])
+        adamw = np.array([False, True, False, False, False, False])
+        lens = shapes[:, 0] * shapes[:, 1]
+        keys = np.arange(5200, 5200 + len(lens), dtype=np.uint64)
+        starts = np.concatenate([[0], np.cumsum(lens)])
+        total = int(lens.sum())
+        rng = np.random.default_rng(SEED)
+        init = (0.02 * rng.standard_normal(total)).astype(np.float32)
+        kv.register_dense("muon_tree", keys, lens=lens, shapes=shapes,
+                          flags=np.where(adamw, KEY_ELEMENTWISE, 0),
+                          init=init)
+        if eng.num_shards > 1:
+            try:
+                eng.push_pull("muon_tree", np.zeros((W, total), np.float32),
+                              handle)
+            except log.CheckError as exc:
+                check("a matrix would lie across chips" in str(exc),
+                      f"muon over {eng.num_shards} shards refuses by name: "
+                      f"{exc}")
+                check(eng.muon_updates == 0, "nothing ran under Muon")
+                print(f"  over {eng.num_shards} shards muon refuses by name")
+                return
+            raise AssertionError("muon over several shards did not refuse")
+        p = [init[starts[k]:starts[k + 1]].astype(np.float64).reshape(
+            shapes[k]) for k in range(len(lens))]
+        mom = [np.zeros_like(x) for x in p]
+        v = [np.zeros_like(x) for x in p]
+        worst = [0.0, 0.0]
+        for t in (1, 2):
+            g = rng.standard_normal((W, total)).astype(np.float32)
+            sent = g if t == 1 else jnp.asarray(g)
+            pulled = np.asarray(eng.push_pull("muon_tree", sent, handle))
+            gs = g.astype(np.float64).sum(axis=0)
+            for k in range(len(lens)):
+                gk = gs[starts[k]:starts[k + 1]].reshape(shapes[k])
+                got = pulled[starts[k]:starts[k + 1]].reshape(shapes[k])
+                if adamw[k]:
+                    mom[k] = b1 * mom[k] + (1 - b1) * gk
+                    v[k] = b2 * v[k] + (1 - b2) * gk * gk
+                    p[k] = (p[k] * (1 - lr * wd)
+                            - lr * np.sqrt(1 - b2 ** t) / (1 - b1 ** t)
+                            * mom[k] / (np.sqrt(v[k]) + eps))
+                    np.testing.assert_allclose(got, p[k], atol=2e-7,
+                                               err_msg=f"adamw step {t}")
+                    continue
+                was = p[k]
+                p[k], mom[k] = _muon_matrix_step(p[k], mom[k], gk, lr, mu,
+                                                 wd)
+                step = np.sqrt(np.mean((p[k] - was) ** 2))
+                diff = np.abs(got - p[k])
+                worst = [max(worst[0], diff.max() / step),
+                         max(worst[1], np.sqrt(np.mean(diff ** 2)) / step)]
+                check(diff.max() < 0.3 * step
+                      and np.sqrt(np.mean(diff ** 2)) < 0.05 * step,
+                      f"muon key {k} {tuple(shapes[k])} step {t}: off by "
+                      f"{diff.max() / step:.3f} of a step at worst, "
+                      f"{np.sqrt(np.mean(diff ** 2)) / step:.4f} rms")
+        check(eng.muon_updates == 2 and eng.muon_matrices == 5,
+              "every op ran under Muon")
+        _, (m_got, _, _, slot) = eng.opt_state("muon_tree")
+        np.testing.assert_allclose(
+            np.asarray(m_got), np.concatenate(
+                [mom[k].reshape(-1) for k in range(len(lens))
+                 if not adamw[k]]), atol=5e-6, err_msg="the momentum")
+        check(float(np.asarray(slot)[0]) == 2.0, "the step slot")
+        check(eng.opt_state_nbytes("muon_tree")
+              == 4 * int(lens[~adamw].sum()) + 8 * int(lens[adamw].sum()) + 4,
+              "the state at its own size")
+        print(f"  {len(lens)} keys ({', '.join(f'{r}x{c}' for r, c in shapes)})"
+              f": 2 steps under {handle} agree, at worst {worst[0]:.3f} of a "
+              f"step in an element, {worst[1]:.4f} rms")
 
     def sparse(self) -> None:
         import pslite_tpu as ps

@@ -16,7 +16,7 @@ from typing import Dict, Optional
 
 import numpy as np
 
-from .parallel.engine import STEP_SLOT_KINDS
+from .parallel.engine import step_slot
 from .utils import logging as log
 
 
@@ -65,11 +65,13 @@ def save_engine_orbax(engine, path: str, sparse_engine=None) -> None:
             kind, states = opt
             slots = []
             for i, s in enumerate(states):
-                if kind in STEP_SLOT_KINDS and i == 2:
+                if i == step_slot(kind, len(states)):
                     # Per-shard step counter -> one entry (identical on
                     # every shard by construction).
                     slots.append(s.reshape(-1)[:1])
                 else:
+                    # (A slot at its own size, muon's, is shorter and
+                    # stays whole.)
                     slots.append(s[: bucket.total_len])
             state["opt"][name] = {f"k_{kind}": slots}
     if sparse_engine is not None:
@@ -132,7 +134,7 @@ def _restore_orbax_v2(engine, path: str, sparse_engine, saved_md) -> None:
         opt_kinds[name] = kind
         tslots = []
         for i, m in enumerate(slots):
-            repl = kind in STEP_SLOT_KINDS and i == 2  # the step scalar
+            repl = i == step_slot(kind, len(slots))  # the step scalar
             tslots.append(_sds(
                 tuple(m.shape),
                 getattr(m, "dtype", np.float32),
@@ -298,7 +300,7 @@ def save_engine(engine, path: str, sparse_engine=None) -> None:
             meta["opt"][name] = {"kind": kind, "n": len(states)}
             for i, s in enumerate(states):
                 host = np.asarray(s)
-                if kind in STEP_SLOT_KINDS and i == 2:
+                if i == step_slot(kind, len(states)):
                     # Per-shard step counter -> one scalar (identical on
                     # every shard by construction).
                     host = host.reshape(-1)[:1]

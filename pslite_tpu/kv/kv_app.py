@@ -846,7 +846,7 @@ class KVWorker:
 
     def register_dense(self, name: str, keys, val_len: Optional[int] = None,
                        dtype=None, init=None, lens=None, flags=None,
-                       job_dtype=None):
+                       job_dtype=None, shapes=None):
         """Register a dense bucket on the collective engine; subsequent
         push/pull on exactly these keys ride jitted ICI collectives.  The
         analog of the reference's first-touch rendezvous + registration
@@ -856,8 +856,12 @@ class KVWorker:
         ``KVPairs.lens`` gives it on the message path.  A call on these
         keys that carries no ``lens``, or the registered ones, is then the
         engine's; ``flags`` (a word a key, ``parallel.engine.KEY_NO_DECAY``
-        / ``KEY_NO_ADAPT``) is read by a server handle that treats keys
-        apart (``lamb:...``).
+        / ``KEY_NO_ADAPT`` / ``KEY_ELEMENTWISE``) is read by a server handle
+        that treats keys apart (``lamb:...``, ``muon:...``); ``shapes``
+        (``(rows, cols)`` a key, ``rows * cols`` the key's length) by one
+        that works on whole matrices (``muon:...``: a key not flagged
+        ``KEY_ELEMENTWISE`` is a matrix to it, and it refuses by name where
+        a matrix would lie across chips).
 
         ``job_dtype`` (default ``dtype``): what this job pushes and what
         ``push_pull`` / ``pull`` hand back, where it is narrower than the
@@ -884,7 +888,7 @@ class KVWorker:
                 (len(old.keys), old.keys.item(0), old.keys.item(-1)), None)
         bucket = engine.register_dense(name, keys, val_len, dtype=dtype,
                                        init=init, lens=lens, flags=flags,
-                                       job_dtype=job_dtype)
+                                       job_dtype=job_dtype, shapes=shapes)
         self._dense_routes[(len(keys), keys.item(0), keys.item(-1))] = name
         self._routes_mixed = any(
             engine.bucket(n).mixed for n in self._dense_routes.values())
@@ -994,7 +998,7 @@ class KVWorker:
         lies in a ``ps.kv.op`` span, whose metadata also names the kind
         of the server handle: the one a call brought (``push_sparse``; the
         engine is given it among ``args``), for a dense op the engine's
-        own (``adam``, ``lamb``).  Callers pass everything by
+        own (``adam``, ``lamb``, ``muon``).  Callers pass everything by
         position, and the dispatch is not a method of its own: on the
         chip's host a Python call costs this path 2-3 us, a keyword call
         half a microsecond more (PERF.md, PR 24).

@@ -51,7 +51,9 @@ class DenseBucket:
     registered with ``lens`` (the reference's ``KVPairs.lens``) keeps each
     key's own length instead, ``keys[i]`` owning ``lens[i]`` values from
     ``starts[i]``, and a flag word a key (``KEY_NO_DECAY``,
-    ``KEY_NO_ADAPT``) for a handle that treats keys apart (``lamb``).
+    ``KEY_NO_ADAPT``, ``KEY_ELEMENTWISE``) for a handle that treats keys
+    apart (``lamb``, ``muon``); with ``shapes`` besides, each key's
+    ``(rows, cols)`` for a handle that works on whole matrices (``muon``).
 
     ``dtype`` is the store's, the optimizer state's and every norm's;
     ``job_dtype`` what the job pushes and is handed back (one field: a
@@ -68,6 +70,7 @@ class DenseBucket:
     padded_len: int  # see _padded_len
     lens: Optional[np.ndarray] = None  # int64 a key
     flags: Optional[np.ndarray] = None  # int32 a key, with ``lens``
+    shapes: Optional[np.ndarray] = None  # int64 (rows, cols) a key
     job_dtype: object = None  # np.dtype; None: the store's
     # Application bytes one push (or one pull) moves, in the job's dtype:
     # the byte counters' unit.
@@ -81,6 +84,9 @@ class DenseBucket:
     # ((padded_len, shards), fused_update.LambPlan) once ``lamb`` has bound
     # the bucket: ``CollectiveEngine._lamb_plan``.
     lamb_plan: Optional[tuple] = field(init=False, default=None)
+    # ops.muon.MuonPlan once ``muon`` has bound the bucket (or its state
+    # was asked for): ``CollectiveEngine._muon_plan``.
+    muon_plan: Optional[object] = field(init=False, default=None)
 
     def __post_init__(self):
         self.job_dtype = np.dtype(
@@ -92,15 +98,27 @@ class DenseBucket:
                 [[0], np.cumsum(self.lens)]).astype(np.int32)
             self.segments_key = (self.starts.tobytes()
                                  + self.flags.tobytes())
+            if self.shapes is not None:
+                self.segments_key += self.shapes.tobytes()
 
 
 # Flags of a key in a bucket registered with ``lens``: LAMB leaves the key
-# out of the weight decay / gives it trust ratio 1.
+# out of the weight decay / gives it trust ratio 1; Muon leaves the key to
+# AdamW (an embedding, an output head, a gain: no matrix to it).
 KEY_NO_DECAY = 1
 KEY_NO_ADAPT = 2
+KEY_ELEMENTWISE = 4
 
-# Optimizer state kinds whose last slot is the per-shard step counter.
-STEP_SLOT_KINDS = ("adam", "lamb")
+# Optimizer state kinds whose LAST slot is the per-shard step counter
+# (``adam`` and ``lamb``: m, v, the step; ``muon``: the momentum, AdamW's m
+# and v over its own keys, the step).
+STEP_SLOT_KINDS = ("adam", "lamb", "muon")
+
+
+def step_slot(kind: str, n_slots: int) -> Optional[int]:
+    """Which of a state's ``n_slots`` slots counts the steps; None where
+    the kind counts none."""
+    return n_slots - 1 if kind in STEP_SLOT_KINDS else None
 
 
 def _lamb_ratios(sq, adapt):
@@ -455,6 +473,13 @@ class CollectiveEngine:
         # Ops on a bucket whose job dtype is narrower than its store's
         # (``engine.dense.narrow``).
         self.narrow_ops = 0
+        # Ops whose program applied Muon (``engine.update.muon``), and
+        # what a step of the last of them holds by its plan: matrices and
+        # Newton-Schulz FLOPs (``engine.update.muon.matrices``,
+        # ``.ns_flops``).
+        self.muon_updates = 0
+        self.muon_matrices = 0
+        self.muon_ns_flops = 0.0
 
     # -- registration --------------------------------------------------------
 
@@ -468,6 +493,7 @@ class CollectiveEngine:
         lens=None,
         flags=None,
         job_dtype=None,
+        shapes=None,
     ) -> DenseBucket:
         """Register a dense bucket and allocate its sharded store.
 
@@ -477,7 +503,10 @@ class CollectiveEngine:
 
         ``val_len`` values a key, or ``lens``: a length for each key (the
         reference's ``KVPairs.lens``), and with them ``flags``, a word a
-        key of ``KEY_NO_DECAY`` / ``KEY_NO_ADAPT`` (default 0).
+        key of ``KEY_NO_DECAY`` / ``KEY_NO_ADAPT`` / ``KEY_ELEMENTWISE``
+        (default 0), and ``shapes``, ``(rows, cols)`` a key with
+        ``rows * cols == len`` (a vector: ``(1, len)``), which a handle
+        that works on whole matrices reads (``muon``).
 
         ``job_dtype`` (default ``dtype``): what the job pushes and what
         ``push_pull`` and ``pull`` hand back, where that is narrower than
@@ -520,6 +549,20 @@ class CollectiveEngine:
             log.check(total < 2 ** 31,
                       f"bucket {name!r}: {total:,} values; the keys' "
                       f"borders are kept as int32")
+        if shapes is not None:
+            log.check(lens is not None,
+                      f"bucket {name!r}: per-key shapes need per-key lens")
+            shapes = np.ascontiguousarray(np.asarray(shapes, dtype=np.int64))
+            log.check(shapes.shape == (len(keys), 2) and (shapes >= 0).all(),
+                      f"bucket {name!r}: shapes must give (rows, cols) for "
+                      f"each of the {len(keys)} keys, got shape "
+                      f"{shapes.shape}")
+            off = np.flatnonzero(shapes[:, 0] * shapes[:, 1] != lens)
+            log.check(off.size == 0,
+                      f"bucket {name!r}: rows * cols must be the key's len; "
+                      f"key {off[:1].tolist()} has shape "
+                      f"{shapes[off[:1]].tolist()} and len "
+                      f"{lens[off[:1]].tolist()}")
         if job_dtype is not None and np.dtype(job_dtype) != np.dtype(dtype):
             job, own = np.dtype(job_dtype), np.dtype(dtype)
             log.check(
@@ -549,6 +592,7 @@ class CollectiveEngine:
             lens=lens,
             flags=flags,
             job_dtype=job_dtype,
+            shapes=shapes,
         )
         sharding = NamedSharding(self.mesh, P(self.axis))
         if init is not None:
@@ -622,7 +666,9 @@ class CollectiveEngine:
         per shard inside shard_map, applying the whole optimizer step as
         one Pallas pass over the shard (the aggregation hot loop of
         kv_app.h:430-452 fused with the reduce-scatter's output).
-        ``lamb`` is told the ``bucket`` whose keys' borders it needs.
+        ``lamb`` and ``muon`` are told the ``bucket`` whose keys' borders
+        (and shapes) they need; ``muon``'s state is laid out by its plan
+        (:meth:`_state_shapes`), not as slots of the store's shape.
         """
         from ..ops import fused_update
 
@@ -630,6 +676,9 @@ class CollectiveEngine:
         if self._needs_segments(handle):
             log.check(bucket is not None and bucket.lens is not None,
                       self._segments_refusal(handle, bucket))
+            if handle.startswith("muon"):
+                fn = self._muon_fn(handle, bucket)
+                return len(self._muon_plan(bucket).chunks) + 3, fn
             return 3, self._lamb_fn(handle, bucket)
         if handle.startswith("sgd_momentum"):
             lr, momentum = self._handle_params(handle, (0.01, 0.9))
@@ -761,6 +810,72 @@ class CollectiveEngine:
 
         return fn
 
+    def _muon_fn(self, handle: str, bucket: DenseBucket) -> Callable:
+        """``muon:lr,mu,wd,b1,b2,eps`` on the one shard that holds
+        ``bucket`` (``ops/muon.py`` has the recurrence): a matrix key is
+        updated by its orthogonalised momentum, five Newton-Schulz steps
+        in bfloat16 with Nesterov and the published coefficients (the
+        optimizer's, no parameters), a key flagged ``KEY_ELEMENTWISE`` by
+        AdamW.  It needs what no flat bucket says, each key's shape, and
+        every matrix whole where its products run; where it cannot run it
+        says so by name (:meth:`_muon_refusal`) and nothing falls back to
+        an element-wise update.  The new parameters are written where
+        they lie in the store; the pulled vector is the program's cut of
+        it (:meth:`_stateful_program`)."""
+        from ..ops import muon
+
+        refusal = self._muon_refusal(handle, bucket)
+        log.check(refusal is None, refusal)
+        lr, mu, wd, b1, b2, eps = self._handle_params(
+            handle, (1e-3, 0.95, 0.1, 0.9, 0.95, 1e-8))
+        plan = self._muon_plan(bucket)
+        starts, shapes = bucket.starts, bucket.shapes
+
+        def fn(store_l, state_l, agg):
+            return muon.muon_update(
+                store_l, state_l, agg, starts, shapes, plan, lr=lr, mu=mu,
+                wd=wd, b1=b1, b2=b2, eps=eps)
+
+        return fn
+
+    def _muon_refusal(self, handle, bucket: DenseBucket) -> Optional[str]:
+        """Why ``muon`` cannot run on ``bucket`` as it lies on this mesh,
+        with what is missing; None where it can."""
+        said = f"handle {handle!r} works on whole matrices"
+        if bucket.shapes is None:
+            return (f"{said} and needs each key's (rows, cols), which "
+                    f"bucket {bucket.name!r}, registered without shapes=, "
+                    f"does not say: register it with lens= and shapes=")
+        if self.num_shards != 1:
+            return (f"{said}, and bucket {bucket.name!r} lies over "
+                    f"{self.num_shards} shards cut at any element, so a "
+                    f"matrix would lie across chips: it runs where one "
+                    f"shard holds the bucket (a bucket sharded on its keys' "
+                    f"borders does not exist yet)")
+        if bucket.mixed:
+            return (f"{said} in f32, and bucket {bucket.name!r} is pushed "
+                    f"and pulled in {bucket.job_dtype} over its "
+                    f"{np.dtype(bucket.dtype)} store, a contract "
+                    f"(register_dense(..., job_dtype=)) that only the LAMB "
+                    f"kernels carry: register it with job_dtype= left out")
+        if np.dtype(bucket.dtype) != np.float32:
+            return (f"{said} with f32 momentum and master weights, and "
+                    f"bucket {bucket.name!r} is kept in "
+                    f"{np.dtype(bucket.dtype)}: register it with "
+                    f"dtype=float32")
+        return None
+
+    def _muon_plan(self, bucket: DenseBucket):
+        """``ops.muon.muon_plan`` of ``bucket``, made once: what
+        :meth:`_muon_fn` builds the program from, the state is laid out
+        by and the ``engine.update.muon.*`` gauges read."""
+        from ..ops.muon import muon_plan
+
+        if bucket.muon_plan is None:
+            bucket.muon_plan = muon_plan(
+                bucket.shapes, (bucket.flags & KEY_ELEMENTWISE) != 0)
+        return bucket.muon_plan
+
     @staticmethod
     def _is_stateful(handle) -> bool:
         return isinstance(handle, str) and (
@@ -768,13 +883,15 @@ class CollectiveEngine:
             or handle.startswith("adam")
             or handle.startswith("adagrad")
             or handle.startswith("lamb")
+            or handle.startswith("muon")
         )
 
     @staticmethod
     def _needs_segments(handle) -> bool:
         """Whether the handle's update of an element depends on which key
         the element belongs to."""
-        return isinstance(handle, str) and handle.startswith("lamb")
+        return isinstance(handle, str) and (handle.startswith("lamb")
+                                            or handle.startswith("muon"))
 
     @staticmethod
     def _segments_refusal(handle, bucket: Optional[DenseBucket]) -> str:
@@ -783,9 +900,10 @@ class CollectiveEngine:
                  if bucket is None else
                  f"bucket {bucket.name!r}, registered with one val_len for "
                  f"all its keys")
-        return (f"handle {handle!r} takes a norm over each key and needs "
-                f"the keys' own lengths, which {where} does not have: "
-                f"register the bucket with lens= and use push_pull or push")
+        return (f"handle {handle!r} treats each key apart (a norm over the "
+                f"key, the key as a matrix) and needs the keys' own "
+                f"lengths, which {where} does not have: register the "
+                f"bucket with lens= and use push_pull or push")
 
     @staticmethod
     def _mixed_refusal(name: str, job, own, what: str, instead: str) -> str:
@@ -1309,7 +1427,19 @@ class CollectiveEngine:
         t0 = stamp()
         sharding = NamedSharding(self.mesh, P(self.axis))
         dt = np.dtype(bucket.dtype)
-        if kind in ("sgd_momentum", "adagrad"):
+        if kind == "muon":
+            # At its own size: a momentum a chunk of matrices, m and v over
+            # the AdamW keys alone, the step.  Zero-filled where it lies.
+            import jax.numpy as jnp
+
+            from ..ops.muon import state_shapes
+
+            state = (
+                *(jnp.zeros(shape, dt, device=sharding)
+                  for shape in state_shapes(self._muon_plan(bucket))),
+                self._place(np.zeros(self.num_shards, np.float32), sharding),
+            )
+        elif kind in ("sgd_momentum", "adagrad"):
             state = (self._place(np.zeros(bucket.padded_len, dt), sharding),)
         else:  # adam, lamb: m, v, the step
             state = (
@@ -1323,15 +1453,31 @@ class CollectiveEngine:
 
     def opt_state(self, name: str):
         """Snapshot of the bucket's optimizer state (checkpointing).
-        Returns (kind, arrays) or None when the bucket has none."""
+        Returns (kind, arrays) or None when the bucket has none.  Under
+        ``muon`` the arrays are the state's logical form, whatever the
+        chunks it is kept in: the momentum as one vector over the Muon
+        keys in key order (a key's values as its matrix lies in the
+        store), AdamW's m and v over its keys in key order, the step."""
         import jax.numpy as jnp
 
         with self._bucket_mu[name]:
             if name not in self._opt_states:
                 return None
-            return self._opt_kinds[name], tuple(
-                jnp.copy(s) for s in self._opt_states[name]
-            )
+            kind, state = self._opt_kinds[name], self._opt_states[name]
+            if kind == "muon":
+                from ..ops.muon import momentum_vector
+
+                plan = self._muon_plan(self._buckets[name])
+                n = len(plan.chunks)
+                return kind, (momentum_vector(plan, state[:n], jnp),
+                              *(jnp.copy(s) for s in state[n:]))
+            return kind, tuple(jnp.copy(s) for s in state)
+
+    def opt_state_nbytes(self, name: str) -> int:
+        """Bytes the bucket's optimizer state holds on the devices (0:
+        none yet), the step slot included."""
+        with self._bucket_mu[name]:
+            return sum(int(s.nbytes) for s in self._opt_states.get(name, ()))
 
     def set_opt_state(self, name: str, kind: str, values) -> None:
         """Restore optimizer state (checkpoint resume).
@@ -1349,11 +1495,14 @@ class CollectiveEngine:
         log.check(name in self._buckets, f"bucket {name!r} not registered")
         bucket = self._buckets[name]
         sharding = NamedSharding(self.mesh, P(self.axis))
+        if kind == "muon":
+            self._set_muon_state(bucket, values, sharding)
+            return
         norm = []
         placed_device = {}
         for i, v in enumerate(values):
-            if isinstance(v, jax.Array) and not (
-                    kind in STEP_SLOT_KINDS and i == 2):
+            if isinstance(v, jax.Array) and i != step_slot(
+                    kind, len(values)):
                 # Fleet-portable DEVICE restore (orbax v2): logical
                 # vectors pad+reshard on device, no host fetch.
                 import jax.numpy as jnp
@@ -1383,7 +1532,7 @@ class CollectiveEngine:
                 norm.append(None)
                 continue
             arr = np.ascontiguousarray(np.asarray(v))
-            if kind in STEP_SLOT_KINDS and i == 2:
+            if i == step_slot(kind, len(values)):
                 step = float(arr.reshape(-1)[0]) if arr.size else 0.0
                 arr = np.full(self.num_shards, step, np.float32)
             else:
@@ -1408,6 +1557,46 @@ class CollectiveEngine:
         with self._bucket_mu[name]:
             self._opt_states[name] = placed
             self._opt_kinds[name] = kind
+
+    def _set_muon_state(self, bucket: DenseBucket, values, sharding) -> None:
+        """``set_opt_state`` under ``muon``: ``values`` in the logical
+        form :meth:`opt_state` hands out (host or device arrays), laid
+        into the plan's chunks on the device."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops.muon import momentum_chunks
+
+        refusal = self._muon_refusal("muon", bucket)
+        log.check(refusal is None, refusal)
+        plan = self._muon_plan(bucket)
+        log.check_eq(len(values), 4,
+                     f"bucket {bucket.name!r}: muon's state is the momentum, "
+                     f"AdamW's m and v and the step")
+        dt = np.dtype(bucket.dtype)
+        vectors = []
+        for v, want, what in zip(
+                values, (plan.muon_len, plan.adamw_len, plan.adamw_len),
+                ("momentum (a value a Muon value)", "AdamW m", "AdamW v")):
+            log.check_eq(np.dtype(v.dtype), dt,
+                         f"bad opt restore dtype for bucket {bucket.name!r}")
+            log.check_eq(int(np.size(v)), want,
+                         f"bad optimizer state length for bucket "
+                         f"{bucket.name!r}: {what}")
+            vectors.append(jnp.asarray(v).reshape(-1))
+        step = np.asarray(values[3]).reshape(-1)
+        placed = (
+            *(jax.device_put(c, sharding)
+              for c in momentum_chunks(plan, vectors[0], jnp)),
+            jax.device_put(vectors[1], sharding),
+            jax.device_put(vectors[2], sharding),
+            self._place(np.full(self.num_shards,
+                                float(step[0]) if step.size else 0.0,
+                                np.float32), sharding),
+        )
+        with self._bucket_mu[bucket.name]:
+            self._opt_states[bucket.name] = placed
+            self._opt_kinds[bucket.name] = "muon"
 
     # -- data plane ops ------------------------------------------------------
 
@@ -1724,10 +1913,8 @@ class CollectiveEngine:
                 prep = self._prep_grads_whole
             if self._needs_segments(resolved) or bucket.mixed:
                 prog = self._counted(
-                    prog, self._needs_segments(resolved),
-                    self._kernel_pulls(op, resolved, bucket), bucket.mixed,
-                    self._lamb_plan(bucket).one_pass_len
-                    if self._needs_segments(resolved) else 0)
+                    prog, resolved.split(":", 1)[0], bucket,
+                    self._kernel_pulls(op, resolved, bucket))
         elif impl == "pallas":
             if self.worker_axis is None:
                 prep = self._prep_grads_ring
@@ -1766,12 +1953,12 @@ class CollectiveEngine:
         (not the store in their place, and not nothing), the handle is
         ``lamb`` (whose second pass can leave them,
         ``fused_update.lamb_apply``), and one shard holds the whole
-        bucket.  Over several shards they are the all-gather of the
-        shards, cut at ``total_len``."""
+        bucket.  Over several shards, and under every other handle, they
+        are the all-gather of the shards, cut at ``total_len``."""
         from ..ops.fused_update import lamb_apply_pulls
 
         return (op == "push_pull_st" and self.num_shards == 1
-                and bucket is not None and self._needs_segments(handle)
+                and bucket is not None and handle.startswith("lamb")
                 and lamb_apply_pulls(bucket.total_len))
 
     def _lamb_plan(self, bucket: DenseBucket):
@@ -1787,19 +1974,29 @@ class CollectiveEngine:
             bucket.lamb_plan = (layout, lamb_plan(bucket.starts, *layout))
         return bucket.lamb_plan[1]
 
-    def _counted(self, prog: Callable, lamb: bool, kernel_pulls: bool,
-                 narrow: bool, one_pass: int) -> Callable:
+    def _counted(self, prog: Callable, kind: str, bucket: DenseBucket,
+                 kernel_pulls: bool) -> Callable:
         """``prog`` behind the counts of ``engine.update.lamb`` (with
         ``engine.update.lamb.one_pass``, the elements that the last such
-        program updated in one pass), where the program takes its pulled
-        values from ``lamb_apply`` of ``engine.pull.from_kernel``, and on
-        a mixed bucket of ``engine.dense.narrow``: what a record of
-        :meth:`_bind` knows is counted by the record's own program, and
-        no other op pays for it."""
+        program updated in one pass) or ``engine.update.muon`` (with
+        ``.matrices`` and ``.ns_flops``, a step's by the plan of the last
+        such program), where the program takes its pulled values from
+        ``lamb_apply`` of ``engine.pull.from_kernel``, and on a mixed
+        bucket of ``engine.dense.narrow``: what a record of :meth:`_bind`
+        knows is counted by the record's own program, and no other op pays
+        for it."""
+        lamb, muon, narrow = kind == "lamb", kind == "muon", bucket.mixed
+        one_pass = self._lamb_plan(bucket).one_pass_len if lamb else 0
+        plan = self._muon_plan(bucket) if muon else None
+
         def counted(*args):
             if lamb:
                 self.lamb_updates += 1
                 self.lamb_one_pass = one_pass
+            elif muon:
+                self.muon_updates += 1
+                self.muon_matrices = plan.matrices
+                self.muon_ns_flops = plan.ns_flops
             self.kernel_pulls += kernel_pulls
             self.narrow_ops += narrow
             return prog(*args)
@@ -1812,6 +2009,11 @@ class CollectiveEngine:
         registry.gauge("engine.update.lamb", fn=lambda: self.lamb_updates)
         registry.gauge("engine.update.lamb.one_pass",
                        fn=lambda: self.lamb_one_pass)
+        registry.gauge("engine.update.muon", fn=lambda: self.muon_updates)
+        registry.gauge("engine.update.muon.matrices",
+                       fn=lambda: self.muon_matrices)
+        registry.gauge("engine.update.muon.ns_flops",
+                       fn=lambda: self.muon_ns_flops)
         registry.gauge("engine.pull.from_kernel",
                        fn=lambda: self.kernel_pulls)
         registry.gauge("engine.dense.narrow", fn=lambda: self.narrow_ops)
@@ -2910,7 +3112,21 @@ class CollectiveEngine:
                     )
                 if opt is not None:
                     kind, arrs = opt
-                    if kind in ("sgd_momentum", "adagrad"):
+                    if kind == "muon":
+                        # One shard to one shard: the chunks as they are.
+                        log.check(
+                            new_num_shards == 1,
+                            f"bucket {n!r} is under muon, which works on "
+                            f"whole matrices, and the new mesh has "
+                            f"{new_num_shards} shards cut at any element, "
+                            f"so a matrix would lie across chips: it "
+                            f"reshards onto one shard alone")
+                        step = float(arrs[-1][0]) if len(arrs[-1]) else 0.0
+                        state = (
+                            *(_nplace(a, sharding) for a in arrs[:-1]),
+                            _nplace(np.full(1, step, np.float32), sharding),
+                        )
+                    elif kind in ("sgd_momentum", "adagrad"):
                         state = (
                             _repad(arrs[0], b.total_len, padded, b.dtype),
                         )

@@ -33,10 +33,37 @@ def test_smoke_phases_at_tiny_size(capsys):
                          expired=lambda name, s: hung.append(name))
     assert not hung
     out = capsys.readouterr().out
-    for phase in ("boot", "resnet50", "readme", "lamb", "mixed", "sparse",
-                  "message_path", "shutdown"):
+    for phase in ("boot", "resnet50", "readme", "lamb", "mixed", "muon",
+                  "sparse", "message_path", "shutdown"):
         assert f"phase {phase}: ok" in out
     assert "W = 8, kernels interpreted" in out
+    assert "over 8 shards muon refuses by name" in out
+
+
+def test_the_muon_phase_where_one_shard_holds_the_bucket(capsys, monkeypatch):
+    """On one device the phase runs its two steps against the float64
+    recurrence (on the 8-device mesh above it must refuse by name)."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from pslite_tpu.parallel.engine import CollectiveEngine
+
+    mesh = Mesh(np.array(jax.devices()[:1]), ("kv",))
+
+    def one_shard(self):
+        self.kv.po.van.engine = CollectiveEngine(
+            mesh=mesh, server_handle=chip_smoke.SERVER_HANDLE)
+        self.muon()
+
+    monkeypatch.setattr(
+        chip_smoke._Smoke, "phases",
+        lambda self: [("boot", 60, self.boot),
+                      ("muon", 150, lambda: one_shard(self)),
+                      ("shutdown", 30, self.shutdown)])
+    chip_smoke.run_smoke(default_mesh(), TINY)
+    out = capsys.readouterr().out
+    assert "phase muon: ok" in out
+    assert "2 steps under muon:0.001,0.95,0.1,0.9,0.95,1e-08 agree" in out
 
 
 def test_failing_phase_is_named(monkeypatch):

@@ -1,0 +1,566 @@
+"""``muon:lr,mu,wd,b1,b2,eps``: the first server handle that works on whole
+matrices, on a dense bucket registered with its keys' lengths AND shapes
+(``lens``, ``shapes``).
+
+Through ``KVWorker.push_pull`` / ``push`` / ``pull`` on the engine path,
+against ``benchmark/muon_reference.py`` (numpy, float64 outside the
+products, operands rounded by ``reference.bf16``, one matrix at a time,
+imports nothing of the program), on the one shard the handle runs on.  The
+tree has wide, tall and square matrices, a 64-row router, equal shapes that
+share a batched product, a key on no lane border between two matrices and
+AdamW keys beside the Muon keys.
+
+Two bfloat16 computations of one recurrence differ by roundings that flip,
+so a Muon key is held to the reference within a few bfloat16 steps of its
+update (``TOL``, of the root mean square of one step of the key: 0.3 in any
+element, 0.03 in the root mean square, where the program reads 0.01 and a
+skipped Newton-Schulz step 0.8), which a missing Nesterov term or a wrong
+scale miss by far; an AdamW key is held to f32 rounding.
+"""
+
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
+
+from pslite_tpu import KVServer, KVServerDefaultHandle, KVWorker  # noqa: E402
+from pslite_tpu import checkpoint  # noqa: E402
+from pslite_tpu.ops import muon  # noqa: E402
+from pslite_tpu.parallel.engine import (CollectiveEngine,  # noqa: E402
+                                        KEY_ELEMENTWISE)
+from pslite_tpu.utils import logging as log  # noqa: E402
+
+from helpers import LoopbackCluster  # noqa: E402
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+import muon_flops  # noqa: E402
+from muon_reference import MuonReference, parse_muon_handle  # noqa: E402
+
+HANDLE = "muon:1e-3,0.95,0.1,0.9,0.95,1e-8"
+HYPER = parse_muon_handle(HANDLE)
+# name, (rows, cols), AdamW.  Between the two matrices of 96 x 256 lies a
+# key of 77 values: no matrix after it starts on a lane border.
+TREE = [
+    ("emb.w", (40, 256), True),
+    ("wide.0", (96, 256), False),
+    ("gain", (1, 77), True),
+    ("wide.1", (96, 256), False),
+    ("tall.0", (256, 96), False),        # the same group, transposed into it
+    ("square", (128, 128), False),
+    ("router", (64, 256), False),
+    ("tall.1", (256, 96), False),
+    ("kv_b", (192, 32), False),
+    ("norm", (1, 300), True),
+]
+NAMES = [n for n, _, _ in TREE]
+SHAPES = np.array([s for _, s, _ in TREE])
+ADAMW = np.array([a for _, _, a in TREE])
+LENS = SHAPES[:, 0] * SHAPES[:, 1]
+FLAGS = np.where(ADAMW, KEY_ELEMENTWISE, 0)
+KEYS = np.arange(100, 100 + len(TREE), dtype=np.uint64)
+TOTAL = int(LENS.sum())
+STARTS = np.concatenate([[0], np.cumsum(LENS)])
+# Of the root mean square of one step: any element, the root mean square.
+TOL = (0.3, 0.03)
+
+
+def _mesh(n):
+    return Mesh(np.array(jax.devices()[:n]), ("kv",))
+
+
+def _split(flat, starts=STARTS):
+    return [np.asarray(flat)[..., starts[k]:starts[k + 1]]
+            for k in range(len(starts) - 1)]
+
+
+def _init(rng, total=TOTAL):
+    return (0.02 * rng.normal(size=total)).astype(np.float32)
+
+
+def _reference(init, shapes=SHAPES, adamw=ADAMW, starts=STARTS, **kw):
+    return MuonReference(_split(init, starts), shapes, adamw, **HYPER, **kw)
+
+
+def _engine(shards=1, handle=HANDLE):
+    return CollectiveEngine(mesh=_mesh(shards), server_handle=handle)
+
+
+def _register(eng, init, name="t", **kw):
+    args = dict(lens=LENS, flags=FLAGS, shapes=SHAPES, init=init)
+    args.update(kw)
+    return eng.register_dense(name, KEYS, **args)
+
+
+def _hold(got, ref, before, adamw=ADAMW, starts=STARTS, where=""):
+    """``got`` (the flat pulled vector) against the reference's parameters
+    after a step that began at ``before``."""
+    for k, (g, want, was) in enumerate(zip(_split(got, starts), ref.p,
+                                           before)):
+        diff = np.abs(np.asarray(g, np.float64) - want)
+        if adamw[k]:
+            assert diff.max() < 2e-7, (where, k, diff.max())
+            continue
+        step = np.sqrt(np.mean((want - was) ** 2))
+        assert diff.max() < TOL[0] * step, (where, k, diff.max(), step)
+        assert np.sqrt(np.mean(diff ** 2)) < TOL[1] * step, (
+            where, k, np.sqrt(np.mean(diff ** 2)), step)
+
+
+def _step(ref, grads):
+    before = [p.copy() for p in ref.p]
+    ref.step(grads)
+    return before
+
+
+@pytest.fixture()
+def cluster():
+    c = LoopbackCluster(num_workers=1, num_servers=1, van_type="ici",
+                        env_extra={"PS_ICI_SERVER_HANDLE": HANDLE})
+    c.start()
+    server = KVServer(0, postoffice=c.servers[0])   # the message path's
+    server.set_request_handle(KVServerDefaultHandle())
+    yield c
+    c.finalize()
+
+
+def _worker(cluster, shards=1):
+    po = cluster.workers[0]
+    assert po.van.engine._server_handle == HANDLE    # from the environment
+    po.van.engine = _engine(shards)
+    po.van.engine.export(po.metrics)
+    return KVWorker(0, 0, postoffice=po)
+
+
+# -- the handle against the reference -----------------------------------------
+
+
+@pytest.mark.parametrize("origin", ["host", "device"])
+def test_muon_through_kvworker_equals_the_reference(cluster, origin):
+    """Three steps from a seeded store, every kind of key in one bucket."""
+    kv = _worker(cluster)
+    eng = kv.engine
+    rng = np.random.default_rng(3)
+    init = _init(rng)
+    bucket = kv.register_dense("tree", KEYS, lens=LENS, flags=FLAGS,
+                               shapes=SHAPES, init=init)
+    assert bucket.total_len == TOTAL and bucket.shapes.shape == (10, 2)
+    ref = _reference(init)
+    for step in range(3):
+        g = rng.normal(size=(1, TOTAL)).astype(np.float32)
+        sent = (jax.device_put(g, NamedSharding(eng.mesh, P(eng.axis, None)))
+                if origin == "device" else g)
+        ts = kv.push_pull(KEYS, sent, None)
+        pulled = kv.get_pulled(ts)
+        kv.wait(ts)
+        before = _step(ref, _split(g))
+        assert pulled.shape == (TOTAL,)
+        _hold(pulled, ref, before, where=step)
+    kind, (mom, m, v, slot) = eng.opt_state("tree")
+    assert kind == "muon"
+    np.testing.assert_array_equal(np.asarray(slot), 3.0)
+    # The momentum is a sum of f32 gradients: f32 rounding, no bf16 in it.
+    want = np.concatenate([ref.m[k] for k in range(len(TREE))
+                           if not ADAMW[k]])
+    np.testing.assert_allclose(np.asarray(mom), want, atol=2e-6)
+    np.testing.assert_allclose(
+        np.asarray(m), np.concatenate([ref.m[k] for k in range(len(TREE))
+                                       if ADAMW[k]]), atol=1e-7)
+    assert eng.push_bytes == eng.pull_bytes == 3 * 4 * TOTAL
+    gauges = kv.po.metrics.snapshot()["gauges"]
+    assert gauges["engine.update.muon"] == 3
+    assert gauges["engine.update.muon.matrices"] == 7
+    assert gauges["engine.update.muon.ns_flops"] == muon_flops.published(
+        [tuple(s) for s in SHAPES[~ADAMW]])
+    assert gauges["engine.pull.from_kernel"] == 0
+    assert gauges["engine.update.lamb"] == 0
+    out = np.zeros(TOTAL, np.float32)
+    kv.wait(kv.pull(KEYS, out))
+    np.testing.assert_array_equal(out, np.asarray(pulled))
+
+
+@pytest.mark.parametrize("name, shape", [
+    ("wide", (48, 160)), ("tall", (160, 48)), ("square", (64, 64)),
+    ("router", (64, 2048)), ("one_row_short", (127, 128))])
+def test_one_matrix_of_each_orientation(name, shape):
+    """A bucket of one key: what the batched group does to a single
+    matrix, three steps."""
+    eng = _engine()
+    rng = np.random.default_rng(len(name))
+    n = shape[0] * shape[1]
+    init = _init(rng, n)
+    eng.register_dense("t", KEYS[:1], lens=[n], shapes=[shape], init=init)
+    starts = np.array([0, n])
+    ref = _reference(init, [shape], [False], starts)
+    for step in range(3):
+        g = rng.normal(size=(1, n)).astype(np.float32)
+        pulled = eng.push_pull("t", g)
+        before = _step(ref, [g])
+        _hold(pulled, ref, before, [False], starts, where=(name, step))
+
+
+def test_a_batched_group_equals_its_keys_one_at_a_time():
+    """Two wide and two tall keys of one side go through ONE batched
+    product; each alone in a bucket of its own gives the same values bit
+    for bit (a batch is a loop over its matrices to the MXU and to the CPU
+    alike), and the plan says which went together."""
+    eng = _engine()
+    rng = np.random.default_rng(11)
+    init = _init(rng)
+    _register(eng, init)
+    plan = eng._muon_plan(eng.bucket("t"))
+    group = next(c for c in plan.chunks if (c.m, c.n) == (96, 256))
+    assert [NAMES[k] for k in group.keys] == ["wide.0", "wide.1", "tall.0",
+                                             "tall.1"]
+    assert group.tall == (False, False, True, True)
+    assert sorted((c.m, c.n, len(c.keys)) for c in plan.chunks) == [
+        (32, 192, 1), (64, 256, 1), (96, 256, 4), (128, 128, 1)]
+    grads = [rng.normal(size=(1, TOTAL)).astype(np.float32)
+             for _ in range(2)]
+    for g in grads:
+        together = np.asarray(eng.push_pull("t", g))
+    for k in group.keys:
+        alone = _engine()
+        sl = slice(STARTS[k], STARTS[k + 1])
+        alone.register_dense("k", KEYS[:1], lens=LENS[k:k + 1],
+                             shapes=SHAPES[k:k + 1], init=init[sl])
+        for g in grads:
+            pulled = np.asarray(alone.push_pull("k", g[:, sl]))
+        np.testing.assert_array_equal(pulled, together[sl])
+
+
+def test_a_group_larger_than_a_chunk_is_cut_in_key_order():
+    shapes = [(8, 16)] * 5 + [(16, 8)] * 2
+    plan = muon.muon_plan(shapes, [False] * 7, chunk_values=3 * 128)
+    assert [(c.keys, c.tall) for c in plan.chunks] == [
+        ((0, 1, 2), (False,) * 3), ((3, 4, 5), (False, False, True)),
+        ((6,), (True,))]
+    # One key at least, whatever its size.
+    assert len(muon.muon_plan([(64, 64)], [False], 10).chunks) == 1
+
+
+def test_two_workers_are_summed_in_f32():
+    """W = 2 on a (dp, kv) = (2, 1) mesh: one shard holds the bucket and
+    the workers' rows are summed before the handle sees them."""
+    mesh = Mesh(np.array(jax.devices()[:2]).reshape(2, 1), ("dp", "kv"))
+    eng = CollectiveEngine(mesh=mesh, axis_name="kv", worker_axis="dp",
+                           server_handle=HANDLE)
+    assert eng.num_workers == 2 and eng.num_shards == 1
+    rng = np.random.default_rng(5)
+    init = _init(rng)
+    _register(eng, init)
+    ref = _reference(init)
+    for step in range(2):
+        g = rng.normal(size=(2, TOTAL)).astype(np.float32)
+        pulled = eng.push_pull("t", g)
+        before = _step(ref, _split(g))
+        _hold(pulled, ref, before, where=step)
+
+
+def test_push_then_pull_equals_push_pull(cluster):
+    kv = _worker(cluster)
+    rng = np.random.default_rng(9)
+    init = _init(rng)
+    for name in ("a", "b"):
+        kv.register_dense(name, KEYS + (0 if name == "a" else 50),
+                          lens=LENS, flags=FLAGS, shapes=SHAPES, init=init)
+    g = rng.normal(size=(1, TOTAL)).astype(np.float32)
+    ts = kv.push_pull(KEYS, g, None)
+    both = np.asarray(kv.get_pulled(ts))
+    kv.wait(ts)
+    kv.wait(kv.push(KEYS + 50, g))
+    out = np.zeros(TOTAL, np.float32)
+    kv.wait(kv.pull(KEYS + 50, out))
+    np.testing.assert_array_equal(out, both)
+    assert kv.engine.muon_updates == 2
+
+
+def test_a_zero_gradient_decays_and_stays_finite():
+    eng = _engine()
+    init = _init(np.random.default_rng(2))
+    _register(eng, init)
+    pulled = np.asarray(eng.push_pull("t", np.zeros((1, TOTAL), np.float32)))
+    assert np.isfinite(pulled).all()
+    keep = np.float32(1.0 - HYPER["lr"] * HYPER["wd"])
+    np.testing.assert_allclose(pulled, init * keep, rtol=1e-6)
+
+
+# -- the state at its own size --------------------------------------------------
+
+
+def test_the_state_is_4_bytes_a_muon_value_and_8_an_adamw_value():
+    eng = _engine()
+    _register(eng, _init(np.random.default_rng(1)))
+    assert eng.opt_state_nbytes("t") == 0
+    eng.push_pull("t", np.ones((1, TOTAL), np.float32))
+    muon_values = int(LENS[~ADAMW].sum())
+    adamw_values = int(LENS[ADAMW].sum())
+    assert eng.opt_state_nbytes("t") == (4 * muon_values + 8 * adamw_values
+                                         + 4)
+    # Three slots of the store's shape would be:
+    assert eng.opt_state_nbytes("t") < 3 * 4 * eng.bucket("t").padded_len
+    plan = eng._muon_plan(eng.bucket("t"))
+    assert plan.state_bytes == 4 * muon_values + 8 * adamw_values
+    kind, (mom, m, v, slot) = eng.opt_state("t")
+    assert (mom.shape, m.shape, v.shape) == ((muon_values,),
+                                             (adamw_values,) , (adamw_values,))
+
+
+def test_the_momentum_vector_and_its_chunks_are_inverses():
+    plan = muon.muon_plan(SHAPES, ADAMW)
+    vector = np.arange(plan.muon_len, dtype=np.float32)
+    chunks = muon.momentum_chunks(plan, vector, np)
+    assert [c.shape for c in chunks] == [
+        (len(c.keys), c.m, c.n) for c in plan.chunks]
+    np.testing.assert_array_equal(
+        muon.momentum_vector(plan, chunks, np), vector)
+    # A tall key lies transposed in its chunk.
+    group = next(c for c in plan.chunks if (c.m, c.n) == (96, 256))
+    i = group.keys.index(NAMES.index("tall.0"))
+    lo = int(plan.mom_starts[list(plan.muon_keys).index(
+        NAMES.index("tall.0"))])
+    np.testing.assert_array_equal(
+        chunks[plan.chunks.index(group)][i],
+        vector[lo:lo + 256 * 96].reshape(256, 96).T)
+
+
+@pytest.mark.parametrize("backend", ["npz", "set_opt_state", "orbax"])
+def test_save_and_restore_carry_momentum_moments_and_the_step(tmp_path,
+                                                              backend):
+    if backend == "orbax" and not checkpoint.have_orbax():
+        pytest.skip("orbax is not installed")
+    eng = _engine()
+    rng = np.random.default_rng(4)
+    init = _init(rng)
+    _register(eng, init)
+    ref = _reference(init)
+    grads = [rng.normal(size=(1, TOTAL)).astype(np.float32)
+             for _ in range(4)]
+    for g in grads[:2]:
+        eng.push_pull("t", g)
+        ref.step(_split(g))
+    other = _engine()
+    _register(other, np.zeros(TOTAL, np.float32))
+    if backend == "npz":
+        path = str(tmp_path / "ckpt")
+        checkpoint.save_engine(eng, path)
+        checkpoint.restore_engine(other, path)
+    elif backend == "orbax":
+        path = str(tmp_path / "ckpt_orbax")
+        checkpoint.save_engine_orbax(eng, path)
+        checkpoint.restore_engine_orbax(other, path)
+    else:
+        kind, state = eng.opt_state("t")
+        other.set_store_array("t", np.asarray(eng.store_array("t"))[:TOTAL])
+        other.set_opt_state("t", kind, [np.asarray(s) for s in state])
+    kind, restored = other.opt_state("t")
+    assert kind == "muon"
+    for got, want in zip(restored, eng.opt_state("t")[1]):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for g in grads[2:]:
+        want = np.asarray(eng.push_pull("t", g))
+        got = np.asarray(other.push_pull("t", g))
+        np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(np.asarray(other.opt_state("t")[1][3]),
+                                  4.0)
+
+
+def test_reshard_from_one_shard_to_one_shard_and_no_further():
+    eng = _engine()
+    rng = np.random.default_rng(6)
+    init = _init(rng)
+    _register(eng, init)
+    twin = _engine()
+    _register(twin, init)
+    g = rng.normal(size=(1, TOTAL)).astype(np.float32)
+    eng.push_pull("t", g)
+    twin.push_pull("t", g)
+    eng.reshard(Mesh(np.array(jax.devices()[1:2]), ("kv",)))
+    np.testing.assert_array_equal(np.asarray(eng.push_pull("t", g)),
+                                  np.asarray(twin.push_pull("t", g)))
+    with pytest.raises(log.CheckError, match="a matrix would lie across"):
+        eng.reshard(_mesh(4))
+    assert eng.num_shards == 1      # staged, refused, nothing committed
+    np.testing.assert_array_equal(np.asarray(eng.push_pull("t", g)),
+                                  np.asarray(twin.push_pull("t", g)))
+
+
+# -- where it cannot run it says so by name -------------------------------------
+
+
+def _refused(eng, match, **kw):
+    with pytest.raises(log.CheckError, match=match):
+        _register(eng, None, **kw)
+        eng.push_pull("t", np.ones((eng.num_workers, TOTAL), np.float32))
+    assert eng.muon_updates == 0 and not eng._opt_states
+
+
+@pytest.mark.parametrize("case", ["no_shapes", "a_wrong_product",
+                                  "four_shards", "a_mixed_bucket",
+                                  "a_bf16_store", "no_lens", "no_bucket"])
+def test_muon_refuses_by_name_what_it_cannot_run(case):
+    import jax.numpy as jnp
+
+    if case == "no_shapes":
+        _refused(_engine(), "needs each key's \\(rows, cols\\).*shapes=",
+                 shapes=None)
+    elif case == "a_wrong_product":
+        bad = SHAPES.copy()
+        bad[3] = (96, 255)
+        _refused(_engine(), "rows \\* cols must be the key's len; key \\[3\\]",
+                 shapes=bad)
+    elif case == "four_shards":
+        _refused(_engine(4), "lies over 4 shards.*a matrix would lie across "
+                 "chips")
+    elif case == "a_mixed_bucket":
+        _refused(_engine(), "pushed and pulled in bfloat16",
+                 dtype=jnp.float32, job_dtype=jnp.bfloat16)
+    elif case == "a_bf16_store":
+        _refused(_engine(), "is kept in bfloat16", dtype=jnp.bfloat16)
+    elif case == "no_lens":
+        eng = _engine()
+        eng.register_dense("flat", KEYS[:2], 64)
+        with pytest.raises(log.CheckError,
+                           match="needs the keys' own lengths"):
+            eng.push_pull("flat", np.ones((1, 128), np.float32))
+        with pytest.raises(log.CheckError, match="shapes need per-key lens"):
+            eng.register_dense("u", KEYS[:2], 64, shapes=[(8, 8), (8, 8)])
+    else:
+        eng = _engine()
+        _register(eng, None)
+        with pytest.raises(log.CheckError, match="carries no bucket"):
+            eng.replay("t", np.ones((2, 1, TOTAL), np.float32))
+    # Nothing fell back to an element-wise update.
+
+
+def test_another_handle_on_the_same_bucket_ignores_the_shapes():
+    eng = _engine(handle="adam:1e-2,0.9,0.999,1e-8")
+    _register(eng, None)
+    pulled = np.asarray(eng.push_pull("t", np.ones((1, TOTAL), np.float32)))
+    np.testing.assert_allclose(pulled, -1e-2, rtol=1e-4)
+    with pytest.raises(log.CheckError, match="already has 'adam' state"):
+        eng.push_pull("t", np.ones((1, TOTAL), np.float32), HANDLE)
+
+
+def test_the_span_of_a_dense_op_names_muon(cluster, monkeypatch):
+    from pslite_tpu.kv import kv_app
+
+    seen = []
+
+    class Span:
+        def __init__(self, *args, **kw):
+            pass
+
+        def __enter__(self):
+            pass
+
+        def __exit__(self, *exc):
+            pass
+
+        def set_metadata(self, **kw):
+            seen.append(kw)
+
+    kv = _worker(cluster)
+    kv.register_dense("tree", KEYS, lens=LENS, flags=FLAGS, shapes=SHAPES)
+    monkeypatch.setattr(kv_app, "tracing", lambda: True)   # a session runs
+    monkeypatch.setattr(kv_app, "TraceAnnotation", Span)
+    ts = kv.push_pull(KEYS, np.ones((1, TOTAL), np.float32), None)
+    kv.wait(ts)
+    assert {"ts": ts, "name": "tree", "handle": "muon"} in seen
+
+
+def test_the_program_names_its_four_parts():
+    plan = muon.muon_plan(SHAPES, ADAMW)
+    state = tuple(np.zeros(s, np.float32) for s in muon.state_shapes(plan))
+    text = jax.jit(
+        lambda store, state, agg: muon.muon_update(
+            store, state, agg, STARTS, SHAPES, plan, **HYPER)
+    ).lower(np.zeros(TOTAL, np.float32),
+            (*state, np.zeros(1, np.float32)),
+            np.zeros((1, TOTAL), np.float32)).as_text(debug_info=True)
+    for scope in ("ps.update.muon.momentum", "ps.update.muon.ns",
+                  "ps.update.muon.apply", "ps.update.muon.adamw"):
+        assert scope in text, scope
+
+
+# -- the plan, the published count and the cut ----------------------------------
+
+
+def _config():
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "moonlight-16b-muon.json")) as fh:
+        return json.load(fh)
+
+
+def test_the_plans_flops_are_the_benchmarks_published_count():
+    cfg = _config()
+    tensors = muon_flops.expand_shapes(cfg["tensors"])
+    adamw = [muon_flops.is_adamw(n, cfg["adamw_keys"]) for n, _ in tensors]
+    plan = muon.muon_plan([s for _, s in tensors], adamw)
+    shapes = muon_flops.matrices(cfg)
+    assert plan.matrices == len(shapes) == 135 and len(tensors) == 153
+    assert plan.ns_flops == muon_flops.published(shapes) == 20631616225280.0
+    assert muon_flops.least(shapes) == 14262857891840.0
+    assert plan.muon_len == 484573184 and plan.adamw_len == 83911168
+    assert plan.state_bytes == 2609582080      # 2.61 GB, not 3 x 2.27
+    assert sum(len(c.keys) for c in plan.chunks) == 135
+    # No batched product takes more than a layer's 24 expert matrices.
+    assert max(len(c.keys) * c.m * c.n for c in plan.chunks) == 69206016
+    assert muon_flops.by_group(shapes)[(1408, 2048)] == 96
+
+
+def _grown(cfg, layers, experts, vocab):
+    """The configuration's tensor list with its three ``reduced`` keys set
+    to other values: MoE layers, routed experts held, vocabulary rows."""
+    out = json.loads(json.dumps(cfg["tensors"]))
+    for entry in out:
+        if isinstance(entry, dict):
+            if entry["name"] != "moe":
+                continue
+            entry["repeat"] = layers
+            for inner in entry["tensors"]:
+                if isinstance(inner, dict):
+                    assert inner["name"] == "expert"
+                    inner["repeat"] = experts
+        elif entry[0] in ("emb.w", "head.w"):
+            entry[1][0] = vocab
+    return muon_flops.expand_shapes(out)
+
+
+def test_the_cut_adds_up_to_the_published_tree():
+    """64 experts held, 163,840 rows and 1 + 26 layers sum to the published
+    15.96 B parameters; and the eight servers' shares of one MoE layer
+    (experts 8k..8k+7 each, what all hold alike counted once) add up to
+    that layer."""
+    cfg = _config()
+    pub = cfg["published"]
+    full = _grown(cfg, pub["num_hidden_layers"] - 1,
+                  pub["n_routed_experts"], pub["vocab_size"])
+    assert sum(r * c for _, (r, c) in full) == pub["parameters"] \
+        == 15960108544
+    here = muon_flops.expand_shapes(cfg["tensors"])
+    assert sum(r * c for _, (r, c) in here) == cfg["parameters"] == 568484352
+    size = lambda tensors, prefix: {
+        n: r * c for n, (r, c) in tensors if n.startswith(prefix)}
+    layer = size(full, "moe.0.")
+    share = size(here, "moe.0.")
+    servers = pub["servers_sharing_a_layer"]
+    held = cfg["sizes"]["n_routed_experts"]
+    assert servers * held == pub["n_routed_experts"]
+    experts = {n: v for n, v in share.items() if ".expert." in n}
+    alike = {n: v for n, v in share.items() if ".expert." not in n}
+    assert len(experts) == 3 * held
+    rebuilt = dict(alike)
+    for k in range(servers):
+        for name, v in experts.items():
+            j = int(name.split(".")[3])
+            rebuilt[name.replace(f".expert.{j}.",
+                                 f".expert.{held * k + j}.")] = v
+    assert rebuilt == layer
+    assert sum(layer.values()) == 31199744 + 64 * 8650752
