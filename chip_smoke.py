@@ -577,9 +577,15 @@ class _Smoke:
         """Two steps of ``muon`` on a bucket registered with ``lens`` and
         ``shapes``: wide, tall and square matrices, two of one shape (one
         batched product), a 64-row router and an AdamW key on no lane
-        border between them, against the recurrence in float64 with the
+        border between them, and in front of them (PR 44) a gain of 512
+        values, a wide key 512 values off a tile of 1,024 and a tall one of
+        its side, each of two grid steps: those three leave the gradient
+        row through ``ops/muon.py``'s kernels (``eng.muon_row_keys``), the
+        others by XLA's cut.  Against the recurrence in float64 with the
         products' operands rounded to bfloat16: what the chip does with
-        bf16 products, which tier-1's interpreter cannot see.  Two
+        bf16 products and with a kernel's blocks (the interpreter loads an
+        output block before the kernel runs; the chip does not), which
+        tier-1 cannot see.  Two
         bfloat16 computations of one recurrence differ by roundings that
         flip, so a matrix is held to 0.3 of one step's root mean square in
         any element and 0.05 in the root mean square (the chip reads 0.146
@@ -595,9 +601,11 @@ class _Smoke:
         W = eng.num_workers
         lr, mu, wd, b1, b2, eps = 1e-3, 0.95, 0.1, 0.9, 0.95, 1e-8
         handle = f"muon:{lr},{mu},{wd},{b1},{b2},{eps}"
-        shapes = np.array([(192, 512), (1, 333), (512, 192), (256, 256),
-                           (192, 512), (64, 2048)])
-        adamw = np.array([False, True, False, False, False, False])
+        shapes = np.array([(1, 512), (512, 1024), (1024, 512), (192, 512),
+                           (1, 333), (512, 192), (256, 256), (192, 512),
+                           (64, 2048)])
+        adamw = np.array([True, False, False, False, True, False, False,
+                          False, False])
         lens = shapes[:, 0] * shapes[:, 1]
         keys = np.arange(5200, 5200 + len(lens), dtype=np.uint64)
         starts = np.concatenate([[0], np.cumsum(lens)])
@@ -653,8 +661,11 @@ class _Smoke:
                       f"muon key {k} {tuple(shapes[k])} step {t}: off by "
                       f"{diff.max() / step:.3f} of a step at worst, "
                       f"{np.sqrt(np.mean(diff ** 2)) / step:.4f} rms")
-        check(eng.muon_updates == 2 and eng.muon_matrices == 5,
+        check(eng.muon_updates == 2 and eng.muon_matrices == 7,
               "every op ran under Muon")
+        check(eng.muon_row_keys == 3 and starts[1] % 1024 == 512,
+              f"the keys in front of the first odd one leave the row "
+              f"through a kernel, not {eng.muon_row_keys}")
         _, (m_got, _, _, slot) = eng.opt_state("muon_tree")
         np.testing.assert_allclose(
             np.asarray(m_got), np.concatenate(

@@ -25,14 +25,23 @@ leaves its product, ``B`` after ``b*A + c*(A A)``, ``X`` after
 ``a*X + (B X)``), where the published code rounds every intermediate; the
 Frobenius norm is taken in f32 over the bf16 values.
 
-Everything is plain XLA: a batched ``dot_general`` a product, one batch a
-:class:`MuonChunk` (keys of one ``(shorter, longer)`` side, tall ones
-transposed into it, at most ``MUON_CHUNK_VALUES`` values together, so that
-the bf16 temporaries of a chunk, X twice, A and B, stay a few hundred MB
-whatever the tree).  The momentum is kept AS THE CHUNKS ARE, one f32 array
-``[B, m, n]`` a chunk, so no pass lays it out anew; ``opt_state`` hands it
-out, and takes it back, as one vector in the keys' order
-(:func:`momentum_vector`, :func:`momentum_chunks`).
+The products and the apply are plain XLA: a batched ``dot_general`` a
+product, one batch a :class:`MuonChunk` (keys of one ``(shorter, longer)``
+side, tall ones transposed into it, at most ``MUON_CHUNK_VALUES`` values
+together, so that the bf16 temporaries of a chunk, X twice, A and B, stay a
+few hundred MB whatever the tree).  The momentum is kept AS THE CHUNKS ARE,
+one f32 array ``[B, m, n]`` a chunk, so no pass lays it out anew;
+``opt_state`` hands it out, and takes it back, as one vector in the keys'
+order (:func:`momentum_vector`, :func:`momentum_chunks`).
+
+How a key's gradient leaves the summed row ``f32[1, total]`` is not XLA's
+(PR 44): the chip lays such a row out one sublane of eight to a tile, and
+XLA's cut of a key is a reduce at a fifth of the HBM rate.  A key that
+starts on a lane border (:func:`takes_row`) is read where it lies by a
+Mosaic kernel with full vector registers: :func:`row_momentum` does a
+chunk's momentum pass on it, :func:`row_vector` hands an AdamW key over as
+a vector.  What decides is in the keys, not a knob; any other key keeps
+XLA's cut.
 """
 
 from __future__ import annotations
@@ -53,11 +62,25 @@ RMS_MATCH = 0.2
 MUON_CHUNK_VALUES = 66 * 2 ** 20
 
 
+# The lanes of a vector register: a key whose gradient leaves the row
+# through :func:`row_momentum` or :func:`row_vector` starts on a multiple
+# of it (:func:`takes_row`).
+LANES = 128
+# The most values of the row a grid step of those kernels holds (1 MiB).
+ROW_BLOCK_VALUES = 2 ** 18
+# VMEM a call of :func:`row_momentum` may take: four streams' double
+# buffers (7 MiB) and what Mosaic keeps of a block while it folds and
+# transposes it.
+ROW_VMEM_BYTES = 32 * 2 ** 20
+
+
 class MuonChunk(NamedTuple):
     m: int                      # the shorter side
     n: int                      # the longer side
     keys: Tuple[int, ...]       # the bucket's key indices, in key order
     tall: Tuple[bool, ...]      # rows > cols: transposed into the chunk
+    row: bool                   # every key's gradient is taken from the
+    #                             row by :func:`row_momentum`
 
 
 class MuonPlan(NamedTuple):
@@ -70,6 +93,8 @@ class MuonPlan(NamedTuple):
     mom_starts: np.ndarray      # where a Muon key lies in the momentum vector
     adamw_starts: np.ndarray    # where an AdamW key lies in m and v
     ns_flops: float             # Newton-Schulz FLOPs a step, as published
+    row_keys: np.ndarray        # key indices whose gradient a kernel takes
+    #                             from the row (:func:`takes_row`)
 
     @property
     def matrices(self) -> int:
@@ -96,15 +121,57 @@ def ns_flops(rows: int, cols: int) -> float:
     return float(NS_STEPS * (4 * m * m * n + 2 * m ** 3))
 
 
+def _block_rows(rows: int, cols: int, unit: int) -> int:
+    """The rows of a ``(rows, cols)`` gradient a grid step takes: the most
+    that divide ``rows``, are a multiple of ``unit`` and hold at most
+    ``ROW_BLOCK_VALUES`` values; ``unit`` where none does."""
+    fits = [d for d in range(unit, rows + 1, unit)
+            if rows % d == 0 and d * cols <= ROW_BLOCK_VALUES]
+    return max(fits, default=unit)
+
+
+def _vector_tile(n: int) -> int:
+    """The values of an AdamW key a grid step of :func:`row_vector`
+    takes: the key whole, or whole tiles of 1,024 (a vector's on the chip)
+    that divide it; 0 where neither fits a block."""
+    if n <= ROW_BLOCK_VALUES:
+        return n
+    return max((d for d in range(1024, ROW_BLOCK_VALUES + 1, 1024)
+                if n % d == 0), default=0)
+
+
+def takes_row(start: int, rows: int, cols: int, elementwise: bool) -> bool:
+    """Whether a kernel takes this key's gradient from the row where it
+    lies, ``[start, start + rows * cols)`` of ``f32[1, total]``: it starts
+    on a lane border and is whole lanes long, and a matrix is cut into
+    blocks the chip's tiles allow (bfloat16 packs 16 rows to a tile, so a
+    wide one has rows in sixteens and whole lanes a row; a tall one, which
+    is transposed in the kernel, whole lanes both ways).  Any other key
+    keeps the cut XLA makes (:func:`muon_update` ``key_grad``)."""
+    if start % LANES or (rows * cols) % LANES or rows * cols == 0:
+        return False
+    if elementwise:
+        return _vector_tile(rows * cols) > 0
+    if rows > cols:
+        return rows % LANES == 0 and cols % LANES == 0
+    return rows % 16 == 0 and cols % LANES == 0
+
+
 def muon_plan(shapes, elementwise, chunk_values: int = MUON_CHUNK_VALUES
               ) -> MuonPlan:
     """``shapes`` ``[K, 2]`` (rows, cols a key), ``elementwise`` ``[K]``
     (the key takes AdamW).  Keys of one ``(shorter, longer)`` side share a
     group whatever their orientation; a group is cut, in key order, into
-    chunks of at most ``chunk_values`` values (one key at least)."""
+    chunks of at most ``chunk_values`` values (one key at least).  A chunk
+    takes its gradients from the row where every key of it can
+    (:func:`takes_row`: a chunk's momentum is one array, updated by one
+    pass), an AdamW key where that key can."""
     shapes = np.asarray(shapes, np.int64).reshape(-1, 2)
     elementwise = np.asarray(elementwise, bool)
     lens = shapes[:, 0] * shapes[:, 1]
+    starts = np.concatenate([[0], np.cumsum(lens)])
+    ok = [takes_row(int(starts[k]), int(shapes[k, 0]), int(shapes[k, 1]),
+                    bool(elementwise[k])) for k in range(len(lens))]
     muon = np.flatnonzero(~elementwise)
     adamw = np.flatnonzero(elementwise)
     groups: dict = {}
@@ -118,12 +185,16 @@ def muon_plan(shapes, elementwise, chunk_values: int = MUON_CHUNK_VALUES
             part = tuple(keys[i:i + per])
             chunks.append(MuonChunk(
                 m, n, part,
-                tuple(bool(shapes[k, 0] > shapes[k, 1]) for k in part)))
+                tuple(bool(shapes[k, 0] > shapes[k, 1]) for k in part),
+                all(ok[k] for k in part)))
+    row_keys = sorted([k for c in chunks if c.row for k in c.keys]
+                      + [int(k) for k in adamw if ok[k]])
     return MuonPlan(
         chunks=tuple(chunks), muon_keys=muon, adamw_keys=adamw,
         mom_starts=np.concatenate([[0], np.cumsum(lens[muon])]),
         adamw_starts=np.concatenate([[0], np.cumsum(lens[adamw])]),
-        ns_flops=float(sum(ns_flops(*shapes[k]) for k in muon)))
+        ns_flops=float(sum(ns_flops(*shapes[k]) for k in muon)),
+        row_keys=np.array(row_keys, np.int64))
 
 
 def state_shapes(plan: MuonPlan) -> Tuple[Tuple[int, ...], ...]:
@@ -182,9 +253,104 @@ def newton_schulz(x, steps: int = NS_STEPS):
     return x
 
 
+def _row_stretch(length: int, lane):
+    """The ``BlockSpec`` of ``length`` values of the row ``f32[1, total]``
+    that begin at lane ``lane(*grid indices, *prefetched)``: addressed by
+    the element, since a key starts on a lane border and on no block's."""
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec(
+        (pl.Element(1), pl.Element(length)),
+        lambda *a: (0, pl.multiple_of(lane(*a) * LANES, LANES)))
+
+
+def row_vector(row, start: int, n: int, *, interpret: bool):
+    """``row[0, start:start + n]`` as a vector ``f32[n]``, read with full
+    vector registers: the chip lays ``f32[1, n]`` out one sublane of eight
+    to a tile, and XLA's own cut squeezes it at a fifth of the HBM rate."""
+    import jax
+    from jax.experimental import pallas as pl
+
+    tile = _vector_tile(n)
+
+    def kernel(g_ref, out_ref):
+        out_ref[...] = g_ref[...].reshape(tile)
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((n,), row.dtype),
+        grid=(n // tile,),
+        in_specs=[_row_stretch(
+            tile, lambda i: start // LANES + i * (tile // LANES))],
+        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
+        interpret=interpret,
+        name="muon_row_vector",
+    )(row)
+
+
+def row_momentum(momentum, row, mom, chunk: MuonChunk, starts, tall: bool,
+                 x=None, *, interpret: bool):
+    """The momentum pass of the ``tall`` (or the other) keys of ``chunk``,
+    their gradients taken from the row where they lie: ``M, X =
+    momentum(M, G)``, M in place in ``mom`` ``[B, m, n]`` and the
+    bfloat16 X into ``x`` (made here where none is given), every other
+    key's slot of both left as it is.  Returns ``(M, X)``: the f32
+    momentum first, which is how ``benchmark/muon_ops.py`` tells this pass
+    from Newton-Schulz.
+
+    A grid step holds a block of whole rows of one key's gradient, one
+    stretch ``(1, rows * cols)`` of the row, and folds it to ``(rows,
+    cols)`` in VMEM; a tall key's block is transposed there and lands in
+    columns of its slot."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, n = chunk.m, chunk.n
+    slots = [i for i, t in enumerate(chunk.tall) if t == tall]
+    lanes = [int(starts[chunk.keys[i]]) // LANES for i in slots]
+    rows, cols = (n, m) if tall else (m, n)
+    step = _block_rows(rows, cols, LANES if tall else 16)
+    block = (1, m, step) if tall else (1, step, n)
+
+    def at(i, j, lanes_ref, slots_ref):
+        return (slots_ref[i], 0, j) if tall else (slots_ref[i], j, 0)
+
+    def kernel(lanes_ref, slots_ref, g_ref, m_ref, *refs):
+        mo_ref, x_ref = refs[-2:]
+        g = g_ref[...].reshape(step, cols)
+        mo_ref[0], x_ref[0] = momentum(m_ref[0], g.T if tall else g)
+
+    in_specs = [
+        _row_stretch(
+            step * cols, lambda i, j, lanes_ref, slots_ref:
+            lanes_ref[i] + j * (step * cols // LANES)),
+        pl.BlockSpec(block, at)]
+    args = [row, mom]
+    if x is not None:
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        args.append(x)
+    return pl.pallas_call(
+        kernel,
+        out_shape=(jax.ShapeDtypeStruct(mom.shape, mom.dtype),
+                   jax.ShapeDtypeStruct(mom.shape, jnp.bfloat16)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(len(slots), rows // step),
+            in_specs=in_specs,
+            out_specs=(pl.BlockSpec(block, at), pl.BlockSpec(block, at))),
+        input_output_aliases={3: 0, **({4: 1} if x is not None else {})},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=ROW_VMEM_BYTES),
+        interpret=interpret,
+        name="muon_row_momentum",
+    )(jnp.asarray(lanes, jnp.int32), jnp.asarray(slots, jnp.int32), *args)
+
+
 def muon_update(store, state, agg, starts, shapes, plan: MuonPlan, *,
                 lr: float, mu: float, wd: float, b1: float, b2: float,
-                eps: float):
+                eps: float, interpret: bool = False):
     """One step on the one shard that holds the bucket.  ``store`` is the
     flat f32 store, ``state`` as :func:`state_shapes` lays it out with the
     step slot last, ``agg`` the summed gradient as a row ``[1, total]``.
@@ -193,7 +359,10 @@ def muon_update(store, state, agg, starts, shapes, plan: MuonPlan, *,
     Every key's values are read from and written to the store where they
     lie (a chain of ``dynamic_update_slice`` in place, a chunk at a time:
     the barrier between two chunks keeps the next one's temporaries from
-    being made before this one's are let go)."""
+    being made before this one's are let go).  A key's gradient leaves
+    the row through a kernel where the plan says it can (``plan.row_keys``;
+    ``interpret`` runs those kernels in the Pallas interpreter) and by
+    XLA's cut where not."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -210,21 +379,35 @@ def muon_update(store, state, agg, starts, shapes, plan: MuonPlan, *,
         return lax.slice(vector, (int(starts[k]),), (int(starts[k + 1]),))
 
     def key_grad(row, k: int, shape):
-        # Cut from the row as it lies: squeezing the row first is a pass
-        # over the whole gradient.
+        # The fall-back, for a key on no lane border.  The chip lays
+        # ``f32[1, n]`` out one sublane of eight to a tile, so XLA squeezes
+        # the cut by a reduce at a fifth of the HBM rate and writes the key
+        # out once more (18.4 ms of a 187 ms step on 568 M values,
+        # PERF.md, PR 43); squeezing the whole row first is no better, a
+        # pass over the gradient and a second copy of it.
         return lax.slice(row, (0, int(starts[k])),
                          (1, int(starts[k + 1]))).reshape(shape)
 
+    def momentum(mom, g):
+        mom = mu * mom + g
+        x = (g + mu * mom).astype(bf16)
+        return mom, x
+
+    row_keys = {int(k) for k in plan.row_keys}
     new_moms = []
     for chunk, mom in zip(plan.chunks, moms):
         with jax.named_scope("ps.update.muon.momentum"):
-            grads = []
-            for k, tall in zip(chunk.keys, chunk.tall):
-                g = key_grad(agg, k, tuple(int(d) for d in shapes[k]))
-                grads.append(g.T if tall else g)
-            g = jnp.stack(grads)
-            mom = mu * mom + g
-            x = (g + mu * mom).astype(bf16)
+            if chunk.row:
+                x = None
+                for tall in sorted(set(chunk.tall)):
+                    mom, x = row_momentum(momentum, agg, mom, chunk, starts,
+                                          tall, x, interpret=interpret)
+            else:
+                grads = []
+                for k, tall in zip(chunk.keys, chunk.tall):
+                    g = key_grad(agg, k, tuple(int(d) for d in shapes[k]))
+                    grads.append(g.T if tall else g)
+                mom, x = momentum(mom, jnp.stack(grads))
         with jax.named_scope("ps.update.muon.ns"):
             o = newton_schulz(x)
         with jax.named_scope("ps.update.muon.apply"):
@@ -245,7 +428,9 @@ def muon_update(store, state, agg, starts, shapes, plan: MuonPlan, *,
             k = int(k)
             lo = int(plan.adamw_starts[j])
             hi = int(plan.adamw_starts[j + 1])
-            g = key_grad(agg, k, (hi - lo,))
+            g = (row_vector(agg, int(starts[k]), hi - lo,
+                            interpret=interpret) if k in row_keys
+                 else key_grad(agg, k, (hi - lo,)))
             m_k = b1 * lax.slice(adam_m, (lo,), (hi,)) + (1.0 - b1) * g
             v_k = b2 * lax.slice(adam_v, (lo,), (hi,)) + (1.0 - b2) * g * g
             new_p = (key_values(store, k) * keep

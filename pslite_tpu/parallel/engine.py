@@ -474,11 +474,13 @@ class CollectiveEngine:
         # (``engine.dense.narrow``).
         self.narrow_ops = 0
         # Ops whose program applied Muon (``engine.update.muon``), and
-        # what a step of the last of them holds by its plan: matrices and
+        # what a step of the last of them holds by its plan: matrices, the
+        # keys whose gradient a kernel takes from the row, and
         # Newton-Schulz FLOPs (``engine.update.muon.matrices``,
-        # ``.ns_flops``).
+        # ``.row_keys``, ``.ns_flops``).
         self.muon_updates = 0
         self.muon_matrices = 0
+        self.muon_row_keys = 0
         self.muon_ns_flops = 0.0
 
     # -- registration --------------------------------------------------------
@@ -829,12 +831,12 @@ class CollectiveEngine:
         lr, mu, wd, b1, b2, eps = self._handle_params(
             handle, (1e-3, 0.95, 0.1, 0.9, 0.95, 1e-8))
         plan = self._muon_plan(bucket)
-        starts, shapes = bucket.starts, bucket.shapes
+        starts, shapes, interp = bucket.starts, bucket.shapes, self._interpret
 
         def fn(store_l, state_l, agg):
             return muon.muon_update(
                 store_l, state_l, agg, starts, shapes, plan, lr=lr, mu=mu,
-                wd=wd, b1=b1, b2=b2, eps=eps)
+                wd=wd, b1=b1, b2=b2, eps=eps, interpret=interp)
 
         return fn
 
@@ -1996,6 +1998,7 @@ class CollectiveEngine:
             elif muon:
                 self.muon_updates += 1
                 self.muon_matrices = plan.matrices
+                self.muon_row_keys = len(plan.row_keys)
                 self.muon_ns_flops = plan.ns_flops
             self.kernel_pulls += kernel_pulls
             self.narrow_ops += narrow
@@ -2012,6 +2015,8 @@ class CollectiveEngine:
         registry.gauge("engine.update.muon", fn=lambda: self.muon_updates)
         registry.gauge("engine.update.muon.matrices",
                        fn=lambda: self.muon_matrices)
+        registry.gauge("engine.update.muon.row_keys",
+                       fn=lambda: self.muon_row_keys)
         registry.gauge("engine.update.muon.ns_flops",
                        fn=lambda: self.muon_ns_flops)
         registry.gauge("engine.pull.from_kernel",

@@ -480,13 +480,216 @@ def test_the_program_names_its_four_parts():
     state = tuple(np.zeros(s, np.float32) for s in muon.state_shapes(plan))
     text = jax.jit(
         lambda store, state, agg: muon.muon_update(
-            store, state, agg, STARTS, SHAPES, plan, **HYPER)
+            store, state, agg, STARTS, SHAPES, plan, interpret=True,
+            **HYPER)
     ).lower(np.zeros(TOTAL, np.float32),
             (*state, np.zeros(1, np.float32)),
             np.zeros((1, TOTAL), np.float32)).as_text(debug_info=True)
     for scope in ("ps.update.muon.momentum", "ps.update.muon.ns",
                   "ps.update.muon.apply", "ps.update.muon.adamw"):
         assert scope in text, scope
+
+
+# -- a key's gradient taken from the row where it lies (PR 44) ---------------
+
+# Every way a key can lie in the row: AdamW vectors and matrices on lane
+# borders, two of them 512 values off a tile of 1,024 (behind a gain of 512
+# values, as every second layer of moonlight-16b-muon lies), a tall key, two
+# that share a chunk with a tall one, and behind a key of 60 values nothing
+# on a lane border any more: those keep XLA's cut.
+ROW_TREE = [
+    ("emb.w", (8, 128), True),
+    ("wide", (16, 256), False),
+    ("gain", (1, 512), True),
+    ("off_tile", (32, 128), False),
+    ("tall", (256, 128), False),
+    ("mate.0", (128, 256), False),
+    ("mate.1", (256, 128), False),
+    ("odd", (6, 10), False),
+    ("odd_gain", (1, 77), True),
+    ("late", (16, 256), False),        # the shape of "wide", off a lane
+]
+ROW_NAMES = [n for n, _, _ in ROW_TREE]
+ROW_SHAPES = np.array([s for _, s, _ in ROW_TREE])
+ROW_ADAMW = np.array([a for _, _, a in ROW_TREE])
+ROW_LENS = ROW_SHAPES[:, 0] * ROW_SHAPES[:, 1]
+ROW_STARTS = np.concatenate([[0], np.cumsum(ROW_LENS)])
+ROW_TOTAL = int(ROW_LENS.sum())
+ROW_KEYS = np.arange(300, 300 + len(ROW_TREE), dtype=np.uint64)
+ROW_TAKEN = ["emb.w", "gain", "off_tile", "tall", "mate.0", "mate.1"]
+
+
+def _todays_cut(row, name):
+    """The parent's ``key_grad``: what XLA squeezes."""
+    from jax import lax
+
+    k = ROW_NAMES.index(name)
+    return np.asarray(lax.slice(
+        row, (0, int(ROW_STARTS[k])), (1, int(ROW_STARTS[k + 1]))
+    ).reshape(tuple(ROW_SHAPES[k])))
+
+
+def _without_the_row(plan):
+    """The parent's plan: every chunk and every AdamW key by XLA's cut."""
+    return plan._replace(
+        chunks=tuple(c._replace(row=False) for c in plan.chunks),
+        row_keys=np.array([], np.int64))
+
+
+def _row_state(plan, rng):
+    """A state that has seen steps: momenta of any sign, v positive."""
+    *moms, m, v = (rng.normal(size=s).astype(np.float32)
+                   for s in muon.state_shapes(plan))
+    return (*moms, m, np.abs(v), np.ones(1, np.float32))
+
+
+def test_the_plan_says_which_keys_leave_the_row_through_a_kernel():
+    plan = muon.muon_plan(ROW_SHAPES, ROW_ADAMW)
+    assert [ROW_NAMES[k] for k in plan.row_keys] == ROW_TAKEN
+    assert {(c.m, c.n): c.row for c in plan.chunks} == {
+        (16, 256): False,      # "late" is on no lane border: "wide" with it
+        (32, 128): True, (128, 256): True, (6, 10): False}
+    assert ROW_STARTS[ROW_NAMES.index("off_tile")] % 1024 == 512
+    assert ROW_STARTS[ROW_NAMES.index("tall")] % 1024 == 512
+    # What the chip's tiles allow of a side.
+    assert muon.takes_row(512, 576, 2048, False)        # rows in sixteens
+    assert not muon.takes_row(512, 8, 2048, False)
+    assert muon.takes_row(0, 2048, 1408, False)         # tall: whole lanes
+    assert not muon.takes_row(0, 2048, 576, False)
+    assert not muon.takes_row(64, 16, 128, False)
+    assert muon.takes_row(128, 1, 41943040, True)
+    assert not muon.takes_row(0, 1, 128 * 2053, True)   # a prime of lanes
+    # moonlight-16b-muon: every one of the 153 keys.
+    cfg = _config()
+    tensors = muon_flops.expand_shapes(cfg["tensors"])
+    full = muon.muon_plan(
+        [s for _, s in tensors],
+        [muon_flops.is_adamw(n, cfg["adamw_keys"]) for n, _ in tensors])
+    assert len(full.row_keys) == 153 and all(c.row for c in full.chunks)
+
+
+@pytest.mark.parametrize("case", ["wide", "tall", "off_tile", "adamw",
+                                  "fall_back", "two_workers"])
+def test_a_key_leaves_the_row_as_todays_cut_bit_for_bit(case):
+    """The kernels' read of a key against ``lax.slice(row, (0, lo), (1,
+    hi)).reshape(shape)`` on one tree: a matrix as it lands in its chunk
+    (the pass handed ``M, X = G, bf16(G)``), an AdamW key as a vector."""
+    import jax.numpy as jnp
+
+    plan = muon.muon_plan(ROW_SHAPES, ROW_ADAMW)
+    rng = np.random.default_rng(len(case))
+    row = jnp.asarray(rng.normal(size=(1, ROW_TOTAL)).astype(np.float32))
+
+    def landed(name):
+        k = ROW_NAMES.index(name)
+        chunk = next(c for c in plan.chunks if k in c.keys)
+        i = chunk.keys.index(k)
+        mom = jnp.full((len(chunk.keys), chunk.m, chunk.n), 7.0, jnp.float32)
+        got, x = muon.row_momentum(
+            lambda mom, g: (g, g.astype(jnp.bfloat16)), row, mom, chunk,
+            ROW_STARTS, chunk.tall[i], interpret=True)
+        want = _todays_cut(row, name)
+        want = want.T if chunk.tall[i] else want
+        np.testing.assert_array_equal(np.asarray(got[i]), want)
+        np.testing.assert_array_equal(
+            np.asarray(x[i]), np.asarray(jnp.asarray(want, jnp.bfloat16)))
+        # Every slot of the other orientation is as it was.
+        for j, tall in enumerate(chunk.tall):
+            if tall != chunk.tall[i]:
+                np.testing.assert_array_equal(np.asarray(got[j]), 7.0)
+
+    if case in ("wide", "tall", "off_tile"):
+        for name in {"wide": ["mate.0"], "tall": ["tall", "mate.1"],
+                     "off_tile": ["off_tile"]}[case]:
+            landed(name)
+    elif case == "adamw":
+        for name in ("emb.w", "gain"):
+            k = ROW_NAMES.index(name)
+            got = muon.row_vector(row, int(ROW_STARTS[k]), int(ROW_LENS[k]),
+                                  interpret=True)
+            np.testing.assert_array_equal(
+                np.asarray(got), _todays_cut(row, name).reshape(-1))
+    elif case == "fall_back":
+        # A key of 60 values, and what lies behind it: the program with
+        # the kernels and the parent's give one store and one state.
+        for name in ("wide", "odd", "odd_gain", "late"):
+            assert ROW_NAMES.index(name) not in plan.row_keys
+        store = _init(rng, ROW_TOTAL)
+        state = _row_state(plan, rng)
+        got, want = (jax.jit(lambda *a, p=p: muon.muon_update(
+            *a, ROW_STARTS, ROW_SHAPES, p, interpret=True, **HYPER))(
+                store, state, row) for p in (plan, _without_the_row(plan)))
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    else:
+        # W = 2: the rows are summed first, and the sum is what is cut.
+        mesh = Mesh(np.array(jax.devices()[:2]).reshape(2, 1), ("dp", "kv"))
+        eng = CollectiveEngine(mesh=mesh, axis_name="kv", worker_axis="dp",
+                               server_handle=HANDLE)
+        one = _engine()
+        init = _init(rng, ROW_TOTAL)
+        for e in (eng, one):
+            e.register_dense("t", ROW_KEYS, lens=ROW_LENS, shapes=ROW_SHAPES,
+                             flags=np.where(ROW_ADAMW, KEY_ELEMENTWISE, 0),
+                             init=init)
+        g = rng.integers(-8, 8, size=(2, ROW_TOTAL)).astype(np.float32)
+        np.testing.assert_array_equal(
+            np.asarray(eng.push_pull("t", g)),
+            np.asarray(one.push_pull("t", g.sum(0, keepdims=True))))
+        assert eng.muon_row_keys == one.muon_row_keys == len(ROW_TAKEN)
+
+
+@pytest.mark.parametrize("group", ["(32, 128)", "(128, 256)"])
+def test_momentum_and_x_of_a_chunk_are_the_parents_bit_for_bit(group):
+    """``M = mu*M + G`` and ``X = bf16(G + mu*M)`` of a chunk whose
+    gradients the kernel takes from the row, against the parent's
+    expression on the parent's cut of the same row."""
+    import jax.numpy as jnp
+
+    mu = HYPER["mu"]
+
+    def momentum(mom, g):       # ``muon_update``'s, letter for letter
+        mom = mu * mom + g
+        x = (g + mu * mom).astype(jnp.bfloat16)
+        return mom, x
+
+    plan = muon.muon_plan(ROW_SHAPES, ROW_ADAMW)
+    chunk = next(c for c in plan.chunks if str((c.m, c.n)) == group)
+    rng = np.random.default_rng(12)
+    row = jnp.asarray(rng.normal(size=(1, ROW_TOTAL)).astype(np.float32))
+    mom0 = jnp.asarray(rng.normal(
+        size=(len(chunk.keys), chunk.m, chunk.n)).astype(np.float32))
+    mom, x = mom0, None
+    for tall in sorted(set(chunk.tall)):
+        mom, x = muon.row_momentum(momentum, row, mom, chunk, ROW_STARTS,
+                                   tall, x, interpret=True)
+    grads = [_todays_cut(row, ROW_NAMES[k]) for k in chunk.keys]
+    want_mom, want_x = jax.jit(momentum)(mom0, jnp.stack(
+        [g.T if t else g for g, t in zip(grads, chunk.tall)]))
+    np.testing.assert_array_equal(np.asarray(mom), np.asarray(want_mom))
+    assert x.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(x), np.asarray(want_x))
+
+
+def test_the_gauge_counts_the_keys_that_left_the_row_through_a_kernel(
+        cluster):
+    kv = _worker(cluster)
+    kv.register_dense("rows", ROW_KEYS, lens=ROW_LENS, shapes=ROW_SHAPES,
+                      flags=np.where(ROW_ADAMW, KEY_ELEMENTWISE, 0))
+    gauges = lambda: kv.po.metrics.snapshot()["gauges"]
+    assert gauges()["engine.update.muon.row_keys"] == 0
+    kv.wait(kv.push_pull(ROW_KEYS, np.ones((1, ROW_TOTAL), np.float32),
+                         None))
+    fell_back = {"wide", "odd", "odd_gain", "late"}
+    assert gauges()["engine.update.muon.row_keys"] == len(ROW_TAKEN) \
+        == len(ROW_KEYS) - len(fell_back)
+    # The existing tree: "emb.w" and the first matrix of 96 x 256 lie on
+    # lane borders, and that matrix shares its chunk with three that do
+    # not (behind the gain of 77 values): AdamW's key alone.
+    kv.register_dense("tree", KEYS, lens=LENS, flags=FLAGS, shapes=SHAPES)
+    kv.wait(kv.push_pull(KEYS, np.ones((1, TOTAL), np.float32), None))
+    assert gauges()["engine.update.muon.row_keys"] == 1
+    assert gauges()["engine.update.muon.matrices"] == 7
 
 
 # -- the plan, the published count and the cut ----------------------------------
