@@ -574,23 +574,33 @@ class _Smoke:
     # -- sparse plane ---------------------------------------------------------
 
     def muon(self) -> None:
-        """Two steps of ``muon`` on a bucket registered with ``lens`` and
-        ``shapes``: wide, tall and square matrices, two of one shape (one
-        batched product), a 64-row router and an AdamW key on no lane
-        border between them, and in front of them (PR 44) a gain of 512
-        values, a wide key 512 values off a tile of 1,024 and a tall one of
-        its side, each of two grid steps: those three leave the gradient
-        row through ``ops/muon.py``'s kernels (``eng.muon_row_keys``), the
-        others by XLA's cut.  Against the recurrence in float64 with the
-        products' operands rounded to bfloat16: what the chip does with
-        bf16 products and with a kernel's blocks (the interpreter loads an
-        output block before the kernel runs; the chip does not), which
-        tier-1 cannot see.  Two
-        bfloat16 computations of one recurrence differ by roundings that
-        flip, so a matrix is held to 0.3 of one step's root mean square in
-        any element and 0.05 in the root mean square (the chip reads 0.146
-        and 0.018, a Newton-Schulz step left out 0.8); the AdamW key and
-        the momentum to f32 rounding.  On more than one chip a matrix would
+        """Two steps of ``muon`` on two buckets registered with ``lens``
+        and ``shapes``.  ``muon_tree``: wide, tall and square matrices, two
+        of one shape (one batched product), a 64-row router and an AdamW
+        key on no lane border between them, and in front of them (PR 44) a
+        gain of 512 values, a wide key 512 values off a tile of 1,024 and a
+        tall one of its side, each of two grid steps: those three leave the
+        gradient row through ``ops/muon.py``'s kernels
+        (``eng.muon_row_keys``), the others by XLA's cut; the new values
+        of the three matrices on lane borders are written by
+        ``row_apply`` (``eng.muon_apply_keys``), and since a key of the
+        bucket is not, the pulled tree stays the program's cut.
+        ``muon_apply`` (PR 45): every key a kernel's both ways, a wide and
+        a tall matrix 512 values off a tile and one of each on a tile
+        border, two grid steps each, AdamW keys whose m and v lie on a
+        tile border and 512 off it, one of two grid steps: there the
+        pulled tree is the kernels' own vector (``eng.kernel_pulls``) and
+        must equal the store bit for bit.  Against the recurrence in
+        float64 with the products' operands rounded to bfloat16: what the
+        chip does with bf16 products, with a kernel's blocks (the
+        interpreter loads an output block before the kernel runs; the chip
+        does not) and with copies a kernel starts in one grid step and
+        waits for in another, which tier-1 cannot see.  Two bfloat16
+        computations of one recurrence differ by roundings that flip, so a
+        matrix is held to 0.3 of one step's root mean square in any
+        element and 0.05 in the root mean square (the chip reads 0.146 and
+        0.018, a Newton-Schulz step left out 0.8); an AdamW key and the
+        momentum to f32 rounding.  On more than one chip a matrix would
         lie across chips: there the handle must refuse by name."""
         import jax.numpy as jnp
 
@@ -601,83 +611,125 @@ class _Smoke:
         W = eng.num_workers
         lr, mu, wd, b1, b2, eps = 1e-3, 0.95, 0.1, 0.9, 0.95, 1e-8
         handle = f"muon:{lr},{mu},{wd},{b1},{b2},{eps}"
-        shapes = np.array([(1, 512), (512, 1024), (1024, 512), (192, 512),
-                           (1, 333), (512, 192), (256, 256), (192, 512),
-                           (64, 2048)])
-        adamw = np.array([True, False, False, False, True, False, False,
-                          False, False])
-        lens = shapes[:, 0] * shapes[:, 1]
-        keys = np.arange(5200, 5200 + len(lens), dtype=np.uint64)
-        starts = np.concatenate([[0], np.cumsum(lens)])
-        total = int(lens.sum())
-        rng = np.random.default_rng(SEED)
-        init = (0.02 * rng.standard_normal(total)).astype(np.float32)
-        kv.register_dense("muon_tree", keys, lens=lens, shapes=shapes,
-                          flags=np.where(adamw, KEY_ELEMENTWISE, 0),
-                          init=init)
-        if eng.num_shards > 1:
-            try:
-                eng.push_pull("muon_tree", np.zeros((W, total), np.float32),
-                              handle)
-            except log.CheckError as exc:
-                check("a matrix would lie across chips" in str(exc),
-                      f"muon over {eng.num_shards} shards refuses by name: "
-                      f"{exc}")
-                check(eng.muon_updates == 0, "nothing ran under Muon")
-                print(f"  over {eng.num_shards} shards muon refuses by name")
-                return
-            raise AssertionError("muon over several shards did not refuse")
-        p = [init[starts[k]:starts[k + 1]].astype(np.float64).reshape(
-            shapes[k]) for k in range(len(lens))]
-        mom = [np.zeros_like(x) for x in p]
-        v = [np.zeros_like(x) for x in p]
-        worst = [0.0, 0.0]
-        for t in (1, 2):
-            g = rng.standard_normal((W, total)).astype(np.float32)
-            sent = g if t == 1 else jnp.asarray(g)
-            pulled = np.asarray(eng.push_pull("muon_tree", sent, handle))
-            gs = g.astype(np.float64).sum(axis=0)
-            for k in range(len(lens)):
-                gk = gs[starts[k]:starts[k + 1]].reshape(shapes[k])
-                got = pulled[starts[k]:starts[k + 1]].reshape(shapes[k])
-                if adamw[k]:
-                    mom[k] = b1 * mom[k] + (1 - b1) * gk
-                    v[k] = b2 * v[k] + (1 - b2) * gk * gk
-                    p[k] = (p[k] * (1 - lr * wd)
-                            - lr * np.sqrt(1 - b2 ** t) / (1 - b1 ** t)
-                            * mom[k] / (np.sqrt(v[k]) + eps))
-                    np.testing.assert_allclose(got, p[k], atol=2e-7,
-                                               err_msg=f"adamw step {t}")
-                    continue
-                was = p[k]
-                p[k], mom[k] = _muon_matrix_step(p[k], mom[k], gk, lr, mu,
-                                                 wd)
-                step = np.sqrt(np.mean((p[k] - was) ** 2))
-                diff = np.abs(got - p[k])
-                worst = [max(worst[0], diff.max() / step),
-                         max(worst[1], np.sqrt(np.mean(diff ** 2)) / step)]
-                check(diff.max() < 0.3 * step
-                      and np.sqrt(np.mean(diff ** 2)) < 0.05 * step,
-                      f"muon key {k} {tuple(shapes[k])} step {t}: off by "
-                      f"{diff.max() / step:.3f} of a step at worst, "
-                      f"{np.sqrt(np.mean(diff ** 2)) / step:.4f} rms")
-        check(eng.muon_updates == 2 and eng.muon_matrices == 7,
-              "every op ran under Muon")
-        check(eng.muon_row_keys == 3 and starts[1] % 1024 == 512,
-              f"the keys in front of the first odd one leave the row "
-              f"through a kernel, not {eng.muon_row_keys}")
-        _, (m_got, _, _, slot) = eng.opt_state("muon_tree")
-        np.testing.assert_allclose(
-            np.asarray(m_got), np.concatenate(
-                [mom[k].reshape(-1) for k in range(len(lens))
-                 if not adamw[k]]), atol=5e-6, err_msg="the momentum")
-        check(float(np.asarray(slot)[0]) == 2.0, "the step slot")
-        check(eng.opt_state_nbytes("muon_tree")
-              == 4 * int(lens[~adamw].sum()) + 8 * int(lens[adamw].sum()) + 4,
-              "the state at its own size")
-        print(f"  {len(lens)} keys ({', '.join(f'{r}x{c}' for r, c in shapes)})"
-              f": 2 steps under {handle} agree, at worst {worst[0]:.3f} of a "
-              f"step in an element, {worst[1]:.4f} rms")
+        # name, shapes, AdamW, keys through the row's kernels, keys a
+        # kernel writes back.
+        trees = [
+            ("muon_tree",
+             [(1, 512), (512, 1024), (1024, 512), (192, 512), (1, 333),
+              (512, 192), (256, 256), (192, 512), (64, 2048)],
+             [True, False, False, False, True, False, False, False, False],
+             3, 3),
+            ("muon_apply",
+             [(1, 512), (512, 1024), (1024, 512), (1, 1536), (512, 1024),
+              (1024, 512), (1, 524288)],
+             [True, False, False, True, False, False, True], 7, 7),
+        ]
+        updates = 0
+        for name, shapes, adamw, row_keys, apply_keys in trees:
+            shapes, adamw = np.array(shapes), np.array(adamw)
+            lens = shapes[:, 0] * shapes[:, 1]
+            keys = np.arange(5200 + 100 * updates,
+                             5200 + 100 * updates + len(lens),
+                             dtype=np.uint64)
+            starts = np.concatenate([[0], np.cumsum(lens)])
+            total = int(lens.sum())
+            rng = np.random.default_rng(SEED + updates)
+            init = (0.02 * rng.standard_normal(total)).astype(np.float32)
+            kv.register_dense(name, keys, lens=lens, shapes=shapes,
+                              flags=np.where(adamw, KEY_ELEMENTWISE, 0),
+                              init=init)
+            if eng.num_shards > 1:
+                try:
+                    eng.push_pull(name, np.zeros((W, total), np.float32),
+                                  handle)
+                except log.CheckError as exc:
+                    check("a matrix would lie across chips" in str(exc),
+                          f"muon over {eng.num_shards} shards refuses by "
+                          f"name: {exc}")
+                    check(eng.muon_updates == 0, "nothing ran under Muon")
+                    print(f"  over {eng.num_shards} shards muon refuses by "
+                          f"name")
+                    return
+                raise AssertionError(
+                    "muon over several shards did not refuse")
+            whole = apply_keys == len(lens)
+            p = [init[starts[k]:starts[k + 1]].astype(np.float64).reshape(
+                shapes[k]) for k in range(len(lens))]
+            mom = [np.zeros_like(x) for x in p]
+            v = [np.zeros_like(x) for x in p]
+            worst = [0.0, 0.0]
+            pulls = eng.kernel_pulls
+            for t in (1, 2):
+                g = rng.standard_normal((W, total)).astype(np.float32)
+                sent = g if t == 1 else jnp.asarray(g)
+                pulled = np.asarray(eng.push_pull(name, sent, handle))
+                gs = g.astype(np.float64).sum(axis=0)
+                for k in range(len(lens)):
+                    gk = gs[starts[k]:starts[k + 1]].reshape(shapes[k])
+                    got = pulled[starts[k]:starts[k + 1]].reshape(shapes[k])
+                    if adamw[k]:
+                        mom[k] = b1 * mom[k] + (1 - b1) * gk
+                        v[k] = b2 * v[k] + (1 - b2) * gk * gk
+                        p[k] = (p[k] * (1 - lr * wd)
+                                - lr * np.sqrt(1 - b2 ** t) / (1 - b1 ** t)
+                                * mom[k] / (np.sqrt(v[k]) + eps))
+                        np.testing.assert_allclose(
+                            got, p[k], atol=2e-7,
+                            err_msg=f"{name}: adamw key {k} step {t}")
+                        continue
+                    was = p[k]
+                    p[k], mom[k] = _muon_matrix_step(p[k], mom[k], gk, lr,
+                                                     mu, wd)
+                    step = np.sqrt(np.mean((p[k] - was) ** 2))
+                    diff = np.abs(got - p[k])
+                    worst = [max(worst[0], diff.max() / step),
+                             max(worst[1],
+                                 np.sqrt(np.mean(diff ** 2)) / step)]
+                    check(diff.max() < 0.3 * step
+                          and np.sqrt(np.mean(diff ** 2)) < 0.05 * step,
+                          f"{name} key {k} {tuple(shapes[k])} step {t}: off "
+                          f"by {diff.max() / step:.3f} of a step at worst, "
+                          f"{np.sqrt(np.mean(diff ** 2)) / step:.4f} rms")
+                if whole:
+                    check(np.array_equal(
+                        pulled, np.asarray(eng.pull(name))[:total]),
+                        f"{name} step {t}: the kernels' pulled vector is "
+                        f"the store's values bit for bit")
+            updates += 2
+            check(eng.muon_updates == updates
+                  and eng.muon_matrices == int((~adamw).sum()),
+                  "every op ran under Muon")
+            check(eng.muon_row_keys == row_keys and starts[1] % 1024 == 512,
+                  f"{name}: {row_keys} keys leave the row through a kernel, "
+                  f"not {eng.muon_row_keys}")
+            check(eng.muon_apply_keys == apply_keys
+                  and eng.kernel_pulls - pulls == 2 * whole,
+                  f"{name}: {apply_keys} keys are written back by a kernel, "
+                  f"not {eng.muon_apply_keys}; the pulled tree the kernels' "
+                  f"{eng.kernel_pulls - pulls} times of 2")
+            _, (m_got, am_got, av_got, slot) = eng.opt_state(name)
+            np.testing.assert_allclose(
+                np.asarray(m_got), np.concatenate(
+                    [mom[k].reshape(-1) for k in range(len(lens))
+                     if not adamw[k]]), atol=5e-6, err_msg="the momentum")
+            for got, want, what in ((am_got, mom, "m"), (av_got, v, "v")):
+                np.testing.assert_allclose(
+                    np.asarray(got), np.concatenate(
+                        [want[k].reshape(-1) for k in range(len(lens))
+                         if adamw[k]]), rtol=1e-5, atol=1e-7,
+                    err_msg=f"{name}: AdamW's {what}")
+            check(float(np.asarray(slot)[0]) == 2.0, "the step slot")
+            check(eng.opt_state_nbytes(name)
+                  == 4 * int(lens[~adamw].sum())
+                  + 8 * int(lens[adamw].sum()) + 4,
+                  "the state at its own size")
+            print(f"  {name}: {len(lens)} keys "
+                  f"({', '.join(f'{r}x{c}' for r, c in shapes)}): 2 steps "
+                  f"under {handle} agree, at worst {worst[0]:.3f} of a step "
+                  f"in an element, {worst[1]:.4f} rms; muon_row_keys "
+                  f"{eng.muon_row_keys}, muon_apply_keys "
+                  f"{eng.muon_apply_keys}, pulled by the kernels "
+                  f"{eng.kernel_pulls - pulls} of 2")
 
     def sparse(self) -> None:
         import pslite_tpu as ps

@@ -25,7 +25,7 @@ leaves its product, ``B`` after ``b*A + c*(A A)``, ``X`` after
 ``a*X + (B X)``), where the published code rounds every intermediate; the
 Frobenius norm is taken in f32 over the bf16 values.
 
-The products and the apply are plain XLA: a batched ``dot_general`` a
+The products are plain XLA: a batched ``dot_general`` a
 product, one batch a :class:`MuonChunk` (keys of one ``(shorter, longer)``
 side, tall ones transposed into it, at most ``MUON_CHUNK_VALUES`` values
 together, so that the bf16 temporaries of a chunk, X twice, A and B, stay a
@@ -42,13 +42,28 @@ Mosaic kernel with full vector registers: :func:`row_momentum` does a
 chunk's momentum pass on it, :func:`row_vector` hands an AdamW key over as
 a vector.  What decides is in the keys, not a knob; any other key keeps
 XLA's cut.
+
+The way out of the products is a kernel's too (PR 45).  Decay, step, a
+tall key's transpose, the store and the pulled tree are one piece of work,
+``W = W*(1 - lr*wd) - lr*0.2*sqrt(n)*O`` written where W lies and handed to
+the worker, which XLA did in four passes (a fusion to a temporary, a
+``dynamic_update_slice`` into a store whose tiles of 1,024 a key need not
+start on, a ``copy`` of O transposed back, a cut of the whole new store for
+the pulled tree).  :func:`row_apply` reads a block of O and the key's p
+where it lies and writes the new values twice, in place and into the
+pulled vector; :func:`row_adamw` is its element-wise sibling for the AdamW
+keys, m and v in place beside p.  Which keys is :func:`takes_apply`'s to
+say, per key; a bucket whose every key they write (``MuonPlan.pulls``)
+pulls the kernels' own vector, any other the program's cut.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple, Tuple
 
+import jax
 import numpy as np
 
 NS_COEFFS = (3.4445, -4.7750, 2.0315)
@@ -95,6 +110,8 @@ class MuonPlan(NamedTuple):
     ns_flops: float             # Newton-Schulz FLOPs a step, as published
     row_keys: np.ndarray        # key indices whose gradient a kernel takes
     #                             from the row (:func:`takes_row`)
+    apply_keys: np.ndarray      # key indices whose new values a kernel
+    #                             writes (:func:`takes_apply`)
 
     @property
     def matrices(self) -> int:
@@ -107,6 +124,13 @@ class MuonPlan(NamedTuple):
     @property
     def adamw_len(self) -> int:
         return int(self.adamw_starts[-1])
+
+    @property
+    def pulls(self) -> bool:
+        """Every key's new values are a kernel's, so the kernels can leave
+        the pulled vector whole beside the store."""
+        return len(self.apply_keys) == len(self.muon_keys) + len(
+            self.adamw_keys)
 
     @property
     def state_bytes(self) -> int:
@@ -157,6 +181,20 @@ def takes_row(start: int, rows: int, cols: int, elementwise: bool) -> bool:
     return rows % 16 == 0 and cols % LANES == 0
 
 
+def takes_apply(start: int, rows: int, cols: int, elementwise: bool,
+                lo: int = 0, state_len: int = 0) -> bool:
+    """Whether a kernel writes this key's new values where they lie, in
+    the store and in the pulled vector (:func:`row_apply`,
+    :func:`row_adamw`): what :func:`takes_row` asks of its start and its
+    sides, since the kernel addresses both vectors by rows of 128 values
+    and walks the blocks that way walks, and of an AdamW key besides that
+    its m and v, ``[lo, lo + rows * cols)`` of vectors ``[state_len]``,
+    lie so too.  Any other key keeps XLA's ``dynamic_update_slice``, and a
+    bucket with such a key the program's cut for its pulled tree."""
+    return (takes_row(start, rows, cols, elementwise)
+            and lo % LANES == 0 and state_len % LANES == 0)
+
+
 def muon_plan(shapes, elementwise, chunk_values: int = MUON_CHUNK_VALUES
               ) -> MuonPlan:
     """``shapes`` ``[K, 2]`` (rows, cols a key), ``elementwise`` ``[K]``
@@ -189,12 +227,19 @@ def muon_plan(shapes, elementwise, chunk_values: int = MUON_CHUNK_VALUES
                 all(ok[k] for k in part)))
     row_keys = sorted([k for c in chunks if c.row for k in c.keys]
                       + [int(k) for k in adamw if ok[k]])
+    adamw_starts = np.concatenate([[0], np.cumsum(lens[adamw])])
+    lie = {int(k): (int(lo), int(adamw_starts[-1]))
+           for k, lo in zip(adamw, adamw_starts)}
+    apply_keys = [k for k in range(len(lens)) if takes_apply(
+        int(starts[k]), int(shapes[k, 0]), int(shapes[k, 1]),
+        bool(elementwise[k]), *lie.get(k, ()))]
     return MuonPlan(
         chunks=tuple(chunks), muon_keys=muon, adamw_keys=adamw,
         mom_starts=np.concatenate([[0], np.cumsum(lens[muon])]),
-        adamw_starts=np.concatenate([[0], np.cumsum(lens[adamw])]),
+        adamw_starts=adamw_starts,
         ns_flops=float(sum(ns_flops(*shapes[k]) for k in muon)),
-        row_keys=np.array(row_keys, np.int64))
+        row_keys=np.array(row_keys, np.int64),
+        apply_keys=np.array(apply_keys, np.int64))
 
 
 def state_shapes(plan: MuonPlan) -> Tuple[Tuple[int, ...], ...]:
@@ -348,21 +393,266 @@ def row_momentum(momentum, row, mom, chunk: MuonChunk, starts, tall: bool,
     )(jnp.asarray(lanes, jnp.int32), jnp.asarray(slots, jnp.int32), *args)
 
 
+def _window_step(t, steps: int, reads, writes, compute):
+    """Grid step ``t`` of ``steps`` of a kernel that moves its windows of
+    HBM itself, two slots deep: ``reads(t, slot)`` and ``writes(t, slot)``
+    are the step's copies into and out of VMEM slot ``slot``,
+    ``compute(slot)`` fills what is written from what was read.  A step
+    starts the next step's reads before it waits for its own, and waits
+    for a slot's writes when the slot comes round again, the last step
+    for all that are under way (a wait names a copy of the size it is to
+    take: the semaphore counts bytes)."""
+    from jax import lax
+    from jax.experimental import pallas as pl
+
+    slot = lax.rem(t, 2)
+
+    @pl.when(t == 0)
+    def _():
+        for copy in reads(t, slot):
+            copy.start()
+
+    @pl.when(t + 1 < steps)
+    def _():
+        for copy in reads(t + 1, 1 - slot):
+            copy.start()
+
+    for copy in reads(t, slot):
+        copy.wait()
+
+    @pl.when(t >= 2)
+    def _():
+        for copy in writes(t, slot):
+            copy.wait()
+
+    compute(slot)
+    for copy in writes(t, slot):
+        copy.start()
+
+    @pl.when(t == steps - 1)
+    def _():
+        for s in (slot, 1 - slot)[:min(steps, 2)]:
+            for copy in writes(t, s):
+                copy.wait()
+
+
+def _write_back(name: str, body, prefetch, blocked, spec, vectors, pulled,
+                pulled_len: int, steps: int, streams: int, *,
+                interpret: bool):
+    """The call of a kernel that updates ``vectors`` (flat, whole lanes
+    long) where they lie and, with ``pulled_len``, leaves the pulled
+    vector ``f32[pulled_len]`` beside them: ``pulled`` where a call before
+    this one has made it, made here (and what this call does not write
+    left unset) where None.  All of them stay in HBM, seen as rows of 128
+    values, the view that costs nothing (the chip tiles a vector by 1,024
+    and a key may start anywhere in a tile: no block of a ``BlockSpec``
+    starts there, so the kernel copies its windows itself,
+    :func:`_window_step`); ``blocked`` alone comes in blocks, by ``spec``.
+
+    ``body(t, prefetched, block, outs, bufs, sems)`` is grid step ``t`` of
+    ``steps``: ``outs`` the vectors' rows and last the pulled vector's,
+    ``bufs`` f32 VMEM ``[2 slots, 2 ways (in, out), streams, rows, 128]``
+    of ``spec``'s rows of 128 values, ``sems`` the DMA semaphores ``[2, 2,
+    streams + 1]`` beside them.  Returns the vectors, then the pulled vector
+    or None."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    views = [x.reshape(-1, LANES) for x in vectors]
+    out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in views]
+    if pulled is not None:
+        views.append(pulled.reshape(-1, LANES))
+    if pulled_len:
+        out_shape.append(jax.ShapeDtypeStruct(
+            (pulled_len // LANES, LANES), jnp.float32))
+    fold = math.prod(spec.block_shape) // LANES
+
+    def kernel(*refs):
+        # Prefetched scalars, the block, the arguments (which the results
+        # alias: the same memory), the results, the scratch.
+        at = len(prefetch) + 1 + len(views)
+        body(pl.program_id(0), refs[:len(prefetch)], refs[len(prefetch)],
+             refs[at:at + len(out_shape)], *refs[-2:])
+
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    outs = pl.pallas_call(
+        kernel,
+        out_shape=tuple(out_shape),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(steps,),
+            in_specs=[spec, *[hbm] * len(views)],
+            out_specs=tuple([hbm] * len(out_shape)),
+            scratch_shapes=[
+                pltpu.VMEM((2, 2, streams, fold, LANES), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 2, streams + 1))]),
+        input_output_aliases={len(prefetch) + 1 + i: i
+                              for i in range(len(views))},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=ROW_VMEM_BYTES),
+        # The interpreter that knows DMAs and semaphores.
+        interpret=(pltpu.InterpretParams(dma_execution_mode="eager")
+                   if interpret else False),
+        name=name,
+    )(*prefetch, blocked, *views)
+    flat = [x.reshape(-1) for x in outs]
+    return (*flat[:len(vectors)], flat[-1] if pulled_len else None)
+
+
+def row_apply(new_values, scale: float, o, store, pulled, chunk: MuonChunk,
+              starts, tall: bool, slots, *, pulled_len: int = 0,
+              interpret: bool):
+    """The way out of the products for the ``tall`` (or the other) keys of
+    ``chunk`` in ``slots``: ``p = new_values(p, O, scale)`` of a key
+    written where it lies in ``store`` (the flat f32 store, in place) and,
+    with ``pulled_len``, into the vector the program pulls
+    (:func:`_write_back`).  Returns ``(store, pulled)``, the store first:
+    an f32 first result is how ``benchmark/muon_ops.py`` tells this pass
+    from Newton-Schulz.
+
+    A grid step holds a block of whole rows of one key's bfloat16 O as
+    :func:`row_momentum` holds the gradient's, widens it, transposes a
+    tall key's in VMEM and folds it to rows of 128 values, which is how it
+    addresses p."""
+    import jax.numpy as jnp
+
+    first = [int(starts[chunk.keys[i]]) // LANES for i in slots]
+    return _row_apply(
+        jnp.asarray(first, jnp.int32), jnp.asarray(slots, jnp.int32), o,
+        store, pulled, new_values=new_values, scale=scale, tall=tall,
+        pulled_len=pulled_len, interpret=interpret)
+
+
+# A program calls these kernels dozens of times, and many calls differ in
+# nothing but where their keys lie, which they are told in scalars: under
+# ``jit`` a call whose shapes and static arguments an earlier one had is
+# neither traced nor lowered again (35 calls of ``moonlight-16b-muon``'s
+# step are 14 traces; on the chip's host a trace is a third of a second).
+@functools.partial(jax.jit, static_argnames=(
+    "new_values", "scale", "tall", "pulled_len", "interpret"))
+def _row_apply(first, slots, o, store, pulled, *, new_values, scale, tall,
+               pulled_len, interpret):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    _, m, n = o.shape
+    rows, cols = (n, m) if tall else (m, n)
+    step = _block_rows(rows, cols, LANES if tall else 16)
+    per, fold = rows // step, step * cols // LANES
+    steps = slots.shape[0] * per
+
+    def body(t, prefetched, o_ref, outs, bufs, sems):
+        first_ref, _ = prefetched
+
+        def window(ref, t):
+            return ref.at[pl.ds(first_ref[t // per] + (t % per) * fold, fold)]
+
+        def reads(t, slot):
+            return [pltpu.make_async_copy(
+                window(outs[0], t), bufs.at[slot, 0, 0], sems.at[slot, 0, 0])]
+
+        def writes(t, slot):
+            return [pltpu.make_async_copy(
+                bufs.at[slot, 1, 0], window(ref, t), sems.at[slot, 1, i])
+                for i, ref in enumerate(outs)]
+
+        def compute(slot):
+            o32 = o_ref[0].astype(jnp.float32)
+            o32 = (o32.T if tall else o32).reshape(fold, LANES)
+            bufs[slot, 1, 0] = new_values(bufs[slot, 0, 0], o32, scale)
+
+        _window_step(t, steps, reads, writes, compute)
+
+    def at(t, first_ref, slots_ref):
+        i, j = slots_ref[t // per], t % per
+        return (i, 0, j) if tall else (i, j, 0)
+
+    return _write_back(
+        "muon_row_apply", body, (first, slots), o,
+        pl.BlockSpec((1, m, step) if tall else (1, step, n), at), [store],
+        pulled, pulled_len, steps, 1, interpret=interpret)
+
+
+def row_adamw(adamw, alpha, g, m, v, store, pulled, start: int, lo: int, *,
+              pulled_len: int = 0, interpret: bool):
+    """:func:`row_apply`'s element-wise sibling for one AdamW key of
+    ``g.shape[0]`` values: ``p, m, v = adamw(p, m, v, g, alpha)`` with p at
+    ``start`` of the store and m and v at ``lo`` of their vectors, all
+    three in place, and the new p into the pulled vector as there.  ``g``
+    is the key's gradient as :func:`row_vector` hands it over, ``alpha``
+    the step's scalar.  Returns ``(store, m, v, pulled)``."""
+    import jax.numpy as jnp
+
+    # p and the pulled values lie at the key's start, m and v at lo.
+    base = [start // LANES, lo // LANES, lo // LANES, start // LANES]
+    return _row_adamw(
+        jnp.reshape(alpha, (1,)), jnp.asarray(base, jnp.int32), g, m, v,
+        store, pulled, adamw=adamw, pulled_len=pulled_len,
+        interpret=interpret)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("adamw", "pulled_len", "interpret"))
+def _row_adamw(alpha, base, g, m, v, store, pulled, *, adamw, pulled_len,
+               interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n = g.shape[0]
+    fold = _vector_tile(n) // LANES
+    steps = n // (fold * LANES)
+
+    def body(t, prefetched, g_ref, outs, bufs, sems):
+        alpha_ref, base_ref = prefetched
+
+        def window(i, t):
+            return outs[i].at[pl.ds(base_ref[i] + t * fold, fold)]
+
+        def reads(t, slot):
+            return [pltpu.make_async_copy(
+                window(i, t), bufs.at[slot, 0, i], sems.at[slot, 0, i])
+                for i in range(3)]
+
+        def writes(t, slot):
+            return [pltpu.make_async_copy(
+                bufs.at[slot, 1, i % 3], window(i, t), sems.at[slot, 1, i])
+                for i in range(len(outs))]
+
+        def compute(slot):
+            new = adamw(bufs[slot, 0, 0], bufs[slot, 0, 1], bufs[slot, 0, 2],
+                        g_ref[...], alpha_ref[0])
+            for i in range(3):
+                bufs[slot, 1, i] = new[i]
+
+        _window_step(t, steps, reads, writes, compute)
+
+    return _write_back(
+        "muon_row_adamw", body, (alpha, base), g.reshape(-1, LANES),
+        pl.BlockSpec((fold, LANES), lambda t, *_: (t, 0)), [store, m, v],
+        pulled, pulled_len, steps, 3, interpret=interpret)
+
+
 def muon_update(store, state, agg, starts, shapes, plan: MuonPlan, *,
                 lr: float, mu: float, wd: float, b1: float, b2: float,
-                eps: float, interpret: bool = False):
+                eps: float, pulled_len: int = 0, interpret: bool = False):
     """One step on the one shard that holds the bucket.  ``store`` is the
     flat f32 store, ``state`` as :func:`state_shapes` lays it out with the
     step slot last, ``agg`` the summed gradient as a row ``[1, total]``.
-    Returns the new store and state.
+    Returns the new store and state and, with ``pulled_len`` (the bucket's
+    ``total_len``, where ``plan.pulls``), the new parameters once more as
+    a vector of their own: the pulled tree, which a cut of the store after
+    the fact would read and write again.
 
     Every key's values are read from and written to the store where they
-    lie (a chain of ``dynamic_update_slice`` in place, a chunk at a time:
-    the barrier between two chunks keeps the next one's temporaries from
-    being made before this one's are let go).  A key's gradient leaves
-    the row through a kernel where the plan says it can (``plan.row_keys``;
-    ``interpret`` runs those kernels in the Pallas interpreter) and by
-    XLA's cut where not."""
+    lie, a chunk at a time: the barrier between two chunks keeps the next
+    one's temporaries from being made before this one's are let go.  A
+    key's gradient leaves the row through a kernel where the plan says it
+    can (``plan.row_keys``), and its new values reach the store, and the
+    pulled vector, through one (``plan.apply_keys``); ``interpret`` runs
+    those kernels in the Pallas interpreter.  Any other key keeps XLA's
+    cut on the way in and its ``dynamic_update_slice`` on the way out."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -371,6 +661,8 @@ def muon_update(store, state, agg, starts, shapes, plan: MuonPlan, *,
     n_chunks = len(plan.chunks)
     moms, (adam_m, adam_v, step_l) = state[:n_chunks], state[n_chunks:]
     keep = 1.0 - lr * wd
+    assert plan.pulls or not pulled_len, "a key of the bucket keeps the cut"
+    pulled = None
 
     def put(store, k: int, new_p):
         return lax.dynamic_update_slice(store, new_p, (int(starts[k]),))
@@ -393,7 +685,16 @@ def muon_update(store, state, agg, starts, shapes, plan: MuonPlan, *,
         x = (g + mu * mom).astype(bf16)
         return mom, x
 
+    def new_values(p, o, scale):
+        return p * keep - scale * o
+
+    def adamw(p, m, v, g, alpha):
+        m = b1 * m + (1.0 - b1) * g
+        v = b2 * v + (1.0 - b2) * g * g
+        return p * keep - alpha * m / (jnp.sqrt(v) + eps), m, v
+
     row_keys = {int(k) for k in plan.row_keys}
+    apply_keys = {int(k) for k in plan.apply_keys}
     new_moms = []
     for chunk, mom in zip(plan.chunks, moms):
         with jax.named_scope("ps.update.muon.momentum"):
@@ -412,10 +713,17 @@ def muon_update(store, state, agg, starts, shapes, plan: MuonPlan, *,
             o = newton_schulz(x)
         with jax.named_scope("ps.update.muon.apply"):
             scale = lr * RMS_MATCH * math.sqrt(chunk.n)
+            taken = [i for i, k in enumerate(chunk.keys) if k in apply_keys]
+            for tall in sorted({chunk.tall[i] for i in taken}):
+                store, pulled = row_apply(
+                    new_values, scale, o, store, pulled, chunk, starts, tall,
+                    [i for i in taken if chunk.tall[i] == tall],
+                    pulled_len=pulled_len, interpret=interpret)
             for i, (k, tall) in enumerate(zip(chunk.keys, chunk.tall)):
-                o_k = (o[i].T if tall else o[i]).reshape(-1).astype(f32)
-                new_p = key_values(store, k) * keep - scale * o_k
-                store = put(store, k, new_p)
+                if i not in taken:
+                    o_k = (o[i].T if tall else o[i]).reshape(-1).astype(f32)
+                    store = put(store, k, new_values(
+                        key_values(store, k), o_k, scale))
         new_moms.append(mom)
         store, agg = lax.optimization_barrier((store, agg))
 
@@ -431,12 +739,20 @@ def muon_update(store, state, agg, starts, shapes, plan: MuonPlan, *,
             g = (row_vector(agg, int(starts[k]), hi - lo,
                             interpret=interpret) if k in row_keys
                  else key_grad(agg, k, (hi - lo,)))
-            m_k = b1 * lax.slice(adam_m, (lo,), (hi,)) + (1.0 - b1) * g
-            v_k = b2 * lax.slice(adam_v, (lo,), (hi,)) + (1.0 - b2) * g * g
-            new_p = (key_values(store, k) * keep
-                     - alpha * m_k / (jnp.sqrt(v_k) + eps))
+            if k in apply_keys:
+                store, adam_m, adam_v, pulled = row_adamw(
+                    adamw, alpha, g, adam_m, adam_v, store, pulled,
+                    int(starts[k]), lo, pulled_len=pulled_len,
+                    interpret=interpret)
+                continue
+            new_p, m_k, v_k = adamw(
+                key_values(store, k), lax.slice(adam_m, (lo,), (hi,)),
+                lax.slice(adam_v, (lo,), (hi,)), g, alpha)
             adam_m = lax.dynamic_update_slice(adam_m, m_k, (lo,))
             adam_v = lax.dynamic_update_slice(adam_v, v_k, (lo,))
             store = put(store, k, new_p)
 
-    return store, (*new_moms, adam_m, adam_v, step_l + 1.0)
+    new_state = (*new_moms, adam_m, adam_v, step_l + 1.0)
+    if pulled is None:
+        return store, new_state
+    return store, new_state, pulled

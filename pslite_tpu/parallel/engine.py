@@ -475,12 +475,14 @@ class CollectiveEngine:
         self.narrow_ops = 0
         # Ops whose program applied Muon (``engine.update.muon``), and
         # what a step of the last of them holds by its plan: matrices, the
-        # keys whose gradient a kernel takes from the row, and
-        # Newton-Schulz FLOPs (``engine.update.muon.matrices``,
-        # ``.row_keys``, ``.ns_flops``).
+        # keys whose gradient a kernel takes from the row, the keys whose
+        # new values a kernel writes, and Newton-Schulz FLOPs
+        # (``engine.update.muon.matrices``, ``.row_keys``, ``.apply_keys``,
+        # ``.ns_flops``).
         self.muon_updates = 0
         self.muon_matrices = 0
         self.muon_row_keys = 0
+        self.muon_apply_keys = 0
         self.muon_ns_flops = 0.0
 
     # -- registration --------------------------------------------------------
@@ -822,9 +824,14 @@ class CollectiveEngine:
         every matrix whole where its products run; where it cannot run it
         says so by name (:meth:`_muon_refusal`) and nothing falls back to
         an element-wise update.  The new parameters are written where
-        they lie in the store; the pulled vector is the program's cut of
-        it (:meth:`_stateful_program`)."""
+        they lie in the store, and with ``pulled_len`` (as
+        :meth:`_lamb_fn` takes it, where :meth:`_kernel_pulls` says so)
+        the kernels that write them leave them once more as a vector of
+        their own, which ``fn`` hands back third: the pulled values.
+        What ``fn`` traces is found by the handle's numbers and the keys'
+        shapes and flags (``call_traced``)."""
         from ..ops import muon
+        from ..utils.compile_cache import call_traced
 
         refusal = self._muon_refusal(handle, bucket)
         log.check(refusal is None, refusal)
@@ -832,11 +839,25 @@ class CollectiveEngine:
             handle, (1e-3, 0.95, 0.1, 0.9, 0.95, 1e-8))
         plan = self._muon_plan(bucket)
         starts, shapes, interp = bucket.starts, bucket.shapes, self._interpret
+        elementwise = (bucket.flags & KEY_ELEMENTWISE) != 0
 
-        def fn(store_l, state_l, agg):
-            return muon.muon_update(
-                store_l, state_l, agg, starts, shapes, plan, lr=lr, mu=mu,
-                wd=wd, b1=b1, b2=b2, eps=eps, interpret=interp)
+        def fn(store_l, state_l, agg, pulled_len=0):
+            def update(store_l, agg, *state_l):
+                return muon.muon_update(
+                    store_l, state_l, agg, starts, shapes, plan, lr=lr,
+                    mu=mu, wd=wd, b1=b1, b2=b2, eps=eps,
+                    pulled_len=pulled_len, interpret=interp)
+
+            if interp:
+                return update(store_l, agg, *state_l)
+            # Compiled for the chip, the step's trace is kept between
+            # processes as its kernels' are in ``ops/row_add.py``: dozens
+            # of Mosaic kernels are seconds of tracing on the chip's host,
+            # each run, before the compile cache can be asked.
+            return call_traced(
+                update, muon.__file__, "tpu", store_l, agg, *state_l,
+                static=(lr, mu, wd, b1, b2, eps, pulled_len,
+                        np.asarray(shapes).tolist(), elementwise.tolist()))
 
         return fn
 
@@ -1952,15 +1973,21 @@ class CollectiveEngine:
                       bucket: Optional[DenseBucket]) -> bool:
         """Whether the program of ``bucket`` under ``op`` and ``handle``
         takes its pulled values from the update kernel: it returns them
-        (not the store in their place, and not nothing), the handle is
-        ``lamb`` (whose second pass can leave them,
-        ``fused_update.lamb_apply``), and one shard holds the whole
-        bucket.  Over several shards, and under every other handle, they
-        are the all-gather of the shards, cut at ``total_len``."""
+        (not the store in their place, and not nothing), one shard holds
+        the whole bucket, and the handle is ``lamb`` (whose second pass
+        can leave them, ``fused_update.lamb_apply``) or ``muon`` on a
+        bucket whose every key's new values a kernel writes
+        (``MuonPlan.pulls``).  Over several shards, and under every other
+        handle, they are the all-gather of the shards, cut at
+        ``total_len``."""
         from ..ops.fused_update import lamb_apply_pulls
 
-        return (op == "push_pull_st" and self.num_shards == 1
-                and bucket is not None and handle.startswith("lamb")
+        if (op != "push_pull_st" or self.num_shards != 1
+                or bucket is None):
+            return False
+        if handle.startswith("muon"):
+            return self._muon_plan(bucket).pulls
+        return (handle.startswith("lamb")
                 and lamb_apply_pulls(bucket.total_len))
 
     def _lamb_plan(self, bucket: DenseBucket):
@@ -1981,9 +2008,10 @@ class CollectiveEngine:
         """``prog`` behind the counts of ``engine.update.lamb`` (with
         ``engine.update.lamb.one_pass``, the elements that the last such
         program updated in one pass) or ``engine.update.muon`` (with
-        ``.matrices`` and ``.ns_flops``, a step's by the plan of the last
-        such program), where the program takes its pulled values from
-        ``lamb_apply`` of ``engine.pull.from_kernel``, and on a mixed
+        ``.matrices``, ``.row_keys``, ``.apply_keys`` and ``.ns_flops``, a
+        step's by the plan of the last such program), where the program
+        takes its pulled values from the update's kernels
+        (:meth:`_kernel_pulls`) of ``engine.pull.from_kernel``, and on a mixed
         bucket of ``engine.dense.narrow``: what a record of :meth:`_bind`
         knows is counted by the record's own program, and no other op pays
         for it."""
@@ -1999,6 +2027,7 @@ class CollectiveEngine:
                 self.muon_updates += 1
                 self.muon_matrices = plan.matrices
                 self.muon_row_keys = len(plan.row_keys)
+                self.muon_apply_keys = len(plan.apply_keys)
                 self.muon_ns_flops = plan.ns_flops
             self.kernel_pulls += kernel_pulls
             self.narrow_ops += narrow
@@ -2017,6 +2046,8 @@ class CollectiveEngine:
                        fn=lambda: self.muon_matrices)
         registry.gauge("engine.update.muon.row_keys",
                        fn=lambda: self.muon_row_keys)
+        registry.gauge("engine.update.muon.apply_keys",
+                       fn=lambda: self.muon_apply_keys)
         registry.gauge("engine.update.muon.ns_flops",
                        fn=lambda: self.muon_ns_flops)
         registry.gauge("engine.pull.from_kernel",

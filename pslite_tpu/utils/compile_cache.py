@@ -76,7 +76,7 @@ def _trace_dir():
     return jax.config.jax_compilation_cache_dir or None
 
 
-def call_traced(fn, source: str, platform: str, *args):
+def call_traced(fn, source: str, platform: str, *args, static=()):
     """``fn(*args)`` inside a program that is lowered for ``platform``,
     with ``fn``'s trace kept between processes as a compiled program is.
 
@@ -84,10 +84,13 @@ def call_traced(fn, source: str, platform: str, *args):
     result is written beside the compiled programs; a later process reads
     it back and calls it without running ``fn``, so without importing what
     ``fn`` imports.  An entry is found by the jax version, ``platform``,
-    the arguments' shapes and dtypes and the bytes of the file ``source``
-    (``fn``'s module: an edited kernel is traced again).  Where no
-    directory keeps programs, ``fn`` is called in place.  ``fn`` takes
-    arrays only and runs on one device (a ``shard_map`` body's view)."""
+    the arguments' shapes and dtypes, the bytes of the file ``source``
+    (``fn``'s module: an edited kernel is traced again) and ``static``:
+    whatever else ``fn`` is made from that its arguments do not show (a
+    closure's numbers, as a tuple of values whose ``repr`` says them
+    whole).  Where no directory keeps programs, ``fn`` is called in place.
+    ``fn`` takes arrays only and runs on one device (a ``shard_map``
+    body's view)."""
     import jax
 
     directory = _trace_dir()
@@ -97,7 +100,8 @@ def call_traced(fn, source: str, platform: str, *args):
     with open(source, "rb") as fh:
         key = hashlib.sha256(fh.read())
     key.update(repr((jax.__version__, platform,
-                     [(a.shape, str(a.dtype)) for a in avals])).encode())
+                     [(a.shape, str(a.dtype)) for a in avals],
+                     *([static] if static else []))).encode())
     path = os.path.join(directory, "traced-" + key.hexdigest())
     exported = _traced.get(path)
     if exported is None:

@@ -839,3 +839,111 @@ def test_a_vector_the_chip_lays_in_one_tile_is_cut_from_the_store(
     text = compiled.as_text()
     assert _results(text, "lamb_apply") == [f"f32[{padded // 128},128]"] * 3
     assert _makers(text, f"f32[{total}]")
+
+
+@pytest.mark.parametrize("op, pulls", [("push_pull_st", True),
+                                       ("push_st", False)])
+def test_muon_writes_a_key_back_where_it_lies_with_kernels(
+        v5e8_mesh, op, pulls, tmp_path, monkeypatch):
+    """``muon`` on a bucket of the cell's widths, every second key 512
+    values off a tile of the store (behind a gain of 512 values, as every
+    second layer of ``moonlight-16b-muon`` lies): wide and tall expert
+    matrices, a key too wide for more than sixteen rows a block, an AdamW
+    key of two grid steps whose m and v lie 512 off a tile too.  The
+    kernels that write the keys back (``ops/muon.py`` ``row_apply``,
+    ``row_adamw``: their own DMAs on windows four sublanes into a tile)
+    lower through Mosaic; nothing of the store's size is XLA's, no copy,
+    slice, update or fusion; and where the program pulls, the pulled
+    vector is a view of the last kernel's second result
+    (``benchmark/tests/test_compile_fullsize_muon_apply.py`` has the cell's
+    own program).  The pulling program is built as a process with a
+    compile cache builds it: the step's trace is kept
+    (``utils/compile_cache.py`` ``call_traced``), and a second program,
+    which a new process would build, is made from the kept trace without
+    running ``muon_update`` and compiles to the same text."""
+    import re
+
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from pslite_tpu.ops import muon
+    from pslite_tpu.parallel.engine import (KEY_ELEMENTWISE,
+                                            CollectiveEngine, DenseBucket,
+                                            _padded_len)
+
+    handle = "muon:1e-3,0.95,0.1,0.9,0.95,1e-8"
+    shapes = np.array([(1, 512), (1408, 2048), (2048, 1408), (1408, 2048),
+                       (1, 524288), (1, 512), (2048, 11264), (64, 2048)])
+    adamw = shapes[:, 0] == 1
+    lens = shapes[:, 0] * shapes[:, 1]
+    starts = np.concatenate([[0], np.cumsum(lens)])
+    assert {int(s) % 1024 for s in starts[:-1]} == {0, 512}
+    mesh = Mesh(np.array(v5e8_mesh.devices.flat[:1]), ("kv",))
+    eng = CollectiveEngine(mesh=mesh, server_handle=handle)
+    total, padded = int(lens.sum()), _padded_len(int(lens.sum()), 1, True)
+    bucket = DenseBucket(
+        name="tree", keys=np.arange(len(lens), dtype=np.uint64), val_len=0,
+        dtype=jnp.float32, total_len=total, padded_len=padded, lens=lens,
+        flags=np.where(adamw, KEY_ELEMENTWISE, 0).astype(np.int32),
+        shapes=shapes)
+    plan = eng._muon_plan(bucket)
+    assert plan.pulls and len(plan.apply_keys) == len(lens)
+    assert eng._kernel_pulls(op, handle, bucket) == pulls
+    shard = NamedSharding(mesh, P("kv"))
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                             sharding=shard)
+    state = [sds(s) for s in muon.state_shapes(plan)] + [sds((1,))]
+    grads = jax.ShapeDtypeStruct(
+        (1, total), jnp.float32, sharding=NamedSharding(mesh, P("kv", None)))
+    from pslite_tpu.utils import compile_cache
+
+    if pulls:
+        monkeypatch.setattr(compile_cache, "_trace_dir",
+                            lambda: str(tmp_path))
+
+    def compile_():
+        eng = CollectiveEngine(mesh=mesh, server_handle=handle)
+        return eng._program(op, padded, jnp.float32, handle, bucket).lower(
+            sds((padded,)), *state, grads).compile()
+
+    compiled = compile_()
+    assert compiled.memory_analysis().alias_size_in_bytes >= (
+        4 * padded + plan.state_bytes)
+    text = compiled.as_text()
+    kept = [f for f in os.listdir(tmp_path) if f.startswith("traced-")]
+    assert len(kept) == pulls
+    if pulls:
+        compile_cache._traced.clear()       # what a new process knows
+        monkeypatch.setattr(muon, "muon_update", None)
+        again = compile_().as_text()
+        ops = lambda t: [re.sub(r"metadata=\{[^}]*\}", "", l)  # call sites
+                         for l in t[t.index("ENTRY"):].splitlines()
+                         if "tpu_custom_call" not in l]     # and kernels'
+        assert ops(again) == ops(text)
+        assert again.count("tpu_custom_call") == text.count(
+            "tpu_custom_call")
+    lines = [l.replace("ROOT ", "").strip()
+             for l in text[text.index("ENTRY"):].splitlines()]
+    applies = [l for l in lines if l.startswith("%muon_row_apply")]
+    adamws = [l for l in lines if l.startswith("%muon_row_adamw")]
+    # Three chunks, one of both orientations; three AdamW keys.
+    assert len(applies) == 4 and len(adamws) == 3
+    store, pulled = f"f32[{padded // 128},128]", f"f32[{total // 128},128]"
+    for l in applies + adamws:
+        # The store first, an f32 result: ``benchmark/muon_ops.py``.
+        assert "tpu_custom_call" in l and re.search(
+            rf"= \(?{re.escape(store)}", l), l[:200]
+        assert (pulled in l.split(" custom-call(")[0]) == pulls, l[:200]
+    whole = [l for l in lines
+             if re.search(rf"= \(?f32\[({padded}|{total}|{padded // 128},128"
+                          rf"|{total // 128},128)\]", l)]
+    xlas = [l[:160] for l in whole if not re.search(
+        r" (parameter|bitcast|get-tuple-element|custom-call|opt-barrier|"
+        r"tuple)\(", l)]
+    assert whole and not xlas, xlas
+    if pulls:
+        root = next(l for l in lines if l.startswith("%tuple")
+                    or " tuple(" in l and f"f32[{total}]" in l)
+        last = re.findall(r"%([\w.\-]+)", root.split(" tuple(")[1])[-1]
+        made = next(l for l in lines if l.startswith(f"%{last} = "))
+        assert " bitcast(" in made, made[:200]

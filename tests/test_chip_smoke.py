@@ -64,6 +64,12 @@ def test_the_muon_phase_where_one_shard_holds_the_bucket(capsys, monkeypatch):
     out = capsys.readouterr().out
     assert "phase muon: ok" in out
     assert "2 steps under muon:0.001,0.95,0.1,0.9,0.95,1e-08 agree" in out
+    # PR 45: the first bucket keeps the program's cut (a key of it is no
+    # kernel's), the second pulls the kernels' own vector.
+    assert ("muon_row_keys 3, muon_apply_keys 3, pulled by the kernels "
+            "0 of 2") in out
+    assert ("muon_row_keys 7, muon_apply_keys 7, pulled by the kernels "
+            "2 of 2") in out
 
 
 def test_failing_phase_is_named(monkeypatch):

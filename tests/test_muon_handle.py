@@ -482,7 +482,7 @@ def test_the_program_names_its_four_parts():
         lambda store, state, agg: muon.muon_update(
             store, state, agg, STARTS, SHAPES, plan, interpret=True,
             **HYPER)
-    ).lower(np.zeros(TOTAL, np.float32),
+    ).lower(np.zeros(TOTAL + -TOTAL % muon.LANES, np.float32),  # a shard
             (*state, np.zeros(1, np.float32)),
             np.zeros((1, TOTAL), np.float32)).as_text(debug_info=True)
     for scope in ("ps.update.muon.momentum", "ps.update.muon.ns",
@@ -530,10 +530,23 @@ def _todays_cut(row, name):
 
 
 def _without_the_row(plan):
-    """The parent's plan: every chunk and every AdamW key by XLA's cut."""
-    return plan._replace(
+    """PR 43's plan: every chunk and every AdamW key by XLA's cut on the
+    way in and its ``dynamic_update_slice`` on the way out."""
+    return _without_the_apply(plan)._replace(
         chunks=tuple(c._replace(row=False) for c in plan.chunks),
         row_keys=np.array([], np.int64))
+
+
+def _without_the_apply(plan):
+    """PR 44's plan: no key's new values are a kernel's."""
+    return plan._replace(apply_keys=np.array([], np.int64))
+
+
+def _shard(values):
+    """A store as a shard holds it: whole lanes long, and what lies
+    behind the last key is nobody's."""
+    return np.pad(np.asarray(values, np.float32),
+                  (0, -len(values) % 1024 + 1024), constant_values=3.0)
 
 
 def _row_state(plan, rng):
@@ -614,7 +627,7 @@ def test_a_key_leaves_the_row_as_todays_cut_bit_for_bit(case):
         # the kernels and the parent's give one store and one state.
         for name in ("wide", "odd", "odd_gain", "late"):
             assert ROW_NAMES.index(name) not in plan.row_keys
-        store = _init(rng, ROW_TOTAL)
+        store = _shard(_init(rng, ROW_TOTAL))
         state = _row_state(plan, rng)
         got, want = (jax.jit(lambda *a, p=p: muon.muon_update(
             *a, ROW_STARTS, ROW_SHAPES, p, interpret=True, **HYPER))(
@@ -690,6 +703,328 @@ def test_the_gauge_counts_the_keys_that_left_the_row_through_a_kernel(
     kv.wait(kv.push_pull(KEYS, np.ones((1, TOTAL), np.float32), None))
     assert gauges()["engine.update.muon.row_keys"] == 1
     assert gauges()["engine.update.muon.matrices"] == 7
+
+
+# -- a key's new values written where they lie, once (PR 45) --------------------
+
+# A bucket whose every key a kernel writes back: wide and tall matrices and
+# AdamW vectors on a tile border of 1,024 and 512 values off it (behind a
+# gain of 512 values), two chunks, one of both orientations, and a tree that
+# ends 128 values into a tile.  With ``ROW_BLOCK_VALUES`` at 4,096
+# (``small_blocks``) every key but the gains takes two grid steps or more.
+APPLY_TREE = [
+    ("emb.w", (64, 128), True),
+    ("wide", (32, 256), False),          # on a tile border
+    ("gain.0", (1, 512), True),
+    ("off_tile", (32, 256), False),      # 512 off, the chunk of "wide"
+    ("tall", (256, 128), False),         # 512 off
+    ("mate", (128, 256), False),         # 512 off, the chunk of the talls
+    ("gain.1", (1, 512), True),          # 512 off, in the store and in m, v
+    ("tall.on", (256, 128), False),      # on a tile border
+    ("norm", (1, 128), True),
+]
+APPLY_NAMES = [n for n, _, _ in APPLY_TREE]
+APPLY_SHAPES = np.array([s for _, s, _ in APPLY_TREE])
+APPLY_ADAMW = np.array([a for _, _, a in APPLY_TREE])
+APPLY_LENS = APPLY_SHAPES[:, 0] * APPLY_SHAPES[:, 1]
+APPLY_STARTS = np.concatenate([[0], np.cumsum(APPLY_LENS)])
+APPLY_TOTAL = int(APPLY_LENS.sum())
+APPLY_KEYS = np.arange(500, 500 + len(APPLY_TREE), dtype=np.uint64)
+APPLY_FLAGS = np.where(APPLY_ADAMW, KEY_ELEMENTWISE, 0)
+
+
+@pytest.fixture()
+def small_blocks(monkeypatch):
+    monkeypatch.setattr(muon, "ROW_BLOCK_VALUES", 4096)
+
+
+def _apply_lies(name):
+    return int(APPLY_STARTS[APPLY_NAMES.index(name)]) % 1024
+
+
+def test_the_apply_tree_lies_as_the_cell_does():
+    assert [_apply_lies(n) for n in ("wide", "off_tile", "tall", "mate",
+                                     "gain.1", "tall.on")] == [
+        0, 512, 512, 512, 512, 0]
+    assert APPLY_TOTAL % 1024 == 128
+    plan = muon.muon_plan(APPLY_SHAPES, APPLY_ADAMW)
+    assert plan.pulls and len(plan.apply_keys) == len(APPLY_TREE)
+    assert [(c.m, c.n, c.tall) for c in plan.chunks] == [
+        (32, 256, (False, False)), (128, 256, (True, False, True))]
+
+
+@pytest.mark.parametrize("group", ["(32, 256)", "(128, 256)"])
+def test_row_apply_writes_store_and_pulled_as_todays_put_bit_for_bit(
+        group, small_blocks):
+    """``row_apply`` against ``put(store, k, key_values(store, k) * keep -
+    scale * o_k)``, the parent's chain, on one chunk: the store bit for
+    bit, every value outside the chunk's keys as it was, and the pulled
+    vector the store's values where a key lies and unset elsewhere."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    plan = muon.muon_plan(APPLY_SHAPES, APPLY_ADAMW)
+    chunk = next(c for c in plan.chunks if str((c.m, c.n)) == group)
+    rows, cols = chunk.m, chunk.n
+    assert rows // muon._block_rows(rows, cols, 16) >= 2        # wide
+    assert cols // muon._block_rows(cols, rows, 128) >= 2       # tall
+    rng = np.random.default_rng(len(group))
+    store = _shard(_init(rng, APPLY_TOTAL))
+    o = jnp.asarray(rng.normal(size=(len(chunk.keys), rows, cols)),
+                    jnp.bfloat16)
+    keep, scale = 0.9999, float(1e-3 * 0.2 * np.sqrt(cols))
+
+    def new_values(p, o, scale):        # ``muon_update``'s
+        return p * keep - scale * o
+
+    def todays(store, o):
+        for i, (k, tall) in enumerate(zip(chunk.keys, chunk.tall)):
+            o_k = (o[i].T if tall else o[i]).reshape(-1).astype(jnp.float32)
+            lo, hi = int(APPLY_STARTS[k]), int(APPLY_STARTS[k + 1])
+            store = lax.dynamic_update_slice(
+                store,
+                new_values(lax.slice(store, (lo,), (hi,)), o_k, scale),
+                (lo,))
+        return store
+
+    def kernels(store, o):
+        pulled = None
+        for tall in sorted(set(chunk.tall)):
+            store, pulled = muon.row_apply(
+                new_values, scale, o, store, pulled, chunk, APPLY_STARTS,
+                tall,
+                [i for i, t in enumerate(chunk.tall) if t == tall],
+                pulled_len=APPLY_TOTAL, interpret=True)
+        return store, pulled
+
+    want = np.asarray(jax.jit(todays)(store, o))
+    got, pulled = (np.asarray(x) for x in jax.jit(kernels)(store, o))
+    np.testing.assert_array_equal(got, want)
+    assert pulled.shape == (APPLY_TOTAL,)
+    written = np.zeros(APPLY_TOTAL, bool)
+    for k in chunk.keys:
+        written[APPLY_STARTS[k]:APPLY_STARTS[k + 1]] = True
+    np.testing.assert_array_equal(pulled[written], want[:APPLY_TOTAL][written])
+    np.testing.assert_array_equal(got[:APPLY_TOTAL][~written],
+                                  store[:APPLY_TOTAL][~written])
+    np.testing.assert_array_equal(got[APPLY_TOTAL:], 3.0)
+    assert (got[:APPLY_TOTAL][written] != store[:APPLY_TOTAL][written]).all()
+
+
+@pytest.mark.parametrize("name", ["emb.w", "gain.0", "gain.1", "norm"])
+def test_row_adamw_is_adamw_of_the_key_alone_bit_for_bit(name, small_blocks):
+    """``row_adamw`` against the same expression jitted on the key's own
+    slices: p, m and v in place at their offsets, the pulled values the
+    new p, every other value of all four as it was."""
+    import jax.numpy as jnp
+
+    b1, b2, eps, keep = 0.9, 0.95, 1e-8, 0.9999
+
+    def adamw(p, m, v, g, alpha):       # ``muon_update``'s
+        m = b1 * m + (1.0 - b1) * g
+        v = b2 * v + (1.0 - b2) * g * g
+        return p * keep - alpha * m / (jnp.sqrt(v) + eps), m, v
+
+    plan = muon.muon_plan(APPLY_SHAPES, APPLY_ADAMW)
+    k = APPLY_NAMES.index(name)
+    j = list(plan.adamw_keys).index(k)
+    start, n = int(APPLY_STARTS[k]), int(APPLY_LENS[k])
+    lo = int(plan.adamw_starts[j])
+    assert (name, n // muon._vector_tile(n)) in [
+        ("emb.w", 2), ("gain.0", 1), ("gain.1", 1), ("norm", 1)]
+    assert (lo % 1024 == 512) == (name == "gain.1")
+    rng = np.random.default_rng(len(name))
+    store = _shard(_init(rng, APPLY_TOTAL))
+    m, v = (np.abs(rng.normal(size=plan.adamw_len)).astype(np.float32)
+            for _ in range(2))
+    g = rng.normal(size=n).astype(np.float32)
+    alpha = jnp.float32(1.7e-3)
+    p1, m1, v1, pulled = (np.asarray(x) for x in jax.jit(
+        lambda *a: muon.row_adamw(adamw, *a, None, start, lo,
+                                  pulled_len=APPLY_TOTAL, interpret=True)
+    )(alpha, g, m, v, store))
+    want = jax.jit(adamw)(store[start:start + n], m[lo:lo + n],
+                          v[lo:lo + n], g, alpha)
+    for got, was, at, new in ((p1, store, start, want[0]),
+                              (m1, m, lo, want[1]), (v1, v, lo, want[2])):
+        np.testing.assert_array_equal(got[at:at + n], np.asarray(new))
+        np.testing.assert_array_equal(got[:at], was[:at])
+        np.testing.assert_array_equal(got[at + n:], was[at + n:])
+    np.testing.assert_array_equal(pulled[start:start + n], p1[start:start + n])
+
+
+@pytest.mark.parametrize("case, start, shape, elementwise, lies, takes", [
+    ("a_wide_key_off_a_tile", 512, (1408, 2048), False, (), True),
+    ("a_tall_key", 0, (2048, 1408), False, (), True),
+    ("start_off_a_lane", 64, (32, 256), False, (), False),
+    ("rows_no_block_divides", 0, (8, 256), False, (), False),
+    ("a_tall_side_of_no_whole_lanes", 0, (256, 96), False, (), False),
+    ("an_adamw_key", 512, (1, 2048), True, (512, 4096), True),
+    ("m_and_v_off_a_lane", 512, (1, 2048), True, (77, 4096), False),
+    ("m_and_v_of_no_whole_lanes", 512, (1, 2048), True, (512, 4173), False),
+])
+def test_the_predicate_of_a_key_a_kernel_writes_back(case, start, shape,
+                                                     elementwise, lies,
+                                                     takes):
+    assert muon.takes_apply(start, *shape, elementwise, *lies) == takes, case
+
+
+def test_the_plan_says_which_keys_a_kernel_writes_back():
+    """Per key, whatever its chunk: a matrix on a lane border is written
+    by the kernel though a mate off one keeps the chunk's gradients on
+    XLA's cut; AdamW keys behind one of 77 values have m and v off a
+    lane."""
+    plan = muon.muon_plan(ROW_SHAPES, ROW_ADAMW)
+    assert [ROW_NAMES[k] for k in plan.apply_keys] == [
+        "wide", "off_tile", "tall", "mate.0", "mate.1"]
+    assert not plan.pulls
+    assert plan.adamw_len % muon.LANES == 77
+    assert len(muon.muon_plan(SHAPES, ADAMW).apply_keys) == 1   # "wide.0"
+    cfg = _config()
+    tensors = muon_flops.expand_shapes(cfg["tensors"])
+    full = muon.muon_plan(
+        [s for _, s in tensors],
+        [muon_flops.is_adamw(n, cfg["adamw_keys"]) for n, _ in tensors])
+    assert len(full.apply_keys) == 153 and full.pulls
+
+
+@pytest.mark.parametrize("odd", ["a_matrix_off_a_lane", "an_adamw_key_of_77"])
+def test_a_bucket_with_a_key_no_kernel_writes_keeps_the_cut(odd,
+                                                            small_blocks):
+    """One key the kernels cannot take, behind the others: they still
+    write theirs, the pulled tree is the program's cut of the store, and
+    it is PR 44's program's bit for bit and the reference's."""
+    extra = {"a_matrix_off_a_lane": [("odd", (6, 10), False)],
+             "an_adamw_key_of_77": [("odd", (1, 77), True)]}[odd]
+    tree = APPLY_TREE + extra
+    shapes = np.array([s for _, s, _ in tree])
+    adamw = np.array([a for _, _, a in tree])
+    lens = shapes[:, 0] * shapes[:, 1]
+    starts = np.concatenate([[0], np.cumsum(lens)])
+    keys = np.arange(len(tree), dtype=np.uint64)
+    rng = np.random.default_rng(21)
+    init = _init(rng, int(lens.sum()))
+    eng = _engine()
+    bucket = eng.register_dense(
+        "t", keys, lens=lens, shapes=shapes, init=init,
+        flags=np.where(adamw, KEY_ELEMENTWISE, 0))
+    plan = eng._muon_plan(bucket)
+    taken = {"a_matrix_off_a_lane": 9, "an_adamw_key_of_77": 5}[odd]
+    assert len(plan.apply_keys) == taken and not plan.pulls
+    assert not eng._kernel_pulls("push_pull_st", HANDLE, bucket)
+    ref = _reference(init, shapes, adamw, starts)
+    g = rng.normal(size=(1, int(lens.sum()))).astype(np.float32)
+    pulled = np.asarray(eng.push_pull("t", g))
+    before = _step(ref, _split(g, starts))
+    _hold(pulled, ref, before, adamw, starts, where=odd)
+    assert eng.kernel_pulls == 0 and eng.muon_apply_keys == taken
+    state = (*(np.zeros(s, np.float32) for s in muon.state_shapes(plan)),
+             np.zeros(1, np.float32))
+    want, _ = jax.jit(lambda *a: muon.muon_update(
+        *a, starts, shapes, _without_the_apply(plan), interpret=True,
+        **HYPER))(np.asarray(_shard(init)), state, g)
+    matrices = np.concatenate([np.arange(starts[k], starts[k + 1])
+                               for k in plan.muon_keys])
+    np.testing.assert_array_equal(pulled[matrices],
+                                  np.asarray(want)[matrices])
+    np.testing.assert_allclose(pulled, np.asarray(want)[:len(pulled)],
+                               rtol=2e-7, atol=1e-9)   # AdamW: one rounding
+
+
+@pytest.mark.parametrize("origin", ["host", "device"])
+def test_the_kernels_pulled_tree_equals_the_reference(cluster, origin,
+                                                      small_blocks):
+    """Three steps of the bucket whose every key a kernel writes back:
+    the pulled tree is the kernels' own vector (``engine.pull.from_kernel``
+    rises) and the store's first ``total`` values bit for bit."""
+    kv = _worker(cluster)
+    eng = kv.engine
+    rng = np.random.default_rng(17)
+    init = _init(rng, APPLY_TOTAL)
+    kv.register_dense("apply", APPLY_KEYS, lens=APPLY_LENS,
+                      flags=APPLY_FLAGS, shapes=APPLY_SHAPES, init=init)
+    ref = _reference(init, APPLY_SHAPES, APPLY_ADAMW, APPLY_STARTS)
+    for step in range(3):
+        g = rng.normal(size=(1, APPLY_TOTAL)).astype(np.float32)
+        sent = (jax.device_put(g, NamedSharding(eng.mesh, P(eng.axis, None)))
+                if origin == "device" else g)
+        ts = kv.push_pull(APPLY_KEYS, sent, None)
+        pulled = np.asarray(kv.get_pulled(ts))
+        kv.wait(ts)
+        before = _step(ref, _split(g, APPLY_STARTS))
+        assert pulled.shape == (APPLY_TOTAL,) and np.isfinite(pulled).all()
+        _hold(pulled, ref, before, APPLY_ADAMW, APPLY_STARTS, where=step)
+        np.testing.assert_array_equal(
+            pulled, np.asarray(eng.pull("apply"))[:APPLY_TOTAL])
+    assert eng.kernel_pulls == 3
+    kind, (mom, m, v, slot) = eng.opt_state("apply")
+    np.testing.assert_array_equal(np.asarray(slot), 3.0)
+    np.testing.assert_allclose(
+        np.asarray(m), np.concatenate(
+            [ref.m[k] for k in range(len(APPLY_TREE)) if APPLY_ADAMW[k]]),
+        atol=1e-7)
+
+
+@pytest.mark.parametrize("way", ["push_pull", "push_then_pull", "pull_alone"])
+def test_every_way_to_the_tree_gives_one_tree(cluster, way, small_blocks):
+    """``push_pull`` hands back the kernels' vector, ``push`` makes none
+    and ``pull`` reads the store: one tree, bit for bit, and PR 44's
+    program's (no key's new values a kernel's) where the matrices lie."""
+    kv = _worker(cluster)
+    eng = kv.engine
+    rng = np.random.default_rng(23)
+    init = _init(rng, APPLY_TOTAL)
+    g = rng.normal(size=(1, APPLY_TOTAL)).astype(np.float32)
+    kv.register_dense("apply", APPLY_KEYS, lens=APPLY_LENS,
+                      flags=APPLY_FLAGS, shapes=APPLY_SHAPES, init=init)
+    out = np.zeros(APPLY_TOTAL, np.float32)
+    if way == "push_pull":
+        ts = kv.push_pull(APPLY_KEYS, g, None)
+        out = np.asarray(kv.get_pulled(ts))
+        kv.wait(ts)
+    elif way == "push_then_pull":
+        kv.wait(kv.push(APPLY_KEYS, g))
+        kv.wait(kv.pull(APPLY_KEYS, out))
+    else:
+        kv.wait(kv.push_pull(APPLY_KEYS, g, None))
+        kv.wait(kv.pull(APPLY_KEYS, out))
+    assert eng.kernel_pulls == (way != "push_then_pull")
+    plan = muon.muon_plan(APPLY_SHAPES, APPLY_ADAMW)
+    state = (*(np.zeros(s, np.float32) for s in muon.state_shapes(plan)),
+             np.zeros(1, np.float32))
+    want, _ = jax.jit(lambda *a: muon.muon_update(
+        *a, APPLY_STARTS, APPLY_SHAPES, _without_the_apply(plan),
+        interpret=True, **HYPER))(_shard(init), state, g)
+    want = np.asarray(want)[:APPLY_TOTAL]
+    matrices = np.concatenate([np.arange(APPLY_STARTS[k], APPLY_STARTS[k + 1])
+                               for k in plan.muon_keys])
+    np.testing.assert_array_equal(out[matrices], want[matrices])
+    np.testing.assert_allclose(out, want, rtol=2e-7, atol=1e-9)
+    ref = _reference(init, APPLY_SHAPES, APPLY_ADAMW, APPLY_STARTS)
+    before = _step(ref, _split(g, APPLY_STARTS))
+    _hold(out, ref, before, APPLY_ADAMW, APPLY_STARTS, where=way)
+
+
+def test_the_gauge_counts_the_keys_a_kernel_writes_back(cluster):
+    kv = _worker(cluster)
+    gauges = lambda: kv.po.metrics.snapshot()["gauges"]
+    assert gauges()["engine.update.muon.apply_keys"] == 0
+    kv.register_dense("apply", APPLY_KEYS, lens=APPLY_LENS,
+                      flags=APPLY_FLAGS, shapes=APPLY_SHAPES)
+    kv.wait(kv.push_pull(APPLY_KEYS, np.ones((1, APPLY_TOTAL), np.float32),
+                         None))
+    # Every key of the bucket: the pulled result is the kernels'.
+    assert gauges()["engine.update.muon.apply_keys"] == len(APPLY_KEYS) \
+        == gauges()["engine.update.muon.row_keys"]
+    assert gauges()["engine.pull.from_kernel"] == 1
+    kv.register_dense("rows", ROW_KEYS, lens=ROW_LENS, shapes=ROW_SHAPES,
+                      flags=np.where(ROW_ADAMW, KEY_ELEMENTWISE, 0))
+    kv.wait(kv.push_pull(ROW_KEYS, np.ones((1, ROW_TOTAL), np.float32),
+                         None))
+    # Five matrices on lane borders; the AdamW keys' m and v lie off one.
+    assert gauges()["engine.update.muon.apply_keys"] == 5
+    assert gauges()["engine.update.muon.row_keys"] == len(ROW_TAKEN)
+    assert gauges()["engine.pull.from_kernel"] == 1
 
 
 # -- the plan, the published count and the cut ----------------------------------

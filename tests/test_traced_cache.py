@@ -60,7 +60,7 @@ def test_a_trace_is_made_once_and_found_by_the_next_process(kept):
     assert runs == [(8,)] and len(_entries(directory)) == 1
 
 
-@pytest.mark.parametrize("change", ["shape", "dtype", "source"])
+@pytest.mark.parametrize("change", ["shape", "dtype", "source", "static"])
 def test_an_entry_is_found_by_what_the_trace_depends_on(kept, change):
     directory, source = kept
     runs = []
@@ -69,13 +69,17 @@ def test_an_entry_is_found_by_what_the_trace_depends_on(kept, change):
         runs.append((x.shape, str(x.dtype)))
         return x + 1
 
-    def call(x):
+    def call(x, **kw):
         jax.jit(lambda a: compile_cache.call_traced(
-            fn, source, "cpu", a)).lower(x)
+            fn, source, "cpu", a, **kw)).lower(x)
 
     call(np.zeros(8, np.float32))
     _forget()
-    if change == "shape":
+    if change == "static":
+        # What a closure is made from and its arguments do not show.
+        call(np.zeros(8, np.float32), static=("lr", 1e-3))
+        call(np.zeros(8, np.float32), static=("lr", 1e-3))
+    elif change == "shape":
         call(np.zeros(16, np.float32))
     elif change == "dtype":
         call(np.zeros(8, np.int32))
@@ -86,7 +90,7 @@ def test_an_entry_is_found_by_what_the_trace_depends_on(kept, change):
     assert len(_entries(directory)) == 2
     # (jax itself remembers this process's trace of the one ``fn`` object;
     # an edited source comes with a new process.)
-    assert len(runs) == (1 if change == "source" else 2)
+    assert len(runs) == (1 if change in ("source", "static") else 2)
 
 
 def test_a_cut_entry_is_made_again_and_no_directory_means_in_place(
