@@ -20,10 +20,7 @@ reference:
   lane-packed two to one: on the chip all are summed by distinct row with
   the ``ops/segment_sum.py`` kernel and written by ``ops/row_add.py``'s);
 - ``message_path``: an unregistered key, which the collective path cannot
-  take, answered by the ``KVServer`` handler;
-- ``ring`` (two or more devices): the ResNet-50 buckets once more through
-  the fused Pallas ring kernel, per bucket and grouped, against XLA's
-  collectives.
+  take, answered by the ``KVServer`` handler.
 
 It has no CPU mode: without a TPU it exits non-zero before any work.  Every
 phase has a deadline; a phase that fails or hangs ends the run non-zero
@@ -49,7 +46,7 @@ import threading
 import time
 import traceback
 from dataclasses import dataclass, field
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import Callable, List, Sequence, Tuple
 
 import numpy as np
 
@@ -57,7 +54,6 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 LR, MOMENTUM = 0.01, 0.9
 SERVER_HANDLE = f"sgd_momentum:{LR},{MOMENTUM}"
-RING_HANDLE = f"sgd:{LR}"  # the ring kernel serves stateless handles
 LAMB = dict(lr=0.01, b1=0.9, b2=0.999, eps=1e-6, wd=0.01)
 LAMB_HANDLE = "lamb:" + ",".join(str(v) for v in LAMB.values())
 SEED = 20260926
@@ -77,10 +73,6 @@ class Sizes:
         default_factory=_resnet50_buckets
     )
     steps: int = 3
-    # How many of the dense buckets also go through the ring kernel (None:
-    # all).  The interpreter takes seconds per ring program, so the CPU
-    # test leaves the phase to tests/test_ring_collective.py.
-    ring_buckets: Optional[int] = None
     readme_keys: int = 40
     readme_val_len: int = 256_000
     emb_rows: int = 1 << 20
@@ -204,7 +196,7 @@ class _Smoke:
         self.server = None
 
     def phases(self) -> List[Tuple[str, float, Callable[[], None]]]:
-        out = [
+        return [
             ("boot", 60, self.boot),
             ("resnet50", 400, self.resnet50),
             ("readme", 150, self.readme),
@@ -213,11 +205,8 @@ class _Smoke:
             ("muon", 150, self.muon),
             ("sparse", 200, self.sparse),
             ("message_path", 30, self.message_path),
+            ("shutdown", 30, self.shutdown),
         ]
-        if self.n_dev >= 2 and self.sizes.ring_buckets != 0:
-            out.append(("ring", 150, self.ring))
-        out.append(("shutdown", 30, self.shutdown))
-        return out
 
     # -- boot / shutdown -----------------------------------------------------
 
@@ -261,7 +250,6 @@ class _Smoke:
         got = [d.id for d in eng.mesh.devices.flat]
         want = [d.id for d in self.mesh.devices.flat]
         check(got == want, f"engine mesh {got} is not the given mesh {want}")
-        check(eng.impl == "xla", f"engine impl {eng.impl!r}")
         print(f"  engine: {self.n_dev} device(s), worker sum W = "
               f"{eng.num_workers}, kernels "
               f"{'interpreted' if eng._interpret else 'compiled (Mosaic)'}")
@@ -559,7 +547,7 @@ class _Smoke:
                     "mixed_tree", jnp.zeros((W, total), jnp.float32),
                     LAMB_HANDLE)),
                 ("a stateless handle", lambda: eng.push_pull(
-                    "mixed_tree", g, RING_HANDLE))):
+                    "mixed_tree", g, f"sgd:{LR}"))):
             try:
                 call()
             except log.CheckError as exc:
@@ -927,53 +915,6 @@ class _Smoke:
         kv.wait(kv.pull(keys, out))
         np.testing.assert_array_equal(out, vals)
         print("  unregistered key 7777 answered by the KVServer handler")
-
-    # -- ring kernel (two or more devices) ----------------------------------
-
-    def ring(self) -> None:
-        """The ResNet-50 buckets through the fused ring kernel, per bucket
-        and grouped, against XLA's reduce-scatter / update / all-gather.
-        The ring kernel serves stateless handles, so this phase owns two
-        engines on the same mesh under plain ``sgd``."""
-        from pslite_tpu.parallel.engine import CollectiveEngine
-
-        xla = CollectiveEngine(mesh=self.mesh, server_handle=RING_HANDLE,
-                               impl="xla")
-        ring = CollectiveEngine(mesh=self.mesh, server_handle=RING_HANDLE,
-                                impl="pallas")
-        W = ring.num_workers
-        buckets = list(self.sizes.dense_buckets)[: self.sizes.ring_buckets]
-        names = [name for name, _ in buckets]
-        rng = np.random.default_rng(SEED + 2)
-        grads = []
-        one = np.arange(1, dtype=np.uint64)
-        check(ring._effective_impl(np.float32, RING_HANDLE) == "pallas",
-              "the ring kernel does not serve this config")
-        for name, n in buckets:
-            xla.register_dense(name, one, n)
-            ring.register_dense(name, one, n)
-            grads.append(rng.standard_normal((W, n), dtype=np.float32))
-
-        def agree(got, want, what):
-            for name, a, b in zip(names, got, want):
-                a, b = np.asarray(a), np.asarray(b)
-                check(np.isfinite(a).all(), f"{what} {name}: not finite")
-                np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5,
-                                           err_msg=f"{what} {name}")
-
-        t0 = time.perf_counter()
-        per_ring = [ring.push_pull(n, g) for n, g in zip(names, grads)]
-        per_xla = [xla.push_pull(n, g) for n, g in zip(names, grads)]
-        agree(per_ring, per_xla, "per bucket")
-        print(f"  {len(names)} buckets, one ring program each, agree with "
-              f"XLA (set-up, compiles: {time.perf_counter() - t0:.2f} s)",
-              flush=True)
-        t0 = time.perf_counter()
-        agree(ring.push_pull_group(names, grads),
-              xla.push_pull_group(names, grads), "grouped")
-        print(f"  {len(names)} ring kernels in one grouped program agree "
-              f"with XLA (set-up, compiles: {time.perf_counter() - t0:.2f} "
-              f"s)")
 
 
 def run_smoke(mesh, sizes: Sizes,

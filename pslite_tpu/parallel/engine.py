@@ -161,8 +161,7 @@ class _BoundOp:
     bucket: DenseBucket
     lock: threading.Lock  # the bucket's write lock
     prog: Callable
-    # One of _prep_grads / _prep_grads_whole / _prep_grads_flat /
-    # _prep_grads_ring.
+    # One of _prep_grads / _prep_grads_whole / _prep_grads_flat.
     prep: Callable
     sharding: object  # what ``prep`` delivers (and passes through as is)
     state_kind: Optional[str]  # the optimizer state's kind; None: stateless
@@ -170,26 +169,6 @@ class _BoundOp:
     # The pulled array carries padding to slice off (a program shared by
     # every bucket of a length; a bucket's own cuts inside: _bind).
     cut: bool
-
-
-def _pad_ring_chunks(g, s, kchunk: int, chunk0: int):
-    """Pad per-ring-position grads [n, chunk0] and store [chunk0] (or a
-    pre-padded store passed as None) up to the kernel tile chunk."""
-    import jax.numpy as jnp
-
-    if kchunk == chunk0:
-        return g, s
-    g = jnp.pad(g, ((0, 0), (0, kchunk - chunk0)))
-    if s is not None:
-        s = jnp.pad(s, (0, kchunk - chunk0))
-    return g, s
-
-
-def _slice_ring_pulled(pulled, n: int, kchunk: int, chunk0: int):
-    """Drop the kernel tile padding from a pulled [n*kchunk] vector."""
-    if kchunk == chunk0:
-        return pulled
-    return pulled.reshape(n, kchunk)[:, :chunk0].reshape(-1)
 
 
 def _aggregate(grads_l, axis, worker_axis=None):
@@ -328,20 +307,8 @@ class CollectiveEngine:
         axis_name: str = "kv",
         server_handle: ServerHandle = "sum",
         worker_axis: Optional[str] = None,
-        impl: Optional[str] = None,
-        wire_compress: Optional[str] = None,
     ):
-        """``impl``: data-plane implementation for stateless ``push_pull``
-        — ``"xla"`` (default; psum_scatter → handle → all_gather as three
-        XLA ops) or ``"pallas"`` (the fused ring kernel of
-        ``ops/ring_collective.py``: one kernel per device, the update
-        applied in VMEM between the reduce-scatter and all-gather ring
-        phases).  Defaults to env ``PS_ICI_IMPL``.  A config the kernel
-        cannot serve (see :meth:`_ring_unserved`) runs the XLA
-        collectives and says so once; a bucket whose per-device chunk
-        exceeds the kernel's VMEM budget is an error.
-
-        ``worker_axis``: optional second mesh axis carrying the worker
+        """``worker_axis``: optional second mesh axis carrying the worker
         fan-in, decoupling worker count from server-shard count (the
         reference's W workers vs S servers asymmetry, on the collective
         path).  With a 2-D mesh ``(dp, kv)``: gradients are summed over
@@ -359,7 +326,7 @@ class CollectiveEngine:
             # MULTI-AXIS kv plane (>=3-D torus with worker_axis): the
             # store shards over the PRODUCT of these axes
             # (P(("kv1","kv2"))) and the pulled broadcast gathers over
-            # both — with the fused dp sub-rings, one push_pull then
+            # both: with the psum over the worker axis, one push_pull
             # drives all three torus axes' links (the reference's 32
             # ports/devices per node, message.h:66-134, ucx_van.h:938-
             # 1006; v5p pods are 3-D tori).
@@ -396,7 +363,7 @@ class CollectiveEngine:
             iter(self.mesh.devices.flat)
         ).platform
         # THE interpret rule for every Pallas kernel this engine builds
-        # (ring collective and fused optimizer handles): a TPU mesh gets
+        # (the fused optimizer handles): a TPU mesh gets
         # compiled Mosaic, always — including an AOT topology mesh built
         # from a CPU-default process — and only a mesh that is not TPU
         # runs the Pallas interpreter.  The process default backend is
@@ -407,28 +374,11 @@ class CollectiveEngine:
             local_shard_count(self.mesh) if self._multiprocess
             else self.num_shards
         )
-        self.impl = impl or os.environ.get("PS_ICI_IMPL", "xla")
-        log.check(self.impl in ("xla", "pallas"),
-                  f"unknown engine impl {self.impl!r}")
-        # Reasons already given for running XLA under impl="pallas".
-        self._impl_said: set = set()
         # Per-step payload threshold for the flat replay slab layout
         # (see _flat_replay); tunable for tests / unusual chips.
         self.replay_flat_min_bytes = int(
             os.environ.get("PS_REPLAY_FLAT_MIN_BYTES", 1 << 20)
         )
-        # Wire compression on the ring data plane (pallas impl only):
-        # "int8" quantizes every hop payload with an embedded absmax
-        # scale — 4x fewer ICI bytes, lossy (the reference's int8 wire
-        # compression applied to the collective itself).  f32 buckets
-        # only; other configs ignore it.
-        self.wire_compress = (
-            wire_compress
-            if wire_compress is not None
-            else os.environ.get("PS_ICI_COMPRESS", "")
-        ) or None
-        log.check(self.wire_compress in (None, "int8"),
-                  f"unknown wire_compress {self.wire_compress!r}")
         self._server_handle = server_handle
         self._buckets: Dict[str, DenseBucket] = {}
         self._stores: Dict[str, jax.Array] = {}
@@ -1092,239 +1042,6 @@ class CollectiveEngine:
             raise ValueError(op)
         return self._keep(key, jitted)
 
-    def _ring_unserved(self, dtype, resolved_handle) -> Optional[str]:
-        """Why the fused ring kernel cannot serve this config, or None
-        when it can.
-
-        2-D (worker_axis) meshes run the MULTI-AXIS plane: the fused
-        ring executes the worker reduction + update + re-replication as
-        per-column sub-rings along the worker axis, and the pulled
-        broadcast rides XLA's all_gather on the kv-axis links — both
-        torus axes carry the one push_pull."""
-        if self._is_stateful(resolved_handle):
-            return ("a stateful handle runs its own fused optimizer "
-                    "kernel between XLA's reduce-scatter and all-gather")
-        if self.worker_axis is None and isinstance(self.axis, tuple):
-            return ("a composite kv axis has no single ring dimension "
-                    "(the multi-axis plane needs worker_axis sub-rings)")
-        ring_n = (
-            self.num_workers if self.worker_axis is not None
-            else self.num_shards
-        )
-        if ring_n < 2:
-            return f"a ring needs 2 or more devices and has {ring_n}"
-        if np.dtype(dtype).itemsize not in (2, 4):
-            return (f"dtype {np.dtype(dtype)} (the kernel tiles 2- and "
-                    f"4-byte dtypes)")
-        if callable(resolved_handle):
-            # The kernel applies the handle blockwise in VMEM with
-            # tile-padding lanes flowing through it, which is only
-            # guaranteed sound for the built-in elementwise handles.
-            return "a callable handle (built-in elementwise handles only)"
-        if self._multiprocess and self._mesh_platform != "tpu":
-            # Real multi-host TPU rings ride ICI fine, but the off-TPU
-            # interpreter cannot DMA to another process's devices.
-            return ("a multi-process mesh that is not TPU (the "
-                    "interpreter cannot DMA across processes)")
-        return None
-
-    def _effective_impl(self, dtype, resolved_handle) -> str:
-        """The configured impl resolved against what the fused ring
-        kernel serves.  Running XLA under ``impl="pallas"`` is said once
-        per reason, never swapped in silence."""
-        if self.impl != "pallas":
-            return "xla"
-        why = self._ring_unserved(dtype, resolved_handle)
-        if why is None:
-            return "pallas"
-        if why not in self._impl_said:
-            self._impl_said.add(why)
-            log.warning(f"impl='pallas': running the XLA collectives "
-                        f"instead of the ring kernel — {why}")
-        return "xla"
-
-    def _ring_program(self, padded_len: int, dtype, handle_key) -> Callable:
-        """Fused ring RS+update+AG push_pull (ops/ring_collective.py):
-        same signature and cache discipline as the XLA push_pull program.
-
-        The kernel needs the per-device chunk tiled to (sublane, 128);
-        buckets whose chunk is not already tile-aligned are padded inside
-        the program (XLA fuses the pad) and sliced on the way out, so the
-        engine-visible shapes are unchanged."""
-        return self._ring_program_op("push_pull", padded_len, dtype,
-                                     handle_key)
-
-    def _ring_compress(self, dtype) -> bool:
-        return (
-            self.wire_compress == "int8"
-            and np.dtype(dtype) == np.float32
-        )
-
-    def _ring_program_op(self, op: str, padded_len: int, dtype,
-                         handle_key) -> Callable:
-        compress = self._ring_compress(dtype)
-        key = (f"ring_{op}", padded_len, str(dtype), handle_key, compress,
-               self.worker_axis)
-        with self._mu:
-            prog = self._programs.get(key)
-        if prog is not None:
-            return prog
-        if self.worker_axis is not None:
-            return self._ring_program_op_2d(op, key, padded_len, dtype,
-                                            handle_key, compress)
-
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-
-        from ..ops.ring_collective import (
-            derive_collective_id,
-            ring_chunk_len,
-            ring_push,
-            ring_push_pull,
-        )
-
-        handle = self._resolved_handle_fn(handle_key)
-        axis = self.axis
-        n = self.num_shards
-        chunk0 = padded_len // n
-        kchunk = ring_chunk_len(padded_len, n, dtype, compress=compress)
-        cid = derive_collective_id(*key)
-        interp = self._interpret
-
-        def _padded(store_l, grads_l):
-            # grads_l: my FLAT row [padded] (see _prep_grads_ring — the
-            # flat parameter keeps 2-byte dtypes packed; a (1, padded)
-            # block would sublane-pad to 2x the bytes).
-            return _pad_ring_chunks(
-                grads_l.reshape(n, chunk0), store_l, kchunk, chunk0
-            )
-
-        def body_pp(store_l, grads_l):
-            g, s = _padded(store_l, grads_l)
-            new, pulled = ring_push_pull(
-                g, s, handle, axis, n, collective_id=cid,
-                compress=compress, interpret=interp,
-            )
-            if kchunk != chunk0:
-                new = new[:chunk0]
-            pulled = _slice_ring_pulled(pulled, n, kchunk, chunk0)
-            return new, pulled
-
-        def body_push(store_l, grads_l):
-            g, s = _padded(store_l, grads_l)
-            new = ring_push(g, s, handle, axis, n, collective_id=cid,
-                            compress=compress, interpret=interp)
-            if kchunk != chunk0:
-                new = new[:chunk0]
-            # Completion token, same contract as the XLA push program.
-            return new, new[:1]
-
-        if op == "push_pull":
-            body, out_specs = body_pp, (P(axis), P(None))
-        else:
-            body, out_specs = body_push, (P(axis), P(axis))
-        fn = jax.shard_map(
-            body,
-            mesh=self.mesh,
-            in_specs=(P(axis), P(axis)),
-            out_specs=out_specs,
-            check_vma=False,
-        )
-        jitted = jax.jit(fn, donate_argnums=(0,))
-        return self._keep(key, jitted)
-
-    def _ring_program_op_2d(self, op: str, key, padded_len: int, dtype,
-                            handle_key, compress: bool) -> Callable:
-        """Multi-axis (2-D torus) ring data plane — VERDICT r02 #1.
-
-        The worker reduction + server update + dp re-replication run as
-        the fused Pallas ring along the WORKER axis: B independent
-        size-A sub-rings (one per kv column) inside one kernel launch,
-        each doing RS + update-in-VMEM + AG exactly like the 1-D plane.
-        The pulled broadcast then rides XLA's native all_gather over the
-        kv axis — a bare gather with nothing to fuse, which XLA already
-        schedules bidirectionally.  Together the two phases drive both
-        torus axes' links for one push_pull, the TPU analog of the
-        reference spreading one transfer across per-device NICs
-        (multi_van.h:173-197, ucx_van.h:938-1006)."""
-        import jax
-        from jax import lax
-        from jax.sharding import PartitionSpec as P
-
-        from ..ops.ring_collective import derive_collective_id
-
-        handle = self._resolved_handle_fn(handle_key)
-        axis = self.axis
-        cid = derive_collective_id(*key)
-        _updated_shard = self._ring_2d_shard_fn(
-            handle, padded_len, dtype, compress, cid
-        )
-
-        def body_pp(store_l, grads_l):
-            new_store = _updated_shard(store_l, grads_l)
-            pulled = _gather(new_store, axis)
-            return new_store, pulled
-
-        def body_push(store_l, grads_l):
-            new_store = _updated_shard(store_l, grads_l)
-            return new_store, new_store[:1]
-
-        if op == "push_pull":
-            body, out_specs = body_pp, (P(axis), P(None))
-        else:
-            body, out_specs = body_push, (P(axis), P(axis))
-        fn = jax.shard_map(
-            body,
-            mesh=self.mesh,
-            in_specs=(P(axis), P(self.worker_axis, axis)),
-            out_specs=out_specs,
-            check_vma=False,
-        )
-        jitted = jax.jit(fn, donate_argnums=(0,))
-        return self._keep(key, jitted)
-
-    def _ring_2d_shard_fn(self, handle, padded_len: int, dtype,
-                          compress: bool, cid: int):
-        """Shard-level body of the 2-D fused data plane: a function
-        ``(store_l, grads_l) -> updated kv shard`` running the dp-axis
-        sub-ring (RS + update-in-VMEM + AG) for use inside a shard_map
-        over the full (dp, kv) mesh.  Shared by the single-bucket and
-        grouped programs."""
-        import jax.numpy as jnp
-        from jax import lax
-
-        from ..ops.ring_collective import ring_chunk_len, ring_push_pull
-
-        waxis = self.worker_axis
-        A = self.num_workers
-        B = self.num_shards
-        interp = self._interpret
-        chunk_kv = padded_len // B  # my kv shard (replicated over dp)
-        ksub = ring_chunk_len(chunk_kv, A, dtype, compress=compress)
-        maxes = tuple(
-            (name, self.mesh.shape[name]) for name in self.mesh.axis_names
-        )
-
-        def _updated_shard(store_l, grads_l):
-            d = lax.axis_index(waxis)
-            g = grads_l[0]
-            s = store_l
-            if A * ksub != chunk_kv:
-                g = jnp.pad(g, (0, A * ksub - chunk_kv))
-                s = jnp.pad(s, (0, A * ksub - chunk_kv))
-            g = g.reshape(A, ksub)
-            s_sub = lax.dynamic_slice(s, (d * ksub,), (ksub,))
-            _, pulled_dp = ring_push_pull(
-                g, s_sub, handle, waxis, A, collective_id=cid,
-                compress=compress, mesh_axes=maxes, interpret=interp,
-            )
-            if A * ksub != chunk_kv:
-                pulled_dp = pulled_dp[:chunk_kv]
-            return pulled_dp
-
-        return _updated_shard
-
     def _stateful_program(self, op: str, key, handle_key: str,
                           bucket: Optional[DenseBucket] = None) -> Callable:
         """Program for the fused-kernel handles: the Pallas optimizer pass
@@ -1679,10 +1396,11 @@ class CollectiveEngine:
 
     def _grads_sharding(self, flat: bool, whole: bool = False):
         """What a prep delivers: ``[W, padded]`` rows over the worker
-        axis (``_prep_grads``), the FLAT forms' ``P(axis)``, or ``whole``
-        rows ``[W, total]`` (``_prep_grads_whole``: no row is cut over
-        the kv axis, which ``total`` need not divide by).  A bound op
-        hands its prep the one its record holds."""
+        axis (``_prep_grads``), the FLAT ``[padded]`` of one worker
+        (``_prep_grads_flat``), or ``whole`` rows ``[W, total]``
+        (``_prep_grads_whole``: no row is cut over the kv axis, which
+        ``total`` need not divide by).  A bound op hands its prep the one
+        its record holds."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         if flat:
@@ -1720,52 +1438,6 @@ class CollectiveEngine:
             # (padded == total on every zc-eligible config, so this is
             # only reachable for malformed lengths, which it rejects).
         arr = self._normalize_host_grads(grads, 1, bucket, np)
-        return jax.device_put(
-            np.ascontiguousarray(arr).reshape(-1), sharding
-        )
-
-    def _prep_grads_ring(self, bucket: DenseBucket, grads, sharding=None):
-        """``[W*padded]`` FLAT grads, sharded ``P(axis)``, for the
-        single-bucket 1-D fused ring programs.
-
-        Why flat: the ``[W, padded]`` form gives each device a
-        ``(1, padded)`` parameter block, and TPU tiled layouts pad the
-        sublane dim — ``T(2,128)`` for 2-byte dtypes stores (and reads)
-        TWICE the bytes for a bf16 grads operand (caught by
-        tools/aot_ring_compile.py's memory cross-check; f32's
-        ``T(1,128)`` happens to be packed).  The flat form is the same
-        bits per device (row-major row d == device d's flat slice) but
-        always lays out packed.  Host arrays flatten for free; a
-        ``[W, padded]`` device array pays one relayout per call (pass
-        flat device arrays on the hot path, as with _prep_grads_flat).
-        """
-        import jax
-
-        if sharding is None:
-            sharding = self._grads_sharding(True)
-        W = self.num_shards
-        flat_len = W * bucket.padded_len
-        if isinstance(grads, jax.Array):
-            if grads.ndim == 2 and int(grads.shape[1]) == bucket.padded_len:
-                log.check_eq(int(grads.shape[0]), W, "bad worker dim")
-                return jax.device_put(grads.reshape(-1), sharding)
-            if grads.ndim == 1 and int(grads.shape[0]) == flat_len:
-                if grads.sharding == sharding:
-                    return grads
-                return jax.device_put(grads, sharding)
-            # Unpadded / broadcast forms fall through to host staging.
-        if self._is_multiprocess():
-            arr = self._normalize_host_grads(
-                grads, self._local_shards(), bucket, np,
-                row_msg="bad local worker dim (rows = this process's "
-                        "devices on a multi-process mesh)",
-            )
-            return jax.make_array_from_process_local_data(
-                sharding,
-                np.ascontiguousarray(arr).reshape(-1),
-                (flat_len,),
-            )
-        arr = self._normalize_host_grads(grads, W, bucket, np)
         return jax.device_put(
             np.ascontiguousarray(arr).reshape(-1), sharding
         )
@@ -1883,41 +1555,32 @@ class CollectiveEngine:
             return resolved, resolved  # stateful handles key by full string
         return resolved, ("_default" if handle is None else handle)
 
-    def _zc_pull_eligible(self, dtype, resolved) -> bool:
-        """Whether in-place pull delivery can serve this config: the kv
-        axis has size 1 (the all-gather is the identity, so the updated
-        store IS the pulled value — and ``padded_len == total_len``), and
-        the data plane is the XLA path (the ring kernel needs >=2 ring
-        devices and defines its own output layout).  Mirrors the
-        reference's RegisterRecvBuffer: in-place delivery happens where
-        the transport allows it, transparently copied elsewhere."""
-        if self.num_shards != 1:
-            return False
-        return self._effective_impl(dtype, resolved) == "xla"
-
     def _bind(self, name: str, handle: Optional[ServerHandle],
               zero_copy: Optional[bool]) -> _BoundOp:
         """Stage ``select`` of the first ``push_pull(name, ., handle,
         zero_copy)`` (``zero_copy`` None: of the first ``push``), and of
         the first after a ``reshard`` or a new registration of ``name``
-        dropped the record: the handle resolved, zero-copy and ring
-        eligibility, the program and the prep that goes with it."""
+        dropped the record: the handle resolved, zero-copy eligibility,
+        the program and the prep that goes with it.
+
+        In-place pull delivery serves a bucket whose kv axis has size 1:
+        the all-gather is the identity, so the updated store IS the
+        pulled value.  It mirrors the reference's RegisterRecvBuffer:
+        in place where the transport allows it, copied elsewhere."""
         mesh, bucket = self.mesh, self._buckets[name]
         resolved, handle_key = self._resolve_handle(handle)
         push = zero_copy is None
         # (A bucket with lens is kept in whole tiles on one shard too: its
         # store is not its pulled value.)
         zc = (bool(zero_copy)
-              and self._zc_pull_eligible(bucket.dtype, resolved)
+              and self.num_shards == 1
               and bucket.padded_len == bucket.total_len
               and not bucket.mixed)  # the store is not the job's dtype
-        # Resolved for every record: it says once why "pallas" runs XLA.
-        impl = self._effective_impl(bucket.dtype, resolved)
         stateful = self._is_stateful(resolved)
         if not stateful:
             self._refuse_mixed(
                 bucket, f"the stateless handle {resolved!r} (on the "
-                f"programs shared by length, or the ring's)",
+                f"programs shared by length)",
                 "use a stateful handle (lamb, adam, adagrad, sgd_momentum)")
         # A stateful program of a bucket that keeps its keys' lengths is
         # the bucket's own: it takes the gradient and gives the pulled
@@ -1938,13 +1601,6 @@ class CollectiveEngine:
                 prog = self._counted(
                     prog, resolved.split(":", 1)[0], bucket,
                     self._kernel_pulls(op, resolved, bucket))
-        elif impl == "pallas":
-            if self.worker_axis is None:
-                prep = self._prep_grads_ring
-            prog = self._ring_program_op(
-                "push" if push else "push_pull",
-                bucket.padded_len, bucket.dtype, handle_key
-            )
         else:
             # Flat [padded] grads: zc says the kv axis has size 1, this
             # branch that the handle is stateless.
@@ -1957,7 +1613,7 @@ class CollectiveEngine:
             op="push" if push else "push_pull", bucket=bucket,
             lock=self._bucket_mu[name], prog=prog, prep=prep,
             sharding=self._grads_sharding(
-                prep not in (self._prep_grads, self._prep_grads_whole), own),
+                prep == self._prep_grads_flat, own),
             state_kind=resolved.split(":", 1)[0] if stateful else None,
             zc=zc, cut=not (push or zc or own
                             or bucket.padded_len == bucket.total_len),
@@ -2064,7 +1720,7 @@ class CollectiveEngine:
         array (async).  The benchmark hot path (SURVEY §3.2).
 
         ``zero_copy=True`` requests in-place pull delivery: where the
-        topology allows it (see :meth:`_zc_pull_eligible`) the returned
+        topology allows it (see :meth:`_bind`) the returned
         array ALIASES the bucket store — zero extra HBM traffic, but it
         is invalidated by the bucket's next mutating op (the next push
         donates the buffer; stale holders raise on use rather than read
@@ -2144,21 +1800,13 @@ class CollectiveEngine:
         for b in buckets:
             self._refuse_mixed(b, "a group of buckets (stateless handles, "
                                "one program for the group)")
-        # MUST mirror _group_program's use_ring resolution: the
-        # grouped 1-D ring program takes each bucket's grads FLAT
-        # (same sublane-pad rationale as _prep_grads_ring).
-        group_flat = self.worker_axis is None and all(
-            self._effective_impl(b.dtype, resolved) == "pallas"
-            for b in buckets
-        )
-        prep = self._prep_grads_ring if group_flat else self._prep_grads
         prog = self._group_program(
             tuple((b.padded_len, str(np.dtype(b.dtype)))
                   for b in buckets),
             handle_key,
         )
         t1 = stamp()  # select | prep
-        gs = [prep(b, g) for b, g in zip(buckets, grads_list)]
+        gs = [self._prep_grads(b, g) for b, g in zip(buckets, grads_list)]
         t2 = stamp()  # prep | launch
         # Lock every bucket in sorted order (deadlock-free against
         # other group/single ops) for the whole load-run-store.
@@ -2181,27 +1829,13 @@ class CollectiveEngine:
         return pulled
 
     def _group_program(self, shapes_key, handle_key) -> Callable:
-        # The ring gate is _effective_impl per bucket dtype — the same
-        # resolution the single-bucket path applies (incl. the
-        # multiprocess/off-TPU interpreter restriction, which cannot DMA
-        # across processes).
-        resolved = (
-            self._server_handle if handle_key == "_default" else handle_key
-        )
-        use_ring = all(
-            self._effective_impl(dt, resolved) == "pallas"
-            for _, dt in shapes_key
-        )
-        key = ("group_pp", shapes_key, handle_key, use_ring,
-               self.worker_axis)
+        key = ("group_pp", shapes_key, handle_key, self.worker_axis)
         with self._mu:
             prog = self._programs.get(key)
         if prog is not None:
             return prog
 
         import jax
-        import jax.numpy as jnp
-        from jax import lax
         from jax.sharding import PartitionSpec as P
 
         axis = self.axis
@@ -2209,64 +1843,15 @@ class CollectiveEngine:
         handle = self._resolved_handle_fn(handle_key)
         k = len(shapes_key)
         store_spec = P(axis)
-        # 1-D ring groups take each bucket's grads FLAT [W*padded]
-        # (packed layout for 2-byte dtypes — _prep_grads_ring); the XLA
-        # and 2-D paths keep the row form.
-        if waxis is not None:
-            grads_spec = P(waxis, axis)
-        elif use_ring:
-            grads_spec = P(axis)
-        else:
-            grads_spec = P(axis, None)
+        grads_spec = P(axis, None) if waxis is None else P(waxis, axis)
         repl_spec = P(None)
-        n = self.num_shards
-        interp = self._interpret
-
-        def _ring_one(i, padded_len, dtype, store_l, grads_l):
-            from ..ops.ring_collective import (
-                derive_collective_id,
-                ring_chunk_len,
-                ring_push_pull,
-            )
-
-            compress = self._ring_compress(dtype)
-            cid = derive_collective_id(*key, i)
-            if waxis is not None:
-                # 2-D: dp sub-ring for this bucket, kv gather for pull.
-                shard_fn = self._ring_2d_shard_fn(
-                    handle, padded_len, dtype, compress, cid
-                )
-                new = shard_fn(store_l, grads_l)
-                pulled = _gather(new, axis)
-                return new, pulled
-            chunk0 = padded_len // n
-            kchunk = ring_chunk_len(padded_len, n, dtype,
-                                    compress=compress)
-            # grads_l: my FLAT row [padded] (grads_spec P(axis)).
-            g, s = _pad_ring_chunks(
-                grads_l.reshape(n, chunk0), store_l, kchunk, chunk0
-            )
-            new, pulled = ring_push_pull(
-                g, s, handle, axis, n,
-                collective_id=cid,
-                compress=compress, interpret=interp,
-            )
-            if kchunk != chunk0:
-                new = new[:chunk0]
-            pulled = _slice_ring_pulled(pulled, n, kchunk, chunk0)
-            return new, pulled
 
         def _body(*args):
             stores, grads = args[:k], args[k:]
             new_stores, pulled = [], []
-            for i, (store_l, grads_l) in enumerate(zip(stores, grads)):
-                if use_ring:
-                    padded_len, dt = shapes_key[i]
-                    new, out = _ring_one(i, padded_len, dt, store_l,
-                                         grads_l)
-                else:
-                    new, out = _rs_update_ag(store_l, grads_l, handle,
-                                             axis, waxis)
+            for store_l, grads_l in zip(stores, grads):
+                new, out = _rs_update_ag(store_l, grads_l, handle, axis,
+                                         waxis)
                 new_stores.append(new)
                 pulled.append(out)
             return (*new_stores, *pulled)
@@ -2317,11 +1902,10 @@ class CollectiveEngine:
                            "a length)")
         resolved, handle_key = self._resolve_handle(handle)
         stateful = self._is_stateful(resolved)
-        zc = (zero_copy and keep == "last"
-              and self._zc_pull_eligible(bucket.dtype, resolved))
+        zc = zero_copy and keep == "last" and self.num_shards == 1
         steps = int(np.shape(grads_seq)[0])
         flat = self._flat_replay(
-            bucket.padded_len, bucket.dtype, handle_key, stateful, steps
+            bucket.padded_len, bucket.dtype, stateful, steps
         )
         prog = self._replay_program(
             steps, bucket.padded_len, bucket.dtype, handle_key, keep,
@@ -2528,24 +2112,8 @@ class CollectiveEngine:
         )
         return jax.device_put(arr, sharding)
 
-    def _replay_use_ring(self, dtype, handle_key, stateful: bool) -> bool:
-        """Whether a replay scans the fused ring step.  Wire compression
-        stays off the replay ring: scanning the per-hop-requantizing
-        kernel is unvalidatable off-TPU (the interpreter takes minutes
-        per step) and compounds quantization error T-fold; compressed
-        configs replay on the XLA step while their single-step/grouped
-        ops keep the compressed ring."""
-        resolved = (
-            self._server_handle if handle_key == "_default" else handle_key
-        )
-        return (
-            self._effective_impl(dtype, resolved) == "pallas"
-            and not stateful
-            and not self._ring_compress(dtype)
-        )
-
-    def _flat_replay(self, padded_len: int, dtype, handle_key,
-                     stateful: bool, steps: int) -> bool:
+    def _flat_replay(self, padded_len: int, dtype, stateful: bool,
+                     steps: int) -> bool:
         """Whether the replay sequence uses the FLAT slab layout
         ``[W, T*padded]`` (each worker's T steps contiguous) instead of
         the stacked ``[T, W, padded]``.
@@ -2562,7 +2130,6 @@ class CollectiveEngine:
         return (
             not stateful
             and self.worker_axis is None
-            and not self._replay_use_ring(dtype, handle_key, stateful)
             and padded_len * np.dtype(dtype).itemsize
             >= self.replay_flat_min_bytes
             # Slab offsets are int32 inside the scan; a slab at or over
@@ -2591,25 +2158,16 @@ class CollectiveEngine:
         """Jitted T-step scan program; cached per (T, shape, dtype,
         handle, keep) like every other engine executable.
 
-        Stateless replays on a qualifying pallas config scan the FUSED
-        RING step (the steady-state persistent program: T ring
-        collectives with VMEM updates, one dispatch); everything else
-        scans the XLA collective step.  ``zero_copy`` (only meaningful
-        with ``keep="last"`` on a zc-eligible config, see
-        :meth:`_zc_pull_eligible`) skips the final all-gather and returns
+        ``zero_copy`` (only meaningful with ``keep="last"`` on one
+        shard, see :meth:`replay`) skips the final all-gather and returns
         the store as the pulled value."""
-        use_ring = self._replay_use_ring(dtype, handle_key, stateful)
-        flat = self._flat_replay(padded_len, dtype, handle_key, stateful,
-                                 steps)
+        flat = self._flat_replay(padded_len, dtype, stateful, steps)
         key = ("replay", steps, padded_len, str(dtype), handle_key, keep,
-               stateful, use_ring, flat, zero_copy)
+               stateful, flat, zero_copy)
         with self._mu:
             prog = self._programs.get(key)
         if prog is not None:
             return prog
-        if use_ring:
-            return self._replay_ring_program(key, padded_len, dtype,
-                                             handle_key, keep)
 
         import jax
         from jax import lax
@@ -2759,101 +2317,6 @@ class CollectiveEngine:
                 check_vma=False,
             )
             jitted = jax.jit(fn, donate_argnums=(0,))
-        return self._keep(key, jitted)
-
-    def _replay_ring_program(self, key, padded_len: int, dtype,
-                             handle_key, keep: str) -> Callable:
-        """T-step scan over the FUSED RING step: each iteration runs the
-        ring RS + VMEM update (+ ring AG for keep="all") kernel; the
-        collective_id is safely reused because scan iterations execute
-        sequentially in SPMD lockstep and the kernel drains every
-        semaphore to zero at exit.  keep="last" scans the push-only
-        ring and gathers once at the end (the T×ZPush + pull shape)."""
-        import jax
-        import jax.numpy as jnp
-        from jax import lax
-        from jax.sharding import PartitionSpec as P
-
-        from ..ops.ring_collective import (
-            derive_collective_id,
-            ring_chunk_len,
-            ring_push,
-            ring_push_pull,
-        )
-
-        handle = self._resolved_handle_fn(handle_key)
-        axis = self.axis
-        waxis = self.worker_axis
-        compress = self._ring_compress(dtype)
-        cid = derive_collective_id(*key)
-        interp = self._interpret
-        store_spec = P(axis)
-
-        if waxis is not None:
-            shard_fn = self._ring_2d_shard_fn(
-                handle, padded_len, dtype, compress, cid
-            )
-
-            def _body(store_l, grads_l):
-                def step(carry, g):
-                    new = shard_fn(carry, g)
-                    out = _gather(new, axis) if keep == "all" else 0.0
-                    return new, out
-
-                new_store, outs = lax.scan(step, store_l, grads_l)
-                if keep == "last":
-                    outs = _gather(new_store, axis)
-                return new_store, outs
-
-            grads_spec = P(None, waxis, axis)
-        else:
-            n = self.num_shards
-            chunk0 = padded_len // n
-            kchunk = ring_chunk_len(padded_len, n, dtype,
-                                    compress=compress)
-
-            def _body(store_l, grads_l):
-                s = store_l
-                if kchunk != chunk0:
-                    s = jnp.pad(s, (0, kchunk - chunk0))
-
-                def step(carry, g):
-                    gr, _ = _pad_ring_chunks(
-                        g[0].reshape(n, chunk0), None, kchunk, chunk0
-                    )
-                    if keep == "all":
-                        new, pulled = ring_push_pull(
-                            gr, carry, handle, axis, n,
-                            collective_id=cid, compress=compress,
-                            interpret=interp,
-                        )
-                        return new, _slice_ring_pulled(
-                            pulled, n, kchunk, chunk0
-                        )
-                    new = ring_push(gr, carry, handle, axis, n,
-                                    collective_id=cid, compress=compress,
-                                    interpret=interp)
-                    return new, 0.0
-
-                s, outs = lax.scan(step, s, grads_l)
-                s_out = s[:chunk0] if kchunk != chunk0 else s
-                if keep == "last":
-                    outs = _gather(s_out, axis)
-                return s_out, outs
-
-            grads_spec = P(None, axis, None)
-
-        fn = jax.shard_map(
-            _body,
-            mesh=self.mesh,
-            in_specs=(store_spec, grads_spec),
-            out_specs=(
-                store_spec,
-                P(None, None) if keep == "all" else P(None),
-            ),
-            check_vma=False,
-        )
-        jitted = jax.jit(fn, donate_argnums=(0,))
         return self._keep(key, jitted)
 
     def pull(self, name: str):

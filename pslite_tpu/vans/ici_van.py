@@ -76,6 +76,12 @@ class _IciDataPlane:
         return None  # CollectiveEngine defaults to the local-device mesh
 
     def start(self, customer_id: int) -> None:
+        for var, served in (("PS_ICI_IMPL", ("", "xla")),
+                            ("PS_ICI_COMPRESS", ("",))):
+            log.check((self.env.find(var) or "") in served,
+                      f"{var} selected the ring kernel, which was removed "
+                      f"at PR 46 (XLA's collectives carry every dense "
+                      f"push_pull): unset it")
         super().start(customer_id)
         # Only worker instances drive the SPMD data plane; scheduler/server
         # instances keep the control-plane role (barriers, bookkeeping, and
@@ -90,7 +96,6 @@ class _IciDataPlane:
             handle = self.env.find("PS_ICI_SERVER_HANDLE", "sum")
             self.engine = CollectiveEngine(
                 mesh=self._make_mesh(), server_handle=handle,
-                impl=self.env.find("PS_ICI_IMPL", None),
             )
             self.sparse_engine = SparseEngine(
                 self.engine.mesh, self.engine.axis,

@@ -2,7 +2,7 @@
 read-update-write against XLA's 1-D gather, add and scatter, bit for bit.
 A CPU run proves values and which accumulators are written, never a speed;
 that the kernel lowers for the chip inside the stateful push is
-``test_aot_ring.py``'s.
+``test_compile_for_v5e.py``'s.
 """
 
 import numpy as np

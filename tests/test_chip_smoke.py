@@ -17,11 +17,9 @@ from pslite_tpu.utils import compile_cache
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# The ring phase is left to tests/test_ring_collective.py: the interpreter
-# takes seconds per ring program on the 8-device mesh.
 TINY = chip_smoke.Sizes(
     dense_buckets=(("t0", 1000), ("t1", 4100), ("t2", 1000)),
-    steps=2, ring_buckets=0,
+    steps=2,
     readme_keys=4, readme_val_len=64,
     emb_rows=4096, emb_dim=8, emb_batch=64,
 )

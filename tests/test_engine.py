@@ -541,76 +541,16 @@ def test_replay_two_axis_mesh():
         np.testing.assert_allclose(pulled[t], acc, rtol=1e-5)
 
 
-@pytest.mark.parametrize("shape,axes", [((2, 4), ("dp", "kv")),
-                                        ((4, 2), ("dp", "kv"))])
-def test_two_axis_ring_kernel_matches_xla(shape, axes):
-    """Multi-axis data plane (VERDICT r02 #1): the fused ring along the
-    worker axis + XLA all_gather along kv must match the pure-XLA 2-D
-    path on a (dp, kv) torus — push_pull, push+pull, and a second step
-    (store donation chain intact)."""
-    from pslite_tpu.parallel.mesh import make_mesh
-
-    mesh2 = make_mesh(shape, axes)
-    keys = np.arange(3, dtype=np.uint64)
-    val_len = 700  # padded + non-tile-aligned sub-chunks
-    rng = np.random.default_rng(41)
-    W = shape[0]
-    grads1 = rng.normal(size=(W, 3 * val_len)).astype(np.float32)
-    grads2 = rng.normal(size=(W, 3 * val_len)).astype(np.float32)
-
-    ref = CollectiveEngine(mesh=mesh2, worker_axis="dp", impl="xla")
-    ref.register_dense("x2", keys, val_len)
-    eng = CollectiveEngine(mesh=mesh2, worker_axis="dp", impl="pallas")
-    eng.register_dense("r2", keys, val_len)
-
-    p_ref = np.asarray(ref.push_pull("x2", grads1))
-    p_ring = np.asarray(eng.push_pull("r2", grads1))
-    np.testing.assert_allclose(p_ring, p_ref, rtol=1e-5, atol=1e-5)
-
-    # push-only keeps the dp-replicated store consistent for a later pull.
-    ref.push("x2", grads2).block_until_ready()
-    eng.push("r2", grads2).block_until_ready()
-    np.testing.assert_allclose(
-        np.asarray(eng.pull("r2")), np.asarray(ref.pull("x2")),
-        rtol=1e-5, atol=1e-5,
-    )
-
-
-def test_two_axis_ring_kernel_int8_compress():
-    """int8 wire compression on the 2-D ring: lossy but bounded, and the
-    pulled result must be identical on every device (owner-quantized AG
-    payloads)."""
-    from pslite_tpu.parallel.mesh import make_mesh
-
-    mesh2 = make_mesh((2, 4), ("dp", "kv"))
-    eng = CollectiveEngine(mesh=mesh2, worker_axis="dp", impl="pallas",
-                           wire_compress="int8")
-    keys = np.arange(2, dtype=np.uint64)
-    val_len = 4096
-    eng.register_dense("c2", keys, val_len)
-    rng = np.random.default_rng(43)
-    grads = rng.normal(size=(2, 2 * val_len)).astype(np.float32)
-    pulled = np.asarray(eng.push_pull("c2", grads))
-    want = grads.sum(axis=0)
-    # absmax ~3.5, 2 ring hops of int8 quantization: tolerance scales
-    # with amax/127 per hop.
-    tol = 3 * np.abs(grads).max() / 127
-    np.testing.assert_allclose(pulled, want, atol=tol)
-
-
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_two_axis_stateful_fused_handles(impl):
+def test_two_axis_stateful_fused_handles():
     """Stateful (fused optimizer) handles on a 2-D (dp, kv) mesh — the
     dp-psum aggregation feeding the Pallas optimizer pass, state sharded
-    over kv / replicated over dp.  Must match the 1-D reference engine
-    step for step.  (impl only routes the stateless path; stateful
-    programs are XLA either way — parametrized to prove the resolve
-    logic doesn't mis-route.)"""
+    over kv / replicated over dp.  Must match the recurrence step for
+    step."""
     from pslite_tpu.parallel.mesh import make_mesh
 
     lr, mu = 0.1, 0.9
     mesh2 = make_mesh((2, 4), ("dp", "kv"))
-    eng = CollectiveEngine(mesh=mesh2, worker_axis="dp", impl=impl,
+    eng = CollectiveEngine(mesh=mesh2, worker_axis="dp",
                            server_handle=f"sgd_momentum:{lr},{mu}")
     keys = np.arange(3, dtype=np.uint64)
     val_len = 100
@@ -657,16 +597,14 @@ def test_two_axis_adam_replay():
                                    rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_two_axis_push_pull_group(impl):
-    """Grouped dispatch on a 2-D mesh (both impls) must match per-bucket
-    singles — the W != S decoupling now covers the model-step group
-    path."""
+def test_two_axis_push_pull_group():
+    """Grouped dispatch on a 2-D mesh must match per-bucket singles —
+    the W != S decoupling covers the model-step group path."""
     from pslite_tpu.parallel.mesh import make_mesh
 
     mesh2 = make_mesh((2, 4), ("dp", "kv"))
-    eng = CollectiveEngine(mesh=mesh2, worker_axis="dp", impl=impl)
-    ref = CollectiveEngine(mesh=mesh2, worker_axis="dp", impl="xla")
+    eng = CollectiveEngine(mesh=mesh2, worker_axis="dp")
+    ref = CollectiveEngine(mesh=mesh2, worker_axis="dp")
     rng = np.random.default_rng(51)
     names, grads_list = [], []
     for i, val_len in enumerate((40, 700, 256)):
@@ -769,76 +707,6 @@ def test_push_pull_stream_overlaps_staging_latency(mesh):
         )
 
 
-@pytest.mark.parametrize("keep", ["all", "last"])
-def test_replay_ring_matches_xla(mesh, keep):
-    """Stateless replay on the pallas impl scans the fused ring step;
-    it must match the XLA-scan replay exactly (1-D mesh)."""
-    keys = np.arange(2, dtype=np.uint64)
-    val_len = 300  # padded, non-tile-aligned chunks
-    rng = np.random.default_rng(57)
-    T = 3
-    seq = rng.normal(size=(T, 8, 2 * val_len)).astype(np.float32)
-
-    ref = CollectiveEngine(mesh=mesh, impl="xla")
-    ref.register_dense("rr_ref", keys, val_len)
-    want = np.asarray(ref.replay("rr_ref", seq, keep=keep))
-
-    eng = CollectiveEngine(mesh=mesh, impl="pallas")
-    eng.register_dense("rr", keys, val_len)
-    got = np.asarray(eng.replay("rr", seq, keep=keep))
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-
-    # Stores advanced identically.
-    np.testing.assert_allclose(
-        np.asarray(eng.pull("rr")), np.asarray(ref.pull("rr_ref")),
-        rtol=1e-5, atol=1e-5,
-    )
-
-
-@pytest.mark.parametrize("keep", ["all", "last"])
-def test_replay_ring_two_axis(keep):
-    """Ring replay on the 2-D torus: dp sub-ring step inside the scan,
-    both keep modes (last = sub-ring pushes + one final kv gather)."""
-    from pslite_tpu.parallel.mesh import make_mesh
-
-    mesh2 = make_mesh((2, 4), ("dp", "kv"))
-    keys = np.arange(2, dtype=np.uint64)
-    val_len = 200
-    rng = np.random.default_rng(59)
-    T = 3
-    seq = rng.normal(size=(T, 2, 2 * val_len)).astype(np.float32)
-
-    ref = CollectiveEngine(mesh=mesh2, worker_axis="dp", impl="xla")
-    ref.register_dense("r2_ref", keys, val_len)
-    want = np.asarray(ref.replay("r2_ref", seq, keep=keep))
-
-    eng = CollectiveEngine(mesh=mesh2, worker_axis="dp", impl="pallas")
-    eng.register_dense("r2", keys, val_len)
-    got = np.asarray(eng.replay("r2", seq, keep=keep))
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-
-
-def test_replay_compressed_config_falls_back_to_xla():
-    """wire_compress engines replay on the XLA step (the compressed ring
-    stays single-step/grouped — see _replay_program): results are exact,
-    not quantized."""
-    mesh1 = default_mesh()
-    eng = CollectiveEngine(mesh=mesh1, impl="pallas",
-                           wire_compress="int8")
-    keys = np.arange(2, dtype=np.uint64)
-    val_len = 4096
-    eng.register_dense("rc", keys, val_len)
-    rng = np.random.default_rng(61)
-    T = 2
-    seq = rng.normal(size=(T, 8, 2 * val_len)).astype(np.float32)
-    pulled = np.asarray(eng.replay("rc", seq))
-    acc = np.zeros(2 * val_len, np.float32)
-    for t in range(T):
-        acc = acc + seq[t].sum(axis=0)
-        # Exact (rtol only): the XLA path carries full precision.
-        np.testing.assert_allclose(pulled[t], acc, rtol=1e-5, atol=1e-5)
-
-
 def test_push_pull_zero_copy_single_device():
     """In-place pull delivery on a degenerate gather (kv axis size 1):
     values match the copying path, the returned array IS the store, and
@@ -924,7 +792,7 @@ def test_replay_flat_slab_matches_sequential(mesh):
     eng.replay_flat_min_bytes = 4  # force the slab layout on tiny buckets
     eng.register_dense("ff", keys, val_len)
     assert eng._flat_replay(eng.bucket("ff").padded_len, np.float32,
-                            "_default", False, 4)
+                            False, 4)
     pulled = np.asarray(eng.replay("ff", seq))
     assert pulled.shape == (T, 3 * val_len)
     for t in range(T):
@@ -964,10 +832,9 @@ def test_replay_zero_copy_last_single_device():
 
 
 def test_three_axis_torus_parity():
-    """3-D torus (dp, kv1, kv2): store sharded over BOTH kv axes, fused
-    dp sub-rings (ring positions translate through three axes'
-    coordinates), pulled broadcast gathered over both kv axes — ring
-    matches XLA (VERDICT r03 missing #4)."""
+    """3-D torus (dp, kv1, kv2): store sharded over BOTH kv axes, the
+    worker sum a psum along dp, pulled broadcast gathered over both kv
+    axes (VERDICT r03 missing #4)."""
     from pslite_tpu.parallel.mesh import make_mesh
 
     mesh3 = make_mesh((2, 2, 2), ("dp", "kv1", "kv2"))
@@ -976,19 +843,13 @@ def test_three_axis_torus_parity():
     rng = np.random.default_rng(91)
     g = rng.normal(size=(2, 303)).astype(np.float32)
 
-    outs = {}
-    for impl in ("xla", "pallas"):
-        eng = CollectiveEngine(mesh=mesh3, axis_name=("kv1", "kv2"),
-                               worker_axis="dp", impl=impl)
-        assert eng.num_shards == 4
-        assert eng._effective_impl(np.float32, "sum") == impl
-        eng.register_dense("t3", keys, val_len)
-        assert eng.bucket("t3").padded_len > eng.bucket("t3").total_len
-        outs[impl] = np.asarray(eng.push_pull("t3", g))
-        np.testing.assert_allclose(outs[impl], g.sum(axis=0),
-                                   rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(outs["pallas"], outs["xla"],
-                               rtol=1e-5, atol=1e-5)
+    eng = CollectiveEngine(mesh=mesh3, axis_name=("kv1", "kv2"),
+                           worker_axis="dp")
+    assert eng.num_shards == 4
+    eng.register_dense("t3", keys, val_len)
+    assert eng.bucket("t3").padded_len > eng.bucket("t3").total_len
+    np.testing.assert_allclose(np.asarray(eng.push_pull("t3", g)),
+                               g.sum(axis=0), rtol=1e-4, atol=1e-4)
 
 
 def test_three_axis_torus_stateful_and_replay():
@@ -1019,15 +880,12 @@ def test_three_axis_torus_stateful_and_replay():
 
 def test_tuple_axis_without_worker_axis_colocated():
     """A composite kv axis with no worker axis: the 1-D colocated
-    semantics hold (workers = product of the axes) and the ring gate
-    falls back to XLA (no single ring dimension)."""
+    semantics hold (workers = product of the axes)."""
     from pslite_tpu.parallel.mesh import make_mesh
 
     mesh3 = make_mesh((2, 4), ("kv1", "kv2"))
-    eng = CollectiveEngine(mesh=mesh3, axis_name=("kv1", "kv2"),
-                           impl="pallas")
+    eng = CollectiveEngine(mesh=mesh3, axis_name=("kv1", "kv2"))
     assert eng.num_shards == 8
-    assert eng._effective_impl(np.float32, "sum") == "xla"
     keys = np.arange(2, dtype=np.uint64)
     eng.register_dense("c2", keys, 64)
     rng = np.random.default_rng(95)

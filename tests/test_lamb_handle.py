@@ -477,7 +477,7 @@ def test_a_push_alone_lowers_the_one_result_kernel(monkeypatch, held_tiles,
         assert ("@lamb_moments(" in text) == (not one_pass)
         assert one_pass or "all_reduce" in text     # the norms' psum
         # (The jitted wrapper's name; the custom call is ``lamb_apply``
-        # either way: tests/test_aot_ring.py.)
+        # either way: tests/test_compile_for_v5e.py.)
         wrapper = "lamb_one_pass" if one_pass else "lamb_apply"
         (sig,) = re.findall(
             r"func\.func private @%s\(.*?\) -> \(?(.*?)\)? \{" % wrapper,

@@ -11,7 +11,7 @@ bit.  Through ``KVWorker`` on the engine path, against float64 recurrences
 of the program's) fed the bf16 gradients widened, on one shard and on the
 4-shard CPU mesh, kernels interpreted.  What is not served is refused by
 name.  The programs compiled for a described v5e are in
-``tests/test_aot_ring.py``.
+``tests/test_compile_for_v5e.py``.
 """
 
 import os
@@ -373,15 +373,11 @@ def test_the_paths_that_do_not_serve_a_job_dtype_refuse_by_name(cluster):
     _register(kv)
     g = np.ones((4, TOTAL), BF16)
     said = ("'tree'", "bfloat16", "float32 store")
-    # A stateless handle: the programs shared by length, and the ring's.
+    # A stateless handle: the programs shared by length.
     for handle in ("sum", "assign", "sgd:0.1"):
         _refused(lambda: eng.push_pull("tree", g, handle), *said,
                  "stateless", "stateful handle")
         _refused(lambda: eng.push("tree", g, handle), *said, "stateless")
-    ring = CollectiveEngine(mesh=_mesh(4), server_handle="sum",
-                            impl="pallas")
-    _register(ring)
-    _refused(lambda: ring.push_pull("tree", g), *said, "ring")
     _refused(lambda: eng.replay("tree", np.ones((2, 4, TOTAL), BF16)),
              *said, "replay")
     _refused(lambda: list(eng.push_pull_stream("tree", [g])), *said,

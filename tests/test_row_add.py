@@ -1,7 +1,7 @@
 """``ops/row_add.py`` under the Pallas interpreter, on small tables: the
 table's write by distinct row against numpy.  A CPU run proves values and
 which rows are visited, never a speed; that the kernel lowers for the chip,
-in place, is ``test_aot_ring.py``'s.
+in place, is ``test_compile_for_v5e.py``'s.
 """
 
 import numpy as np

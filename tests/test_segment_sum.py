@@ -1,7 +1,7 @@
 """``ops/segment_sum.py`` under the Pallas interpreter: the combine's segment
 sum over sorted rows against numpy in float64.  A CPU run proves values and
 which rows are written, never a speed; that the kernel lowers for the chip
-inside both pushes is ``test_aot_ring.py``'s.
+inside both pushes is ``test_compile_for_v5e.py``'s.
 """
 
 import numpy as np

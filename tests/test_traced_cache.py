@@ -1,7 +1,7 @@
 """``utils/compile_cache.py`` ``call_traced``: a function's trace kept in
 the compile cache's directory, found again by a later process, made again
 when what it was made from changes.  (That the kernel it exists for comes
-back without Pallas, in place, at full size: ``test_aot_ring.py``.)
+back without Pallas, in place, at full size: ``test_compile_for_v5e.py``.)
 """
 
 import os
