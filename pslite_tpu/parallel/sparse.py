@@ -13,8 +13,20 @@ static shapes:
 - ``pull``: every shard materializes the owned rows for every worker's
   index list (zeros elsewhere); a ``psum_scatter`` over the worker dimension
   both sums the one-hot contributions and routes each worker exactly its
-  own batch — gather traffic rides the same bandwidth-optimal collective as
-  dense push.
+  own batch.
+
+What that costs between chips (compiled for a v5e 2x2 at W = 4, n = 131,072,
+d = 128; ``PERF.md`` section 5 has the chip's times): every shard is SENT
+every worker's whole batch, ids and gradients (two all-gathers, ``W*n`` slots
+a shard though it owns about ``n`` of them), and the pull's ``psum_scatter``
+is compiled as an ALL-REDUCE of the whole ``[W, n, d]`` workspace with the
+worker's slice cut afterwards, not as a reduce-scatter: about six times the
+bytes an exchange routed by owner would move.  On the chip the three
+collectives are 5.4 ms of that cell's 25.3 ms step, and the gathers, sorts
+and sums over the ``W*n`` slots most of the rest.  The scopes
+``ps.sparse.route.ids`` / ``.grads`` / ``.rows`` tell the three exchanges
+apart in a trace and ``engine.sparse.route.slots`` counts the slots a shard
+works on.
 
 Row ownership is round-robin (``row % num_shards``) rather than contiguous
 range: skewed key distributions (the 1M-key embedding workload,
@@ -32,7 +44,8 @@ from typing import Callable, Dict, Optional
 import numpy as np
 
 from ..utils import logging as log
-from ..utils.profiling import ENGINE_OP, stage_clock, stamp
+from ..utils.profiling import (ENGINE_OP, SPARSE_ROUTE, stage_clock,
+                               stamp)
 from .placement import staging_xp
 
 
@@ -75,6 +88,7 @@ class _BoundPush:
     segsum_kernel: bool  # it sums its segments with ops/segment_sum.py
     acc_kernel: bool  # it updates the accumulator with ops/acc_update.py
     packed: bool  # the table is lane-packed (pack > 1)
+    slots: int  # batch-workspace rows a shard's program combines (W * batch)
 
 
 def _interleave_rows(glob, num_rows: int, rps: int, S: int, dtype):
@@ -161,6 +175,30 @@ def _store_out_format(store, mesh, axis):
     return NamedSharding(mesh, P(axis, None))
 
 
+def _route_ids(axis, S, idx_l):
+    """What every sparse body starts with, inside its ``ps.sparse.route``:
+    every worker's row ids gathered on every shard (``s32[W*n]``), and for
+    each slot whether this shard owns its row (``row % S``) and the row's
+    place here (``row // S``).  Scope ``ps.sparse.route.ids``."""
+    import jax
+    from jax import lax
+
+    with jax.named_scope("ps.sparse.route.ids"):
+        all_idx = lax.all_gather(idx_l[0], axis, tiled=True)  # [W*n]
+        owned = (all_idx % S) == lax.axis_index(axis)
+        return owned, all_idx // S
+
+
+def _route_grads(axis, grads_l):
+    """A push's gradient rows gathered on every shard beside their ids
+    (``[W*n, d]``).  Scope ``ps.sparse.route.grads``."""
+    import jax
+    from jax import lax
+
+    with jax.named_scope("ps.sparse.route.grads"):
+        return lax.all_gather(grads_l[0], axis, tiled=True)
+
+
 def _scatter_rows(axis, S, R, pack, dim, store_l, idx_l, grads_l):
     """Sum-handle push: add the owned rows DIRECTLY into the donated
     (possibly packed) store.  A dense-aggregate form reads + writes the
@@ -179,7 +217,10 @@ def _scatter_rows(axis, S, R, pack, dim, store_l, idx_l, grads_l):
 
     The sparse bodies carry ``jax.named_scope``s, by which a device trace
     is read: ``ps.sparse.route`` (indices and rows crossing the workers,
-    and who owns what), ``ps.sparse.push.scatter_add``,
+    and who owns what; inside it ``ps.sparse.route.ids``, the index
+    all-gather and the ownership, ``ps.sparse.route.grads``, a push's
+    gradient all-gather, and ``ps.sparse.route.rows``, the pull's
+    ``psum_scatter``), ``ps.sparse.push.scatter_add``,
     ``ps.sparse.pull.gather``, ``ps.sparse.combine`` (sort and segment sum
     of duplicates: under a stateful handle, and before ``row_add`` in the
     sum), ``ps.sparse.pack.place`` (a lane-packed table's rows placed in
@@ -187,17 +228,13 @@ def _scatter_rows(axis, S, R, pack, dim, store_l, idx_l, grads_l):
     follows) and under a stateful handle ``ps.update`` (accumulator and
     step)."""
     import jax
-    from jax import lax
     import jax.numpy as jnp
 
     from ..ops.row_add import row_add
 
     with jax.named_scope("ps.sparse.route"):
-        all_idx = lax.all_gather(idx_l[0], axis, tiled=True)  # [W*n]
-        all_g = lax.all_gather(grads_l[0], axis, tiled=True)  # [W*n, d]
-        my = lax.axis_index(axis)
-        owned = (all_idx % S) == my
-        local = all_idx // S
+        owned, local = _route_ids(axis, S, idx_l)
+        all_g = _route_grads(axis, grads_l)  # [W*n, d]
 
     def scatter(store_l, owned, local, all_g):
         with jax.named_scope("ps.sparse.push.scatter_add"):
@@ -487,15 +524,12 @@ def _adagrad_sparse(axis, S, R, pack, dim, store_l, acc_l, idx_l,
     read, and written only zeros where they share a touched physical
     row."""
     import jax
-    from jax import lax
     import jax.numpy as jnp
 
     with jax.named_scope("ps.sparse.route"):
-        all_idx = lax.all_gather(idx_l[0], axis, tiled=True)   # [m]
-        all_g = lax.all_gather(grads_l[0], axis, tiled=True)   # [m, d]
-        my = lax.axis_index(axis)
-        owned = (all_idx % S) == my
-        local = jnp.where(owned, all_idx // S, R)  # R = sentinel (dropped)
+        owned, local = _route_ids(axis, S, idx_l)              # [m]
+        all_g = _route_grads(axis, grads_l)                    # [m, d]
+        local = jnp.where(owned, local, R)  # R = sentinel (dropped)
 
     with jax.named_scope("ps.sparse.combine"):
         G_seg, row_seg, valid = _combine_rows(local, all_g, R)
@@ -526,17 +560,14 @@ def _pull_rows(axis, S, store_l, idx_l, pack: int = 1, dim: int = None):
     import jax.numpy as jnp
 
     with jax.named_scope("ps.sparse.route"):
-        all_idx = lax.all_gather(idx_l[0], axis, tiled=True)  # [W*n]
-        my = lax.axis_index(axis)
-        owned = (all_idx % S) == my
-        local = all_idx // S
+        owned, local = _route_ids(axis, S, idx_l)  # [W*n]
     with jax.named_scope("ps.sparse.pull.gather"):
         if pack == 1:
             rows = store_l[jnp.where(owned, local, 0)]  # [W*n, d]
             d = store_l.shape[1]
         else:
             d = dim
-            m = all_idx.shape[0]
+            m = local.shape[0]
             phys = store_l[jnp.where(owned, local // pack, 0)]  # [W*n, 128]
             slot = (local % pack).astype(jnp.int32)
             rows = jnp.take_along_axis(
@@ -544,7 +575,8 @@ def _pull_rows(axis, S, store_l, idx_l, pack: int = 1, dim: int = None):
             )[:, 0]
         vals = jnp.where(owned[:, None], rows, 0)
         vals = vals.reshape(S, -1, d)  # [W, n, d]
-    with jax.named_scope("ps.sparse.route"):
+    with jax.named_scope("ps.sparse.route"), \
+            jax.named_scope("ps.sparse.route.rows"):
         return lax.psum_scatter(vals, axis, scatter_dimension=0,
                                 tiled=True)[0]  # [n, d] for my indices
 
@@ -663,6 +695,10 @@ class SparseEngine:
         registry.gauge(
             "engine.sparse.acc.bytes",
             fn=lambda: sum(int(a.nbytes) for a in list(self._acc.values())))
+        # The process's, as the stage clock is (several engines of one
+        # process note into the one clock).
+        registry.gauge("engine.sparse.route.slots",
+                       fn=lambda: self._clock.routed_totals()[0])
 
     def _keep(self, key, prog):
         """Cache a program a lookup missed; the misses are counted here,
@@ -982,12 +1018,21 @@ class SparseEngine:
             kind, params, self._row_kernel(table),
             self._segsum_kernel(table, kind is not None),
             kind is not None and self._acc_kernel(table, batch),
-            table.pack != 1)
+            table.pack != 1, self._route_slots(batch))
         with self._mu:
             # A new registration meanwhile: the next push binds.
             if self._tables.get(name) is table:
                 self._bound[(name, handle, batch)] = bound
         return bound
+
+    def _route_slots(self, batch: int) -> int:
+        """The rows of the batch workspace one shard's program works on in
+        an op of ``batch`` lookups a worker (the push combines them, the
+        pull gathers them): every shard is sent every worker's batch, so W
+        x ``batch``, whatever share of them it owns.  From shapes alone;
+        an op notes it once (``SPARSE_ROUTE``, gauge
+        ``engine.sparse.route.slots``)."""
+        return self.num_shards * batch
 
     def _row_kernel(self, table: SparseTable) -> bool:
         """Whether this mesh's push programs of ``table``, the sum's and a
@@ -1060,6 +1105,7 @@ class SparseEngine:
             self.packed_pushes += b.packed
         self._observe("push", table, batch)
         t3 = stamp()
+        self._note((SPARSE_ROUTE, t3, b.slots, -1, -1))
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
         # The token is a tiny non-donated output that becomes ready when
         # the push completes — block on it freely (the store itself is
@@ -1245,6 +1291,8 @@ class SparseEngine:
             self._unlock_tables(ordered)
         t3 = stamp()
         # One op with one launch, whatever it groups.
+        self._note((SPARSE_ROUTE, t3, self._route_slots(sum(batches)),
+                    -1, -1))
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
         for t, batch in zip(tables, batches):
             self._observe("push", t, batch)
@@ -1275,6 +1323,8 @@ class SparseEngine:
         for t, batch in zip(tables, batches):
             self._observe("pull", t, batch)
         t3 = stamp()
+        self._note((SPARSE_ROUTE, t3, self._route_slots(sum(batches)),
+                    -1, -1))
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
         return pulled
 
@@ -1294,6 +1344,7 @@ class SparseEngine:
             pulled = out.reshape(self.num_shards, -1, table.dim)
         self._observe("pull", table, batch)
         t3 = stamp()
+        self._note((SPARSE_ROUTE, t3, self._route_slots(batch), -1, -1))
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
         return pulled
 

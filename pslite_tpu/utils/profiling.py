@@ -150,6 +150,17 @@ KV_OP, ENGINE_OP, COMPLETED = 0, 1, 2
 _STAGES_OF = ((0, 4, None),   # KV_OP: route, dispatch, -1
               (1, 2, 3),      # ENGINE_OP: select, prep, launch
               (5, 6, None))   # COMPLETED: complete.wait, complete.copy, -1
+# A fourth kind carries no stage: ``(SPARSE_ROUTE, t_end, slots, -1, -1)``,
+# noted by ``SparseEngine`` once an op, BEFORE its ``ENGINE_OP`` note (the
+# account pairs an ``ENGINE_OP`` note with the ``KV_OP`` note next after
+# it).  ``slots`` are the rows of the batch workspace one shard's program
+# works on in that op (the push combines them, the pull gathers them): W x n
+# while every shard is sent every worker's batch, known from shapes when the
+# op is bound.  Their sum and the ops that noted them ride behind the
+# occupancy account in the totals' vector (``ROUTED``), so the slot marks
+# carry them and :meth:`StageClock.routed` answers for a window.
+SPARSE_ROUTE = 3
+ROUTED = ("slots", "ops")
 
 StageWindow = Tuple[Dict[str, Tuple[int, int]], int, float]
 
@@ -192,6 +203,7 @@ READY_NS = 100_000
 FORGET_NS = 1 << 32
 _READY, _RESETS = (_OCC + OCCUPANCY.index(name)
                    for name in ("ready_at_wait", "resets"))
+_ROUTED = _OCC + len(OCCUPANCY)  # where ``ROUTED`` begins in the vector
 # A spell's row: OCCUPANCY up to and with ``spells``.
 _PRELAUNCH, _LAUNCH = 0, 1
 _COPY, _ROUTE, _SELECT, _PREP, _OUTSIDE = (
@@ -199,6 +211,7 @@ _COPY, _ROUTE, _SELECT, _PREP, _OUTSIDE = (
 _SPELLS = OCCUPANCY.index("spells")
 
 OccupancyWindow = Tuple[Dict[str, int], int, float]
+RoutedWindow = Tuple[Tuple[int, int], int, float]
 _NO_TIMES = np.empty(0, dtype=np.int64)
 
 
@@ -243,8 +256,9 @@ class StageClock:
         self.note = self._pending.append
         self.backlog = self._pending.__len__  # notes not yet folded
         self._fold_mu = threading.Lock()
-        # ns, calls of each stage; then the occupancy account
-        self._totals = [0] * (_OCC + len(OCCUPANCY))
+        # ns, calls of each stage; then the occupancy account; then what
+        # the sparse ops routed
+        self._totals = [0] * (_ROUTED + len(ROUTED))
         # slot -> the totals at its start
         self._marks: Dict[int, Tuple[int, ...]] = {}
         self._slot = -1  # the newest slot an op ended in
@@ -303,6 +317,9 @@ class StageClock:
                             tot[2 * stage + 1] += int(had.sum())
                 waits = of_slot[of_slot[:, 0] == COMPLETED, 2]
                 tot[_READY] += int((waits < READY_NS).sum())
+                routed = of_slot[of_slot[:, 0] == SPARSE_ROUTE, 2]
+                tot[_ROUTED] += int(routed.sum())
+                tot[_ROUTED + 1] += len(routed)
                 # A spell is put down to the slot of the op that ended it.
                 if len(ended_in):
                     ended = spells[ended_in == slot].sum(axis=0).tolist()
@@ -488,7 +505,7 @@ class StageClock:
 
     @staticmethod
     def _occupancy(vector) -> Dict[str, int]:
-        account = dict(zip(OCCUPANCY, vector[_OCC:]))
+        account = dict(zip(OCCUPANCY, vector[_OCC:_ROUTED]))
         account["completed"] = vector[2 * STAGES.index("complete.wait") + 1]
         return account
 
@@ -498,6 +515,22 @@ class StageClock:
         slots, seconds)``."""
         grown, slots, seconds = self._between(t_lo, t_hi)
         return (self._occupancy(grown) if slots else {}), slots, seconds
+
+    def routed_totals(self) -> Tuple[int, int]:
+        """``(slots, ops)`` of the sparse ops since the process started
+        (``SPARSE_ROUTE``): the batch-workspace rows a shard worked on,
+        summed over the ops, and the ops."""
+        self.fold()
+        return tuple(self._totals[_ROUTED:_ROUTED + len(ROUTED)])
+
+    def routed(self, t_lo: float, t_hi: float) -> RoutedWindow:
+        """:meth:`routed_totals` over the whole slots inside ``[t_lo,
+        t_hi]``, as :meth:`window` answers for the stages: ``((slots, ops),
+        slots of the clock, seconds)``."""
+        grown, slots, seconds = self._between(t_lo, t_hi)
+        if not slots:
+            return (0, 0), 0, 0.0
+        return tuple(grown[_ROUTED:_ROUTED + len(ROUTED)]), slots, seconds
 
     def export(self, registry) -> None:
         """Lazily sampled gauges in a node's ``Registry``, so
@@ -548,6 +581,12 @@ class _NullStageClock:
 
     def occupancy(self, t_lo: float, t_hi: float) -> OccupancyWindow:
         return {}, 0, 0.0
+
+    def routed_totals(self) -> Tuple[int, int]:
+        return 0, 0
+
+    def routed(self, t_lo: float, t_hi: float) -> RoutedWindow:
+        return (0, 0), 0, 0.0
 
     def export(self, registry) -> None:
         pass
