@@ -1066,7 +1066,7 @@ class SparseEngine:
         def _pull(store_l, idx_l, *count_l):
             over = []
             rows = _pull_rows(axis, S, store_l, idx_l, pack=pack, dim=dim,
-                              over=over)
+                              over=over)[None]                 # [1, n, d]
             return (rows, *_counted(count_l, over)) if routed else rows
 
         if op == "push":
@@ -1104,8 +1104,8 @@ class SparseEngine:
                 _pull,
                 mesh=self.mesh,
                 in_specs=(P(axis, None), P(axis, None), *count_spec),
-                out_specs=(P(axis, None), *count_spec) if routed
-                else P(axis, None),
+                out_specs=(P(axis, None, None), *count_spec) if routed
+                else P(axis, None, None),
                 check_vma=False,
             )
             jitted = jax.jit(fn, donate_argnums=(2,) * routed)
@@ -1542,7 +1542,7 @@ class SparseEngine:
                 over = []
                 rows = [
                     _pull_rows(axis, S, s, idxs[i], pack=packs[i],
-                               dim=dims[i], over=over)
+                               dim=dims[i], over=over)[None]   # [1, n, d]
                     for i, s in enumerate(stores)
                 ]
                 return (*rows, *_counted(args[2 * k:], over))
@@ -1551,7 +1551,7 @@ class SparseEngine:
                 body, mesh=self.mesh,
                 in_specs=tuple([store_spec] * k + [idx_spec] * k
                                + count_spec),
-                out_specs=tuple([store_spec] * k + count_spec),
+                out_specs=tuple([g_spec] * k + count_spec),
                 check_vma=False,
             )
             jitted = jax.jit(fn, donate_argnums=(2 * k,) * routed)
@@ -1659,16 +1659,14 @@ class SparseEngine:
             # Resolve table.pack under the locks (see push).
             prog = self._sparse_group_program("pull", tables, batches)
             t2 = stamp()  # select | launch
+            # The program's own results, [W, n_i, d_i] each: a reshape out
+            # here would be one more launch and one more copy of the batch.
             if self._group_routed(batches):
-                *outs, self._overflow[names[0]] = prog(
+                *pulled, self._overflow[names[0]] = prog(
                     *[self._stores[n] for n in names], *idxs,
                     self._overflow_count(names[0]))
             else:
-                outs = prog(*[self._stores[n] for n in names], *idxs)
-            pulled = [
-                o.reshape(self.num_shards, -1, t.dim)
-                for o, t in zip(outs, tables)
-            ]
+                pulled = list(prog(*[self._stores[n] for n in names], *idxs))
         finally:
             self._unlock_tables(ordered)
         for t, batch in zip(tables, batches):
@@ -1681,7 +1679,8 @@ class SparseEngine:
 
     def pull(self, name: str, indices):
         """indices: [W, n] -> [W, n, d] rows, each worker shard receiving its
-        own batch."""
+        own batch: the pull program's own result (``P(axis, None, None)``),
+        one launch an op."""
         t0 = stamp()  # stage borders: see _note
         table = self._tables[name]
         idx, _ = self._prep(table, indices)
@@ -1691,12 +1690,12 @@ class SparseEngine:
             # Resolve table.pack under the lock (see push).
             prog = self._sparse_program("pull", table, batch)
             t2 = stamp()  # select | launch
+            # The program's own result, [W, n, d] (see pull_group).
             if self._routed(batch):
-                out, self._overflow[name] = prog(
+                pulled, self._overflow[name] = prog(
                     self._stores[name], idx, self._overflow_count(name))
             else:
-                out = prog(self._stores[name], idx)  # global [W*n, d]
-            pulled = out.reshape(self.num_shards, -1, table.dim)
+                pulled = prog(self._stores[name], idx)
         self._observe("pull", table, batch)
         t3 = stamp()
         self._note((SPARSE_ROUTE, t3, self._route_slots(batch), -1, -1))

@@ -586,6 +586,117 @@ def test_a_lane_packed_tables_pushes_at_full_size_write_it_with_row_add(
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 16e9
 
 
+# -- the pull's result as the program leaves it (parallel/sparse.py) -----------
+
+
+def _engine_on_described_chips(mesh, tables):
+    """A ``SparseEngine`` over chips that are described and not attached,
+    its tables registered by shape alone (nothing can be placed on them):
+    the engine's OWN programs can then be built and compiled."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pslite_tpu.parallel.sparse import SparseEngine, SparseTable
+
+    eng = SparseEngine(mesh)
+    S = eng.num_shards
+    for name, (rps, dim) in tables.items():
+        pack = 128 // dim
+        table = SparseTable(name, S * rps, dim, rps, jnp.float32, pack=pack)
+        eng._tables[name] = table
+        eng._stores[name] = jax.ShapeDtypeStruct(
+            (table.phys_rows * S, pack * dim), jnp.float32,
+            sharding=NamedSharding(mesh, P("kv", None)))
+    return eng
+
+
+def _moves_of_a_batch(text, n, dim):
+    """The compiled instructions that write a worker's batch over again: a
+    copy, or a transpose that is none of the gather's own (``dimensions=
+    {0,1}``: the identity, inside its fusion), with the batch for result."""
+    import re
+
+    batch = re.compile(
+        rf"= f32\[(1,)?{n},{dim}\]\S* (copy|copy-start|transpose)\(")
+    return [l.strip() for l in text.splitlines() if batch.search(l)
+            and "dimensions={0,1}" not in l]
+
+
+PULLS = {
+    # chips, the tables' widths, whether the program threads a count
+    "one-chip": (1, [128], False),
+    "four-chips-routed": (4, [128], True),
+    "one-chip-lane-packed-64": (1, [64], False),
+    "four-chips-group-128-and-64": (4, [128, 64], True),
+}
+
+
+@pytest.mark.parametrize("case", list(PULLS))
+def test_the_pull_program_hands_the_batch_over_as_it_gathered_it(
+        v5e8_mesh, case):
+    """The engine's own pull programs for the v5e (4,096 lookups a worker,
+    2^20 rows a shard; ``benchmark/tests/test_compile_fullsize_sparse_pull.py``
+    compiles the cells' sizes): the result is ``[W, n, d]``, a worker's batch
+    a chip, and after the gather (routed: after the rows' ``all-to-all`` and
+    the permutation back to the batch's order) nothing of the batch's size is
+    written again: the leading unit dimension is a bitcast, so the reshape
+    that used to follow in a program of its own, and its copy, are gone and
+    none has come in its place.  A 64-wide result is laid with the batch
+    along the lanes by the compiler's own choice, before this change as
+    after it: that one re-laying copy is the program's last instruction (of
+    each branch where the exchange is routed) and the only one."""
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    S, dims, routed = PULLS[case]
+    rps, n = 1 << 20, 4096
+    mesh = Mesh(np.array(list(v5e8_mesh.devices.flat[:S])), ("kv",))
+    names = [f"t{d}" for d in dims]
+    eng = _engine_on_described_chips(mesh, {nm: (rps, d)
+                                            for nm, d in zip(names, dims)})
+    assert eng._routed(n) == routed
+    idx = jax.ShapeDtypeStruct((S, n), jnp.int32,
+                               sharding=NamedSharding(mesh, P("kv", None)))
+    count = [jax.ShapeDtypeStruct((S,), jnp.int32,
+                                  sharding=NamedSharding(mesh, P("kv")))
+             ] * routed
+    stores = [eng._stores[nm] for nm in names]
+    if len(names) == 1:
+        prog = eng._sparse_program("pull", eng._tables[names[0]], n)
+    else:
+        prog = eng._sparse_group_program(
+            "pull", [eng._tables[nm] for nm in names], (n,) * len(names))
+    lowered = prog.lower(*stores, *[idx] * len(names), *count)
+    outs = jax.tree_util.tree_leaves(lowered.out_info)
+    assert [tuple(o.shape) for o in outs] == (
+        [(S, n, d) for d in dims] + [(S,)] * routed)
+    compiled = lowered.compile()
+    for sharding in jax.tree_util.tree_leaves(
+            compiled.output_shardings)[:len(dims)]:
+        assert sharding.is_equivalent_to(
+            NamedSharding(mesh, P("kv", None, None)), 3)
+    text = compiled.as_text()
+    for d in dims:
+        moves = _moves_of_a_batch(text, n, d)
+        if d == 128:
+            assert not moves, moves
+            continue
+        # The one re-laying of a narrow result, a branch of the routed
+        # program's conditional its own: last, and of the layout.
+        assert len(moves) == 1 + routed, moves
+        assert all(" copy(" in m and f"f32[1,{n},{d}]{{1,2,0:" in m
+                   for m in moves), moves
+        assert routed or moves[0].startswith("ROOT ")
+    if dims == [128] and not routed:
+        # The program's last instruction: the unit dimension, for nothing.
+        root = [l.strip() for l in text[text.index("\nENTRY "):].splitlines()
+                if l.strip().startswith("ROOT ")][0]
+        assert f"f32[1,{n},128]" in root and " bitcast(" in root, root
+    mem = compiled.memory_analysis()
+    want = sum(n * d * 4 for d in dims)
+    assert want <= mem.output_size_in_bytes <= want + 4096 * (1 + len(dims))
+
+
 # -- LAMB's one pass and its pulled values (ops/fused_update.py) ----------------
 
 
