@@ -984,7 +984,8 @@ class KVWorker:
     def _engine_op(self, op, args, keys=None, cmd: int = 0, lens=None,
                    out=None, callback=None, keep_result: bool = False,
                    pull: bool = False,
-                   handle: Optional[str] = None) -> Optional[int]:
+                   handle: Optional[str] = None,
+                   tables: int = 0) -> Optional[int]:
         """One op of the collective path: route, the engine's op, then
         timestamp + async completion.  None where ``keys`` are no
         registered bucket: the op is the message path's.
@@ -1023,6 +1024,12 @@ class KVWorker:
         get_pulled() unless the bucket's pull buffer is pinned — a pinned
         result is donated by the NEXT pull, so retaining it would hand
         out deleted arrays; its completion is what that next pull joins.
+
+        ``tables`` marks a GROUPED sparse op (``pull_sparse_group`` /
+        ``push_sparse_group``) and says how many tables it carries: the
+        first of ``args`` is then their names, the op goes by the first of
+        them, its span also carries ``tables``, and a grouped pull's result
+        (and ``out``) is a list, a table an entry.
         """
         span = TraceAnnotation(OP_SPAN) if tracing() else None
         if span is not None:
@@ -1041,6 +1048,8 @@ class KVWorker:
         name = args[0]
         result = op(*args)
         t2 = stamp()  # launch | dispatch, but for the way back up
+        if tables:
+            name = name[0]
         pinned = pull and self.engine.pinned_pull_buffer(name) is not None
         if pull:
             keep_result = not pinned
@@ -1078,6 +1087,8 @@ class KVWorker:
             meta = {"ts": ts, "name": name}
             if isinstance(handle, str):
                 meta["handle"] = handle.partition(":")[0]
+            if tables:
+                meta["tables"] = tables
             bucket = (self.engine._buckets.get(name)
                       if keys is not None else None)
             if bucket is not None and bucket.mixed:
@@ -1095,7 +1106,10 @@ class KVWorker:
         room = self._DEVICE_RESULTS_BYTES
         stamps = list(kept)
         for i in range(len(stamps) - 1, -1, -1):
-            room -= getattr(kept[stamps[i]], "nbytes", 0)
+            result = kept[stamps[i]]
+            room -= (sum(r.nbytes for r in result)  # a grouped pull's
+                     if type(result) is list
+                     else getattr(result, "nbytes", 0))
             if room < 0 and i < len(stamps) - 1:
                 for old in stamps[:i + 1]:
                     del kept[old]
@@ -1136,7 +1150,12 @@ class KVWorker:
                 if traced else None)
         if span is not None:
             span.__enter__()
-        result.block_until_ready()
+        grouped = type(result) is list  # a grouped sparse pull's arrays
+        if grouped:
+            for r in result:
+                r.block_until_ready()
+        else:
+            result.block_until_ready()
         if span is not None:
             span.__exit__(None, None, None)
         t1 = stamp()
@@ -1145,25 +1164,11 @@ class KVWorker:
         if span is not None:
             span.__enter__()
         if out is not None:
-            if getattr(result, "is_fully_addressable", True) or getattr(
-                result, "is_fully_replicated", False
-            ):
-                host = np.asarray(result)
+            if grouped:
+                for r, o in zip(result, out):
+                    self._copy_out(r, o)
             else:
-                # Multi-process mesh, worker-sharded result (sparse pull):
-                # this process's rows are its addressable shards, in
-                # global row order.
-                shards = sorted(
-                    result.addressable_shards,
-                    key=lambda s: tuple(sl.start or 0 for sl in s.index),
-                )
-                host = np.concatenate(
-                    [np.asarray(s.data) for s in shards], axis=0
-                )
-            np.copyto(
-                out.reshape(-1),
-                host.reshape(-1)[: out.size].astype(out.dtype),
-            )
+                self._copy_out(result, out)
         if callback is not None:
             callback()
         if span is not None:
@@ -1173,9 +1178,33 @@ class KVWorker:
         if not ts & 1023:  # now and then, and not while an op is issued
             self._stage_clock.fold()
 
+    @staticmethod
+    def _copy_out(result, out) -> None:
+        """One device result's values into the caller's host buffer, flat."""
+        if getattr(result, "is_fully_addressable", True) or getattr(
+            result, "is_fully_replicated", False
+        ):
+            host = np.asarray(result)
+        else:
+            # Multi-process mesh, worker-sharded result (sparse pull):
+            # this process's rows are its addressable shards, in
+            # global row order.
+            shards = sorted(
+                result.addressable_shards,
+                key=lambda s: tuple(sl.start or 0 for sl in s.index),
+            )
+            host = np.concatenate(
+                [np.asarray(s.data) for s in shards], axis=0
+            )
+        np.copyto(
+            out.reshape(-1),
+            host.reshape(-1)[: out.size].astype(out.dtype),
+        )
+
     def get_pulled(self, ts: int):
         """Device-resident pull result for a recent engine-path timestamp
-        (bounded window of the last few results)."""
+        (bounded window of the last few results); of a grouped sparse pull
+        the list of its tables' arrays, in the call's order."""
         with self._mu:
             return self._device_results.get(ts)
 
@@ -1229,6 +1258,36 @@ class KVWorker:
         log.check(eng is not None, "pull_sparse requires the ici van")
         return self._engine_op(eng.pull, (name, indices), None, 0, None,
                                out, callback, True)
+
+    def push_sparse_group(self, names, indices_list, grads_list,
+                          handle: Optional[str] = None, callback=None) -> int:
+        """A step's rows of SEVERAL tables in one op: one timestamp, one
+        program, one launch (``SparseEngine.push_group``).  A table's
+        semantics are :meth:`push_sparse`'s own, ``handle`` applies to every
+        table of the group, and a table may not appear twice (its store is
+        donated to the program once)."""
+        eng = getattr(self.po.van, "sparse_engine", None)
+        log.check(eng is not None, "push_sparse_group requires the ici van")
+        return self._engine_op(eng.push_group,
+                               (names, indices_list, grads_list, handle),
+                               None, 0, None, None, callback, False, False,
+                               handle, len(names))
+
+    def pull_sparse_group(self, names, indices_list, outs=None,
+                          callback=None) -> int:
+        """The rows of SEVERAL tables in one op (``SparseEngine.pull_group``):
+        ``get_pulled(ts)`` is the list of ``[W, n_i, d_i]`` arrays in
+        ``names`` order, ``wait(ts)`` returns when every one is ready, and
+        with ``outs`` (a host buffer a table) each table's rows are copied to
+        its buffer on the completion thread, as ``pull_sparse(..., out=)``
+        copies one."""
+        eng = getattr(self.po.van, "sparse_engine", None)
+        log.check(eng is not None, "pull_sparse_group requires the ici van")
+        log.check(outs is None or len(outs) == len(names),
+                  "pull_sparse_group: one host buffer a table")
+        return self._engine_op(eng.pull_group, (names, indices_list), None,
+                               0, None, outs, callback, True, False, None,
+                               len(names))
 
     # -- telemetry -----------------------------------------------------------
 

@@ -57,8 +57,8 @@ from typing import Callable, Dict, Optional
 import numpy as np
 
 from ..utils import logging as log
-from ..utils.profiling import (ENGINE_OP, SPARSE_ROUTE, stage_clock,
-                               stamp)
+from ..utils.profiling import (ENGINE_OP, SPARSE_GROUP, SPARSE_ROUTE,
+                               stage_clock, stamp)
 from .placement import staging_xp
 
 
@@ -957,6 +957,11 @@ class SparseEngine:
                        fn=lambda: self._clock.routed_totals()[0])
         registry.gauge("engine.sparse.route.overflow",
                        fn=self.route_overflows)
+        # Grouped ops (pull_group / push_group) and the tables they carried.
+        registry.gauge("engine.sparse.group.ops",
+                       fn=lambda: self._clock.grouped_totals()[1])
+        registry.gauge("engine.sparse.group.tables",
+                       fn=lambda: self._clock.grouped_totals()[0])
 
     def route_overflows(self) -> int:
         """Ops of this engine so far whose batch did not fit the routed
@@ -1437,7 +1442,10 @@ class SparseEngine:
     def _sparse_group_program(self, op: str, tables, batches: tuple):
         """One jitted program over SEVERAL tables (one dispatch instead
         of len(tables) — the many-embedding-tables pattern of a real
-        recommender step, dense analog: engine.push_pull_group)."""
+        recommender step, dense analog: engine.push_pull_group).  Each
+        table's body is a one-table program's, inside
+        ``ps.sparse.table.<name>`` under one ``ps.sparse.group``: a device
+        trace tells the tables apart by scope."""
         key = (op, tuple((t.name, t.pack) for t in tables), batches)
         with self._mu:
             prog = self._programs.get(key)
@@ -1462,6 +1470,13 @@ class SparseEngine:
         packs = [t.pack for t in tables]
         dims = [t.dim for t in tables]
 
+        def scope(i):
+            both = contextlib.ExitStack()
+            both.enter_context(jax.named_scope("ps.sparse.group"))
+            both.enter_context(
+                jax.named_scope("ps.sparse.table." + tables[i].name))
+            return both
+
         from jax.sharding import NamedSharding
 
         store_fmts = tuple(
@@ -1481,12 +1496,12 @@ class SparseEngine:
                 stores = args[:k]
                 idxs = args[k:2 * k]
                 grads = args[2 * k:3 * k]
-                over = []
-                new = [
-                    _scatter_rows(axis, S, Rs[i], packs[i], dims[i],
-                                  s, idxs[i], grads[i], over)
-                    for i, s in enumerate(stores)
-                ]
+                new, over = [], []
+                for i, s in enumerate(stores):
+                    with scope(i):
+                        new.append(_scatter_rows(
+                            axis, S, Rs[i], packs[i], dims[i], s, idxs[i],
+                            grads[i], over))
                 return (*new, new[0][:1, :1],
                         *_counted(args[3 * k:], over))
 
@@ -1511,10 +1526,11 @@ class SparseEngine:
                 lr, eps = args[4 * k], args[4 * k + 1]
                 new_s, new_a, over = [], [], []
                 for i, (s, a) in enumerate(zip(stores, accs)):
-                    n2, a2 = _adagrad_sparse(
-                        axis, S, Rs[i], packs[i], dims[i], s, a,
-                        idxs[i], grads[i], lr, eps, over,
-                    )
+                    with scope(i):
+                        n2, a2 = _adagrad_sparse(
+                            axis, S, Rs[i], packs[i], dims[i], s, a,
+                            idxs[i], grads[i], lr, eps, over,
+                        )
                     new_s.append(n2)
                     new_a.append(a2)
                 return (*new_s, *new_a, new_s[0][:1, :1],
@@ -1539,12 +1555,12 @@ class SparseEngine:
             def body(*args):
                 stores = args[:k]
                 idxs = args[k:2 * k]
-                over = []
-                rows = [
-                    _pull_rows(axis, S, s, idxs[i], pack=packs[i],
-                               dim=dims[i], over=over)[None]   # [1, n, d]
-                    for i, s in enumerate(stores)
-                ]
+                rows, over = [], []
+                for i, s in enumerate(stores):
+                    with scope(i):
+                        rows.append(_pull_rows(
+                            axis, S, s, idxs[i], pack=packs[i], dim=dims[i],
+                            over=over)[None])                  # [1, n, d]
                 return (*rows, *_counted(args[2 * k:], over))
 
             fn = jax.shard_map(
@@ -1580,8 +1596,12 @@ class SparseEngine:
         as :meth:`push` (``handle`` applies to all)."""
         log.check(len(names) == len(indices_list) == len(grads_list),
                   "group length mismatch")
-        log.check(len(set(names)) == len(names),
-                  "duplicate table in group (stores are donated)")
+        if len(set(names)) != len(names):
+            twice = sorted({n for n in names if list(names).count(n) > 1})
+            log.check(False,
+                      f"table(s) {twice} appear twice in one grouped push: "
+                      f"a table's store is donated to the program once "
+                      f"(push its rows in one entry, or in two ops)")
         t0 = stamp()  # stage borders: see _note
         tables = [self._tables[n] for n in names]
         prepped = [
@@ -1639,6 +1659,7 @@ class SparseEngine:
         # One op with one launch, whatever it groups.
         self._note((SPARSE_ROUTE, t3, sum(map(self._route_slots, batches)),
                     -1, -1))
+        self._note((SPARSE_GROUP, t3, len(names), -1, -1))
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
         for t, batch in zip(tables, batches):
             self._observe("push", t, batch)
@@ -1674,6 +1695,7 @@ class SparseEngine:
         t3 = stamp()
         self._note((SPARSE_ROUTE, t3, sum(map(self._route_slots, batches)),
                     -1, -1))
+        self._note((SPARSE_GROUP, t3, len(names), -1, -1))
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
         return pulled
 

@@ -161,6 +161,15 @@ _STAGES_OF = ((0, 4, None),   # KV_OP: route, dispatch, -1
 # carry them and :meth:`StageClock.routed` answers for a window.
 SPARSE_ROUTE = 3
 ROUTED = ("slots", "ops")
+# A fifth kind, stageless as the fourth: ``(SPARSE_GROUP, t_end, tables, -1,
+# -1)``, noted by ``SparseEngine`` once a GROUPED op (``pull_group`` /
+# ``push_group``: several tables in one program and one launch), before its
+# ``ENGINE_OP`` note too.  ``tables`` is how many the op carried, known from
+# the call's own arguments.  Their sum and the ops that noted them ride behind
+# ``ROUTED`` in the totals' vector (``GROUPED``); :meth:`StageClock.grouped`
+# answers for a window.  A one-table op notes none.
+SPARSE_GROUP = 4
+GROUPED = ("tables", "ops")
 
 StageWindow = Tuple[Dict[str, Tuple[int, int]], int, float]
 
@@ -204,6 +213,7 @@ FORGET_NS = 1 << 32
 _READY, _RESETS = (_OCC + OCCUPANCY.index(name)
                    for name in ("ready_at_wait", "resets"))
 _ROUTED = _OCC + len(OCCUPANCY)  # where ``ROUTED`` begins in the vector
+_GROUPED = _ROUTED + len(ROUTED)  # and ``GROUPED``
 # A spell's row: OCCUPANCY up to and with ``spells``.
 _PRELAUNCH, _LAUNCH = 0, 1
 _COPY, _ROUTE, _SELECT, _PREP, _OUTSIDE = (
@@ -212,6 +222,7 @@ _SPELLS = OCCUPANCY.index("spells")
 
 OccupancyWindow = Tuple[Dict[str, int], int, float]
 RoutedWindow = Tuple[Tuple[int, int], int, float]
+GroupedWindow = RoutedWindow
 _NO_TIMES = np.empty(0, dtype=np.int64)
 
 
@@ -257,8 +268,8 @@ class StageClock:
         self.backlog = self._pending.__len__  # notes not yet folded
         self._fold_mu = threading.Lock()
         # ns, calls of each stage; then the occupancy account; then what
-        # the sparse ops routed
-        self._totals = [0] * (_ROUTED + len(ROUTED))
+        # the sparse ops routed, and what the grouped ones carried
+        self._totals = [0] * (_GROUPED + len(GROUPED))
         # slot -> the totals at its start
         self._marks: Dict[int, Tuple[int, ...]] = {}
         self._slot = -1  # the newest slot an op ended in
@@ -320,6 +331,9 @@ class StageClock:
                 routed = of_slot[of_slot[:, 0] == SPARSE_ROUTE, 2]
                 tot[_ROUTED] += int(routed.sum())
                 tot[_ROUTED + 1] += len(routed)
+                grouped = of_slot[of_slot[:, 0] == SPARSE_GROUP, 2]
+                tot[_GROUPED] += int(grouped.sum())
+                tot[_GROUPED + 1] += len(grouped)
                 # A spell is put down to the slot of the op that ended it.
                 if len(ended_in):
                     ended = spells[ended_in == slot].sum(axis=0).tolist()
@@ -521,16 +535,34 @@ class StageClock:
         (``SPARSE_ROUTE``): the batch-workspace rows a shard worked on,
         summed over the ops, and the ops."""
         self.fold()
-        return tuple(self._totals[_ROUTED:_ROUTED + len(ROUTED)])
+        return tuple(self._totals[_ROUTED:_GROUPED])
 
     def routed(self, t_lo: float, t_hi: float) -> RoutedWindow:
         """:meth:`routed_totals` over the whole slots inside ``[t_lo,
         t_hi]``, as :meth:`window` answers for the stages: ``((slots, ops),
         slots of the clock, seconds)``."""
+        return self._pair_between(_ROUTED, t_lo, t_hi)
+
+    def _pair_between(self, at: int, t_lo: float, t_hi: float):
+        """A sum and its ops at ``at`` of the totals' vector (``ROUTED``,
+        ``GROUPED``) over the whole slots inside ``[t_lo, t_hi]``."""
         grown, slots, seconds = self._between(t_lo, t_hi)
         if not slots:
             return (0, 0), 0, 0.0
-        return tuple(grown[_ROUTED:_ROUTED + len(ROUTED)]), slots, seconds
+        return tuple(grown[at:at + 2]), slots, seconds
+
+    def grouped_totals(self) -> Tuple[int, int]:
+        """``(tables, ops)`` of the grouped sparse ops since the process
+        started (``SPARSE_GROUP``): the tables they carried, summed over the
+        ops, and the ops."""
+        self.fold()
+        return tuple(self._totals[_GROUPED:])
+
+    def grouped(self, t_lo: float, t_hi: float) -> GroupedWindow:
+        """:meth:`grouped_totals` over the whole slots inside ``[t_lo,
+        t_hi]``, as :meth:`routed` answers for the slots: ``((tables, ops),
+        slots of the clock, seconds)``."""
+        return self._pair_between(_GROUPED, t_lo, t_hi)
 
     def export(self, registry) -> None:
         """Lazily sampled gauges in a node's ``Registry``, so
@@ -586,6 +618,12 @@ class _NullStageClock:
         return 0, 0
 
     def routed(self, t_lo: float, t_hi: float) -> RoutedWindow:
+        return (0, 0), 0, 0.0
+
+    def grouped_totals(self) -> Tuple[int, int]:
+        return 0, 0
+
+    def grouped(self, t_lo: float, t_hi: float) -> GroupedWindow:
         return (0, 0), 0, 0.0
 
     def export(self, registry) -> None:

@@ -105,6 +105,53 @@ def test_the_lane_packed_cell_names_its_table_traffic_and_driver(harness):
     assert config["guarantees"].startswith(emb["guarantees"])
 
 
+def test_the_many_tables_cell_names_its_tables_traffic_and_driver(harness):
+    """``dlrm-terabyte-26tables.zipf`` (PR 50): the same Terabyte run with
+    its 26 tables kept as 26 tables, under a driver of its own whose step is
+    one grouped pull and one grouped push, and the four per-layer metrics
+    that only this cell reports."""
+    cell = harness.load_cell("dlrm-terabyte-26tables.zipf")
+    config, traffic = cell.config, cell.traffic
+    sibling = harness.load_cell("dlrm-terabyte-emb64.zipf")
+    assert config["kind"] == "sparse" and config["server_handle"] == "sum"
+    assert config["reduced"] == [] and config["dtype"] == "float32"
+    tables = config["tables"]
+    assert [name for name, _ in tables] == [f"emb{i:02d}" for i in range(26)]
+    rows = [r for _, r in tables]
+    assert sum(rows) == config["rows"] == 54_063_992
+    assert (min(rows), max(rows), rows.count(10_000_000)) == (3, 10**7, 5)
+    # No width, batch, skew or guarantee of the sibling is changed.
+    assert config["dim"] == sibling.config["dim"] == 64
+    assert config["sizes"] == sibling.config["sizes"]
+    assert config["guarantees"].startswith(sibling.config["guarantees"])
+    assert config["limits"]["first3_err"] <= 1.5e-4
+    assert config["limits"]["final_err"] <= 2e-2
+    assert traffic["name"] == "zipf-tables-2048x26"
+    assert traffic["driver"] == "sparse_tables_pull_push"
+    assert traffic["lookups_per_table"] == config["sizes"]["mini_batch"]
+    assert traffic["lookups_per_table"] * len(tables) \
+        == traffic["lookups_per_worker"] \
+        == sibling.traffic["lookups_per_worker"] == 53_248
+    for key in ("pool_batches", "zipf_constant", "gradient_scale",
+                "warm_steps", "step_deadline_s", "trace"):
+        assert traffic[key] == sibling.traffic[key], key
+    entry = next(c for c in BENCHMARK["configs"]
+                 if c["name"] == "dlrm-terabyte-26tables")
+    assert entry["source"] == config["source"] and entry["reduced"] == []
+    assert len(config["source"]) <= 200
+    # The whole deployment on one chip, two rows to a 128-lane physical row.
+    held = sum(-(-r // 2) for r in rows) * 512
+    assert held == 13_840_384_000 and 0.25 * 16e9 < held < 16e9
+    driver = harness.resolve(cell)
+    assert driver is not harness.load_driver(cell.search, "sparse_pull_push")
+    names = {m["name"] for m in cell.per_layer}
+    assert {"sparse_tables_per_op", "tables_combine_ms", "tables_write_ms",
+            "sparse_device_ops_per_step", "roofline_share", "busy_ms",
+            "launches_per_step", "ops_per_step"} <= names
+    assert not {"combine_ms", "table_write_ms", "packed_write_ms",
+                "packed_combine_ms", "route_ms"} & names
+
+
 # What an addition PR may do: append entries, each at the end of its list,
 # and add files under ``paths``.  The occupancy account's three metrics
 # (PR 37) came that way.

@@ -206,6 +206,18 @@ def test_the_lowered_programs_carry_the_exchanges_scopes(cluster, op, group):
     # operation's place ``<scopes>/<primitive>``, and a branch of the
     # program's one conditional ahead of the scopes.
     places = set(re.findall(r'loc\("([^"]*ps\.sparse\.[^"]*)"', text))
+    if group:
+        # A group program's bodies lie a table each inside
+        # ``ps.sparse.group/ps.sparse.table.<name>`` (PR 50); within it a
+        # body is the one-table program's.
+        table = re.compile(r"^ps\.sparse\.group/ps\.sparse\.table\."
+                           r"(emb|twin)/")
+        # (a function the body calls names its places from its own start)
+        assert {m.group(1) for m in map(table.match, places) if m} == {
+            "emb", "twin"}
+        assert all(table.match(p) for p in places
+                   if not p.startswith("cond/")), places
+        places = {table.sub("", p) for p in places}
     routed = {p[len(ROUTED):] for p in places if p.startswith(ROUTED)}
     gathered = {p[len(GATHERED):] for p in places if p.startswith(GATHERED)}
     last = ".grads" if op == "push" else ".rows"
