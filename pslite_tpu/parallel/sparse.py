@@ -88,23 +88,36 @@ class SparseTable:
 
 
 @dataclass(eq=False, slots=True)
-class _BoundPush:
-    """What every ``push`` of one table under one handle at one batch size
-    works out the same way, worked out once by ``SparseEngine._bind_push``
-    (the sparse twin of the dense engine's ``_BoundOp``).  Invariants
-    only: never a store, an accumulator or a gradient."""
+class _Bound:
+    """What every sparse op of one key works out the same way, worked out
+    once by ``SparseEngine._bind`` (the sparse twin of the dense engine's
+    ``_BoundOp``): a ``push`` of ``(name, handle, batch)``, a ``push_group``
+    or ``pull_group`` of ``(names, handle, batches)``.  A table's record is
+    the group of one, on the one-table program.  Invariants only: never a
+    store, an accumulator, an index or a gradient."""
 
     prog: Callable
-    kind: Optional[str]  # the handle's kind; None: the plain sum
+    kind: Optional[str]  # the handle's kind; None: the plain sum, or a pull
     params: tuple  # the handle's numbers as device scalars; () for the sum
-    row_kernel: bool  # the program's table write is ops/row_add.py
-    segsum_kernel: bool  # it sums its segments with ops/segment_sum.py
-    acc_kernel: bool  # it updates the accumulator with ops/acc_update.py
-    packed: bool  # the table is lane-packed (pack > 1)
-    # Batch-workspace rows a shard's bound body combines (:func:`_slots`:
-    # S * C where the exchange is routed by owner, W * batch gathered).
+    # What a push adds to the engine's counters: whether the program writes
+    # any of its tables through ops/row_add.py, sums any combine's segments
+    # with ops/segment_sum.py, updates any accumulator with
+    # ops/acc_update.py, and whether any table is lane-packed (pack > 1).
+    row_kernel: bool
+    segsum_kernel: bool
+    acc_kernel: bool
+    packed: bool
+    # Batch-workspace rows a shard's bound bodies work on, the tables' sum
+    # (:func:`_slots`: S * C where an exchange is routed by owner, W * batch
+    # gathered).
     slots: int
     routed: bool  # the program has both bodies and threads the overflow count
+    order: tuple  # the tables' names, each once, in the order of their locks
+    dtypes: tuple  # a table: the numpy dtype its gradient lies in
+    payload: int  # bytes an op moves (``push_bytes`` / ``pull_bytes``)
+
+
+_INT32 = np.dtype(np.int32)
 
 
 def _interleave_rows(glob, num_rows: int, rps: int, S: int, dtype):
@@ -162,13 +175,21 @@ def _unpack_host(phys, rps: int, S: int, pack: int, dim: int):
 
 
 def _lies_as(arr, sharding, dtype, ndim: int) -> bool:
-    """Whether ``arr`` is a device array of ``dtype`` laid out as
+    """Whether ``arr`` is a device array of the numpy ``dtype`` laid out as
     ``sharding`` (the same one, not merely an equivalent: the program's
-    cache is keyed by it): what ``jax.device_put(arr, sharding)`` returns."""
-    import jax
+    cache is keyed by it): what ``jax.device_put(arr, sharding)`` returns.
+    A host array has no ``sharding``."""
+    return (getattr(arr, "sharding", None) == sharding and arr.ndim == ndim
+            and arr.dtype == dtype)
 
-    return (isinstance(arr, jax.Array) and arr.ndim == ndim
-            and arr.dtype == dtype and arr.sharding == sharding)
+
+def _input_shardings(mesh, axis):
+    """The two layouts a sparse op's inputs lie in: ids ``[W, n]`` and
+    gradient rows ``[W, n, d]``, a worker's batch on its device."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return (NamedSharding(mesh, P(axis, None)),
+            NamedSharding(mesh, P(axis, None, None)))
 
 
 def _store_out_format(store, mesh, axis):
@@ -856,10 +877,16 @@ class SparseEngine:
         # as the table), created lazily by push(handle="row_adagrad:...").
         self._acc: Dict[str, object] = {}
         self._programs: Dict[tuple, Callable] = {}
-        # (table, handle, batch) -> see _bind_push.  Dropped with the
-        # programs by a reshard, and a table's by a new registration of
-        # its name or a change of its packing.
-        self._bound: Dict[tuple, _BoundPush] = {}
+        # (table, handle, batch) of a push, (op, tables, handle, batches)
+        # of a grouped op -> see _bind.  Dropped with the programs by a
+        # reshard, and a table's, with every group's it is a member of, by
+        # a new registration of its name or a change of its packing.
+        self._bound: Dict[tuple, _Bound] = {}
+        self.group_binds = 0  # grouped ops' records built (see export)
+        # What an op's inputs are compared with and placed as: ids
+        # ``[W, n]`` and gradients ``[W, n, d]`` over the worker axis.
+        self._idx_sharding, self._g_sharding = _input_shardings(
+            mesh, axis_name)
         # Pushes that ran under a stateful handle, and pushes, under a
         # handle or not, whose program writes the table through
         # ops/row_add.py, sums its duplicates with ops/segment_sum.py, or
@@ -931,8 +958,9 @@ class SparseEngine:
         return table
 
     def _unbind(self, name: str) -> None:
-        """Drop the table's push records (call with ``_mu`` held)."""
-        for key in [k for k in self._bound if k[0] == name]:
+        """Drop the records of the table and of every group it is a member
+        of (call with ``_mu`` held)."""
+        for key in [k for k, b in self._bound.items() if name in b.order]:
             del self._bound[key]
 
     def export(self, registry) -> None:
@@ -962,6 +990,10 @@ class SparseEngine:
                        fn=lambda: self._clock.grouped_totals()[1])
         registry.gauge("engine.sparse.group.tables",
                        fn=lambda: self._clock.grouped_totals()[0])
+        # Records built for grouped ops (:meth:`_bind`): this engine's.  The
+        # bound share of a window's grouped ops is 1 - binds / ops.
+        registry.gauge("engine.sparse.group.binds",
+                       fn=lambda: self.group_binds)
 
     def route_overflows(self) -> int:
         """Ops of this engine so far whose batch did not fit the routed
@@ -1131,58 +1163,62 @@ class SparseEngine:
             self.mesh, host_arr, sharding, self._multiprocess
         )
 
-    def _prep(self, table: SparseTable, indices, grads=None):
-        """[W, n] indices (+ [W, n, d] grads) sharded over the worker axis.
+    def _prep_ids(self, indices):
+        """``[W, n]`` row ids as the programs take them: int32, sharded over
+        the worker axis.
 
-        On a multi-process mesh the host inputs carry only THIS process's
-        worker rows ([local, n] / [local, n, d])."""
+        On a multi-process mesh the host input carries only THIS process's
+        worker rows (``[local, n]``; :meth:`_prep_grads`: ``[local, n, d]``)."""
         import jax
-        import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
 
-        idx_sharding = NamedSharding(self.mesh, P(self.axis, None))
-        g_sharding = NamedSharding(self.mesh, P(self.axis, None, None))
+        sharding = self._idx_sharding
         if self._is_multiprocess():
             idx = np.ascontiguousarray(np.asarray(indices, dtype=np.int32))
-            local = self._local_shards()
-            log.check_eq(int(idx.shape[0]), local,
+            log.check_eq(int(idx.shape[0]), self._local_shards(),
                          "bad local worker dim (rows = this process's "
                          "devices on a multi-process mesh)")
-            idx_sh = jax.make_array_from_process_local_data(
-                idx_sharding, idx, (self.num_shards,) + idx.shape[1:]
+            return jax.make_array_from_process_local_data(
+                sharding, idx, (self.num_shards,) + idx.shape[1:]
             )
-            if grads is None:
-                return idx_sh, None
-            g = np.ascontiguousarray(
-                np.asarray(grads, dtype=np.dtype(table.dtype))
-            )
-            g_sh = jax.make_array_from_process_local_data(
-                g_sharding, g, (self.num_shards,) + g.shape[1:]
-            )
-            return idx_sh, g_sh
         # Host inputs are cast on the host and placed row by row, each
         # worker's batch straight onto its device (see staging_xp).  A
         # device array that already lies as the program takes it (a
         # trainer's own batch) is passed on as it is: the cast and the
         # placement would both hand it back, ~0.13 ms of the host later.
-        placed = _lies_as(indices, idx_sharding, jnp.int32, 2)
+        placed = _lies_as(indices, sharding, _INT32, 2)
         idx = (indices if placed
-               else staging_xp(indices).asarray(indices, dtype=jnp.int32))
+               else staging_xp(indices).asarray(indices, dtype=_INT32))
         log.check_eq(int(idx.shape[0]), self.num_shards, "bad worker dim")
-        idx_sh = idx if placed else jax.device_put(idx, idx_sharding)
-        if grads is None:
-            return idx_sh, None
-        if _lies_as(grads, g_sharding, table.dtype, 3):
-            return idx_sh, grads
-        g = staging_xp(grads).asarray(grads, dtype=table.dtype)
-        g_sh = jax.device_put(g, g_sharding)
-        return idx_sh, g_sh
+        return idx if placed else jax.device_put(idx, sharding)
 
-    def _observe(self, op: str, table: SparseTable, batch: int) -> None:
-        payload = (
-            self.num_shards * batch * table.dim
-            * np.dtype(table.dtype).itemsize
-        )
+    def _prep_grads(self, dtype, grads):
+        """``[W, n, d]`` gradient rows of a table of the numpy ``dtype`` as
+        its push programs take them (see :meth:`_prep_ids`)."""
+        import jax
+
+        sharding = self._g_sharding
+        if self._is_multiprocess():
+            g = np.ascontiguousarray(np.asarray(grads, dtype=dtype))
+            return jax.make_array_from_process_local_data(
+                sharding, g, (self.num_shards,) + g.shape[1:]
+            )
+        if _lies_as(grads, sharding, dtype, 3):
+            return grads
+        return jax.device_put(
+            staging_xp(grads).asarray(grads, dtype=dtype), sharding)
+
+    def _prep(self, table: SparseTable, indices, grads=None):
+        """A one-table op's ``(ids, gradients or None)``, placed."""
+        return (self._prep_ids(indices),
+                None if grads is None
+                else self._prep_grads(np.dtype(table.dtype), grads))
+
+    def _payload(self, table: SparseTable, batch: int) -> int:
+        """Bytes an op of ``batch`` lookups a worker moves of ``table``."""
+        return (self.num_shards * batch * table.dim
+                * np.dtype(table.dtype).itemsize)
+
+    def _observe(self, op: str, payload: int) -> None:
         with self._counter_mu:
             if op == "push":
                 self.push_bytes += payload
@@ -1314,29 +1350,62 @@ class SparseEngine:
         kind, params = self._parse_handle(handle)
         return kind, tuple(jnp.float32(p) for p in params)
 
-    def _bind_push(self, name: str, handle: Optional[str], batch: int
-                   ) -> _BoundPush:
-        """Stage ``select`` of the first ``push(name, ., ., handle)`` at
-        this batch size (and of the first after a reshard, a new
-        registration of ``name`` or a change of its packing dropped the
-        record): the handle parsed, its numbers placed as device scalars,
-        the program.  An unknown handle fails here, by name.  Call with
-        the table's lock held."""
-        table = self._tables[name]
+    def _bind(self, op: str, names, handle: Optional[str], batches
+              ) -> _Bound:
+        """Stage ``select`` of the first op of this key (and of the first
+        after a reshard, a new registration of a member or a change of its
+        packing dropped the record): ``push(name, ., ., handle)`` at a
+        batch size (``names`` a name, ``batches`` a number: the one-table
+        program) or a grouped op of ``names`` at ``batches`` (tuples: the
+        group program).  The handle parsed, its numbers placed as device
+        scalars, the program, and what an op adds to each counter.  A
+        table named twice in a grouped push and an unknown handle fail
+        here, by name.  Call with the tables' locks held."""
+        group = type(names) is tuple
+        key = (op, names, handle, batches)
+        if not group:
+            key, names, batches = key[1:], (names,), (batches,)
+        if op == "push" and len(set(names)) != len(names):
+            twice = sorted({n for n in names if names.count(n) > 1})
+            log.check(False,
+                      f"table(s) {twice} appear twice in one grouped push: "
+                      f"a table's store is donated to the program once "
+                      f"(push its rows in one entry, or in two ops)")
+        tables = [self._tables[n] for n in names]
         kind, params = (None, ()) if handle is None \
             else self._handle_scalars(handle)
-        bound = _BoundPush(
-            self._sparse_program("push" if kind is None else "push_" + kind,
-                                 table, batch),
-            kind, params, self._row_kernel(table),
-            self._segsum_kernel(table, kind is not None),
-            kind is not None and self._acc_kernel(table, batch),
-            table.pack != 1, self._route_slots(batch), self._routed(batch))
+        stateful = kind is not None
+        prog_op = "push_" + kind if stateful else op
+        push = op == "push"
+        bound = _Bound(
+            self._sparse_group_program(prog_op, tables, batches) if group
+            else self._sparse_program(prog_op, tables[0], batches[0]),
+            kind, params,
+            push and any(map(self._row_kernel, tables)),
+            push and any(self._segsum_kernel(t, stateful) for t in tables),
+            stateful and any(map(self._acc_kernel, tables, batches)),
+            push and any(t.pack != 1 for t in tables),
+            sum(map(self._route_slots, batches)),
+            self._group_routed(batches),
+            tuple(sorted(set(names))),
+            tuple(np.dtype(t.dtype) for t in tables),
+            sum(map(self._payload, tables, batches)))
         with self._mu:
-            # A new registration meanwhile: the next push binds.
-            if self._tables.get(name) is table:
-                self._bound[(name, handle, batch)] = bound
+            # A new registration meanwhile: the next op binds.
+            if all(self._tables.get(t.name) is t for t in tables):
+                self._bound[key] = bound
+            self.group_binds += group
         return bound
+
+    def _pushed(self, b: _Bound) -> None:
+        """One push, whatever it groups: counted where it ran under a
+        stateful handle, where a kernel serves any of its tables, where any
+        is lane-packed (see export)."""
+        self.stateful_pushes += b.kind is not None
+        self.row_kernel_pushes += b.row_kernel
+        self.segsum_kernel_pushes += b.segsum_kernel
+        self.acc_kernel_pushes += b.acc_kernel
+        self.packed_pushes += b.packed
 
     def _route_slots(self, batch: int) -> int:
         """The rows of the batch workspace one shard's bound body works on
@@ -1399,8 +1468,8 @@ class SparseEngine:
         dense engine's optimizer handles.
 
         Bound once, launched many times: what no two pushes of ``(name,
-        handle, batch)`` differ in is a :class:`_BoundPush` that the first
-        builds (:meth:`_bind_push`) and the others look up."""
+        handle, batch)`` differ in is a :class:`_Bound` that the first
+        builds (:meth:`_bind`) and the others look up."""
         t0 = stamp()  # stage borders: see _note
         table = self._tables[name]
         idx, g = self._prep(table, indices, grads)
@@ -1411,7 +1480,7 @@ class SparseEngine:
         # prep, select, launch, and select has the wait for the lock).
         with self._table_mu[name]:
             b = (self._bound.get((name, handle, batch))
-                 or self._bind_push(name, handle, batch))
+                 or self._bind("push", name, handle, batch))
             t2 = stamp()  # select | launch
             count = (self._overflow_count(name),) if b.routed else ()
             if b.kind is None:
@@ -1423,14 +1492,10 @@ class SparseEngine:
                 self._stores[name], self._acc[name], token, *count = b.prog(
                     self._stores[name], self._acc[name], idx, g, *b.params,
                     *count)
-                self.stateful_pushes += 1
             if count:
                 self._overflow[name] = count[0]
-            self.row_kernel_pushes += b.row_kernel
-            self.segsum_kernel_pushes += b.segsum_kernel
-            self.acc_kernel_pushes += b.acc_kernel
-            self.packed_pushes += b.packed
-        self._observe("push", table, batch)
+            self._pushed(b)
+        self._observe("push", b.payload)
         t3 = stamp()
         self._note((SPARSE_ROUTE, t3, b.slots, -1, -1))
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
@@ -1580,121 +1645,116 @@ class SparseEngine:
         count: any of its tables' exchanges is routed by owner."""
         return any(map(self._routed, batches))
 
-    def _lock_tables(self, names):
-        ordered = sorted(set(names))
+    def _lock_tables(self, ordered) -> None:
         for n in ordered:
             self._table_mu[n].acquire()
-        return ordered
 
-    def _unlock_tables(self, ordered):
+    def _unlock_tables(self, ordered) -> None:
         for n in reversed(ordered):
             self._table_mu[n].release()
+
+    def _group_inputs(self, op: str, names, handle, indices_list,
+                      grads_list=None):
+        """Stage ``prep`` of a grouped op: its record's key, the tables'
+        names in the order of their locks, and the inputs as the program
+        takes them.  An input that lies so already (a trainer's own batch)
+        costs a comparison; any other is cast and placed, that table alone
+        (:meth:`_prep_ids`, :meth:`_prep_grads`).  The record, where the
+        key has one, says what a gradient is compared with and in what order
+        the locks go; the op itself looks it up again under the locks."""
+        W, sharding = self.num_shards, self._idx_sharding
+        idxs = [i if _lies_as(i, sharding, _INT32, 2) and i.shape[0] == W
+                else self._prep_ids(i) for i in indices_list]
+        names = tuple(names)
+        key = (op, names, handle, tuple([i.shape[1] for i in idxs]))
+        b = self._bound.get(key)
+        ordered = b.order if b is not None else tuple(sorted(set(names)))
+        if grads_list is None:
+            return key, ordered, idxs, None
+        dtypes = b.dtypes if b is not None else [
+            np.dtype(self._tables[n].dtype) for n in names]
+        sharding = self._g_sharding
+        gs = [g if _lies_as(g, sharding, dtype, 3)
+              else self._prep_grads(dtype, g)
+              for g, dtype in zip(grads_list, dtypes)]
+        return key, ordered, idxs, gs
 
     def push_group(self, names, indices_list, grads_list,
                    handle: str = None):
         """Push SEVERAL tables in one dispatch; same semantics per table
-        as :meth:`push` (``handle`` applies to all)."""
+        as :meth:`push` (``handle`` applies to all), and bound once as a
+        push is: the group's :class:`_Bound` by ``(names, handle,
+        batches)``."""
         log.check(len(names) == len(indices_list) == len(grads_list),
                   "group length mismatch")
-        if len(set(names)) != len(names):
-            twice = sorted({n for n in names if list(names).count(n) > 1})
-            log.check(False,
-                      f"table(s) {twice} appear twice in one grouped push: "
-                      f"a table's store is donated to the program once "
-                      f"(push its rows in one entry, or in two ops)")
         t0 = stamp()  # stage borders: see _note
-        tables = [self._tables[n] for n in names]
-        prepped = [
-            self._prep(t, i, g)
-            for t, i, g in zip(tables, indices_list, grads_list)
-        ]
-        idxs = [p[0] for p in prepped]
-        gs = [p[1] for p in prepped]
-        batches = tuple(int(i.shape[1]) for i in idxs)
+        key, ordered, idxs, gs = self._group_inputs(
+            "push", names, handle, indices_list, grads_list)
         t1 = stamp()  # prep | select
-        ordered = self._lock_tables(names)
+        self._lock_tables(ordered)
         try:
-            prog = self._sparse_group_program(
-                "push" if handle is None else "push_row_adagrad",
-                tables, batches,
-            )
+            # Under the locks, as table.pack is resolved (see push).
+            b = self._bound.get(key) or self._bind(*key)
             t2 = stamp()  # select | launch
-            kk = len(names)
+            stores, accs = self._stores, self._acc
             # The group's overflow count is its first table's.
-            count = ((self._overflow_count(names[0]),)
-                     if self._group_routed(batches) else ())
-            if handle is None:
-                outs = prog(*[self._stores[n] for n in names],
-                            *idxs, *gs, *count)
-                for i, n in enumerate(names):
-                    self._stores[n] = outs[i]
-                token = outs[kk]
+            count = (self._overflow_count(names[0]),) if b.routed else ()
+            if b.kind is None:
+                outs = b.prog(*[stores[n] for n in names], *idxs, *gs,
+                              *count)
             else:
-                _, params = self._handle_scalars(handle)
-                for n, t in zip(names, tables):
-                    self._ensure_acc(n, t)
-                outs = prog(
-                    *[self._stores[n] for n in names],
-                    *[self._acc[n] for n in names],
-                    *idxs, *gs, *params, *count,
-                )
-                for i, n in enumerate(names):
-                    self._stores[n] = outs[i]
-                    self._acc[n] = outs[kk + i]
-                token = outs[2 * kk]
-                self.stateful_pushes += 1
+                for n in names:
+                    if n not in accs:
+                        self._ensure_acc(n, self._tables[n])
+                outs = b.prog(*[stores[n] for n in names],
+                              *[accs[n] for n in names],
+                              *idxs, *gs, *b.params, *count)
+                accs.update(zip(names, outs[len(names):]))
+            # The program's results: the stores, the accumulators under a
+            # handle, the token, the count where it took one.
+            stores.update(zip(names, outs))
             if count:
                 self._overflow[names[0]] = outs[-1]
-            # One push, whatever it groups: counted where a kernel
-            # serves any of its tables.
-            self.row_kernel_pushes += any(map(self._row_kernel, tables))
-            self.segsum_kernel_pushes += any(
-                self._segsum_kernel(t, handle is not None) for t in tables)
-            self.acc_kernel_pushes += handle is not None and any(
-                map(self._acc_kernel, tables, batches))
-            self.packed_pushes += any(t.pack != 1 for t in tables)
+            token = outs[-1 - len(count)]
+            self._pushed(b)
         finally:
             self._unlock_tables(ordered)
         t3 = stamp()
         # One op with one launch, whatever it groups.
-        self._note((SPARSE_ROUTE, t3, sum(map(self._route_slots, batches)),
-                    -1, -1))
+        self._note((SPARSE_ROUTE, t3, b.slots, -1, -1))
         self._note((SPARSE_GROUP, t3, len(names), -1, -1))
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
-        for t, batch in zip(tables, batches):
-            self._observe("push", t, batch)
+        self._observe("push", b.payload)
         return token
 
     def pull_group(self, names, indices_list):
         """Pull SEVERAL tables in one dispatch; returns the list of
-        [W, n_i, d_i] arrays in ``names`` order."""
+        [W, n_i, d_i] arrays in ``names`` order.  Bound once, as
+        :meth:`push_group`."""
         log.check(len(names) == len(indices_list), "group length mismatch")
         t0 = stamp()  # stage borders: see _note
-        tables = [self._tables[n] for n in names]
-        idxs = [self._prep(t, i)[0]
-                for t, i in zip(tables, indices_list)]
-        batches = tuple(int(i.shape[1]) for i in idxs)
+        key, ordered, idxs, _ = self._group_inputs(
+            "pull", names, None, indices_list)
         t1 = stamp()  # prep | select
-        ordered = self._lock_tables(names)
+        self._lock_tables(ordered)
         try:
-            # Resolve table.pack under the locks (see push).
-            prog = self._sparse_group_program("pull", tables, batches)
+            # Under the locks, as table.pack is resolved (see push).
+            b = self._bound.get(key) or self._bind(*key)
             t2 = stamp()  # select | launch
             # The program's own results, [W, n_i, d_i] each: a reshape out
             # here would be one more launch and one more copy of the batch.
-            if self._group_routed(batches):
-                *pulled, self._overflow[names[0]] = prog(
+            if b.routed:
+                *pulled, self._overflow[names[0]] = b.prog(
                     *[self._stores[n] for n in names], *idxs,
                     self._overflow_count(names[0]))
             else:
-                pulled = list(prog(*[self._stores[n] for n in names], *idxs))
+                pulled = list(b.prog(*[self._stores[n] for n in names],
+                                     *idxs))
         finally:
             self._unlock_tables(ordered)
-        for t, batch in zip(tables, batches):
-            self._observe("pull", t, batch)
+        self._observe("pull", b.payload)
         t3 = stamp()
-        self._note((SPARSE_ROUTE, t3, sum(map(self._route_slots, batches)),
-                    -1, -1))
+        self._note((SPARSE_ROUTE, t3, b.slots, -1, -1))
         self._note((SPARSE_GROUP, t3, len(names), -1, -1))
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
         return pulled
@@ -1718,7 +1778,7 @@ class SparseEngine:
                     self._stores[name], idx, self._overflow_count(name))
             else:
                 pulled = prog(self._stores[name], idx)
-        self._observe("pull", table, batch)
+        self._observe("pull", self._payload(table, batch))
         t3 = stamp()
         self._note((SPARSE_ROUTE, t3, self._route_slots(batch), -1, -1))
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
@@ -1964,7 +2024,8 @@ class SparseEngine:
             overflows = self._overflow_settled + sum(
                 map(self._overflows_of, names))
             new_num_shards = mesh.shape[axis]
-            row_sharding = NamedSharding(mesh, P(axis, None))
+            # A store lies as the ids do.
+            row_sharding, g_sharding = _input_shardings(mesh, axis)
             acc_sharding = NamedSharding(mesh, P(axis))
             staged = {}
             for n in names:
@@ -2008,6 +2069,8 @@ class SparseEngine:
                 )
                 self._overflow_settled = overflows
                 self._overflow = {}
+                self._idx_sharding, self._g_sharding = (row_sharding,
+                                                        g_sharding)
                 with self._mu:
                     self._programs.clear()
                     self._bound.clear()

@@ -69,7 +69,12 @@ def _spy(eng, op="pull"):
     keys = [k for k in eng._programs if k[0] == op]
     assert keys
     for k in keys:
-        eng._programs[k] = wrap(eng._programs[k])
+        prog = eng._programs[k]
+        eng._programs[k] = wrap(prog)
+        # A grouped op's record holds its program (``SparseEngine._bind``).
+        for b in eng._bound.values():
+            if b.prog is prog:
+                b.prog = eng._programs[k]
     return returned
 
 
