@@ -996,7 +996,8 @@ class KVWorker:
         first of ``args``), the engine's ``op(name, *args)``, which notes
         ``select``, ``prep`` and ``launch`` itself, then ``dispatch``,
         the rest of this method.  While a profiler session runs the op
-        lies in a ``ps.kv.op`` span, whose metadata also names the kind
+        lies in a ``ps.kv.op`` span, whose metadata names the op's kind
+        (``op``, one of ``profiling.LAUNCH_OPS``) and the kind
         of the server handle: the one a call brought (``push_sparse``; the
         engine is given it among ``args``), for a dense op the engine's
         own (``adam``, ``lamb``, ``muon``).  Callers pass everything by
@@ -1084,7 +1085,11 @@ class KVWorker:
             if handle is None and keys is not None:
                 # A dense op runs under the engine's own handle.
                 handle = self.engine._server_handle
-            meta = {"ts": ts, "name": name}
+            # Which of ``LAUNCH_OPS`` it is, by the method it was handed
+            # (a grouped op is its kind; ``_engine_pull`` is the dense pull).
+            kind = op.__name__.rpartition("engine_")[2].removesuffix("_group")
+            meta = {"ts": ts, "name": name,
+                    "op": ("sparse." if keys is None else "dense.") + kind}
             if isinstance(handle, str):
                 meta["handle"] = handle.partition(":")[0]
             if tables:

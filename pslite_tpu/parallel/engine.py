@@ -37,7 +37,8 @@ from typing import Callable, Dict, Optional, Union
 import numpy as np
 
 from ..utils import logging as log
-from ..utils.profiling import ENGINE_OP, stage_clock, stamp
+from ..utils.profiling import (ENGINE_OP, LAUNCH, LAUNCH_SHIFT, launched,
+                               stage_clock, stamp)
 from .placement import staging_xp
 
 
@@ -169,6 +170,14 @@ class _BoundOp:
     # The pulled array carries padding to slice off (a program shared by
     # every bucket of a length; a bucket's own cuts inside: _bind).
     cut: bool
+    # The last integer of the op's LAUNCH note (``profiling.launched``): the
+    # arrays ``prog`` takes and gives, over the op's kind.
+    launched: int
+
+
+# The same of the ops that are not bound: what they count themselves goes
+# over these.
+_PUSH_PULL, _PULL = launched("dense.push_pull", 0), launched("dense.pull", 0)
 
 
 def _aggregate(grads_l, axis, worker_axis=None):
@@ -406,8 +415,9 @@ class CollectiveEngine:
         # plane, surfaced next to Van.send_bytes/recv_bytes; host time per
         # stage of an op goes to the process's StageClock.
         self._clock = stage_clock()
-        # An op notes (ENGINE_OP, t_end, select ns, prep ns, launch ns): one
-        # C call, whatever the op groups or replays (see StageClock).
+        # An op notes (ENGINE_OP, t_end, select ns, prep ns, launch ns) and,
+        # before it, what its launch was made of (LAUNCH): a C call each,
+        # whatever the op groups or replays (see StageClock).
         self._note = self._clock.note
         self.push_bytes = 0
         self.pull_bytes = 0
@@ -1152,6 +1162,15 @@ class CollectiveEngine:
         jitted = jax.jit(fn, donate_argnums=tuple(range(1 + n_state)))
         return self._keep(key, jitted)
 
+    def _n_state(self, handle: str, bucket: DenseBucket) -> int:
+        """How many arrays :meth:`_ensure_opt_state` keeps for ``bucket``
+        under ``handle``, which its program takes and gives beside the
+        store."""
+        kind = handle.split(":", 1)[0]
+        if kind == "muon":
+            return len(self._muon_plan(bucket).chunks) + 3
+        return 1 if kind in ("sgd_momentum", "adagrad") else 3
+
     def _ensure_opt_state(self, name: str, handle: str, bucket) -> None:
         """Allocate (or validate) the bucket's optimizer state for
         ``handle`` (or its kind alone).  Call with the bucket lock held."""
@@ -1609,6 +1628,9 @@ class CollectiveEngine:
             op = "push" if push else "push_pull_zc" if zc else "push_pull"
             prog = self._program(op, bucket.padded_len, bucket.dtype,
                                  handle_key)
+        # The program takes the store, the state and the gradient, and gives
+        # the store, the state and, but in place, the pulled array or a token.
+        held = 1 + (self._n_state(resolved, bucket) if stateful else 0)
         bound = _BoundOp(
             op="push" if push else "push_pull", bucket=bucket,
             lock=self._bucket_mu[name], prog=prog, prep=prep,
@@ -1617,6 +1639,8 @@ class CollectiveEngine:
             state_kind=resolved.split(":", 1)[0] if stateful else None,
             zc=zc, cut=not (push or zc or own
                             or bucket.padded_len == bucket.total_len),
+            launched=launched("dense.push" if push else "dense.push_pull",
+                              2 * held + 1 + (not zc)),
         )
         with self._mu:
             # A reshard or a new registration meanwhile: the next op binds.
@@ -1746,21 +1770,28 @@ class CollectiveEngine:
             if b.state_kind is not None:
                 if self._opt_kinds.get(name) != b.state_kind:
                     self._ensure_opt_state(name, b.state_kind, b.bucket)
+                c0 = stamp()  # the jitted call alone: see LAUNCH
                 outs = b.prog(
                     self._stores[name], *self._opt_states[name], g
                 )
+                c1 = stamp()
                 n_state = len(self._opt_states[name])
                 self._stores[name] = outs[0]
                 self._opt_states[name] = tuple(outs[1:1 + n_state])
                 pulled = outs[0] if b.zc else outs[-1]
             elif b.zc:
+                c0 = stamp()
                 pulled = self._stores[name] = b.prog(self._stores[name], g)
+                c1 = stamp()
             else:
+                c0 = stamp()
                 self._stores[name], pulled = b.prog(self._stores[name], g)
+                c1 = stamp()
             if b.cut:
                 pulled = pulled[: b.bucket.total_len]
         self._observe(b.op, b.bucket)
         t3 = stamp()
+        self._note((LAUNCH, t3, c1 - c0, t3 - t2, b.launched))
         self._note((ENGINE_OP, t3, t1 - t0, t2 - t1, t3 - t2))
         return pulled
 
@@ -1814,7 +1845,9 @@ class CollectiveEngine:
         for n in ordered:
             self._bucket_mu[n].acquire()
         try:
+            c0 = stamp()  # the jitted call alone: see LAUNCH
             outs = prog(*[self._stores[n] for n in names], *gs)
+            c1 = stamp()
             k = len(names)
             for i, n in enumerate(names):
                 self._stores[n] = outs[i]
@@ -1825,6 +1858,9 @@ class CollectiveEngine:
         for b in buckets:
             self._observe("push_pull", b)
         t3 = stamp()
+        # A store and a gradient in, a store and a pulled array out, a bucket.
+        self._note((LAUNCH, t3, c1 - c0, t3 - t2,
+                    4 * k << LAUNCH_SHIFT | _PUSH_PULL))
         self._note((ENGINE_OP, t3, t1 - t0, t2 - t1, t3 - t2))
         return pulled
 
@@ -1915,19 +1951,26 @@ class CollectiveEngine:
         g = self._prep_grads_seq(bucket, grads_seq, flat=flat)
         t2 = stamp()  # prep | launch
         with self._bucket_mu[name]:
+            n_state = 0
             if stateful:
                 self._ensure_opt_state(name, resolved, bucket)
+                c0 = stamp()  # the jitted call alone: see LAUNCH
                 outs = prog(
                     self._stores[name], *self._opt_states[name], g
                 )
+                c1 = stamp()
                 n_state = len(self._opt_states[name])
                 self._stores[name] = outs[0]
                 self._opt_states[name] = tuple(outs[1:1 + n_state])
                 pulled = outs[0] if zc else outs[-1]
             elif zc:
+                c0 = stamp()
                 pulled = self._stores[name] = prog(self._stores[name], g)
+                c1 = stamp()
             else:
+                c0 = stamp()
                 self._stores[name], pulled = prog(self._stores[name], g)
+                c1 = stamp()
             # A zero-copy result aliases the store; padded == total on
             # zc configs.
             if not zc:
@@ -1936,6 +1979,9 @@ class CollectiveEngine:
         self._observe("push_pull", bucket, pushes=steps,
                       pulls=steps if keep == "all" else 1)
         t3 = stamp()
+        # As a bound push_pull's (_bind): the steps are one program's.
+        self._note((LAUNCH, t3, c1 - c0, t3 - t2,
+                    2 * n_state + 3 + (not zc) << LAUNCH_SHIFT | _PUSH_PULL))
         self._note((ENGINE_OP, t3, t1 - t0, t2 - t1, t3 - t2))
         return pulled
 
@@ -2342,19 +2388,27 @@ class CollectiveEngine:
                 # Padded length: the caller registered the buffer and
                 # owns its layout — slicing here would materialize a
                 # copy and break the address-identity contract.
+                c0 = stamp()  # the jitted call alone: see LAUNCH
                 pulled = prog(pinned, self._stores[name])
+                c1 = stamp()
                 self._pinned_pulls[name] = pulled
+                arrays = 3  # the buffer and the store in, the buffer out
             else:
                 if to_pinned:
                     prog = self._program("pull", bucket.padded_len,
                                          bucket.dtype, "_pull")
+                c0 = stamp()
                 pulled = prog(self._stores[name])
+                c1 = stamp()
+                arrays = 2
                 if own is None:
                     pulled = pulled[: bucket.total_len]
                 else:
                     self.narrow_ops += 1
         self._observe("pull", bucket)
         t2 = stamp()
+        self._note((LAUNCH, t2, c1 - c0, t2 - t1,
+                    arrays << LAUNCH_SHIFT | _PULL))
         self._note((ENGINE_OP, t2, t1 - t0, 0, t2 - t1))
         return pulled
 

@@ -57,8 +57,8 @@ from typing import Callable, Dict, Optional
 import numpy as np
 
 from ..utils import logging as log
-from ..utils.profiling import (ENGINE_OP, SPARSE_GROUP, SPARSE_ROUTE,
-                               stage_clock, stamp)
+from ..utils.profiling import (ENGINE_OP, LAUNCH, LAUNCH_SHIFT, SPARSE_GROUP,
+                               SPARSE_ROUTE, launched, stage_clock, stamp)
 from .placement import staging_xp
 
 
@@ -115,6 +115,13 @@ class _Bound:
     order: tuple  # the tables' names, each once, in the order of their locks
     dtypes: tuple  # a table: the numpy dtype its gradient lies in
     payload: int  # bytes an op moves (``push_bytes`` / ``pull_bytes``)
+    # The last integer of the op's LAUNCH note (``profiling.launched``): the
+    # arrays ``prog`` takes and gives, over the op's kind.
+    launched: int
+
+
+# The same of the one-table pull, which is not bound: its few arrays go over it.
+_PULL = launched("sparse.pull", 0)
 
 
 _INT32 = np.dtype(np.int32)
@@ -864,8 +871,9 @@ class SparseEngine:
         # Observability mirroring CollectiveEngine: byte counters, and
         # host time per stage of an op on the process's StageClock.
         self._clock = stage_clock()
-        # An op notes (ENGINE_OP, t_end, select ns, prep ns, launch ns): one
-        # C call (see StageClock).  The sparse stages run prep, select,
+        # An op notes (ENGINE_OP, t_end, select ns, prep ns, launch ns) and,
+        # before it, what its launch was made of (LAUNCH): a C call each
+        # (see StageClock).  The sparse stages run prep, select,
         # launch: the program is chosen under the table's lock.
         self._note = self._clock.note
         self.push_bytes = 0
@@ -1377,6 +1385,14 @@ class SparseEngine:
         stateful = kind is not None
         prog_op = "push_" + kind if stateful else op
         push = op == "push"
+        # A table's store and indices in and its rows out; a push also takes
+        # its gradient, gives the store in the rows' place and a token, and
+        # under a handle takes and gives the accumulator and takes the
+        # handle's numbers; a routed program takes and gives the count.
+        k, routed = len(names), self._group_routed(batches)
+        arrays = (3 * k + 2 * routed if not push
+                  else 4 * k + 1 + 2 * routed
+                  + (2 * k + len(params) if stateful else 0))
         bound = _Bound(
             self._sparse_group_program(prog_op, tables, batches) if group
             else self._sparse_program(prog_op, tables[0], batches[0]),
@@ -1386,10 +1402,11 @@ class SparseEngine:
             stateful and any(map(self._acc_kernel, tables, batches)),
             push and any(t.pack != 1 for t in tables),
             sum(map(self._route_slots, batches)),
-            self._group_routed(batches),
+            routed,
             tuple(sorted(set(names))),
             tuple(np.dtype(t.dtype) for t in tables),
-            sum(map(self._payload, tables, batches)))
+            sum(map(self._payload, tables, batches)),
+            launched("sparse." + op, arrays))
         with self._mu:
             # A new registration meanwhile: the next op binds.
             if all(self._tables.get(t.name) is t for t in tables):
@@ -1484,20 +1501,25 @@ class SparseEngine:
             t2 = stamp()  # select | launch
             count = (self._overflow_count(name),) if b.routed else ()
             if b.kind is None:
+                c0 = stamp()  # the jitted call alone: see LAUNCH
                 self._stores[name], token, *count = b.prog(
                     self._stores[name], idx, g, *count)
+                c1 = stamp()
             else:
                 if name not in self._acc:
                     self._ensure_acc(name, table)
+                c0 = stamp()
                 self._stores[name], self._acc[name], token, *count = b.prog(
                     self._stores[name], self._acc[name], idx, g, *b.params,
                     *count)
+                c1 = stamp()
             if count:
                 self._overflow[name] = count[0]
             self._pushed(b)
         self._observe("push", b.payload)
         t3 = stamp()
         self._note((SPARSE_ROUTE, t3, b.slots, -1, -1))
+        self._note((LAUNCH, t3, c1 - c0, t3 - t2, b.launched))
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
         # The token is a tiny non-donated output that becomes ready when
         # the push completes — block on it freely (the store itself is
@@ -1700,15 +1722,19 @@ class SparseEngine:
             # The group's overflow count is its first table's.
             count = (self._overflow_count(names[0]),) if b.routed else ()
             if b.kind is None:
+                c0 = stamp()  # the jitted call alone: see LAUNCH
                 outs = b.prog(*[stores[n] for n in names], *idxs, *gs,
                               *count)
+                c1 = stamp()
             else:
                 for n in names:
                     if n not in accs:
                         self._ensure_acc(n, self._tables[n])
+                c0 = stamp()
                 outs = b.prog(*[stores[n] for n in names],
                               *[accs[n] for n in names],
                               *idxs, *gs, *b.params, *count)
+                c1 = stamp()
                 accs.update(zip(names, outs[len(names):]))
             # The program's results: the stores, the accumulators under a
             # handle, the token, the count where it took one.
@@ -1723,6 +1749,7 @@ class SparseEngine:
         # One op with one launch, whatever it groups.
         self._note((SPARSE_ROUTE, t3, b.slots, -1, -1))
         self._note((SPARSE_GROUP, t3, len(names), -1, -1))
+        self._note((LAUNCH, t3, c1 - c0, t3 - t2, b.launched))
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
         self._observe("push", b.payload)
         return token
@@ -1744,18 +1771,23 @@ class SparseEngine:
             # The program's own results, [W, n_i, d_i] each: a reshape out
             # here would be one more launch and one more copy of the batch.
             if b.routed:
+                c0 = stamp()  # the jitted call alone: see LAUNCH
                 *pulled, self._overflow[names[0]] = b.prog(
                     *[self._stores[n] for n in names], *idxs,
                     self._overflow_count(names[0]))
+                c1 = stamp()
             else:
+                c0 = stamp()
                 pulled = list(b.prog(*[self._stores[n] for n in names],
                                      *idxs))
+                c1 = stamp()
         finally:
             self._unlock_tables(ordered)
         self._observe("pull", b.payload)
         t3 = stamp()
         self._note((SPARSE_ROUTE, t3, b.slots, -1, -1))
         self._note((SPARSE_GROUP, t3, len(names), -1, -1))
+        self._note((LAUNCH, t3, c1 - c0, t3 - t2, b.launched))
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
         return pulled
 
@@ -1774,13 +1806,21 @@ class SparseEngine:
             t2 = stamp()  # select | launch
             # The program's own result, [W, n, d] (see pull_group).
             if self._routed(batch):
+                c0 = stamp()  # the jitted call alone: see LAUNCH
                 pulled, self._overflow[name] = prog(
                     self._stores[name], idx, self._overflow_count(name))
+                c1 = stamp()
+                arrays = 5  # the store, the indices, the count; rows, count
             else:
+                c0 = stamp()
                 pulled = prog(self._stores[name], idx)
+                c1 = stamp()
+                arrays = 3
         self._observe("pull", self._payload(table, batch))
         t3 = stamp()
         self._note((SPARSE_ROUTE, t3, self._route_slots(batch), -1, -1))
+        self._note((LAUNCH, t3, c1 - c0, t3 - t2,
+                    arrays << LAUNCH_SHIFT | _PULL))
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
         return pulled
 

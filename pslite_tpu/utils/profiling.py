@@ -170,6 +170,25 @@ ROUTED = ("slots", "ops")
 # answers for a window.  A one-table op notes none.
 SPARSE_GROUP = 4
 GROUPED = ("tables", "ops")
+# A sixth kind opens the ``launch`` stage: ``(LAUNCH, t_end, call ns, launch
+# ns, arrays << LAUNCH_SHIFT | op)``, noted by both engines once an op, before
+# its ``ENGINE_OP`` note too and with that note's ``t_end`` and ``launch`` ns
+# (the same integer, so the op kinds' launches add up to the stage exactly).
+# ``call`` is the time inside the jitted call alone, two ``stamp()``s around
+# that one expression: ``launch - call`` is this repo's own Python under the
+# stage (the lock, first-time state, rebinding store and state, the cut of
+# the pulled array, the byte counters).  ``arrays`` is how many arrays the
+# program was handed and how many it returned (a scalar placed on the device
+# is one: the runtime sees an array), and ``op`` an index into ``LAUNCH_OPS``
+# (a grouped or replayed op is its kind).  Both are the same in every op of
+# a bound record, which holds them as one integer (:func:`launched`).  What
+# the kinds sum to rides behind ``GROUPED`` in the totals' vector, ``LAUNCHED``
+# a kind; :meth:`StageClock.launches` answers for a window.
+LAUNCH = 5
+LAUNCH_OPS = ("dense.push_pull", "dense.push", "dense.pull", "sparse.pull",
+              "sparse.push")
+LAUNCHED = ("calls", "ns", "call.ns", "arrays")  # the launches, their ns
+LAUNCH_SHIFT = 3  # room for eight kinds under the arrays
 
 StageWindow = Tuple[Dict[str, Tuple[int, int]], int, float]
 
@@ -214,6 +233,8 @@ _READY, _RESETS = (_OCC + OCCUPANCY.index(name)
                    for name in ("ready_at_wait", "resets"))
 _ROUTED = _OCC + len(OCCUPANCY)  # where ``ROUTED`` begins in the vector
 _GROUPED = _ROUTED + len(ROUTED)  # and ``GROUPED``
+_LAUNCHED = _GROUPED + len(GROUPED)  # and ``LAUNCHED``, kind after kind
+_KINDS = np.arange(len(LAUNCH_OPS))
 # A spell's row: OCCUPANCY up to and with ``spells``.
 _PRELAUNCH, _LAUNCH = 0, 1
 _COPY, _ROUTE, _SELECT, _PREP, _OUTSIDE = (
@@ -223,7 +244,16 @@ _SPELLS = OCCUPANCY.index("spells")
 OccupancyWindow = Tuple[Dict[str, int], int, float]
 RoutedWindow = Tuple[Tuple[int, int], int, float]
 GroupedWindow = RoutedWindow
+Launches = Dict[str, Tuple[int, int, int, int]]  # a kind: ``LAUNCHED``
+LaunchesWindow = Tuple[Launches, int, float]
 _NO_TIMES = np.empty(0, dtype=np.int64)
+
+
+def launched(op: str, arrays: int) -> int:
+    """The last integer of a ``LAUNCH`` note: ``arrays`` over the index of
+    ``op`` in ``LAUNCH_OPS``.  Worked out where an op is bound, or once a
+    module for an op that counts its own few arrays."""
+    return arrays << LAUNCH_SHIFT | LAUNCH_OPS.index(op)
 
 
 class StageClock:
@@ -268,8 +298,9 @@ class StageClock:
         self.backlog = self._pending.__len__  # notes not yet folded
         self._fold_mu = threading.Lock()
         # ns, calls of each stage; then the occupancy account; then what
-        # the sparse ops routed, and what the grouped ones carried
-        self._totals = [0] * (_GROUPED + len(GROUPED))
+        # the sparse ops routed, what the grouped ones carried, and the
+        # launch stage by op kind
+        self._totals = [0] * (_LAUNCHED + len(LAUNCHED) * len(LAUNCH_OPS))
         # slot -> the totals at its start
         self._marks: Dict[int, Tuple[int, ...]] = {}
         self._slot = -1  # the newest slot an op ended in
@@ -334,6 +365,16 @@ class StageClock:
                 grouped = of_slot[of_slot[:, 0] == SPARSE_GROUP, 2]
                 tot[_GROUPED] += int(grouped.sum())
                 tot[_GROUPED + 1] += len(grouped)
+                calls = of_slot[of_slot[:, 0] == LAUNCH, 2:]
+                if len(calls):
+                    code = calls[:, 2]
+                    of_kind = ((code & ((1 << LAUNCH_SHIFT) - 1))[:, None]
+                               == _KINDS).astype(np.int64)
+                    sums = of_kind.T @ np.stack(
+                        (np.ones_like(code), calls[:, 1], calls[:, 0],
+                         code >> LAUNCH_SHIFT), axis=1)  # kinds x LAUNCHED
+                    for i, total in enumerate(sums.ravel().tolist()):
+                        tot[_LAUNCHED + i] += total
                 # A spell is put down to the slot of the op that ended it.
                 if len(ended_in):
                     ended = spells[ended_in == slot].sum(axis=0).tolist()
@@ -556,13 +597,35 @@ class StageClock:
         started (``SPARSE_GROUP``): the tables they carried, summed over the
         ops, and the ops."""
         self.fold()
-        return tuple(self._totals[_GROUPED:])
+        return tuple(self._totals[_GROUPED:_LAUNCHED])
 
     def grouped(self, t_lo: float, t_hi: float) -> GroupedWindow:
         """:meth:`grouped_totals` over the whole slots inside ``[t_lo,
         t_hi]``, as :meth:`routed` answers for the slots: ``((tables, ops),
         slots of the clock, seconds)``."""
         return self._pair_between(_GROUPED, t_lo, t_hi)
+
+    def launches_totals(self) -> Launches:
+        """``{op kind: (calls, ns, call ns, arrays)}`` since the process
+        started (``LAUNCH``, ``LAUNCH_OPS``, ``LAUNCHED``): the ``launch``
+        stage by the kind of op that paid it (the kinds' calls and ns add
+        up to the stage's exactly), of it the time inside the jitted calls,
+        and the arrays those were handed and returned."""
+        self.fold()
+        return self._launches(self._totals)
+
+    @staticmethod
+    def _launches(vector) -> Launches:
+        n = len(LAUNCHED)
+        return {op: tuple(vector[_LAUNCHED + n * k:_LAUNCHED + n * (k + 1)])
+                for k, op in enumerate(LAUNCH_OPS)}
+
+    def launches(self, t_lo: float, t_hi: float) -> LaunchesWindow:
+        """:meth:`launches_totals` over the whole slots inside ``[t_lo,
+        t_hi]``, as :meth:`window` answers for the stages: ``(kinds, slots,
+        seconds)``."""
+        grown, slots, seconds = self._between(t_lo, t_hi)
+        return (self._launches(grown) if slots else {}), slots, seconds
 
     def export(self, registry) -> None:
         """Lazily sampled gauges in a node's ``Registry``, so
@@ -577,9 +640,15 @@ class StageClock:
             registry.gauge(f"engine.occupancy.{name}{unit}",
                            fn=lambda name=name:
                            self.occupancy_totals()[name])
+        # Is the job launch-bound, in which op, and is it the runtime
+        # (``call``) or this repo's Python around it.
+        for op in LAUNCH_OPS:
+            for i, name in enumerate(LAUNCHED):
+                registry.gauge(f"engine.launch.{op}.{name}",
+                               fn=lambda op=op, i=i:
+                               self.launches_totals()[op][i])
         registry.gauge("engine.programs.misses",
                        fn=lambda: self.programs_built)
-        registry.gauge("engine.bound.misses", fn=lambda: self.ops_bound)
         registry.gauge("engine.state_create.s",
                        fn=lambda: self.state_create_ns / 1e9)
 
@@ -625,6 +694,12 @@ class _NullStageClock:
 
     def grouped(self, t_lo: float, t_hi: float) -> GroupedWindow:
         return (0, 0), 0, 0.0
+
+    def launches_totals(self) -> Launches:
+        return {}
+
+    def launches(self, t_lo: float, t_hi: float) -> LaunchesWindow:
+        return {}, 0, 0.0
 
     def export(self, registry) -> None:
         pass

@@ -152,7 +152,7 @@ def worker():
 
 def _counters(worker):
     snap = worker.po.metrics.snapshot()
-    return (snap["gauges"]["engine.bound.misses"],
+    return (profiling.stage_clock().ops_bound,
             snap["counters"].get("kv.complete.threaded", 0))
 
 
@@ -344,7 +344,7 @@ def test_window_over_copyless_ops_counts_each_once(worker):
 
 
 def test_counters_over_the_tiny_cells_of_the_benchmark():
-    """``engine.bound.misses`` = the buckets registered, none of them
+    """``StageClock.ops_bound`` = the buckets registered, none of them
     inside the window, and no op of either driver goes through the pool
     (both pass ``out=None`` and no callback).  In a child: the benchmark's
     rehearsal pins its own devices and imports its modules by bare name."""
@@ -371,7 +371,7 @@ def test_counters_over_the_tiny_cells_of_the_benchmark():
         "    return out\n"
         "def capture(self):\n"
         "    snap = self.kv.po.metrics.snapshot()\n"
-        "    seen['misses'] = snap['gauges']['engine.bound.misses']\n"
+        "    seen['misses'] = stage_clock().ops_bound\n"
         "    seen['threaded'] = snap['counters']['kv.complete.threaded']\n"
         "    seen['buckets'] = len(self.engine._buckets)\n"
         "    shutdown(self)\n"

@@ -881,4 +881,5 @@ def test_the_span_of_a_dense_op_names_the_handles_kind(cluster, monkeypatch):
     monkeypatch.setattr(kv_app, "TraceAnnotation", Span)
     ts = kv.push_pull(KEYS, np.ones((1, TOTAL), np.float32), None)
     kv.wait(ts)
-    assert {"ts": ts, "name": "tree", "handle": "lamb"} in seen
+    assert {"ts": ts, "name": "tree", "op": "dense.push_pull",
+            "handle": "lamb"} in seen

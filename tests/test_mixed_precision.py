@@ -477,12 +477,13 @@ def test_the_span_of_a_dense_op_names_the_job_dtype(cluster, monkeypatch):
     monkeypatch.setattr(kv_app, "TraceAnnotation", Span)
     ts = kv.push_pull(KEYS, np.ones((1, TOTAL), BF16), None)
     kv.wait(ts)
-    assert {"ts": ts, "name": "tree", "handle": "adam",
-            "job": "bfloat16"} in seen
+    assert {"ts": ts, "name": "tree", "op": "dense.push_pull",
+            "handle": "adam", "job": "bfloat16"} in seen
     ts = kv.push_pull(np.array([50], dtype=np.uint64),
                       np.ones((1, 256), np.float32), None)
     kv.wait(ts)
-    assert {"ts": ts, "name": "plain", "handle": "adam"} in seen
+    assert {"ts": ts, "name": "plain", "op": "dense.push_pull",
+            "handle": "adam"} in seen
 
 
 def test_widening_and_narrowing_outside_a_kernel_lie_under_their_scopes():

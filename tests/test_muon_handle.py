@@ -472,7 +472,8 @@ def test_the_span_of_a_dense_op_names_muon(cluster, monkeypatch):
     monkeypatch.setattr(kv_app, "TraceAnnotation", Span)
     ts = kv.push_pull(KEYS, np.ones((1, TOTAL), np.float32), None)
     kv.wait(ts)
-    assert {"ts": ts, "name": "tree", "handle": "muon"} in seen
+    assert {"ts": ts, "name": "tree", "op": "dense.push_pull",
+            "handle": "muon"} in seen
 
 
 def test_the_program_names_its_four_parts():

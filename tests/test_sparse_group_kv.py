@@ -283,15 +283,19 @@ def test_one_note_one_span_and_the_group_counter_an_op(monkeypatch):
             assert kinds.count(kind) == 2, (kind, kinds)
         assert [note[2] for note in notes
                 if note[0] == profiling.SPARSE_GROUP] == [5, 3]
-        # The group's note comes before its op's ENGINE_OP, whose KV_OP is
-        # the note next after it (``StageClock._route_of``).
+        # The group's note comes before its op's ENGINE_OP (the launch's
+        # parts between them), whose KV_OP is the note next after it
+        # (``StageClock._route_of``).
         for i, kind in enumerate(kinds):
             if kind == profiling.ENGINE_OP:
-                assert kinds[i - 1] == profiling.SPARSE_GROUP
+                assert kinds[i - 2:i] == [profiling.SPARSE_GROUP,
+                                          profiling.LAUNCH]
                 assert kinds[i + 1] == profiling.KV_OP
         ops = [s for s in spans if s.name == profiling.OP_SPAN]
         assert [(s.meta["ts"], s.meta["name"], s.meta["tables"])
                 for s in ops] == [(ts_push, "emb00", 5), (ts_pull, "emb00", 3)]
+        # A grouped op is its kind.
+        assert [s.meta["op"] for s in ops] == ["sparse.push", "sparse.pull"]
         assert ops[0].meta["handle"] == "row_adagrad"
         assert "handle" not in ops[1].meta
         waits = [s for s in spans if s.name == profiling.COMPLETE_SPANS[0]]

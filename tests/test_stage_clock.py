@@ -470,7 +470,9 @@ def test_the_account_leaves_the_stages_as_they_were():
     assert clock.totals() == {k: tuple(v) for k, v in want.items()}
     assert len(clock._totals) == (2 * len(STAGES) + len(profiling.OCCUPANCY)
                                   + len(profiling.ROUTED)
-                                  + len(profiling.GROUPED))
+                                  + len(profiling.GROUPED)
+                                  + len(profiling.LAUNCHED)
+                                  * len(profiling.LAUNCH_OPS))
     assert all(len(mark) == len(clock._totals)
                for mark in clock._marks.values())
 
