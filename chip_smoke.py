@@ -22,7 +22,9 @@ reference:
   over several chips the exchange is routed by owner, and under the sum and
   under ``row_adagrad`` alike one batch that fits its buckets and one whose
   ids all have one owner, which falls back to the gathered body, stays exact
-  and is counted by ``engine.sparse.route.overflow``;
+  and is counted by ``engine.sparse.route.overflow``; last the three tables
+  in one ``push_sparse_group`` and one ``pull_sparse_group``, whose program
+  gives one array a width, each entry against its table's ``pull_sparse``;
 - ``message_path``: an unregistered key, which the collective path cannot
   take, answered by the ``KVServer`` handler.
 
@@ -841,6 +843,37 @@ class _Smoke:
         # under the handle; its third push is the one that falls back.
         for name, dim in (("emb_opt", sz.emb_opt_dim), ("emb_opt64", 64)):
             self._sparse_under_handle(se, name, dim, even, rng)
+        self._sparse_grouped(se, even, rng)
+
+    def _sparse_grouped(self, se, idx, rng) -> None:
+        """The three tables in ONE grouped push and one grouped pull
+        (``push_sparse_group`` / ``pull_sparse_group``): the pull's program
+        gives one array a width (two here: the two 64-wide tables' rows
+        side by side), and every entry cut from it is the one-table
+        ``pull_sparse`` of the same ids, bit for bit."""
+        kv, sz = self.kv, self.sizes
+        names = ["emb", "emb_opt", "emb_opt64"]
+        dims = [se.table(n).dim for n in names]
+        grads = [rng.standard_normal((se.num_shards, sz.emb_batch, d),
+                                     dtype=np.float32) for d in dims]
+        kv.wait(kv.push_sparse_group(names, [idx] * 3, grads))
+        ts = kv.pull_sparse_group(names, [idx] * 3)
+        kv.wait(ts)
+        pulled = kv.get_pulled(ts)
+        classes = list(dict.fromkeys(dims))
+        check([a.shape[1:] for a in pulled.arrays]
+              == [(sz.emb_batch * dims.count(d), d) for d in classes],
+              f"a grouped pull's results {[a.shape for a in pulled.arrays]}")
+        for name, rows in zip(names, pulled):
+            one = kv.pull_sparse(name, idx)
+            kv.wait(one)
+            check((np.asarray(rows).view(np.uint32)
+                   == np.asarray(kv.get_pulled(one)).view(np.uint32)).all(),
+                  f"a grouped pull's rows of {name} differ from pull_sparse")
+        check(np.abs(np.asarray(pulled[1])).max() > 0, "nothing was pushed")
+        print(f"  one grouped push and one grouped pull of {names}: "
+              f"{len(pulled.arrays)} results for {len(names)} tables, every "
+              f"entry equal to its table's pull_sparse")
 
     def _sparse_under_handle(self, se, name, dim, idx, rng) -> None:
         """From the zero state one push of row-wise Adagrad leaves

@@ -49,6 +49,7 @@ from ..message import (
     Role,
 )
 from ..ops import codecs as codecs_mod
+from ..parallel.sparse import PulledGroup
 from ..range import Range, find_range
 from ..sarray import SArray
 from ..utils import logging as log
@@ -1029,8 +1030,10 @@ class KVWorker:
         ``tables`` marks a GROUPED sparse op (``pull_sparse_group`` /
         ``push_sparse_group``) and says how many tables it carries: the
         first of ``args`` is then their names, the op goes by the first of
-        them, its span also carries ``tables``, and a grouped pull's result
-        (and ``out``) is a list, a table an entry.
+        them, and its span also carries ``tables``.  A grouped pull's
+        result is a ``PulledGroup``: nothing here, in ``wait`` or in the
+        completion touches an entry of it (a cut is a launch), only its
+        class arrays; its ``out`` is a list, a host buffer a table.
         """
         span = TraceAnnotation(OP_SPAN) if tracing() else None
         if span is not None:
@@ -1112,8 +1115,8 @@ class KVWorker:
         stamps = list(kept)
         for i in range(len(stamps) - 1, -1, -1):
             result = kept[stamps[i]]
-            room -= (sum(r.nbytes for r in result)  # a grouped pull's
-                     if type(result) is list
+            room -= (sum(r.nbytes for r in result.arrays)  # a grouped pull's
+                     if type(result) is PulledGroup
                      else getattr(result, "nbytes", 0))
             if room < 0 and i < len(stamps) - 1:
                 for old in stamps[:i + 1]:
@@ -1155,9 +1158,9 @@ class KVWorker:
                 if traced else None)
         if span is not None:
             span.__enter__()
-        grouped = type(result) is list  # a grouped sparse pull's arrays
+        grouped = type(result) is PulledGroup  # a grouped sparse pull's
         if grouped:
-            for r in result:
+            for r in result.arrays:  # a class of its entries each
                 r.block_until_ready()
         else:
             result.block_until_ready()
@@ -1170,10 +1173,13 @@ class KVWorker:
             span.__enter__()
         if out is not None:
             if grouped:
-                for r, o in zip(result, out):
-                    self._copy_out(r, o)
+                # One copy to the host a class; a table's buffer is filled
+                # from its rows of that copy.
+                hosts = [self._host_rows(r) for r in result.arrays]
+                for (c, off, n), o in zip(result.entries, out):
+                    self._fill(o, hosts[c][:, off:off + n])
             else:
-                self._copy_out(result, out)
+                self._fill(out, self._host_rows(result))
         if callback is not None:
             callback()
         if span is not None:
@@ -1184,32 +1190,40 @@ class KVWorker:
             self._stage_clock.fold()
 
     @staticmethod
-    def _copy_out(result, out) -> None:
-        """One device result's values into the caller's host buffer, flat."""
+    def _host_rows(result) -> np.ndarray:
+        """One device result on the host."""
         if getattr(result, "is_fully_addressable", True) or getattr(
             result, "is_fully_replicated", False
         ):
-            host = np.asarray(result)
-        else:
-            # Multi-process mesh, worker-sharded result (sparse pull):
-            # this process's rows are its addressable shards, in
-            # global row order.
-            shards = sorted(
-                result.addressable_shards,
-                key=lambda s: tuple(sl.start or 0 for sl in s.index),
-            )
-            host = np.concatenate(
-                [np.asarray(s.data) for s in shards], axis=0
-            )
+            return np.asarray(result)
+        # Multi-process mesh, worker-sharded result (sparse pull): this
+        # process's rows are its addressable shards, in global row order
+        # (along axis 0, the workers': a grouped pull's classes lie along
+        # the lookup axis, which this does not touch).
+        shards = sorted(
+            result.addressable_shards,
+            key=lambda s: tuple(sl.start or 0 for sl in s.index),
+        )
+        return np.concatenate([np.asarray(s.data) for s in shards], axis=0)
+
+    @staticmethod
+    def _fill(out, host) -> None:
+        """A host array's values into the caller's host buffer, flat."""
         np.copyto(
             out.reshape(-1),
-            host.reshape(-1)[: out.size].astype(out.dtype),
+            host.reshape(-1)[: out.size].astype(out.dtype, copy=False),
         )
 
     def get_pulled(self, ts: int):
         """Device-resident pull result for a recent engine-path timestamp
-        (bounded window of the last few results); of a grouped sparse pull
-        the list of its tables' arrays, in the call's order."""
+        (bounded window of the last few results).  Of a grouped sparse pull
+        a ``parallel.sparse.PulledGroup``: a read-only sequence of the
+        entries' ``[W, n_i, d_i]`` rows in the call's order, over ONE device
+        array a class of ``(d, dtype)`` (``.arrays``, with ``.entries`` the
+        ``(class, offset, n)`` of each).  ``seq[i]`` and iteration CUT an
+        entry from its class's array when asked: an eager slice, a launch
+        and a buffer each.  A jitted forward pass takes the sequence itself
+        (a pytree of ``.arrays``) and cuts inside, where a slice is free."""
         with self._mu:
             return self._device_results.get(ts)
 
@@ -1281,11 +1295,14 @@ class KVWorker:
     def pull_sparse_group(self, names, indices_list, outs=None,
                           callback=None) -> int:
         """The rows of SEVERAL tables in one op (``SparseEngine.pull_group``):
-        ``get_pulled(ts)`` is the list of ``[W, n_i, d_i]`` arrays in
-        ``names`` order, ``wait(ts)`` returns when every one is ready, and
-        with ``outs`` (a host buffer a table) each table's rows are copied to
-        its buffer on the completion thread, as ``pull_sparse(..., out=)``
-        copies one."""
+        the program gives one array ``[W, sum n_i, d]`` a class of ``(d,
+        dtype)`` among the entries, ``get_pulled(ts)`` is the ``PulledGroup``
+        over them (the entries' ``[W, n_i, d_i]`` rows in ``names`` order,
+        each cut when asked for: see :meth:`get_pulled`), and ``wait(ts)``
+        returns when every row is there.  With ``outs`` (a host buffer a
+        table) each CLASS is copied to the host once, on the completion
+        thread, and each table's buffer filled from its rows of that copy,
+        as ``pull_sparse(..., out=)`` copies one."""
         eng = getattr(self.po.van, "sparse_engine", None)
         log.check(eng is not None, "pull_sparse_group requires the ici van")
         log.check(outs is None or len(outs) == len(names),

@@ -55,6 +55,7 @@ from dataclasses import dataclass
 from typing import Callable, Dict, Optional
 
 import numpy as np
+from jax import tree_util
 
 from ..utils import logging as log
 from ..utils.profiling import (ENGINE_OP, LAUNCH, LAUNCH_SHIFT, SPARSE_GROUP,
@@ -118,6 +119,57 @@ class _Bound:
     # The last integer of the op's LAUNCH note (``profiling.launched``): the
     # arrays ``prog`` takes and gives, over the op's kind.
     launched: int
+    # A grouped pull: where each entry's rows lie in the program's results
+    # (:func:`_group_entries`); () for every other op.
+    entries: tuple = ()
+
+
+class PulledGroup:
+    """What a grouped pull hands back: the program's results, one array
+    ``[W, sum n_i, d]`` a class of ``(d, dtype)`` among the call's entries
+    (``arrays``, in the order a class first appears), and for each entry
+    (a position of the call: a table named twice is two) ``(class, offset,
+    n)`` in ``entries``, its rows being ``arrays[class][:, offset:offset +
+    n]``.  A read-only sequence of the entries' rows ``[W, n_i, d_i]``,
+    each CUT WHEN ASKED FOR (``len``, iteration, ``seq[i]``): outside a
+    ``jit`` a cut is a launch and a buffer of its own.  A pytree of its
+    arrays with the entries static: a jitted forward pass takes the sequence
+    itself and cuts inside, where a slice is free, and
+    ``jax.block_until_ready`` waits for the arrays."""
+
+    __slots__ = ("arrays", "entries")
+
+    def __init__(self, arrays: tuple, entries: tuple):
+        self.arrays, self.entries = arrays, entries
+
+    def __len__(self) -> int:
+        return len(self.entries)
+
+    def __getitem__(self, i: int):
+        c, off, n = self.entries[i]
+        return self.arrays[c][:, off:off + n]
+
+    def __iter__(self):
+        return map(self.__getitem__, range(len(self.entries)))
+
+
+tree_util.register_pytree_node(
+    PulledGroup, lambda p: (p.arrays, p.entries),
+    lambda entries, arrays: PulledGroup(tuple(arrays), entries))
+
+
+def _group_entries(tables, batches) -> tuple:
+    """``(class, offset, n)`` of each entry of a grouped pull, and the number
+    of classes: entries of one ``(dim, dtype)`` share a result, side by side
+    along the lookup axis in the call's order; classes are numbered as they
+    first appear."""
+    classes: Dict[tuple, list] = {}
+    entries = []
+    for t, n in zip(tables, batches):
+        at = classes.setdefault((t.dim, np.dtype(t.dtype)), [len(classes), 0])
+        entries.append((at[0], at[1], n))
+        at[1] += n
+    return tuple(entries), len(classes)
 
 
 # The same of the one-table pull, which is not bound: its few arrays go over it.
@@ -1385,12 +1437,14 @@ class SparseEngine:
         stateful = kind is not None
         prog_op = "push_" + kind if stateful else op
         push = op == "push"
-        # A table's store and indices in and its rows out; a push also takes
-        # its gradient, gives the store in the rows' place and a token, and
-        # under a handle takes and gives the accumulator and takes the
-        # handle's numbers; a routed program takes and gives the count.
+        # A table's store and indices in, and out one array of rows a class
+        # of the pull's entries; a push also takes a table's gradient, gives
+        # its store and a token, and under a handle takes and gives the
+        # accumulator and takes the handle's numbers; a routed program takes
+        # and gives the count.
         k, routed = len(names), self._group_routed(batches)
-        arrays = (3 * k + 2 * routed if not push
+        entries, classes = ((), 0) if push else _group_entries(tables, batches)
+        arrays = (2 * k + classes + 2 * routed if not push
                   else 4 * k + 1 + 2 * routed
                   + (2 * k + len(params) if stateful else 0))
         bound = _Bound(
@@ -1406,7 +1460,8 @@ class SparseEngine:
             tuple(sorted(set(names))),
             tuple(np.dtype(t.dtype) for t in tables),
             sum(map(self._payload, tables, batches)),
-            launched("sparse." + op, arrays))
+            launched("sparse." + op, arrays),
+            entries)
         with self._mu:
             # A new registration meanwhile: the next op binds.
             if all(self._tables.get(t.name) is t for t in tables):
@@ -1639,22 +1694,32 @@ class SparseEngine:
                                *count_sh),
             )
         elif op == "pull":
+            # One result a class of (dim, dtype) among the entries, their
+            # rows side by side along the lookup axis (PulledGroup): a
+            # result is a buffer the runtime allocates at every launch.
+            entries, classes = _group_entries(tables, batches)
+
             def body(*args):
                 stores = args[:k]
                 idxs = args[k:2 * k]
-                rows, over = [], []
+                rows, over = [[] for _ in range(classes)], []
                 for i, s in enumerate(stores):
                     with scope(i):
-                        rows.append(_pull_rows(
+                        rows[entries[i][0]].append(_pull_rows(
                             axis, S, s, idxs[i], pack=packs[i], dim=dims[i],
-                            over=over)[None])                  # [1, n, d]
-                return (*rows, *_counted(args[2 * k:], over))
+                            over=over))                        # [n, d]
+                with jax.named_scope("ps.sparse.group"):
+                    outs = [jnp.concatenate(r)[None] for r in rows]
+                if classes == 1 and not routed:
+                    return outs[0]                     # [1, sum n, d], bare
+                return (*outs, *_counted(args[2 * k:], over))
 
             fn = jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=tuple([store_spec] * k + [idx_spec] * k
                                + count_spec),
-                out_specs=tuple([g_spec] * k + count_spec),
+                out_specs=g_spec if classes == 1 and not routed
+                else tuple([g_spec] * classes + count_spec),
                 check_vma=False,
             )
             jitted = jax.jit(fn, donate_argnums=(2 * k,) * routed)
@@ -1754,10 +1819,14 @@ class SparseEngine:
         self._observe("push", b.payload)
         return token
 
-    def pull_group(self, names, indices_list):
-        """Pull SEVERAL tables in one dispatch; returns the list of
-        [W, n_i, d_i] arrays in ``names`` order.  Bound once, as
-        :meth:`push_group`."""
+    def pull_group(self, names, indices_list) -> PulledGroup:
+        """Pull SEVERAL tables in one dispatch.  The program gives ONE array
+        ``[W, sum n_i, d]`` a class of ``(d, dtype)`` among the entries, so
+        the runtime allocates one result a class and not one a table; what
+        is returned is a :class:`PulledGroup` over them: a sequence of the
+        entries' ``[W, n_i, d_i]`` rows in ``names`` order, an entry cut
+        from its class's array when asked for and not before.  Bound once,
+        as :meth:`push_group`."""
         log.check(len(names) == len(indices_list), "group length mismatch")
         t0 = stamp()  # stage borders: see _note
         key, ordered, idxs, _ = self._group_inputs(
@@ -1768,8 +1837,8 @@ class SparseEngine:
             # Under the locks, as table.pack is resolved (see push).
             b = self._bound.get(key) or self._bind(*key)
             t2 = stamp()  # select | launch
-            # The program's own results, [W, n_i, d_i] each: a reshape out
-            # here would be one more launch and one more copy of the batch.
+            # The program's own results, [W, sum n_i, d] a class: a reshape
+            # or a cut out here would be one more launch and one more copy.
             if b.routed:
                 c0 = stamp()  # the jitted call alone: see LAUNCH
                 *pulled, self._overflow[names[0]] = b.prog(
@@ -1778,11 +1847,13 @@ class SparseEngine:
                 c1 = stamp()
             else:
                 c0 = stamp()
-                pulled = list(b.prog(*[self._stores[n] for n in names],
-                                     *idxs))
+                pulled = b.prog(*[self._stores[n] for n in names], *idxs)
                 c1 = stamp()
+                if type(pulled) is not tuple:  # one class: the array, bare
+                    pulled = (pulled,)
         finally:
             self._unlock_tables(ordered)
+        pulled = PulledGroup(tuple(pulled), b.entries)
         self._observe("pull", b.payload)
         t3 = stamp()
         self._note((SPARSE_ROUTE, t3, b.slots, -1, -1))

@@ -628,6 +628,8 @@ PULLS = {
     "four-chips-routed": (4, [128], True),
     "one-chip-lane-packed-64": (1, [64], False),
     "four-chips-group-128-and-64": (4, [128, 64], True),
+    # (PR 53) a group's entries of one width share ONE result, bare
+    "one-chip-group-three-64-one-result": (1, [64, 64, 64], False),
 }
 
 
@@ -644,7 +646,9 @@ def test_the_pull_program_hands_the_batch_over_as_it_gathered_it(
     none has come in its place.  A 64-wide result is laid with the batch
     along the lanes by the compiler's own choice, before this change as
     after it: that one re-laying copy is the program's last instruction (of
-    each branch where the exchange is routed) and the only one."""
+    each branch where the exchange is routed) and the only one.  A group's
+    entries of one width lie side by side in one result, ``[W, sum n, d]``
+    (PR 53): three 64-wide tables end in ONE re-laying copy, not three."""
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -668,24 +672,26 @@ def test_the_pull_program_hands_the_batch_over_as_it_gathered_it(
             "pull", [eng._tables[nm] for nm in names], (n,) * len(names))
     lowered = prog.lower(*stores, *[idx] * len(names), *count)
     outs = jax.tree_util.tree_leaves(lowered.out_info)
+    # A result a width, in the order a width first appears.
+    classes = {d: n * dims.count(d) for d in dims}
     assert [tuple(o.shape) for o in outs] == (
-        [(S, n, d) for d in dims] + [(S,)] * routed)
+        [(S, m, d) for d, m in classes.items()] + [(S,)] * routed)
     compiled = lowered.compile()
     for sharding in jax.tree_util.tree_leaves(
-            compiled.output_shardings)[:len(dims)]:
+            compiled.output_shardings)[:len(classes)]:
         assert sharding.is_equivalent_to(
             NamedSharding(mesh, P("kv", None, None)), 3)
     text = compiled.as_text()
-    for d in dims:
-        moves = _moves_of_a_batch(text, n, d)
+    for d, m in classes.items():
+        moves = _moves_of_a_batch(text, m, d)
         if d == 128:
             assert not moves, moves
             continue
         # The one re-laying of a narrow result, a branch of the routed
         # program's conditional its own: last, and of the layout.
         assert len(moves) == 1 + routed, moves
-        assert all(" copy(" in m and f"f32[1,{n},{d}]{{1,2,0:" in m
-                   for m in moves), moves
+        assert all(" copy(" in mv and f"f32[1,{m},{d}]{{1,2,0:" in mv
+                   for mv in moves), moves
         assert routed or moves[0].startswith("ROOT ")
     if dims == [128] and not routed:
         # The program's last instruction: the unit dimension, for nothing.
@@ -694,7 +700,7 @@ def test_the_pull_program_hands_the_batch_over_as_it_gathered_it(
         assert f"f32[1,{n},128]" in root and " bitcast(" in root, root
     mem = compiled.memory_analysis()
     want = sum(n * d * 4 for d in dims)
-    assert want <= mem.output_size_in_bytes <= want + 4096 * (1 + len(dims))
+    assert want <= mem.output_size_in_bytes <= want + 4096 * (1 + len(classes))
 
 
 # -- LAMB's one pass and its pulled values (ops/fused_update.py) ----------------

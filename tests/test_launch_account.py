@@ -133,7 +133,8 @@ CASES = {
     "sparse.pull": (_sparse_pull, "sparse.pull", 6, 2, 1),
     "sparse.push": (_sparse_push, "sparse.push", 6, 3, 2),
     "sparse.push/row_adagrad": (_row_adagrad, "sparse.push", 6, 6, 3),
-    "sparse.pull_group/3": (_pull_group, "sparse.pull", 6, 6, 3),
+    # (three tables of one width: one class, one result)
+    "sparse.pull_group/3": (_pull_group, "sparse.pull", 6, 6, 1),
     "sparse.push_group/3": (_push_group, "sparse.push", 6, 9, 4),
     "sparse.pull/routed": (_routed_pull, "sparse.pull", 6, 3, 2),
     "sparse.push_group/3/routed": (_routed_push_group, "sparse.push", 6, 10,

@@ -215,6 +215,12 @@ def test_the_lowered_programs_carry_the_exchanges_scopes(cluster, op, group):
         # (a function the body calls names its places from its own start)
         assert {m.group(1) for m in map(table.match, places) if m} == {
             "emb", "twin"}
+        # (and a pull's rows are put side by side in the group's own scope,
+        # one result a class: ``PulledGroup``)
+        whole = {"ps.sparse.group/concatenate",
+                 "ps.sparse.group/broadcast_in_dim"} if op == "pull" else set()
+        assert whole <= places
+        places -= whole
         assert all(table.match(p) for p in places
                    if not p.startswith("cond/")), places
         places = {table.sub("", p) for p in places}

@@ -8,9 +8,9 @@ bit for bit as the same tables' one-table calls do (26 tables, one shard and
 four, the plain sum and ``row_adagrad``); tables of 3, 4 and 10 rows beside
 one of 100,003 under a batch of 2,048 (more slots than rows) through the
 kernel the chip writes them with; ``outs=``, ``callback``, ``wait`` and
-``get_pulled`` of a list; the refusal of a table named twice; the group
-counter (``SPARSE_GROUP`` notes, gauges ``engine.sparse.group.ops`` /
-``.tables``) and the span's ``tables``.
+``get_pulled`` of a grouped pull (a ``PulledGroup``: PR 53); the refusal of
+a table named twice; the group counter (``SPARSE_GROUP`` notes, gauges
+``engine.sparse.group.ops`` / ``.tables``) and the span's ``tables``.
 """
 
 import numpy as np
@@ -21,6 +21,7 @@ jax = pytest.importorskip("jax")
 from jax.sharding import Mesh  # noqa: E402
 
 from pslite_tpu import KVWorker  # noqa: E402
+from pslite_tpu.parallel.sparse import PulledGroup  # noqa: E402
 from pslite_tpu.utils import profiling  # noqa: E402
 from pslite_tpu.utils.logging import CheckError  # noqa: E402
 
@@ -97,7 +98,9 @@ def test_a_grouped_op_is_its_tables_one_table_ops_bit_for_bit(shards, k,
         ts = kv.pull_sparse_group(names, idx)
         kv.wait(ts)
         pulled = kv.get_pulled(ts)
-        assert isinstance(pulled, list) and len(pulled) == k
+        # One result for the one class of (DIM, f32), an entry a table.
+        assert type(pulled) is PulledGroup and len(pulled) == k
+        assert [a.shape for a in pulled.arrays] == [(shards, k * N_26, DIM)]
         for n, s, i, rows in zip(names, solo, idx, pulled):
             one = kv.pull_sparse(s, i)
             kv.wait(one)
@@ -216,13 +219,15 @@ def test_get_pulled_keeps_lists_and_trims_them_by_their_bytes(one_shard,
         ts = kv.pull_sparse_group(names, idx)
         kv.wait(ts)
         stamps.append(ts)
-    # The window of the last 8 results, a list an entry.
+    # The window of the last 8 results, a sequence an entry.
     kept = [ts for ts in stamps if kv.get_pulled(ts) is not None]
     assert kept == stamps[-8:]
-    assert sum(r.nbytes for r in kv.get_pulled(stamps[-1])) == a_list
+    last = kv.get_pulled(stamps[-1])
+    assert type(last) is PulledGroup
+    assert sum(a.nbytes for a in last.arrays) == a_list
     # While a heavy bucket is registered the window is held to a budget of
-    # bytes, and a list weighs what its arrays weigh: room for two and a
-    # half lists keeps two.
+    # bytes, and a sequence weighs what its class arrays weigh: room for two
+    # and a half keeps two.
     monkeypatch.setattr(kv, "_results_heavy", True)
     monkeypatch.setattr(kv, "_DEVICE_RESULTS_BYTES", int(2.5 * a_list))
     ts = kv.pull_sparse_group(names, idx)

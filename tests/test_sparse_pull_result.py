@@ -1,7 +1,8 @@
 """A sparse pull is one program: ``SparseEngine.pull`` and ``pull_group``
 return the very arrays their program returned, ``[W, n, d]`` sharded a
 worker's batch to its device (``P(axis, None, None)``), and no reshape after
-it launches a second program and copies the batch once more (PR 49).
+it launches a second program and copies the batch once more (PR 49; since
+PR 53 ``pull_group``'s are one array a width, held by a ``PulledGroup``).
 
 What the caller sees is what it saw before: every pulled row is the stored
 row bit for bit, each worker's batch on its own device.  Held here on every
@@ -18,7 +19,7 @@ jax = pytest.importorskip("jax")
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
 from pslite_tpu import KVWorker  # noqa: E402
-from pslite_tpu.parallel.sparse import SparseEngine  # noqa: E402
+from pslite_tpu.parallel.sparse import PulledGroup, SparseEngine  # noqa: E402
 
 from helpers import LoopbackCluster  # noqa: E402
 
@@ -135,13 +136,15 @@ def test_pull_group_returns_its_programs_own_arrays(shards):
     eng.pull_group(names, asked)
     returned = _spy(eng)
     pulled = eng.pull_group(names, asked)
-    assert len(returned) == 1 and isinstance(pulled, list)
+    assert len(returned) == 1 and type(pulled) is PulledGroup
     outs = returned[0]
+    # Two widths, two classes: a result each, and the count where routed.
     assert len(outs) == 2 + eng._group_routed((N, 48))
-    for got, out, d, init, idx in zip(pulled, outs, dims, inits, asked):
-        assert got is out
-        _is_a_workers_batch_a_device(got, eng, idx.shape[1], d)
-        assert (np.asarray(got).view(np.uint32)
+    assert pulled.entries == ((0, 0, N), (1, 0, 48)) and len(pulled) == 2
+    for c, (out, d, init, idx) in enumerate(zip(outs, dims, inits, asked)):
+        assert pulled.arrays[c] is out      # nothing launched after it
+        _is_a_workers_batch_a_device(out, eng, idx.shape[1], d)
+        assert (np.asarray(pulled[c]).view(np.uint32)
                 == init[idx].view(np.uint32)).all()
 
 
