@@ -986,7 +986,8 @@ class KVWorker:
                    out=None, callback=None, keep_result: bool = False,
                    pull: bool = False,
                    handle: Optional[str] = None,
-                   tables: int = 0) -> Optional[int]:
+                   tables: int = 0,
+                   pool: Optional[str] = None) -> Optional[int]:
         """One op of the collective path: route, the engine's op, then
         timestamp + async completion.  None where ``keys`` are no
         registered bucket: the op is the message path's.
@@ -1034,6 +1035,14 @@ class KVWorker:
         result is a ``PulledGroup``: nothing here, in ``wait`` or in the
         completion touches an entry of it (a cut is a launch), only its
         class arrays; its ``out`` is a list, a host buffer a table.
+
+        ``pool`` marks a sparse op whose lookups are BAGS (``pool="sum"``;
+        the engine is given it last among ``args``, and nothing where the
+        call gave none: the op without it is the call it has always been):
+        its span carries ``pool``.
+        Nothing else here knows a bag: a pooled pull's result is ``[W, B,
+        d]``, a row a bag, a grouped one's entries lie side by side as rows
+        do.
         """
         span = TraceAnnotation(OP_SPAN) if tracing() else None
         if span is not None:
@@ -1097,6 +1106,8 @@ class KVWorker:
                 meta["handle"] = handle.partition(":")[0]
             if tables:
                 meta["tables"] = tables
+            if pool is not None:
+                meta["pool"] = pool
             bucket = (self.engine._buckets.get(name)
                       if keys is not None else None)
             if bucket is not None and bucket.mixed:
@@ -1256,7 +1267,8 @@ class KVWorker:
         return self.engine.push_pull_stream(name, grads_iter, depth=depth)
 
     def push_sparse(self, name: str, indices, grads,
-                    handle: Optional[str] = None, callback=None) -> int:
+                    handle: Optional[str] = None, callback=None,
+                    pool: Optional[str] = None) -> int:
         """Sparse push: [W, n] rows + [W, n, d] grads into the sharded
         table.  With no ``handle`` they are scatter-added (the
         aggregation server handle); ``handle="row_adagrad:lr,eps"`` has
@@ -1264,36 +1276,58 @@ class KVWorker:
         its accumulator kept beside the table (``SparseEngine.push``).
         The handle is the call's, as ``CollectiveEngine.push_pull(name,
         grads, handle)`` has it: an unknown one fails at the first push,
-        by name."""
+        by name.
+
+        ``pool="sum"``: a lookup is a BAG of ids whose rows the job takes
+        summed (an ``EmbeddingBag``).  indices ``[W, B, h]``, a worker's
+        ``B`` bags of ``h`` ids; grads ``[W, B, d]``, ONE gradient a bag,
+        which every slot of the bag brings to its row, under the handle or
+        without: what a push of ``[W, B * h]`` ids with each gradient
+        repeated ``h`` times does, without that array.  A row that lies
+        twice in a bag receives it twice; bags of one id are rows."""
         eng = getattr(self.po.van, "sparse_engine", None)
         log.check(eng is not None, "push_sparse requires the ici van")
-        return self._engine_op(eng.push, (name, indices, grads, handle),
+        args = (name, indices, grads, handle)
+        return self._engine_op(eng.push,
+                               args if pool is None else (*args, pool),
                                None, 0, None, None, callback, False, False,
-                               handle)
+                               handle, 0, pool)
 
     def pull_sparse(self, name: str, indices, out=None,
-                    callback=None) -> int:
+                    callback=None, pool: Optional[str] = None) -> int:
+        """Sparse pull: [W, n] rows -> ``get_pulled(ts)`` ``[W, n, d]``
+        (``out``: a host buffer of as many values).  ``pool="sum"``:
+        indices ``[W, B, h]``, bags of ``h`` ids -> ``[W, B, d]``, each
+        bag's rows summed in f32 where the table lives; a row that lies
+        twice in a bag is added twice."""
         eng = getattr(self.po.van, "sparse_engine", None)
         log.check(eng is not None, "pull_sparse requires the ici van")
-        return self._engine_op(eng.pull, (name, indices), None, 0, None,
-                               out, callback, True)
+        return self._engine_op(eng.pull,
+                               (name, indices) if pool is None
+                               else (name, indices, pool), None, 0,
+                               None, out, callback, True, False, None, 0,
+                               pool)
 
     def push_sparse_group(self, names, indices_list, grads_list,
-                          handle: Optional[str] = None, callback=None) -> int:
+                          handle: Optional[str] = None, callback=None,
+                          pool: Optional[str] = None) -> int:
         """A step's rows of SEVERAL tables in one op: one timestamp, one
         program, one launch (``SparseEngine.push_group``).  A table's
         semantics are :meth:`push_sparse`'s own, ``handle`` applies to every
         table of the group, and a table may not appear twice (its store is
-        donated to the program once)."""
+        donated to the program once).  ``pool="sum"`` applies to every table
+        too, each with a bag size of its own: table ``t``'s indices ``[W, B,
+        h_t]``, its gradients ``[W, B, d]``."""
         eng = getattr(self.po.van, "sparse_engine", None)
         log.check(eng is not None, "push_sparse_group requires the ici van")
+        args = (names, indices_list, grads_list, handle)
         return self._engine_op(eng.push_group,
-                               (names, indices_list, grads_list, handle),
+                               args if pool is None else (*args, pool),
                                None, 0, None, None, callback, False, False,
-                               handle, len(names))
+                               handle, len(names), pool)
 
     def pull_sparse_group(self, names, indices_list, outs=None,
-                          callback=None) -> int:
+                          callback=None, pool: Optional[str] = None) -> int:
         """The rows of SEVERAL tables in one op (``SparseEngine.pull_group``):
         the program gives one array ``[W, sum n_i, d]`` a class of ``(d,
         dtype)`` among the entries, ``get_pulled(ts)`` is the ``PulledGroup``
@@ -1302,14 +1336,19 @@ class KVWorker:
         returns when every row is there.  With ``outs`` (a host buffer a
         table) each CLASS is copied to the host once, on the completion
         thread, and each table's buffer filled from its rows of that copy,
-        as ``pull_sparse(..., out=)`` copies one."""
+        as ``pull_sparse(..., out=)`` copies one.  ``pool="sum"``: table
+        ``t``'s indices are bags ``[W, B, h_t]`` and its entry the pooled
+        rows ``[W, B, d]`` (:meth:`pull_sparse`), a class's side by side
+        ``[W, sum B, d]``."""
         eng = getattr(self.po.van, "sparse_engine", None)
         log.check(eng is not None, "pull_sparse_group requires the ici van")
         log.check(outs is None or len(outs) == len(names),
                   "pull_sparse_group: one host buffer a table")
-        return self._engine_op(eng.pull_group, (names, indices_list), None,
-                               0, None, outs, callback, True, False, None,
-                               len(names))
+        return self._engine_op(eng.pull_group,
+                               (names, indices_list) if pool is None
+                               else (names, indices_list, pool),
+                               None, 0, None, outs, callback, True, False,
+                               None, len(names), pool)
 
     # -- telemetry -----------------------------------------------------------
 

@@ -59,7 +59,8 @@ from jax import tree_util
 
 from ..utils import logging as log
 from ..utils.profiling import (ENGINE_OP, LAUNCH, LAUNCH_SHIFT, SPARSE_GROUP,
-                               SPARSE_ROUTE, launched, stage_clock, stamp)
+                               SPARSE_POOL, SPARSE_ROUTE, launched,
+                               stage_clock, stamp)
 from .placement import staging_xp
 
 
@@ -122,6 +123,9 @@ class _Bound:
     # A grouped pull: where each entry's rows lie in the program's results
     # (:func:`_group_entries`); () for every other op.
     entries: tuple = ()
+    # A pooled op (``pool="sum"`` with a bag of more than one slot): the bags
+    # and the lookups it carries, all workers' (:func:`_pooled`); () else.
+    pooled: tuple = ()
 
 
 class PulledGroup:
@@ -162,14 +166,50 @@ def _group_entries(tables, batches) -> tuple:
     """``(class, offset, n)`` of each entry of a grouped pull, and the number
     of classes: entries of one ``(dim, dtype)`` share a result, side by side
     along the lookup axis in the call's order; classes are numbered as they
-    first appear."""
+    first appear.  ``n`` is the rows the entry is given: one a lookup, of a
+    pooled entry one a bag (:func:`_bags`)."""
     classes: Dict[tuple, list] = {}
     entries = []
-    for t, n in zip(tables, batches):
+    for t, batch in zip(tables, batches):
+        n = _bags(batch)
         at = classes.setdefault((t.dim, np.dtype(t.dtype)), [len(classes), 0])
         entries.append((at[0], at[1], n))
         at[1] += n
     return tuple(entries), len(classes)
+
+
+# A BATCH is what a program is keyed by of one table's ids behind the worker
+# axis: ``n``, one id a lookup (``[W, n]``), or ``(B, h)``, ``B`` bags of ``h``
+# ids each (``[W, B, h]`` under ``pool="sum"``: a lookup is the SUM of a bag's
+# rows, a gradient is a bag's and every slot of the bag receives it).  Bags of
+# one id are rows: their batch is ``B``, the key, the record and the program
+# of ``[W, B]``.
+
+
+def _batch_of(idx):
+    """The batch of placed ids (see above)."""
+    return idx.shape[1] if idx.ndim == 2 or idx.shape[2] == 1 \
+        else tuple(idx.shape[1:])
+
+
+def _bags(batch) -> int:
+    """Rows a worker of a batch's result and of its gradient: one a lookup,
+    one a bag."""
+    return batch if type(batch) is int else batch[0]
+
+
+def _lookups(batch) -> int:
+    """Slots a worker of a batch: the ids it carries, ``B * h`` of bags."""
+    return batch if type(batch) is int else batch[0] * batch[1]
+
+
+def _pooled(W: int, batches) -> tuple:
+    """``(bags, lookups)`` over all workers of an op of these batches where
+    any holds a bag of more than one id (what ``SPARSE_POOL`` notes), else
+    ``()``."""
+    if all(type(b) is int for b in batches):
+        return ()
+    return (W * sum(map(_bags, batches)), W * sum(map(_lookups, batches)))
 
 
 # The same of the one-table pull, which is not bound: its few arrays go over it.
@@ -453,23 +493,57 @@ def _counted(count_l, over):
     return (count_l[0] + functools.reduce(jnp.maximum, over),)
 
 
+def _slot_grads(all_g, h: int):
+    """A bag's gradient for each of its ``h`` slots, ``[m // h, d]`` ->
+    ``[m, d]`` slot-major as the ids lie, for a body that works slot by slot
+    (XLA's scatter, a lane-packed table's placing).  Scope
+    ``ps.sparse.push.bag``; ``h == 1``: the rows as they are."""
+    import jax
+    import jax.numpy as jnp
+
+    if h == 1:
+        return all_g
+    with jax.named_scope("ps.sparse.push.bag"):
+        return jnp.repeat(all_g, h, axis=0)
+
+
 def _push_slots(axis, S, R, idx_l, grads_l, work, over):
     """A push body's slots through :func:`_exchange`: ``work(owned, local,
-    all_g)`` on the slots this shard is sent, ``local s32[m]`` a slot's row
+    all_g, h)`` on the slots this shard is sent, ``local s32[m]`` a slot's row
     here, ``owned pred[m]`` whether it is this shard's (elsewhere ``local``
-    is not for use), ``all_g [m, d]`` the slots' gradient rows.  Routed,
-    ``m = S * C``: ids and gradient rows by ``all_to_all``, worker-major,
-    the padding not owned and its rows zeros.  Gathered, ``m = W * n``:
-    :func:`_route_ids`, :func:`_route_grads`."""
+    is not for use), ``all_g [m // h, d]`` the slots' gradient rows, slot
+    ``i``'s being ``all_g[i // h]``.  Routed, ``m = S * C``: ids and gradient
+    rows by ``all_to_all``, worker-major, the padding not owned and its rows
+    zeros, ``h`` 1.  Gathered, ``m = W * n``: :func:`_route_ids`,
+    :func:`_route_grads`.
+
+    Bags (``idx_l`` is ``s32[1, B, h]``, ``grads_l`` ``[1, B, d]``: one
+    gradient a BAG): the slots are the bags' ids in the batch's order, ``n =
+    B * h``, and slot ``i`` of a worker lies in its bag ``i // h``.  Gathered,
+    the bags' gradients go as they are (``h`` the bag's size) and are read
+    through the bag where the combine gathers them into sorted order
+    (:func:`_combine_rows`): no ``[n, d]`` copy of them is made.  Routed, a
+    bucket's rows are gathered out of the batch through the bag, in scope
+    ``ps.sparse.push.bag``, and cross the chips a slot each."""
     import jax
     from jax import lax
     import jax.numpy as jnp
 
+    h = 1
+    if idx_l.ndim == 3:
+        h = idx_l.shape[2]
+        idx_l = idx_l.reshape(1, -1)
+    if grads_l.shape[1] * h != idx_l.shape[1]:
+        raise ValueError(
+            f"sparse push: gradients {tuple(grads_l.shape[1:])} a worker for "
+            f"{idx_l.shape[1] // h} bags of {h} ids: one gradient row a "
+            f"{'bag' if h > 1 else 'lookup'}")
+
     def gathered():
         with jax.named_scope("ps.sparse.route"):
             owned, local = _route_ids(axis, S, idx_l)
-            all_g = _route_grads(axis, grads_l)                # [W*n, d]
-        return work(owned, local, all_g)
+            all_g = _route_grads(axis, grads_l)                # [W*B, d]
+        return work(owned, local, all_g, h)
 
     def routed(rows, src, _):
         with jax.named_scope("ps.sparse.route"):
@@ -482,11 +556,17 @@ def _push_slots(axis, S, R, idx_l, grads_l, work, over):
                 # from the parameter itself, in HBM, it takes 10.5.
                 g = grads_l[0]
                 g = jnp.concatenate([g, jnp.zeros((1, g.shape[1]), g.dtype)])
-                sent = g[src.reshape(-1)]                      # [S*C, d]
+                src = src.reshape(-1)
+                if h > 1:
+                    # The place behind the batch, n = B * h, is bag B: the
+                    # row of zeros.
+                    with jax.named_scope("ps.sparse.push.bag"):
+                        src = src // h
+                sent = g[src]                                  # [S*C, d]
                 all_g = _all_to_all(
                     sent.reshape(S, -1, sent.shape[1]), axis
                 ).reshape(sent.shape)                          # [W*C, d]
-        return work(local < R, local, all_g)
+        return work(local < R, local, all_g, 1)
 
     return _exchange(axis, S, R, idx_l, over, gathered, routed)
 
@@ -516,7 +596,9 @@ def _scatter_rows(axis, S, R, pack, dim, store_l, idx_l, grads_l,
     owner and the indices' exchange, ``ps.sparse.route.grads``, a push's
     gradient rows bucketed and exchanged, and ``ps.sparse.route.rows``, the
     pull's rows handed back), ``ps.sparse.push.scatter_add``,
-    ``ps.sparse.pull.gather``, ``ps.sparse.combine`` (sort and segment sum
+    ``ps.sparse.pull.gather``, ``ps.sparse.pull.pool`` (a pooled pull's sum
+    over a bag), ``ps.sparse.push.bag`` (where a pooled push reads a slot's
+    gradient through its bag), ``ps.sparse.combine`` (sort and segment sum
     of duplicates: under a stateful handle, and before ``row_add`` in the
     sum), ``ps.sparse.pack.place`` (a lane-packed table's rows placed in
     their slot's lanes, and merged by physical row where ``row_add``
@@ -527,25 +609,32 @@ def _scatter_rows(axis, S, R, pack, dim, store_l, idx_l, grads_l,
 
     from ..ops.row_add import row_add
 
-    def scatter(store_l, owned, local, all_g):
+    def scatter(store_l, owned, local, all_g, h):
         with jax.named_scope("ps.sparse.push.scatter_add"):
-            masked = jnp.where(owned[:, None], all_g, 0)
+            masked = jnp.where(owned[:, None], _slot_grads(all_g, h), 0)
             # R: out of bounds (as R // pack is in the packed table), drop
             masked, rows = _place_rows(masked, jnp.where(owned, local, R),
                                        pack)
             return store_l.at[rows].add(masked, mode="drop")
 
-    def by_distinct_row(store_l, owned, local, all_g, interpret):
+    def by_distinct_row(store_l, owned, local, all_g, h, interpret):
         with jax.named_scope("ps.sparse.combine"):
-            G_seg, row_seg, valid = _combine_phys_rows(
-                jnp.where(owned, local, R), all_g, R, pack)
+            local = jnp.where(owned, local, R)
+            # An unpacked table's gradients are read through the bag by the
+            # combine itself; a packed one's are placed slot by slot.
+            G_seg, row_seg, valid = (
+                _combine_rows(local, all_g, R, h) if pack == 1
+                else _combine_phys_rows(local, _slot_grads(all_g, h), R,
+                                        pack))
         with jax.named_scope("ps.sparse.push.scatter_add"):
             return row_add(store_l, row_seg, G_seg, jnp.sum(valid),
                            interpret=interpret)
 
-    def write(owned, local, all_g):
-        return _on_row_add(scatter, by_distinct_row, store_l, owned, local,
-                           all_g)
+    def write(owned, local, all_g, h):
+        # ``h``, a bag's size, is the program's and no operand.
+        return _on_row_add(functools.partial(scatter, h=h),
+                           functools.partial(by_distinct_row, h=h),
+                           store_l, owned, local, all_g)
 
     return _push_slots(axis, S, R, idx_l, grads_l, write, over)
 
@@ -750,10 +839,14 @@ def _combine_phys_rows(local, g, R, pack):
         return _combine_rows(phys, placed, R // pack)
 
 
-def _combine_rows(local, all_g, R):
+def _combine_rows(local, all_g, R, h: int = 1):
     """Combine the duplicates of a gathered batch: ``local`` is ``s32[m]``,
     a slot's row on this shard or the sentinel ``R`` where another shard
-    owns it, ``all_g`` the slots' gradient rows ``[m, d]``.  Returns
+    owns it, ``all_g`` the slots' gradient rows ``[m, d]``, or with ``h > 1``
+    the bags' ``[m // h, d]``, slot ``i`` taking ``all_g[i // h]``: the one
+    gather that brings the gradients into sorted order reads them through
+    the bag (scope ``ps.sparse.push.bag``), so every sum below adds what it
+    would add of the rows multiplied out, in the same order.  Returns
     ``G_seg [m, d]``, ``row_seg s32[m]``, ``valid pred[m]``: the distinct
     owned rows ascending, each once with the sum of its slots' gradients,
     the valid ones first.  Past them ``row_seg`` is ``R`` and ``G_seg`` is
@@ -770,13 +863,18 @@ def _combine_rows(local, all_g, R):
     order's.  Shared by both pushes: the stateful handle's
     (:func:`_adagrad_sparse`) and, where ``row_add`` writes the table, the
     plain sum's (:func:`_scatter_rows`)."""
+    import jax
     from jax import lax
     import jax.numpy as jnp
 
     m = local.shape[0]
     sr, order = lax.sort((local.astype(jnp.int32), lax.iota(jnp.int32, m)),
                          num_keys=1, is_stable=True)
-    sg = all_g[order]
+    if h == 1:
+        sg = all_g[order]
+    else:
+        with jax.named_scope("ps.sparse.push.bag"):
+            sg = all_g[order // h]
     # One segment a distinct row; the sentinels sort last, into one.
     first = jnp.concatenate([jnp.ones((1,), bool), sr[1:] != sr[:-1]])
     seg = jnp.cumsum(first) - 1                                # [m]
@@ -822,12 +920,12 @@ def _adagrad_sparse(axis, S, R, pack, dim, store_l, acc_l, idx_l,
     import jax
     import jax.numpy as jnp
 
-    def update(owned, local, all_g):                           # [m], [m, d]
+    def update(owned, local, all_g, h):                   # [m], [m // h, d]
         with jax.named_scope("ps.sparse.route"):
             local = jnp.where(owned, local, R)  # R = sentinel (dropped)
 
         with jax.named_scope("ps.sparse.combine"):
-            G_seg, row_seg, valid = _combine_rows(local, all_g, R)
+            G_seg, row_seg, valid = _combine_rows(local, all_g, R, h)
 
         with jax.named_scope("ps.update"):
             # Accumulator: the touched rows read, stepped and written back
@@ -862,10 +960,34 @@ def _pull_rows(axis, S, store_l, idx_l, pack: int = 1, dim: int = None,
     zeros elsewhere, and a psum_scatter over the worker dimension that sums
     the one-hot contributions and routes each worker its batch.  Shared
     single/group; packed stores gather the 128-lane physical row and select
-    the logical slot (see SparseTable.pack)."""
+    the logical slot (see SparseTable.pack).
+
+    Bags (``idx_l`` is ``s32[1, B, h]``): the rows of every slot as above,
+    back with the worker and a packed table's slot selected, then in scope
+    ``ps.sparse.pull.pool`` the sum over a bag, added in f32 in the order
+    of the bag's slots: ``[B, d]``.  A row that lies twice in a bag is
+    added twice."""
     import jax
     from jax import lax
     import jax.numpy as jnp
+
+    if idx_l.ndim == 3:
+        _, B, h = idx_l.shape
+        if h == 1:
+            return _pull_rows(axis, S, store_l, idx_l.reshape(1, B), pack,
+                              dim, over)
+        # The slots SLOT-major, ``[h, B]``: the gathered rows are then ``h``
+        # slabs of ``[B, d]`` and the pool a sum of slabs, each laid out as
+        # the result is.  Bag-major, ``[B, h, d]``, a v5e's compiler re-lays
+        # the whole ``[B * h, d]`` before it can reduce over ``h`` (a bag's
+        # rows do not fill tiles of 8: 218 MB written and read again for
+        # the 100-id table of 4,096 bags).
+        with jax.named_scope("ps.sparse.pull.pool"):
+            by_slot = idx_l.reshape(B, h).T.reshape(1, h * B)
+        rows = _pull_rows(axis, S, store_l, by_slot, pack, dim, over)
+        with jax.named_scope("ps.sparse.pull.pool"):
+            return jnp.sum(rows.reshape(h, B, -1), axis=0,
+                           dtype=jnp.float32).astype(rows.dtype)
 
     d = store_l.shape[1] if pack == 1 else dim
 
@@ -1050,6 +1172,14 @@ class SparseEngine:
                        fn=lambda: self._clock.grouped_totals()[1])
         registry.gauge("engine.sparse.group.tables",
                        fn=lambda: self._clock.grouped_totals()[0])
+        # Pooled ops (``pool="sum"`` with a bag of more than one id), the
+        # bags and the lookups they carried: lookups / bags is the mean bag.
+        registry.gauge("engine.sparse.pool.ops",
+                       fn=lambda: self._clock.pooled_totals()[2])
+        registry.gauge("engine.sparse.pool.bags",
+                       fn=lambda: self._clock.pooled_totals()[0])
+        registry.gauge("engine.sparse.pool.lookups",
+                       fn=lambda: self._clock.pooled_totals()[1])
         # Records built for grouped ops (:meth:`_bind`): this engine's.  The
         # bound share of a window's grouped ops is 1 - binds / ops.
         registry.gauge("engine.sparse.group.binds",
@@ -1095,7 +1225,9 @@ class SparseEngine:
         self._clock.program_built()
         return prog
 
-    def _sparse_program(self, op: str, table: SparseTable, batch: int):
+    def _sparse_program(self, op: str, table: SparseTable, batch):
+        """The one-table program of ``op`` at ``batch`` (a batch as the
+        programs are keyed by it: ``n``, or ``(B, h)`` of bags)."""
         key = (op, table.name, batch, table.pack)
         with self._mu:
             prog = self._programs.get(key)
@@ -1135,6 +1267,9 @@ class SparseEngine:
         routed = self._routed(batch)
         count_spec = (P(axis),) * routed
         count_sh = (_sh(P(axis)),) * routed
+        # Ids ``[W, n]`` or bags ``[W, B, h]``: a spec names the leading
+        # axes, a worker's batch on its device either way.
+        idx_spec = P(axis, None)
 
         def _push(store_l, idx_l, grads_l, *count_l):
             # Add directly into the donated (packed) store: see
@@ -1170,7 +1305,7 @@ class SparseEngine:
             fn = jax.shard_map(
                 _push,
                 mesh=self.mesh,
-                in_specs=(P(axis, None), P(axis, None), P(axis, None, None),
+                in_specs=(P(axis, None), idx_spec, P(axis, None, None),
                           *count_spec),
                 out_specs=(P(axis, None), P(axis, None), *count_spec),
                 check_vma=False,
@@ -1185,7 +1320,7 @@ class SparseEngine:
             fn = jax.shard_map(
                 _push_row_adagrad,
                 mesh=self.mesh,
-                in_specs=(P(axis, None), P(axis), P(axis, None),
+                in_specs=(P(axis, None), P(axis), idx_spec,
                           P(axis, None, None), P(), P(), *count_spec),
                 out_specs=(P(axis, None), P(axis), P(axis, None),
                            *count_spec),
@@ -1200,7 +1335,7 @@ class SparseEngine:
             fn = jax.shard_map(
                 _pull,
                 mesh=self.mesh,
-                in_specs=(P(axis, None), P(axis, None), *count_spec),
+                in_specs=(P(axis, None), idx_spec, *count_spec),
                 out_specs=(P(axis, None, None), *count_spec) if routed
                 else P(axis, None, None),
                 check_vma=False,
@@ -1223,20 +1358,41 @@ class SparseEngine:
             self.mesh, host_arr, sharding, self._multiprocess
         )
 
-    def _prep_ids(self, indices):
+    def _prep_ids(self, indices, pool: Optional[str] = None):
         """``[W, n]`` row ids as the programs take them: int32, sharded over
-        the worker axis.
+        the worker axis; under ``pool`` ``[W, B, h]``, bags of ``h`` ids, the
+        same way.  Another rank is refused here, by name.  Bags of one id are
+        rows: a host array's are handed on as ``[W, B]`` (a view), a device
+        array's as they lie (a reshape there would be a launch), to the
+        program of ``[W, B]`` either way (:func:`_batch_of`).
 
         On a multi-process mesh the host input carries only THIS process's
         worker rows (``[local, n]``; :meth:`_prep_grads`: ``[local, n, d]``)."""
         import jax
 
-        sharding = self._idx_sharding
+        sharding, ndim = self._idx_sharding, 2
+        # (A refusal's message is made where it is refused: an f-string a
+        # call is microseconds of every op's ``prep``.)
+        if pool is not None:
+            if pool != "sum":
+                log.check(False, f"unknown pool {pool!r}: a bag's rows are "
+                                 f"pooled by 'sum'")
+            shape = np.shape(indices)
+            if len(shape) != 3:
+                log.check(False,
+                          f"pool={pool!r}: ids must be [W, B, h], a worker's "
+                          f"B bags of h ids each (rank 3), not of shape "
+                          f"{tuple(shape)}")
+            if shape[2] == 1 and not isinstance(indices, jax.Array):
+                indices = np.asarray(indices).reshape(shape[:2])
+            else:
+                sharding, ndim = self._g_sharding, 3
         if self._is_multiprocess():
             idx = np.ascontiguousarray(np.asarray(indices, dtype=np.int32))
             log.check_eq(int(idx.shape[0]), self._local_shards(),
                          "bad local worker dim (rows = this process's "
                          "devices on a multi-process mesh)")
+            log.check_eq(idx.ndim, ndim, "bad rank of sparse ids")
             return jax.make_array_from_process_local_data(
                 sharding, idx, (self.num_shards,) + idx.shape[1:]
             )
@@ -1245,9 +1401,14 @@ class SparseEngine:
         # device array that already lies as the program takes it (a
         # trainer's own batch) is passed on as it is: the cast and the
         # placement would both hand it back, ~0.13 ms of the host later.
-        placed = _lies_as(indices, sharding, _INT32, 2)
+        placed = _lies_as(indices, sharding, _INT32, ndim)
         idx = (indices if placed
                else staging_xp(indices).asarray(indices, dtype=_INT32))
+        if idx.ndim != ndim:
+            log.check(False,
+                      f"sparse ids must be [W, n], one id a lookup (rank 2), "
+                      f"not of shape {tuple(idx.shape)}: bags [W, B, h] go "
+                      f"with pool='sum'")
         log.check_eq(int(idx.shape[0]), self.num_shards, "bad worker dim")
         return idx if placed else jax.device_put(idx, sharding)
 
@@ -1267,15 +1428,17 @@ class SparseEngine:
         return jax.device_put(
             staging_xp(grads).asarray(grads, dtype=dtype), sharding)
 
-    def _prep(self, table: SparseTable, indices, grads=None):
+    def _prep(self, table: SparseTable, indices, grads=None, pool=None):
         """A one-table op's ``(ids, gradients or None)``, placed."""
-        return (self._prep_ids(indices),
+        return (self._prep_ids(indices, pool),
                 None if grads is None
                 else self._prep_grads(np.dtype(table.dtype), grads))
 
-    def _payload(self, table: SparseTable, batch: int) -> int:
-        """Bytes an op of ``batch`` lookups a worker moves of ``table``."""
-        return (self.num_shards * batch * table.dim
+    def _payload(self, table: SparseTable, batch) -> int:
+        """Bytes an op of ``batch`` a worker moves of ``table``: a row a
+        lookup, of bags a row a BAG (what crosses the API: the pooled rows
+        out, the bags' gradients in)."""
+        return (self.num_shards * _bags(batch) * table.dim
                 * np.dtype(table.dtype).itemsize)
 
     def _observe(self, op: str, payload: int) -> None:
@@ -1410,8 +1573,8 @@ class SparseEngine:
         kind, params = self._parse_handle(handle)
         return kind, tuple(jnp.float32(p) for p in params)
 
-    def _bind(self, op: str, names, handle: Optional[str], batches
-              ) -> _Bound:
+    def _bind(self, op: str, names, handle: Optional[str], batches,
+              grads=None) -> _Bound:
         """Stage ``select`` of the first op of this key (and of the first
         after a reshard, a new registration of a member or a change of its
         packing dropped the record): ``push(name, ., ., handle)`` at a
@@ -1419,8 +1582,10 @@ class SparseEngine:
         program) or a grouped op of ``names`` at ``batches`` (tuples: the
         group program).  The handle parsed, its numbers placed as device
         scalars, the program, and what an op adds to each counter.  A
-        table named twice in a grouped push and an unknown handle fail
-        here, by name.  Call with the tables' locks held."""
+        table named twice in a grouped push, an unknown handle and a
+        gradient (``grads``: the push's, placed) that is not a row a lookup,
+        or a row a bag, of its table's width fail here, by name.  Call with
+        the tables' locks held."""
         group = type(names) is tuple
         key = (op, names, handle, batches)
         if not group:
@@ -1432,6 +1597,15 @@ class SparseEngine:
                       f"a table's store is donated to the program once "
                       f"(push its rows in one entry, or in two ops)")
         tables = [self._tables[n] for n in names]
+        W = self.num_shards
+        for t, batch, g in zip(tables, batches, grads or ()):
+            want = (W, _bags(batch), t.dim)
+            if tuple(g.shape) != want:
+                log.check(False,
+                          f"push of table {t.name!r}: gradients of shape "
+                          f"{tuple(g.shape)}, not {want}: [W, "
+                          f"{'B' if type(batch) is tuple else 'n'}, d], one "
+                          f"row a {'bag' if type(batch) is tuple else 'lookup'}")
         kind, params = (None, ()) if handle is None \
             else self._handle_scalars(handle)
         stateful = kind is not None
@@ -1461,7 +1635,7 @@ class SparseEngine:
             tuple(np.dtype(t.dtype) for t in tables),
             sum(map(self._payload, tables, batches)),
             launched("sparse." + op, arrays),
-            entries)
+            entries, _pooled(W, batches))
         with self._mu:
             # A new registration meanwhile: the next op binds.
             if all(self._tables.get(t.name) is t for t in tables):
@@ -1479,10 +1653,10 @@ class SparseEngine:
         self.acc_kernel_pushes += b.acc_kernel
         self.packed_pushes += b.packed
 
-    def _route_slots(self, batch: int) -> int:
+    def _route_slots(self, batch) -> int:
         """The rows of the batch workspace one shard's bound body works on
-        in an op of ``batch`` lookups a worker (the push combines them, the
-        pull gathers them): ``S * C`` where the exchange is routed by owner
+        in an op of ``batch`` (its lookups, :func:`_lookups`) a worker (the
+        push combines them, the pull gathers them): ``S * C`` where the exchange is routed by owner
         (a shard is sent the slots it owns, in buckets of :func:`_capacity`:
         1.5 a lookup), W x ``batch`` where every shard is sent every
         worker's batch (one shard, or a batch too small to route).  From
@@ -1490,13 +1664,13 @@ class SparseEngine:
         at the bound body's count (``engine.sparse.route.overflow`` counts
         those); an op notes it once (``SPARSE_ROUTE``, gauge
         ``engine.sparse.route.slots``)."""
-        return _slots(self.num_shards, batch)
+        return _slots(self.num_shards, _lookups(batch))
 
-    def _routed(self, batch: int) -> bool:
+    def _routed(self, batch) -> bool:
         """Whether this mesh's programs of ``batch`` lookups a worker are
         bound to the routed body (:func:`_routes`), and so take and return
         an overflow count."""
-        return _routes(self.num_shards, batch)
+        return _routes(self.num_shards, _lookups(batch))
 
     def _row_kernel(self, table: SparseTable) -> bool:
         """Whether this mesh's push programs of ``table``, the sum's and a
@@ -1520,7 +1694,7 @@ class SparseEngine:
                      or (stateful
                          and _row_add_takes(table.dim, table.dtype))))
 
-    def _acc_kernel(self, table: SparseTable, batch: int) -> bool:
+    def _acc_kernel(self, table: SparseTable, batch) -> bool:
         """Whether this mesh's stateful push program of ``table`` at
         ``batch`` lookups a worker updates the accumulator with
         ``ops/acc_update.py`` (the rule of :func:`_update_acc`)."""
@@ -1528,7 +1702,8 @@ class SparseEngine:
                 and _acc_update_takes(table.rows_per_shard,
                                       self._route_slots(batch)))
 
-    def push(self, name: str, indices, grads, handle: str = None):
+    def push(self, name: str, indices, grads, handle: str = None,
+             pool: str = None):
         """indices: [W, n] int rows per worker; grads: [W, n, d].
         Duplicate rows (within or across workers) accumulate — the
         aggregation contract of the default server handle.
@@ -1539,20 +1714,27 @@ class SparseEngine:
         ``-lr * G / (sqrt(acc) + eps)`` — the fused sparse analog of the
         dense engine's optimizer handles.
 
+        ``pool="sum"``: a lookup is a BAG.  indices ``[W, B, h]``, a worker's
+        ``B`` bags of ``h`` ids each; grads ``[W, B, d]``, one gradient a bag,
+        which every slot of the bag brings to its row: what a push of
+        ``[W, B * h]`` ids with each gradient repeated ``h`` times adds, sum
+        for sum (a row that lies twice in a bag receives the gradient twice),
+        without the ``[W, B * h, d]`` array.  Bags of one id are rows.
+
         Bound once, launched many times: what no two pushes of ``(name,
         handle, batch)`` differ in is a :class:`_Bound` that the first
         builds (:meth:`_bind`) and the others look up."""
         t0 = stamp()  # stage borders: see _note
         table = self._tables[name]
-        idx, g = self._prep(table, indices, grads)
-        batch = int(idx.shape[1])
+        idx, g = self._prep(table, indices, grads, pool)
+        batch = _batch_of(idx)
         t1 = stamp()  # prep | select
         # The record depends on table.pack, which the orbax compat shim
         # can mutate — look it up under the lock (so the sparse stages run
         # prep, select, launch, and select has the wait for the lock).
         with self._table_mu[name]:
             b = (self._bound.get((name, handle, batch))
-                 or self._bind("push", name, handle, batch))
+                 or self._bind("push", name, handle, batch, (g,)))
             t2 = stamp()  # select | launch
             count = (self._overflow_count(name),) if b.routed else ()
             if b.kind is None:
@@ -1574,6 +1756,8 @@ class SparseEngine:
         self._observe("push", b.payload)
         t3 = stamp()
         self._note((SPARSE_ROUTE, t3, b.slots, -1, -1))
+        if b.pooled:
+            self._note((SPARSE_POOL, t3, *b.pooled, -1))
         self._note((LAUNCH, t3, c1 - c0, t3 - t2, b.launched))
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
         # The token is a tiny non-donated output that becomes ready when
@@ -1587,7 +1771,8 @@ class SparseEngine:
         recommender step, dense analog: engine.push_pull_group).  Each
         table's body is a one-table program's, inside
         ``ps.sparse.table.<name>`` under one ``ps.sparse.group``: a device
-        trace tells the tables apart by scope."""
+        trace tells the tables apart by scope.  ``batches``: a table's batch
+        each, ``n`` or ``(B, h)`` of bags, as the programs are keyed."""
         key = (op, tuple((t.name, t.pack) for t in tables), batches)
         with self._mu:
             prog = self._programs.get(key)
@@ -1606,7 +1791,7 @@ class SparseEngine:
 
         store_spec = P(axis, None)
         acc_spec = P(axis)
-        idx_spec = P(axis, None)
+        idx_spec = P(axis, None)  # ids [W, n] or bags [W, B, h] alike
         g_spec = P(axis, None, None)
 
         packs = [t.pack for t in tables]
@@ -1741,19 +1926,25 @@ class SparseEngine:
             self._table_mu[n].release()
 
     def _group_inputs(self, op: str, names, handle, indices_list,
-                      grads_list=None):
+                      grads_list=None, pool=None):
         """Stage ``prep`` of a grouped op: its record's key, the tables'
         names in the order of their locks, and the inputs as the program
         takes them.  An input that lies so already (a trainer's own batch)
         costs a comparison; any other is cast and placed, that table alone
-        (:meth:`_prep_ids`, :meth:`_prep_grads`).  The record, where the
+        (:meth:`_prep_ids`, :meth:`_prep_grads`).  Under ``pool`` every
+        table's ids are bags ``[W, B, h_t]`` and the key holds ``(B, h_t)``
+        for a table (``B`` where ``h_t`` is 1).  The record, where the
         key has one, says what a gradient is compared with and in what order
         the locks go; the op itself looks it up again under the locks."""
-        W, sharding = self.num_shards, self._idx_sharding
-        idxs = [i if _lies_as(i, sharding, _INT32, 2) and i.shape[0] == W
-                else self._prep_ids(i) for i in indices_list]
+        W = self.num_shards
+        sharding, ndim = ((self._idx_sharding, 2) if pool is None
+                          else (self._g_sharding, 3))
+        idxs = [i if _lies_as(i, sharding, _INT32, ndim) and i.shape[0] == W
+                else self._prep_ids(i, pool) for i in indices_list]
+        batches = (tuple([i.shape[1] for i in idxs]) if pool is None
+                   else tuple([_batch_of(i) for i in idxs]))
         names = tuple(names)
-        key = (op, names, handle, tuple([i.shape[1] for i in idxs]))
+        key = (op, names, handle, batches)
         b = self._bound.get(key)
         ordered = b.order if b is not None else tuple(sorted(set(names)))
         if grads_list is None:
@@ -1767,21 +1958,21 @@ class SparseEngine:
         return key, ordered, idxs, gs
 
     def push_group(self, names, indices_list, grads_list,
-                   handle: str = None):
+                   handle: str = None, pool: str = None):
         """Push SEVERAL tables in one dispatch; same semantics per table
-        as :meth:`push` (``handle`` applies to all), and bound once as a
-        push is: the group's :class:`_Bound` by ``(names, handle,
-        batches)``."""
+        as :meth:`push` (``handle`` and ``pool`` apply to all, each table
+        with a bag size of its own), and bound once as a push is: the
+        group's :class:`_Bound` by ``(names, handle, batches)``."""
         log.check(len(names) == len(indices_list) == len(grads_list),
                   "group length mismatch")
         t0 = stamp()  # stage borders: see _note
         key, ordered, idxs, gs = self._group_inputs(
-            "push", names, handle, indices_list, grads_list)
+            "push", names, handle, indices_list, grads_list, pool)
         t1 = stamp()  # prep | select
         self._lock_tables(ordered)
         try:
             # Under the locks, as table.pack is resolved (see push).
-            b = self._bound.get(key) or self._bind(*key)
+            b = self._bound.get(key) or self._bind(*key, gs)
             t2 = stamp()  # select | launch
             stores, accs = self._stores, self._acc
             # The group's overflow count is its first table's.
@@ -1814,23 +2005,28 @@ class SparseEngine:
         # One op with one launch, whatever it groups.
         self._note((SPARSE_ROUTE, t3, b.slots, -1, -1))
         self._note((SPARSE_GROUP, t3, len(names), -1, -1))
+        if b.pooled:
+            self._note((SPARSE_POOL, t3, *b.pooled, -1))
         self._note((LAUNCH, t3, c1 - c0, t3 - t2, b.launched))
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
         self._observe("push", b.payload)
         return token
 
-    def pull_group(self, names, indices_list) -> PulledGroup:
+    def pull_group(self, names, indices_list, pool: str = None
+                   ) -> PulledGroup:
         """Pull SEVERAL tables in one dispatch.  The program gives ONE array
         ``[W, sum n_i, d]`` a class of ``(d, dtype)`` among the entries, so
         the runtime allocates one result a class and not one a table; what
         is returned is a :class:`PulledGroup` over them: a sequence of the
         entries' ``[W, n_i, d_i]`` rows in ``names`` order, an entry cut
         from its class's array when asked for and not before.  Bound once,
-        as :meth:`push_group`."""
+        as :meth:`push_group`.  ``pool="sum"``: every table's ids are bags
+        ``[W, B, h_t]`` and its entry the pooled rows ``[W, B, d]``
+        (:meth:`pull`), a class's side by side ``[W, sum B, d]``."""
         log.check(len(names) == len(indices_list), "group length mismatch")
         t0 = stamp()  # stage borders: see _note
         key, ordered, idxs, _ = self._group_inputs(
-            "pull", names, None, indices_list)
+            "pull", names, None, indices_list, None, pool)
         t1 = stamp()  # prep | select
         self._lock_tables(ordered)
         try:
@@ -1858,18 +2054,24 @@ class SparseEngine:
         t3 = stamp()
         self._note((SPARSE_ROUTE, t3, b.slots, -1, -1))
         self._note((SPARSE_GROUP, t3, len(names), -1, -1))
+        if b.pooled:
+            self._note((SPARSE_POOL, t3, *b.pooled, -1))
         self._note((LAUNCH, t3, c1 - c0, t3 - t2, b.launched))
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))
         return pulled
 
-    def pull(self, name: str, indices):
+    def pull(self, name: str, indices, pool: str = None):
         """indices: [W, n] -> [W, n, d] rows, each worker shard receiving its
         own batch: the pull program's own result (``P(axis, None, None)``),
-        one launch an op."""
+        one launch an op.  ``pool="sum"``: indices ``[W, B, h]``, bags of
+        ``h`` ids -> ``[W, B, d]``, a bag's rows summed in f32 where the
+        table lives (a row that lies twice in a bag added twice): what the
+        pull of ``[W, B * h]`` gives, summed over a bag, without the
+        ``[W, B * h, d]`` result.  Bags of one id are rows."""
         t0 = stamp()  # stage borders: see _note
         table = self._tables[name]
-        idx, _ = self._prep(table, indices)
-        batch = int(idx.shape[1])
+        idx, _ = self._prep(table, indices, None, pool)
+        batch = _batch_of(idx)
         t1 = stamp()  # prep | select
         with self._table_mu[name]:
             # Resolve table.pack under the lock (see push).
@@ -1890,6 +2092,9 @@ class SparseEngine:
         self._observe("pull", self._payload(table, batch))
         t3 = stamp()
         self._note((SPARSE_ROUTE, t3, self._route_slots(batch), -1, -1))
+        if type(batch) is tuple:
+            self._note((SPARSE_POOL, t3, *_pooled(self.num_shards, (batch,)),
+                        -1))
         self._note((LAUNCH, t3, c1 - c0, t3 - t2,
                     arrays << LAUNCH_SHIFT | _PULL))
         self._note((ENGINE_OP, t3, t2 - t1, t1 - t0, t3 - t2))

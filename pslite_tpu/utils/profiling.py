@@ -185,6 +185,17 @@ GROUPED = ("tables", "ops")
 # the kinds sum to rides behind ``GROUPED`` in the totals' vector, ``LAUNCHED``
 # a kind; :meth:`StageClock.launches` answers for a window.
 LAUNCH = 5
+# A seventh kind, stageless as the fourth: ``(SPARSE_POOL, t_end, bags,
+# lookups, -1)``, noted by ``SparseEngine`` once a POOLED op (``pool="sum"``
+# with a bag of more than one id: a lookup is the sum of a bag's rows, a
+# gradient a bag's), before its ``ENGINE_OP`` note too.  ``bags`` and
+# ``lookups`` are what the op carried over all workers and tables (the bags
+# of one id of its other tables among them), known from shapes when the op is
+# bound.  Their sums and the ops that noted them ride last in the totals'
+# vector (``POOLED``); :meth:`StageClock.pooled` answers for a window.  An op
+# that pools nothing notes none.
+SPARSE_POOL = 6
+POOLED = ("bags", "lookups", "ops")
 LAUNCH_OPS = ("dense.push_pull", "dense.push", "dense.pull", "sparse.pull",
               "sparse.push")
 LAUNCHED = ("calls", "ns", "call.ns", "arrays")  # the launches, their ns
@@ -235,6 +246,7 @@ _ROUTED = _OCC + len(OCCUPANCY)  # where ``ROUTED`` begins in the vector
 _GROUPED = _ROUTED + len(ROUTED)  # and ``GROUPED``
 _LAUNCHED = _GROUPED + len(GROUPED)  # and ``LAUNCHED``, kind after kind
 _KINDS = np.arange(len(LAUNCH_OPS))
+_POOLED = _LAUNCHED + len(LAUNCHED) * len(LAUNCH_OPS)  # and ``POOLED``, last
 # A spell's row: OCCUPANCY up to and with ``spells``.
 _PRELAUNCH, _LAUNCH = 0, 1
 _COPY, _ROUTE, _SELECT, _PREP, _OUTSIDE = (
@@ -244,6 +256,7 @@ _SPELLS = OCCUPANCY.index("spells")
 OccupancyWindow = Tuple[Dict[str, int], int, float]
 RoutedWindow = Tuple[Tuple[int, int], int, float]
 GroupedWindow = RoutedWindow
+PooledWindow = Tuple[Tuple[int, int, int], int, float]
 Launches = Dict[str, Tuple[int, int, int, int]]  # a kind: ``LAUNCHED``
 LaunchesWindow = Tuple[Launches, int, float]
 _NO_TIMES = np.empty(0, dtype=np.int64)
@@ -298,9 +311,9 @@ class StageClock:
         self.backlog = self._pending.__len__  # notes not yet folded
         self._fold_mu = threading.Lock()
         # ns, calls of each stage; then the occupancy account; then what
-        # the sparse ops routed, what the grouped ones carried, and the
-        # launch stage by op kind
-        self._totals = [0] * (_LAUNCHED + len(LAUNCHED) * len(LAUNCH_OPS))
+        # the sparse ops routed, what the grouped ones carried, the
+        # launch stage by op kind, and what the pooled ops carried
+        self._totals = [0] * (_POOLED + len(POOLED))
         # slot -> the totals at its start
         self._marks: Dict[int, Tuple[int, ...]] = {}
         self._slot = -1  # the newest slot an op ended in
@@ -365,6 +378,11 @@ class StageClock:
                 grouped = of_slot[of_slot[:, 0] == SPARSE_GROUP, 2]
                 tot[_GROUPED] += int(grouped.sum())
                 tot[_GROUPED + 1] += len(grouped)
+                pooled = of_slot[of_slot[:, 0] == SPARSE_POOL, 2:4]
+                if len(pooled):
+                    tot[_POOLED] += int(pooled[:, 0].sum())
+                    tot[_POOLED + 1] += int(pooled[:, 1].sum())
+                    tot[_POOLED + 2] += len(pooled)
                 calls = of_slot[of_slot[:, 0] == LAUNCH, 2:]
                 if len(calls):
                     code = calls[:, 2]
@@ -584,13 +602,14 @@ class StageClock:
         slots of the clock, seconds)``."""
         return self._pair_between(_ROUTED, t_lo, t_hi)
 
-    def _pair_between(self, at: int, t_lo: float, t_hi: float):
-        """A sum and its ops at ``at`` of the totals' vector (``ROUTED``,
-        ``GROUPED``) over the whole slots inside ``[t_lo, t_hi]``."""
+    def _pair_between(self, at: int, t_lo: float, t_hi: float, n: int = 2):
+        """The ``n`` sums at ``at`` of the totals' vector (``ROUTED``,
+        ``GROUPED``: a sum and its ops; ``POOLED``) over the whole slots
+        inside ``[t_lo, t_hi]``."""
         grown, slots, seconds = self._between(t_lo, t_hi)
         if not slots:
-            return (0, 0), 0, 0.0
-        return tuple(grown[at:at + 2]), slots, seconds
+            return (0,) * n, 0, 0.0
+        return tuple(grown[at:at + n]), slots, seconds
 
     def grouped_totals(self) -> Tuple[int, int]:
         """``(tables, ops)`` of the grouped sparse ops since the process
@@ -604,6 +623,19 @@ class StageClock:
         t_hi]``, as :meth:`routed` answers for the slots: ``((tables, ops),
         slots of the clock, seconds)``."""
         return self._pair_between(_GROUPED, t_lo, t_hi)
+
+    def pooled_totals(self) -> Tuple[int, int, int]:
+        """``(bags, lookups, ops)`` of the pooled sparse ops since the
+        process started (``SPARSE_POOL``): the bags and the lookups they
+        carried, summed over the ops, and the ops."""
+        self.fold()
+        return tuple(self._totals[_POOLED:_POOLED + len(POOLED)])
+
+    def pooled(self, t_lo: float, t_hi: float) -> PooledWindow:
+        """:meth:`pooled_totals` over the whole slots inside ``[t_lo,
+        t_hi]``, as :meth:`routed` answers for the slots: ``((bags, lookups,
+        ops), slots of the clock, seconds)``."""
+        return self._pair_between(_POOLED, t_lo, t_hi, len(POOLED))
 
     def launches_totals(self) -> Launches:
         """``{op kind: (calls, ns, call ns, arrays)}`` since the process
@@ -694,6 +726,12 @@ class _NullStageClock:
 
     def grouped(self, t_lo: float, t_hi: float) -> GroupedWindow:
         return (0, 0), 0, 0.0
+
+    def pooled_totals(self) -> Tuple[int, int, int]:
+        return 0, 0, 0
+
+    def pooled(self, t_lo: float, t_hi: float) -> PooledWindow:
+        return (0, 0, 0), 0, 0.0
 
     def launches_totals(self) -> Launches:
         return {}

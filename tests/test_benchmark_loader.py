@@ -152,6 +152,70 @@ def test_the_many_tables_cell_names_its_tables_traffic_and_driver(harness):
                 "packed_combine_ms", "route_ms"} & names
 
 
+def test_the_bags_cell_names_its_tables_bag_sizes_traffic_and_driver(harness):
+    """``dlrm-dcnv2-multihot.bags`` (PR 54): MLPerf's DLRM-DCNv2 tables, in
+    which a lookup is a bag of ``multi_hot_sizes[t]`` ids, under a driver of
+    its own built on the many-tables driver, and the five per-layer metrics
+    that only this cell reports."""
+    cell = harness.load_cell("dlrm-dcnv2-multihot.bags")
+    config, traffic = cell.config, cell.traffic
+    sibling = harness.load_cell("dlrm-criteo-rowadagrad.zipf").config
+    assert config["kind"] == "sparse" and config["dtype"] == "float32"
+    assert config["server_handle"] == sibling["server_handle"] \
+        == "row_adagrad:0.004,1e-8"
+    assert config["reduced"] == ["max_ind_range"]
+    tables, hs = config["tables"], config["bag_sizes"]
+    assert [name for name, _ in tables] == [f"emb{i:02d}" for i in range(26)]
+    rows, sizes = [r for _, r in tables], config["sizes"]
+    cap = sizes["max_ind_range"]
+    assert rows == [min(c, cap) for c in sizes["num_embeddings_per_feature"]]
+    assert hs == sizes["multi_hot_sizes"] and len(hs) == 26
+    assert (sum(hs), max(hs), hs.count(1)) == (214, 100, 11)
+    assert sum(rows) == config["rows"]
+    assert (min(rows), max(rows), rows.count(cap)) == (3, cap, 5)
+    # No width of the source is changed; the sibling's guarantee word for
+    # word up to the bag's lines.
+    assert config["dim"] == sizes["embedding_width"] == sibling["dim"] == 128
+    assert sizes["max_ind_range_published"] == 40_000_000
+    head = sibling["guarantees"].split("(KVWorker.push_sparse ")[0]
+    assert config["guarantees"].startswith(head)
+    for phrase in ("a row that lies twice in a bag added twice",
+                   "each slot exactly once",
+                   "A table's rows are touched by its own ids alone",
+                   "No cell may weaken this."):
+        assert phrase in config["guarantees"], phrase
+    assert set(config["limits"]) == {"first3_err", "final_err", "acc_err"}
+    assert traffic["name"] == "zipf-bags-4096x214"
+    assert traffic["driver"] == "sparse_bags_pull_push"
+    B = traffic["bags_per_table"]
+    assert B == sizes["samples_per_worker"] == 4096
+    assert B * sum(hs) == traffic["lookups_per_worker"] == 876_544
+    entry = next(c for c in BENCHMARK["configs"]
+                 if c["name"] == "dlrm-dcnv2-multihot")
+    assert entry["source"] == config["source"]
+    assert entry["reduced"] == config["reduced"]
+    assert len(config["source"]) <= 200
+    # The whole deployment on one chip: the tables and an f32 accumulator a
+    # row, above the floor of a quarter of the chip.
+    held = sum(rows) * (512 + 4)
+    assert 0.25 * 16e9 < held < 16e9
+    driver = harness.resolve(cell)
+    base = harness.load_driver(cell.search, "sparse_tables_pull_push")
+    assert issubclass(driver, base) and driver is not base
+    names = {m["name"] for m in cell.per_layer}
+    assert {"bag_lookups_per_bag", "bag_pull_ms", "bag_pull_roofline",
+            "bag_combine_ms", "bag_write_ms", "roofline_share", "busy_ms",
+            "launches_per_step", "ops_per_step"} <= names
+    assert not {"combine_ms", "table_write_ms", "tables_combine_ms",
+                "tables_write_ms", "sparse_tables_per_op", "launch_pull_ms",
+                "launch_push_ms"} & names
+    for name in ("bag_lookups_per_bag", "bag_pull_ms", "bag_pull_roofline",
+                 "bag_combine_ms", "bag_write_ms"):
+        entry, = [m for m in BENCHMARK["per_layer"] if m["name"] == name]
+        assert entry["workloads"] == ["dlrm-dcnv2-multihot.bags"]
+        assert callable(harness.load_reader(cell.search, name))
+
+
 # What an addition PR may do: append entries, each at the end of its list,
 # and add files under ``paths``.  The occupancy account's three metrics
 # (PR 37) came that way.

@@ -526,3 +526,57 @@ def test_two_shards_route_and_match_the_gathered_body(monkeypatch, handle):
         table = got[0][0].reshape(S, -1, 128).transpose(1, 0, 2)
         assert _row_error(table.reshape(-1, 128)[:ROWS],
                           _reference(init, idx, grads)) < TOL
+
+
+@pytest.mark.parametrize("handle", list(HANDLES))
+def test_bags_over_four_servers_pool_after_the_rows_are_back(cluster, handle):
+    """``pool="sum"`` over four shards (ISSUE 54): a worker's bags are routed
+    a SLOT each (a bucket's gradient rows are read through the bag as they
+    are bucketed) and pooled once the rows are back with the worker.  Three
+    pooled pushes and a pooled pull of bags of 5 ids are, bit for bit, the
+    unpooled ops of the bags multiplied out; a batch whose every id has one
+    owner does not fit its buckets, falls back in the same program, stays
+    exact and is counted."""
+    kv, eng = cluster
+    h, B = 5, N // 4                 # 160 slots a worker: buckets of 60
+    rng = np.random.default_rng(54)
+    init = rng.normal(size=(ROWS, 128)).astype(np.float32)
+    bags = [_zipf(rng, (W, B, h)) for _ in range(STEPS)]
+    for batch in bags:
+        batch[:, 0, 0] = HOT            # every worker sends the hottest row
+        batch[:, 1, 1] = batch[:, 1, 0]     # an id twice in one bag
+    # Every id on shard 1: a bucket would need 160 slots and holds 60.
+    bags[1] = (rng.integers(0, ROWS // W, size=(W, B, h)) * W + 1
+               ).astype(np.int32)
+    grads = [rng.normal(size=(W, B, 128)).astype(np.float32)
+             for _ in range(STEPS)]
+    eng.register_sparse("bags", ROWS, 128, init=init)
+    eng.register_sparse("rows", ROWS, 128, init=init)
+    hd = HANDLES[handle]
+    over0 = eng.route_overflows()
+    for i, g in zip(bags, grads):
+        kv.wait(kv.push_sparse("bags", i, g, hd, pool="sum"))
+        kv.wait(kv.push_sparse("rows", i.reshape(W, B * h),
+                               np.repeat(g, h, axis=1), hd))
+    assert eng.route_overflows() == over0 + 2       # bags[1], each way
+    assert (np.asarray(eng.store_raw("bags")).view(np.uint32)
+            == np.asarray(eng.store_raw("rows")).view(np.uint32)).all()
+    if hd is not None:
+        assert (np.asarray(eng.acc_array("bags"))
+                == np.asarray(eng.acc_array("rows"))).all()
+    ts = kv.pull_sparse("bags", bags[0], pool="sum")
+    kv.wait(ts)
+    pooled = np.asarray(kv.get_pulled(ts))
+    ts = kv.pull_sparse("rows", bags[0].reshape(W, B * h))
+    kv.wait(ts)
+    rows = np.asarray(kv.get_pulled(ts)).reshape(W, B, h, 128)
+    assert pooled.shape == (W, B, 128)
+    want = rows.astype(np.float64).sum(axis=2)
+    assert _row_error(pooled, want) < TOL
+    if hd is None:
+        table = _reference(init, [i.reshape(W, -1) for i in bags],
+                           [np.repeat(g, h, axis=1) for g in grads])
+        assert _row_error(pooled, table[bags[0]].sum(axis=2)) < TOL
+    # The slots a shard works on are the lookups', 1.5 a lookup routed.
+    assert eng._route_slots((B, h)) == W * sparse._capacity(W, B * h) \
+        == W * 60

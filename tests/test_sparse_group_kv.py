@@ -349,3 +349,57 @@ def test_the_group_counter_reads_as_gauges_and_over_a_window():
     gauges = registry.snapshot()["gauges"]
     assert gauges["engine.sparse.group.ops"] == 6
     assert gauges["engine.sparse.group.tables"] == 84
+
+
+@pytest.mark.parametrize("handle", [None, "row_adagrad:0.05,1e-8"],
+                         ids=["sum", "row_adagrad"])
+@pytest.mark.parametrize("shards", [1, 4], ids=["one-shard", "four-shards"])
+def test_a_grouped_pooled_op_is_its_tables_one_table_pooled_ops(shards,
+                                                                handle):
+    """``pool="sum"`` in the grouped calls (ISSUE 54): every table with a bag
+    size of its own in ONE op.  Six tables with bags of 1 to 27 ids pushed
+    twice and pulled, once through the grouped calls and once through six
+    one-table pooled calls: stores, accumulators and pooled rows bit for
+    bit; the grouped pull's one result holds a table's ``B`` pooled rows side
+    by side, ``[W, 6 * B, d]``, whatever the bags held."""
+    rows, bags, B = ROWS_26[:6], [8, 1, 3, 2, 27, 5], 16
+    names = _names(6)
+    rng = np.random.default_rng(54)
+    idx = [rng.integers(0, r, size=(shards, B, h)).astype(np.int32)
+           for r, h in zip(rows, bags)]
+    grads = [rng.normal(size=(shards, B, DIM)).astype(np.float32)
+             for _ in rows]
+    c, kv, eng = _cluster(shards)
+    try:
+        solo = [n + ".solo" for n in names]
+        for n, s, r in zip(names, solo, rows):
+            eng.register_sparse(n, r, DIM)
+            eng.register_sparse(s, r, DIM)
+        for _ in range(2):
+            kv.wait(kv.push_sparse_group(names, idx, grads, handle,
+                                         pool="sum"))
+            for s, i, g in zip(solo, idx, grads):
+                kv.wait(kv.push_sparse(s, i, g, handle, pool="sum"))
+        ts = kv.pull_sparse_group(names, idx, pool="sum")
+        kv.wait(ts)
+        pulled = kv.get_pulled(ts)
+        assert type(pulled) is PulledGroup and len(pulled) == 6
+        assert [a.shape for a in pulled.arrays] == [(shards, 6 * B, DIM)]
+        for n, s, i, got in zip(names, solo, idx, pulled):
+            one = kv.pull_sparse(s, i, pool="sum")
+            kv.wait(one)
+            assert got.shape == (shards, B, DIM)
+            assert (_bits(got) == _bits(kv.get_pulled(one))).all(), n
+            assert (_bits(eng.store_raw(n)) == _bits(eng.store_raw(s))).all()
+            if handle is not None:
+                assert (_bits(eng._acc[n]) == _bits(eng._acc[s])).all(), n
+        assert all(np.abs(np.asarray(p)).max() > 0 for p in pulled)
+        # The group's record is keyed by the bags: (B, h) a table, B where
+        # a bag is one id.
+        key = ("pull", tuple(names), None,
+               tuple(B if h == 1 else (B, h) for h in bags))
+        assert key in eng._bound
+        assert eng._bound[key].pooled == (shards * B * 6,
+                                          shards * B * sum(bags))
+    finally:
+        c.finalize()

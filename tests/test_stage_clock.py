@@ -472,7 +472,8 @@ def test_the_account_leaves_the_stages_as_they_were():
                                   + len(profiling.ROUTED)
                                   + len(profiling.GROUPED)
                                   + len(profiling.LAUNCHED)
-                                  * len(profiling.LAUNCH_OPS))
+                                  * len(profiling.LAUNCH_OPS)
+                                  + len(profiling.POOLED))
     assert all(len(mark) == len(clock._totals)
                for mark in clock._marks.values())
 
