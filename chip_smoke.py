@@ -87,6 +87,10 @@ class Sizes:
     # The table pushed under the stateful handle, then summed into: rows of
     # 128 lanes, which on the chip a push writes through ops/row_add.py.
     emb_opt_dim: int = 128
+    # A third table under the handle whose rows are no multiple of 128 on
+    # any mesh: its accumulator is kept in whole 128s a shard and takes
+    # ops/acc_update.py's pass as the others do.
+    emb_odd_rows: int = 1_000_003
 
 
 class PhaseFailed(RuntimeError):
@@ -843,6 +847,10 @@ class _Smoke:
         # under the handle; its third push is the one that falls back.
         for name, dim in (("emb_opt", sz.emb_opt_dim), ("emb_opt64", 64)):
             self._sparse_under_handle(se, name, dim, even, rng)
+        self._sparse_under_handle(
+            se, "emb_odd", sz.emb_opt_dim,
+            self._even_batch(rng, sz.emb_odd_rows, W, sz.emb_batch), rng,
+            rows=sz.emb_odd_rows)
         self._sparse_grouped(se, even, rng)
 
     def _sparse_grouped(self, se, idx, rng) -> None:
@@ -875,20 +883,25 @@ class _Smoke:
               f"{len(pulled.arrays)} results for {len(names)} tables, every "
               f"entry equal to its table's pull_sparse")
 
-    def _sparse_under_handle(self, se, name, dim, idx, rng) -> None:
+    def _sparse_under_handle(self, se, name, dim, idx, rng,
+                             rows=None) -> None:
         """From the zero state one push of row-wise Adagrad leaves
         -lr * G / (sqrt(mean(G**2)) + eps) in every touched row; a plain
         sum into the same table then adds G; a second push under the handle
         meets the accumulators the first left (on the chip
         ops/acc_update.py reads, steps and writes them; on several chips
-        each its own shard's)."""
+        each its own shard's).  ``rows``: the table's, ``emb_rows`` unless
+        given; whatever they are, a shard keeps its accumulators in whole
+        128s and the tail behind its rows stays zero."""
         kv, sz = self.kv, self.sizes
         W = se.num_shards
         lr, eps = 0.05, 1e-8
-        table = se.register_sparse(name, sz.emb_rows, dim)
+        table_rows = rows or sz.emb_rows
+        table = se.register_sparse(name, table_rows, dim)
         handle = f"row_adagrad:{lr},{eps}"
         before = (se.row_kernel_pushes, se.packed_pushes,
-                  se.segsum_kernel_pushes, se.acc_kernel_pushes)
+                  se.segsum_kernel_pushes, se.acc_kernel_pushes,
+                  se.acc_kernel_tables)
         check(self._fits(se, idx), "the batch under the handle fits")
         overflows = se.route_overflows()
         grads = rng.standard_normal((W, sz.emb_batch, dim), dtype=np.float32)
@@ -920,7 +933,7 @@ class _Smoke:
         check(se.acc_kernel_pushes == before[3] + self.on_tpu,
               f"accumulator kernel pushes {se.acc_kernel_pushes}")
         print(f"  one push under {handle} through "
-              f"push_sparse, {sz.emb_rows:,} x {dim} (pack {table.pack}), "
+              f"push_sparse, {table_rows:,} x {dim} (pack {table.pack}), "
               f"duplicates summed by "
               f"{'ops/segment_sum.py' if kernel else 'XLA scatter-add'}, "
               f"the table written by "
@@ -998,6 +1011,16 @@ class _Smoke:
         check(se.acc_kernel_pushes == before[3] + 3 * self.on_tpu,
               f"accumulator kernel pushes {se.acc_kernel_pushes} after the "
               f"third push")
+        check(se.acc_kernel_tables == before[4] + 3 * self.on_tpu,
+              f"tables whose accumulator the kernel updated "
+              f"{se.acc_kernel_tables} after the third push")
+        kept = np.asarray(se._acc[name]).view(np.uint32).reshape(W, -1)
+        check(kept.shape[1] == table.acc_rows
+              and table.acc_rows == -(-table.rows_per_shard // 128) * 128,
+              f"a shard keeps {kept.shape[1]:,} accumulators for "
+              f"{table.rows_per_shard:,} rows")
+        check(not kept[:, table.rows_per_shard:].any(),
+              "the tail behind a shard's accumulators is not zero")
         # All of one owner: over several shards the two pulls and the push
         # ran the gathered body, the fallback under the handle.
         fell_back = 3 * (not self._fits(se, low))
@@ -1007,7 +1030,8 @@ class _Smoke:
               f"the third push, {fell_back} ops fell back")
         print(f"  a third, {low.size:,} distinct rows of the first shard's "
               f"lowest: rows and accumulators follow, every other "
-              f"accumulator is as it was"
+              f"accumulator is as it was, a shard's "
+              f"{table.rows_per_shard:,} kept in {table.acc_rows:,}"
               + (f"; all of one owner, its {fell_back} ops fell back to the "
                  f"gathered body and were counted" if fell_back else ""))
 

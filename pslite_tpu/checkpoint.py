@@ -248,10 +248,10 @@ def restore_engine_orbax(engine, path: str, sparse_engine=None) -> None:
             # Mirror of save: every registered table has an acc entry in
             # the checkpoint, so target it unconditionally (no
             # ensure_acc pre-call needed by users).
-            sparse_engine.ensure_acc(name)
-            acc = sparse_engine._acc[name]
+            # The interleaved logical form (what acc_array gives and a
+            # legacy checkpoint holds), not the engine's kept length.
             target["sparse_acc"][name] = jax.ShapeDtypeStruct(
-                acc.shape, acc.dtype,
+                unpacked[:1], np.float32,
                 sharding=NamedSharding(
                     sparse_engine.mesh, P(sparse_engine.axis)
                 ),

@@ -59,38 +59,42 @@ def acc_update(acc, rows, g2, n, *, interpret: bool):
     which aliases ``acc``'s buffer where that is donated, and ``f32[m]``
     whose entry ``i < n`` is the new ``acc[rows[i]]``.
 
-    ``acc`` is ``f32[R]`` with ``R`` a multiple of 128, ``rows`` ``s32[m]``
-    ascending and unique in its first ``n`` entries, ``g2`` ``f32[m]``, ``n``
-    an integer scalar on the device.  Entries ``i >= n`` of ``rows`` and
-    ``g2`` may hold anything: they are neither read into a result nor
-    written, and what the second result holds there is not for use.
+    ``acc`` is ``f32[R]``, ``rows`` ``s32[m]`` ascending and unique in its
+    first ``n`` entries, ``g2`` ``f32[m]``, ``n`` an integer scalar on the
+    device.  Entries ``i >= n`` of ``rows`` and ``g2`` may hold anything:
+    they are neither read into a result nor written, and what the second
+    result holds there is not for use.  ``R`` is whole 128s wherever the
+    array is to be updated in place (the sparse engine keeps every
+    accumulator so, ``parallel/sparse.py`` ``_acc_rows``); any other length
+    is padded to them and cut again, a copy each way.
 
     Compiled for the chip, the kernel's trace is kept between processes
     (``utils/compile_cache.py`` ``call_traced``), as ``row_add``'s is.
     """
     rows = rows.astype(jnp.int32)
     n = jnp.reshape(n, (1,)).astype(jnp.int32)
-    if not interpret:
-        return call_traced(_acc_update, __file__, "tpu", acc, rows, g2, n)
+    (R,) = acc.shape
     # The interpreter returns an aliased operand with a ragged last block
     # padded to whole blocks, which its own result type then refuses: it
     # is given whole tiles (the chip takes the ragged one).
-    (R,) = acc.shape
-    ragged = -R % (_tile_rows(R) * _LANES)
-    new_acc, new_rows = _acc_update(jnp.pad(acc, (0, ragged)), rows, g2, n,
-                                    True)
-    return new_acc[:R], new_rows
+    ragged = -R % (_tile_rows(R) * _LANES if interpret else _LANES)
+    if ragged:
+        acc = jnp.pad(acc, (0, ragged))
+    new_acc, new_rows = (
+        _acc_update(acc, rows, g2, n, True) if interpret
+        else call_traced(_acc_update, __file__, "tpu", acc, rows, g2, n))
+    return (new_acc[:R] if ragged else new_acc), new_rows
 
 
 def _tile_rows(R: int) -> int:
     """Rows of 128 accumulators a tile: ``_TILE_ROWS``, or all ``R`` holds
     in whole sublanes where that is fewer."""
-    return min(_TILE_ROWS, -(-(R // _LANES) // 8) * 8)
+    return min(_TILE_ROWS, -(-R // (8 * _LANES)) * 8)
 
 
 def steps(R: int, m: int) -> int:
     """Grid steps of the pass over ``R`` accumulators beside ``m`` ids."""
-    tiles = -(-(R // _LANES) // _tile_rows(R))
+    tiles = -(-R // (_tile_rows(R) * _LANES))
     return tiles + -(-m // _CHUNK) - 1
 
 

@@ -87,6 +87,10 @@ class SparseTable:
         """Physical store rows per shard."""
         return self.rows_per_shard // self.pack
 
+    @property
+    def acc_rows(self) -> int:
+        """Accumulators a shard KEEPS (:func:`_acc_rows`)."""
+        return _acc_rows(self.rows_per_shard)
 
 
 @dataclass(eq=False, slots=True)
@@ -104,10 +108,12 @@ class _Bound:
     # What a push adds to the engine's counters: whether the program writes
     # any of its tables through ops/row_add.py, sums any combine's segments
     # with ops/segment_sum.py, updates any accumulator with
-    # ops/acc_update.py, and whether any table is lane-packed (pack > 1).
+    # ops/acc_update.py (and how many tables' it does), and whether any table
+    # is lane-packed (pack > 1).
     row_kernel: bool
     segsum_kernel: bool
     acc_kernel: bool
+    acc_tables: int
     packed: bool
     # Batch-workspace rows a shard's bound bodies work on, the tables' sum
     # (:func:`_slots`: S * C where an exchange is routed by owner, W * batch
@@ -250,6 +256,39 @@ def _deinterleave_rows(inter, num_rows: int, rps: int, S: int):
     return inter.reshape(S, rps, -1).transpose(1, 0, 2).reshape(
         -1, inter.shape[1]
     )[:num_rows].copy()
+
+
+def _acc_rows(rps: int) -> int:
+    """Accumulators a shard keeps for its ``rps`` logical rows: whole 128s,
+    so that ``ops/acc_update.py`` can view them as ``[n, 128]`` whatever
+    the table's row count.  The first ``rps`` are the rows', in the store's
+    order; the tail is zeros that no id names (an id is under ``num_rows``,
+    a sentinel is dropped) and nothing reads.  128 and not a sublane tile
+    of 1,024: the kernel takes a ragged last tile, and of MLPerf
+    DLRM-DCNv2's 26 tables 1,024 would make 19 distinct ``(rows, slots)``
+    kernels where 128 makes 20."""
+    return -(-rps // 128) * 128
+
+
+def _acc_kept(inter, rps: int, S: int):
+    """Interleaved logical accumulators ``[S * rps]`` (a numpy or a jax
+    array) -> as the engine keeps them, ``[S * _acc_rows(rps)]``: every
+    shard's zero tail behind its rows."""
+    pad = _acc_rows(rps) - rps
+    if not pad:
+        return inter
+    if isinstance(inter, np.ndarray):
+        xp = np
+    else:
+        import jax.numpy as xp
+    return xp.pad(inter.reshape(S, rps), ((0, 0), (0, pad))).reshape(-1)
+
+
+def _acc_logical(kept, rps: int, S: int):
+    """Inverse of :func:`_acc_kept`: the tails cut."""
+    if kept.shape[0] == S * rps:
+        return kept
+    return kept.reshape(S, -1)[:, :rps].reshape(-1)
 
 
 def _pack_host(inter, rps: int, S: int, pack: int, dim: int):
@@ -678,20 +717,28 @@ def _row_add_takes(width: int, dtype) -> bool:
 # slots there and is the faster from ~34,000 (between 16,384 and 32,768
 # under Zipf duplicates), XLA's kept at a loss of at most a tenth in between;
 # at 1,048,576 rows taken from ~2,200 slots and the faster from ~1,700.
+# Over small accumulators both are cheaper and the pass more so (my chip
+# runs, PR 55, ``PERF.md`` section 6: 128 to 590,208 kept accumulators under
+# 4,096 to 40,960 slots): the pair 10.5-13 ns a slot up to 39,168
+# accumulators and 28 from 405,376; the pass alone 5.4 us over ``f32[128]``
+# under 4,096 slots where the pair takes 53.8, 63 against 294 at
+# ``f32[20352]`` under 24,576, 187 against 1,134 at ``f32[590208]`` under
+# 40,960: the walk ends with the last live chunk, so a table of few rows
+# takes one step, and a start is ~1 us on the device.  No start is reckoned.
 _ACC_STEP_NS = 1100
 _ACC_SLOT_NS = 20
 
 
 def _acc_update_takes(R: int, m: int) -> bool:
-    """Whether ``ops/acc_update.py`` updates an accumulator of ``R`` rows
-    under a batch of ``m`` slots: the accumulator is whole 128-lane rows,
-    and the pass, which costs by ``R`` (and by ``m`` only in chunks), is
-    reckoned cheaper than XLA's pair, which costs by the slot.  A small
+    """Whether ``ops/acc_update.py`` updates an accumulator of ``R`` kept
+    rows (:func:`_acc_rows`) under a batch of ``m`` slots: the pass, which
+    costs by ``R`` (and by ``m`` only in chunks), is reckoned cheaper than
+    XLA's pair, which costs by the slot.  A cost and nothing else: no row
+    count is refused (every accumulator is kept in whole 128s).  A small
     batch into a large table keeps XLA's."""
     from ..ops.acc_update import steps
 
-    return (R % 128 == 0
-            and steps(R, m) * _ACC_STEP_NS < m * _ACC_SLOT_NS)
+    return steps(R, m) * _ACC_STEP_NS < m * _ACC_SLOT_NS
 
 
 def _where_lowered(interprets, xla, kernel, *operands):
@@ -752,12 +799,15 @@ def _update_acc(acc_l, row_seg, valid, g2):
     and the pass pays (:func:`_acc_update_takes`), XLA's 1-D gather and
     scatter anywhere else (:func:`_where_lowered`;
     ``SparseEngine._acc_kernel`` counts it).  The accumulator is by logical
-    row whatever the table's width, dtype or packing."""
+    row whatever the table's width, dtype or packing, and as the engine
+    keeps it (:func:`_acc_rows`): at least as long as the sentinel
+    ``row_seg`` holds past the valid rows, so XLA's scatter drops at the
+    array's own length."""
     import jax.numpy as jnp
 
     from ..ops.acc_update import acc_update
 
-    R = acc_l.shape[0]
+    R = acc_l.shape[0]  # past every row and every sentinel: dropped
 
     def xla(acc_l, row_seg, valid, g2):
         new_rows = acc_l[jnp.where(valid, row_seg, 0)] + g2
@@ -1077,6 +1127,7 @@ class SparseEngine:
         self.row_kernel_pushes = 0
         self.segsum_kernel_pushes = 0
         self.acc_kernel_pushes = 0
+        self.acc_kernel_tables = 0  # the tables whose accumulator it updates
         self.packed_pushes = 0  # pushes into a lane-packed table
         # Ops whose batch did not fit the routed exchange's buckets and ran
         # the gathered body (:func:`_exchange`), counted ON THE DEVICE: a
@@ -1156,6 +1207,8 @@ class SparseEngine:
                        fn=lambda: self.segsum_kernel_pushes)
         registry.gauge("engine.sparse.push.acc_kernel",
                        fn=lambda: self.acc_kernel_pushes)
+        registry.gauge("engine.sparse.push.acc_kernel_tables",
+                       fn=lambda: self.acc_kernel_tables)
         registry.gauge("engine.sparse.push.packed",
                        fn=lambda: self.packed_pushes)
         registry.gauge(
@@ -1455,7 +1508,7 @@ class SparseEngine:
 
         t0 = stamp()
         self._acc[name] = self._place(
-            np.zeros(table.rows_per_shard * self.num_shards, np.float32),
+            np.zeros(table.acc_rows * self.num_shards, np.float32),
             NamedSharding(self.mesh, P(self.axis)),
         )
         self._clock.state_created(stamp() - t0)
@@ -1469,32 +1522,42 @@ class SparseEngine:
 
     def acc_array(self, name: str):
         """Adagrad accumulator snapshot (checkpointing); row-interleaved
-        like the table store."""
+        like the table store, ``[num_shards * rows_per_shard]``: the logical
+        rows' alone, without the tail a shard keeps behind them
+        (:func:`_acc_rows`), so its length is what it was before the tail
+        existed."""
         import jax.numpy as jnp
 
         with self._table_mu[name]:
             log.check(name in self._acc, f"no accumulator for {name!r}")
-            return jnp.copy(self._acc[name])
+            rps = self._tables[name].rows_per_shard
+            return jnp.copy(_acc_logical(self._acc[name], rps,
+                                         self.num_shards))
 
     def set_acc_array(self, name: str, value,
                       global_rows: bool = False) -> None:
+        """Restore an accumulator: ``global_rows=True`` takes the GLOBAL
+        logical ``[num_rows]``; otherwise the interleaved form
+        :meth:`acc_array` gives, ``[num_shards * rows_per_shard]`` (what a
+        checkpoint from before the kept tail holds too).  The tail is put
+        behind every shard's rows here (:func:`_acc_kept`)."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         table = self._tables[name]
-        expected = (table.rows_per_shard * self.num_shards,)
+        S, rps = self.num_shards, table.rows_per_shard
+        expected = (rps * S,)
         sharding = NamedSharding(self.mesh, P(self.axis))
         if global_rows and isinstance(value, jax.Array):
             # Device-side global restore (see set_store_array).
             import jax.numpy as jnp
 
-            S, rps = self.num_shards, table.rows_per_shard
             log.check_eq(tuple(value.shape), (table.num_rows,),
                          "bad global-rows accumulator shape")
             v = jnp.pad(value.astype(np.float32),
                         (0, rps * S - table.num_rows))
             inter = v.reshape(rps, S).transpose(1, 0).reshape(-1)
-            placed = jax.device_put(inter, sharding)
+            placed = jax.device_put(_acc_kept(inter, rps, S), sharding)
             with self._table_mu[name]:
                 self._acc[name] = placed
             return
@@ -1516,12 +1579,14 @@ class SparseEngine:
             if equivalent:
                 log.check_eq(tuple(value.shape), expected,
                              "bad accumulator shape")
+                # The tail put on where the array lies: never fetched.
+                kept = jax.device_put(_acc_kept(value, rps, S), sharding)
                 with self._table_mu[name]:
-                    self._acc[name] = value
+                    self._acc[name] = kept
                 return
         host = np.asarray(value, np.float32)
         log.check_eq(host.shape, expected, "bad accumulator shape")
-        placed = self._place(host, sharding)
+        placed = self._place(_acc_kept(host, rps, S), sharding)
         with self._table_mu[name]:
             self._acc[name] = placed
 
@@ -1621,13 +1686,15 @@ class SparseEngine:
         arrays = (2 * k + classes + 2 * routed if not push
                   else 4 * k + 1 + 2 * routed
                   + (2 * k + len(params) if stateful else 0))
+        acc_tables = (sum(map(self._acc_kernel, tables, batches))
+                      if stateful else 0)
         bound = _Bound(
             self._sparse_group_program(prog_op, tables, batches) if group
             else self._sparse_program(prog_op, tables[0], batches[0]),
             kind, params,
             push and any(map(self._row_kernel, tables)),
             push and any(self._segsum_kernel(t, stateful) for t in tables),
-            stateful and any(map(self._acc_kernel, tables, batches)),
+            acc_tables > 0, acc_tables,
             push and any(t.pack != 1 for t in tables),
             sum(map(self._route_slots, batches)),
             routed,
@@ -1651,6 +1718,7 @@ class SparseEngine:
         self.row_kernel_pushes += b.row_kernel
         self.segsum_kernel_pushes += b.segsum_kernel
         self.acc_kernel_pushes += b.acc_kernel
+        self.acc_kernel_tables += b.acc_tables
         self.packed_pushes += b.packed
 
     def _route_slots(self, batch) -> int:
@@ -1699,7 +1767,7 @@ class SparseEngine:
         ``batch`` lookups a worker updates the accumulator with
         ``ops/acc_update.py`` (the rule of :func:`_update_acc`)."""
         return (self._platform() in _ACC_UPDATE_INTERPRET
-                and _acc_update_takes(table.rows_per_shard,
+                and _acc_update_takes(table.acc_rows,
                                       self._route_slots(batch)))
 
     def push(self, name: str, indices, grads, handle: str = None,
@@ -2159,7 +2227,8 @@ class SparseEngine:
             log.check(name in self._acc, f"no accumulator for {name!r}")
             S, rps = self.num_shards, t.rows_per_shard
             acc = jnp.copy(self._acc[name])
-        return acc.reshape(S, rps).transpose(1, 0).reshape(-1)[:t.num_rows]
+        return _acc_logical(acc, rps, S).reshape(S, rps).transpose(
+            1, 0).reshape(-1)[:t.num_rows]
 
     def store_spec(self, name: str):
         """Shape/dtype/sharding of a table without copying it (restore
@@ -2322,7 +2391,8 @@ class SparseEngine:
                 acc_glob = None
                 if n in self._acc:
                     acc_glob = _deinterleave_rows(
-                        to_host_global(self._acc[n], old_mp),
+                        _acc_logical(to_host_global(self._acc[n], old_mp),
+                                     rps, S),
                         t.num_rows, rps, S,
                     )
                 snap[n] = (t, glob, acc_glob)
@@ -2361,8 +2431,10 @@ class SparseEngine:
                 if acc_glob is not None:
                     acc = place_host_array(
                         mesh,
-                        _interleave_rows(acc_glob, t.num_rows, rps,
-                                         new_num_shards, np.float32),
+                        _acc_kept(
+                            _interleave_rows(acc_glob, t.num_rows, rps,
+                                             new_num_shards, np.float32),
+                            rps, new_num_shards),
                         acc_sharding, new_multiprocess,
                     )
                 staged[n] = (

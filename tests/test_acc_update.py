@@ -205,3 +205,65 @@ def test_the_walk_names_no_chunk_or_tile_past_the_arrays_and_meets_every_id(
     assert (last[:chunks] == touched[
         np.minimum(np.arange(1, chunks + 1) * K, n) - 1]).all()
     assert (last[chunks:] < 0).all()
+
+
+# Tables whose rows are no multiple of 128 (MLPerf DLRM-DCNv2's own counts
+# among them), each as the sparse engine keeps its accumulator: the logical
+# rows first, zeros up to the next whole 128.
+LOGICAL = [3, 63, 128, 1_003, 20_265, 39_060]
+TOUCHED = {
+    "no id": lambda rows: [],
+    "every id": lambda rows: np.arange(rows),
+    "the last logical id alone": lambda rows: [rows - 1],
+    "a few ids, the first and the last among them": lambda rows: np.unique(
+        np.concatenate([[0, rows - 1], _spread(rows, min(rows, 40), rows)])),
+}
+
+
+@pytest.mark.parametrize("which", list(TOUCHED))
+@pytest.mark.parametrize("logical", LOGICAL)
+def test_logical_accumulators_kept_in_whole_128s(logical, which):
+    """The one property against numpy, at the kernel's own tile and chunk:
+    ``acc[rows[i]] += g2[i]`` for ``i < n`` and nothing else, with past ``n``
+    the sentinel a combine leaves (the LOGICAL row count, which lies inside
+    the kept array), a NaN and an ``inf``; the tail no id names stays zero
+    bit for bit."""
+    kept = -(-logical // LANES) * LANES
+    touched = np.asarray(TOUCHED[which](logical), np.int64)
+    n = len(touched)
+    m = -(-max(n, 1) // 64) * 64 + 64         # slots past ``n``, always
+    rng = np.random.default_rng(logical + n)
+    acc = np.zeros(kept, np.float32)
+    acc[:logical] = rng.normal(size=logical) ** 2
+    g2 = (rng.normal(size=m) ** 2).astype(np.float32)
+    g2[n], g2[n + 1] = NAN, INF
+    rows = np.concatenate([touched, np.full(m - n, logical)]).astype(np.int32)
+    want = acc.copy()
+    want[touched] += g2[:n]
+
+    got_acc, got_new = jax.jit(
+        lambda a, r, g, k: acc_update(a, r, g, k, interpret=True))(
+            acc, rows, g2, np.int32(n))
+    got_acc, got_new = np.asarray(got_acc), np.asarray(got_new)
+    assert got_acc.shape == (kept,) and got_new.shape == (m,)
+    assert (got_acc.view(np.int32) == want.view(np.int32)).all()
+    assert (got_new[:n].view(np.int32) == want[touched].view(np.int32)).all()
+    assert not got_acc[logical:].view(np.int32).any()
+    # XLA's pair over the same kept array drops the sentinel too.
+    pair_acc, _ = jax.jit(_xla)(acc, rows, g2, np.int32(n))
+    assert (np.asarray(pair_acc).view(np.int32) == want.view(np.int32)).all()
+
+
+def test_an_accumulator_of_no_whole_128s_is_padded_and_cut():
+    """A caller outside the engine: any length goes through (a copy each
+    way), and ``steps`` reckons it by the rows of 128 it takes."""
+    acc = np.arange(1, 62, dtype=np.float32)
+    rows = np.array([0, 5, 60, 61, 61, 61, 61, 61], np.int32)
+    g2 = np.full(8, 0.5, np.float32)
+    got_acc, got_new = acc_update(jnp.asarray(acc), rows, g2, np.int32(3),
+                                  interpret=True)
+    want = acc.copy()
+    want[[0, 5, 60]] += 0.5
+    assert got_acc.shape == (61,) and (np.asarray(got_acc) == want).all()
+    assert (np.asarray(got_new)[:3] == want[[0, 5, 60]]).all()
+    assert acc_update_module.steps(61, 8) == acc_update_module.steps(128, 8)

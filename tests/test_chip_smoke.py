@@ -21,7 +21,7 @@ TINY = chip_smoke.Sizes(
     dense_buckets=(("t0", 1000), ("t1", 4100), ("t2", 1000)),
     steps=2,
     readme_keys=4, readme_val_len=64,
-    emb_rows=4096, emb_dim=8, emb_batch=64,
+    emb_rows=4096, emb_dim=8, emb_batch=64, emb_odd_rows=4099,
 )
 
 

@@ -220,9 +220,11 @@ def kernels_on_cpu(monkeypatch):
 def test_the_pooled_push_through_the_kernels_the_chip_runs(
         one_shard, kernels_on_cpu, handle, bags_reference):
     """The chip's bodies (the combine that reads a slot's gradient through its
-    bag, then the kernels) in one grouped push of a 128-row table whose
-    accumulator takes ``acc_update``'s pass and of one that keeps XLA's pair:
-    equal to the unpooled push bit for bit, and to float64."""
+    bag, then the kernels) in one grouped push of two tables whose
+    accumulators take ``acc_update``'s pass, one of 256 rows and one of 1,003,
+    no multiple of 128 (kept in whole 128s: 1,024): equal to the unpooled
+    push bit for bit, and to float64.  Which body an accumulator takes is a
+    cost's verdict: a smaller batch into the same table keeps XLA's pair."""
     kv, eng = one_shard
     rows, bags, dim = [256, 1003], [27, 3], 128
     names, twins = ["a", "b"], ["a.u", "b.u"]
@@ -243,7 +245,12 @@ def test_the_pooled_push_through_the_kernels_the_chip_runs(
     if handle is not None:
         assert eng.acc_kernel_pushes == 4
         assert eng._acc_kernel(eng.table("a"), (big, 27))
-        assert not eng._acc_kernel(eng.table("b"), (big, 3))
+        # The rule is the cost alone: 60 slots pay for the one grid step
+        # over 1,024 accumulators, 45 do not; no row count is refused.
+        assert eng.table("b").acc_rows == 1024
+        assert eng._acc_kernel(eng.table("b"), (big, 3))
+        assert not eng._acc_kernel(eng.table("b"), (big - 5, 3))
+        assert eng.acc_kernel_tables == 8
     for n, t, r, i, g in zip(names, twins, rows, idx, grads):
         assert (_bits(eng.store_raw(n)) == _bits(eng.store_raw(t))).all(), n
         ref = bags_reference.bag_reference(np.arange(r), dim, handle)
@@ -305,7 +312,7 @@ def test_the_system_agrees_with_the_plain_reference(cluster, handle,
         scale = np.maximum(np.abs(ref.sums).max(axis=1), 0.05)
         assert (np.abs(table - ref.sums).max(axis=1) / scale).max() < 2e-5
         if handle is not None:
-            acc = np.asarray(eng._acc[n]).reshape(W, rps).T.reshape(-1)[:r]
+            acc = np.asarray(eng.acc_global_device(n))
             assert np.allclose(acc, ref.acc, rtol=2e-5, atol=0), n
 
 

@@ -135,10 +135,12 @@ def test_three_pushes_then_a_pull_match_the_reference(cluster, dim):
     acc = np.asarray(eng.acc_global_device("emb"))
     assert (acc[quiet] == 0).all() and (acc[touched] > 0).all()
     np.testing.assert_allclose(acc, ref.acc, rtol=1e-5)
-    # The accumulator is sharded like the table: 1/W of it a device.
+    # The accumulator is sharded like the table: 1/W of it a device, the
+    # shard's rows in whole 128s.
     shards = eng._acc["emb"].addressable_shards
-    rps = eng.table("emb").rows_per_shard
-    assert len(shards) == W and {s.data.shape for s in shards} == {(rps,)}
+    kept = eng.table("emb").acc_rows
+    assert kept == -(-eng.table("emb").rows_per_shard // 128) * 128
+    assert len(shards) == W and {s.data.shape for s in shards} == {(kept,)}
     assert len({s.device for s in shards}) == W
     # The order of pushes matters, and bf16 arithmetic fails the limit.
     swapped = RowAdagrad(init)
@@ -404,25 +406,32 @@ def _acc_kernel_on_cpu(monkeypatch):
 
 @pytest.mark.parametrize("grouped", [False, True],
                          ids=["single", "grouped"])
+@pytest.mark.parametrize("table_rows", [None, 1003],
+                         ids=["whole-128s", "1003-rows"])
 @pytest.mark.parametrize("dim", [64, 128, 256],
                          ids=["lane-packed", "unpacked", "256-wide"])
 @pytest.mark.parametrize("cluster", [1, 4], indirect=True)
 def test_the_accumulator_kernel_in_the_push_is_counted_and_bit_equal(
-        cluster, dim, grouped, monkeypatch):
+        cluster, dim, table_rows, grouped, monkeypatch):
     """On the CPU mesh a stateful push updates the accumulator with XLA's
     1-D gather and scatter and ``engine.sparse.push.acc_kernel`` stays 0;
     with the CPU named among ``acc_update``'s platforms (interpreted; alone,
     so that sums and table writes keep XLA's order) the same three pushes
     with duplicates leave the same bits in table and accumulator, whatever the
     table's width or packing (the accumulator is by logical row), and the
-    counter equals the pushes, a group counting as one.  The plain sum, and
+    counter equals the pushes, a group counting as one, while
+    ``engine.sparse.push.acc_kernel_tables`` counts a group's tables.  A
+    table of 1,003 rows, no multiple of 128 on any mesh, takes the pass as
+    one of 128 a shard does: its accumulator is kept in whole 128s and the
+    tail behind a shard's rows stays zero bit for bit.  The plain sum, and
     a batch too small for a pass over the accumulator to pay, are not
     counted and keep XLA's."""
     from pslite_tpu.parallel import sparse
 
     kv, eng = cluster
     W = eng.num_shards
-    rows, batch = 128 * W, 64           # 128 accumulators a shard: one row
+    # 128 accumulators a shard, one row of them; or 1,003 rows in all.
+    rows, batch = table_rows or 128 * W, 64
     rng = np.random.default_rng(dim + W)
     idx = rng.integers(0, rows, size=(W, batch)).astype(np.int32)
     idx[:, 0], idx[:, 1] = 0, rows - 1  # a shard's first and last, by all
@@ -446,19 +455,32 @@ def test_the_accumulator_kernel_in_the_push_is_counted_and_bit_equal(
     twin = SparseEngine(eng.mesh, eng.axis)
     run(twin)                               # the CPU's own: XLA's
     assert (twin.stateful_pushes, twin.acc_kernel_pushes) == (3, 0)
+    assert twin.acc_kernel_tables == 0
     traced = _acc_kernel_on_cpu(monkeypatch)
     run(eng)
-    assert set(traced) == {(128, m) for m in _workspaces(W, batch)}
+    rps, kept = eng.table("emb").rows_per_shard, eng.table("emb").acc_rows
+    assert kept == -(-rps // 128) * 128 and (kept == rps) == (not table_rows)
+    assert set(traced) == {(kept, m) for m in _workspaces(W, batch)}
     assert (eng.stateful_pushes, eng.acc_kernel_pushes) == (3, 3)
+    assert eng.acc_kernel_tables == 3 * len(names)
     assert _gauges(kv)["engine.sparse.push.acc_kernel"] == 3
+    assert _gauges(kv)["engine.sparse.push.acc_kernel_tables"] == 3 * len(
+        names)
     if not grouped:
         assert eng._bound[("emb", HANDLE, batch)].acc_kernel
+        assert eng._bound[("emb", HANDLE, batch)].acc_tables == 1
         assert not twin._bound[("emb", HANDLE, batch)].acc_kernel
     for name in names:
         assert (eng.store_array(name) == twin.store_array(name)).all()
         acc = np.asarray(eng._acc[name])
-        assert (acc == np.asarray(twin._acc[name])).all()
-        assert acc.shape == (rows,) and (acc > 0).sum() == len(np.unique(idx))
+        assert (acc.view(np.uint32)
+                == np.asarray(twin._acc[name]).view(np.uint32)).all()
+        assert acc.shape == (kept * W,)
+        assert (acc > 0).sum() == len(np.unique(idx))
+        # The tail no id names: zero, bit for bit.
+        assert not acc.view(np.uint32).reshape(W, kept)[:, rps:].any()
+        by_row = np.asarray(eng.acc_global_device(name))
+        assert by_row.shape == (rows,) and (by_row[np.unique(idx)] > 0).all()
     # The plain sum has no accumulator; a batch of 12 slots a worker keeps
     # XLA's (the pass costs by the accumulator, the gather by the slot).
     del traced[:]
@@ -467,12 +489,13 @@ def test_the_accumulator_kernel_in_the_push_is_counted_and_bit_equal(
     assert not traced and eng.acc_kernel_pushes == 3
     assert eng.stateful_pushes == 4
     assert not eng._acc_kernel(eng.table("emb"), 12)
-    assert not sparse._acc_update_takes(128, 12 * W)
-    # The rule on shapes alone: the cell's, a small batch into its table,
-    # and an accumulator that is no whole 128-lane rows.
+    assert not sparse._acc_update_takes(kept, 12 * W)
+    # The rule on shapes alone: the cell's, and a small batch into its
+    # table.  It is a cost: no row count is refused.
     assert sparse._acc_update_takes(20_000_000, 131_072)
     assert not sparse._acc_update_takes(20_000_000, 4_096)
-    assert not sparse._acc_update_takes(20_000_001, 131_072)
+    assert sparse._acc_update_takes(sparse._acc_rows(20_000_001), 131_072)
+    assert sparse._acc_rows(20_000_001) == 20_000_128
 
 
 @pytest.mark.parametrize("cluster", [1, 4], indirect=True)
@@ -900,8 +923,9 @@ def test_counters_of_a_stateful_push(cluster):
     assert clock.state_create_ns == once                     # and once
     after = _gauges(kv)
     assert after["engine.sparse.push.stateful"] == 2
-    rps = eng.table("emb").rows_per_shard
-    assert after["engine.sparse.acc.bytes"] == 4 * rps * W
+    # What the device holds: a shard's accumulators in whole 128s.
+    kept = eng.table("emb").acc_rows
+    assert after["engine.sparse.acc.bytes"] == 4 * kept * W
     assert after["engine.state_create.s"] > before["engine.state_create.s"]
     # Nothing to copy and no callback: no op went through the pool.
     counters = kv.po.metrics.snapshot()["counters"]
@@ -957,3 +981,108 @@ def test_the_lowered_program_carries_the_scopes(cluster):
         "ps.sparse.combine" in l or "ps.sparse.route.ids" in l
         for l in sort_lines)
     assert any("ps.sparse.combine" in l for l in sort_lines)
+
+
+# -- the accumulator as the engine keeps it: whole 128s a shard ---------------
+
+
+def _pushed_engine(W, rows=1003, dim=128, pushes=2, seed=5):
+    """An engine over ``W`` devices with a table of ``rows`` rows (no
+    multiple of 128 a shard) pushed under the handle, and the traffic."""
+    eng = SparseEngine(_mesh(W))
+    rng = np.random.default_rng(seed)
+    init = rng.normal(size=(rows, dim)).astype(np.float32)
+    idx = rng.integers(0, rows, size=(W, 32)).astype(np.int32)
+    idx[:, 0], idx[:, 1] = 0, rows - 1
+    grads = [rng.normal(size=(W, 32, dim)).astype(np.float32)
+             for _ in range(pushes)]
+    eng.register_sparse("t", rows, dim, init=init)
+    for g in grads:
+        eng.push("t", idx, g, HANDLE).block_until_ready()
+    return eng, init, idx, grads
+
+
+def _tails_are_zero(eng, name="t"):
+    t = eng.table(name)
+    kept = np.asarray(eng._acc[name]).view(np.uint32)
+    assert kept.shape == (eng.num_shards * t.acc_rows,)
+    return not kept.reshape(eng.num_shards, -1)[:, t.rows_per_shard:].any()
+
+
+@pytest.mark.parametrize("form", ["interleaved", "global rows"])
+@pytest.mark.parametrize("on", ["host", "device"])
+@pytest.mark.parametrize("W", [1, 4])
+def test_an_accumulator_round_trips_through_acc_array_and_set_acc_array(
+        W, on, form):
+    """What ``acc_array`` (interleaved, ``[W * rows_per_shard]``: the logical
+    rows alone) or ``acc_global_device`` (``[num_rows]``) gives, set into a
+    fresh engine as a host or a device array, is the kept accumulator bit
+    for bit, tails zero, and the next push steps the same."""
+    eng, init, idx, grads = _pushed_engine(W)
+    t = eng.table("t")
+    global_rows = form == "global rows"
+    snap = eng.acc_global_device("t") if global_rows else eng.acc_array("t")
+    assert snap.shape == ((1003,) if global_rows
+                          else (W * t.rows_per_shard,))
+    assert t.acc_rows > t.rows_per_shard
+    if on == "host":
+        snap = np.asarray(snap)
+    fresh = SparseEngine(_mesh(W))
+    fresh.register_sparse("t", 1003, 128, init=np.asarray(
+        eng.store_global_device("t")))
+    fresh.set_acc_array("t", snap, global_rows=global_rows)
+    assert (np.asarray(fresh._acc["t"]).view(np.uint32)
+            == np.asarray(eng._acc["t"]).view(np.uint32)).all()
+    assert _tails_are_zero(fresh) and _tails_are_zero(eng)
+    for e in (eng, fresh):
+        e.push("t", idx, grads[0], HANDLE).block_until_ready()
+    assert (np.asarray(fresh._acc["t"]) == np.asarray(eng._acc["t"])).all()
+    assert (fresh.store_array("t") == eng.store_array("t")).all()
+
+
+@pytest.mark.parametrize("on", ["host", "device, sharded as the engine's"])
+@pytest.mark.parametrize("W", [1, 4])
+def test_an_interleaved_accumulator_of_the_parents_length_loads(W, on):
+    """A checkpoint from before the kept tail holds ``[W * rows_per_shard]``
+    interleaved, on the host (npz) or sharded over the mesh (orbax, same
+    fleet): it loads, every logical accumulator where it was."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pslite_tpu.parallel.sparse import _interleave_rows
+
+    eng = SparseEngine(_mesh(W))
+    t = eng.register_sparse("t", 1003, 128)
+    want = (np.random.default_rng(W).normal(size=1003) ** 2).astype(
+        np.float32)
+    old = _interleave_rows(want, 1003, t.rows_per_shard, W, np.float32)
+    assert old.shape == (W * t.rows_per_shard,) != (W * t.acc_rows,)
+    if on != "host":
+        old = jax.device_put(old, NamedSharding(eng.mesh, P("kv")))
+    eng.set_acc_array("t", old)
+    assert (np.asarray(eng.acc_global_device("t")) == want).all()
+    assert (np.asarray(eng.acc_array("t")) == np.asarray(old)).all()
+    assert _tails_are_zero(eng)
+    # And a length that is neither is refused by name.
+    with pytest.raises(log.CheckError, match="bad accumulator shape"):
+        eng.set_acc_array("t", np.zeros(W * t.acc_rows, np.float32))
+
+
+def test_a_reshard_keeps_every_logical_accumulators_bits():
+    """1 -> 4 -> 1 devices: rows per shard 1,003 -> 251 -> 1,003, kept
+    1,024 -> 256 -> 1,024; every logical accumulator's bits survive, the
+    tails are zero on every mesh, and a push after the round trip steps as
+    one on an engine that never moved."""
+    eng, init, idx, grads = _pushed_engine(1)
+    twin, *_ = _pushed_engine(1)
+    want = np.asarray(eng.acc_global_device("t")).view(np.uint32)
+    assert want.any()
+    for W, kept in ((4, 256), (1, 1024)):
+        eng.reshard(_mesh(W))
+        assert eng.table("t").acc_rows == kept
+        assert (np.asarray(eng.acc_global_device("t")).view(np.uint32)
+                == want).all()
+        assert _tails_are_zero(eng)
+    for e in (eng, twin):
+        e.push("t", idx, grads[0], HANDLE).block_until_ready()
+    assert (np.asarray(eng._acc["t"]) == np.asarray(twin._acc["t"])).all()
+    assert (eng.store_array("t") == twin.store_array("t")).all()
