@@ -598,12 +598,13 @@ class _Smoke:
         matrix is held to 0.3 of one step's root mean square in any
         element and 0.05 in the root mean square (the chip reads 0.146 and
         0.018, a Newton-Schulz step left out 0.8); an AdamW key and the
-        momentum to f32 rounding.  On more than one chip a matrix would
-        lie across chips: there the handle must refuse by name."""
+        momentum to f32 rounding.  On more than one chip the bucket is
+        sharded on its keys' borders (PR 56): every matrix whole on one
+        owner, the W rows summed to the owners, the pulled tree the gather
+        laid back into key order and equal to ``pull``'s bit for bit."""
         import jax.numpy as jnp
 
         from pslite_tpu.parallel.engine import KEY_ELEMENTWISE
-        from pslite_tpu.utils import logging as log
 
         kv, eng = self.kv, self.kv.engine
         W = eng.num_workers
@@ -636,21 +637,11 @@ class _Smoke:
             kv.register_dense(name, keys, lens=lens, shapes=shapes,
                               flags=np.where(adamw, KEY_ELEMENTWISE, 0),
                               init=init)
-            if eng.num_shards > 1:
-                try:
-                    eng.push_pull(name, np.zeros((W, total), np.float32),
-                                  handle)
-                except log.CheckError as exc:
-                    check("a matrix would lie across chips" in str(exc),
-                          f"muon over {eng.num_shards} shards refuses by "
-                          f"name: {exc}")
-                    check(eng.muon_updates == 0, "nothing ran under Muon")
-                    print(f"  over {eng.num_shards} shards muon refuses by "
-                          f"name")
-                    return
-                raise AssertionError(
-                    "muon over several shards did not refuse")
-            whole = apply_keys == len(lens)
+            # Over several shards the bucket lies by its owner plan (every
+            # matrix whole on one shard) and the pulled tree is the gather,
+            # laid back into key order: no kernel's own vector.
+            one_shard = eng.num_shards == 1
+            whole = apply_keys == len(lens) and one_shard
             p = [init[starts[k]:starts[k + 1]].astype(np.float64).reshape(
                 shapes[k]) for k in range(len(lens))]
             mom = [np.zeros_like(x) for x in p]
@@ -659,6 +650,9 @@ class _Smoke:
             pulls = eng.kernel_pulls
             for t in (1, 2):
                 g = rng.standard_normal((W, total)).astype(np.float32)
+                # As in ``lamb``: no sum of the W rows lies where AdamW's
+                # m / (sqrt(v) + eps) turns on the f32 sum's last bits.
+                g[0, np.abs(g.sum(axis=0, dtype=np.float64)) < 1e-3] += 0.5
                 sent = g if t == 1 else jnp.asarray(g)
                 pulled = np.asarray(eng.push_pull(name, sent, handle))
                 gs = g.astype(np.float64).sum(axis=0)
@@ -688,7 +682,7 @@ class _Smoke:
                           f"{name} key {k} {tuple(shapes[k])} step {t}: off "
                           f"by {diff.max() / step:.3f} of a step at worst, "
                           f"{np.sqrt(np.mean(diff ** 2)) / step:.4f} rms")
-                if whole:
+                if whole or not one_shard:
                     check(np.array_equal(
                         pulled, np.asarray(eng.pull(name))[:total]),
                         f"{name} step {t}: the kernels' pulled vector is "
@@ -697,6 +691,22 @@ class _Smoke:
             check(eng.muon_updates == updates
                   and eng.muon_matrices == int((~adamw).sum()),
                   "every op ran under Muon")
+            owned = eng.bucket(name).owned
+            check((owned is None) == one_shard
+                  and eng.muon_owners == min(eng.num_shards,
+                                             int((~adamw).sum())),
+                  f"{name}: laid by {eng.muon_owners} owners over "
+                  f"{eng.num_shards} shards")
+            if not one_shard:
+                # A slot starts on a lane border whatever the key order,
+                # and a key left over (all of them, where the owners
+                # outnumber a shape class) leaves through no kernel: the
+                # plan's own counts, a key by its slot.
+                row_keys, apply_keys = (eng.muon_row_keys,
+                                        eng.muon_apply_keys)
+                check(owned.padded_len == eng.bucket(name).padded_len
+                      and row_keys >= 1 and apply_keys >= 1,
+                      f"{name}: the owners' layout")
             check(eng.muon_row_keys == row_keys and starts[1] % 1024 == 512,
                   f"{name}: {row_keys} keys leave the row through a kernel, "
                   f"not {eng.muon_row_keys}")
@@ -714,12 +724,15 @@ class _Smoke:
                 np.testing.assert_allclose(
                     np.asarray(got), np.concatenate(
                         [want[k].reshape(-1) for k in range(len(lens))
-                         if adamw[k]]), rtol=1e-5, atol=1e-7,
+                         if adamw[k]]), rtol=1e-5,
+                    # (The f32 sum of W rows, in another order.)
+                    atol=1e-7 * max(1, W / 2),
                     err_msg=f"{name}: AdamW's {what}")
             check(float(np.asarray(slot)[0]) == 2.0, "the step slot")
+            least = 4 * int(lens[~adamw].sum()) + 8 * int(lens[adamw].sum())
             check(eng.opt_state_nbytes(name)
-                  == 4 * int(lens[~adamw].sum())
-                  + 8 * int(lens[adamw].sum()) + 4,
+                  == (least + 4 if one_shard
+                      else owned.state_bytes + 4 * eng.num_shards) >= least,
                   "the state at its own size")
             print(f"  {name}: {len(lens)} keys "
                   f"({', '.join(f'{r}x{c}' for r, c in shapes)}): 2 steps "
@@ -727,7 +740,8 @@ class _Smoke:
                   f"in an element, {worst[1]:.4f} rms; muon_row_keys "
                   f"{eng.muon_row_keys}, muon_apply_keys "
                   f"{eng.muon_apply_keys}, pulled by the kernels "
-                  f"{eng.kernel_pulls - pulls} of 2")
+                  f"{eng.kernel_pulls - pulls} of 2; {eng.muon_owners} "
+                  f"owners over {eng.num_shards} shards")
 
     @staticmethod
     def _fits(se, idx) -> bool:

@@ -1113,6 +1113,9 @@ class KVWorker:
             if bucket is not None and bucket.mixed:
                 # Pushed and pulled in another dtype than it is kept.
                 meta["job"] = str(bucket.job_dtype)
+            if bucket is not None and bucket.owned is not None:
+                # Sharded on its keys' borders: over how many owners.
+                meta["owners"] = bucket.owned.shards
             span.set_metadata(**meta)
             span.__exit__(None, None, None)
         return ts

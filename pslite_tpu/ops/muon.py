@@ -277,6 +277,301 @@ def momentum_chunks(plan: MuonPlan, vector, xp):
     return out
 
 
+# -- a bucket over several shards: whole keys an owner ------------------------
+
+
+class OwnerPlan(NamedTuple):
+    """How a bucket's keys lie over ``shards`` owners, every matrix whole
+    on one (:func:`owner_plan`).  A shard is laid out the same way on every
+    owner: the slots of ``plan.chunks`` one behind the other (of each shape
+    class as many an owner as divide evenly, wide and tall apart), then the
+    room of what is left of the classes, which each owner fills with its
+    own (``branches``), then the owner's stretch of the element-wise keys'
+    values.  ``starts`` and ``shapes`` are a slot's, as a key's are on one
+    shard; the slots of two branches lie over each other."""
+
+    shards: int
+    shard_len: int
+    starts: np.ndarray          # [slots]: where a slot begins in a shard
+    shapes: np.ndarray          # [slots, 2]
+    plan: MuonPlan              # what every owner runs: the chunks all
+    #                             have, the element-wise stretch as one key
+    rest: Tuple[Tuple[int, int, int], ...]  # (B, m, n) of each momentum
+    #                             array of what is left of the classes
+    # A branch: (which array of ``rest``, the chunk it runs there) pairs.
+    branches: Tuple[Tuple[Tuple[int, MuonChunk], ...], ...]
+    branch_of: np.ndarray       # [shards]: the branch an owner takes
+    # A matrix key's place, [K, 4]: its owner, its momentum array (among
+    # the chunks', then ``rest``'s), its row there on the owner, its slot;
+    # -1s for an element-wise key.
+    where: np.ndarray
+    stretch: int                # element-wise values an owner holds
+    elementwise_len: int        # ... and all owners together, unpadded
+    # (start in key order, start in owners' order, values), by the first.
+    segments: np.ndarray
+    total_len: int
+    flops: np.ndarray           # [shards]: Newton-Schulz FLOPs an owner
+
+    @property
+    def padded_len(self) -> int:
+        return self.shards * self.shard_len
+
+    @property
+    def matrices(self) -> int:
+        return int((self.where[:, 0] >= 0).sum())
+
+    @property
+    def ns_flops(self) -> float:
+        return float(self.flops.sum())
+
+    @property
+    def laid(self):
+        """The runs by where they land, and the ``(start, values)`` that
+        no run covers: what is laid, and what is filled with zeros."""
+        by_dst = sorted(self.segments.tolist(), key=lambda e: e[1])
+        gaps, at = [], 0
+        for _, dst, n in by_dst + [[0, self.padded_len, 0]]:
+            if dst > at:
+                gaps.append((at, dst - at))
+            at = dst + n
+        return by_dst, gaps
+
+    @property
+    def state_bytes(self) -> int:
+        """What the state holds over all owners, the step slots apart."""
+        return 4 * self.shards * (
+            sum(math.prod(s) for s in owner_state_shapes(self)))
+
+
+def _up(n: int, unit: int) -> int:
+    return -(-n // unit) * unit
+
+
+def owner_plan(shapes, elementwise, shards: int,
+               chunk_values: int = MUON_CHUNK_VALUES) -> OwnerPlan:
+    """Deal the keys of a bucket to ``shards`` owners by whole keys.
+
+    Of a shape class ``(shorter, longer)`` every owner gets the same
+    number of wide keys and of tall ones, a run of the key order each: so
+    every owner's shard is laid out alike and ONE program serves them.
+    What does not divide (five ``q`` matrices over four owners) is dealt
+    one key at a time, the heaviest first, to the owner with the fewest
+    Newton-Schulz FLOPs so far; owners left with equal lists share a
+    branch of the program, and the room a shard keeps for them is the
+    fullest owner's.  The element-wise keys' values, which no handle needs
+    whole, are cut into equal stretches in key order, so they level the
+    owners out."""
+    shapes = np.asarray(shapes, np.int64).reshape(-1, 2)
+    elementwise = np.asarray(elementwise, bool)
+    lens = shapes[:, 0] * shapes[:, 1]
+    key_starts = np.concatenate([[0], np.cumsum(lens)])
+    S = int(shards)
+    groups: dict = {}
+    for k in np.flatnonzero(~elementwise):
+        r, c = (int(d) for d in shapes[k])
+        groups.setdefault((min(r, c), max(r, c)), ([], []))[r > c].append(
+            int(k))
+
+    slot_shapes, slot_starts = [], []
+    where = np.full((len(lens), 4), -1, np.int64)
+    at = 0
+
+    def slot(shape) -> int:
+        nonlocal at
+        at = _up(at, LANES)
+        slot_starts.append(at)
+        slot_shapes.append(tuple(int(d) for d in shape))
+        at += int(shape[0]) * int(shape[1])
+        return len(slot_starts) - 1
+
+    # What divides: the same slots on every owner.
+    flops = np.zeros(S)
+    chunks, left = [], []
+    for (m, n), by_side in groups.items():
+        mine = [[] for _ in range(S)]        # (key, tall) an owner, by slot
+        for tall, keys in enumerate(by_side):
+            per = len(keys) // S
+            for s in range(S):
+                mine[s] += [(k, bool(tall)) for k in
+                            keys[s * per:(s + 1) * per]]
+            left += [((m, n), bool(tall), k) for k in keys[S * per:]]
+        flops += len(mine[0]) * ns_flops(m, n)
+        per = max(1, chunk_values // (m * n))
+        for i in range(0, len(mine[0]), per):
+            talls = tuple(t for _, t in mine[0][i:i + per])
+            ids = tuple(slot((n, m) if t else (m, n)) for t in talls)
+            for s in range(S):
+                for j, (k, _) in enumerate(mine[s][i:i + per]):
+                    where[k] = (s, len(chunks), j, ids[j])
+            chunks.append((m, n, ids, talls))
+
+    # What is left: a key at a time, the heaviest first, to the lightest.
+    theirs = [[] for _ in range(S)]
+    for cls, tall, k in sorted(left, key=lambda e: -ns_flops(*e[0])):
+        s = int(np.argmin(flops))
+        flops[s] += ns_flops(*cls)
+        theirs[s].append((cls, tall, k))
+    classes = [cls for cls in groups if any(e[0] == cls for e in left)]
+    rest = tuple(
+        (max(sum(e[0] == cls for e in theirs[s]) for s in range(S)), *cls)
+        for cls in classes)
+    room, signatures, branches = at, [], []
+    main_slots = len(slot_starts)
+    branch_of = np.zeros(S, np.int64)
+    end = room
+    for s in range(S):
+        lists = [(a, sorted((t, k) for c, t, k in theirs[s] if c == cls))
+                 for a, cls in enumerate(classes)]
+        signature = tuple((a, tuple(t for t, _ in ks))
+                          for a, ks in lists if ks)
+        if signature not in signatures:
+            signatures.append(signature)
+            at, pairs = room, []
+            for a, talls in signature:
+                _, m, n = rest[a]
+                ids = tuple(slot((n, m) if t else (m, n)) for t in talls)
+                pairs.append((a, (m, n, ids, talls)))
+            branches.append(pairs)
+            end = max(end, at)
+        b = branch_of[s] = signatures.index(signature)
+        for (a, ks), (_, (_, _, ids, _)) in zip(
+                [e for e in lists if e[1]], branches[b]):
+            for j, (_, k) in enumerate(ks):
+                where[k] = (s, len(chunks) + a, j, ids[j])
+
+    # The element-wise values: equal stretches of their order.
+    adamw = np.flatnonzero(elementwise)
+    E = int(lens[adamw].sum())
+    stretch, adamw_slot = 0, None
+    at = _up(end, 1024)
+    if E:
+        stretch = -(-E // S)
+        stretch = _up(stretch, LANES if stretch <= ROW_BLOCK_VALUES
+                      else 2 ** 16)
+        adamw_slot = slot((1, stretch))
+    shard_len = _up(at, 1024)
+    starts = np.array(slot_starts, np.int64)
+    sshapes = np.array(slot_shapes, np.int64).reshape(-1, 2)
+    flagged = np.zeros(len(starts), bool)
+    if adamw_slot is not None:
+        flagged[adamw_slot] = True
+    ok = [takes_row(int(starts[i]), *(int(d) for d in sshapes[i]),
+                    bool(flagged[i])) for i in range(len(starts))]
+
+    def chunk(m, n, ids, talls) -> MuonChunk:
+        return MuonChunk(m, n, ids, talls, all(ok[i] for i in ids))
+
+    adamw_keys = np.array([] if adamw_slot is None else [adamw_slot],
+                          np.int64)
+    plan = MuonPlan(
+        chunks=tuple(chunk(*c) for c in chunks),
+        muon_keys=np.flatnonzero(~flagged), adamw_keys=adamw_keys,
+        mom_starts=np.zeros(1, np.int64),
+        adamw_starts=np.array([0, stretch] if E else [0], np.int64),
+        ns_flops=float(flops.sum()),
+        row_keys=np.flatnonzero(ok),
+        # A left-over key's new values reach the store by XLA's
+        # ``dynamic_update_slice``: the kernel that writes them back is
+        # one every device enters (its interpreter walks it with all of
+        # them in step), and a branch is entered by its owners alone.
+        apply_keys=np.array([i for i in range(len(starts)) if (
+            i < main_slots or flagged[i]) and takes_apply(
+            int(starts[i]), *(int(d) for d in sshapes[i]), bool(flagged[i]),
+            0, stretch)], np.int64))
+
+    # Where each run of the key order lies in the owners' order.
+    segments, e = [], 0
+    for k in range(len(lens)):
+        if not elementwise[k]:
+            s, _, _, i = where[k]
+            segments.append((int(key_starts[k]),
+                             int(s * shard_len + starts[i]), int(lens[k])))
+            continue
+        lo, n = int(key_starts[k]), int(lens[k])
+        while n:
+            s, off = divmod(e, stretch)
+            take = min(n, stretch - off)
+            segments.append((lo, int(s * shard_len + starts[adamw_slot]
+                                     + off), take))
+            lo, n, e = lo + take, n - take, e + take
+    merged = []
+    for src, dst, n in segments:
+        if n == 0:
+            continue
+        if merged and (merged[-1][0] + merged[-1][2] == src
+                       and merged[-1][1] + merged[-1][2] == dst):
+            merged[-1][2] += n
+        else:
+            merged.append([src, dst, n])
+    return OwnerPlan(
+        shards=S, shard_len=int(shard_len), starts=starts, shapes=sshapes,
+        plan=plan, rest=rest,
+        branches=tuple(tuple((a, chunk(*c)) for a, c in pairs)
+                       for pairs in branches),
+        branch_of=branch_of, where=where, stretch=int(stretch),
+        elementwise_len=E,
+        segments=np.array(merged, np.int64).reshape(-1, 3),
+        total_len=int(key_starts[-1]), flops=flops)
+
+
+def owner_state_shapes(owners: OwnerPlan) -> Tuple[Tuple[int, ...], ...]:
+    """:func:`state_shapes` of one owner: a momentum a chunk, one for each
+    class that left keys over (every owner keeps it, the one that owns none
+    of them too), then AdamW's m and v over the owner's stretch."""
+    return (*((len(c.keys), c.m, c.n) for c in owners.plan.chunks),
+            *owners.rest, (owners.stretch,), (owners.stretch,))
+
+
+def place(owners: OwnerPlan, values, xp):
+    """``values`` ``[..., total_len]`` in key order laid into the owners'
+    order ``[..., shards * shard_len]``, zeros where no key lies."""
+    by_dst, gaps = owners.laid
+    lead = tuple(values.shape[:-1])
+    pieces = [(dst, values[..., src:src + n]) for src, dst, n in by_dst]
+    pieces += [(at, xp.zeros(lead + (n,), values.dtype)) for at, n in gaps]
+    return xp.concatenate(
+        [piece for _, piece in sorted(pieces, key=lambda e: e[0])], axis=-1)
+
+
+def unplace(owners: OwnerPlan, values, xp):
+    """The inverse of :func:`place`: key order, at ``total_len``."""
+    return xp.concatenate([values[..., dst:dst + n]
+                           for _, dst, n in owners.segments.tolist()],
+                          axis=-1)
+
+
+def owner_momentum_vector(owners: OwnerPlan, arrays, xp):
+    """:func:`momentum_vector` of the owners' momenta (``arrays``: a
+    chunk's or a left-over class's over all owners, ``[shards * B, m,
+    n]``)."""
+    parts = []
+    for k in np.flatnonzero(owners.where[:, 0] >= 0):
+        s, a, j, i = (int(x) for x in owners.where[k])
+        mat = arrays[a][s * (arrays[a].shape[0] // owners.shards) + j]
+        rows, cols = owners.shapes[i]
+        parts.append((mat.T if rows > cols else mat).reshape(-1))
+    if not parts:
+        return xp.zeros((0,), np.float32)
+    return xp.concatenate(parts)
+
+
+def owner_momentum_arrays(owners: OwnerPlan, vector, xp):
+    """The inverse of :func:`owner_momentum_vector`; a slot no key lies in
+    is zeros."""
+    shapes = owner_state_shapes(owners)[:-2]
+    rows = [[None] * (owners.shards * b) for b, _, _ in shapes]
+    lo = 0
+    for k in np.flatnonzero(owners.where[:, 0] >= 0):
+        s, a, j, i = (int(x) for x in owners.where[k])
+        r, c = (int(d) for d in owners.shapes[i])
+        mat = vector[lo:lo + r * c].reshape(r, c)
+        rows[a][s * shapes[a][0] + j] = mat.T if r > c else mat
+        lo += r * c
+    return [xp.stack([xp.zeros((m, n), np.float32) if x is None else x
+                      for x in held])
+            for held, (_, m, n) in zip(rows, shapes)]
+
+
 def newton_schulz(x, steps: int = NS_STEPS):
     """``NS5`` of a batch ``[B, m, n]`` of bfloat16 matrices, m <= n."""
     import jax.numpy as jnp
@@ -636,7 +931,8 @@ def _row_adamw(alpha, base, g, m, v, store, pulled, *, adamw, pulled_len,
 
 def muon_update(store, state, agg, starts, shapes, plan: MuonPlan, *,
                 lr: float, mu: float, wd: float, b1: float, b2: float,
-                eps: float, pulled_len: int = 0, interpret: bool = False):
+                eps: float, pulled_len: int = 0, interpret: bool = False,
+                rest=None):
     """One step on the one shard that holds the bucket.  ``store`` is the
     flat f32 store, ``state`` as :func:`state_shapes` lays it out with the
     step slot last, ``agg`` the summed gradient as a row ``[1, total]``.
@@ -658,8 +954,7 @@ def muon_update(store, state, agg, starts, shapes, plan: MuonPlan, *,
     from jax import lax
 
     f32, bf16 = jnp.float32, jnp.bfloat16
-    n_chunks = len(plan.chunks)
-    moms, (adam_m, adam_v, step_l) = state[:n_chunks], state[n_chunks:]
+    moms, (adam_m, adam_v, step_l) = state[:-3], state[-3:]
     keep = 1.0 - lr * wd
     assert plan.pulls or not pulled_len, "a key of the bucket keeps the cut"
     pulled = None
@@ -667,8 +962,13 @@ def muon_update(store, state, agg, starts, shapes, plan: MuonPlan, *,
     def put(store, k: int, new_p):
         return lax.dynamic_update_slice(store, new_p, (int(starts[k]),))
 
+    def end(k: int) -> int:
+        # From the key's own shape: an owner's slots need not lie one
+        # behind the other (:func:`owner_plan`).
+        return int(starts[k]) + int(shapes[k][0]) * int(shapes[k][1])
+
     def key_values(vector, k: int):
-        return lax.slice(vector, (int(starts[k]),), (int(starts[k + 1]),))
+        return lax.slice(vector, (int(starts[k]),), (end(k),))
 
     def key_grad(row, k: int, shape):
         # The fall-back, for a key on no lane border.  The chip lays
@@ -678,7 +978,7 @@ def muon_update(store, state, agg, starts, shapes, plan: MuonPlan, *,
         # PERF.md, PR 43); squeezing the whole row first is no better, a
         # pass over the gradient and a second copy of it.
         return lax.slice(row, (0, int(starts[k])),
-                         (1, int(starts[k + 1]))).reshape(shape)
+                         (1, end(k))).reshape(shape)
 
     def momentum(mom, g):
         mom = mu * mom + g
@@ -695,8 +995,10 @@ def muon_update(store, state, agg, starts, shapes, plan: MuonPlan, *,
 
     row_keys = {int(k) for k in plan.row_keys}
     apply_keys = {int(k) for k in plan.apply_keys}
-    new_moms = []
-    for chunk, mom in zip(plan.chunks, moms):
+
+    def chunk_step(chunk, mom, store, pulled, agg):
+        """One chunk's momentum pass, products and way out: its momentum,
+        the store and the pulled vector after it."""
         with jax.named_scope("ps.update.muon.momentum"):
             if chunk.row:
                 x = None
@@ -724,8 +1026,44 @@ def muon_update(store, state, agg, starts, shapes, plan: MuonPlan, *,
                     o_k = (o[i].T if tall else o[i]).reshape(-1).astype(f32)
                     store = put(store, k, new_values(
                         key_values(store, k), o_k, scale))
+        return mom, store, pulled
+
+    new_moms = []
+    for chunk, mom in zip(plan.chunks, moms):
+        mom, store, pulled = chunk_step(chunk, mom, store, pulled, agg)
         new_moms.append(mom)
         store, agg = lax.optimization_barrier((store, agg))
+
+    if rest is not None:
+        # What is left of a shape class once every owner has as many: the
+        # owners' lists differ, and each takes the branch that runs its
+        # own (an owner does no products for a matrix it does not own).
+        rest_moms = moms[len(plan.chunks):]
+
+        def branch(pairs):
+            def run(store, agg, *rest_moms):
+                rest_moms = list(rest_moms)
+                for at, chunk in pairs:
+                    held = len(chunk.keys)
+                    mom, store, _ = chunk_step(
+                        chunk, rest_moms[at][:held], store, None, agg)
+                    rest_moms[at] = (
+                        mom if held == rest_moms[at].shape[0] else
+                        lax.dynamic_update_slice(rest_moms[at], mom,
+                                                 (0, 0, 0)))
+                    store, agg = lax.optimization_barrier((store, agg))
+                return (store, *rest_moms)
+
+            return run
+
+        branches, which = rest
+        runs = [branch(pairs) for pairs in branches]
+        if len(runs) == 1:
+            store, *rest_moms = runs[0](store, agg, *rest_moms)
+        else:
+            store, *rest_moms = lax.switch(which, runs, store, agg,
+                                           *rest_moms)
+        new_moms += rest_moms
 
     with jax.named_scope("ps.update.muon.adamw"):
         t = step_l[0] + 1.0

@@ -54,7 +54,10 @@ class DenseBucket:
     ``starts[i]``, and a flag word a key (``KEY_NO_DECAY``,
     ``KEY_NO_ADAPT``, ``KEY_ELEMENTWISE``) for a handle that treats keys
     apart (``lamb``, ``muon``); with ``shapes`` besides, each key's
-    ``(rows, cols)`` for a handle that works on whole matrices (``muon``).
+    ``(rows, cols)`` for a handle that works on whole matrices (``muon``):
+    over several shards such a handle has the bucket sharded on its keys'
+    borders (``owned``), every matrix whole on one shard, where any other
+    handle finds it cut at any element.
 
     ``dtype`` is the store's, the optimizer state's and every norm's;
     ``job_dtype`` what the job pushes and is handed back (one field: a
@@ -88,6 +91,15 @@ class DenseBucket:
     # ops.muon.MuonPlan once ``muon`` has bound the bucket (or its state
     # was asked for): ``CollectiveEngine._muon_plan``.
     muon_plan: Optional[object] = field(init=False, default=None)
+    # (shards, ops.muon.OwnerPlan) of a bucket with ``shapes`` on a mesh of
+    # several shards, made at registration: which key would lie whole on
+    # which shard (``CollectiveEngine._owner_plan``).
+    owner_plan: Optional[tuple] = field(init=False, default=None)
+    # That plan once the store IS laid by it, every matrix key whole on its
+    # owner (``CollectiveEngine._lay_by_owners``: when ``muon`` takes the
+    # bucket); None while the store lies in key order, cut at any element.
+    # ``padded_len`` is the current layout's.
+    owned: Optional[object] = field(init=False, default=None)
 
     def __post_init__(self):
         self.job_dtype = np.dtype(
@@ -207,13 +219,22 @@ def _aggregate_whole(rows_l, shard_len: int, shards: int, axis,
     shard's part as a row: ``[1, shard_len]`` of the gradient padded with
     zeros where the bucket lies over several shards, and on one shard the
     row as it came, ``[1, total]``, since nothing has to be cut and a pad
-    is a copy of the whole gradient."""
+    is a copy of the whole gradient.  A VECTOR ``[shards * shard_len]``
+    (a bucket that lies by its owner plan hands its row over laid out and
+    flat: :meth:`CollectiveEngine._stateful_program`) is summed and cut as
+    the vector it is: as a row the chip keeps a third copy of the tree
+    through the sum."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
     with jax.named_scope("ps.push.reduce"):
-        if shards > 1:
+        if rows_l.ndim == 1:
+            if worker_axis is None:
+                return lax.psum_scatter(rows_l, axis, scatter_dimension=0,
+                                        tiled=True).reshape(1, -1)
+            rows_l = rows_l.reshape(1, -1)
+        if shards * shard_len > rows_l.shape[1] and shards > 1:
             rows_l = jnp.pad(
                 rows_l,
                 ((0, 0), (0, shards * shard_len - rows_l.shape[1])))
@@ -444,6 +465,12 @@ class CollectiveEngine:
         self.muon_row_keys = 0
         self.muon_apply_keys = 0
         self.muon_ns_flops = 0.0
+        # ... and over several shards, by the owner plan: the shards that
+        # own a matrix, and the fullest owner's Newton-Schulz FLOPs over
+        # the owners' mean, x1000 (``engine.update.muon.owners``,
+        # ``.owner_flops``; 1 and 1000 on one shard).
+        self.muon_owners = 0
+        self.muon_owner_flops = 0
 
     # -- registration --------------------------------------------------------
 
@@ -558,6 +585,8 @@ class CollectiveEngine:
             job_dtype=job_dtype,
             shapes=shapes,
         )
+        if shapes is not None:
+            self._owner_plan(bucket)
         sharding = NamedSharding(self.mesh, P(self.axis))
         if init is not None:
             flat = np.zeros(padded, dtype=np.dtype(dtype))
@@ -642,7 +671,7 @@ class CollectiveEngine:
                       self._segments_refusal(handle, bucket))
             if handle.startswith("muon"):
                 fn = self._muon_fn(handle, bucket)
-                return len(self._muon_plan(bucket).chunks) + 3, fn
+                return self._n_state(handle, bucket), fn
             return 3, self._lamb_fn(handle, bucket)
         if handle.startswith("sgd_momentum"):
             lr, momentum = self._handle_params(handle, (0.01, 0.9))
@@ -775,15 +804,19 @@ class CollectiveEngine:
         return fn
 
     def _muon_fn(self, handle: str, bucket: DenseBucket) -> Callable:
-        """``muon:lr,mu,wd,b1,b2,eps`` on the one shard that holds
-        ``bucket`` (``ops/muon.py`` has the recurrence): a matrix key is
+        """``muon:lr,mu,wd,b1,b2,eps`` on a shard of ``bucket``
+        (``ops/muon.py`` has the recurrence): a matrix key is
         updated by its orthogonalised momentum, five Newton-Schulz steps
         in bfloat16 with Nesterov and the published coefficients (the
         optimizer's, no parameters), a key flagged ``KEY_ELEMENTWISE`` by
         AdamW.  It needs what no flat bucket says, each key's shape, and
         every matrix whole where its products run; where it cannot run it
         says so by name (:meth:`_muon_refusal`) and nothing falls back to
-        an element-wise update.  The new parameters are written where
+        an element-wise update.  Over several shards the bucket lies by
+        its owner plan (``bucket.owned``, :meth:`_lay_by_owners`): every
+        shard runs the one update over its own slots, and what the shape
+        classes leave over runs in the branch of the owner that has it
+        (``ops.muon.owner_plan``).  The new parameters are written where
         they lie in the store, and with ``pulled_len`` (as
         :meth:`_lamb_fn` takes it, where :meth:`_kernel_pulls` says so)
         the kernels that write them leave them once more as a vector of
@@ -797,17 +830,37 @@ class CollectiveEngine:
         log.check(refusal is None, refusal)
         lr, mu, wd, b1, b2, eps = self._handle_params(
             handle, (1e-3, 0.95, 0.1, 0.9, 0.95, 1e-8))
-        plan = self._muon_plan(bucket)
-        starts, shapes, interp = bucket.starts, bucket.shapes, self._interpret
-        elementwise = (bucket.flags & KEY_ELEMENTWISE) != 0
+        interp, axis, owners = self._interpret, self.axis, bucket.owned
+        if owners is None:
+            plan = self._muon_plan(bucket)
+            starts, shapes = bucket.starts, bucket.shapes
+            elementwise = (bucket.flags & KEY_ELEMENTWISE) != 0
+            layout = (np.asarray(shapes).tolist(), elementwise.tolist())
+        else:
+            plan, starts, shapes = owners.plan, owners.starts, owners.shapes
+            layout = (starts.tolist(), shapes.tolist(), repr(plan.chunks),
+                      repr(owners.branches))
 
         def fn(store_l, state_l, agg, pulled_len=0):
+            import jax.numpy as jnp
+            from jax import lax
+
             def update(store_l, agg, *state_l):
+                rest = None
+                if owners is not None and owners.rest:
+                    *state_l, which = state_l
+                    rest = (owners.branches, which[0])
                 return muon.muon_update(
                     store_l, state_l, agg, starts, shapes, plan, lr=lr,
                     mu=mu, wd=wd, b1=b1, b2=b2, eps=eps,
-                    pulled_len=pulled_len, interpret=interp)
+                    pulled_len=pulled_len, interpret=interp, rest=rest)
 
+            if owners is not None and owners.rest:
+                # The branch this owner takes, as an array: ``update`` is
+                # traced alone, outside the mesh.
+                state_l = (*state_l, jnp.asarray(
+                    owners.branch_of, jnp.int32)[lax.axis_index(axis)
+                                                 ].reshape(1))
             if interp:
                 return update(store_l, agg, *state_l)
             # Compiled for the chip, the step's trace is kept between
@@ -816,8 +869,7 @@ class CollectiveEngine:
             # each run, before the compile cache can be asked.
             return call_traced(
                 update, muon.__file__, "tpu", store_l, agg, *state_l,
-                static=(lr, mu, wd, b1, b2, eps, pulled_len,
-                        np.asarray(shapes).tolist(), elementwise.tolist()))
+                static=(lr, mu, wd, b1, b2, eps, pulled_len, *layout))
 
         return fn
 
@@ -829,12 +881,6 @@ class CollectiveEngine:
             return (f"{said} and needs each key's (rows, cols), which "
                     f"bucket {bucket.name!r}, registered without shapes=, "
                     f"does not say: register it with lens= and shapes=")
-        if self.num_shards != 1:
-            return (f"{said}, and bucket {bucket.name!r} lies over "
-                    f"{self.num_shards} shards cut at any element, so a "
-                    f"matrix would lie across chips: it runs where one "
-                    f"shard holds the bucket (a bucket sharded on its keys' "
-                    f"borders does not exist yet)")
         if bucket.mixed:
             return (f"{said} in f32, and bucket {bucket.name!r} is pushed "
                     f"and pulled in {bucket.job_dtype} over its "
@@ -858,6 +904,107 @@ class CollectiveEngine:
             bucket.muon_plan = muon_plan(
                 bucket.shapes, (bucket.flags & KEY_ELEMENTWISE) != 0)
         return bucket.muon_plan
+
+    def _owner_plan(self, bucket: DenseBucket):
+        """``ops.muon.owner_plan`` of ``bucket`` over this mesh's shards,
+        made once for a shard count (``reshard`` gives the mesh others):
+        which key lies whole on which shard, at which offset, and the
+        padded length, for a handle that needs its keys whole.  None on
+        one shard, where the plan is the identity and the bucket lies in
+        key order, and for a bucket without ``shapes``."""
+        from ..ops.muon import owner_plan
+
+        if self.num_shards == 1 or bucket.shapes is None:
+            return None
+        if (bucket.owner_plan is None
+                or bucket.owner_plan[0] != self.num_shards):
+            bucket.owner_plan = (self.num_shards, owner_plan(
+                bucket.shapes, (bucket.flags & KEY_ELEMENTWISE) != 0,
+                self.num_shards))
+        return bucket.owner_plan[1]
+
+    def _relaid(self, bucket: DenseBucket, store, owners):
+        """``store`` (``bucket``'s, on the device, sharded, in key order
+        with whatever lies behind ``total_len``) laid by ``owners``: every
+        shard gathers the tree, lays it out and keeps its own part (a
+        program of its own, run when a layout changes, never in a step)."""
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+        from jax.sharding import PartitionSpec as P
+
+        from ..ops.muon import place
+
+        axis = self.axis
+
+        def body(store_l):
+            tree = lax.all_gather(store_l, axis, tiled=True)
+            # (What lies behind ``total_len`` is in no run of the plan.)
+            placed = place(owners, tree, jnp)
+            return lax.dynamic_slice_in_dim(
+                placed, lax.axis_index(axis) * owners.shard_len,
+                owners.shard_len)
+
+        return jax.jit(jax.shard_map(
+            body, mesh=self.mesh, in_specs=P(axis), out_specs=P(axis),
+            check_vma=False))(store)
+
+    def _in_key_order(self, bucket: DenseBucket, store):
+        """The inverse: an owned bucket's store ``[total_len]`` in key
+        order, on every device."""
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+        from jax.sharding import PartitionSpec as P
+
+        from ..ops.muon import unplace
+
+        axis, owners = self.axis, bucket.owned
+        return jax.jit(jax.shard_map(
+            lambda store_l: unplace(
+                owners, lax.all_gather(store_l, axis, tiled=True), jnp),
+            mesh=self.mesh, in_specs=P(axis), out_specs=P(None),
+            check_vma=False))(store)
+
+    def _lay_by_owners(self, bucket: DenseBucket) -> None:
+        """Lay ``bucket``'s store by its owner plan, once: a handle that
+        needs its keys whole takes the bucket for good (a bucket has one
+        kind of state), so from here on every matrix key lies whole on one
+        shard and ``padded_len`` is the plan's.  What leaves the bucket
+        (``store_array``, ``opt_state``, ``pull``, a checkpoint) stays in
+        key order.  Nothing on one shard."""
+        owners = self._owner_plan(bucket)
+        if owners is None or bucket.owned is not None:
+            return
+        name = bucket.name
+        with self._bucket_mu[name]:
+            if bucket.owned is not None:
+                return
+            have = self._opt_kinds.get(name)
+            log.check(have is None,
+                      f"bucket {name!r} already has {have!r} state over a "
+                      f"store cut at any element; cannot switch to a handle "
+                      f"that has it sharded on its keys' borders")
+            log.check(name not in self._pinned_pulls, self._owned_refusal(
+                name, "a pinned pull buffer (a padded-length buffer in the "
+                "store's own order)", "unregister it"))
+            self._stores[name] = self._relaid(bucket, self._stores[name],
+                                              owners)
+            bucket.owned = owners
+            bucket.padded_len = owners.padded_len
+        with self._mu:
+            for key in [k for k in self._bound if k[0] == name]:
+                del self._bound[key]
+
+    @staticmethod
+    def _owned_refusal(name: str, what: str, instead: str) -> str:
+        """Why ``what`` does not serve a bucket that lies by its owner
+        plan, and what does."""
+        return (f"bucket {name!r} is sharded on its keys' borders (a handle "
+                f"that works on whole matrices has taken it over several "
+                f"shards), which only the bucket's own programs serve "
+                f"(push_pull and push under that handle, pull); {what} "
+                f"would read it in key order: {instead}")
 
     @staticmethod
     def _is_stateful(handle) -> bool:
@@ -985,6 +1132,15 @@ class CollectiveEngine:
             return new, new[:1]
 
         def _pull(store_l):
+            if bucket is not None and bucket.owned is not None:
+                # Sharded on its keys' borders: gathered, then key order.
+                import jax.numpy as jnp
+
+                from ..ops.muon import unplace
+
+                pulled = _gather(store_l, axis)
+                with jax.named_scope("ps.pull.place"):
+                    return unplace(bucket.owned, pulled, jnp)
             if bucket is not None:  # mixed: rounded, gathered, cut
                 return _gather(_narrowed(store_l, bucket.job_dtype),
                                axis)[:bucket.total_len]
@@ -1068,7 +1224,11 @@ class CollectiveEngine:
         pulled values at ``total_len``: what lies behind the last key is
         the program's business and no caller's.  Before or after the
         program, a pad or a cut is a launch and a copy of its own, of a
-        whole tree where the bucket is one.  Inside it the cut is that
+        whole tree where the bucket is one.  A bucket that lies by its
+        owner plan (``bucket.owned``: under ``muon`` over several shards)
+        has the row laid into the owners' order before the sum
+        (``ps.push.place``) and the gathered shards back into key order
+        after (``ps.pull.place``), both inside.  Inside it the cut is that
         copy too where one shard holds the bucket (the all-gather is the
         identity): there a handle whose kernel can leave the pulled values
         itself is asked to (:meth:`_kernel_pulls`), and what its function
@@ -1082,8 +1242,10 @@ class CollectiveEngine:
         cross the chips) where no kernel wrote the pulled values in the
         job's dtype itself."""
         import jax
-        from jax import lax
+        import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
+
+        from ..ops import muon as muon_ops
 
         n_state, sfn = self._stateful_handle(handle_key, bucket)
         if self._kernel_pulls(op, handle_key, bucket):
@@ -1106,6 +1268,7 @@ class CollectiveEngine:
             shards = self.num_shards
             shard_len, total = bucket.padded_len // shards, bucket.total_len
             passes = shards == 1 and self.num_workers == 1
+            owners = bucket.owned
             if not self._needs_segments(handle_key):
                 sfn = _zero_filled(sfn)
 
@@ -1116,10 +1279,20 @@ class CollectiveEngine:
                     grads_l = _widened(
                         grads_l, bucket.dtype,
                         shards * shard_len if shards > 1 else total)
+                if owners is not None:
+                    # The job's row, in key order, laid as the owners lie:
+                    # the scatter then hands each its own keys whole.
+                    with jax.named_scope("ps.push.place"):
+                        grads_l = muon_ops.place(owners, grads_l,
+                                                 jnp).reshape(-1)
                 return _aggregate_whole(grads_l, shard_len, shards, axis,
                                         waxis)
 
             def cut(pulled):
+                if owners is not None:
+                    # ... and the gathered tree back into key order.
+                    with jax.named_scope("ps.pull.place"):
+                        return muon_ops.unplace(owners, pulled, jnp)
                 return pulled[:total]
 
         def narrow(store_l):
@@ -1168,8 +1341,20 @@ class CollectiveEngine:
         store."""
         kind = handle.split(":", 1)[0]
         if kind == "muon":
-            return len(self._muon_plan(bucket).chunks) + 3
+            return len(self._muon_state_shapes(bucket)) + 1
         return 1 if kind in ("sgd_momentum", "adagrad") else 3
+
+    def _muon_state_shapes(self, bucket: DenseBucket):
+        """The shapes of ``muon``'s state over the mesh, the step slot
+        apart: ``ops.muon.state_shapes`` on one shard; over several an
+        owner's (``owner_state_shapes``), every owner's side by side."""
+        from ..ops.muon import owner_state_shapes, state_shapes
+
+        owners = self._owner_plan(bucket)
+        if owners is None:
+            return state_shapes(self._muon_plan(bucket))
+        return tuple((self.num_shards * shape[0], *shape[1:])
+                     for shape in owner_state_shapes(owners))
 
     def _ensure_opt_state(self, name: str, handle: str, bucket) -> None:
         """Allocate (or validate) the bucket's optimizer state for
@@ -1191,11 +1376,9 @@ class CollectiveEngine:
             # the AdamW keys alone, the step.  Zero-filled where it lies.
             import jax.numpy as jnp
 
-            from ..ops.muon import state_shapes
-
             state = (
                 *(jnp.zeros(shape, dt, device=sharding)
-                  for shape in state_shapes(self._muon_plan(bucket))),
+                  for shape in self._muon_state_shapes(bucket)),
                 self._place(np.zeros(self.num_shards, np.float32), sharding),
             )
         elif kind in ("sgd_momentum", "adagrad"):
@@ -1214,7 +1397,7 @@ class CollectiveEngine:
         """Snapshot of the bucket's optimizer state (checkpointing).
         Returns (kind, arrays) or None when the bucket has none.  Under
         ``muon`` the arrays are the state's logical form, whatever the
-        chunks it is kept in: the momentum as one vector over the Muon
+        chunks it is kept in and whichever owner keeps them: the momentum as one vector over the Muon
         keys in key order (a key's values as its matrix lies in the
         store), AdamW's m and v over its keys in key order, the step."""
         import jax.numpy as jnp
@@ -1224,8 +1407,17 @@ class CollectiveEngine:
                 return None
             kind, state = self._opt_kinds[name], self._opt_states[name]
             if kind == "muon":
-                from ..ops.muon import momentum_vector
+                from ..ops.muon import momentum_vector, owner_momentum_vector
 
+                owners = self._buckets[name].owned
+                if owners is not None:
+                    # Key order, whatever the owners' layout: an owner's
+                    # stretch of m and v is full up to the last.
+                    n = owners.elementwise_len
+                    return kind, (
+                        owner_momentum_vector(owners, state[:-3], jnp),
+                        jnp.copy(state[-3][:n]), jnp.copy(state[-2][:n]),
+                        jnp.copy(state[-1]))
                 plan = self._muon_plan(self._buckets[name])
                 n = len(plan.chunks)
                 return kind, (momentum_vector(plan, state[:n], jnp),
@@ -1324,11 +1516,12 @@ class CollectiveEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..ops.muon import momentum_chunks
+        from ..ops.muon import momentum_chunks, owner_momentum_arrays
 
         refusal = self._muon_refusal("muon", bucket)
         log.check(refusal is None, refusal)
-        plan = self._muon_plan(bucket)
+        self._lay_by_owners(bucket)
+        plan, owners = self._muon_plan(bucket), bucket.owned
         log.check_eq(len(values), 4,
                      f"bucket {bucket.name!r}: muon's state is the momentum, "
                      f"AdamW's m and v and the step")
@@ -1344,9 +1537,14 @@ class CollectiveEngine:
                          f"{bucket.name!r}: {what}")
             vectors.append(jnp.asarray(v).reshape(-1))
         step = np.asarray(values[3]).reshape(-1)
+        if owners is None:
+            chunks = momentum_chunks(plan, vectors[0], jnp)
+        else:
+            chunks = owner_momentum_arrays(owners, vectors[0], jnp)
+            fill = owners.shards * owners.stretch - plan.adamw_len
+            vectors[1:] = [jnp.pad(v, (0, fill)) for v in vectors[1:]]
         placed = (
-            *(jax.device_put(c, sharding)
-              for c in momentum_chunks(plan, vectors[0], jnp)),
+            *(jax.device_put(c, sharding) for c in chunks),
             jax.device_put(vectors[1], sharding),
             jax.device_put(vectors[2], sharding),
             self._place(np.full(self.num_shards,
@@ -1589,6 +1787,17 @@ class CollectiveEngine:
         mesh, bucket = self.mesh, self._buckets[name]
         resolved, handle_key = self._resolve_handle(handle)
         push = zero_copy is None
+        if isinstance(resolved, str) and resolved.startswith("muon"):
+            # A handle that needs its keys whole: over several shards the
+            # bucket is laid by its owner plan, once, before its program
+            # is built for that layout.
+            if bucket.shapes is not None:
+                refusal = self._muon_refusal(resolved, bucket)
+                log.check(refusal is None, refusal)
+                self._lay_by_owners(bucket)
+        else:
+            log.check(bucket.owned is None, self._owned_refusal(
+                name, f"handle {resolved!r}", "use the handle that took it"))
         # (A bucket with lens is kept in whole tiles on one shard too: its
         # store is not its pulled value.)
         zc = (bool(zero_copy)
@@ -1688,8 +1897,9 @@ class CollectiveEngine:
         """``prog`` behind the counts of ``engine.update.lamb`` (with
         ``engine.update.lamb.one_pass``, the elements that the last such
         program updated in one pass) or ``engine.update.muon`` (with
-        ``.matrices``, ``.row_keys``, ``.apply_keys`` and ``.ns_flops``, a
-        step's by the plan of the last such program), where the program
+        ``.matrices``, ``.row_keys``, ``.apply_keys``, ``.ns_flops``,
+        ``.owners`` and ``.owner_flops``, a step's by the plan of the last
+        such program), where the program
         takes its pulled values from the update's kernels
         (:meth:`_kernel_pulls`) of ``engine.pull.from_kernel``, and on a mixed
         bucket of ``engine.dense.narrow``: what a record of :meth:`_bind`
@@ -1698,6 +1908,18 @@ class CollectiveEngine:
         lamb, muon, narrow = kind == "lamb", kind == "muon", bucket.mixed
         one_pass = self._lamb_plan(bucket).one_pass_len if lamb else 0
         plan = self._muon_plan(bucket) if muon else None
+        owners, owned, spread = 1, bucket.owned if muon else None, 1000
+        if owned is not None:
+            # A key's way in and out is its slot's, on its owner: a
+            # matrix's own, an element-wise key's the owners' stretch.
+            slots = np.where(owned.where[:, 0] >= 0, owned.where[:, 3],
+                             owned.plan.adamw_keys[:1].sum())
+            plan = plan._replace(
+                row_keys=slots[np.isin(slots, owned.plan.row_keys)],
+                apply_keys=slots[np.isin(slots, owned.plan.apply_keys)])
+            owners = int(np.count_nonzero(owned.flops))
+            spread = int(round(1000 * owned.flops.max()
+                               / owned.flops.mean()))
 
         def counted(*args):
             if lamb:
@@ -1709,6 +1931,8 @@ class CollectiveEngine:
                 self.muon_row_keys = len(plan.row_keys)
                 self.muon_apply_keys = len(plan.apply_keys)
                 self.muon_ns_flops = plan.ns_flops
+                self.muon_owners = owners
+                self.muon_owner_flops = spread
             self.kernel_pulls += kernel_pulls
             self.narrow_ops += narrow
             return prog(*args)
@@ -1730,6 +1954,16 @@ class CollectiveEngine:
                        fn=lambda: self.muon_apply_keys)
         registry.gauge("engine.update.muon.ns_flops",
                        fn=lambda: self.muon_ns_flops)
+        registry.gauge("engine.update.muon.owners",
+                       fn=lambda: self.muon_owners)
+        registry.gauge("engine.update.muon.owner_flops",
+                       fn=lambda: self.muon_owner_flops)
+        registry.gauge(
+            "engine.dense.owned.pad_bytes",
+            fn=lambda: sum(
+                (b.padded_len - b.total_len) * np.dtype(b.dtype).itemsize
+                for b in list(self._buckets.values())
+                if b.owned is not None))
         registry.gauge("engine.pull.from_kernel",
                        fn=lambda: self.kernel_pulls)
         registry.gauge("engine.dense.narrow", fn=lambda: self.narrow_ops)
@@ -2370,8 +2604,10 @@ class CollectiveEngine:
         bucket = self._buckets[name]
         to_pinned = name in self._pinned_pulls
         # A mixed bucket's pull is its own program: the store rounded to
-        # the job's dtype, gathered and cut at total_len inside.
-        own = bucket if bucket.mixed else None
+        # the job's dtype, gathered and cut at total_len inside; so is
+        # that of a bucket sharded on its keys' borders: gathered and laid
+        # back into key order.
+        own = bucket if bucket.mixed or bucket.owned is not None else None
         prog = self._program(
             "pull_pinned" if to_pinned else "pull", bucket.padded_len,
             bucket.dtype, "_pull_pinned" if to_pinned else "_pull", own,
@@ -2403,7 +2639,7 @@ class CollectiveEngine:
                 arrays = 2
                 if own is None:
                     pulled = pulled[: bucket.total_len]
-                else:
+                elif bucket.mixed:
                     self.narrow_ops += 1
         self._observe("pull", bucket)
         t2 = stamp()
@@ -2431,6 +2667,9 @@ class CollectiveEngine:
         self._refuse_mixed(bucket, "a pinned pull buffer (a padded-length "
                            "buffer of the store's dtype, donated from pull "
                            "to pull)", "pull into a fresh array")
+        log.check(bucket.owned is None, self._owned_refusal(
+            name, "a pinned pull buffer (a padded-length buffer in the "
+            "store's own order)", "pull into a fresh array"))
         # _place handles multi-process meshes (device_put cannot target
         # non-addressable devices).
         buf = self._place(
@@ -2460,6 +2699,10 @@ class CollectiveEngine:
         import jax.numpy as jnp
 
         with self._bucket_mu[name]:
+            bucket = self._buckets[name]
+            if bucket.owned is not None:
+                # Key order at ``total_len``, whatever the owners' layout.
+                return self._in_key_order(bucket, self._stores[name])
             return jnp.copy(self._stores[name])
 
     def store_spec(self, name: str):
@@ -2472,6 +2715,17 @@ class CollectiveEngine:
             return jax.ShapeDtypeStruct(
                 arr.shape, arr.dtype, sharding=arr.sharding
             )
+
+    def opt_state_specs(self, name: str):
+        """Shape/dtype/sharding of each array the bucket's optimizer state
+        is KEPT in, without copying any (``opt_state`` hands out the logical
+        form); () where the bucket has none."""
+        import jax
+
+        with self._bucket_mu[name]:
+            return tuple(
+                jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+                for a in self._opt_states.get(name, ()))
 
     def set_store_array(self, name: str, value) -> None:
         """Restore server state (checkpoint resume).
@@ -2486,6 +2740,25 @@ class CollectiveEngine:
 
         bucket = self._buckets[name]
         sharding = NamedSharding(self.mesh, P(self.axis))
+        if (bucket.owned is not None
+                and int(np.size(value)) == bucket.total_len):
+            # Key order in, laid by the owners (what ``store_array`` and a
+            # checkpoint hand out; an array of ``padded_len`` is taken as
+            # laid out already, as for any bucket).
+            from ..ops.muon import place
+
+            if isinstance(value, jax.Array):
+                log.check_eq(value.dtype, np.dtype(bucket.dtype),
+                             "bad restore dtype")
+                placed = self._relaid(bucket, value.reshape(-1),
+                                      bucket.owned)
+            else:
+                placed = self._place(place(
+                    bucket.owned, np.asarray(value, np.dtype(
+                        bucket.dtype)).reshape(-1), np), sharding)
+            with self._bucket_mu[name]:
+                self._stores[name] = placed
+            return
         if isinstance(value, jax.Array):
             if (tuple(value.shape) == (bucket.total_len,)
                     and bucket.total_len != bucket.padded_len):
@@ -2602,19 +2875,33 @@ class CollectiveEngine:
             # collectives require).
             old_mp = self._multiprocess
             names = ordered
+            from ..ops import muon as muon_ops
+
             snap = {}
             for n in names:
                 b = self._buckets[n]
-                store = to_host_global(
-                    self._stores[n], old_mp
-                )[: b.total_len].copy()
+                store = to_host_global(self._stores[n], old_mp)
+                # Key order between layouts, whichever this one is.
+                store = (muon_ops.unplace(b.owned, store, np)
+                         if b.owned is not None
+                         else store[: b.total_len].copy())
                 opt = None
                 if n in self._opt_states:
-                    opt = (
-                        self._opt_kinds[n],
-                        [to_host_global(a, old_mp).copy()
-                         for a in self._opt_states[n]],
-                    )
+                    kind = self._opt_kinds[n]
+                    arrs = [to_host_global(a, old_mp).copy()
+                            for a in self._opt_states[n]]
+                    if kind == "muon":
+                        # The logical form ``opt_state`` hands out.
+                        if b.owned is not None:
+                            mom = muon_ops.owner_momentum_vector(
+                                b.owned, arrs[:-3], np)
+                        else:
+                            mom = muon_ops.momentum_vector(
+                                self._muon_plan(b), arrs[:-3], np)
+                        held = self._muon_plan(b).adamw_len
+                        arrs = [mom, arrs[-3][:held], arrs[-2][:held],
+                                arrs[-1]]
+                    opt = (kind, arrs)
                 snap[n] = (b, store, opt)
 
             # STAGE: build every new placement against the NEW mesh
@@ -2651,9 +2938,22 @@ class CollectiveEngine:
                 b, store, opt = snap[n]
                 padded = _padded_len(b.total_len, new_num_shards,
                                      b.lens is not None)
+                # A bucket that a handle has taken whole key by whole key
+                # lies by the NEW mesh's owner plan (none on one shard).
+                owners = None
+                if new_num_shards > 1 and (
+                        b.owned is not None
+                        or (opt is not None and opt[0] == "muon")):
+                    owners = muon_ops.owner_plan(
+                        b.shapes, (b.flags & KEY_ELEMENTWISE) != 0,
+                        new_num_shards)
+                    padded = owners.padded_len
+                    store = muon_ops.place(owners, store, np)
                 entry = {
                     "padded": padded,
-                    "store": _repad(store, b.total_len, padded, b.dtype),
+                    "owners": owners,
+                    "store": (_repad(store, b.total_len, padded, b.dtype)
+                              if owners is None else _nplace(store, sharding)),
                 }
                 if n in self._pinned_pulls:
                     # Re-pin on the new mesh: the old pinned buffer's
@@ -2666,18 +2966,25 @@ class CollectiveEngine:
                 if opt is not None:
                     kind, arrs = opt
                     if kind == "muon":
-                        # One shard to one shard: the chunks as they are.
-                        log.check(
-                            new_num_shards == 1,
-                            f"bucket {n!r} is under muon, which works on "
-                            f"whole matrices, and the new mesh has "
-                            f"{new_num_shards} shards cut at any element, "
-                            f"so a matrix would lie across chips: it "
-                            f"reshards onto one shard alone")
-                        step = float(arrs[-1][0]) if len(arrs[-1]) else 0.0
+                        # From the logical form into the new layout's
+                        # arrays: the chunks of one shard, or every
+                        # owner's side by side.
+                        mom, m, v, step = arrs
+                        if owners is None:
+                            chunks = muon_ops.momentum_chunks(
+                                self._muon_plan(b), mom, np)
+                        else:
+                            chunks = muon_ops.owner_momentum_arrays(
+                                owners, mom, np)
+                            fill = (new_num_shards * owners.stretch
+                                    - len(m))
+                            m, v = np.pad(m, (0, fill)), np.pad(v, (0, fill))
+                        step = float(step[0]) if len(step) else 0.0
                         state = (
-                            *(_nplace(a, sharding) for a in arrs[:-1]),
-                            _nplace(np.full(1, step, np.float32), sharding),
+                            *(_nplace(np.ascontiguousarray(a), sharding)
+                              for a in (*chunks, m, v)),
+                            _nplace(np.full(new_num_shards, step,
+                                            np.float32), sharding),
                         )
                     elif kind in ("sgd_momentum", "adagrad"):
                         state = (
@@ -2720,6 +3027,9 @@ class CollectiveEngine:
                     b = snap[n][0]
                     entry = staged[n]
                     b.padded_len = entry["padded"]
+                    b.owned = entry["owners"]
+                    if b.owned is not None:
+                        b.owner_plan = (new_num_shards, b.owned)
                     self._stores[n] = entry["store"]
                     if "pinned" in entry:
                         self._pinned_pulls[n] = entry["pinned"]

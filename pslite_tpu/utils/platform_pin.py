@@ -30,6 +30,16 @@ def pin_cpu(n_devices: int = 8) -> None:
         os.environ["XLA_FLAGS"] = flags
     else:
         os.environ["XLA_FLAGS"] = (flags + " " + flag).strip()
+    # The CPU client runs every device's program on ONE pool of
+    # max(cores, devices) threads (XLA reads ``NPROC`` for the cores).  A
+    # host callback inside a program (the Pallas TPU interpreter's DMAs and
+    # semaphores: ``ops/muon.py`` ``row_apply``) holds its device's thread
+    # and needs another for the arrays it is handed, so a program over ALL
+    # the devices of a machine with no more cores than devices never ends:
+    # keep a spare thread a device.
+    spare = 2 * n_devices
+    if int(os.environ.get("NPROC") or os.cpu_count() or 1) < spare:
+        os.environ["NPROC"] = str(spare)
 
     import jax
 

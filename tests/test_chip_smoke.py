@@ -25,6 +25,29 @@ TINY = chip_smoke.Sizes(
 )
 
 
+def _muon_alone(monkeypatch, devices: int) -> None:
+    """Boot, the ``muon`` phase on an engine over the first ``devices`` of
+    the eight, shutdown."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from pslite_tpu.parallel.engine import CollectiveEngine
+
+    mesh = Mesh(np.array(jax.devices()[:devices]), ("kv",))
+
+    def phase(self):
+        self.kv.po.van.engine = CollectiveEngine(
+            mesh=mesh, server_handle=chip_smoke.SERVER_HANDLE)
+        self.muon()
+
+    monkeypatch.setattr(
+        chip_smoke._Smoke, "phases",
+        lambda self: [("boot", 60, self.boot),
+                      ("muon", 150, lambda: phase(self)),
+                      ("shutdown", 30, self.shutdown)])
+    chip_smoke.run_smoke(default_mesh(), TINY)
+
+
 def test_smoke_phases_at_tiny_size(capsys):
     hung = []
     chip_smoke.run_smoke(default_mesh(), TINY,
@@ -35,30 +58,27 @@ def test_smoke_phases_at_tiny_size(capsys):
                   "sparse", "message_path", "shutdown"):
         assert f"phase {phase}: ok" in out
     assert "W = 8, kernels interpreted" in out
-    assert "over 8 shards muon refuses by name" in out
+    # PR 56: over the eight shards both trees lie by their owners, a matrix
+    # each as far as the matrices go (seven, and four).
+    assert "7 owners over 8 shards" in out and "4 owners over 8 shards" in out
+
+
+def test_the_muon_phase_over_four_owners(capsys, monkeypatch):
+    """PR 56: over four shards (the host the benchmark's four-chip cell
+    runs on) both trees are sharded on their keys' borders and run their
+    two steps against the float64 recurrence; the pulled tree is the
+    gather laid back into key order, no kernel's own vector."""
+    _muon_alone(monkeypatch, 4)
+    out = capsys.readouterr().out
+    assert "phase muon: ok" in out
+    assert out.count(
+        "2 steps under muon:0.001,0.95,0.1,0.9,0.95,1e-08 agree") == 2
+    assert out.count("pulled by the kernels 0 of 2") == 2
 
 
 def test_the_muon_phase_where_one_shard_holds_the_bucket(capsys, monkeypatch):
-    """On one device the phase runs its two steps against the float64
-    recurrence (on the 8-device mesh above it must refuse by name)."""
-    import numpy as np
-    from jax.sharding import Mesh
-
-    from pslite_tpu.parallel.engine import CollectiveEngine
-
-    mesh = Mesh(np.array(jax.devices()[:1]), ("kv",))
-
-    def one_shard(self):
-        self.kv.po.van.engine = CollectiveEngine(
-            mesh=mesh, server_handle=chip_smoke.SERVER_HANDLE)
-        self.muon()
-
-    monkeypatch.setattr(
-        chip_smoke._Smoke, "phases",
-        lambda self: [("boot", 60, self.boot),
-                      ("muon", 150, lambda: one_shard(self)),
-                      ("shutdown", 30, self.shutdown)])
-    chip_smoke.run_smoke(default_mesh(), TINY)
+    """On one device the plan is the identity."""
+    _muon_alone(monkeypatch, 1)
     out = capsys.readouterr().out
     assert "phase muon: ok" in out
     assert "2 steps under muon:0.001,0.95,0.1,0.9,0.95,1e-08 agree" in out

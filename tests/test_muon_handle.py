@@ -5,7 +5,8 @@ matrices, on a dense bucket registered with its keys' lengths AND shapes
 Through ``KVWorker.push_pull`` / ``push`` / ``pull`` on the engine path,
 against ``benchmark/muon_reference.py`` (numpy, float64 outside the
 products, operands rounded by ``reference.bf16``, one matrix at a time,
-imports nothing of the program), on the one shard the handle runs on.  The
+imports nothing of the program), on one shard (``test_muon_owners.py`` has
+the handle over four).  The
 tree has wide, tall and square matrices, a 64-row router, equal shapes that
 share a batched product, a key on no lane border between two matrices and
 AdamW keys beside the Muon keys.
@@ -371,7 +372,9 @@ def test_save_and_restore_carry_momentum_moments_and_the_step(tmp_path,
                                   4.0)
 
 
-def test_reshard_from_one_shard_to_one_shard_and_no_further():
+def test_reshard_from_one_shard_to_four_owners_and_back():
+    """1 -> 4 -> 1 shards: store, momentum, m, v and the step move intact
+    between the key order of one shard and the four owners' layout."""
     eng = _engine()
     rng = np.random.default_rng(6)
     init = _init(rng)
@@ -379,16 +382,38 @@ def test_reshard_from_one_shard_to_one_shard_and_no_further():
     twin = _engine()
     _register(twin, init)
     g = rng.normal(size=(1, TOTAL)).astype(np.float32)
-    eng.push_pull("t", g)
-    twin.push_pull("t", g)
+    jax.block_until_ready((eng.push_pull("t", g), twin.push_pull("t", g)))
+
+    def same_state():
+        *states, steps = zip(eng.opt_state("t")[1], twin.opt_state("t")[1])
+        for got, want in states:            # momentum, m, v: key order
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        # The step: a slot a shard, of whichever mesh.
+        assert np.asarray(steps[0])[0] == np.asarray(steps[1])[0]
+        np.testing.assert_array_equal(
+            np.asarray(eng.store_array("t"))[:TOTAL],
+            np.asarray(twin.store_array("t"))[:TOTAL])
+
     eng.reshard(Mesh(np.array(jax.devices()[1:2]), ("kv",)))
     np.testing.assert_array_equal(np.asarray(eng.push_pull("t", g)),
                                   np.asarray(twin.push_pull("t", g)))
-    with pytest.raises(log.CheckError, match="a matrix would lie across"):
-        eng.reshard(_mesh(4))
-    assert eng.num_shards == 1      # staged, refused, nothing committed
-    np.testing.assert_array_equal(np.asarray(eng.push_pull("t", g)),
-                                  np.asarray(twin.push_pull("t", g)))
+    eng.reshard(_mesh(4))
+    assert eng.num_shards == 4 and eng.bucket("t").owned is not None
+    assert eng.bucket("t").padded_len == eng.bucket("t").owned.padded_len
+    same_state()
+    # Four workers that each push a quarter of g: the sum is g's, up to its
+    # last place.
+    np.testing.assert_allclose(
+        np.asarray(eng.push_pull("t", np.repeat(g / 4, 4, axis=0))),
+        np.asarray(twin.push_pull("t", g)), atol=2e-5)
+    jax.block_until_ready(eng._stores["t"])
+    eng.reshard(_mesh(1))
+    assert eng.num_shards == 1 and eng.bucket("t").owned is None
+    np.testing.assert_array_equal(np.asarray(eng.opt_state("t")[1][3]), 3.0)
+    np.testing.assert_allclose(np.asarray(eng.push_pull("t", g)),
+                               np.asarray(twin.push_pull("t", g)), atol=4e-5)
+    assert np.asarray(eng.opt_state("t")[1][0]).shape == (
+        int(LENS[~ADAMW].sum()),)
 
 
 # -- where it cannot run it says so by name -------------------------------------
@@ -402,7 +427,7 @@ def _refused(eng, match, **kw):
 
 
 @pytest.mark.parametrize("case", ["no_shapes", "a_wrong_product",
-                                  "four_shards", "a_mixed_bucket",
+                                  "a_mixed_bucket",
                                   "a_bf16_store", "no_lens", "no_bucket"])
 def test_muon_refuses_by_name_what_it_cannot_run(case):
     import jax.numpy as jnp
@@ -415,9 +440,6 @@ def test_muon_refuses_by_name_what_it_cannot_run(case):
         bad[3] = (96, 255)
         _refused(_engine(), "rows \\* cols must be the key's len; key \\[3\\]",
                  shapes=bad)
-    elif case == "four_shards":
-        _refused(_engine(4), "lies over 4 shards.*a matrix would lie across "
-                 "chips")
     elif case == "a_mixed_bucket":
         _refused(_engine(), "pushed and pulled in bfloat16",
                  dtype=jnp.float32, job_dtype=jnp.bfloat16)
